@@ -21,8 +21,7 @@ means the model axis pays for itself on this substrate).
 
 Usage: python benchmarks/micro_mesh.py [--cpu] [--out out.json]
 The CPU rehearsal artifact lives at benchmarks/micro_mesh_cpu.json
-(8 virtual host devices); chip numbers queue through
-scripts/round6_chain.sh.
+(8 virtual host devices); on a chip: not measured.
 """
 
 import argparse

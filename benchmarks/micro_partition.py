@@ -24,7 +24,7 @@ the Reddit-shaped ``planted`` community graph.
 
 Usage: python benchmarks/micro_partition.py [--cpu] [--out out.json]
 The CPU rehearsal artifact lives at benchmarks/micro_partition_cpu.json;
-chip numbers queue through scripts/round6_chain.sh.
+on a chip: not measured.
 """
 
 import argparse

@@ -16,7 +16,7 @@ Usage:
 
 ``--json`` prints one JSON object on stdout — findings, baseline
 split, and the program-space compile-budget reports with full
-program-key sets — so CI and the bench probe can diff program counts
+program-key sets — so CI can diff program counts
 across commits without parsing text.
 
 The baseline (``scripts/lint_baseline.json``) is ratchet-only:
@@ -59,8 +59,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                         "an AST-only selection skips the jax trace "
                         "stage entirely.  'concurrency' expands to "
                         "every level-six concurrency/signal-safety "
-                        "rule (jax-free — the scripts/test.sh and "
-                        "round6_chain.sh preflight selection); "
+                        "rule (jax-free — the scripts/test.sh "
+                        "preflight selection); "
                         "'sharding' expands to every level-seven "
                         "sharding/replication rule (runs the rig "
                         "builds + jaxpr walks, no compiles); "

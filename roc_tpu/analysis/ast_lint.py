@@ -87,10 +87,9 @@ class StdoutPrintRule(AstRule):
                    # the prewarm CLI's stdout IS its product (one
                    # machine-readable JSON report line per config)
                    "roc_tpu/prewarm.py",
-                   # same for the timeline merger and the regression
-                   # sentinel: their stdout is the report/verdict
+                   # same for the timeline merger: its stdout is the
+                   # report
                    "roc_tpu/obs/timeline.py", "roc_tpu/timeline.py",
-                   "roc_tpu/obs/sentinel.py", "roc_tpu/sentinel.py",
                    # the serve export CLI prints one JSON report line
                    # (error paths go to stderr like every CLI here)
                    "roc_tpu/serve/export.py", "roc_tpu/export.py"}

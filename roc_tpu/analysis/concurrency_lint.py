@@ -4,8 +4,8 @@ The host-side runtime is no longer one SPMD step loop: a StagingPool
 h2d worker (``core/streaming.py``), the Heartbeat watchdog
 (``obs/heartbeat.py``), the coalescing ``Server._loop`` dispatcher
 (``serve/server.py``), the event-bus locks (``obs/events.py``),
-SIGTERM/SIGINT handlers (``resilience/preempt.py``), and bench's
-stderr reader threads all run concurrently with the training/serving
+SIGTERM/SIGINT handlers (``resilience/preempt.py``) and the router's
+replica reader threads all run concurrently with the training/serving
 main thread.  Every concurrency bug shipped so far was caught by hand
 review *after* the fact — the non-signal-reentrant event-bus lock and
 the ``interrupt_main``-never-delivered hang (PR 8), the open-loop
@@ -13,7 +13,7 @@ wake-before-callback race (PR 11).  This level makes that bug class a
 ratcheted static gate, same contract as the other five.
 
 The auditor parses the whole host-side tree (``roc_tpu/**/*.py`` plus
-the repo-root ``bench.py`` and ``benchmarks/*.py``) ONCE into a
+``benchmarks/*.py``) ONCE into a
 cross-module model of
 
 - **lock objects** — ``threading.Lock/RLock/Condition`` bound to
@@ -196,9 +196,6 @@ class TreeModel:
         self.modules: Dict[str, ModuleModel] = {}
         base = pathlib.Path(root)
         paths = sorted(base.glob("roc_tpu/**/*.py"))
-        for extra in [base / "bench.py"]:
-            if extra.exists():
-                paths.append(extra)
         paths.extend(sorted(base.glob("benchmarks/*.py")))
         for path in paths:
             rel = path.relative_to(base).as_posix()
@@ -1260,7 +1257,7 @@ def _const_target_name(ts: ThreadStart) -> Optional[str]:
 # of this level, ISSUE 14 satellite): the checkpoint-rotation prefix
 # (N training processes, one rotation dir — the DCN drill's shared
 # rotation), the persistent compile-cache dir (prewarm children +
-# bench probes + serve replicas), and the prewarm warm-state JSON.
+# serve replicas), and the prewarm warm-state JSON.
 # Each has ONE sanctioned ownership protocol:
 #
 # - rotation prefix: the shared-rotation handshake — process 0 writes,

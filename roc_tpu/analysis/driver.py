@@ -149,7 +149,7 @@ def build_trace_findings(select: Optional[List[str]] = None,
             tr.mask, tr.gctx), **ctx),
         JaxprUnit("eval_step", jax.make_jaxpr(tr._eval_step._jit)(
             tr.params, tr.feats, tr.labels, tr.mask, tr.gctx), **ctx),
-        # the recorded-op model graph, traced directly (no pjit): the
+        # the recorded-op model graph, traced directly (no jit): the
         # builder's interpreter is where an op-list rewrite (fusion,
         # streaming split) would first leak an anti-pattern
         JaxprUnit("model_graph", jax.make_jaxpr(

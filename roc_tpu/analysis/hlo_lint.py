@@ -4,9 +4,12 @@ The compile observer (obs/compile_watch.py) already lowers and
 compiles the step AOT; this layer inspects what XLA actually built:
 
 - [hlo-large-copy] ``copy`` / ``transpose`` instructions materializing
-  activation-scale ([V, F]) tensors OUTSIDE fusions — each one is a
-  full HBM round trip the fusion pipeline failed to elide (layout
+  activation-scale ([V, F]) FLOAT tensors OUTSIDE fusions — each one
+  is a full HBM round trip the fusion pipeline failed to elide (layout
   mismatches at custom-call/donation boundaries are the usual cause).
+  Integer tensors are not activations: XLA:CPU's rolled threefry loop
+  copies its [V, F] u32 dropout state between rounds, which says
+  nothing about the step's data movement.
 - [hlo-bytes-model] executable-level ``bytes accessed`` exceeding the
   core/memory.py plan estimate by a configurable factor — the static
   analog of ObservedJit's modeled-vs-actual warning, catching
@@ -40,10 +43,11 @@ def _shape_elems(dims: str) -> int:
 
 def check_large_copy(unit: str, hlo_text: str, copy_min_elems: int
                      ) -> List[Finding]:
-    """Flag un-fused copy/transpose of tensors >= ``copy_min_elems``
-    elements.  Instructions inside ``fused_computation`` bodies are
-    skipped — there the transpose is folded into the fusion's
-    reads/writes, not a separate materialization."""
+    """Flag un-fused copy/transpose of float tensors >=
+    ``copy_min_elems`` elements.  Instructions inside
+    ``fused_computation`` bodies are skipped — there the transpose is
+    folded into the fusion's reads/writes, not a separate
+    materialization."""
     out: List[Finding] = []
     in_fusion = False
     for line in hlo_text.splitlines():
@@ -58,7 +62,7 @@ def check_large_copy(unit: str, hlo_text: str, copy_min_elems: int
             continue
         dtype, dims, op = m.groups()
         n = _shape_elems(dims)
-        if n >= copy_min_elems:
+        if dtype.startswith(("f", "bf")) and n >= copy_min_elems:
             out.append(Finding(
                 "hlo-large-copy", unit,
                 f"un-fused {op} materializes {dtype}[{dims}] "

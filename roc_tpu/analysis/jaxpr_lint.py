@@ -4,7 +4,7 @@ Each lint *unit* is one traced program (train step, eval step, the
 recorded-op model graph) plus the static context a rule needs to tell
 intended from unintended: the configured compute dtype, the
 dataset's [V, F] scale, the halo mode, donation thresholds.  Rules
-walk the whole nesting (pjit / shard_map / custom_vjp / scan bodies)
+walk the whole nesting (jit / shard_map / custom_vjp / scan bodies)
 — an anti-pattern inside a remat body is still an anti-pattern.
 
 The thresholds are *scale-relative*, not absolute: "[V, F]-scale"
@@ -22,9 +22,10 @@ from .findings import Finding
 # int32 overflow hazard threshold (rule jaxpr-int32-overflow)
 _INT32_LIMIT = 2 ** 31
 
-# host-callback primitive names across jax versions
-_CALLBACK_PRIMS = ("debug_callback", "pure_callback", "io_callback",
-                   "debug_print", "outside_call", "host_callback")
+# host-callback primitive names (jax.debug.print traces to
+# debug_print, jax.debug.callback to debug_callback)
+_CALLBACK_PRIMS = ("debug_print", "debug_callback", "pure_callback",
+                   "io_callback")
 
 _COLLECTIVE_GATHERS = ("all_gather", "all_gather_invariant",
                        "all_to_all")
@@ -64,7 +65,7 @@ class JaxprUnit:
 
 
 def _inner_jaxprs(eqn) -> Iterator[Any]:
-    """Jaxprs nested in an eqn's params (pjit/shard_map/custom_vjp/
+    """Jaxprs nested in an eqn's params (jit/shard_map/custom_vjp/
     scan/remat bodies), whatever the param key."""
     for v in eqn.params.values():
         for item in (v if isinstance(v, (tuple, list)) else (v,)):
@@ -156,9 +157,9 @@ def check_non_donated(u: JaxprUnit) -> List[Finding]:
     passed undonated double their HBM residency for the whole step
     (XLA must keep the input alive while writing the update).
 
-    Only the DISPATCH-BOUNDARY pjit is judged — the single top-level
-    pjit eqn of a traced jitted callable.  Donation is a caller-side
-    contract at that boundary; inner library pjits are inlined by XLA,
+    Only the DISPATCH-BOUNDARY jit is judged — the single top-level
+    jit eqn of a traced jitted callable.  Donation is a caller-side
+    contract at that boundary; inner library jits are inlined by XLA,
     which reuses their buffers without any donate_argnums.
 
     Value-and-grad recognition: jax's ``value_and_grad`` convention
@@ -190,7 +191,7 @@ def check_non_donated(u: JaxprUnit) -> List[Finding]:
     keep that convention or donate explicitly."""
     out: List[Finding] = []
     top = [e for e in u.jaxpr.jaxpr.eqns
-           if e.primitive.name == "pjit"]
+           if e.primitive.name == "jit"]
     if len(top) != 1 or len(u.jaxpr.jaxpr.eqns) != 1:
         return out
     for eqn in top:
@@ -323,7 +324,7 @@ def check_int32_overflow(u: JaxprUnit) -> List[Finding]:
 
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
-            if name == "pjit" or _is_container(eqn):
+            if name == "jit" or _is_container(eqn):
                 for inner in _inner_jaxprs(eqn):
                     inner_bounds: Dict[Any, int] = {}
                     for iv, ov in zip(getattr(inner, "invars", ()),

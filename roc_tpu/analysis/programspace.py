@@ -355,8 +355,7 @@ def candidate_programs(tr) -> List["Candidate"]:
     — note predict compiles NOTHING of its own since it reuses the
     eval program's logits output; the multi-process-only
     ``dist_predict_gather`` is out of scope for single-controller
-    rigs).  Works on any built trainer — the audited rigs AND live
-    bench trainers (utils/prewarm.warm_trainer)."""
+    rigs).  Works on any built trainer, not only the audited rigs."""
     import jax
     import jax.numpy as jnp
 
@@ -407,9 +406,9 @@ def candidate_programs(tr) -> List["Candidate"]:
             roles=("params", "data", "data", "data", "tables"))
     else:                                         # streamed head
         # abstract stand-ins, never materialized: [V, H] at the >HBM
-        # tier is multi-GB, and warm_trainer runs this on LIVE bench
-        # trainers whose aot closures would otherwise pin the buffers
-        # alive for the whole warm loop.  leaf_struct renders a
+        # tier is multi-GB, and on a live trainer the aot closures
+        # would otherwise pin the buffers alive for the whole warm
+        # loop.  leaf_struct renders a
         # ShapeDtypeStruct identically to a default-placed array
         # (spec '-'), and both make_jaxpr and jit.lower accept them,
         # so keys and prewarmed executables are unchanged.

@@ -33,7 +33,7 @@ no compilation, no chip time.  Three products:
     bound down); plus a loose ledger-vs-plan excess check;
   - ``full-width-materialization`` — ops whose abstract-eval output
     is unsplit along a sharded-input axis (the implicit re-gather);
-  - ``sharding-mismatch`` — pjit in/out shardings or
+  - ``sharding-mismatch`` — jit in/out shardings or
     ``with_sharding_constraint``s that force an implicit
     all-gather/reshard on the hot path;
   - ``donation-under-sharding`` — donated buffers whose donor/donee
@@ -53,7 +53,7 @@ no compilation, no chip time.  Three products:
 
 Live-mesh semantics vs simulation: findings come from the LIVE rig
 semantics (the real 1-D parts mesh, plus any ``sharding_constraint``
-/ pjit sharding the code actually carries — today none, so the
+/ jit sharding the code actually carries — today none, so the
 baseline is EMPTY and stays so until the 2-D work begins, exactly
 like the compile-explosion ratchet before a new program shape).  The
 ``model``-axis seeding is confined to the portability REPORT, whose
@@ -152,6 +152,15 @@ class Site:
                 "per_device_bytes": per_shape}
 
 
+def _pspec_names(pspec) -> Dict[int, Tuple[str, ...]]:
+    """A shard_map eqn's PartitionSpec as {dim: mesh axis names}."""
+    out: Dict[int, Tuple[str, ...]] = {}
+    for d, entry in enumerate(pspec):
+        if entry is not None:
+            out[d] = entry if isinstance(entry, tuple) else (entry,)
+    return out
+
+
 def _src_of(eqn) -> str:
     """Best-effort ``file:line`` of the user frame that traced this
     eqn — informational only (fingerprints never embed it).  Frames
@@ -197,7 +206,7 @@ _ELEMENTWISE = {
 # spec-transparent containers: propagate into the sub-jaxpr with
 # end-aligned invar mapping (handles cond's leading index operand and
 # custom_vjp's nondiff prefixes), outputs end-aligned back
-_CONTAINER = {"pjit", "closed_call", "core_call", "call", "remat",
+_CONTAINER = {"jit", "closed_call", "core_call", "call", "remat",
               "remat2", "checkpoint", "custom_jvp_call",
               "custom_vjp_call", "custom_jvp_call_jaxpr",
               "custom_vjp_call_jaxpr", "custom_lin"}
@@ -347,7 +356,7 @@ class Propagator:
                                     "shape", ()))
                       for v in eqn.invars]
             had_split = bool(self._axes_of(specs))
-            # containers (pjit/scan/shard_map/...) are wrappers, not
+            # containers (jit/scan/shard_map/...) are wrappers, not
             # ops: their BODIES are walked and counted, and a
             # shard_map boundary pin is already a reported site —
             # charging the wrapper eqn would double-book it
@@ -666,8 +675,8 @@ class Propagator:
 
     def _shard_map(self, eqn, specs, shapes) -> List[Optional[Spec]]:
         body = eqn.params["jaxpr"]
-        in_names = eqn.params.get("in_names", ())
-        out_names = eqn.params.get("out_names", ())
+        in_names = [_pspec_names(p) for p in eqn.params["in_specs"]]
+        out_names = [_pspec_names(p) for p in eqn.params["out_specs"]]
         body_in: List[Spec] = []
         for i, (spec, names) in enumerate(zip(specs, in_names)):
             names = dict(names or {})

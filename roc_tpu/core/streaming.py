@@ -111,8 +111,8 @@ class StagingPool:
         The derived summary — ``wait_p50_ms``, ``stage_p50_ms``,
         ``overlap_frac`` (clamped ``1 - wait_total/stage_total``;
         None when nothing was staged) — is computed HERE, once, so
-        every consumer (trainer epoch records, bench rows,
-        micro_stream) reports identical semantics."""
+        every consumer (trainer epoch records, micro_stream)
+        reports identical semantics."""
         with self._lock:
             wait, stage = self.h2d_wait_ms, self.stage_ms
             wait_t0, stage_t0 = self.h2d_wait_t0, self.stage_t0

@@ -40,13 +40,14 @@ scan:4096               260.0      39.4
 blocked:1024            294.6      34.8
 ====================  =========  ========
 
-**bf16 limitation (measured 2026-07-30):** with bfloat16 features the
-kernel fails Mosaic compilation on v5e (remote-compile INTERNAL error;
-the per-row ``[1, F]`` bf16 DMA/accumulate pattern — fp32 compiles and
-runs).  The framework never routes bf16 through this kernel by
-default (``ell`` wins the race anyway); the micro bench records the
-error as data (``measured_baselines.json
-neighbor_aggregation_reduced_mixed.impls.pallas``).
+**bf16** (TPU v5 lite, libtpu 0.0.34, PR 21): the kernel used to be
+refused by Mosaic with bfloat16 features ("cannot statically prove
+that index in dimension 0 is a multiple of 8" on the single-row load
+of the bf16 output block).  It compiles in both dtypes since the
+output block is fp32 and the DMA group follows the feature dtype's
+sublane tiling (:func:`_group_rows`); ``chip_smoke.py`` compiles it on
+the chip in fp32 and bf16.  Compile only: it has not been timed in
+bf16.
 
 The XLA gather path wins by ~18x net of sync overhead and **is the
 framework default**.  Two structural reasons, both discovered only by
@@ -78,37 +79,48 @@ _EDGES_PER_STEP = 2048
 _NBUF = 8
 
 
+def _group_rows(dtype) -> int:
+    """Rows of one aligned DMA group: the sublane tiling of ``dtype``
+    in HBM — (8, 128) for 4-byte elements, (16, 128) for 2-byte ones
+    (two rows pack into one sublane)."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
 def _bucket_kernel(idx_ref, feats_ref, out_ref, buf, sem, *, nbuf: int):
     """One (row-block, width-chunk) tile of a single ELL bucket.
 
     idx_ref: int32 [BR, WC] in SMEM (source row ids; dummy -> zero row).
     feats_ref: [R_gathered + 1, F] in HBM/ANY (never block-copied).
-    out_ref: [BR, F] VMEM output block, revisited over the width axis.
-    buf: VMEM [nbuf, 8, F] rotating group buffer; sem: DMA sems [nbuf].
+    out_ref: fp32 [BR, F] VMEM output block, revisited over the width
+    axis (fp32 whatever the feature dtype: rows accumulate across the
+    width chunks, and a 2-byte output block cannot take the single-row
+    dynamic store).
+    buf: VMEM [nbuf, G, F] rotating group buffer; sem: DMA sems [nbuf].
 
-    HBM memrefs are (8, 128)-tiled on TPU, so a single feature row can
-    NOT be DMA'd (Mosaic: "slice shape along dimension 0 must be aligned
-    to tiling (8)"); each copy therefore stages the aligned 8-row group
-    containing the source row and the reduction mask-selects the one row
-    — an 8x gather amplification that is this design's intrinsic cost
-    (see module docstring for the measured consequence).
+    HBM memrefs are (G, 128)-tiled on TPU (:func:`_group_rows`), so a
+    single feature row can NOT be DMA'd (Mosaic: "slice shape along
+    dimension 0 must be aligned to tiling"); each copy therefore
+    stages the aligned G-row group containing the source row and the
+    reduction mask-selects the one row — a Gx gather amplification
+    that is this design's intrinsic cost (see module docstring for the
+    measured consequence).
     """
     BR, WC = idx_ref.shape
     F = out_ref.shape[1]
-    total_rows = feats_ref.shape[0]
+    G = buf.shape[1]
     j = pl.program_id(1)
     total = BR * WC
 
     def group_base(e):
-        # aligned 8-row group start; the wrapper pads feats to a
-        # multiple of 8 rows, so this is always in-bounds AND Mosaic
+        # aligned G-row group start; the wrapper pads feats to a
+        # multiple of G rows, so this is always in-bounds AND Mosaic
         # can prove tiling divisibility (a min-clamp defeats the prover)
         gid = idx_ref[e // WC, e % WC]
-        return (gid // 8) * 8
+        return (gid // G) * G
 
     def dma(e, slot):
         return pltpu.make_async_copy(
-            feats_ref.at[pl.ds(group_base(e), 8), :],
+            feats_ref.at[pl.ds(group_base(e), G), :],
             buf.at[slot],
             sem.at[slot])
 
@@ -120,7 +132,7 @@ def _bucket_kernel(idx_ref, feats_ref, out_ref, buf, sem, *, nbuf: int):
     for k in range(min(nbuf, WC)):  # static unroll; nbuf, WC static
         dma(k, k % nbuf).start()
 
-    lane = lax.broadcasted_iota(jnp.int32, (8, 1), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (G, 1), 0)
 
     def row_body(r, _):
         def w_body(w, acc):
@@ -142,8 +154,7 @@ def _bucket_kernel(idx_ref, feats_ref, out_ref, buf, sem, *, nbuf: int):
 
         acc = lax.fori_loop(0, WC, w_body, jnp.zeros((1, F), jnp.float32),
                             unroll=False)
-        out_ref[pl.ds(r, 1), :] = (
-            out_ref[pl.ds(r, 1), :] + acc.astype(out_ref.dtype))
+        out_ref[pl.ds(r, 1), :] = out_ref[pl.ds(r, 1), :] + acc
         return 0
 
     lax.fori_loop(0, BR, row_body, 0, unroll=False)
@@ -177,12 +188,13 @@ def ell_aggregate_pallas(feats: jax.Array, ell_idx, ell_row_pos: jax.Array,
     """
     F = feats.shape[1]
     dummy = feats.shape[0] - 1
-    # pad rows to a multiple of 8 so every aligned 8-row DMA group is
-    # in-bounds (HBM tiling; see _bucket_kernel docstring)
+    # pad rows to a multiple of the group so every aligned DMA group
+    # is in-bounds (HBM tiling; see _bucket_kernel docstring)
+    G = _group_rows(feats.dtype)
     Rg = feats.shape[0]
-    Rg8 = -(-Rg // 8) * 8
-    if Rg8 != Rg:
-        feats = jnp.pad(feats, ((0, Rg8 - Rg), (0, 0)))
+    Rgp = -(-Rg // G) * G
+    if Rgp != Rg:
+        feats = jnp.pad(feats, ((0, Rgp - Rg), (0, 0)))
     outs = []
     for idx in ell_idx:
         R, W = idx.shape
@@ -199,17 +211,17 @@ def ell_aggregate_pallas(feats: jax.Array, ell_idx, ell_row_pos: jax.Array,
             in_specs=[
                 pl.BlockSpec((BR, WC), lambda i, j: (i, j),
                              memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((BR, F), lambda i, j: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((Rp, F), feats.dtype),
+            out_shape=jax.ShapeDtypeStruct((Rp, F), jnp.float32),
             scratch_shapes=[
-                pltpu.VMEM((_NBUF, 8, F), feats.dtype),
+                pltpu.VMEM((_NBUF, G, F), feats.dtype),
                 pltpu.SemaphoreType.DMA((_NBUF,)),
             ],
             interpret=interpret,
         )(idx, feats)
-        outs.append(out[:R])
+        outs.append(out[:R].astype(feats.dtype))
     zero = jnp.zeros((1, F), dtype=feats.dtype)
     cat = jnp.concatenate(outs + [zero], axis=0)
     return cat[ell_row_pos]

@@ -39,7 +39,6 @@ def _seg_reduce_kernel(dst_ref, g_ref, out_ref, carry_ref):
     """One edge chunk: segmented sum of gathered rows ``g`` by sorted
     local destination, emitting the window block + last-row carry."""
     C = dst_ref.shape[1]
-    F = g_ref.shape[1]
     dst = dst_ref[0, :]                               # [C] int32
     r0 = dst_ref[0, 0]
     local = dst - r0                                  # [C] in [0, C)
@@ -53,10 +52,13 @@ def _seg_reduce_kernel(dst_ref, g_ref, out_ref, carry_ref):
     L = lax.dot_general(sel, g, (((0,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)  # [C, F]
 
-    carry_ref[0, :] = lax.dynamic_slice(L, (pos, 0), (1, F))[0].astype(
-        carry_ref.dtype)
-    rows = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
-    out_ref[:] = jnp.where(rows == pos, 0.0, L).astype(out_ref.dtype)
+    # row ``pos`` leaves as the carry and is zeroed in the window; a
+    # masked reduction selects it (Mosaic lowers no dynamic_slice on
+    # a value)
+    last = lax.broadcasted_iota(jnp.int32, (C, 1), 0) == pos
+    carry_ref[:] = jnp.sum(jnp.where(last, L, 0.0), axis=0,
+                           keepdims=True).astype(carry_ref.dtype)
+    out_ref[:] = jnp.where(last, 0.0, L).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,

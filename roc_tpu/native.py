@@ -5,10 +5,15 @@ CUDA task bodies (``load_task.cu``, ``gnn.cc:751-872``); here the same
 components live in ``native/rocio.cc`` behind a C ABI, loaded lazily
 via ctypes.  Every entry point has a pure-numpy fallback in
 ``roc_tpu.core`` — the native library is a performance path, not a hard
-dependency, so ``available()`` gates all call sites.
+dependency, so ``available()`` gates all call sites.  It does decide
+what ``aggr_impl='auto'`` can choose (the bdense structure probe is
+native-only), so a library that failed to build or load is never
+silent: the reason is echoed once as a ``resolve`` event and recorded
+in every run manifest (:func:`status`).
 
 The library is built with ``make -C native`` (attempted automatically
-on first use if the toolchain is present).
+on first use); :func:`rebuild` forces a fresh build and raises when
+the result does not load.
 """
 
 from __future__ import annotations
@@ -25,8 +30,11 @@ _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
 _LIB_PATH = os.environ.get(
     "ROC_TPU_NATIVE", os.path.join(_NATIVE_DIR, "librocio.so"))
 
+_ABI_VERSION = 5
+
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_why: Optional[str] = None      # why _lib is None after a load attempt
 
 
 def _i64p(a: np.ndarray):
@@ -37,14 +45,16 @@ def _i32p(a: np.ndarray):
     return a.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
 
 
+def _pinned() -> bool:
+    """A library pinned via ROC_TPU_NATIVE is an explicit operator
+    override: never rebuilt, trusted as-is."""
+    return "ROC_TPU_NATIVE" in os.environ
+
+
 def _stale() -> bool:
     """True when a previously built .so is older than its source —
     rebuilding then keeps native tests validating current code (the
-    binary is a build artifact, never checked in).  A library pinned
-    via ROC_TPU_NATIVE is trusted as-is (the env var is an explicit
-    operator override)."""
-    if "ROC_TPU_NATIVE" in os.environ:
-        return False
+    binary is a build artifact, never checked in)."""
     try:
         lib_mtime = os.path.getmtime(_LIB_PATH)
         return any(
@@ -54,42 +64,77 @@ def _stale() -> bool:
         return False
 
 
+def _make(force: bool = False) -> Optional[str]:
+    """``make -C native`` (``-B`` when ``force``); None on success,
+    else the reason it failed."""
+    cmd = ["make", "-C", _NATIVE_DIR] + (["-B"] if force else [])
+    try:
+        r = subprocess.run(cmd, capture_output=True, text=True,
+                           timeout=120, check=False)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"{' '.join(cmd)}: {e!r}"
+    if r.returncode != 0:
+        return (f"{' '.join(cmd)} exited {r.returncode}: "
+                f"{r.stderr.strip()[-400:]}")
+    return None
+
+
+def _abi_of(lib: ctypes.CDLL) -> int:
+    try:
+        lib.roc_abi_version.restype = ctypes.c_int
+        return int(lib.roc_abi_version())
+    except AttributeError:
+        return 1  # predates the version export
+
+
+def _dlopen():
+    """(lib, None) or (None, reason) for the library at ``_LIB_PATH``."""
+    if not os.path.exists(_LIB_PATH):
+        return None, f"{_LIB_PATH} does not exist"
+    try:
+        lib = ctypes.CDLL(_LIB_PATH)
+    except OSError as e:
+        return None, f"dlopen {_LIB_PATH}: {e}"
+    # ABI gate: the argtypes in _load describe THIS source tree's C
+    # signatures; a .so from before an ABI bump would read a pointer
+    # slot as an int (SIGSEGV or silent garbage)
+    got = _abi_of(lib)
+    if got != _ABI_VERSION:
+        return None, (f"{_LIB_PATH} has ABI v{got}, this tree expects "
+                      f"v{_ABI_VERSION}")
+    return lib, None
+
+
+def _open():
+    """(lib, None) or (None, reason).  Builds the in-tree library when
+    it is missing or older than its source, and once more from scratch
+    when what it then finds does not load — an ABI bump a copied
+    tree's rewritten mtimes hid from :func:`_stale`."""
+    if _pinned():
+        return _dlopen()
+    found = os.path.exists(_LIB_PATH)
+    build_err = _make() if not found or _stale() else None
+    lib, why = _dlopen()
+    if lib is None and found and build_err is None:
+        build_err = _make(force=True)
+        if build_err is None:
+            lib, why = _dlopen()
+    if lib is None and build_err:
+        why = f"{why}; build: {build_err}"
+    return lib, why
+
+
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _why
     if _lib is not None or _tried:
         return _lib
     _tried = True
-    if not os.path.exists(_LIB_PATH) or _stale():
-        makefile = os.path.join(_NATIVE_DIR, "Makefile")
-        if os.path.exists(makefile):
-            try:
-                subprocess.run(["make", "-C", _NATIVE_DIR],
-                               capture_output=True, timeout=120,
-                               check=False)
-            except (OSError, subprocess.TimeoutExpired):
-                pass
-    if not os.path.exists(_LIB_PATH):
-        return None
-    try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
-        return None
-    # ABI gate: the argtypes below describe THIS source tree's C
-    # signatures; a stale or pinned .so from before an ABI bump would
-    # read a pointer slot as an int (SIGSEGV or silent garbage), so
-    # mismatches fall back to the numpy paths instead of loading.
-    _ABI_VERSION = 5
-    try:
-        lib.roc_abi_version.restype = ctypes.c_int
-        got = int(lib.roc_abi_version())
-    except AttributeError:
-        got = 1  # predates the version export
-    if got != _ABI_VERSION:
+    lib, _why = _open()
+    if lib is None:
         from .obs.events import emit
-        emit("resolve", f"librocio.so ABI v{got} != expected "
-             f"v{_ABI_VERSION}; ignoring {_LIB_PATH} (rebuild with "
-             f"make -C native)", abi_got=got,
-             abi_expected=_ABI_VERSION)
+        emit("resolve", f"native librocio.so unavailable — {_why}; "
+             f"numpy host paths in use and aggr_impl='auto' cannot "
+             f"probe for bdense", native=False, reason=_why)
         return None
     # Full argtypes: int64_t params must not fall back to the 32-bit
     # c_int default (graphs with > 2^31 edges are in scope for the
@@ -134,6 +179,32 @@ def _load() -> Optional[ctypes.CDLL]:
     lib.roc_lpa_iterate.argtypes = [i64p, i32p, i64, i32p, i32p]
     _lib = lib
     return _lib
+
+
+def rebuild() -> None:
+    """Build the in-tree library afresh (``make -B``) and load it;
+    raises when the build fails or the result does not load.  For
+    callers that must not run on a library they merely found
+    (chip_smoke.py: a copied tree keeps ignored build products and
+    rewrites the mtimes :func:`_stale` compares)."""
+    global _lib, _tried, _why
+    if _lib is not None:
+        raise RuntimeError("native.rebuild() after the library was "
+                           "loaded: call it before first use")
+    err = _make(force=True)
+    if err:
+        raise RuntimeError(f"native build failed: {err}")
+    _tried = False
+    if _load() is None:
+        raise RuntimeError(f"native library did not load: {_why}")
+
+
+def status() -> dict:
+    """{'loaded', 'path', 'reason'} for the run manifest: which host
+    data path this process runs on, and why when it is not native."""
+    loaded = _load() is not None
+    return {"loaded": loaded, "path": _LIB_PATH,
+            "reason": None if loaded else _why}
 
 
 def available() -> bool:

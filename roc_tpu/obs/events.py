@@ -13,7 +13,7 @@ through :func:`emit` as a categorized event.  Two sinks:
 
 The module-level bus starts with a console sink only; a JSONL sink
 attaches via :func:`configure` (the CLI's ``--events`` flag) or the
-``ROC_TPU_EVENTS`` environment variable — inherited by bench child
+``ROC_TPU_EVENTS`` environment variable — inherited by child
 processes, so a staged benchmark's events land in one artifact.
 
 Deliberately jax-free and thread-safe: the stall heartbeat emits from
@@ -38,7 +38,6 @@ from typing import Any, Dict, List, Optional
 #   plan      memory plans, bdense occupancy, partition/ring echoes
 #   compile   lowering+compile cost, XLA cost/memory introspection
 #   epoch     per-eval timing, phase spans, throughput
-#   bench     benchmark stage lifecycle
 #   stall     heartbeat "still waiting in <stage>" events
 #   run       CLI lifecycle (resume, checkpoint, artifact writes)
 #   analysis  roc-lint findings (python -m roc_tpu.analysis)
@@ -83,7 +82,7 @@ from typing import Any, Dict, List, Optional
 #             invariant verdicts — ``python -m roc_tpu.report
 #             --protocol`` renders the tables from these records
 CATEGORIES = ("manifest", "resolve", "plan", "compile", "epoch",
-              "bench", "stall", "run", "analysis", "pipeline",
+              "stall", "run", "analysis", "pipeline",
               "costmodel", "programspace", "resilience", "timeline",
               "serve", "sharding", "checkpoint", "slo", "protocol")
 
@@ -260,7 +259,7 @@ _BUS_LOCK = threading.Lock()
 
 def get_bus() -> EventLog:
     """The process-global bus, created on first use: a console sink,
-    plus a JSONL sink when ``ROC_TPU_EVENTS`` is set (bench children
+    plus a JSONL sink when ``ROC_TPU_EVENTS`` is set (child processes
     and multi-host workers inherit the artifact path via env)."""
     global _BUS
     with _BUS_LOCK:

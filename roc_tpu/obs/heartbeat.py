@@ -1,11 +1,10 @@
 """Stall watchdog: periodic "still waiting in <stage>" events, with an
 optional deadline that converts a silent hang into a raisable failure.
 
-The round-5 bench stages all timed out silently at "claiming backend"
-— a blank timeout is undiagnosable after the fact.  A
+A run that times out silently is undiagnosable after the fact.  A
 :class:`Heartbeat` wraps any potentially-hanging region (backend
-claim, first compile, multihost setup collectives, a bench stage
-child) and emits a ``stall`` event every ``interval_s`` from a daemon
+claim, first compile, multihost setup collectives) and emits a
+``stall`` event every ``interval_s`` from a daemon
 thread, so the artifact records WHERE the time went and for how long,
 even when the region never returns.
 
@@ -16,7 +15,7 @@ set (or ``deadline_s`` passed), a region that outlives the deadline is
 thread blocked inside a C call) and the context manager converts the
 resulting ``KeyboardInterrupt`` into a :class:`StallFailure`, which the recovery
 loop (``resilience/recovery.py``) can checkpoint-restart instead of
-letting the run die as a blank bench timeout.  Only armed when the
+letting the run die as a blank timeout.  Only armed when the
 guarded region runs on the main thread (interrupting the main thread
 on behalf of a worker-thread region would hit the wrong victim).
 
@@ -36,7 +35,7 @@ from typing import Any, Optional
 
 from .events import emit
 
-# default watchdog period; bench/test harnesses tighten it via env
+# default watchdog period; test harnesses tighten it via env
 DEFAULT_INTERVAL_S = 30.0
 
 

@@ -85,6 +85,10 @@ def run_manifest(config=None, dataset=None, model=None,
             {d.device_kind for d in devs})
     except Exception as e:  # noqa: BLE001 - backendless manifest
         fields["backend_error"] = repr(e)
+    # which host data path this run is on: the native library decides
+    # what aggr_impl='auto' can choose (native.py status)
+    from .. import native
+    fields["native"] = native.status()
     if config is not None:
         fields["config"] = _config_dict(config)
         fields["resolved"] = {

@@ -212,7 +212,7 @@ def _marker(rec: Dict[str, Any]) -> Optional[Tuple[str, Dict[str, Any]]]:
                 {"msg": rec.get("msg"), "spec": rec.get("spec"),
                  "burn": rec.get("burn"), "value": rec.get("value"),
                  "target": rec.get("target")})
-    if cat in ("bench", "programspace", "run"):
+    if cat in ("programspace", "run"):
         return (f"{cat}", {"msg": rec.get("msg")})
     return None
 

@@ -41,14 +41,9 @@ def inv_sqrt_degree_np(in_degree: np.ndarray) -> np.ndarray:
                     0.0).astype(np.float32)
 
 
-def indegree_norm(x: jax.Array, in_degree: jax.Array,
-                  impl: str = "xla") -> jax.Array:
+def indegree_norm(x: jax.Array, in_degree: jax.Array) -> jax.Array:
     """x: [V, F]; in_degree: int32 [V].  Returns x / sqrt(indegree).
-
-    ``impl='pallas'`` routes through the explicit VMEM-tiled kernel
-    (kernels/graphnorm.py) — numerically identical; the XLA path is
-    the default because the multiply fuses into neighboring ops."""
-    if impl == "pallas":
-        from ..kernels.graphnorm import indegree_norm_pallas
-        return indegree_norm_pallas(x, in_degree)
+    Plain XLA: the multiply fuses into neighboring ops (the explicit
+    VMEM-tiled kernel, kernels/graphnorm.py, is reached only through
+    ``aggr_impl='pallas'``, which plumbs ``interpret`` itself)."""
     return x * inv_sqrt_degree(in_degree)[:, None].astype(x.dtype)

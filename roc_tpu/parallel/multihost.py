@@ -70,11 +70,7 @@ def init_distributed(coordinator_address: Optional[str] = None,
         # which accelerator-backend collectives never route through,
         # so TPU/GPU pods are unaffected; an EXPLICIT non-cpu platform
         # list skips it.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:  # noqa: BLE001 - flag renamed across jax
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     num_processes = num_processes if num_processes is not None else int(
         os.environ.get("JAX_NUM_PROCESSES", "1"))
     process_id = process_id if process_id is not None else int(

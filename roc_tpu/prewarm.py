@@ -3,21 +3,20 @@
 Feeds the program-space auditor's exact static enumeration
 (``analysis/programspace.py`` — keyed by the quantized plan shapes the
 rebalancer preserves) into AOT ``lower().compile()`` against the
-persistent compile cache, so rebalance / resume / serving / the bench
-probe all start warm.  Compile-only: nothing executes on a device.
+persistent compile cache, so rebalance / resume / serving all start
+warm.  Compile-only: nothing executes on a device.
 
 Usage:
-    python -m roc_tpu.prewarm                      # every hosted rig
-    python -m roc_tpu.prewarm --config gin_flat8   # one rig
-    python -m roc_tpu.prewarm --jobs 2             # parallel procs
-    python -m roc_tpu.prewarm --cpu                # force CPU backend
+    python -m roc_tpu.prewarm --cpu                # every rig, CPU
+    python -m roc_tpu.prewarm --config sgc_stream  # one rig
+    python -m roc_tpu.prewarm --cpu --jobs 2       # parallel procs
 
-Writes the warm-state artifact (``programspace_warm.json`` next to the
-bench artifacts) recording each warmed config's program-key set — the
-bench probe preflight diffs ``python -m roc_tpu.analysis --json``
-against it and refuses to burn chip deadline on a config whose program
-set grew since the cache was warmed.  Stdout gets one JSON line per
+Writes the warm-state artifact (``benchmarks/programspace_warm.json``)
+recording each warmed config's program-key set, diffable against
+``python -m roc_tpu.analysis --json``.  Stdout gets one JSON line per
 warmed config (machine-readable; `# ...` diagnostics go to stderr).
+A config the backend cannot host (fewer devices than its mesh) is an
+error: the command warms what it can and exits 1.
 """
 
 from __future__ import annotations
@@ -38,7 +37,8 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
                          "rig_configs) or 'all' (default)")
     ap.add_argument("--cache-dir", default=None,
                     help="persistent cache directory (default: "
-                         "$ROC_TPU_CACHE_DIR or ~/.cache/roc_tpu/xla)")
+                         "$JAX_COMPILATION_CACHE_DIR, which also wins "
+                         "over this flag, else <repo>/.jax_cache)")
     ap.add_argument("--state", default=None,
                     help="warm-state artifact path (default: "
                          "benchmarks/programspace_warm.json, honoring "
@@ -46,17 +46,14 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap.add_argument("--no-state", action="store_true",
                     help="do not write the warm-state artifact")
     ap.add_argument("--jobs", type=int, default=1,
-                    help="warm configs in N parallel child processes. "
-                         "The cache itself is file-based and multi-"
-                         "process safe, but (a) on a TPU host keep "
-                         "the default 1 — libtpu owns the accelerator "
-                         "exclusively, so a second concurrent child "
-                         "fails backend init — and (b) concurrent "
-                         "children sharing one cache dir make the "
-                         "warm-vs-cold attribution best-effort (a "
-                         "sibling's write inside a candidate's "
-                         "before/after window counts as cold); the "
-                         "warm-state KEY sets stay exact either way")
+                    help="warm configs in N parallel child processes; "
+                         "needs --cpu (an accelerator belongs to one "
+                         "process, so a second child could never "
+                         "reach it).  Concurrent children sharing one "
+                         "cache dir make the warm-vs-cold attribution "
+                         "best-effort (a sibling's write inside a "
+                         "candidate's before/after window counts as "
+                         "cold); the warm-state KEY sets stay exact")
     ap.add_argument("--cpu", action="store_true",
                     help="force the CPU backend (CI / cache priming "
                          "for CPU-rig tests)")
@@ -105,9 +102,9 @@ def _parallel(names: List[str], args) -> int:
     if reports and not args.no_state:
         from .utils.prewarm import write_warm_state
         # keep keys=[] reports: an all-failed config must be RECORDED
-        # as warmed-nothing so the preflight sees its whole program
-        # set as growth and refuses — dropping it would skip the
-        # guard entirely (same semantics as the sequential path)
+        # as warmed-nothing, so a diff against the analysis report
+        # shows its whole program set as growth (same semantics as
+        # the sequential path)
         path = write_warm_state(
             [r for r in reports if "config" in r], args.state)
         print(f"# warm state -> {path}", file=sys.stderr)
@@ -116,12 +113,15 @@ def _parallel(names: List[str], args) -> int:
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = parse_args(argv)
+    if args.jobs > 1 and not args.cpu:
+        print("error: --jobs > 1 needs --cpu: an accelerator belongs "
+              "to one process, so parallel children could never all "
+              "reach it", file=sys.stderr)
+        return 2
     if args.cpu:
         # before any backend init; children inherit the env too.  The
         # 8-virtual-device flag must land before CPU-client init or
-        # the multi-device rigs (gin_flat8 parts=2) are SILENTLY
-        # skipped and never warmed — the exact masked cold-compile
-        # the warm state exists to surface
+        # the multi-device rigs (gin_flat8 parts=2) cannot be hosted
         from .analysis import force_cpu_rig
         force_cpu_rig()
     from .analysis.programspace import rig_configs
@@ -136,29 +136,26 @@ def main(argv: Optional[List[str]] = None) -> int:
         return _parallel(names, args)
 
     from .utils.prewarm import prewarm_config, write_warm_state
-    reports = []
+    reports, rc = [], 0
     for name in names:
-        rep = prewarm_config(name, cache_dir=args.cache_dir,
-                             verbose=args.verbose)
-        if rep is not None:
-            reports.append(rep)
-            print(json.dumps({k: v for k, v in rep.items()
-                              if k != "slots"}))
-        else:
-            print(f"# prewarm {name}: skipped — backend cannot host "
-                  f"the rig mesh (with --cpu the 8-virtual-device "
-                  f"flag is set automatically)", file=sys.stderr)
+        try:
+            rep = prewarm_config(name, cache_dir=args.cache_dir,
+                                 verbose=args.verbose)
+        except ValueError as e:     # the backend cannot host the rig
+            print(f"error: {e}", file=sys.stderr)
+            rc = 1
+            continue
+        reports.append(rep)
+        print(json.dumps({k: v for k, v in rep.items()
+                          if k != "slots"}))
     if reports and not args.no_state:
         path = write_warm_state(reports, args.state)
         print(f"# warm state -> {path}", file=sys.stderr)
-    # a failed candidate was NOT warmed, and an unavailable cache dir
-    # means NOTHING was warmed (keys withheld either way, so the
-    # preflight sees growth) — surface both in the exit code so
-    # round6_chain.sh step 0 can't report success over them
-    if any(r.get("failed") or r.get("cache_unavailable")
-           for r in reports):
-        return 1
-    return 0
+    # a failed candidate was NOT warmed (its key is withheld): surface
+    # it in the exit code
+    if any(r.get("failed") for r in reports):
+        rc = 1
+    return rc
 
 
 if __name__ == "__main__":

@@ -56,7 +56,7 @@ DEFAULT_FLUSH_TIMEOUT_S = 600.0
 # out-of-band override for the flush/drain deadline (the saver_stall
 # drill pins it low WITHOUT arming the global heartbeat deadline)
 ENV_FLUSH_TIMEOUT = "ROC_TPU_CKPT_FLUSH_TIMEOUT_S"
-# keep the last few completed-save stat records (stats() / bench)
+# keep the last few completed-save stat records (stats())
 _STATS_KEEP = 8
 
 
@@ -247,8 +247,7 @@ class AsyncSaver:
     # ------------------------------------------------------ inspection
 
     def stats(self) -> Dict[str, Any]:
-        """Saver counters + the recent completed-save records (the
-        bench `ckpt_*` headline fields read these)."""
+        """Saver counters + the recent completed-save records."""
         with self._cond:
             return {"saved": self._saved,
                     "superseded": self._superseded,

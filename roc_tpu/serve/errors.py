@@ -9,7 +9,7 @@ failures onto the same vocabulary so one `except ServeError` covers a
 single-process `Server` and a replicated `Router` alike.
 
 Import-light on purpose (no jax, no numpy): the router's client side
-and the sentinel-adjacent accounting import these without a backend.
+imports these without a backend.
 """
 
 from __future__ import annotations

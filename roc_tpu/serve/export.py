@@ -12,8 +12,8 @@ off the request path:
    ``StagingPool`` machinery, so >HBM graphs export the way they
    train);
 3. AOT-compile every bucketed serve program into the persistent
-   compile cache (``utils/prewarm.warm_candidates`` — the same
-   warm-vs-cold accounting the bench children record) and assert
+   compile cache (``utils/prewarm.warm_candidates``, with its
+   warm-vs-cold accounting) and assert
    warm-hit parity with a second pass;
 4. write ``serve_manifest.json`` — program keys, quantized buckets,
    the resolved model op list (``Model.to_spec``), and the model
@@ -385,7 +385,7 @@ def export_predictor(pred: Predictor, out_dir: str,
             "rows_padded": int(slices[0].rows_padded),
             "halo": int(slices[0].halo),
             "files": files,
-            # the capacity math the fleet view / sentinel column reads:
+            # the capacity math the fleet view reads:
             # per-replica bytes are O(V/N) + halo, vs O(V) full
             "bytes_per_replica": int(table_bytes(
                 (slices[0].rows_padded + slices[0].halo + 1, F),
@@ -395,8 +395,7 @@ def export_predictor(pred: Predictor, out_dir: str,
             "program_keys": spred.program_keys(),
             "prewarm": {k: swarm.get(k) for k in
                         ("programs", "compile_warm_hits",
-                         "compile_cold", "failed", "prewarm_s",
-                         "cache_unavailable")},
+                         "compile_cold", "failed", "prewarm_s")},
         }
     cfg = pred.config
     manifest: Dict[str, Any] = {
@@ -436,14 +435,13 @@ def export_predictor(pred: Predictor, out_dir: str,
     warm = pred.warm(cache_dir=cache_dir, name="serve_export")
     manifest["prewarm"] = {k: warm.get(k) for k in
                           ("programs", "compile_warm_hits",
-                           "compile_cold", "failed", "prewarm_s",
-                           "cache_unavailable")}
+                           "compile_cold", "failed", "prewarm_s")}
     if warm.get("failed"):
         raise RuntimeError(
             f"serve export: {warm['failed']} program(s) failed to "
             f"AOT-compile — the artifact would cold-compile at first "
             f"query; see the compile events")
-    if verify_warm and not warm.get("cache_unavailable"):
+    if verify_warm:
         check = pred.warm(cache_dir=cache_dir, name="serve_verify")
         manifest["prewarm"]["verified_warm_hits"] = \
             check.get("compile_warm_hits")
@@ -677,7 +675,8 @@ def parse_args(argv: Optional[List[str]] = None):
                          "table bytes")
     ap.add_argument("--cache-dir", default=None,
                     help="persistent compile cache dir (default: "
-                         "$ROC_TPU_CACHE_DIR or ~/.cache/roc_tpu/xla)")
+                         "$JAX_COMPILATION_CACHE_DIR, which also wins "
+                         "over this flag, else <repo>/.jax_cache)")
     ap.add_argument("--no-verify-warm", action="store_true",
                     help="skip the second AOT pass that asserts "
                          "warm-hit parity")

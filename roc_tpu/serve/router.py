@@ -15,8 +15,8 @@ properties one process cannot have:
   full-range replicas that degenerates to pure least-loaded.
 - **health + failover** — liveness rides the replica heartbeat lines
   (the ``obs`` heartbeat cadence, ``ROC_TPU_SERVE_HB_S``); a silent
-  replica leaves a dated ``stall`` event exactly like a wedged bench
-  stage.  When a replica dies (EOF/exit — the ``replica_sigkill``
+  replica leaves a dated ``stall`` event exactly like a stalled
+  first compile.  When a replica dies (EOF/exit — the ``replica_sigkill``
   drill), its in-flight requests are requeued onto survivors and the
   failover lands as a timeline marker (``serve`` event,
   kind=``failover``).
@@ -186,6 +186,20 @@ class Router:
                  gather_rider_cap: int = 8):
         if n_replicas < 1:
             raise ValueError("need at least one replica")
+        child_platforms = (env if env is not None
+                           else os.environ).get("JAX_PLATFORMS", "")
+        if n_replicas > 1 and not cpu and child_platforms != "cpu":
+            # decided from the arguments alone: this parent must not
+            # touch a jax backend to find out (it would take the chip
+            # from its own first replica)
+            raise ValueError(
+                f"n_replicas={n_replicas} without cpu=True: every "
+                f"replica process initialises the default accelerator "
+                f"backend, and a chip belongs to one process — on one "
+                f"chip the second replica can never come up, on a "
+                f"multi-chip host the first takes every chip.  Pass "
+                f"cpu=True or n_replicas=1 (a device per replica is "
+                f"ROADMAP D6/R8)")
         if shards is not None and len(shards) != n_replicas:
             raise ValueError("one shard range per replica")
         self._sharded = bool(sharded)
@@ -296,7 +310,7 @@ class Router:
         cmd += self._replica_args
         child_env = dict(env) if env is not None else os.environ.copy()
         # `-m roc_tpu.serve.replica` must resolve regardless of the
-        # caller's cwd (a bench child runs from an arbitrary dir):
+        # caller's cwd (a benchmark runs from an arbitrary dir):
         # the package's parent dir rides PYTHONPATH
         pkg_root = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
@@ -362,7 +376,7 @@ class Router:
                                           deadline - time.monotonic()))
             except subprocess.TimeoutExpired:
                 # a wedged replica (the replica_stall drill) cannot
-                # drain — escalate the way bench does: TERM, then KILL
+                # drain — escalate: TERM, then KILL
                 rep.proc.terminate()
                 try:
                     rep.proc.wait(timeout=5.0)
@@ -861,7 +875,7 @@ class Router:
                         rep.silent_noted = True
                         age = now - rep.last_hb
                 if silent:
-                    # same evidence trail as a wedged bench stage
+                    # same evidence trail as a stalled trainer
                     emit("stall", f"replica {rep.idx} heartbeat "
                          f"silent for {age:.1f}s",
                          stage=f"serve_replica{rep.idx}",

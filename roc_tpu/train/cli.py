@@ -20,7 +20,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
-from typing import List, Optional
+from typing import Any, Callable, List, Optional
 
 
 def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
@@ -173,9 +173,9 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
                          "not persisted.  Default: "
                          "$ROC_TPU_CACHE_MIN_SECS or 1.0; pass 0 to "
                          "persist every program (what `python -m "
-                         "roc_tpu.prewarm` and the bench children do "
-                         "— the 1.0 s default silently skips the "
-                         "small per-block streamed-head programs)")
+                         "roc_tpu.prewarm` does — the 1.0 s default "
+                         "silently skips the small per-block "
+                         "streamed-head programs)")
     ap.add_argument("--eval-every", type=int, default=5)
     ap.add_argument("--checkpoint", type=str, default=None,
                     help="save params+opt state here after training")
@@ -276,7 +276,13 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     return ap.parse_args(argv)
 
 
-def main(argv: Optional[List[str]] = None) -> int:
+def main(argv: Optional[List[str]] = None,
+         inspect: Optional[Callable[[Any], None]] = None) -> int:
+    """The ``roc-tpu-train`` entry point.  ``inspect``, when given, is
+    called with the live trainer once the run's work is done and
+    before it is dropped — how an in-process caller (chip_smoke.py)
+    checks placement and timing on the objects the run actually
+    used."""
     args = parse_args(argv)
     from ..obs.events import emit, install_excepthook
     # crash flight recorder: an unhandled exception dumps the last
@@ -534,6 +540,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         m = trainer.evaluate()
         print(format_metrics(trainer.epoch, m))
         save_logits()
+        if inspect is not None:
+            inspect(trainer)
         return 0
 
     if args.profile_dir:
@@ -624,6 +632,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         emit("run", f"checkpoint saved to {args.checkpoint}",
              path=args.checkpoint)
     save_logits()
+    if inspect is not None:
+        inspect(trainer)
     return 0
 
 

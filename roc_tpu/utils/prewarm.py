@@ -6,10 +6,9 @@ enumerates the EXACT compiled-program set of a config — the same
 ``candidate_programs`` extraction here drives each candidate through
 the AOT path (``jit.lower(*args).compile()``) against the persistent
 compile cache (``utils/compile_cache.py``), so every later process
-that builds the same trainer starts warm: rebalance, resume, serving,
-and the bench probe all skip the first-compile stall that burned every
-r01-r05 probe timeout.  Compile-only — nothing executes on the device,
-so a prewarm is safe to run while a chip claim is precious.
+that builds the same trainer starts warm: rebalance, resume and
+serving all skip the first-compile stall.  Compile-only — nothing
+executes on the device.
 
 Warm-vs-cold accounting is file-based: a candidate whose AOT compile
 leaves NO new entry in the cache directory was served from the cache
@@ -23,14 +22,12 @@ Entry points:
 - :func:`prewarm_config` — warm one registered rig config (the
   auditor's exact enumeration; ``python -m roc_tpu.prewarm`` drives
   this, one process per config with ``--jobs``).
-- :func:`warm_trainer` — warm a LIVE trainer's candidate programs
-  (the bench children call this before their timed phase and record
-  ``compile_warm_hits`` / ``compile_cold`` in the stage result).
+- :func:`warm_candidates` — warm any candidate set (the serve export
+  warms its bucket programs through it).
 - :func:`write_warm_state` / :func:`load_warm_state` — the cached
-  warm-state artifact (program-key sets per config) the bench probe
-  preflight diffs against ``python -m roc_tpu.analysis --json`` so a
-  probe refuses to burn chip deadline on a config whose program set
-  grew since the cache was warmed.
+  warm-state artifact (program-key sets per config), diffable against
+  ``python -m roc_tpu.analysis --json`` to see whether a config's
+  program set grew since the cache was warmed.
 """
 
 from __future__ import annotations
@@ -47,10 +44,8 @@ WARM_STATE_NAME = "programspace_warm.json"
 
 
 def warm_state_path(path: Optional[str] = None) -> str:
-    """The warm-state artifact location: explicit > the bench
-    artifacts dir (ROC_TPU_BENCH_ARTIFACTS) > the repo's
-    ``benchmarks/`` — the same resolution bench.py uses, so the
-    prewarm writer and the probe preflight reader agree."""
+    """The warm-state artifact location: explicit > the artifacts
+    dir (ROC_TPU_BENCH_ARTIFACTS) > the repo's ``benchmarks/``."""
     if path:
         return path
     art = os.environ.get("ROC_TPU_BENCH_ARTIFACTS") or os.path.join(
@@ -61,8 +56,7 @@ def warm_state_path(path: Optional[str] = None) -> str:
 
 def load_warm_state(path: Optional[str] = None) -> Dict[str, Any]:
     """{config: {"programs": n, "keys": [...], "t": iso}} recorded at
-    the last prewarm; missing/corrupt file = no cached warm state
-    (the preflight then has nothing to guard against)."""
+    the last prewarm; missing/corrupt file = no cached warm state."""
     try:
         with open(warm_state_path(path)) as f:
             db = json.load(f)
@@ -92,19 +86,11 @@ def write_warm_state(reports: List[Dict[str, Any]],
     return p
 
 
-def _cache_entries(cache_dir: Optional[str]) -> set:
-    if not cache_dir:
-        return set()
-    try:
-        return set(os.listdir(cache_dir))
-    except OSError:
-        return set()
-
-
-def warm_candidates(cands, cache_dir: Optional[str],
+def warm_candidates(cands, cache_dir: str,
                     config: str = "trainer",
                     verbose: bool = False) -> Dict[str, Any]:
-    """AOT-compile every candidate against the persistent cache.
+    """AOT-compile every candidate against the persistent cache at
+    ``cache_dir`` (the directory ``enable_compile_cache`` returned).
     A candidate whose compile raises is recorded and skipped — a
     corrupt/stale cache entry must degrade to a live compile later,
     never crash the warmer (the cache is an optimization).  Failed
@@ -113,26 +99,14 @@ def warm_candidates(cands, cache_dir: Optional[str],
     attribution is listdir-diff based and exact for a single warmer
     per cache dir; concurrent warmers (``--jobs`` > 1) make it
     best-effort — a sibling's write inside this candidate's window
-    counts as cold here (the key sets, the preflight's real guard,
-    stay exact).  ``cache_dir=None`` (enable_compile_cache could not
-    create the directory — read-only HOME, sandboxed CI) persists
-    NOTHING: every compile counts cold, no keys are recorded (the
-    next process really does start cold, so the warm state must not
-    claim otherwise), and the report carries ``cache_unavailable``
-    so the CLI can fail loudly instead of reporting all-warm."""
+    counts as cold here (the key sets stay exact)."""
     from ..obs.compile_watch import program_key_of
-    cache_ok = bool(cache_dir) and os.path.isdir(cache_dir)
-    if not cache_ok:
-        emit("compile", f"prewarm {config}: persistent cache "
-             f"UNAVAILABLE (dir={cache_dir!r}) — compiles will not "
-             f"persist, nothing is warmed for later processes",
-             console=True, prewarm=config, cache_unavailable=True)
     warm = cold = failed = 0
     t_start = time.perf_counter()
     slots: List[Dict[str, Any]] = []
     keys: List[str] = []
     for c in cands:
-        before = _cache_entries(cache_dir)
+        before = set(os.listdir(cache_dir))
         t0 = time.perf_counter()
         try:
             c.aot()
@@ -142,15 +116,12 @@ def warm_candidates(cands, cache_dir: Optional[str],
                  f"{type(e).__name__}: {e}", console=verbose,
                  prewarm=config, slot=c.slot, error=str(e)[:200])
             continue
-        # key recorded only AFTER a successful compile landed in a
-        # USABLE cache: a failed (or unpersisted) candidate must show
-        # up as GROWTH in the preflight diff (the probe would pay its
-        # cold compile), not be masked as already-warm
-        if cache_ok:
-            keys.append(program_key_of(c.slot, c.args, c.donate))
+        # key recorded only AFTER a successful compile: a failed
+        # candidate must show up as GROWTH against the warm state,
+        # not be masked as already-warm
+        keys.append(program_key_of(c.slot, c.args, c.donate))
         dt = time.perf_counter() - t0
-        new = _cache_entries(cache_dir) - before
-        is_cold = bool(new) or not cache_ok
+        is_cold = bool(set(os.listdir(cache_dir)) - before)
         cold += is_cold
         warm += not is_cold
         slots.append({"slot": c.slot, "compile_s": round(dt, 3),
@@ -164,8 +135,6 @@ def warm_candidates(cands, cache_dir: Optional[str],
            "failed": failed,
            "prewarm_s": round(time.perf_counter() - t_start, 2),
            "cache_dir": cache_dir, "slots": slots, "keys": keys}
-    if not cache_ok:
-        out["cache_unavailable"] = True
     emit("compile", f"prewarm {config}: {out['programs']} programs, "
          f"{warm} warm / {cold} cold"
          + (f" / {failed} failed" if failed else "")
@@ -177,28 +146,16 @@ def warm_candidates(cands, cache_dir: Optional[str],
     return out
 
 
-def warm_trainer(tr, cache_dir: Optional[str] = None,
-                 name: str = "trainer",
-                 verbose: bool = False) -> Dict[str, Any]:
-    """Pre-pay a LIVE trainer's whole program set (the bench children
-    call this before their timed phase).  Enables the cache at
-    min_compile_secs=0.0 — prewarm is driving, so even sub-second
-    programs must persist (the 1.0 s default silently skipped the
-    small per-block streamed-head programs)."""
-    from ..analysis.programspace import candidate_programs
-    d = enable_compile_cache(cache_dir, min_compile_secs=0.0)
-    return warm_candidates(candidate_programs(tr), d, config=name,
-                           verbose=verbose)
-
-
 def prewarm_config(name: str, dataset=None,
                    cache_dir: Optional[str] = None,
-                   verbose: bool = False) -> Optional[Dict[str, Any]]:
+                   verbose: bool = False) -> Dict[str, Any]:
     """Warm one registered rig config against the persistent cache:
     builds the rig trainer (tables only — nothing compiles eagerly)
     and AOT-compiles the auditor's exact candidate set.  Returns the
     warm report (with the enumerated ``keys`` for the warm-state
-    artifact), or None when the backend cannot host the rig's mesh."""
+    artifact); raises ``ValueError`` when the backend has fewer
+    devices than the rig's mesh needs — a config that was asked for
+    and not warmed is a failure, not a skip."""
     import jax
 
     from ..analysis.programspace import (build_rig_dataset,
@@ -209,10 +166,10 @@ def prewarm_config(name: str, dataset=None,
     spec = rig_configs()[name]
     needed = rig_required_devices(spec)
     if needed > len(jax.devices()):
-        emit("compile", f"prewarm {name}: skipped (needs "
-             f"{needed} devices, have {len(jax.devices())})",
-             console=verbose, prewarm=name, skipped=True)
-        return None
+        raise ValueError(
+            f"prewarm {name}: needs {needed} devices, the "
+            f"{jax.default_backend()} backend has {len(jax.devices())} "
+            f"(--cpu gives the 8-virtual-device rig)")
     d = enable_compile_cache(cache_dir, min_compile_secs=0.0)
     ds = dataset if dataset is not None else build_rig_dataset()
     tr = build_rig_trainer(spec, ds)

@@ -45,11 +45,6 @@ python -m roc_tpu.analysis --select protocol --json \
 # it grew) — a PR that adds a compiled-program shape shows it before
 # the test tier starts.
 python -m roc_tpu.analysis --strict
-# perf-regression sentinel preflight: median+MAD gate over the
-# checked-in BENCH_*.json trajectory (roc_tpu/obs/sentinel.py) — a
-# round that regressed step/compile time beyond noise fails HERE,
-# before chip time is spent (set -e makes the nonzero exit fatal)
-python -m roc_tpu.sentinel --json
 # serving SLO smoke preflight (PR 17): export a predictor artifact,
 # cold-load it in subprocess replicas, drive a 100-query load gen
 # with the declared availability/latency objectives armed, and

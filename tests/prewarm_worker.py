@@ -8,7 +8,7 @@ programs: its ``compile`` events' program_key set equals the auditor's
 enumeration, and no new step-program entry appeared in the cache.
 
 Usage: python prewarm_worker.py <rig_name>
-Env:   ROC_TPU_CACHE_DIR (cache), ROC_TPU_EVENTS (events JSONL),
+Env:   JAX_COMPILATION_CACHE_DIR (cache), ROC_TPU_EVENTS (events JSONL),
        ROC_TPU_CACHE_MIN_SECS=0 (persist everything).
 """
 

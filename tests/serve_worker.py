@@ -10,7 +10,7 @@ ZERO new serve programs and that its compile events' program_key set
 matches the artifact manifest exactly.
 
 Usage: python serve_worker.py <artifact_dir>
-Env:   ROC_TPU_CACHE_DIR (cache), ROC_TPU_EVENTS (events JSONL),
+Env:   JAX_COMPILATION_CACHE_DIR (cache), ROC_TPU_EVENTS (events JSONL),
        ROC_TPU_CACHE_MIN_SECS=0 (persist everything).
 """
 

@@ -581,7 +581,7 @@ def test_artifact_lock_ownership_pragma_and_writer_fns(tmp_path):
            "def bad(tr):\n"
            "    checkpoint_trainer(tr, 'ck')\n"               # line 4
            "def vouched(tr):\n"
-           "    # one bench child per stage: "
+           "    # one writer process per prefix: "
            "roc-lint: ok=artifact-lock-ownership\n"
            "    checkpoint_trainer(tr, 'ck')\n")
     got = run_concurrency_lint(str(tmp_path),
@@ -600,7 +600,7 @@ def test_artifact_surface_inventories_real_tree():
             for m in surface["artifacts"]}
     assert any(a["kind"] == "rotation"
                and a["owner"] == "proc0-gate"
-               for a in arts.get("bench.py", [])), arts
+               for a in arts.get("roc_tpu/train/cli.py", [])), arts
     assert any(a["kind"] == "warm-state"
                and a["owner"] == "atomic-replace"
                for a in arts.get("roc_tpu/prewarm.py", []))
@@ -650,7 +650,7 @@ def test_surface_documents_the_runtime_thread_model():
     assert "roc_tpu/core/streaming.py" in by_mod       # StagingPool
     assert "roc_tpu/serve/server.py" in by_mod         # Server._loop
     assert "roc_tpu/obs/heartbeat.py" in by_mod        # watchdog
-    assert "bench.py" in by_mod                        # stderr reader
+    assert "roc_tpu/serve/router.py" in by_mod         # replica readers
     # the checkpoint saver thread (ISSUE 15) — the tree-clean pin
     # above already proves all six rules model it
     asv = by_mod["roc_tpu/resilience/async_save.py"]
@@ -713,7 +713,7 @@ def _run_cli(args, cwd=None):
 
 
 def test_cli_select_concurrency_alias_green_on_tree():
-    """`--select concurrency` (the test.sh / round6_chain preflight
+    """`--select concurrency` (the scripts/test.sh preflight
     line) expands to all six rules, runs jax-free fast, and exits 0
     on the tree with the surface in the --json payload."""
     r = _run_cli(["--select", "concurrency", "--json"])

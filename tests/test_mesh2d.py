@@ -110,3 +110,40 @@ def test_training_parity_ring_halo(dataset):
     two = _train(dataset, 2, "2x4", halo="ring")
     _assert_model_sharded_at_rest(two, 4)
     _assert_parity(ref, two)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_psum_halo_gather_matches_all_gather(dtype):
+    """The partial-auto halo gather (psum of placed blocks; 16-bit
+    floats as uint16 bit patterns) equals lax.all_gather in value AND
+    in its vjp — the bitcast has no derivative of its own, and a bf16
+    all-reduce under a partial-auto axis aborts XLA:CPU outright."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from roc_tpu.parallel import PARTS_AXIS
+    from roc_tpu.parallel.distributed import _gather_by_psum, _shard_map
+    mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4),
+                (PARTS_AXIS, MODEL_AXIS))
+    x = jnp.asarray(np.random.RandomState(0).randn(2, 16, 8), dtype)
+    ct = jnp.asarray(np.random.RandomState(1).randn(32, 8), dtype)
+    pids = jnp.arange(2, dtype=jnp.int32)
+
+    def run(gather, axis_names):
+        def body(x, ct, pids):
+            full, vjp = jax.vjp(lambda b: gather(b, pids[0]), x[0])
+            return full, vjp(ct)[0][None]
+        f = _shard_map(body, mesh,
+                       (P(PARTS_AXIS), P(), P(PARTS_AXIS)),
+                       (P(), P(PARTS_AXIS)), axis_names=axis_names)
+        return [np.asarray(a, np.float32) for a in jax.jit(f)(x, ct, pids)]
+
+    ref = run(lambda b, _: lax.all_gather(
+        b, PARTS_AXIS, axis=0, tiled=True), frozenset())
+    got = run(lambda b, pid: _gather_by_psum(b, pid, 2),
+              frozenset({PARTS_AXIS}))
+    np.testing.assert_array_equal(got[0], np.asarray(
+        x, np.float32).reshape(32, 8))
+    np.testing.assert_array_equal(got[0], ref[0])
+    np.testing.assert_array_equal(got[1], ref[1])

@@ -236,3 +236,22 @@ def test_block_plan_rectangular_native_matches_numpy():
                  (pn.res_col, pp.res_col)):
         np.testing.assert_array_equal(a, b)
     assert pn.dense_edges == pp.dense_edges
+
+
+def test_unavailable_library_is_reported_not_silent(tmp_path):
+    """A library that cannot load still falls back to numpy — but says
+    why, once on stderr and in ``status()`` (every run manifest carries
+    it), because it decides what aggr_impl='auto' can choose."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, ROC_TPU_NATIVE=str(tmp_path / "absent.so"),
+               PYTHONPATH=repo, JAX_PLATFORMS="cpu")
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "from roc_tpu import native; s = native.status(); "
+         "assert not native.available(); "
+         "assert s['loaded'] is False and 'absent.so' in s['reason'], s"],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    assert r.stderr.count("native librocio.so unavailable") == 1

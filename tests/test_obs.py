@@ -205,6 +205,9 @@ def test_peak_flops_table():
     assert peak_flops_per_s("TPU v5 lite") == 197e12
     assert peak_flops_per_s("TPU v4") == 275e12
     assert peak_flops_per_s("cpu") is None
+    assert peak_flops_per_s() is None          # the CPU rig itself
+    with pytest.raises(ValueError, match="TPU v9"):
+        peak_flops_per_s("TPU v9")
 
 
 # --------------------------------------------- end-to-end through CLI
@@ -253,47 +256,6 @@ def test_cli_events_jsonl_and_report(tmp_path):
     for needle in ("run manifest", "compile", "train_step",
                    "phase spans", "edges_per_s"):
         assert needle in r.stdout, (needle, r.stdout)
-
-
-# --------------------------------------------------- bench heartbeats
-
-def test_bench_slow_stage_emits_heartbeat_before_timeout(
-        tmp_path, monkeypatch):
-    """A forced-slow bench stage must leave stall events (parent-side
-    'bench:<stage>' heartbeats) before its timeout — never again a
-    blank 'timeout after Ns' with zero evidence."""
-    sys.path.insert(0, _REPO)
-    import bench
-    from roc_tpu.obs.events import configure
-    ev = str(tmp_path / "events.jsonl")
-    monkeypatch.setenv("ROC_TPU_BENCH_ARTIFACTS", str(tmp_path))
-    monkeypatch.setenv("ROC_TPU_HEARTBEAT_S", "0.5")
-    # a FRESH compile-cache dir: a warm persistent cache (left by any
-    # earlier bench/test run in this container) lets the child finish
-    # inside the 2 s budget on a fast box, voiding the forced-slow
-    # premise — the stage must pay its cold compile here
-    monkeypatch.setenv("ROC_TPU_CACHE_DIR", str(tmp_path / "cache"))
-    monkeypatch.setattr(bench, "_ART_DIR", str(tmp_path))
-    monkeypatch.setattr(bench, "_STAGES_PATH",
-                        str(tmp_path / "bench_stages.jsonl"))
-    try:
-        configure(jsonl_path=ev, console=False)
-        # 'full' at CPU with a 2 s timeout: the child cannot even
-        # finish importing jax — a guaranteed slow stage
-        rec = bench._run_stage(
-            "full", 2.0,
-            ["--cpu", "--nodes", "4096", "--edges", "32768",
-             "--epochs", "1"], grace=5.0)
-    finally:
-        configure(jsonl_path=None)
-    assert not rec.get("ok")
-    assert "timeout" in rec.get("error", "")
-    assert rec.get("heartbeats", 0) >= 1
-    stalls = [json.loads(line) for line in open(ev)
-              if json.loads(line).get("cat") == "stall"]
-    assert stalls
-    assert stalls[0]["stage"] == "bench:full"
-    assert "still waiting in bench:full" in stalls[0]["msg"]
 
 
 # ------------------------------------------------------- lint ratchet

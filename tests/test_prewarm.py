@@ -2,9 +2,8 @@
 roc_tpu.prewarm` completes on CPU inside the CI budget, a warm second
 process records ZERO new-program compile events (program_key set
 equality against the auditor's enumeration AND no new step-program
-cache entries) on both rig configs, a deliberately-stale cache
-degrades gracefully (compile live, no crash), and the bench probe's
-programspace preflight refuses growth against the cached warm state.
+cache entries) on both rig configs, and a deliberately-stale cache
+degrades gracefully (compile live, no crash).
 """
 
 import json
@@ -36,7 +35,7 @@ def _env(cache_dir, events=None):
         env["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8"
         ).strip()
-    env["ROC_TPU_CACHE_DIR"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env["ROC_TPU_CACHE_MIN_SECS"] = "0"
     if events:
         env["ROC_TPU_EVENTS"] = events
@@ -155,34 +154,22 @@ def test_stale_cache_degrades_gracefully(warmed):
     assert "WORKER_OK" in r.stdout
 
 
-def test_bench_preflight_refuses_growth(tmp_path, monkeypatch):
-    """bench.py's programspace preflight: no warm state = no guard;
-    unchanged key sets pass; a config whose program set GREW since
-    the cached warm state is refused (the diff logic — the CLI
-    enumeration itself is covered by test_programspace)."""
-    import bench
-    art = tmp_path / "art"
-    art.mkdir()
-    monkeypatch.setattr(bench, "_ART_DIR", str(art))
-    payload = {"program_space": [
-        {"config": "gin_flat8", "keys": ["a", "b", "c"]}]}
+def test_jobs_without_cpu_is_refused():
+    """An accelerator belongs to one process: parallel children are a
+    CPU-only mode, refused up front instead of failing backend init
+    one child at a time."""
+    from roc_tpu import prewarm
+    assert prewarm.main(["--jobs", "2"]) == 2
 
-    class _R:
-        stdout = json.dumps(payload)
 
-    monkeypatch.setattr(bench.subprocess, "run",
-                        lambda *a, **k: _R())
-    # no warm state: nothing to guard
-    assert bench._programspace_preflight() is None
-    # unchanged: empty growth
-    (art / "programspace_warm.json").write_text(json.dumps(
-        {"gin_flat8": {"keys": ["a", "b", "c"], "programs": 3}}))
-    assert bench._programspace_preflight() == {}
-    # grown: one new key
-    (art / "programspace_warm.json").write_text(json.dumps(
-        {"gin_flat8": {"keys": ["a", "b"], "programs": 2}}))
-    assert bench._programspace_preflight() == {"gin_flat8": 1}
-    # a SHRUNK set is not growth (ratchet direction is free)
-    (art / "programspace_warm.json").write_text(json.dumps(
-        {"gin_flat8": {"keys": ["a", "b", "c", "d"], "programs": 4}}))
-    assert bench._programspace_preflight() == {}
+def test_rig_the_backend_cannot_host_fails(tmp_path):
+    """A rig that needs more devices than the backend has is an
+    error in the exit code, not a skip that exits 0."""
+    env = _env(str(tmp_path / "cache"))
+    env["XLA_FLAGS"] = ""           # one CPU device: parts=2 cannot fit
+    r = subprocess.run(
+        [sys.executable, "-m", "roc_tpu.prewarm", "--config",
+         "gin_flat8", "--no-state"],
+        capture_output=True, text=True, timeout=120, env=env, cwd=_REPO)
+    assert r.returncode == 1, r.stdout + r.stderr
+    assert "needs 2 devices" in r.stderr

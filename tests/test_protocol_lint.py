@@ -519,7 +519,7 @@ def _run_cli(args, cwd=None):
 
 
 def test_cli_select_protocol_alias_green_on_tree():
-    """`--select protocol` (the test.sh / round6_chain preflight
+    """`--select protocol` (the scripts/test.sh preflight
     line) expands to all five rules, runs jax-free fast, exits 0 on
     the tree, and the --json payload carries the surface with all
     three models explored to completion."""

@@ -177,7 +177,7 @@ def test_cold_server_zero_new_compiles_int8(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
-    env["ROC_TPU_CACHE_DIR"] = cache
+    env["JAX_COMPILATION_CACHE_DIR"] = cache
     env["ROC_TPU_CACHE_MIN_SECS"] = "0"
     events = str(tmp_path / "events.jsonl")
     env["ROC_TPU_EVENTS"] = events

@@ -304,7 +304,7 @@ def artifact(tmp_path_factory):
     d = tmp_path_factory.mktemp("serve_art")
     cache = str(d / "cache")
     os.makedirs(cache)
-    os.environ["ROC_TPU_CACHE_DIR"] = cache
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache
     os.environ["ROC_TPU_CACHE_MIN_SECS"] = "0"
     ds = _dataset()
     pred = build_predictor(_sgc_model(), ds, _config(),
@@ -315,7 +315,7 @@ def artifact(tmp_path_factory):
                                    "E": int(ds.graph.num_edges)})
     ref = pred.query(np.arange(ds.graph.num_nodes))
     yield art, ref, ds
-    os.environ.pop("ROC_TPU_CACHE_DIR", None)
+    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
 
 def _router_env(fault=None):
@@ -492,3 +492,17 @@ def test_replica_drains_gracefully_on_sigterm(artifact):
             assert np.abs(np.asarray(rows) - ref[[i]]).max() <= 1e-5
         stats = router.stats()
     assert sum(1 for r in stats["replicas"] if r["alive"]) == 1
+
+
+def test_router_refuses_accelerator_fleet_up_front(tmp_path):
+    """N > 1 replica processes on the default accelerator backend can
+    never all come up (a chip belongs to one process): the constructor
+    says so at once — before spawning anything, before the 180 s ready
+    wait — from its arguments alone, without touching a backend."""
+    from roc_tpu.serve.router import Router
+    t0 = time.monotonic()
+    with pytest.raises(ValueError, match="chip belongs to one process"):
+        Router(str(tmp_path), n_replicas=2, env={"JAX_PLATFORMS": ""})
+    with pytest.raises(ValueError, match="chip belongs to one process"):
+        Router(str(tmp_path), n_replicas=4, env={})
+    assert time.monotonic() - t0 < 1.0

@@ -339,7 +339,7 @@ def artifact(tmp_path_factory):
     d = tmp_path_factory.mktemp("slo_art")
     cache = str(d / "cache")
     os.makedirs(cache)
-    os.environ["ROC_TPU_CACHE_DIR"] = cache
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache
     os.environ["ROC_TPU_CACHE_MIN_SECS"] = "0"
     ds = synthetic_dataset(num_nodes=300, avg_degree=6, in_dim=24,
                            num_classes=5, seed=0)
@@ -352,7 +352,7 @@ def artifact(tmp_path_factory):
                      dataset_meta={"V": ds.graph.num_nodes,
                                    "E": int(ds.graph.num_edges)})
     yield art, ds
-    os.environ.pop("ROC_TPU_CACHE_DIR", None)
+    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
 
 def test_slo_spike_breach_recovery_e2e(artifact, tmp_path,
