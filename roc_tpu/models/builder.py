@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 import numpy as np
 
+from ..obs.scopes import HALO_SCOPE, LOSS_SCOPE, op_scope
 from ..ops import dense
 from ..parallel import PARTS_AXIS
 from ..ops.aggregate import (aggregate, aggregate_ell, aggregate_ell_max,
@@ -166,9 +167,11 @@ class GraphContext:
     axis_name: str = PARTS_AXIS
 
     def _gathered_with_zero(self, x: jax.Array) -> jax.Array:
-        """Halo exchange + the appended dummy zero source row that
+        """Halo exchange (under its own ``roc.halo`` scope, inside the
+        aggregation's) + the appended dummy zero source row that
         padding table entries point at."""
-        full = self.gather_features(x)
+        with jax.named_scope(HALO_SCOPE):
+            full = self.gather_features(x)
         zero = jnp.zeros((1, full.shape[1]), dtype=full.dtype)
         return jnp.concatenate([full, zero], axis=0)
 
@@ -391,9 +394,7 @@ class GraphContext:
             a_src = a_src[None, :]
             a_dst = a_dst[None, :]
         K, dh = a_src.shape
-        full = self.gather_features(x)
-        zero = jnp.zeros((1, full.shape[1]), dtype=full.dtype)
-        full = jnp.concatenate([full, zero], axis=0)
+        full = self._gathered_with_zero(x)
         fullr = full.reshape(full.shape[0], K, dh)
         s_full = jnp.einsum("gkd,kd->gk", fullr,
                             a_src.astype(full.dtype))   # [G+1, K]
@@ -419,9 +420,7 @@ class GraphContext:
             raise NotImplementedError(
                 "AGGR_MAX is not supported with halo='ring' (the ring "
                 "accumulator is additive); use halo='gather'")
-        full = self.gather_features(x)
-        zero = jnp.zeros((1, full.shape[1]), dtype=full.dtype)
-        full = jnp.concatenate([full, zero], axis=0)
+        full = self._gathered_with_zero(x)
         dummy = full.shape[0] - 1
         neg = jnp.asarray(-jnp.inf, dtype=full.dtype)
         if self.aggr_impl == "flat_sum":
@@ -952,73 +951,79 @@ class Model:
                         if op.kind == "linear"), default=-1)
         for i, op in enumerate(self._ops[1:], start=1):
             x = vals[op.inputs[0]] if op.inputs else None
-            if op.kind == "dropout":
-                if train and key is not None:
-                    sub = jax.random.fold_in(key, n_dropout)
+            # one scope per model op (obs/scopes.py): roc.agg.op<i> for
+            # the aggregating kinds, roc.dense.op<i>.<kind> for the rest.
+            # Entered here, outside the aggregations' custom_vjp, it is
+            # still on the name stack when JAX traces their backward
+            # (tests/test_scopes.py holds that)
+            with jax.named_scope(op_scope(i, op.kind)):
+                if op.kind == "dropout":
+                    if train and key is not None:
+                        sub = jax.random.fold_in(key, n_dropout)
+                    else:
+                        sub = None
+                    n_dropout += 1
+                    vals[i] = dense.dropout(x, op.attrs["rate"], sub, train)
+                elif op.kind == "linear":
+                    if gctx.head_chunk and i == head_idx \
+                            and x.shape[0] > gctx.head_chunk:
+                        # the classification head, chunked on the vertex
+                        # axis: the compiled matmul is [head_chunk, C]
+                        # regardless of V_p, so the head subprogram stays
+                        # small and shape-stable (bit-identical values —
+                        # each output row's dot product is unchanged; dW
+                        # differs only in fp summation order)
+                        vals[i] = dense.linear_chunked(
+                            x, params[op.param], op.attrs["activation"],
+                            gctx.head_chunk)
+                    else:
+                        vals[i] = dense.linear(x, params[op.param],
+                                               op.attrs["activation"])
+                elif op.kind == "indegree_norm":
+                    vals[i] = indegree_norm(x, gctx.in_degree)
+                elif op.kind == "scatter_gather":
+                    # named so the remat policy can SAVE aggregation
+                    # outputs: recomputing the halo gather + CSR sum in
+                    # backward is the one thing worth activation memory
+                    # (train/trainer.py remat_policy="save_aggregates")
+                    vals[i] = checkpoint_name(
+                        gctx.aggregate(x, op.attrs["aggr"]), "aggregate")
+                elif op.kind == "fused_aggregate":
+                    # norm -> sum -> norm [-> relu] in one op (fuse_norm_
+                    # aggregate).  The activation sits OUTSIDE the
+                    # symmetric custom_vjp (relu is nonlinear) but inside
+                    # this op's fusion scope, so XLA folds it into the
+                    # aggregation epilogue.  Same checkpoint name as
+                    # scatter_gather: the remat policy saves fused
+                    # aggregations identically.
+                    y = checkpoint_name(gctx.aggregate_fused(x),
+                                        "aggregate")
+                    if op.attrs.get("activation",
+                                    AC_MODE_NONE) != AC_MODE_NONE:
+                        y = dense.activation(y, op.attrs["activation"])
+                    vals[i] = y
+                elif op.kind == "gat":
+                    vals[i] = checkpoint_name(
+                        gctx.gat_attention(
+                            x, params[f"{op.param}_src"],
+                            params[f"{op.param}_dst"],
+                            neg_slope=op.attrs["neg_slope"]), "aggregate")
+                elif op.kind == "activation":
+                    vals[i] = dense.activation(x, op.attrs["mode"])
+                elif op.kind == "add":
+                    vals[i] = vals[op.inputs[0]] + vals[op.inputs[1]]
+                elif op.kind == "scale_add":
+                    eps = params[op.param].astype(vals[op.inputs[0]].dtype)
+                    vals[i] = (vals[op.inputs[0]]
+                               + eps * vals[op.inputs[1]])
+                elif op.kind == "mul":
+                    vals[i] = vals[op.inputs[0]] * vals[op.inputs[1]]
+                elif op.kind == "lerp":
+                    al = op.attrs["alpha"]
+                    vals[i] = ((1.0 - al) * vals[op.inputs[0]]
+                               + al * vals[op.inputs[1]])
                 else:
-                    sub = None
-                n_dropout += 1
-                vals[i] = dense.dropout(x, op.attrs["rate"], sub, train)
-            elif op.kind == "linear":
-                if gctx.head_chunk and i == head_idx \
-                        and x.shape[0] > gctx.head_chunk:
-                    # the classification head, chunked on the vertex
-                    # axis: the compiled matmul is [head_chunk, C]
-                    # regardless of V_p, so the head subprogram stays
-                    # small and shape-stable (bit-identical values —
-                    # each output row's dot product is unchanged; dW
-                    # differs only in fp summation order)
-                    vals[i] = dense.linear_chunked(
-                        x, params[op.param], op.attrs["activation"],
-                        gctx.head_chunk)
-                else:
-                    vals[i] = dense.linear(x, params[op.param],
-                                           op.attrs["activation"])
-            elif op.kind == "indegree_norm":
-                vals[i] = indegree_norm(x, gctx.in_degree)
-            elif op.kind == "scatter_gather":
-                # named so the remat policy can SAVE aggregation
-                # outputs: recomputing the halo gather + CSR sum in
-                # backward is the one thing worth activation memory
-                # (train/trainer.py remat_policy="save_aggregates")
-                vals[i] = checkpoint_name(
-                    gctx.aggregate(x, op.attrs["aggr"]), "aggregate")
-            elif op.kind == "fused_aggregate":
-                # norm -> sum -> norm [-> relu] in one op (fuse_norm_
-                # aggregate).  The activation sits OUTSIDE the
-                # symmetric custom_vjp (relu is nonlinear) but inside
-                # this op's fusion scope, so XLA folds it into the
-                # aggregation epilogue.  Same checkpoint name as
-                # scatter_gather: the remat policy saves fused
-                # aggregations identically.
-                y = checkpoint_name(gctx.aggregate_fused(x),
-                                    "aggregate")
-                if op.attrs.get("activation",
-                                AC_MODE_NONE) != AC_MODE_NONE:
-                    y = dense.activation(y, op.attrs["activation"])
-                vals[i] = y
-            elif op.kind == "gat":
-                vals[i] = checkpoint_name(
-                    gctx.gat_attention(
-                        x, params[f"{op.param}_src"],
-                        params[f"{op.param}_dst"],
-                        neg_slope=op.attrs["neg_slope"]), "aggregate")
-            elif op.kind == "activation":
-                vals[i] = dense.activation(x, op.attrs["mode"])
-            elif op.kind == "add":
-                vals[i] = vals[op.inputs[0]] + vals[op.inputs[1]]
-            elif op.kind == "scale_add":
-                eps = params[op.param].astype(vals[op.inputs[0]].dtype)
-                vals[i] = (vals[op.inputs[0]]
-                           + eps * vals[op.inputs[1]])
-            elif op.kind == "mul":
-                vals[i] = vals[op.inputs[0]] * vals[op.inputs[1]]
-            elif op.kind == "lerp":
-                al = op.attrs["alpha"]
-                vals[i] = ((1.0 - al) * vals[op.inputs[0]]
-                           + al * vals[op.inputs[1]])
-            else:
-                raise ValueError(f"unknown op kind {op.kind}")
+                    raise ValueError(f"unknown op kind {op.kind}")
         out_idx = self._loss_op if self._loss_op is not None else -1
         return vals[out_idx]
 
@@ -1030,5 +1035,6 @@ class Model:
         gradient equals the reference's ``softmax - onehot`` on train rows
         (``softmax_kernel.cu:19-33``)."""
         logits = self.apply(params, feats, gctx, key=key, train=train)
-        loss = masked_softmax_cross_entropy(logits, labels, mask)
+        with jax.named_scope(LOSS_SCOPE):
+            loss = masked_softmax_cross_entropy(logits, labels, mask)
         return gctx.psum(loss), logits

@@ -27,6 +27,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 from .events import emit
 from .heartbeat import Heartbeat
+from .scopes import has_scopes, parse_program_text
 
 
 def leaf_struct(x) -> Tuple[str, Tuple[int, ...], str]:
@@ -131,6 +132,45 @@ def memory_summary(compiled) -> Dict[str, Optional[int]]:
     return out
 
 
+def compile_text_uncached(lowered) -> str:
+    """The optimized HLO text of ``lowered`` from a fresh XLA compile:
+    past JAX's in-memory and persistent caches, with the options
+    ``lowered.compile()`` would use, and WITHOUT loading the result
+    where the backend can compile alone (the TPU can; a loaded program
+    reserves its temporaries, and a second step program does not fit
+    beside a live Reddit-scale trainer).  Where it cannot (XLA:CPU has
+    no stand-alone compiler) the program is compiled and loaded.
+
+    There is no public way to ask for either, so this follows
+    ``jax._src.interpreters.pxla.UnloadedMeshExecutable.from_hlo`` down
+    to ``compiler.backend_compile`` (jax 0.9)."""
+    import jax
+    import numpy as np
+    from jax._src import compiler
+    from jax._src.interpreters import pxla
+    comp = lowered._lowering            # pxla.MeshComputation
+    args = comp.compile_args
+    devices = comp._device_list
+    ins, outs = (tuple(pxla.maybe_concretize_mesh(s, devices)
+                       for s in args[k])
+                 for k in ("in_shardings", "out_shardings"))
+    prop_in, prop_out = pxla.get_prop_to_input_output(
+        ins, outs, len(args["ordered_effects"]))
+    options = pxla.create_compile_options(
+        comp._hlo, None, args["spmd_lowering"], args["tuple_args"],
+        args["auto_spmd_lowering"], prop_in, prop_out, args["backend"],
+        np.array(list(devices), dtype=object), args["pmap_nreps"],
+        dict(comp._compiler_options_kvs))
+    try:
+        exe = compiler.backend_compile(args["backend"], comp._hlo,
+                                       devices, options)
+    except jax.errors.JaxRuntimeError:
+        exe = compiler.backend_compile_and_load(
+            args["backend"], comp._hlo, devices, options,
+            args["host_callbacks"])
+    return exe.hlo_modules()[0].to_string()
+
+
 # Per-chip peak dense FLOP/s (bf16 MXU path — the precision the
 # production configs run), keyed by device_kind substring.  MFU is a
 # *style* of utilization number: a coarse, stable denominator for
@@ -211,6 +251,7 @@ class ObservedJit:
         self.verbose = verbose
         self.cost: Optional[Dict[str, Any]] = None  # last compile event
         self._compiled = None
+        self._lowered = None       # kept for instruction_scopes()
         self._degraded = False
 
     # expose the underlying jit's AOT surface for callers that poke it
@@ -267,10 +308,58 @@ class ObservedJit:
                  warning=True, name=self.name)
         self.cost = fields
         self._compiled = compiled
+        self._lowered = lowered
+
+    def instruction_scopes(self) -> Optional[Dict[str, Any]]:
+        """``{"module", "scopes": {instruction name: op_name},
+        "map_from", "text_bytes"}`` of the program this observer runs
+        (``obs/scopes.py parse_program_text``): what joins a device
+        trace, whose events are named by instruction, to the program
+        scopes.  None before the first call and after a degrade.
+        Nothing on the hot path calls this; it costs nothing until
+        asked.
+
+        ``map_from`` is ``"loaded"`` when the running executable's own
+        text carries the scopes.  A persistent-cache entry written
+        before a scope existed is served unchanged (metadata is not in
+        the cache key), and its text has none: the same lowered program
+        is then compiled once more past the cache
+        (:func:`compile_text_uncached` — not loaded, so it needs no
+        device memory beside the live programs) and ``map_from`` is
+        ``"recompiled"``.  One ``compile`` event says which."""
+        if self._compiled is None:
+            return None
+        t0 = time.perf_counter()
+        try:
+            text = self._compiled.as_text() or ""
+        except Exception:  # noqa: BLE001 - introspection is best-effort
+            text = ""
+        map_from = "loaded"
+        if not has_scopes(text):
+            try:
+                with Heartbeat(f"scopes:{self.name}"):
+                    text = compile_text_uncached(self._lowered)
+                map_from = "recompiled"
+            except Exception as e:  # noqa: BLE001 - best-effort too
+                emit("compile",
+                     f"instruction scopes of {self.name}: the loaded "
+                     f"program's text has no roc. scope and the "
+                     f"uncached compile failed: {type(e).__name__}: "
+                     f"{str(e)[:300]}", warning=True, name=self.name)
+        got = parse_program_text(text)
+        got.update(map_from=map_from, text_bytes=len(text))
+        took = time.perf_counter() - t0
+        emit("compile",
+             f"instruction scopes of {self.name}: {map_from}, "
+             f"{len(got['scopes'])} instructions, {took:.1f}s",
+             console=self.verbose, name=self.name, scopes_from=map_from,
+             scopes_s=round(took, 3), instructions=len(got["scopes"]))
+        return got
 
     def _degrade(self, e: BaseException):
         self._degraded = True
         self._compiled = None
+        self._lowered = None
         emit("compile",
              f"compile observer disabled for {self.name}: "
              f"{type(e).__name__}: {e}",

@@ -1,0 +1,106 @@
+"""Program scopes: the names the step's operations carry, and how to
+read them back out of a compiled program.
+
+Every operation a step dispatches sits under one ``jax.named_scope``
+whose name begins ``roc.<class>``.  JAX writes the scope path into each
+HLO instruction's ``metadata={op_name="..."}`` — and wraps it in
+``jvp(...)`` / ``transpose(jvp(...))`` itself, so the direction is never
+written by hand:
+
+    jit(step)/jvp(roc.agg.op03)/while/body/...             forward
+    jit(step)/transpose(jvp(roc.agg.op03))/while/body/...  backward
+
+The scopes are metadata only: the lowered program, the executable and
+the compile-cache key are the same with and without them.  The device
+trace names an operation by its instruction (``%fusion.28 = ...``), not
+by its scope; :func:`parse_program_text` gives the instruction -> scope
+map (``ObservedJit.instruction_scopes``) that joins the two.
+
+| class | what runs under it | name |
+| --- | --- | --- |
+| ``agg`` | a model op that aggregates over edges (``scatter_gather``, ``fused_aggregate``, ``gat``) | ``roc.agg.op<i>`` |
+| ``halo`` | the feature halo exchange inside an aggregation (all-gather, ring hops) | ``roc.halo`` |
+| ``dense`` | every other model op | ``roc.dense.op<i>.<kind>`` |
+| ``loss`` | masked cross-entropy and the metric reductions | ``roc.loss`` |
+| ``opt`` | the Adam update and the parameter casts | ``roc.opt`` |
+| ``allreduce`` | the gradient / loss / metric ``psum`` across partitions | ``roc.allreduce`` |
+
+``<i>`` is the op's index in ``Model._ops``, two digits.
+"""
+
+from __future__ import annotations
+
+import re
+from typing import Any, Dict, Optional, Tuple
+
+PREFIX = "roc."
+AGG, HALO, DENSE, LOSS, OPT, ALLREDUCE = (
+    "agg", "halo", "dense", "loss", "opt", "allreduce")
+CLASSES = (AGG, HALO, DENSE, LOSS, OPT, ALLREDUCE)
+# the model op kinds whose scope class is ``agg``; every other kind is
+# ``dense``
+AGG_KINDS = ("scatter_gather", "fused_aggregate", "gat")
+
+HALO_SCOPE = PREFIX + HALO
+LOSS_SCOPE = PREFIX + LOSS
+OPT_SCOPE = PREFIX + OPT
+ALLREDUCE_SCOPE = PREFIX + ALLREDUCE
+
+_SCOPE = re.compile(r"roc\.(" + "|".join(CLASSES) + r")(?:\.op(\d+))?")
+_MODULE = re.compile(r"^HloModule\s+([^\s,]+)")
+# "  ROOT %fusion.7 = f32[8]{0} fusion(...), ..., metadata={op_name="..."}"
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=%]+)\s+=\s")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+
+
+def op_scope(index: int, kind: str) -> str:
+    """The scope of ``Model._ops[index]``."""
+    if kind in AGG_KINDS:
+        return f"{PREFIX}{AGG}.op{index:02d}"
+    return f"{PREFIX}{DENSE}.op{index:02d}.{kind}"
+
+
+def parse_op_name(op_name: str
+                  ) -> Optional[Tuple[str, Optional[int], str]]:
+    """``(class, op index or None, "fwd" | "bwd")`` of an ``op_name``
+    path, None when no component is a ``roc.`` scope.  The innermost
+    ``roc.`` component gives the class (a ``roc.halo`` inside a
+    ``roc.agg.op03`` is halo); the op index is the innermost one any
+    component carries (that halo belongs to op 3); the direction is
+    ``bwd`` where JAX wrapped a component in ``transpose(`` (the
+    primitive of that name has no parenthesis)."""
+    found = _SCOPE.findall(op_name)
+    if not found:
+        return None
+    index = next((int(i) for _, i in reversed(found) if i), None)
+    return (found[-1][0], index,
+            "bwd" if "transpose(" in op_name else "fwd")
+
+
+def parse_program_text(text: str) -> Dict[str, Any]:
+    """``{"module": <HloModule name>, "scopes": {<instruction name>:
+    <op_name>}}`` from a compiled program's text: every instruction of
+    every computation (the operations inside a ``while`` body are
+    events of a device trace too), with ``""`` where the instruction
+    carries no ``op_name``."""
+    module = ""
+    scopes: Dict[str, str] = {}
+    for line in text.splitlines():
+        if not module:
+            m = _MODULE.match(line)
+            if m:
+                module = m.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        name = _OP_NAME.search(line, m.end())
+        scopes[m.group(1)] = name.group(1) if name else ""
+    return {"module": module, "scopes": scopes}
+
+
+def has_scopes(text: str) -> bool:
+    """Whether a program's text carries any ``roc.`` scope at all — a
+    program served by a compile-cache entry written before the scopes
+    existed has none (metadata is not in the cache key)."""
+    return _SCOPE.search(text) is not None

@@ -45,6 +45,7 @@ from ..core.graph import Dataset, MASK_NONE
 from ..core.partition import PartitionedGraph, partition_graph
 from ..models.builder import GraphContext, Model
 from ..obs.events import emit
+from ..obs.scopes import ALLREDUCE_SCOPE, LOSS_SCOPE, OPT_SCOPE
 from ..ops.loss import masked_softmax_cross_entropy, perf_metrics, summarize_metrics
 from ..train.optimizer import AdamConfig, adam_init, adam_update
 from ..train.trainer import (TrainConfig, cast_floats, compute_dtype_of,
@@ -1268,10 +1269,13 @@ class DistributedTrainer:
             def local_loss(p):
                 # mixed precision: fp32 master params cast per step;
                 # astype's vjp keeps grads (and the psum) in fp32
-                logits = self.model.apply(cast_floats(p, self.compute),
-                                          feats, gctx, key=part_key,
+                with jax.named_scope(OPT_SCOPE):
+                    p = cast_floats(p, self.compute)
+                logits = self.model.apply(p, feats, gctx, key=part_key,
                                           train=True)
-                return masked_softmax_cross_entropy(logits, labels, mask)
+                with jax.named_scope(LOSS_SCOPE):
+                    return masked_softmax_cross_entropy(logits, labels,
+                                                        mask)
 
             if self.config.remat:
                 local_loss = jax.checkpoint(
@@ -1279,10 +1283,12 @@ class DistributedTrainer:
             local_l, grads = jax.value_and_grad(local_loss)(params)
             # the reference's replica-sum gradient allreduce
             # (optimizer_kernel.cu:88-94) as an ICI psum
-            grads = self._psum_parts(grads)
-            loss = self._psum_parts(local_l)
-            params, opt_state = adam_update(params, grads, opt_state, lr,
-                                            self.adam_cfg)
+            with jax.named_scope(ALLREDUCE_SCOPE):
+                grads = self._psum_parts(grads)
+                loss = self._psum_parts(local_l)
+            with jax.named_scope(OPT_SCOPE):
+                params, opt_state = adam_update(params, grads, opt_state,
+                                                lr, self.adam_cfg)
             return params, opt_state, loss
 
         return _shard_map(
@@ -1309,8 +1315,10 @@ class DistributedTrainer:
             edge_src[0], edge_dst[0], in_degree[0], ell_idx,
             ell_row_pos, ell_row_id, ring_idx, sect_idx, sect_sub_dst,
             bd_tabs, fuse_tabs, pid=pid)
-        return self.model.apply(cast_floats(params, self.compute),
-                                feats, gctx, key=None, train=False)
+        with jax.named_scope(OPT_SCOPE):
+            params = cast_floats(params, self.compute)
+        return self.model.apply(params, feats, gctx, key=None,
+                                train=False)
 
     def _build_eval_step(self):
         mesh = self.mesh
@@ -1327,12 +1335,14 @@ class DistributedTrainer:
                 pid = pids[0]
             logits = self._local_forward(params, feats, *graph_args,
                                          pid=pid)
-            m = perf_metrics(logits, labels[0], mask[0])
+            with jax.named_scope(LOSS_SCOPE):
+                m = perf_metrics(logits, labels[0], mask[0])
             # (replicated metrics, sharded logits): predict() reuses
             # this program's logits output — no second compile, no
             # collective added to the eval path
-            return jax.tree_util.tree_map(self._psum_parts,
-                                          m), logits
+            with jax.named_scope(ALLREDUCE_SCOPE):
+                return jax.tree_util.tree_map(self._psum_parts,
+                                              m), logits
 
         return _shard_map(
             step, mesh=mesh,

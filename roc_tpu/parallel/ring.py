@@ -35,6 +35,7 @@ import numpy as np
 from jax import lax
 
 from ..core.partition import PartitionedGraph
+from ..obs.scopes import HALO_SCOPE
 from . import PARTS_AXIS
 
 
@@ -225,6 +226,11 @@ def ring_aggregate(x: jax.Array, ring_src: jax.Array,
         out, _ = lax.scan(chunk_body, out, xs)
         return out
 
+    def hop(buf):
+        # the halo exchange of this layout: one rotation of the ring
+        with jax.named_scope(HALO_SCOPE):
+            return lax.ppermute(buf, axis_name, perm)
+
     def step(k, carry):
         buf, out = carry
         # double-buffered hop: the rotation that fills the NEXT step's
@@ -235,7 +241,7 @@ def ring_aggregate(x: jax.Array, ring_src: jax.Array,
         # compute instead of after it.  (Skipped rotation work on the
         # last step is harmless; keeping it unconditional keeps the
         # loop body uniform.)
-        nxt = (lax.ppermute(buf, axis_name, perm) if overlap else None)
+        nxt = hop(buf) if overlap else None
         src_shard = jnp.mod(me - k, S)
         src_e = lax.dynamic_index_in_dim(ring_src, src_shard, axis=0,
                                          keepdims=False)
@@ -249,7 +255,7 @@ def ring_aggregate(x: jax.Array, ring_src: jax.Array,
         out = local_pair(out, buf_ext, src_e, dst_e, w_e)
         if not overlap:
             # sequential reference: rotate only after the accumulate
-            nxt = lax.ppermute(buf, axis_name, perm)
+            nxt = hop(buf)
         return nxt, out
 
     out0 = jnp.zeros((n, F), dtype=x.dtype)

@@ -548,7 +548,7 @@ def main(argv: Optional[List[str]] = None,
         trainer.train(epochs=1)  # compile outside the trace
         # phase spans route through jax.profiler.TraceAnnotation for
         # the traced epoch (utils/profiling.py EpochTimer.annotate),
-        # so the XLA device trace carries the same named phases as
+        # so the trace's host plane carries the same named phases as
         # the host timeline lanes.  The CLI owns the toggle here: it
         # never sets TrainConfig.profile_dir (run_epoch_loop would
         # start a SECOND nested profiler trace), so the constructor's
@@ -562,6 +562,20 @@ def main(argv: Optional[List[str]] = None,
             trainer.timer.annotate = False
         emit("run", f"profile written to {args.profile_dir}",
              path=args.profile_dir)
+        # the trace names device operations by HLO instruction; the
+        # instruction -> program-scope map of every step program that
+        # ran goes beside it (obs/scopes.py, README "Observability")
+        import json
+        import os
+        from ..obs.compile_watch import ObservedJit
+        for step in vars(trainer).values():
+            got = (step.instruction_scopes()
+                   if isinstance(step, ObservedJit) else None)
+            if got is not None:
+                with open(os.path.join(
+                        args.profile_dir,
+                        f"scopes.{step.name}.json"), "w") as f:
+                    json.dump(got, f)
 
     t0 = time.time()
     remaining = args.epochs - trainer.epoch

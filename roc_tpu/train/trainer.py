@@ -23,6 +23,7 @@ from ..core.partition import padded_edge_list
 from ..models.builder import GraphContext, Model
 from ..obs.events import emit
 from ..obs.metrics_registry import MetricsRegistry
+from ..obs.scopes import LOSS_SCOPE, OPT_SCOPE
 from ..ops.loss import perf_metrics, summarize_metrics
 from .optimizer import AdamConfig, adam_init, adam_update, decayed_lr
 
@@ -1072,8 +1073,8 @@ class Trainer:
                      console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
         # annotate=True routes every phase span through
-        # jax.profiler.TraceAnnotation so --profile-dir device
-        # traces carry the same named phases as the timeline lanes
+        # jax.profiler.TraceAnnotation so a --profile-dir trace's host
+        # plane carries the same named phases as the timeline lanes
         self.timer = EpochTimer(
             annotate=bool(config.profile_dir))
         self.metrics_log = MetricsLog(config.metrics_path)
@@ -1086,22 +1087,27 @@ class Trainer:
         def objective(p):
             # mixed precision: compute in self.compute; the astype vjp
             # returns fp32 cotangents, so grads/Adam stay in dtype
-            loss, _ = self.model.loss_fn(cast_floats(p, self.compute),
-                                         feats, labels, mask,
+            with jax.named_scope(OPT_SCOPE):
+                p = cast_floats(p, self.compute)
+            loss, _ = self.model.loss_fn(p, feats, labels, mask,
                                          gctx, key=key, train=True)
             return loss
         if self.config.remat:
             objective = jax.checkpoint(
                 objective, policy=remat_policy(self.config))
         loss, grads = jax.value_and_grad(objective)(params)
-        params, opt_state = adam_update(params, grads, opt_state, lr,
-                                        self.adam_cfg)
+        with jax.named_scope(OPT_SCOPE):
+            params, opt_state = adam_update(params, grads, opt_state,
+                                            lr, self.adam_cfg)
         return params, opt_state, loss
 
     def _eval_step_impl(self, params, feats, labels, mask, gctx):
-        logits = self.model.apply(cast_floats(params, self.compute),
-                                  feats, gctx, key=None, train=False)
-        return perf_metrics(logits, labels, mask), logits
+        with jax.named_scope(OPT_SCOPE):
+            params = cast_floats(params, self.compute)
+        logits = self.model.apply(params, feats, gctx, key=None,
+                                  train=False)
+        with jax.named_scope(LOSS_SCOPE):
+            return perf_metrics(logits, labels, mask), logits
 
     # ---- host-feature streaming path (config.features == "host") ----
 
@@ -1109,9 +1115,10 @@ class Trainer:
         """Loss + grads of the device-resident tail w.r.t. (params, Y);
         dY feeds the streamed head weight gradient."""
         def objective(p, yy):
+            with jax.named_scope(OPT_SCOPE):
+                p = cast_floats(p, self.compute)
             loss, _ = self._tail_model.loss_fn(
-                cast_floats(p, self.compute), yy, labels, mask,
-                gctx, key=key, train=True)
+                p, yy, labels, mask, gctx, key=key, train=True)
             return loss
         if self.config.remat:
             objective = jax.checkpoint(
@@ -1123,12 +1130,17 @@ class Trainer:
     def _tail_eval_impl(self, params, y, labels, mask, gctx):
         # (metrics, logits) like _eval_step_impl: the streamed tier's
         # predict reuses this one compiled program (no tail_predict)
-        logits = self._tail_model.apply(cast_floats(params, self.compute),
-                                        y, gctx, key=None, train=False)
-        return perf_metrics(logits, labels, mask), logits
+        with jax.named_scope(OPT_SCOPE):
+            params = cast_floats(params, self.compute)
+        logits = self._tail_model.apply(params, y, gctx, key=None,
+                                        train=False)
+        with jax.named_scope(LOSS_SCOPE):
+            return perf_metrics(logits, labels, mask), logits
 
     def _apply_update_impl(self, params, opt_state, grads, lr):
-        return adam_update(params, grads, opt_state, lr, self.adam_cfg)
+        with jax.named_scope(OPT_SCOPE):
+            return adam_update(params, grads, opt_state, lr,
+                               self.adam_cfg)
 
     def _pin_stream(self, y):
         """Model-shard the streamed-head [V, H] handoff: under a

@@ -8,8 +8,15 @@ calls this a gap to fill, not copy).  The TPU-native equivalents:
 - :func:`trace` — context manager around ``jax.profiler`` emitting a
   TensorBoard/Perfetto trace directory (the analog of Legion's
   ``-lg:prof`` logs).
-- :class:`annotate` — ``jax.profiler.TraceAnnotation`` wrapper so epoch
-  phases (forward/backward/update/eval) show up as named spans.
+- :class:`annotate` — ``jax.profiler.TraceAnnotation`` wrapper: a named
+  span on the trace's HOST plane, on the profiler's clock.  What the
+  device did is not named by it: on this stack (jax 0.9, libtpu 0.0.34)
+  the device plane's ``XLA Ops`` events are named by HLO instruction
+  (``%fusion.28 = ...``) and carry no scope or category.  Which model
+  op an instruction belongs to is in the compiled program's text; the
+  CLI writes that map beside a ``--profile-dir`` trace as
+  ``scopes.<program>.json`` (``obs/scopes.py``,
+  ``ObservedJit.instruction_scopes``).
 - :class:`EpochTimer` — honest wall-clock epoch timing, plus named
   per-phase spans (train burst / eval / streamed-head sub-phases)
   recorded with the same barrier (:func:`sync`).
@@ -69,9 +76,9 @@ class EpochTimer:
     The first ``warmup`` laps (compile + cache effects) are recorded but
     excluded from the summary statistics.  ``span(name)`` records a
     phase (train burst, eval, halo exchange, streamed head
-    forward/wgrad, optimizer update) into its own series — the host-
-    visible analog of :func:`annotate`'s device-trace spans, summarized
-    by :meth:`span_summary` as p50/p90 per phase.
+    forward/wgrad, optimizer update) into its own series — host wall
+    time, like :func:`annotate`'s spans on a trace's host plane —
+    summarized by :meth:`span_summary` as p50/p90 per phase.
     """
 
     warmup: int = 1
@@ -82,9 +89,10 @@ class EpochTimer:
     # drained by :meth:`take_timeline` into periodic ``timeline``
     # events (train/trainer.py run_epoch_loop)
     timeline: List[tuple] = field(default_factory=list)
-    # route spans through jax.profiler.TraceAnnotation too, so device
-    # traces (--profile-dir) carry the same named phases as the host
-    # timeline lanes; off by default (annotate imports jax)
+    # route spans through jax.profiler.TraceAnnotation too, so a
+    # --profile-dir trace carries the same named phases, on its host
+    # plane, as the host timeline lanes; off by default (annotate
+    # imports jax)
     annotate: bool = False
     _t0: Optional[float] = None
 
@@ -121,9 +129,11 @@ class EpochTimer:
 
         With :attr:`annotate` set, the span body also runs inside a
         ``jax.profiler.TraceAnnotation`` of the same name, so a
-        ``--profile-dir`` device trace carries the phases the host
-        timeline shows (the merged-timeline lanes and the XLA trace
-        line up by name)."""
+        ``--profile-dir`` trace shows, on its host plane and its own
+        clock, the phases the host timeline shows.  The device plane
+        is not touched by this: its operations are named by HLO
+        instruction, and ``scopes.<program>.json`` beside the trace
+        maps those to the program scopes (module docstring)."""
         ann = annotate(name) if self.annotate else None
         if ann is not None:
             ann.__enter__()
