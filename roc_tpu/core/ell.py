@@ -286,12 +286,22 @@ class SectionedEll:
       consecutive sub-rows;
     - ``sub_dst[s]``: int32 ``[n_chunks, seg_rows]`` the output row of
       each sub-row, ascending within each chunk (scatter-add with
-      ``indices_are_sorted``); chunk padding points at ``num_rows``.
+      ``indices_are_sorted``); chunk padding points at ``num_rows``;
+    - ``win_rows[s]``: how tall a chunk's run of real destinations can
+      be in this section (:func:`chunk_window_rows` over ``sub_dst``;
+      stacked tables: over every part, SPMD shapes must agree).
 
     The aggregation is a ``lax.scan`` over chunks carrying the output:
-    gather-sum from the section slice, sorted scatter-add of the
-    ``[seg_rows, F]`` partials.  Padding cost: each (row, section) pair
-    rounds up to 8 — for avg section-degree d_s the overhead is
+    gather-sum from the section slice, then a sorted scatter-add of the
+    ``[seg_rows, F]`` partials into the ``[win_rows, F]`` window of the
+    carry that starts at the chunk's first destination — the carry
+    itself is only sliced and updated in place, so a chunk step costs
+    its window, not the whole ``[num_rows, F]`` output
+    (ops/aggregate.py ``_scan_window_sum``; a window past half the
+    carry does not pay and scans the whole of it,
+    ``scan_window_rows``).  Padding cost: each
+    (row, section) pair rounds up to 8 — for avg section-degree d_s
+    the overhead is
     <= 8/d_s + 4/d_s ~ a few percent at Reddit scale, but grows toward
     2x when d_s ~ 8 (many sections or low degree): prefer plain ELL
     for small graphs; this layout targets tables past VMEM size.
@@ -306,10 +316,26 @@ class SectionedEll:
     idx: Tuple[np.ndarray, ...]
     sub_dst: Tuple[np.ndarray, ...]
     sub_w: int = 8
+    win_rows: Tuple[int, ...] = ()
+
+    def __post_init__(self):
+        # derived from the table, never configured: every builder
+        # (native, numpy, stacked) gets it from the one pass here
+        if not self.win_rows:
+            self.win_rows = tuple(chunk_window_rows(d, self.num_rows)
+                                  for d in self.sub_dst)
 
     @property
     def padded_edges(self) -> int:
         return sum(a.size for a in self.idx)
+
+    @property
+    def meta(self) -> Tuple[Tuple[int, int, int], ...]:
+        """Static ``(start, size, win_rows)`` per section — the
+        ``sect_meta`` of :func:`roc_tpu.ops.aggregate.
+        aggregate_ell_sect`."""
+        return tuple(zip(self.sec_starts, self.sec_sizes,
+                         self.win_rows))
 
     def as_jax(self):
         """(idx, sub_dst, meta) in the calling convention of
@@ -318,7 +344,7 @@ class SectionedEll:
         import jax.numpy as jnp
         return (tuple(jnp.asarray(a) for a in self.idx),
                 tuple(jnp.asarray(a) for a in self.sub_dst),
-                tuple(zip(self.sec_starts, self.sec_sizes)))
+                self.meta)
 
     def weight_tables(self, d_dst: np.ndarray,
                       d_src: np.ndarray) -> Tuple[np.ndarray, ...]:
@@ -371,6 +397,26 @@ class SectionedEll:
         from dataclasses import replace
         return replace(
             self, idx=tuple(a.astype(dtype) for a in self.idx))
+
+
+# Window heights round up to this many rows: a multiple of every
+# dtype's sublane packing (8 fp32 / 16 bf16 rows a tile), and coarse
+# enough that graphs of one shape share a compiled scan.
+WIN_ROWS_MULTIPLE = 128
+
+
+def chunk_window_rows(sub_dst: np.ndarray, num_rows: int) -> int:
+    """Rows the chunk scan's destination window needs for
+    ``sub_dst`` (``[..., n_chunks, seg_rows]``, ascending within a
+    chunk, chunk padding == ``num_rows`` at the tail): the largest
+    ``last real dst - first dst + 1`` over all chunks (and parts),
+    rounded up to :data:`WIN_ROWS_MULTIPLE`.  Padding rows are not
+    destinations — their partials are exactly zero (dummy source row,
+    weight 0) — and an all-padding chunk needs no rows."""
+    sub_dst = np.asarray(sub_dst)
+    last = np.where(sub_dst < num_rows, sub_dst, -1).max(axis=-1)
+    span = int(np.maximum(last - sub_dst[..., 0] + 1, 0).max())
+    return -(-max(span, 1) // WIN_ROWS_MULTIPLE) * WIN_ROWS_MULTIPLE
 
 
 # Uniform flat-sum layout (aggregate_flat_sum): chunk granularity of
@@ -438,9 +484,13 @@ SECTION_ROWS_DEFAULT = 65_536   # 64 MiB of fp32 rows at F=256
 #   V=500k: sectioned 440 ms vs ell 477 ms   (marginal win)
 #   V=1M:   sectioned 964 ms vs ell 440 ms   (2.2x LOSS)
 #   V=2.45M: sectioned 3784 ms vs ell 1010 ms (3.7x loss)
-# Past ~0.6M output rows the carry-scan's scatter-add dominates (the
-# [V, F] carry is rewritten every chunk step), so 'auto' hands back to
-# the whole-table ELL gather.
+# That sweep predates the windowed chunk scan: its scatter-add rewrote
+# the whole [V, F] carry every chunk step, which is what dominated past
+# ~0.6M output rows and why 'auto' hands back to the whole-table
+# gather there.  A chunk step now costs its [win_rows, F] window
+# (SectionedEll.win_rows), so the bound is stale rather than measured:
+# it keeps its value until sectioned is raced past it again (ROADMAP
+# S1(b)).
 SECTIONED_MAX_ROWS = 600_000
 
 # The auto-impl window is a MEASURED property of a device generation,
@@ -533,13 +583,15 @@ def resolve_auto_impl(num_nodes: int,
     The two sectioned bounds scale with different sizes: the LOWER
     bound is the gathered source-table size (global ``num_nodes`` —
     sectioned's win is VMEM-resident section gathers, and a partition
-    gathers from ALL nodes), while the UPPER bound is the scatter-add
-    carry ``[out_rows, F]`` rewritten every chunk step — per-partition
-    ``out_rows`` in distributed runs (defaults to ``num_nodes``
-    single-device).  The bounds are generation-keyed
-    (:func:`sectioned_bounds`).  ``num_edges=None`` skips the
-    flat_sum route (legacy callers keep the old sectioned/ell
-    split)."""
+    gathers from ALL nodes), while the UPPER bound is the output
+    carry ``[out_rows, F]`` — per-partition ``out_rows`` in
+    distributed runs (defaults to ``num_nodes`` single-device) — and
+    dates from when every chunk step rewrote all of it; the scan now
+    touches a ``win_rows`` window a step, so that bound awaits a new
+    sweep (see :data:`SECTIONED_MAX_ROWS`).  The bounds are
+    generation-keyed (:func:`sectioned_bounds`).  ``num_edges=None``
+    skips the flat_sum route (legacy callers keep the old
+    sectioned/ell split)."""
     if out_rows is None:
         out_rows = num_nodes
     lo, hi = sectioned_bounds(device_kind)
@@ -768,4 +820,6 @@ def sectioned_from_padded_parts(part_row_ptr: np.ndarray,
                   for s in range(len(first.idx))),
         sub_dst=tuple(np.stack([pp.sub_dst[s] for pp in per_part])
                       for s in range(len(first.sub_dst))),
-        sub_w=sub_w)
+        sub_w=sub_w,
+        win_rows=tuple(max(pp.win_rows[s] for pp in per_part)
+                       for s in range(len(first.win_rows))))

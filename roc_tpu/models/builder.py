@@ -34,7 +34,7 @@ from ..ops import dense
 from ..parallel import PARTS_AXIS
 from ..ops.aggregate import (aggregate, aggregate_ell, aggregate_ell_max,
                              aggregate_ell_sect, aggregate_flat_max,
-                             aggregate_flat_sum)
+                             aggregate_flat_sum, scan_window_rows)
 from ..ops.dense import AC_MODE_NONE, AC_MODE_RELU, AC_MODE_SIGMOID
 from ..ops.loss import masked_softmax_cross_entropy
 from ..ops.norm import indegree_norm
@@ -108,11 +108,12 @@ class GraphContext:
     ell_row_id: Tuple[jax.Array, ...] = ()
     # Sectioned layout (aggr_impl == "sectioned"): per-section
     # [n_chunks, seg_rows, 8] sub-row tables + [n_chunks, seg_rows]
-    # output rows, with static (start, size) metadata (core/ell.py
-    # SectionedEll — measured 2.3x over "ell" at Reddit scale)
+    # output rows, with static (start, size, win_rows) metadata
+    # (core/ell.py SectionedEll.meta — measured 2.3x over "ell" at
+    # Reddit scale)
     sect_idx: Tuple[jax.Array, ...] = ()
     sect_sub_dst: Tuple[jax.Array, ...] = ()
-    sect_meta: Tuple[Tuple[int, int], ...] = ()
+    sect_meta: Tuple[Tuple[int, ...], ...] = ()
     # Uniform width-8 flat layout: one [n_chunks, seg_rows, 8]
     # global-id table + [n_chunks, seg_rows] output rows, whose
     # compile size is degree-distribution-independent.  Two consumers:
@@ -122,10 +123,14 @@ class GraphContext:
     # aggregate_flat_sum — ONE scan program instead of one per degree
     # bucket).  flat8_w carries the baked fused-normalization weights
     # for the flat_sum form (shape mirrors flat8_idx; None = derive d
-    # from in_degree and pre/post-scale in-op).
+    # from in_degree and pre/post-scale in-op).  flat8_win is the
+    # table's static destination-window height for the sum scan
+    # (SectionedEll.win_rows[0]; 0 = the whole carry), set for
+    # "flat_sum" only — attention and MAX never read it.
     flat8_idx: Optional[jax.Array] = None
     flat8_dst: Optional[jax.Array] = None
     flat8_w: Optional[jax.Array] = None
+    flat8_win: int = 0
     # Block-dense MXU layout (aggr_impl == "bdense"): dense [128,128]
     # adjacency tiles as uint8 multiplicity tables + tile ids, with
     # the residual (scattered) edges in the sect_* sectioned tables
@@ -166,6 +171,20 @@ class GraphContext:
     head_chunk: int = 0
     axis_name: str = PARTS_AXIS
 
+    def agg_window(self) -> dict:
+        """How far the chunk scan's destination window engaged — the
+        run manifest's ``resolved`` carries it (obs/manifest.py):
+        rows a chunk step reads and writes per section
+        (``scan_window_rows`` of the table's ``win_rows``) against the
+        carry's height.  Empty for the layouts that scan no carry."""
+        carry = self.num_rows + 1
+        wins = [m[2] for m in self.sect_meta if len(m) > 2]
+        if self.flat8_win:
+            wins = [self.flat8_win]
+        return {"agg_window_rows": [scan_window_rows(w, carry)
+                                    for w in wins],
+                "agg_carry_rows": carry if wins else None}
+
     def _gathered_with_zero(self, x: jax.Array) -> jax.Array:
         """Halo exchange (under its own ``roc.halo`` scope, inside the
         aggregation's) + the appended dummy zero source row that
@@ -192,7 +211,8 @@ class GraphContext:
                                       self.num_rows)
         if self.aggr_impl == "flat_sum":
             return aggregate_flat_sum(full, self.flat8_idx,
-                                      self.flat8_dst, self.num_rows)
+                                      self.flat8_dst, self.num_rows,
+                                      win_rows=self.flat8_win)
         if self.aggr_impl == "bdense":
             from ..ops.blockdense import aggregate_block_dense
             out = None
@@ -283,7 +303,8 @@ class GraphContext:
             full = self._gathered_with_zero(x)
             return aggregate_flat_sum(full, self.flat8_idx,
                                       self.flat8_dst, self.num_rows,
-                                      flat_w=self.flat8_w)
+                                      flat_w=self.flat8_w,
+                                      win_rows=self.flat8_win)
         if self.aggr_impl == "bdense" and self.bd_scale:
             from ..ops.blockdense import aggregate_block_dense
             full = self._gathered_with_zero(x)
@@ -465,14 +486,14 @@ def _gctx_flatten(g: GraphContext):
     aux = (g.num_rows, g.gathered_rows, g.gather_features, g.psum,
            g.aggr_impl, g.chunk, g.symmetric, g.halo, g.axis_name,
            g.sect_meta, g.bd_vpad, g.bd_src_vpad, g.bd_group,
-           g.ring_overlap, g.head_chunk)
+           g.ring_overlap, g.head_chunk, g.flat8_win)
     return children, aux
 
 
 def _gctx_unflatten(aux, children):
     (num_rows, gathered_rows, gather_features, psum, aggr_impl, chunk,
      symmetric, halo, axis_name, sect_meta, bd_vpad, bd_src_vpad,
-     bd_group, ring_overlap, head_chunk) = aux
+     bd_group, ring_overlap, head_chunk, flat8_win) = aux
     (edge_src, edge_dst, in_degree, ell_idx, ell_row_pos, ring_idx,
      sect_idx, sect_sub_dst, ell_row_id, flat8_idx,
      flat8_dst, flat8_w, bd_a, bd_src, bd_dst, ell_w, sect_w, ring_w,
@@ -486,8 +507,9 @@ def _gctx_unflatten(aux, children):
         ring_idx=ring_idx, axis_name=axis_name, sect_idx=sect_idx,
         sect_sub_dst=sect_sub_dst, sect_meta=sect_meta,
         ell_row_id=ell_row_id, flat8_idx=flat8_idx,
-        flat8_dst=flat8_dst, flat8_w=flat8_w, bd_a=bd_a, bd_src=bd_src,
-        bd_dst=bd_dst, bd_vpad=bd_vpad, bd_src_vpad=bd_src_vpad,
+        flat8_dst=flat8_dst, flat8_w=flat8_w, flat8_win=flat8_win,
+        bd_a=bd_a, bd_src=bd_src, bd_dst=bd_dst, bd_vpad=bd_vpad,
+        bd_src_vpad=bd_src_vpad,
         bd_group=bd_group, ring_overlap=ring_overlap,
         head_chunk=head_chunk,
         ell_w=ell_w, sect_w=sect_w, ring_w=ring_w, bd_scale=bd_scale)

@@ -62,8 +62,12 @@ def _config_dict(config) -> Dict[str, Any]:
 def run_manifest(config=None, dataset=None, model=None,
                  num_parts: int = 1,
                  extra: Optional[Dict[str, Any]] = None,
+                 agg_window: Optional[Dict[str, Any]] = None,
                  console: bool = True) -> Dict[str, Any]:
     """Assemble + emit the ``manifest`` event; returns the fields.
+    ``agg_window`` (``GraphContext.agg_window()``) joins ``resolved``:
+    the chunk scan's window rows per section and the carry's height,
+    so a run says how far the windowed scatter engaged.
 
     Everything is best-effort: a missing backend or detached checkout
     degrades to nulls, never to an exception at trainer setup."""
@@ -98,6 +102,7 @@ def run_manifest(config=None, dataset=None, model=None,
             "features": getattr(config, "features", None),
             "remat": getattr(config, "remat", None),
             "num_parts": num_parts,
+            **(agg_window or {}),
         }
     if dataset is not None:
         g = dataset.graph

@@ -234,6 +234,62 @@ def aggregate_ell(feats: jax.Array, ell_idx, ell_row_pos: jax.Array,
     return cat[ell_row_pos]
 
 
+def scan_window_rows(win_rows: int, carry_rows: int) -> int:
+    """Rows of the carry a chunk step of :func:`_scan_window_sum`
+    reads and writes, given the table's ``win_rows`` (0: none known).
+    The window costs three passes over its rows (slice, scatter,
+    write-back) against one scatter over the whole carry, and a tall
+    one no longer fits VMEM: on the v5e it wins up to half the carry's
+    height and loses past it (PERF §6, PR 27: -8% a chunk step at a
+    tenth, -4% at a third, -0.4% at half, +2.4% at 0.6-0.9), so past
+    half the step takes the whole carry."""
+    return win_rows if 0 < 2 * win_rows <= carry_rows else carry_rows
+
+
+def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
+                     win_rows: int) -> jax.Array:
+    """The chunk scan of the width-8 sub-row layouts — one body for
+    :func:`aggregate_ell_sect` (per section) and
+    :func:`aggregate_flat_sum` (its single global section).  Each step
+    gather-sums a chunk's ``[seg_rows, 8]`` ids out of ``table`` and
+    adds the ``[seg_rows, F]`` partials into the carry ``out``.
+
+    The destinations of a chunk are ascending, so its real ones lie
+    in one run of at most ``win_rows`` rows (core/ell.py
+    ``chunk_window_rows``).  The step therefore slices that window out
+    of the carry at the chunk's first destination, scatter-adds into
+    the window, and writes it back: XLA updates a scan carry in place
+    under ``dynamic_update_slice``, so a step reads and writes
+    ``win_rows`` rows instead of the whole ``[num_rows + 1, F]``
+    carry.  Chunk padding (destination ``num_rows``; partial exactly
+    zero: dummy source row, weight 0) is clamped onto the window's
+    last row, where adding a zero changes nothing.  Where
+    :func:`scan_window_rows` says the window does not pay (none known,
+    or taller than half the carry) the window IS the carry, and XLA
+    folds the slice and the write-back away: the whole-carry scatter.
+
+    xs: ``(idx [n, seg, 8], dst [n, seg])`` plus optional weights
+    shaped like ``idx``."""
+    carry_rows, F = out.shape
+    win = scan_window_rows(win_rows, carry_rows)
+
+    def body(o, ch):
+        idx_ch, dst_ch = ch[0], ch[1]
+        g = table[idx_ch]
+        if len(ch) > 2:
+            g = g * ch[2][:, :, None]
+        part = g.sum(axis=1)
+        # clamped so the slice never clips (an all-padding chunk, or a
+        # run that ends at the carry's last rows)
+        r0 = jnp.minimum(dst_ch[0], carry_rows - win)
+        w = lax.dynamic_slice(o, (r0, 0), (win, F))
+        w = w.at[jnp.minimum(dst_ch - r0, win - 1)].add(
+            part, indices_are_sorted=True)
+        return lax.dynamic_update_slice(o, w, (r0, 0)), None
+
+    return lax.scan(body, out, xs)[0]
+
+
 def aggregate_ell_sect(feats: jax.Array, sect_idx, sect_sub_dst,
                        sect_meta, num_rows: int,
                        sect_w=None) -> jax.Array:
@@ -242,12 +298,15 @@ def aggregate_ell_sect(feats: jax.Array, sect_idx, sect_sub_dst,
     section: slice the <= 64 MiB source block out of ``feats`` (XLA
     keeps it VMEM-resident), ``lax.scan`` over sub-row chunks carrying
     the output — gather-sum ``xsec[idx].sum(1)`` hits the fast gather
-    path, then a sorted scatter-add of the ``[seg_rows, F]`` partials.
+    path, then a sorted scatter-add of the ``[seg_rows, F]`` partials
+    into the chunk's destination window (:func:`_scan_window_sum`).
 
     feats: [src_rows(+ optional trailing rows), F]; sections read
       ``[start, start+size)`` so an appended global dummy row is fine.
     sect_idx / sect_sub_dst: SectionedEll.idx / .sub_dst as jax arrays.
-    sect_meta: static tuple of (start, size) per section.
+    sect_meta: static tuple of (start, size, win_rows) per section
+      (SectionedEll.meta); a bare (start, size) scans with the whole
+      carry as its window.
     sect_w (optional): per-section edge weights shaped like
       ``sect_idx`` (SectionedEll.weight_tables — the baked fused-norm
       scales), applied in-register before the width reduction.
@@ -256,23 +315,14 @@ def aggregate_ell_sect(feats: jax.Array, sect_idx, sect_sub_dst,
     out = jnp.zeros((num_rows + 1, F), dtype=feats.dtype)
     zero = jnp.zeros((1, F), dtype=feats.dtype)
     weighted = sect_w is not None and len(sect_w) > 0
-    for si, ((st, sz), tbl, sdst) in enumerate(
+    for si, ((st, sz, *win), tbl, sdst) in enumerate(
             zip(sect_meta, sect_idx, sect_sub_dst)):
         xsec = jnp.concatenate(
             [lax.slice(feats, (st, 0), (st + sz, F)), zero], axis=0)
         xs = (tbl, sdst)
         if weighted:
             xs += (sect_w[si].astype(feats.dtype),)
-
-        def body(o, ch, xsec=xsec):
-            idx_ch, dst_ch = ch[0], ch[1]
-            g = xsec[idx_ch]
-            if len(ch) > 2:
-                g = g * ch[2][:, :, None]
-            part = g.sum(axis=1)
-            return o.at[dst_ch].add(part, indices_are_sorted=True), None
-
-        out, _ = lax.scan(body, out, xs)
+        out = _scan_window_sum(out, xsec, xs, win[0] if win else 0)
     return out[:num_rows]
 
 
@@ -287,7 +337,8 @@ def aggregate_ell_sect_split(feats: jax.Array, sect_idx, sect_sub_dst,
     F = feats.shape[1]
     out = jnp.zeros((num_rows + 1, F), dtype=feats.dtype)
     zero = jnp.zeros((1, F), dtype=feats.dtype)
-    for (st, sz), tbl, sdst in zip(sect_meta, sect_idx, sect_sub_dst):
+    for (st, sz, *_), tbl, sdst in zip(sect_meta, sect_idx,
+                                       sect_sub_dst):
         xsec = jnp.concatenate(
             [lax.slice(feats, (st, 0), (st + sz, F)), zero], axis=0)
         W = tbl.shape[-1]
@@ -305,7 +356,7 @@ def aggregate_ell_sect_split(feats: jax.Array, sect_idx, sect_sub_dst,
 
 def aggregate_flat_sum(feats: jax.Array, flat_idx: jax.Array,
                        flat_dst: jax.Array, num_rows: int,
-                       flat_w=None) -> jax.Array:
+                       flat_w=None, win_rows: int = 0) -> jax.Array:
     """Uniform width-8 sub-row SUM — the sum-path twin of the
     attention layout's ``gat_aggregate_flat8`` (ops/attention.py) and
     the compile-wall fix for the per-bucket ELL unroll: every row's
@@ -330,22 +381,16 @@ def aggregate_flat_sum(feats: jax.Array, flat_idx: jax.Array,
       ``D^-1/2 A D^-1/2`` fused-normalization entries
       (``SectionedEll.weight_tables`` of the single section), applied
       in-register before the width reduction.
+    win_rows: static height of a chunk's destination window
+      (``SectionedEll.win_rows[0]``; 0 = the whole carry) — the scan
+      is :func:`_scan_window_sum`, shared with the sectioned layout.
     """
     F = feats.shape[1]
     out = jnp.zeros((num_rows + 1, F), dtype=feats.dtype)
     xs = (flat_idx, flat_dst)
     if flat_w is not None:
         xs += (flat_w.astype(feats.dtype),)
-
-    def body(o, ch):
-        g = feats[ch[0]]
-        if len(ch) > 2:
-            g = g * ch[2][:, :, None]
-        part = g.sum(axis=1)
-        return o.at[ch[1]].add(part, indices_are_sorted=True), None
-
-    out, _ = lax.scan(body, out, xs)
-    return out[:num_rows]
+    return _scan_window_sum(out, feats, xs, win_rows)[:num_rows]
 
 
 def aggregate_flat_max(feats: jax.Array, flat_idx: jax.Array,

@@ -219,14 +219,16 @@ class ShardedData:
     ring_idx: Tuple[jax.Array, ...] = ()  # (src, dst) [P, S, pair_edges]
     # sectioned layout (aggr_impl == "sectioned"): per section
     # [P, n_chunks_s, seg_rows, 8] / [P, n_chunks_s, seg_rows], plus
-    # the static (start, size) metadata.  For aggr_impl ==
-    # "attn_flat8" the same slots carry the SINGLE-section uniform
-    # width-8 attention tables (ids in gathered coordinates, dummy ==
-    # P*part_nodes; the step body routes them to GraphContext
-    # flat8_idx/flat8_dst)
+    # the static (start, size, win_rows) metadata (SectionedEll.meta).
+    # For aggr_impl == "attn_flat8" / "flat_sum" the same slots carry
+    # the SINGLE-section uniform width-8 tables (ids in gathered
+    # coordinates, dummy == P*part_nodes; the step body routes them to
+    # GraphContext flat8_idx/flat8_dst) with no sect_meta; flat_sum's
+    # static window height rides flat_win (-> GraphContext.flat8_win)
     sect_idx: Tuple[jax.Array, ...] = ()
     sect_sub_dst: Tuple[jax.Array, ...] = ()
-    sect_meta: Tuple[Tuple[int, int], ...] = ()
+    sect_meta: Tuple[Tuple[int, ...], ...] = ()
+    flat_win: int = 0
     # block-dense MXU layout (aggr_impl == "bdense"): per-partition
     # dense [128,128] tiles over (local dst rows x gathered source
     # coords), padded to a uniform block count; () or
@@ -282,7 +284,7 @@ def _sectioned_tables(ptrs: np.ndarray, cols: np.ndarray,
                        sect.weight_tables(fuse_d[0], fuse_d[1]))
     return (tuple(put(a) for a in sect.idx),
             tuple(put(a) for a in sect.sub_dst),
-            tuple(zip(sect.sec_starts, sect.sec_sizes)),
+            sect.meta,
             sect_w)
 
 
@@ -319,6 +321,7 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
     sect_idx = ()
     sect_sub_dst = ()
     sect_meta = ()
+    flat_win = 0
     bd_tabs = ()
     bd_vpad = 0
     bd_src_vpad = 0
@@ -489,9 +492,11 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
                 pg.part_nodes, src_rows=src_rows)
             sect_idx = tuple(put(a) for a in sect.idx)
             sect_sub_dst = tuple(put(a) for a in sect.sub_dst)
-            if aggr_impl == "flat_sum" and fuse_d is not None:
-                sect_w = tuple(put(w) for w in sect.weight_tables(
-                    fuse_d[0], fuse_d[1]))
+            if aggr_impl == "flat_sum":
+                flat_win = sect.win_rows[0]
+                if fuse_d is not None:
+                    sect_w = tuple(put(w) for w in sect.weight_tables(
+                        fuse_d[0], fuse_d[1]))
         if aggr_impl in ("ell", "pallas", "sectioned", "attn_flat8",
                          "flat_sum", "bdense"):
             col_padded = np.zeros((pg.num_parts, 1), dtype=np.int32)
@@ -509,6 +514,7 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
         sect_idx=sect_idx,
         sect_sub_dst=sect_sub_dst,
         sect_meta=sect_meta,
+        flat_win=flat_win,
         bd_tabs=bd_tabs,
         bd_vpad=bd_vpad,
         bd_src_vpad=bd_src_vpad,
@@ -807,6 +813,7 @@ class DistributedTrainer:
                             "bd_occupancy": list(
                                 self.data.bd_occupancy),
                             "partition": self._partition_stats},
+                     agg_window=self._gctx().agg_window(),
                      console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
         # annotate=True routes every phase span through
@@ -997,9 +1004,9 @@ class DistributedTrainer:
                 sh(data.ell_row_pos), sh(data.ell_row_id),
                 sh(data.ring_idx), sh(data.sect_idx),
                 sh(data.sect_sub_dst), sh(data.sect_meta),
-                sh(data.bd_tabs), data.bd_vpad, data.bd_src_vpad,
-                data.bd_group, sh(data.ell_w), sh(data.sect_w),
-                sh(data.ring_w), sh(data.bd_scale))
+                data.flat_win, sh(data.bd_tabs), data.bd_vpad,
+                data.bd_src_vpad, data.bd_group, sh(data.ell_w),
+                sh(data.sect_w), sh(data.ring_w), sh(data.bd_scale))
 
     def _phi(self) -> np.ndarray:
         """Cached per-partition feature matrix for the CURRENT split
@@ -1178,6 +1185,7 @@ class DistributedTrainer:
             halo=self.config.halo,
             ring_overlap=self.config.ring_overlap,
             sect_meta=self.data.sect_meta,
+            flat8_win=self.data.flat_win,
             bd_vpad=self.data.bd_vpad,
             bd_src_vpad=self.data.bd_src_vpad,
             # the DATA's group, validated == config at init: the

@@ -372,6 +372,18 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
     sect_idx = ()
     sect_sub_dst = ()
     sect_meta = ()
+    flat_win = 0
+
+    def agreed_win_rows(sects):
+        """Per-section destination-window heights every host compiles
+        with: the max over ALL parts of each table's own ``win_rows``.
+        The windows exist only once the tables are built, so this is a
+        second O(P * n_sec) exchange after the chunk plan's — same
+        collective, same place in every host's sequence."""
+        return tuple(int(w) for w in _allreduce_part_vec_max(
+            mesh, local, {p: np.asarray(sects[p].win_rows)
+                          for p in local}))
+
     if aggr_impl in ("attn_flat8", "flat_sum"):
         # the uniform flat layout (attention's attn_flat8 and the sum
         # path's flat_sum share it), partition-local: ONE section
@@ -399,6 +411,9 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
                               (plan[0], seg, 8), np.int32),)
         sect_sub_dst = (put_parts(lambda p: sects[p].sub_dst[0],
                                   (plan[0], seg), np.int32),)
+        if aggr_impl == "flat_sum":
+            flat_win = agreed_win_rows(sects)[0]
+
     def local_sectioned_tables(ptrs, colmap):
         """Stacked sectioned tables from per-part (ptr, cols) dicts —
         the ONE multihost implementation of the uniform-chunk-plan
@@ -434,7 +449,8 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
             tuple(put_parts(lambda p, s=s: sects[p].sub_dst[s],
                             (plan[s], seg), np.int32)
                   for s in range(len(first.sub_dst))),
-            tuple(zip(first.sec_starts, first.sec_sizes)))
+            tuple(zip(first.sec_starts, first.sec_sizes,
+                      agreed_win_rows(sects))))
 
     if aggr_impl == "sectioned":
         from ..core.ell import clean_part_ptr
@@ -553,6 +569,7 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
         sect_idx=sect_idx,
         sect_sub_dst=sect_sub_dst,
         sect_meta=sect_meta,
+        flat_win=flat_win,
         bd_tabs=bd_tabs,
         bd_vpad=bd_vpad,
         bd_src_vpad=bd_src_vpad,

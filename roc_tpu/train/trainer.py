@@ -753,6 +753,7 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
     sect_sub_dst: tuple = ()
     sect_meta: tuple = ()
     flat8_idx = flat8_dst = flat8_w = None
+    flat8_win = 0
     bd_a = bd_src = bd_dst = None
     bd_vpad = 0
     ell_w: tuple = ()
@@ -861,11 +862,13 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
         sect = flat_sum_from_graph(g.row_ptr, g.col_idx, g.num_nodes)
         flat8_idx = jnp.asarray(sect.idx[0])
         flat8_dst = jnp.asarray(sect.sub_dst[0])
-        if fuse and aggr_impl == "flat_sum":
-            # baked D^-1/2 A D^-1/2 entries of the single section —
-            # zero runtime normalization on the fused flat path
-            flat8_w = jnp.asarray(
-                sect.weight_tables(d_np, d_np)[0])
+        if aggr_impl == "flat_sum":
+            flat8_win = sect.win_rows[0]
+            if fuse:
+                # baked D^-1/2 A D^-1/2 entries of the single section
+                # — zero runtime normalization on the fused flat path
+                flat8_w = jnp.asarray(
+                    sect.weight_tables(d_np, d_np)[0])
     return GraphContext(
         edge_src=jnp.asarray(edge_src),
         edge_dst=jnp.asarray(edge_dst),
@@ -884,6 +887,7 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
         flat8_idx=flat8_idx,
         flat8_dst=flat8_dst,
         flat8_w=flat8_w,
+        flat8_win=flat8_win,
         head_chunk=head_chunk,
         bd_a=bd_a,
         bd_src=bd_src,
@@ -1070,6 +1074,7 @@ class Trainer:
         from ..obs.manifest import run_manifest
         run_manifest(config=self.config, dataset=dataset, model=model,
                      extra={"modeled_step_bytes": self._modeled_bytes},
+                     agg_window=self.gctx.agg_window(),
                      console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
         # annotate=True routes every phase span through

@@ -102,6 +102,50 @@ def test_run_manifest_schema(tmp_path):
     assert fields["dataset"]["num_edges"] == ds.graph.num_edges
 
 
+@pytest.mark.parametrize("impl,parts", [("sectioned", 1),
+                                        ("flat_sum", 1),
+                                        ("flat_sum", 2), ("ell", 1)])
+def test_manifest_resolved_carries_agg_window(tmp_path, impl, parts):
+    """``resolved`` (what the benchmark's ``plan`` line prints) says
+    how far the windowed chunk scan engaged: window rows per section
+    and the carry's height — straight from the trainer's own tables;
+    nothing for a layout that scans no carry."""
+    from roc_tpu.core.graph import synthetic_dataset
+    from roc_tpu.models.gcn import build_gcn
+    from roc_tpu.obs.events import configure
+    from roc_tpu.parallel.distributed import DistributedTrainer
+    from roc_tpu.train.trainer import TrainConfig, Trainer
+    p = str(tmp_path / "ev.jsonl")
+    ds = synthetic_dataset(300, 5, in_dim=8, num_classes=3, seed=1)
+    cfg = TrainConfig(aggr_impl=impl, verbose=False, symmetric=True)
+    try:
+        configure(jsonl_path=p, console=False)
+        if parts > 1:
+            tr = DistributedTrainer(build_gcn([8, 8, 3]), ds, parts, cfg)
+            rows, gctx = tr.pg.part_nodes, tr._gctx()
+        else:
+            tr = Trainer(build_gcn([8, 8, 3]), ds, cfg)
+            rows, gctx = ds.graph.num_nodes, tr.gctx
+    finally:
+        configure(jsonl_path=None)
+    res = [json.loads(line) for line in open(p)
+           if json.loads(line)["cat"] == "manifest"][-1]["resolved"]
+    assert res["aggr_impl"] == impl
+    if impl == "ell":
+        assert res["agg_window_rows"] == []
+        assert res["agg_carry_rows"] is None
+        return
+    from roc_tpu.ops.aggregate import scan_window_rows
+    carry = rows + 1
+    assert res["agg_carry_rows"] == carry
+    wins = res["agg_window_rows"]
+    table = ([m[2] for m in gctx.sect_meta] if impl == "sectioned"
+             else [gctx.flat8_win])
+    assert table and all(w > 0 and w % 128 == 0 for w in table)
+    # what a chunk step really reads and writes, not the raw span
+    assert wins == [scan_window_rows(w, carry) for w in table]
+
+
 def test_git_sha_resolves_here():
     from roc_tpu.obs.manifest import git_sha
     sha = git_sha()

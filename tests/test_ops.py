@@ -364,3 +364,378 @@ def test_sectioned_uint16_and_split_gather_match():
         _pytest.skip("graph too small to overflow uint8 sections")
     with _pytest.raises(ValueError, match="does not fit"):
         big.with_idx_dtype(np.uint8)
+
+
+# ---- the windowed chunk scan (ops/aggregate.py _scan_window_sum) ----
+# Values are small multiples of 1/4 so every partial sum is exact in
+# fp32 AND bf16: the scan and the segment reference must then agree to
+# rounding whatever order they add in.
+
+def _csr(rows):
+    """dst-major CSR from a list of per-row neighbour arrays."""
+    row_ptr = np.zeros(len(rows) + 1, dtype=np.int64)
+    np.cumsum([len(r) for r in rows], out=row_ptr[1:])
+    col = (np.concatenate([np.asarray(r, np.int64) for r in rows])
+           if row_ptr[-1] else np.zeros(0, np.int64))
+    return row_ptr, col.astype(np.int32)
+
+
+def _win_graph(case):
+    """(row_ptr, col_idx, num_rows, build kwargs) per window case —
+    each tall enough that its 128-row windows stay under half the
+    carry, where the scan really takes the window."""
+    rng = np.random.RandomState(7)
+    if case in ("hub", "hub_short"):
+        # row 17 gathers from every node: its sub-rows fill four or
+        # more seg_rows=4 chunks of each 128-source section.  The
+        # short twin's 128-row window is past half of its 193-row
+        # carry: the scan must fall back to the whole carry
+        n = 640 if case == "hub" else 192
+        rows = [np.unique(np.r_[v, rng.randint(0, n, 3)])
+                for v in range(n)]
+        rows[17] = np.arange(n)
+        return _csr(rows) + (n, dict(section_rows=128, seg_rows=4))
+    if case == "gaps":
+        # block-local edges only, every 7th row empty and rows
+        # 400..519 too: inside a section the destinations jump over
+        # rows with no neighbour there, once by more than 100 rows
+        n = 1200
+        rows = [(np.unique(rng.randint(v // 300 * 300,
+                                       v // 300 * 300 + 300, 5))
+                 if v % 7 and not 400 <= v < 520
+                 else np.zeros(0, np.int64)) for v in range(n)]
+        return _csr(rows) + (n, dict(section_rows=300, seg_rows=16))
+    if case == "trailing_pad":
+        n = 600
+        rows = [np.unique(np.r_[v, rng.randint(0, n, 4)])
+                for v in range(n)]
+        return _csr(rows) + (n, dict(section_rows=256, seg_rows=16,
+                                     chunks_plan=[120, 120, 120]))
+    raise AssertionError(case)
+
+
+def _spans(sub_dst, num_rows):
+    """Per chunk: last real destination - first + 1 (0: all padding)."""
+    real = np.where(sub_dst < num_rows, sub_dst, -1).max(axis=-1)
+    return np.maximum(real - sub_dst[..., 0] + 1, 0)
+
+
+def _win_inputs(num_src, F, dtype, seed=0):
+    rng = np.random.RandomState(seed)
+    feats = rng.randint(-4, 5, (num_src + 1, F)).astype(np.float32) / 4
+    feats[-1] = 0
+    return jnp.asarray(feats, dtype=dtype)
+
+
+def _edge_weights(sect, seed=1):
+    """Per-destination x per-source scales in {1/2, 1, 2} laid out
+    like the fused tables (SectionedEll.weight_tables)."""
+    rng = np.random.RandomState(seed)
+    d_dst = rng.choice([0.5, 1.0, 2.0], sect.num_rows)
+    d_src = rng.choice([0.5, 1.0, 2.0], sect.src_rows)
+    return d_dst, d_src, sect.weight_tables(d_dst, d_src)
+
+
+def _segment_ref(x, row_ptr, col, num_rows, d_dst=None, d_src=None):
+    dst = np.repeat(np.arange(num_rows), np.diff(row_ptr))
+    g = np.asarray(x, np.float32)[col]
+    if d_dst is not None:
+        g = g * (d_dst[dst] * d_src[col])[:, None]
+    out = np.zeros((num_rows, x.shape[1]), np.float32)
+    np.add.at(out, dst, g)
+    return out
+
+
+_TOL = {"float32": 1e-6, "bfloat16": 1e-2}
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["plain", "weighted"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["hub", "gaps", "trailing_pad",
+                                  "full_window", "flat_hub",
+                                  "short_carry"])
+def test_windowed_scan_matches_segment(case, dtype, weighted):
+    """The windowed scan == the segment reference, for every way a
+    chunk's destination run can look: (a) a hub row over three or more
+    chunks, (b) destinations that jump, (c) a trailing all-padding
+    chunk, (d) a window as tall as the carry, the flat layout's
+    single section, and a window past half a short carry (the scan
+    takes the whole carry)."""
+    from roc_tpu.core.ell import flat_sum_from_graph, sectioned_from_graph
+    from roc_tpu.ops.aggregate import (aggregate_ell_sect,
+                                       aggregate_flat_sum,
+                                       scan_window_rows)
+    flat = case == "flat_hub"
+    row_ptr, col, n, kw = _win_graph(
+        {"flat_hub": "hub", "full_window": "hub",
+         "short_carry": "hub_short"}.get(case, case))
+    if flat:
+        sect = flat_sum_from_graph(row_ptr, col, n, seg_rows=8)
+    else:
+        sect = sectioned_from_graph(row_ptr, col, n, **kw)
+    spans = [_spans(d, n) for d in sect.sub_dst]
+    carry = n + 1
+    if case in ("hub", "flat_hub", "short_carry"):
+        # the hub's sub-rows really do cross three or more chunks
+        assert max(int((d == 17).any(axis=1).sum())
+                   for d in sect.sub_dst) >= 3
+    if case == "gaps":
+        assert max(int(np.diff(d[d < n]).max())
+                   for d in sect.sub_dst) > 100
+    if case == "trailing_pad":
+        assert all(s[-1] == 0 for s in spans)
+    # the windowed path is what runs — or, on the short carry, not
+    assert all(scan_window_rows(w, carry) ==
+               (carry if case == "short_carry" else w)
+               for w in sect.win_rows)
+    x = _win_inputs(sect.src_rows, 5, dtype)
+    d_dst = d_src = w = None
+    if weighted:
+        d_dst, d_src, w = _edge_weights(sect)
+        w = tuple(jnp.asarray(a) for a in w)
+    sidx, sdst, meta = sect.as_jax()
+    if case == "full_window":
+        # a bare (start, size) and an explicit carry-high window are
+        # the same whole-carry scatter
+        got = aggregate_ell_sect(x, sidx, sdst,
+                                 tuple(m[:2] for m in meta), n, sect_w=w)
+        same = aggregate_ell_sect(
+            x, sidx, sdst, tuple(m[:2] + (carry,) for m in meta), n,
+            sect_w=w)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(same, np.float32))
+    elif flat:
+        got = aggregate_flat_sum(x, sidx[0], sdst[0], n,
+                                 flat_w=w[0] if weighted else None,
+                                 win_rows=sect.win_rows[0])
+    else:
+        got = aggregate_ell_sect(x, sidx, sdst, meta, n, sect_w=w)
+    assert got.dtype == x.dtype
+    want = _segment_ref(x, row_ptr, col, n, d_dst, d_src)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=_TOL[dtype], atol=_TOL[dtype])
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["plain", "weighted"])
+@pytest.mark.parametrize("layout", ["sectioned", "flat_sum"])
+@pytest.mark.parametrize("parts", [2, 4])
+def test_windowed_scan_stacked_parts(parts, layout, weighted):
+    """(e) stacked [P, ...] tables over unequal parts: every part scans
+    with the one window all parts agreed on, and still equals its own
+    segment reference."""
+    from roc_tpu.core.ell import (flat_sum_from_padded_parts,
+                                  sectioned_from_padded_parts)
+    from roc_tpu.core.partition import partition_graph
+    from roc_tpu.ops.aggregate import (aggregate_ell_sect,
+                                       aggregate_flat_sum)
+    from roc_tpu.parallel.distributed import remap_to_padded
+    g = add_self_edges(synthetic_graph(1400, 9, seed=5, power_law=True))
+    pg = partition_graph(g, parts, node_multiple=8, edge_multiple=8)
+    assert len(set(int(r) for r in pg.real_nodes)) > 1  # unequal parts
+    cols = remap_to_padded(pg)
+    src_rows = parts * pg.part_nodes
+    if layout == "flat_sum":
+        sect = flat_sum_from_padded_parts(
+            pg.part_row_ptr, cols, pg.real_nodes, pg.part_nodes,
+            src_rows=src_rows, seg_rows=16)
+    else:
+        sect = sectioned_from_padded_parts(
+            pg.part_row_ptr, cols, pg.real_nodes, pg.part_nodes,
+            src_rows=src_rows, section_rows=128, seg_rows=16)
+    assert all(2 * w <= pg.part_nodes + 1 for w in sect.win_rows)
+    x = _win_inputs(src_rows, 4, "float32")
+    w = None
+    if weighted:
+        rng = np.random.RandomState(3)
+        d_dst = rng.choice([0.5, 1.0, 2.0], (parts, pg.part_nodes))
+        d_src = rng.choice([0.5, 1.0, 2.0], src_rows)
+        w = sect.weight_tables(d_dst, d_src)
+    for p in range(parts):
+        n_real = int(pg.real_nodes[p])
+        ptr = pg.part_row_ptr[p, :n_real + 1].astype(np.int64)
+        want = np.zeros((pg.part_nodes, 4), np.float32)
+        want[:n_real] = _segment_ref(
+            x, ptr, cols[p][:ptr[-1]], n_real,
+            d_dst[p] if weighted else None,
+            d_src if weighted else None)
+        idx_p = tuple(jnp.asarray(a[p]) for a in sect.idx)
+        dst_p = tuple(jnp.asarray(a[p]) for a in sect.sub_dst)
+        w_p = tuple(jnp.asarray(a[p]) for a in w) if weighted else None
+        if layout == "flat_sum":
+            got = aggregate_flat_sum(
+                x, idx_p[0], dst_p[0], pg.part_nodes,
+                flat_w=w_p[0] if weighted else None,
+                win_rows=sect.win_rows[0])
+        else:
+            got = aggregate_ell_sect(x, idx_p, dst_p, sect.meta,
+                                     pg.part_nodes, sect_w=w_p)
+        np.testing.assert_allclose(np.asarray(got), want,
+                                   rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["plain", "weighted"])
+@pytest.mark.parametrize("layout", ["sectioned", "flat_sum"])
+def test_windowed_scan_grad_matches_segment(layout, weighted):
+    """Exact autodiff THROUGH the scan (what symmetric=False runs): the
+    slice / scatter-add / update-slice body transposes to the segment
+    reference's gradient."""
+    from roc_tpu.core.ell import flat_sum_from_graph, sectioned_from_graph
+    from roc_tpu.ops.aggregate import (aggregate_ell_sect,
+                                       aggregate_flat_sum)
+    row_ptr, col, n, kw = _win_graph("hub")
+    if layout == "flat_sum":
+        sect = flat_sum_from_graph(row_ptr, col, n, seg_rows=8)
+    else:
+        sect = sectioned_from_graph(row_ptr, col, n, **kw)
+    assert all(2 * w <= n + 1 for w in sect.win_rows)
+    x = _win_inputs(n, 3, "float32")
+    d_dst = d_src = w = None
+    if weighted:
+        d_dst, d_src, w = _edge_weights(sect)
+        w = tuple(jnp.asarray(a) for a in w)
+    sidx, sdst, meta = sect.as_jax()
+    cot = jnp.asarray(np.random.RandomState(5).randint(
+        -3, 4, (n, 3)).astype(np.float32))
+
+    def through_scan(v):
+        if layout == "flat_sum":
+            out = aggregate_flat_sum(v, sidx[0], sdst[0], n,
+                                     flat_w=w[0] if weighted else None,
+                                     win_rows=sect.win_rows[0])
+        else:
+            out = aggregate_ell_sect(v, sidx, sdst, meta, n, sect_w=w)
+        return (out * cot).sum()
+
+    got = np.asarray(jax.grad(through_scan)(x))
+    # d/dx sum(cot * A x) = A^T cot, the dummy row included (zero)
+    dst = np.repeat(np.arange(n), np.diff(row_ptr))
+    scale = (d_dst[dst] * d_src[col])[:, None] if weighted else 1.0
+    want = np.zeros((n + 1, 3), np.float32)
+    np.add.at(want, col, np.asarray(cot)[dst] * scale)
+    np.testing.assert_allclose(got[:n], want[:n], rtol=1e-6, atol=1e-6)
+
+
+# ---- the table's window (core/ell.py SectionedEll.win_rows) ----
+
+@pytest.mark.parametrize("case", ["hub", "gaps", "trailing_pad"])
+@pytest.mark.parametrize("builder", ["native", "numpy"])
+def test_win_rows_bounds_every_chunk(case, builder, monkeypatch):
+    """win_rows covers every chunk's real span and stays below the
+    next rounding step above the largest; native and numpy builders
+    agree on it; narrowing the index dtype keeps it."""
+    import roc_tpu.core.ell as E
+    from roc_tpu import native
+    if builder == "native" and not native.available():
+        pytest.skip("native library unavailable")
+    row_ptr, col, n, kw = _win_graph(case)
+    other = E.sectioned_from_graph(row_ptr, col, n, **kw)
+    if builder == "numpy":
+        monkeypatch.setattr(native, "available", lambda: False)
+    sect = E.sectioned_from_graph(row_ptr, col, n, **kw)
+    assert sect.win_rows == other.win_rows
+    assert len(sect.win_rows) == len(sect.sub_dst)
+    for w, d in zip(sect.win_rows, sect.sub_dst):
+        largest = int(_spans(d, n).max())
+        assert largest <= w < largest + E.WIN_ROWS_MULTIPLE
+        assert w % E.WIN_ROWS_MULTIPLE == 0
+    assert sect.meta == tuple(zip(sect.sec_starts, sect.sec_sizes,
+                                  sect.win_rows))
+    assert sect.with_idx_dtype(np.uint16).win_rows == sect.win_rows
+
+
+@pytest.mark.parametrize("parts", [2, 4])
+def test_win_rows_stacked_is_max_over_parts(parts):
+    """SPMD shapes must agree: the stacked table's window is the
+    largest any part needs, per section."""
+    from roc_tpu.core.ell import (chunk_window_rows,
+                                  sectioned_from_padded_parts)
+    from roc_tpu.core.partition import partition_graph
+    from roc_tpu.parallel.distributed import remap_to_padded
+    g = add_self_edges(synthetic_graph(900, 8, seed=9, power_law=True))
+    pg = partition_graph(g, parts, node_multiple=8, edge_multiple=8)
+    sect = sectioned_from_padded_parts(
+        pg.part_row_ptr, remap_to_padded(pg), pg.real_nodes,
+        pg.part_nodes, src_rows=parts * pg.part_nodes,
+        section_rows=256, seg_rows=16)
+    for s, d in enumerate(sect.sub_dst):
+        per_part = [chunk_window_rows(d[p], pg.part_nodes)
+                    for p in range(parts)]
+        assert sect.win_rows[s] == max(per_part)
+        assert d.shape[0] == parts
+    # not vacuous: at least one section's parts differ
+    assert any(len({chunk_window_rows(d[p], pg.part_nodes)
+                    for p in range(parts)}) > 1
+               for d in sect.sub_dst)
+
+
+# ---- the program's shape: the carry is only sliced and updated ----
+
+def test_scan_window_rows_rule():
+    """The one rule for when the window pays (measured on the v5e):
+    up to half the carry's height; none known, or taller, is the
+    whole carry."""
+    from roc_tpu.ops.aggregate import scan_window_rows
+    assert scan_window_rows(0, 1000) == 1000
+    assert scan_window_rows(128, 1000) == 128
+    assert scan_window_rows(500, 1000) == 500
+    assert scan_window_rows(512, 1000) == 1000
+    assert scan_window_rows(4096, 1000) == 1000
+
+
+@pytest.mark.parametrize("layout", ["sectioned", "flat_sum",
+                                    "sectioned_short"])
+def test_scan_program_touches_carry_only_by_slices(layout):
+    """In the lowered program the scatter's operand is the
+    [win_rows, F] window and nothing but dynamic_slice /
+    dynamic_update_slice (and the scan's own plumbing) produces or
+    consumes a carry-shaped tensor inside the loop — a later edit
+    cannot bring the whole-carry scatter back unnoticed.  On the short
+    carry (window past half of it) the same body IS the whole-carry
+    scatter: the slice and the write-back are of the carry itself."""
+    import re
+    from roc_tpu.core.ell import flat_sum_from_graph, sectioned_from_graph
+    from roc_tpu.ops.aggregate import (aggregate_ell_sect,
+                                       aggregate_flat_sum,
+                                       scan_window_rows)
+    short = layout == "sectioned_short"
+    row_ptr, col, n, kw = _win_graph("hub_short" if short else "hub")
+    F = 6
+    if layout == "flat_sum":
+        sect = flat_sum_from_graph(row_ptr, col, n, seg_rows=8)
+        sidx, sdst, _ = sect.as_jax()
+        fn = lambda v: aggregate_flat_sum(v, sidx[0], sdst[0], n,
+                                          win_rows=sect.win_rows[0])
+    else:
+        sect = sectioned_from_graph(row_ptr, col, n, **kw)
+        sidx, sdst, meta = sect.as_jax()
+        fn = lambda v: aggregate_ell_sect(v, sidx, sdst, meta, n)
+    wins = {scan_window_rows(w, n + 1) for w in sect.win_rows}
+    assert wins == ({n + 1} if short else set(sect.win_rows))
+    text = jax.jit(fn).lower(_win_inputs(n, F, "float32")).as_text()
+    carry = f"tensor<{n + 1}x{F}xf32>"
+    scatters = re.findall(
+        r'"stablehlo\.scatter"\((%\w+),.*?\}\) : \(([^,]*),[^)]*\) -> '
+        r'(tensor<[^>]*>)', text, flags=re.S)
+    assert len(scatters) == len(sect.idx)
+    for _, operand, result in scatters:
+        assert operand.strip() == result
+        assert (result == carry) == short
+        rows = int(re.match(r"tensor<(\d+)x", result).group(1))
+        assert rows in wins and result.endswith(f"x{F}xf32>")
+    # which ops yield a carry-shaped value anywhere in the program:
+    # its zero init, the loop and its outlined body, and the in-place
+    # window write-back — nothing that computes on all of it
+    makers = {re.search(r"(stablehlo|func)\.([a-z_]+)", ln).group(2)
+              for ln in text.splitlines()
+              if re.search(r"= \"?(stablehlo|func)\.", ln)
+              and ln.rstrip().endswith(carry)}
+    plumbing = {"broadcast_in_dim", "while", "call",
+                "dynamic_update_slice"}
+    assert makers == (plumbing | {"dynamic_slice"}
+                      if short else plumbing), makers
+    windows = re.findall(r"stablehlo\.dynamic_slice %\w+.*-> "
+                         rf"tensor<(\d+)x{F}xf32>", text)
+    assert {int(w) for w in windows} == wins
