@@ -29,7 +29,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 import numpy as np
 
-from ..obs.scopes import HALO_SCOPE, LOSS_SCOPE, op_scope
+from ..obs.scopes import (ATTN_SCORES_SCOPE, HALO_SCOPE, LOSS_SCOPE,
+                          op_scope)
 from ..ops import dense
 from ..parallel import PARTS_AXIS
 from ..ops.aggregate import (aggregate, aggregate_ell, aggregate_ell_max,
@@ -184,6 +185,45 @@ class GraphContext:
         return {"agg_window_rows": [scan_window_rows(w, carry)
                                     for w in wins],
                 "agg_carry_rows": carry if wins else None}
+
+    def attention_plan(self, ops, ell_idx=None, flat8_idx=None) -> dict:
+        """What each attention op of ``ops`` (``Model._ops``) runs on —
+        the run manifest's ``resolved`` carries it beside
+        :meth:`agg_window`: heads, head width, the layout, forward
+        passes over the edge tables (the bucketed layout reduces a row
+        in one; the flat layout scans once for the row max, once for
+        the numerator — once a ``resolve_dh_chunk`` slice — and once
+        more for the denominator when sliced), table slots a pass
+        (stored edges + padding, per partition), and the height of the
+        fp32 carry a scan step rewrites (None: the bucketed layout
+        carries nothing).  Empty for a model without attention.  The
+        distributed trainer, whose tables live outside its context,
+        hands them in (stacked: the shapes' trailing axes are read)."""
+        from ..ops.attention import resolve_dh_chunk
+        flat8 = self.aggr_impl == "attn_flat8"
+        ell_idx = self.ell_idx if ell_idx is None else ell_idx
+        flat8_idx = self.flat8_idx if flat8_idx is None else flat8_idx
+        out = []
+        for i, op in enumerate(ops):
+            if op.kind != "gat":
+                continue
+            heads = int(op.attrs.get("heads", 1))
+            dh = op.dim // heads
+            if flat8:
+                chunk = resolve_dh_chunk(self.num_rows, heads, dh)
+                passes = 2 if chunk is None else 2 + -(-dh // chunk)
+                slots = int(np.prod(flat8_idx.shape[-3:]))
+            else:
+                passes = 1
+                slots = sum(int(np.prod(a.shape[-2:]))
+                            for a in ell_idx)
+            out.append({"op": i, "heads": heads, "head_width": dh,
+                        "layout": self.aggr_impl,
+                        "edge_passes": passes,
+                        "padded_slots_per_pass": slots,
+                        "carry_rows": self.num_rows + 1 if flat8
+                        else None})
+        return {"attention": out} if out else {}
 
     def _gathered_with_zero(self, x: jax.Array) -> jax.Array:
         """Halo exchange (under its own ``roc.halo`` scope, inside the
@@ -389,10 +429,11 @@ class GraphContext:
         """Additive-attention aggregation (ops/attention.py): per
         destination row, softmax over its neighbors of
         ``LeakyReLU(a_src.h_j + a_dst.h_i)`` weighting the neighbor
-        sum.  Needs the ELL tables (every row's neighborhood in one
-        bucket makes the edge softmax exact); gradients are plain
-        autodiff — attention is nonlinear, the symmetric
-        kernel-reuse trick does not apply."""
+        sum, per head.  Needs the ELL tables (every row's neighborhood
+        in one bucket row) or the flat8 tables (a row's sub-rows
+        combined by sorted scatters); gradients are plain autodiff —
+        attention is nonlinear, the symmetric kernel-reuse trick does
+        not apply."""
         if self.halo == "ring":
             raise NotImplementedError(
                 "attention is not supported with halo='ring' (the ring "
@@ -416,13 +457,20 @@ class GraphContext:
             a_dst = a_dst[None, :]
         K, dh = a_src.shape
         full = self._gathered_with_zero(x)
-        fullr = full.reshape(full.shape[0], K, dh)
-        s_full = jnp.einsum("gkd,kd->gk", fullr,
-                            a_src.astype(full.dtype))   # [G+1, K]
-        d = jnp.einsum("vkd,kd->vk", x.reshape(x.shape[0], K, dh),
-                       a_dst.astype(x.dtype))           # [num_rows, K]
-        d_local = jnp.concatenate(
-            [d, jnp.zeros((1, K), dtype=d.dtype)])
+        with jax.named_scope(ATTN_SCORES_SCOPE):
+            # the K scores a vertex are kept in fp32 whatever the
+            # compute dtype: they feed exp(), where a bf16 rounding of
+            # a score of magnitude 8 is a 3% error in the edge's weight
+            fullr = full.reshape(full.shape[0], K, dh)
+            s_full = jnp.einsum(
+                "gkd,kd->gk", fullr, a_src.astype(full.dtype),
+                preferred_element_type=jnp.float32)     # [G+1, K]
+            d = jnp.einsum(
+                "vkd,kd->vk", x.reshape(x.shape[0], K, dh),
+                a_dst.astype(x.dtype),
+                preferred_element_type=jnp.float32)     # [num_rows, K]
+            d_local = jnp.concatenate(
+                [d, jnp.zeros((1, K), dtype=d.dtype)])
         if flat8:
             return gat_aggregate_flat8(full, s_full, d_local,
                                        self.flat8_idx, self.flat8_dst,
@@ -555,8 +603,9 @@ class Model:
         self._loss_op: Optional[int] = None
 
     def uses_attention(self) -> bool:
-        """True when the op list contains a gat op — such models need
-        the ELL tables (trainers force aggr_impl='ell')."""
+        """True when the op list contains a gat op — such models run
+        on the ELL tables or the flat8 tables (train/trainer.py
+        resolve_attention_impl picks by edge count)."""
         return any(op.kind == "gat" for op in self._ops)
 
     def uses_max_aggregation(self) -> bool:

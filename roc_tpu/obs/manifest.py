@@ -65,9 +65,12 @@ def run_manifest(config=None, dataset=None, model=None,
                  agg_window: Optional[Dict[str, Any]] = None,
                  console: bool = True) -> Dict[str, Any]:
     """Assemble + emit the ``manifest`` event; returns the fields.
-    ``agg_window`` (``GraphContext.agg_window()``) joins ``resolved``:
-    the chunk scan's window rows per section and the carry's height,
-    so a run says how far the windowed scatter engaged.
+    ``agg_window`` (``GraphContext.agg_window()``, and with it
+    ``GraphContext.attention_plan()``) joins ``resolved``: the chunk
+    scan's window rows per section and the carry's height, so a run
+    says how far the windowed scatter engaged; one ``attention`` entry
+    per attention op (heads, head width, layout, passes over the edge
+    tables, slots a pass, carry rows).
 
     Everything is best-effort: a missing backend or detached checkout
     degrades to nulls, never to an exception at trainer setup."""

@@ -26,6 +26,17 @@ map (``ObservedJit.instruction_scopes``) that joins the two.
 | ``allreduce`` | the gradient / loss / metric ``psum`` across partitions | ``roc.allreduce`` |
 
 ``<i>`` is the op's index in ``Model._ops``, two digits.
+
+An attention op (``gat``) splits its ``roc.agg.op<i>`` into three
+phases, each a scope nested inside it (``ops/attention.py``); the
+class stays ``agg``, so whatever reads classes sees one op as before,
+and :func:`parse_op_phase` gives the finer rows:
+
+| phase | what runs under it | name |
+| --- | --- | --- |
+| ``scores`` | ``s = a_src . z``, ``t = a_dst . z``, their per-edge gather, LeakyReLU, the padding mask | ``roc.attn.scores`` |
+| ``stats`` | the softmax statistics: row max, ``exp``, denominator | ``roc.attn.stats`` |
+| ``gather`` | the feature gather, the weighted sum (numerator) and the division | ``roc.attn.gather`` |
 """
 
 from __future__ import annotations
@@ -41,12 +52,18 @@ CLASSES = (AGG, HALO, DENSE, LOSS, OPT, ALLREDUCE)
 # ``dense``
 AGG_KINDS = ("scatter_gather", "fused_aggregate", "gat")
 
+ATTN_PHASES = ("scores", "stats", "gather")
+
 HALO_SCOPE = PREFIX + HALO
 LOSS_SCOPE = PREFIX + LOSS
 OPT_SCOPE = PREFIX + OPT
 ALLREDUCE_SCOPE = PREFIX + ALLREDUCE
+# entered inside an attention op's own ``roc.agg.op<i>``
+ATTN_SCORES_SCOPE, ATTN_STATS_SCOPE, ATTN_GATHER_SCOPE = (
+    f"{PREFIX}attn.{phase}" for phase in ATTN_PHASES)
 
 _SCOPE = re.compile(r"roc\.(" + "|".join(CLASSES) + r")(?:\.op(\d+))?")
+_PHASE = re.compile(r"roc\.attn\.(" + "|".join(ATTN_PHASES) + r")")
 _MODULE = re.compile(r"^HloModule\s+([^\s,]+)")
 # "  ROOT %fusion.7 = f32[8]{0} fusion(...), ..., metadata={op_name="..."}"
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=%]+)\s+=\s")
@@ -75,6 +92,19 @@ def parse_op_name(op_name: str
     index = next((int(i) for _, i in reversed(found) if i), None)
     return (found[-1][0], index,
             "bwd" if "transpose(" in op_name else "fwd")
+
+
+def parse_op_phase(op_name: str
+                   ) -> Optional[Tuple[str, Optional[int], str, str]]:
+    """``("agg", op index, phase, "fwd" | "bwd")`` of an operation
+    inside an attention phase (the innermost ``roc.attn.`` component),
+    None for every other operation — one under ``roc.halo`` inside an
+    attention op included: its class is not ``agg``."""
+    key = parse_op_name(op_name)
+    phases = _PHASE.findall(op_name)
+    if key is None or key[0] != AGG or not phases:
+        return None
+    return (AGG, key[1], phases[-1], key[2])
 
 
 def parse_program_text(text: str) -> Dict[str, Any]:

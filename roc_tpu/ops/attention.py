@@ -1,13 +1,16 @@
-"""Graph attention aggregation (GAT) on the degree-bucketed ELL layout.
+"""Graph attention aggregation (GAT): the edge softmax and its weighted
+neighbor sum, on the degree-bucketed ELL layout and on the uniform
+width-8 flat layout.
 
 The reference implements only unweighted CSR sum aggregation
 (``scattergather_kernel.cu:20-76``); attention is the framework's
 TPU-native extension for the GAT model family (Velickovic et al.,
-ICLR'18 — additive single-head attention):
+ICLR'18 — additive attention, K heads side by side on the feature
+axis, each over its own ``dh``-wide slice):
 
-    e_ij   = LeakyReLU(a_src . h_j + a_dst . h_i)   for j in N(i)
-    alpha  = softmax_j(e_ij)
-    out_i  = sum_j alpha_ij h_j
+    e_ij^k   = LeakyReLU(a_src^k . h_j^k + a_dst^k . h_i^k)  for j in N(i)
+    alpha^k  = softmax_j(e_ij^k)
+    out_i^k  = sum_j alpha_ij^k h_j^k
 
 The ELL layout makes the edge softmax *exact and scatter-free*: every
 row's whole neighborhood lives in ONE bucket row (bucket width >= the
@@ -20,6 +23,13 @@ would require a cross-section softmax reduction (use ``ell``).
 
 Gradients are plain autodiff: attention is nonlinear in both inputs,
 so the reference's symmetric kernel-reuse trick does not apply.
+
+Every operation sits under one of three phase scopes (obs/scopes.py
+``ATTN_*_SCOPE``), nested inside the model op's ``roc.agg.op<i>``:
+``scores`` (the per-vertex ``s``/``t`` projections, their per-edge
+gather, LeakyReLU, the padding mask), ``stats`` (row max, ``exp``,
+denominator) and ``gather`` (the feature gather, the weighted sum —
+the numerator — and the division).
 """
 
 from __future__ import annotations
@@ -29,6 +39,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..obs.scopes import (ATTN_GATHER_SCOPE as _GATHER,
+                          ATTN_SCORES_SCOPE as _SCORES,
+                          ATTN_STATS_SCOPE as _STATS)
 
 
 def gat_aggregate_ell(full: jax.Array, s_full: jax.Array,
@@ -71,20 +85,23 @@ def gat_aggregate_ell(full: jax.Array, s_full: jax.Array,
     def seg_out(idx_seg, rid_seg):
         # scores softmax in fp32 for stability regardless of compute
         # dtype (bf16 exp over a wide range loses the tail)
-        e = (s_full[idx_seg].astype(jnp.float32)
-             + d_local[rid_seg].astype(jnp.float32)[:, None, :])
-        e = jax.nn.leaky_relu(e, neg_slope)              # [r, w, K]
-        valid = (idx_seg != dummy)[:, :, None]
-        e = jnp.where(valid, e, neg)
-        m = jnp.max(e, axis=1, keepdims=True)
-        # all-padding rows have m == -inf; zero them via the guard
-        w = jnp.where(valid, jnp.exp(e - jnp.where(
-            jnp.isfinite(m), m, 0.0)), 0.0)
-        den = jnp.maximum(w.sum(axis=1, keepdims=True), 1e-20)
-        alpha = (w / den).astype(full.dtype)             # [r, w, K]
-        g = full[idx_seg].reshape(*idx_seg.shape, K, F // K)
-        return jnp.einsum("rwk,rwkd->rkd", alpha,
-                          g).reshape(idx_seg.shape[0], F)
+        with jax.named_scope(_SCORES):
+            e = (s_full[idx_seg].astype(jnp.float32)
+                 + d_local[rid_seg].astype(jnp.float32)[:, None, :])
+            e = jax.nn.leaky_relu(e, neg_slope)          # [r, w, K]
+            valid = (idx_seg != dummy)[:, :, None]
+            e = jnp.where(valid, e, neg)
+        with jax.named_scope(_STATS):
+            m = jnp.max(e, axis=1, keepdims=True)
+            # all-padding rows have m == -inf; zero them via the guard
+            w = jnp.where(valid, jnp.exp(e - jnp.where(
+                jnp.isfinite(m), m, 0.0)), 0.0)
+            den = jnp.maximum(w.sum(axis=1, keepdims=True), 1e-20)
+        with jax.named_scope(_GATHER):
+            alpha = (w / den).astype(full.dtype)         # [r, w, K]
+            g = full[idx_seg].reshape(*idx_seg.shape, K, F // K)
+            return jnp.einsum("rwk,rwkd->rkd", alpha,
+                              g).reshape(idx_seg.shape[0], F)
 
     outs = []
     for idx, rid in zip(ell_idx, ell_row_id):
@@ -123,9 +140,10 @@ def gat_aggregate_ell(full: jax.Array, s_full: jax.Array,
                                (idx_p.reshape(segs, seg_rows, W),
                                 rid_p.reshape(segs, seg_rows)))
         outs.append(segs_out.reshape(Rp, F)[:R])
-    zero = jnp.zeros((1, F), dtype=full.dtype)
-    cat = jnp.concatenate(outs + [zero], axis=0)
-    return cat[ell_row_pos]
+    with jax.named_scope(_GATHER):
+        zero = jnp.zeros((1, F), dtype=full.dtype)
+        cat = jnp.concatenate(outs + [zero], axis=0)
+        return cat[ell_row_pos]
 
 
 def resolve_dh_chunk(num_rows: int, heads: int, dh: int,
@@ -196,56 +214,77 @@ def gat_aggregate_flat8(full: jax.Array, s_full: jax.Array,
     neg = jnp.asarray(-jnp.inf, dtype=jnp.float32)
 
     def scores(idx_ch, dst_ch):
-        e = (s_full[idx_ch].astype(jnp.float32)
-             + d_local[dst_ch].astype(jnp.float32)[:, None, :])
-        e = jax.nn.leaky_relu(e, neg_slope)            # [seg, 8, K]
-        valid = (idx_ch != dummy)[:, :, None]
-        return jnp.where(valid, e, neg), valid
+        with jax.named_scope(_SCORES):
+            e = (s_full[idx_ch].astype(jnp.float32)
+                 + d_local[dst_ch].astype(jnp.float32)[:, None, :])
+            e = jax.nn.leaky_relu(e, neg_slope)        # [seg, 8, K]
+            valid = (idx_ch != dummy)[:, :, None]
+            return jnp.where(valid, e, neg), valid
+
+    def weights(idx_ch, dst_ch):
+        e, valid = scores(idx_ch, dst_ch)
+        with jax.named_scope(_STATS):
+            return jnp.where(
+                valid, jnp.exp(e - rowmax[dst_ch][:, None, :]), 0.0)
 
     def pass1(rm, ch):
         e, _ = scores(*ch)
-        m8 = jnp.max(e, axis=1)                        # [seg, K]
-        return rm.at[ch[1]].max(m8, indices_are_sorted=True), None
+        with jax.named_scope(_STATS):
+            m8 = jnp.max(e, axis=1)                    # [seg, K]
+            return rm.at[ch[1]].max(m8, indices_are_sorted=True), None
 
-    rm0 = jnp.full((num_rows + 1, K), -jnp.inf, dtype=jnp.float32)
+    with jax.named_scope(_STATS):
+        rm0 = jnp.full((num_rows + 1, K), -jnp.inf, dtype=jnp.float32)
     rowmax, _ = lax.scan(jax.checkpoint(pass1), rm0, (f8_idx, f8_dst))
     # rows with no finite score (no neighbors) shift by 0; softmax is
     # shift-invariant so the max carries no gradient
-    rowmax = lax.stop_gradient(
-        jnp.where(jnp.isfinite(rowmax), rowmax, 0.0))
+    with jax.named_scope(_STATS):
+        rowmax = lax.stop_gradient(
+            jnp.where(jnp.isfinite(rowmax), rowmax, 0.0))
 
     dh = F // K
+
+    def add_den(den, w, dst_ch):
+        with jax.named_scope(_STATS):
+            return den.at[dst_ch].add(w.sum(axis=1),
+                                      indices_are_sorted=True)
+
+    def add_num(num, w, src, idx_ch, dst_ch):
+        """``num[dst] += sum_w w * src[idx]`` for one chunk, per head,
+        ``src`` [G+1, K*dc].  The numerator carry stays fp32: a hub
+        row of degree d receives d/8 sequential scatter-adds of
+        full-magnitude partials — accumulating those in bf16 would
+        lose low-order bits every add (the bucket path reduces a whole
+        row in one fp32-MXU einsum, and this path must match its
+        numerics)."""
+        with jax.named_scope(_GATHER):
+            g = src[idx_ch].reshape(*idx_ch.shape, K, -1)
+            part = jnp.einsum("swk,swkd->skd", w.astype(src.dtype), g,
+                              preferred_element_type=jnp.float32
+                              ).reshape(idx_ch.shape[0], src.shape[1])
+            return num.at[dst_ch].add(part, indices_are_sorted=True)
+
+    def divide(num, den):
+        with jax.named_scope(_GATHER):
+            den = jnp.maximum(den[:num_rows], 1e-20)
+            numr = num[:num_rows].reshape(num_rows, K, -1)
+            return (numr / den[:, :, None]).astype(full.dtype)
+
+    with jax.named_scope(_STATS):
+        den0 = jnp.zeros((num_rows + 1, K), dtype=jnp.float32)
     if dh_chunk is None or dh_chunk >= dh:
         def pass2(carry, ch):
             num, den = carry
             idx_ch, dst_ch = ch
-            e, valid = scores(idx_ch, dst_ch)
-            w = jnp.where(valid,
-                          jnp.exp(e - rowmax[dst_ch][:, None, :]),
-                          0.0)                         # [seg, 8, K]
-            den = den.at[dst_ch].add(w.sum(axis=1),
-                                     indices_are_sorted=True)
-            g = full[idx_ch].reshape(*idx_ch.shape, K, dh)
-            # numerator carry stays fp32: a hub row of degree d
-            # receives d/8 sequential scatter-adds of full-magnitude
-            # partials — accumulating those in bf16 would lose
-            # low-order bits every add (the bucket path reduces a
-            # whole row in one fp32-MXU einsum, and this path must
-            # match its numerics)
-            part = jnp.einsum("swk,swkd->skd", w.astype(full.dtype),
-                              g, preferred_element_type=jnp.float32
-                              ).reshape(idx_ch.shape[0], F)
-            num = num.at[dst_ch].add(part, indices_are_sorted=True)
-            return (num, den), None
+            w = weights(idx_ch, dst_ch)                # [seg, 8, K]
+            return (add_num(num, w, full, idx_ch, dst_ch),
+                    add_den(den, w, dst_ch)), None
 
-        num0 = jnp.zeros((num_rows + 1, F), dtype=jnp.float32)
-        den0 = jnp.zeros((num_rows + 1, K), dtype=jnp.float32)
+        with jax.named_scope(_GATHER):
+            num0 = jnp.zeros((num_rows + 1, F), dtype=jnp.float32)
         (num, den), _ = lax.scan(jax.checkpoint(pass2), (num0, den0),
                                  (f8_idx, f8_dst))
-        den = jnp.maximum(den[:num_rows], 1e-20)
-        numr = num[:num_rows].reshape(num_rows, K, dh)
-        out = (numr / den[:, :, None]).astype(full.dtype)
-        return out.reshape(num_rows, F)
+        return divide(num, den).reshape(num_rows, F)
 
     # dh-chunked numerator (resolve_dh_chunk): the fused pass2 carry
     # is [num_rows+1, F] fp32 and autodiff doubles it — the products-
@@ -255,41 +294,26 @@ def gat_aggregate_flat8(full: jax.Array, s_full: jax.Array,
     # and scatter-add order match the fused form (tested to <=3e-7;
     # XLA lowers non-dividing slice widths slightly differently).
     def passden(den, ch):
-        e, valid = scores(*ch)
-        w = jnp.where(valid,
-                      jnp.exp(e - rowmax[ch[1]][:, None, :]), 0.0)
-        return den.at[ch[1]].add(w.sum(axis=1),
-                                 indices_are_sorted=True), None
+        return add_den(den, weights(*ch), ch[1]), None
 
-    den0 = jnp.zeros((num_rows + 1, K), dtype=jnp.float32)
     den, _ = lax.scan(jax.checkpoint(passden), den0,
                       (f8_idx, f8_dst))
-    den = jnp.maximum(den[:num_rows], 1e-20)
     fullr = full.reshape(full.shape[0], K, dh)
     outs = []
     for lo in range(0, dh, dh_chunk):
         dc = min(dh_chunk, dh - lo)
         # materialize the slice once per chunk ([G+1, K*dc]) so the
         # scan gathers dc-wide rows, not F-wide ones
-        full_c = lax.slice_in_dim(fullr, lo, lo + dc, axis=2) \
-            .reshape(full.shape[0], K * dc)
+        with jax.named_scope(_GATHER):
+            full_c = lax.slice_in_dim(fullr, lo, lo + dc, axis=2) \
+                .reshape(full.shape[0], K * dc)
+            num0 = jnp.zeros((num_rows + 1, K * dc), dtype=jnp.float32)
 
-        def pass2c(num, ch, full_c=full_c, dc=dc):
-            idx_ch, dst_ch = ch
-            e, valid = scores(idx_ch, dst_ch)
-            w = jnp.where(valid,
-                          jnp.exp(e - rowmax[dst_ch][:, None, :]),
-                          0.0)
-            g = full_c[idx_ch].reshape(*idx_ch.shape, K, dc)
-            part = jnp.einsum("swk,swkd->skd", w.astype(full.dtype),
-                              g, preferred_element_type=jnp.float32
-                              ).reshape(idx_ch.shape[0], K * dc)
-            return num.at[dst_ch].add(part,
-                                      indices_are_sorted=True), None
+        def pass2c(num, ch, full_c=full_c):
+            return add_num(num, weights(*ch), full_c, *ch), None
 
-        num0 = jnp.zeros((num_rows + 1, K * dc), dtype=jnp.float32)
         num, _ = lax.scan(jax.checkpoint(pass2c), num0,
                           (f8_idx, f8_dst))
-        numr = num[:num_rows].reshape(num_rows, K, dc)
-        outs.append((numr / den[:, :, None]).astype(full.dtype))
-    return jnp.concatenate(outs, axis=2).reshape(num_rows, F)
+        outs.append(divide(num, den))
+    with jax.named_scope(_GATHER):
+        return jnp.concatenate(outs, axis=2).reshape(num_rows, F)

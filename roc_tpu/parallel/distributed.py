@@ -813,7 +813,12 @@ class DistributedTrainer:
                             "bd_occupancy": list(
                                 self.data.bd_occupancy),
                             "partition": self._partition_stats},
-                     agg_window=self._gctx().agg_window(),
+                     agg_window={
+                         **self._gctx().agg_window(),
+                         **self._gctx().attention_plan(
+                             model._ops, ell_idx=self.data.ell_idx,
+                             flat8_idx=next(iter(self.data.sect_idx),
+                                            None))},
                      console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
         # annotate=True routes every phase span through

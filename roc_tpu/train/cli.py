@@ -54,6 +54,18 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
                     help="attention heads for --model gat (hidden "
                          "dims must divide by it; output layer stays "
                          "single-head)")
+    ap.add_argument("--skip", action="store_true",
+                    help="for --model gat: add a bias-free linear map "
+                         "of each layer's input to its attention "
+                         "output (DGL GATConv's res_fc), output layer "
+                         "included")
+    ap.add_argument("--act", choices=["elu", "relu"], default=None,
+                    help="for --model gat: hidden-layer activation "
+                         "(default elu, the paper's)")
+    ap.add_argument("--input-dropout", type=float, default=None,
+                    help="for --model gat: dropout rate on the raw "
+                         "features (default: -dropout, as on every "
+                         "other layer)")
     ap.add_argument("--hops", type=int, default=None,
                     help="for --model sgc/appnp: propagation depth k "
                          "(sgc: logits = softmax(S^k X W), default 2; "
@@ -371,6 +383,11 @@ def main(argv: Optional[List[str]] = None,
         print("error: --heads applies to --model gat only",
               file=sys.stderr)
         return 2
+    if args.model != "gat" and (args.skip or args.act is not None
+                                or args.input_dropout is not None):
+        print("error: --skip/--act/--input-dropout apply to --model "
+              "gat only", file=sys.stderr)
+        return 2
     if args.learn_eps and args.model != "gin":
         print("error: --learn-eps applies to --model gin only",
               file=sys.stderr)
@@ -432,6 +449,11 @@ def main(argv: Optional[List[str]] = None,
             print(f"error: hidden dims {bad} not divisible by "
                   f"--heads {args.heads}", file=sys.stderr)
             return 2
+        if args.input_dropout is not None and \
+                not 0.0 <= args.input_dropout < 1.0:
+            print("error: --input-dropout must be in [0, 1)",
+                  file=sys.stderr)
+            return 2
 
     if args.file:
         ds = load_dataset(args.file, in_dim=layers[0],
@@ -459,7 +481,11 @@ def main(argv: Optional[List[str]] = None,
 
     from ..models import model_builders
     build = model_builders()
-    kwargs = {"heads": args.heads} if args.model == "gat" else {}
+    kwargs = {}
+    if args.model == "gat":
+        kwargs = {"heads": args.heads, "skip": args.skip,
+                  "activation": args.act or "elu",
+                  "input_dropout": args.input_dropout}
     if args.model == "gin" and args.learn_eps:
         kwargs["learn_eps"] = True
     if args.model in ("sgc", "appnp"):

@@ -1074,7 +1074,9 @@ class Trainer:
         from ..obs.manifest import run_manifest
         run_manifest(config=self.config, dataset=dataset, model=model,
                      extra={"modeled_step_bytes": self._modeled_bytes},
-                     agg_window=self.gctx.agg_window(),
+                     agg_window={
+                         **self.gctx.agg_window(),
+                         **self.gctx.attention_plan(model._ops)},
                      console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
         # annotate=True routes every phase span through

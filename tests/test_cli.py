@@ -43,6 +43,14 @@ def test_checkpoint_resume_eval_only(tmp_path, capsys):
     (["--halo", "ring", "-layers", "8-8-3"], "--parts"),
     (["--model", "gcn", "--learn-eps", "-layers", "8-8-3"],
      "--learn-eps applies"),
+    (["--model", "gcn", "--skip", "-layers", "8-8-3"],
+     "--skip/--act/--input-dropout apply"),
+    (["--model", "sage", "--act", "elu", "-layers", "8-8-3"],
+     "--skip/--act/--input-dropout apply"),
+    (["--model", "gcn", "--input-dropout", "0.1", "-layers", "8-8-3"],
+     "--skip/--act/--input-dropout apply"),
+    (["--model", "gat", "--input-dropout", "1.0", "-layers", "8-8-3"],
+     "--input-dropout must be"),
 ])
 def test_flag_validation_fails_fast(argv, msg, capsys):
     assert _run(argv) == 2
@@ -124,6 +132,35 @@ def test_gat_mixed_distributed(capsys):
                "--eval-every", "2"])
     assert rc == 0
     assert "[INFER]" in capsys.readouterr().out
+
+
+def test_gat_published_flags_reach_the_model(capsys, tmp_path):
+    """The OGB full-batch GAT's flags through the CLI: the skip, ReLU
+    and the input-dropout rate reach the built model, whose manifest
+    then carries one ``attention`` entry per attention op."""
+    import json
+    events = str(tmp_path / "events.jsonl")
+    seen = []
+    rc = cli.main(["--cpu", "--no-compile-cache", "-e", "2", "-layers",
+                   "8-12-12-3", "--model", "gat", "--heads", "3",
+                   "--skip", "--act", "relu", "-dropout", "0.75",
+                   "--input-dropout", "0.1", "--eval-every", "2",
+                   "--events", events], inspect=seen.append)
+    assert rc == 0
+    assert "[INFER]" in capsys.readouterr().out
+    ops = seen[0].model._ops
+    assert sum(op.kind == "linear" for op in ops) == 6
+    assert [op.attrs["rate"] for op in ops if op.kind == "dropout"] == [
+        0.1, 0.75, 0.75]
+    assert {op.attrs["mode"] for op in ops
+            if op.kind == "activation"} == {"relu"}
+    with open(events) as f:
+        man = [json.loads(ln) for ln in f if '"manifest"' in ln][-1]
+    assert [(e["op"], e["heads"], e["head_width"], e["layout"],
+             e["edge_passes"], e["carry_rows"])
+            for e in man["resolved"]["attention"]] == [
+        (3, 3, 4, "ell", 1, None), (9, 3, 4, "ell", 1, None),
+        (15, 1, 3, "ell", 1, None)]
 
 
 def test_cli_sgc_model_trains():
