@@ -196,14 +196,22 @@ class GraphContext:
         more for the denominator when sliced), table slots a pass
         (stored edges + padding, per partition), and the height of the
         fp32 carry a scan step rewrites (None: the bucketed layout
-        carries nothing).  Empty for a model without attention.  The
-        distributed trainer, whose tables live outside its context,
+        carries nothing).  Beside it, one ``attention_backward`` entry
+        per op: the gradient rule :meth:`gat_attention` takes
+        (``transposed``: the bucketed layout on a symmetric graph;
+        ``autodiff`` otherwise), the passes over the edge tables its
+        backward makes (autodiff recomputes each forward scan step,
+        then transposes it) and the whole ``[G+1, .]`` cotangents
+        those scatter-add into, once a scan step each (features,
+        source and destination scores).  Empty for a model without
+        attention.  The distributed trainer, whose tables live outside its context,
         hands them in (stacked: the shapes' trailing axes are read)."""
         from ..ops.attention import resolve_dh_chunk
         flat8 = self.aggr_impl == "attn_flat8"
         ell_idx = self.ell_idx if ell_idx is None else ell_idx
         flat8_idx = self.flat8_idx if flat8_idx is None else flat8_idx
-        out = []
+        transposed = self.symmetric and not flat8
+        out, back = [], []
         for i, op in enumerate(ops):
             if op.kind != "gat":
                 continue
@@ -223,7 +231,19 @@ class GraphContext:
                         "padded_slots_per_pass": slots,
                         "carry_rows": self.num_rows + 1 if flat8
                         else None})
-        return {"attention": out} if out else {}
+            if transposed:
+                back.append({"op": i, "rule": "transposed",
+                             "edge_passes": 1, "scatters": 0})
+                continue
+            # the flat layout's row-max scan takes no gradient; its
+            # denominator scan, where apart, gathers no features
+            scans = passes - 1 if flat8 else passes
+            apart = 1 if flat8 and scans > 1 else 0
+            back.append({"op": i, "rule": "autodiff",
+                         "edge_passes": 2 * scans,
+                         "scatters": 3 * scans - apart})
+        return ({"attention": out, "attention_backward": back}
+                if out else {})
 
     def _gathered_with_zero(self, x: jax.Array) -> jax.Array:
         """Halo exchange (under its own ``roc.halo`` scope, inside the
@@ -431,9 +451,15 @@ class GraphContext:
         ``LeakyReLU(a_src.h_j + a_dst.h_i)`` weighting the neighbor
         sum, per head.  Needs the ELL tables (every row's neighborhood
         in one bucket row) or the flat8 tables (a row's sub-rows
-        combined by sorted scatters); gradients are plain autodiff —
-        attention is nonlinear, the symmetric kernel-reuse trick does
-        not apply."""
+        combined by sorted scatters).
+
+        Gradients, as :meth:`aggregate_sum` chooses them: on a
+        symmetric graph the bucketed layout's backward is a second
+        pass over the same tables, each row gathering from the rows it
+        feeds (``gat_ell_backward``; the cotangent and the packed row
+        statistics ride the same halo as the forward's features);
+        ``symmetric=False`` and the flat layout are exact autodiff
+        through the forward."""
         if self.halo == "ring":
             raise NotImplementedError(
                 "attention is not supported with halo='ring' (the ring "
@@ -449,12 +475,37 @@ class GraphContext:
                 f"{self.aggr_impl!r}; sectioned splits a row's "
                 "neighbors across sections and cannot host the edge "
                 "softmax")
-        from ..ops.attention import (gat_aggregate_ell,
-                                     gat_aggregate_flat8,
-                                     resolve_dh_chunk)
         if a_src.ndim == 1:                  # single-head vectors
             a_src = a_src[None, :]
             a_dst = a_dst[None, :]
+        if flat8 or not self.symmetric:
+            return self._gat_fwd(x, a_src, a_dst, neg_slope, flat8)
+        from ..ops.attention import gat_ell_backward, gat_ell_forward
+        tables = (self.ell_idx, self.ell_row_id, self.ell_row_pos,
+                  self.num_rows)
+
+        @jax.custom_vjp
+        def attn(x, a_src, a_dst):
+            return self._gat_fwd(x, a_src, a_dst, neg_slope, flat8)
+
+        def fwd(x, a_src, a_dst):
+            out, c, stats = gat_ell_forward(
+                *self._gat_scores(x, a_src, a_dst), *tables,
+                neg_slope=neg_slope)
+            return out, (x, a_src, a_dst, out, c, stats)
+
+        def bwd(res, g):
+            return gat_ell_backward(*res, g, self._gathered_with_zero,
+                                    *tables, neg_slope=neg_slope)
+
+        attn.defvjp(fwd, bwd)
+        return attn(x, a_src, a_dst)
+
+    def _gat_scores(self, x: jax.Array, a_src: jax.Array,
+                    a_dst: jax.Array):
+        """The halo'd features and the per-vertex halves of the edge
+        score: ``(full [G+1, K*dh], s_full [G+1, K], d_local
+        [num_rows+1, K])``, a dummy slot last in each."""
         K, dh = a_src.shape
         full = self._gathered_with_zero(x)
         with jax.named_scope(ATTN_SCORES_SCOPE):
@@ -471,7 +522,19 @@ class GraphContext:
                 preferred_element_type=jnp.float32)     # [num_rows, K]
             d_local = jnp.concatenate(
                 [d, jnp.zeros((1, K), dtype=d.dtype)])
+        return full, s_full, d_local
+
+    def _gat_fwd(self, x: jax.Array, a_src: jax.Array, a_dst: jax.Array,
+                 neg_slope: float, flat8: bool) -> jax.Array:
+        """Halo exchange + the layout's attention forward — the eval
+        program, and what autodiff goes through where it is the
+        gradient."""
+        from ..ops.attention import (gat_aggregate_ell,
+                                     gat_aggregate_flat8,
+                                     resolve_dh_chunk)
+        full, s_full, d_local = self._gat_scores(x, a_src, a_dst)
         if flat8:
+            K, dh = a_src.shape
             return gat_aggregate_flat8(full, s_full, d_local,
                                        self.flat8_idx, self.flat8_dst,
                                        self.num_rows,

@@ -70,7 +70,9 @@ def run_manifest(config=None, dataset=None, model=None,
     scan's window rows per section and the carry's height, so a run
     says how far the windowed scatter engaged; one ``attention`` entry
     per attention op (heads, head width, layout, passes over the edge
-    tables, slots a pass, carry rows).
+    tables, slots a pass, carry rows) and one ``attention_backward``
+    entry (the gradient rule, its edge passes, the whole-array
+    cotangents it scatters into).
 
     Everything is best-effort: a missing backend or detached checkout
     degrades to nulls, never to an exception at trainer setup."""

@@ -21,8 +21,13 @@ normalization.  This is also why the ``sectioned`` layout cannot host
 attention: it splits a row's neighbors across source sections, which
 would require a cross-section softmax reduction (use ``ell``).
 
-Gradients are plain autodiff: attention is nonlinear in both inputs,
-so the reference's symmetric kernel-reuse trick does not apply.
+Gradients: the forward is scatter-free, and on a symmetric graph so is
+the bucketed layout's backward — the reference's design (the backward
+is the same kernel on the transposed graph) holds for attention too,
+because an edge's weight is a function of per-vertex quantities alone
+(:func:`gat_ell_backward`).  A directed graph and the flat layout take
+plain autodiff through the forward; there every scan step scatter-adds
+into a whole ``[G+1, .]`` cotangent.
 
 Every operation sits under one of three phase scopes (obs/scopes.py
 ``ATTN_*_SCOPE``), nested inside the model op's ``roc.agg.op<i>``:
@@ -45,6 +50,194 @@ from ..obs.scopes import (ATTN_GATHER_SCOPE as _GATHER,
                           ATTN_STATS_SCOPE as _STATS)
 
 
+def _forward_tile(full, s_full, d_local, neg_slope, residuals=False):
+    """``tile(idx_seg, rid_seg)``: the edge softmax and weighted sum of
+    one ``[r, w]`` tile of bucket rows — the segment function the
+    forward of both gradient paths shares.  It returns ``(out [r, F],)``;
+    with ``residuals`` (the symmetric VJP's forward rule, taken while
+    the tile is in hand) also ``c [r, F]`` fp32 and the row statistics
+    ``[r, 2K]`` fp32 (row max, denominator).
+
+    ``c_i = (1 - slope) (sum_j [z_ij >= 0] alpha_ij h_j - out_i
+    sum_j [z_ij >= 0] alpha_ij)`` is what the destination score's
+    gradient needs of the row's neighbors: ``d_bar_i = G_i . c_i``.
+    LeakyReLU's slope is ``slope + (1 - slope) [z >= 0]`` and the
+    constant part cancels (``sum_j alpha_ij (G_i . h_j - G_i . out_i)
+    == 0``), so a row whose edges all sit on one side of 0 reads an
+    exact 0 at any compute dtype."""
+    F = full.shape[1]
+    K = s_full.shape[1]
+    dummy = full.shape[0] - 1
+    neg = jnp.asarray(-jnp.inf, dtype=jnp.float32)
+
+    def tile(idx_seg, rid_seg):
+        # scores softmax in fp32 for stability regardless of compute
+        # dtype (bf16 exp over a wide range loses the tail)
+        with jax.named_scope(_SCORES):
+            z = (s_full[idx_seg].astype(jnp.float32)
+                 + d_local[rid_seg].astype(jnp.float32)[:, None, :])
+            e = jax.nn.leaky_relu(z, neg_slope)          # [r, w, K]
+            valid = (idx_seg != dummy)[:, :, None]
+            e = jnp.where(valid, e, neg)
+        with jax.named_scope(_STATS):
+            m = jnp.max(e, axis=1, keepdims=True)
+            # all-padding rows have m == -inf; zero them via the guard
+            m = jnp.where(jnp.isfinite(m), m, 0.0)
+            w = jnp.where(valid, jnp.exp(e - m), 0.0)
+            den = jnp.maximum(w.sum(axis=1, keepdims=True), 1e-20)
+        with jax.named_scope(_GATHER):
+            soft = w / den
+            alpha = soft.astype(full.dtype)              # [r, w, K]
+            g = full[idx_seg].reshape(*idx_seg.shape, K, F // K)
+            if not residuals:
+                return (jnp.einsum("rwk,rwkd->rkd", alpha,
+                                   g).reshape(idx_seg.shape[0], F),)
+            # both sums as the forward takes its one: asked for in
+            # fp32, XLA:TPU first writes the whole tile out in fp32
+            num = jnp.einsum("rwk,rwkd->rkd", alpha, g)
+            num_up = jnp.einsum("rwk,rwkd->rkd",
+                                jnp.where(z >= 0, alpha, 0), g)
+        with jax.named_scope(_STATS):
+            # in fp32, not the rounded weights: 1 where every edge is up
+            share_up = jnp.where(z >= 0, soft, 0.0).sum(axis=1)
+            stats = jnp.concatenate([m[:, 0], den[:, 0]], axis=1)
+        with jax.named_scope(_GATHER):
+            c = (1.0 - neg_slope) * (
+                num_up.astype(jnp.float32)
+                - share_up[:, :, None] * num.astype(jnp.float32))
+            return (num.reshape(idx_seg.shape[0], F),
+                    c.reshape(idx_seg.shape[0], F), stats)
+
+    return tile
+
+
+def _transposed_tile(g_full, p_full, s_pad, x_pad, neg_slope):
+    """``tile(idx_seg, rid_seg)`` of the symmetric backward: at bucket
+    row ``j`` over its own slots ``i = idx[j, w]`` — the rows ``j`` is
+    a source of, because stored edges are symmetric — recompute
+    ``alpha_ij`` from row ``i``'s statistics and reduce over the width
+    axis as the forward does:
+
+        h_bar_j = sum_w alpha_ij G_i
+        s_bar_j = sum_w alpha_ij (G_i . h_j - q_i) leaky'(s_j + d_i)
+
+    g_full: [G+1, F] cotangent of the op's output through the halo;
+    p_full: [G+1, 4K] fp32, per vertex ``d, m, den, q = G . out``;
+    s_pad [num_rows+1, K] fp32 and x_pad [num_rows+1, F]: the rows'
+    own source scores and features, a zero row last for padding bucket
+    rows."""
+    F = g_full.shape[1]
+    K = s_pad.shape[1]
+    dummy = g_full.shape[0] - 1
+
+    def tile(idx_seg, rid_seg):
+        with jax.named_scope(_SCORES):
+            d, m, den, q = jnp.split(p_full[idx_seg], 4, axis=2)
+            z = s_pad[rid_seg][:, None, :] + d           # [r, w, K]
+            valid = (idx_seg != dummy)[:, :, None]
+        with jax.named_scope(_STATS):
+            # the dummy slot's statistics are 0: keep it out of the
+            # division
+            alpha = jnp.where(
+                valid, jnp.exp(jax.nn.leaky_relu(z, neg_slope) - m), 0.0
+            ) / jnp.where(valid, den, 1.0)
+        with jax.named_scope(_GATHER):
+            g = g_full[idx_seg].reshape(*idx_seg.shape, K, F // K)
+            h_bar = jnp.einsum("rwk,rwkd->rkd", alpha.astype(g.dtype),
+                               g).reshape(idx_seg.shape[0], F)
+            own = x_pad[rid_seg].reshape(idx_seg.shape[0], K, F // K)
+            u = jnp.einsum("rwkd,rkd->rwk", g, own,
+                           preferred_element_type=jnp.float32)
+        with jax.named_scope(_SCORES):
+            s_bar = (alpha * (u - q)
+                     * jnp.where(z >= 0, 1.0, neg_slope)).sum(axis=1)
+        return h_bar, s_bar
+
+    return tile
+
+
+def _lane_head_width(K: int, dh: int) -> int:
+    """The head width the hand-written passes run their tiles at:
+    ``dh`` rounded up to the 128 lanes of a vector register, where the
+    K heads then fill no more lane tiles than the K*dh-wide row already
+    does in memory (3 x 250 -> 3 x 256 = 768 = 750 rounded up) — the
+    gather moves the same bytes and the ``[r, w, K, dh]`` view of the
+    gathered tile is free; at 250 it is a relayout of the whole tile
+    every scan step.  Else ``dh`` (one head, or 8 x 8, where padding
+    the heads apart would gather 16x the row)."""
+    dhp = -(-dh // 128) * 128
+    return dhp if K > 1 and K * dhp <= -(-K * dh // 128) * 128 else dh
+
+
+def _pad_heads(a: jax.Array, K: int, dhp: int) -> jax.Array:
+    """``[N, K*dh] -> [N, K*dhp]``, zeros past each head's ``dh``."""
+    dh = a.shape[1] // K
+    if dhp == dh:
+        return a
+    return jnp.pad(a.reshape(-1, K, dh), ((0, 0), (0, 0), (0, dhp - dh))
+                   ).reshape(-1, K * dhp)
+
+
+def _unpad_heads(a: jax.Array, K: int, dh: int) -> jax.Array:
+    dhp = a.shape[1] // K
+    if dhp == dh:
+        return a
+    return a.reshape(-1, K, dhp)[:, :, :dh].reshape(-1, K * dh)
+
+
+def _bucket_rows(tile, ell_idx, ell_row_id, num_rows, dummy, unit,
+                 budget_elems, remat=False):
+    """``tile(idx_seg, rid_seg)`` -> a tuple of ``[rows, .]`` arrays,
+    run over every bucket; returns, per output, the buckets' rows in
+    bucket order.  A bucket past ``budget_elems`` (``unit`` elements a
+    (row, width) slot) is row-segmented under ``lax.scan``; every
+    segment writes its own rows of the stacked outputs and the scan
+    carries nothing, so the segment count costs loop steps only.
+    ``remat`` checkpoints each step, for a scan autodiff goes
+    through."""
+    cols = None
+    for idx, rid in zip(ell_idx, ell_row_id):
+        R, W = idx.shape
+        if R * W * unit <= budget_elems:
+            outs = tile(idx, rid)
+        else:
+            segs = -(-R * W * unit // budget_elems)
+            seg_rows = -(-R // segs)
+            Rp = seg_rows * segs
+            idx_p = jnp.concatenate(
+                [idx, jnp.full((Rp - R, W), dummy, dtype=idx.dtype)],
+                axis=0)
+            rid_p = jnp.concatenate(
+                [rid, jnp.full((Rp - R,), num_rows, dtype=rid.dtype)],
+                axis=0)
+            step = jax.checkpoint(tile) if remat else tile
+
+            def body(_, ch):
+                return None, step(*ch)
+
+            _, stacked = lax.scan(body, None,
+                                  (idx_p.reshape(segs, seg_rows, W),
+                                   rid_p.reshape(segs, seg_rows)))
+            outs = tuple(o.reshape(Rp, o.shape[-1])[:R] for o in stacked)
+        cols = cols or tuple([] for _ in outs)
+        for col, o in zip(cols, outs):
+            col.append(o)
+    return cols
+
+
+def _in_row_order(parts, ell_row_pos):
+    """Bucket-order rows -> vertex order; a row in no bucket reads the
+    appended zero row."""
+    zero = jnp.zeros((1, parts[0].shape[1]), dtype=parts[0].dtype)
+    return jnp.concatenate(list(parts) + [zero], axis=0)[ell_row_pos]
+
+
+def _slot_elems(full, s_full):
+    """Elements a (row, width) slot holds while a tile is in flight:
+    the feature row and three fp32 score tensors a head."""
+    return full.shape[1] + 3 * s_full.shape[1]
+
+
 def gat_aggregate_ell(full: jax.Array, s_full: jax.Array,
                       d_local: jax.Array, ell_idx, ell_row_id,
                       ell_row_pos: jax.Array, num_rows: int,
@@ -53,7 +246,8 @@ def gat_aggregate_ell(full: jax.Array, s_full: jax.Array,
     """Attention-weighted neighbor aggregation over ELL buckets,
     multi-head: K heads attend independently over the same
     neighborhood and their outputs concatenate (the GAT paper's
-    concat form; K == 1 is single-head).
+    concat form; K == 1 is single-head).  The forward of every path,
+    and the whole of the directed one: autodiff goes through it.
 
     full: [G+1, K*dh] gathered features with trailing zero row (the
       halo result; G == gathered_rows); the feature axis is the K
@@ -73,77 +267,118 @@ def gat_aggregate_ell(full: jax.Array, s_full: jax.Array,
     fp32 score tensors (e / w / alpha, [K] each) — at many heads and
     narrow head width the scores rival the gather, so the budget math
     counts both.
+
+    Each scan step is rematerialized: WITHOUT it, autodiff saves every
+    step's [seg_rows, W, F] feature gather as a stacked scan residual —
+    [segs, seg_rows, W, F] = 18.5 GiB at products scale (observed OOM,
+    v5e 2026-07-30).  Its transpose also carries one whole [G+1, .]
+    cotangent per closed-over array and scatter-adds into it once a
+    segment — what :func:`gat_ell_backward` avoids on a symmetric
+    graph.
+
+    NOTE (compile size): every bucket past the budget emits its own
+    scan — at products scale (lognormal degrees -> ~18 width buckets)
+    the unrolled HLO pushed remote compile past 40 min.  Large-graph
+    attention therefore routes through gat_aggregate_flat8 (ONE
+    uniform scan shape) — see resolve_attention_impl.
     """
-    F = full.shape[1]
-    K = s_full.shape[1]
-    assert F % K == 0, (F, K)
-    # elements per (row, width) slot the segmentation must bound
-    unit = F + 3 * K
-    dummy = full.shape[0] - 1
-    neg = jnp.asarray(-jnp.inf, dtype=jnp.float32)
-
-    def seg_out(idx_seg, rid_seg):
-        # scores softmax in fp32 for stability regardless of compute
-        # dtype (bf16 exp over a wide range loses the tail)
-        with jax.named_scope(_SCORES):
-            e = (s_full[idx_seg].astype(jnp.float32)
-                 + d_local[rid_seg].astype(jnp.float32)[:, None, :])
-            e = jax.nn.leaky_relu(e, neg_slope)          # [r, w, K]
-            valid = (idx_seg != dummy)[:, :, None]
-            e = jnp.where(valid, e, neg)
-        with jax.named_scope(_STATS):
-            m = jnp.max(e, axis=1, keepdims=True)
-            # all-padding rows have m == -inf; zero them via the guard
-            w = jnp.where(valid, jnp.exp(e - jnp.where(
-                jnp.isfinite(m), m, 0.0)), 0.0)
-            den = jnp.maximum(w.sum(axis=1, keepdims=True), 1e-20)
-        with jax.named_scope(_GATHER):
-            alpha = (w / den).astype(full.dtype)         # [r, w, K]
-            g = full[idx_seg].reshape(*idx_seg.shape, K, F // K)
-            return jnp.einsum("rwk,rwkd->rkd", alpha,
-                              g).reshape(idx_seg.shape[0], F)
-
-    outs = []
-    for idx, rid in zip(ell_idx, ell_row_id):
-        R, W = idx.shape
-        if R * W * unit <= budget_elems:
-            outs.append(seg_out(idx, rid))
-            continue
-        # NOTE (compile size): every bucket that lands here emits its
-        # own checkpointed scan, and autodiff doubles each — at
-        # products scale (lognormal degrees -> ~18 width buckets) the
-        # unrolled HLO pushed remote compile past 40 min.  Large-graph
-        # attention therefore routes through gat_aggregate_flat8
-        # (ONE uniform scan shape) — see resolve_attention_impl.
-        segs = -(-R * W * unit // budget_elems)
-        seg_rows = -(-R // segs)
-        Rp = seg_rows * segs
-        idx_p = jnp.concatenate(
-            [idx, jnp.full((Rp - R, W), dummy, dtype=idx.dtype)], axis=0)
-        rid_p = jnp.concatenate(
-            [rid, jnp.full((Rp - R,), num_rows, dtype=rid.dtype)],
-            axis=0)
-
-        # remat each step: WITHOUT it, autodiff saves every step's
-        # [seg_rows, W, F] feature gather as a stacked scan residual —
-        # [segs, seg_rows, W, F] = 18.5 GiB at products scale
-        # (observed OOM, v5e 2026-07-30).  Attention is nonlinear, so
-        # unlike the sum path the backward genuinely needs the
-        # gathered values; recomputing them per step in the backward
-        # sweep bounds memory at one step's transient.
-        seg_out_ckpt = jax.checkpoint(seg_out)
-
-        def body(_, ch):
-            return None, seg_out_ckpt(*ch)
-
-        _, segs_out = lax.scan(body, None,
-                               (idx_p.reshape(segs, seg_rows, W),
-                                rid_p.reshape(segs, seg_rows)))
-        outs.append(segs_out.reshape(Rp, F)[:R])
+    (outs,) = _bucket_rows(
+        _forward_tile(full, s_full, d_local, neg_slope), ell_idx,
+        ell_row_id, num_rows, full.shape[0] - 1,
+        _slot_elems(full, s_full), budget_elems, remat=True)
     with jax.named_scope(_GATHER):
-        zero = jnp.zeros((1, F), dtype=full.dtype)
-        cat = jnp.concatenate(outs + [zero], axis=0)
-        return cat[ell_row_pos]
+        return _in_row_order(outs, ell_row_pos)
+
+
+def gat_ell_forward(full: jax.Array, s_full: jax.Array,
+                    d_local: jax.Array, ell_idx, ell_row_id,
+                    ell_row_pos: jax.Array, num_rows: int,
+                    neg_slope: float = 0.2,
+                    budget_elems: int = 1 << 24):
+    """:func:`gat_aggregate_ell` as the symmetric VJP's forward rule:
+    the same tiles, and from each, while it is in hand, what the
+    backward needs per vertex — ``(out [num_rows, F], c [num_rows, F]
+    fp32, stats [num_rows, 2K] fp32)`` (:func:`_forward_tile`).  Nothing is
+    differentiated through, so no step is rematerialized."""
+    K = s_full.shape[1]
+    dh = full.shape[1] // K
+    with jax.named_scope(_GATHER):
+        wide = _pad_heads(full, K, _lane_head_width(K, dh))
+    outs, cs, stats = _bucket_rows(
+        _forward_tile(wide, s_full, d_local, neg_slope, residuals=True),
+        ell_idx, ell_row_id, num_rows, full.shape[0] - 1,
+        _slot_elems(full, s_full), budget_elems)
+    with jax.named_scope(_GATHER):
+        out = _unpad_heads(_in_row_order(outs, ell_row_pos), K, dh)
+        c = _unpad_heads(_in_row_order(cs, ell_row_pos), K, dh)
+    with jax.named_scope(_STATS):
+        return out, c, _in_row_order(stats, ell_row_pos)
+
+
+def gat_ell_backward(x: jax.Array, a_src: jax.Array, a_dst: jax.Array,
+                     out: jax.Array, c: jax.Array, stats: jax.Array,
+                     g: jax.Array, halo, ell_idx, ell_row_id,
+                     ell_row_pos: jax.Array, num_rows: int,
+                     neg_slope: float = 0.2,
+                     budget_elems: int = 1 << 24):
+    """Attention's backward on a SYMMETRIC graph as scatter-free
+    passes over the forward's own tables — the reference's design
+    (backward = the same kernel on the transposed graph,
+    ``scattergather_kernel.cu:160-170``) carried over to attention:
+    edge ``i <- j``'s weight is a function of per-vertex quantities
+    (``s_j``; ``d_i``, row ``i``'s max and denominator), so row ``j``
+    can recompute it for every row it feeds from a gather of those,
+    and no cotangent is scattered (:func:`_transposed_tile`).  The
+    destination scores' gradient is per vertex, from the forward
+    rule's ``c``.
+
+    x [num_rows, F], a_src / a_dst [K, dh]: the op's inputs; out, c,
+    stats: :func:`gat_ell_forward`'s; g: the cotangent of ``out``;
+    ``halo``: GraphContext._gathered_with_zero, through which ``g``
+    and the packed per-vertex scalars reach the rows of other
+    partitions as the forward's features did.  Returns the cotangents
+    of ``(x, a_src, a_dst)``.  False on a directed graph: the rows
+    ``j`` feeds are then not the rows in ``j``'s own bucket row."""
+    K, dh = a_src.shape
+    F = K * dh
+    f32 = jnp.float32
+    with jax.named_scope(_SCORES):
+        xr = x.reshape(num_rows, K, dh)
+        gr = g.reshape(num_rows, K, dh)
+        s = jnp.einsum("vkd,kd->vk", xr, a_src.astype(x.dtype),
+                       preferred_element_type=f32)
+        d = jnp.einsum("vkd,kd->vk", xr, a_dst.astype(x.dtype),
+                       preferred_element_type=f32)
+        q = jnp.einsum("vkd,vkd->vk", gr, out.reshape(num_rows, K, dh),
+                       preferred_element_type=f32)
+        d_bar = jnp.einsum("vkd,vkd->vk", gr.astype(f32),
+                           c.reshape(num_rows, K, dh))
+        packed = jnp.concatenate([d, stats, q], axis=1)  # [rows, 4K]
+        s_pad = jnp.concatenate([s, jnp.zeros((1, K), dtype=f32)])
+        p_full = halo(packed)
+    with jax.named_scope(_GATHER):
+        dhp = _lane_head_width(K, dh)
+        g_full = halo(_pad_heads(g, K, dhp))
+        x_pad = _pad_heads(
+            jnp.concatenate([x, jnp.zeros((1, F), dtype=x.dtype)]), K, dhp)
+        # the scans' own plumbing books to this phase; a tile's
+        # operations to the phase they name
+        h_parts, s_parts = _bucket_rows(
+            _transposed_tile(g_full, p_full, s_pad, x_pad, neg_slope),
+            ell_idx, ell_row_id, num_rows, g_full.shape[0] - 1,
+            _slot_elems(g, s_pad), budget_elems)
+        h_bar = _unpad_heads(_in_row_order(h_parts, ell_row_pos), K, dh)
+    with jax.named_scope(_SCORES):
+        s_bar = _in_row_order(s_parts, ell_row_pos)
+        through_scores = (s_bar[:, :, None] * a_src.astype(f32)
+                          + d_bar[:, :, None] * a_dst.astype(f32))
+        x_bar = h_bar + through_scores.reshape(num_rows, F).astype(x.dtype)
+        a_src_bar = jnp.einsum("vk,vkd->kd", s_bar, xr,
+                               preferred_element_type=f32)
+        a_dst_bar = jnp.einsum("vk,vkd->kd", d_bar, xr,
+                               preferred_element_type=f32)
+    return (x_bar, a_src_bar.astype(a_src.dtype),
+            a_dst_bar.astype(a_dst.dtype))
 
 
 def resolve_dh_chunk(num_rows: int, heads: int, dh: int,

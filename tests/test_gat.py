@@ -80,6 +80,59 @@ def test_gat_aggregate_matches_dense_reference(dataset, budget):
                                atol=2e-5)
 
 
+_COTANGENTS = {}
+
+
+@pytest.mark.parametrize("which", ["out", "x", "a_src", "a_dst"])
+@pytest.mark.parametrize("budget", [1 << 24, 512])
+@pytest.mark.parametrize("heads,dh", [(1, 6), (4, 6), (2, 120)])
+def test_symmetric_backward_matches_autodiff(dataset, heads, dh, budget,
+                                             which, monkeypatch):
+    """One attention op through ``GraphContext.gat_attention``: on a
+    symmetric graph the hand-written backward (a second pass over the
+    forward's tables) returns autodiff's cotangents, whole buckets and
+    scan-segmented ones; 2 x 120 runs its tiles at head width 128."""
+    import functools
+    from roc_tpu.ops import attention
+    from roc_tpu.train.trainer import make_graph_context
+    key = (heads, dh, budget)
+    if key not in _COTANGENTS:
+        assert dataset.graph.is_symmetric()
+        assert attention._lane_head_width(heads, dh) == (
+            128 if dh == 120 else dh)
+        V = dataset.graph.num_nodes
+        rng = np.random.RandomState(heads)
+        x = jnp.asarray(rng.randn(V, heads * dh), jnp.float32)
+        a_src = jnp.asarray(rng.randn(heads, dh), jnp.float32)
+        a_dst = jnp.asarray(rng.randn(heads, dh), jnp.float32)
+        g = jnp.asarray(rng.randn(V, heads * dh), jnp.float32)
+        for name in ("gat_aggregate_ell", "gat_ell_forward",
+                     "gat_ell_backward"):
+            monkeypatch.setattr(attention, name, functools.partial(
+                getattr(attention, name), budget_elems=budget))
+        got = {}
+        for symmetric in (True, False):
+            gctx = make_graph_context(dataset, "ell", symmetric=symmetric)
+            out, vjp = jax.vjp(gctx.gat_attention, x, a_src, a_dst)
+            got[symmetric] = dict(zip(("out", "x", "a_src", "a_dst"),
+                                      (out,) + vjp(g)))
+        _COTANGENTS[key] = got
+    got = _COTANGENTS[key]
+    want = np.asarray(got[False][which])
+    np.testing.assert_allclose(np.asarray(got[True][which]), want,
+                               rtol=2e-5, atol=2e-6 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("heads,dh,want", [
+    (3, 250, 256), (2, 120, 128), (4, 128, 128), (1, 40, 40), (1, 250, 250),
+    (8, 8, 8), (3, 10, 10), (4, 100, 128), (4, 90, 90)])
+def test_lane_head_width(heads, dh, want):
+    """A head is widened to a lane multiple only where the row's heads
+    then take the lane tiles the row already took."""
+    from roc_tpu.ops.attention import _lane_head_width
+    assert _lane_head_width(heads, dh) == want
+
+
 def test_gat_zero_degree_rows_are_zero():
     """A row with no in-edges aggregates to exactly 0 (the sum path's
     convention), not NaN from an empty softmax."""

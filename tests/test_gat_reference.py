@@ -10,6 +10,14 @@ with and without padding in the layout's scan (bucket segments with
 padding rows / a padding chunk of sub-rows against tables that divide
 exactly).  ``--dtype mixed``: inside the tolerances of the cell
 ``gat-arxiv.fullgraph``.  And the phase scopes of the compiled step.
+
+The symmetric graph's gradient rule (``ops/attention.py
+gat_ell_backward``: the backward as a second pass over the forward's
+own tables) against autodiff, which the cases above hold to the
+reference: the same graph made symmetric, with self edges and two
+pairs stored twice; float32 and mixed; one partition and two; the
+lowered step's shape; the ``attention_backward`` entries of the run
+manifest.
 """
 
 import functools
@@ -25,7 +33,8 @@ import pytest
 from roc_tpu.core.ell import flat_sum_from_graph
 from roc_tpu.core.graph import Dataset, Graph, MASK_TRAIN
 from roc_tpu.models.gat import build_gat
-from roc_tpu.obs.scopes import AGG, ATTN_PHASES, parse_op_phase
+from roc_tpu.obs.scopes import (AGG, ATTN_PHASES, parse_op_name,
+                                parse_op_phase)
 from roc_tpu.train.trainer import (TrainConfig, Trainer, cast_floats,
                                    make_graph_context)
 
@@ -98,10 +107,38 @@ def data():
     return ds, model, params
 
 
-def _gctx(ds, layout, padding):
+def _symmetric_graph():
+    """``_graph`` with every edge stored both ways — the hub and the 60
+    rows it holds now hold each other — every self edge kept, row
+    ``ISOLATED`` still empty, and two pairs stored twice."""
+    row_ptr, col = _graph()
+    count = np.zeros((V, V), np.int64)
+    count[np.repeat(np.arange(V), np.diff(row_ptr)), col] = 1
+    count = np.maximum(count, count.T)
+    for a, b in ((5, 9), (HUB, 17)):
+        count[a, b] = count[b, a] = 2
+    col = np.concatenate([np.repeat(np.arange(V), count[v])
+                          for v in range(V)]).astype(np.int32)
+    row_ptr = np.concatenate([[0], np.cumsum(count.sum(axis=1))])
+    return row_ptr.astype(np.int64), col
+
+
+@pytest.fixture(scope="module")
+def sym_data(data):
+    """``data``'s model and parameters on the symmetric graph."""
+    ds, model, params = data
+    g = Graph(*_symmetric_graph())
+    assert g.is_symmetric() and not ds.graph.is_symmetric()
+    assert g.num_edges > np.unique(
+        g.edge_dst().astype(np.int64) * V + g.col_idx).size  # repeats
+    return (Dataset(g, ds.features, ds.labels, ds.mask,
+                    num_classes=CLASSES), model, params)
+
+
+def _gctx(ds, layout, padding, symmetric=False):
     """The layout's tables as the trainer builds them; ``padded`` /
     ``exact`` choose whether its scan meets padding."""
-    gctx = make_graph_context(ds, layout, symmetric=False)
+    gctx = make_graph_context(ds, layout, symmetric=symmetric)
     if layout == "attn_flat8":
         g = ds.graph
         seg = SEG_ROWS if padding == "exact" else SEG_ROWS - 3
@@ -121,18 +158,25 @@ def _budget(layout, padding):
 
 
 _cache = {}
+# every entry point of the bucketed layout that takes the budget
+ELL_ENTRIES = ("gat_aggregate_ell", "gat_ell_forward", "gat_ell_backward")
 
 
-def _system(data, layout, padding, monkeypatch, dtype=jnp.float32):
-    key = (layout, padding, jnp.dtype(dtype).name)
+def _set_budget(monkeypatch, budget):
+    from roc_tpu.ops import attention
+    for name in ELL_ENTRIES:
+        monkeypatch.setattr(attention, name, functools.partial(
+            getattr(attention, name), budget_elems=budget))
+
+
+def _system(data, layout, padding, monkeypatch, dtype=jnp.float32,
+            symmetric=False):
+    ds, model, params = data
+    key = (id(ds), layout, padding, jnp.dtype(dtype).name, symmetric)
     if key in _cache:
         return _cache[key]
-    from roc_tpu.ops import attention
-    ds, model, params = data
-    gctx = _gctx(ds, layout, padding)
-    monkeypatch.setattr(attention, "gat_aggregate_ell", functools.partial(
-        attention.gat_aggregate_ell,
-        budget_elems=_budget(layout, padding)))
+    gctx = _gctx(ds, layout, padding, symmetric)
+    _set_budget(monkeypatch, _budget(layout, padding))
     feats = jnp.asarray(ds.features, dtype)
     labels, mask = jnp.asarray(ds.labels), jnp.asarray(ds.mask)
 
@@ -261,25 +305,34 @@ def test_mixed_precision_is_inside_the_cells_tolerances(
     assert got["row_rel_l2_median"] <= tol["row_rel_l2_median"], got
 
 
-@pytest.mark.parametrize("layout", LAYOUTS)
-def test_phase_scopes_parse_from_the_compiled_step(data, layout):
+@pytest.mark.parametrize("layout,rule", [
+    ("ell", "autodiff"), ("attn_flat8", "autodiff"), ("ell", "transposed")])
+def test_phase_scopes_parse_from_the_compiled_step(data, sym_data, layout,
+                                                   rule, monkeypatch):
     """Every attention op of the compiled train step has rows ``(agg,
     op, phase, fwd)`` and ``(agg, op, phase, bwd)`` for each of the
-    three phases; the eval step has the forward ones alone."""
-    ds, model, _ = data
-    tr = Trainer(model, ds, TrainConfig(verbose=False, aggr_impl=layout,
-                                        symmetric=False))
+    three phases; the eval step has the forward ones alone.  Of the
+    hand-written backward, every instruction sits in a phase or is the
+    halo's."""
+    ds, model, _ = sym_data if rule == "transposed" else data
+    _set_budget(monkeypatch, 700)
+    tr = Trainer(model, ds, TrainConfig(verbose=False, aggr_impl=layout))
     assert tr.config.aggr_impl == layout
+    assert tr.gctx.symmetric == (rule == "transposed")
     tr.train(epochs=1)
     tr.evaluate()
     ops = {i for i, op in enumerate(tr.model._ops) if op.kind == "gat"}
     assert len(ops) == 3
     want = {(AGG, i, ph, way) for i in ops for ph in ATTN_PHASES
             for way in ("fwd", "bwd")}
-    rows = {p for p in map(parse_op_phase,
-                           tr._train_step.instruction_scopes()[
-                               "scopes"].values()) if p}
+    scopes = tr._train_step.instruction_scopes()["scopes"]
+    rows = {p for p in map(parse_op_phase, scopes.values()) if p}
     assert rows == want
+    if rule == "transposed":
+        backward = [s for s in scopes.values()
+                    if parse_op_name(s) in {(AGG, i, "bwd") for i in ops}]
+        assert len(backward) > 100
+        assert all(parse_op_phase(s) for s in backward)
     rows = {p for p in map(parse_op_phase,
                            tr._eval_step.instruction_scopes()[
                                "scopes"].values()) if p}
@@ -334,6 +387,39 @@ def test_attention_plan_entries(data, layout, parts, monkeypatch):
     assert {(e["edge_passes"], e["padded_slots_per_pass"],
              e["carry_rows"]) for e in got} == {want}
     assert want[1] >= ds.graph.num_edges / parts
+    assert man["resolved"]["attention_backward"] == [
+        {"op": i, "rule": "autodiff", "edge_passes": 2, "scatters": 3}
+        for i in (3, 9, 15)]
+
+
+@pytest.mark.parametrize("parts", [1, 2])
+@pytest.mark.parametrize("graph,layout,rule", [
+    ("symmetric", "ell", "transposed"), ("directed", "ell", "autodiff"),
+    ("symmetric", "attn_flat8", "autodiff")])
+def test_attention_backward_entries(data, sym_data, graph, layout, rule,
+                                    parts, monkeypatch):
+    """The gradient rule each attention op took, read off the graph
+    (``symmetric`` unset), in the run manifest's ``resolved`` beside
+    ``attention`` — from both trainers."""
+    from roc_tpu.obs import manifest
+    from roc_tpu.parallel.distributed import DistributedTrainer
+    ds, model, _ = sym_data if graph == "symmetric" else data
+    cfg = TrainConfig(verbose=False, aggr_impl=layout)
+    seen = []
+    monkeypatch.setattr(
+        manifest, "emit",
+        lambda cat, msg, **fields: seen.append((cat, fields)))
+    if parts == 1:
+        Trainer(model, ds, cfg)
+    else:
+        DistributedTrainer(model, ds, parts, cfg)
+    (man,) = [fields for cat, fields in seen if cat == "manifest"]
+    want = ({"rule": "transposed", "edge_passes": 1, "scatters": 0}
+            if rule == "transposed" else
+            {"rule": "autodiff", "edge_passes": 2, "scatters": 3})
+    assert man["resolved"]["attention_backward"] == [
+        {"op": i, **want} for i in (3, 9, 15)]
+    assert [e["op"] for e in man["resolved"]["attention"]] == [3, 9, 15]
 
 
 def test_attention_plan_counts_the_sliced_numerator(data, monkeypatch):
@@ -346,6 +432,189 @@ def test_attention_plan_counts_the_sliced_numerator(data, monkeypatch):
                         lambda rows, heads, dh: 4)
     got = gctx.attention_plan(model._ops)["attention"]
     assert [e["edge_passes"] for e in got] == [2 + 3, 2 + 3, 2 + 2]
+    back = gctx.attention_plan(model._ops)["attention_backward"]
+    # the denominator's scan and one a slice, each recomputed and
+    # transposed; the denominator's gathers no features
+    assert [(e["rule"], e["edge_passes"], e["scatters"]) for e in back] \
+        == [("autodiff", 8, 11), ("autodiff", 8, 11), ("autodiff", 6, 8)]
     sums = make_graph_context(ds, "flat_sum", symmetric=False)
     assert sums.attention_plan([op for op in model._ops
                                 if op.kind != "gat"]) == {}
+
+
+# ---- the symmetric graph's gradient rule against autodiff ----
+
+SYM_CHECKS = ("logits", "loss") + tuple(PARAMS)
+
+
+@pytest.mark.parametrize("what", SYM_CHECKS)
+@pytest.mark.parametrize("padding", PADDING)
+def test_symmetric_rule_matches_autodiff(sym_data, padding, what,
+                                         monkeypatch):
+    """float32, 3 heads x 10 twice and 1 head x 5: the same logits and
+    loss (the forward rule's tiles are the forward's) and every
+    parameter's gradient, with every bucket segmented into scans that
+    meet padding rows and with none segmented."""
+    auto = _system(sym_data, "ell", padding, monkeypatch)
+    sym = _system(sym_data, "ell", padding, monkeypatch, symmetric=True)
+    if what == "logits":
+        np.testing.assert_allclose(sym[0], auto[0], rtol=1e-6, atol=1e-6)
+        assert np.abs(sym[0][HUB]).max() > 0
+    elif what == "loss":
+        assert sym[1] == pytest.approx(auto[1], rel=1e-6)
+    else:
+        want = auto[2][what]
+        assert np.abs(want).max() > 1e-4, "a dead parameter tests nothing"
+        np.testing.assert_allclose(sym[2][what], want, rtol=2e-5,
+                                   atol=5e-6 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("name", PARAMS)
+def test_symmetric_rule_in_mixed_precision(sym_data, name, monkeypatch):
+    """bfloat16 features and weights: the rule's gradient is no further
+    from the float32 one than autodiff's bfloat16 gradient is (one
+    width reduction a row against a cotangent rounded once a segment),
+    give or take a rounding."""
+    exact = _system(sym_data, "ell", "padded", monkeypatch)[2][name]
+    scale = np.linalg.norm(exact)
+
+    def off(symmetric):
+        got = _system(sym_data, "ell", "padded", monkeypatch,
+                      dtype=jnp.bfloat16, symmetric=symmetric)[2][name]
+        return np.linalg.norm(got.astype(np.float32) - exact) / scale
+
+    assert off(True) <= max(1.5 * off(False), 0.01), (off(True), off(False))
+
+
+def test_symmetric_rule_mixed_logits_inside_the_cells_tolerances(
+        ref, sym_data, monkeypatch):
+    reference, _ = ref
+    with open(os.path.join(BENCH, "workloads",
+                           "gat-arxiv.fullgraph.json")) as f:
+        tol = json.load(f)["correct"]
+    want = _system(sym_data, "ell", "padded", monkeypatch)[0]
+    logits = _system(sym_data, "ell", "padded", monkeypatch,
+                     dtype=jnp.bfloat16, symmetric=True)[0]
+    got = reference.compare(logits, want)
+    assert got["finite"]
+    assert got["row_rel_l2_max"] <= tol["row_rel_l2_max"], got
+    assert got["row_rel_l2_median"] <= tol["row_rel_l2_median"], got
+
+
+def test_directed_graph_takes_autodiff(data, monkeypatch):
+    """``symmetric`` unset on a directed graph resolves to autodiff:
+    the gradient program is, to the letter, the one ``symmetric=False``
+    gives — which the cases at the top hold to the reference."""
+    ds, model, params = data
+    _set_budget(monkeypatch, 700)
+    texts = []
+    for symmetric in (None, False):
+        gctx = make_graph_context(ds, "ell", symmetric=symmetric)
+        assert gctx.symmetric is False
+        texts.append(jax.jit(jax.grad(
+            lambda p, gctx=gctx: model.loss_fn(
+                p, jnp.asarray(ds.features), jnp.asarray(ds.labels),
+                jnp.asarray(ds.mask), gctx, key=None, train=False)[0])
+        ).lower(params).as_text())
+    assert texts[0] == texts[1] and "stablehlo.scatter" in texts[0]
+
+
+_parts_cache = {}
+
+
+@pytest.mark.parametrize("name", PARAMS)
+def test_symmetric_rule_through_the_halo(sym_data, name, monkeypatch):
+    """Two partitions: the cotangent and the packed row statistics
+    reach the other partition's rows through the all-gather the
+    forward's features took, and the summed gradients (the step's
+    ``psum``, read by standing in for Adam) are autodiff's."""
+    from roc_tpu.parallel import distributed
+    ds, model, _ = sym_data
+    monkeypatch.setattr(distributed, "adam_update",
+                        lambda params, grads, state, lr, cfg: (grads, state))
+    _set_budget(monkeypatch, 700)
+    for symmetric in (None, False):
+        if symmetric not in _parts_cache:
+            dt = distributed.DistributedTrainer(
+                model, ds, 2, TrainConfig(verbose=False, aggr_impl="ell",
+                                          symmetric=symmetric,
+                                          eval_every=1 << 30))
+            assert dt.symmetric == (symmetric is None)
+            dt.train(epochs=1)
+            _parts_cache[symmetric] = {
+                k: np.asarray(v) for k, v in jax.device_get(
+                    dt.params).items()}
+    want = _parts_cache[False][name]
+    assert np.abs(want).max() > 1e-4
+    np.testing.assert_allclose(_parts_cache[None][name], want, rtol=2e-5,
+                               atol=5e-6 * np.abs(want).max())
+
+
+def _produced_in_loops(text, rows):
+    """The operations of a lowered program that yield a ``[rows, .]``
+    value inside a ``while`` — its regions and every function they
+    call — as ``{operation name}``."""
+    import re
+    funcs, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"\s*func\.func (?:public |private )?@([\w.]+)", line)
+        if m:
+            name = m.group(1)
+            funcs[name] = []
+        elif name:
+            funcs[name].append(line)
+    shaped = re.compile(rf"-> \(?tensor<{rows}x|: tensor<{rows}x[^)]*$")
+    made, inside, seen = set(), [], set()
+
+    def scan(lines, in_loop):
+        depth, loops = 0, []
+        for line in lines:
+            if "stablehlo.while" in line:
+                loops.append(depth)
+            elif in_loop or loops:
+                op = re.search(r"= \"?(?:stablehlo|func)\.([a-z_]+)", line)
+                if shaped.search(line):     # "})": an op with a region
+                    made.add(op.group(1) if op else "region")
+                call = re.search(r"call @([\w.]+)", line)
+                if call and call.group(1) not in seen:
+                    seen.add(call.group(1))
+                    inside.append(call.group(1))
+            depth += line.count("{") - line.count("}")
+            if loops and depth <= loops[-1] and "}" in line \
+                    and "do {" not in line:
+                loops.pop()
+
+    for lines in list(funcs.values()):
+        scan(lines, False)
+    while inside:
+        scan(funcs[inside.pop()], True)
+    return made
+
+
+_eval_texts = {}
+
+
+@pytest.mark.parametrize("rule", ["transposed", "autodiff"])
+def test_train_step_program_shape(sym_data, rule, monkeypatch):
+    """The symmetric rule's lowered train step scatters nothing under
+    an attention phase and makes no ``[G+1, .]`` value inside a loop
+    (every bucket is segmented here: a later edit cannot bring the
+    once-a-segment whole-array cotangent back unnoticed); autodiff on
+    the same graph does both.  The eval step is, to the letter, the
+    same program under either."""
+    ds, model, _ = sym_data
+    _set_budget(monkeypatch, 700)
+    tr = Trainer(model, ds, TrainConfig(
+        verbose=False, aggr_impl="ell",
+        symmetric=None if rule == "transposed" else False))
+    tr.train(epochs=1)
+    tr.evaluate()
+    scatters = [n for n, s in tr._train_step.instruction_scopes()[
+        "scopes"].items() if n.startswith("scatter") and "roc.attn" in s]
+    made = _produced_in_loops(tr._train_step._lowered.as_text(), V + 1)
+    if rule == "transposed":
+        assert not scatters and not made, (scatters, made)
+    else:
+        assert scatters and {"add", "region"} <= made
+    text = tr._eval_step._lowered.as_text()
+    assert _eval_texts.setdefault("ell", text) == text
