@@ -1,5 +1,5 @@
-"""Shared benchmark-substrate spec parser (micro_agg.py,
-blockdense_occupancy.py): ONE grammar for the synthetic graphs the
+"""Shared benchmark-substrate spec parser
+(blockdense_occupancy.py): ONE grammar for the synthetic graphs the
 aggregation races run on.
 
     random               uniform sources (the headline synthetic)

@@ -3,8 +3,8 @@ ride [128,128] MXU tiles under a given vertex order.
 
 Host-side only (no accelerator): the stat that decides whether
 ``aggr_impl='bdense'`` can beat the ~7 ns/edge gather row-rate
-(BASELINE.md "Round-5 additions").  Substrate spec mirrors
-micro_agg's ``--graph``, plus an optional reorder pass so the
+(BASELINE.md "Round-5 additions").  Substrate spec is
+``_substrates.py``'s, plus an optional reorder pass so the
 ordering-recovery claim (core/reorder.py lpa_order) is measurable at
 any scale with one command:
 
@@ -43,8 +43,7 @@ def main():
                     choices=["none", "bfs", "lpa"])
     ap.add_argument("--min-fill", type=int, default=64)
     ap.add_argument("--a-budget", type=int, default=2 << 30,
-                    help="uint8 A-table byte cap (0 = uncapped, same "
-                         "convention as micro_agg.py --a-budget)")
+                    help="uint8 A-table byte cap (0 = uncapped)")
     ap.add_argument("--group", type=int, default=1,
                     help="pad_plan_groups alignment (the grouped "
                          "output-tile reduction); occupancy then "

@@ -72,8 +72,8 @@ def build_parser():
                          "bdense at scale; metrics are relabeling-"
                          "invariant)")
     ap.add_argument("--impl", default="auto",
-                    choices=["auto", "segment", "blocked", "scan",
-                             "ell", "pallas", "sectioned", "bdense"],
+                    choices=["auto", "segment", "ell", "sectioned",
+                             "bdense", "flat_sum"],
                     help="aggregation impl (default auto: the "
                          "window + structure-probe resolution)")
     ap.add_argument("--cpu", action="store_true",

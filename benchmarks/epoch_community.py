@@ -2,7 +2,7 @@
 """Full-epoch race on the community substrate: does the block-dense
 aggregation win survive end-to-end?
 
-The micro race (micro_agg.py --impls sectioned,bdense) measures ONE
+A micro race of sectioned against bdense measures ONE
 aggregation; an epoch is 2 forward + 2 backward aggregations plus the
 dense stack, so this script runs the headline GCN workload
 (602-256-41, dropout 0.5, Adam — example_run.sh:1 semantics) through
@@ -100,7 +100,7 @@ def main() -> int:
     for spec in args.impls.split(","):
         # 'IMPL+fuse' races the fused-normalization path (table-baked
         # D^-1/2 + fused epilogue) against the bare 'IMPL' row — the
-        # epoch-level form of micro_agg.py's chain-/fused- rows
+        # epoch-level form of a chain-against-fused micro race
         impl, _, fuse_tag = spec.partition("+")
         if fuse_tag not in ("", "fuse"):
             print(f"# unknown impl spec {spec!r} (IMPL or IMPL+fuse)",

@@ -42,7 +42,7 @@ from _substrates import GRAPH_SPEC_HELP, graph_from_spec  # noqa: E402
 
 
 def bench(fn, iters=10):
-    """Median wall ms with the fetch-based barrier (micro_agg.py)."""
+    """Median wall ms with the fetch-based barrier."""
     import jax.numpy as jnp
     out = fn()
     float(jnp.sum(out))

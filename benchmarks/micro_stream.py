@@ -38,7 +38,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 
 def bench(fn, iters=10):
-    """Median wall ms with the fetch-based barrier (micro_agg.py)."""
+    """Median wall ms with the fetch-based barrier."""
     import jax.numpy as jnp
     out = fn()
     float(jnp.sum(out))

@@ -7,7 +7,7 @@ Eight levels, mirroring XLA's own cost_analysis / HLO-verifier split:
 
 - :mod:`ast_lint` — source-level rules over the tree (stdout
   discipline, host syncs in hot paths, jits bypassing the compile
-  observer, pallas interpret plumbing);
+  observer);
 - :mod:`concurrency_lint` — the host-side threading/signal surface
   (lock-order cycles, signal-handler safety, condvar predicates,
   unguarded shared state, blocking under locks, thread shutdown

@@ -129,8 +129,7 @@ class HostSyncHotPathRule(AstRule):
     # query's fetch — exactly the latency bug class this tier will
     # grow.  The ONE sanctioned fetch (the result itself) carries a
     # pragma at the call site (serve/predictor.py).
-    HOT_PREFIXES = ("roc_tpu/ops/", "roc_tpu/kernels/",
-                    "roc_tpu/serve/")
+    HOT_PREFIXES = ("roc_tpu/ops/", "roc_tpu/serve/")
     HOT_FILES = {"roc_tpu/core/streaming.py"}
 
     def select(self, relpath: str) -> bool:
@@ -179,7 +178,7 @@ class SyncH2dInLoopRule(AstRule):
            "the transfer behind compute; stage through "
            "core/streaming.StagingPool so block k+1's copy runs "
            "under block k's work")
-    HOT_PREFIXES = ("roc_tpu/ops/", "roc_tpu/kernels/")
+    HOT_PREFIXES = ("roc_tpu/ops/",)
     HOT_FILES = {"roc_tpu/core/streaming.py"}
 
     def select(self, relpath: str) -> bool:
@@ -252,32 +251,6 @@ class BareJitRule(AstRule):
                           "bare jax.jit bypasses ObservedJit",
                           line=node.lineno,
                           key=f"jit@{node.lineno}")
-
-
-class PallasInterpretRule(AstRule):
-    """Every ``pl.pallas_call`` must plumb ``interpret=`` — kernels
-    without it cannot run on the CPU test rig (jax dropped the global
-    force_tpu_interpret_mode switch), so their coverage silently
-    evaporates."""
-
-    name = "pallas-interpret"
-    why = ("kernels must expose interpret= or they are untestable on "
-           "the CPU rig")
-
-    def select(self, relpath: str) -> bool:
-        return relpath.startswith("roc_tpu/kernels/")
-
-    def check(self, tree, relpath):
-        for node in ast.walk(tree):
-            if not (isinstance(node, ast.Call)
-                    and _is_attr(node.func, "pallas_call")):
-                continue
-            if any(kw.arg == "interpret" for kw in node.keywords):
-                continue
-            yield Finding(self.name, relpath,
-                          "pallas_call without interpret= plumbing",
-                          line=node.lineno,
-                          key=f"pallas@{node.lineno}")
 
 
 class SwallowedExceptionRule(AstRule):
@@ -503,7 +476,6 @@ class DequantHotPathRule(AstRule):
 
 RULES: List[AstRule] = [StdoutPrintRule(), HostSyncHotPathRule(),
                         SyncH2dInLoopRule(), BareJitRule(),
-                        PallasInterpretRule(),
                         SwallowedExceptionRule(), EventClockRule(),
                         MetricAdhocRule(), DequantHotPathRule()]
 

@@ -479,7 +479,7 @@ SECTION_ROWS_DEFAULT = 65_536   # 64 MiB of fp32 rows at F=256
 # ~11% on the aggregation itself (row-rate-bound gathers, ~7 ns/edge).
 
 # Upper bound of the sectioned layout's winning range (v5e, F=256,
-# median of 5, benchmarks/micro_agg.py 2026-07-30):
+# median of 5; July, builder: BASELINE.md, 2026-07-30):
 #   V=233k: sectioned 865 ms vs ell 2006 ms  (2.3x win)
 #   V=500k: sectioned 440 ms vs ell 477 ms   (marginal win)
 #   V=1M:   sectioned 964 ms vs ell 440 ms   (2.2x LOSS)
@@ -567,6 +567,25 @@ def sectioned_bounds(device_kind: Optional[str] = None
         f"{device_kind!r} (known: {sorted(SECTIONED_BOUNDS_BY_KIND)} "
         f"+ {calibration_path()}); run benchmarks/calibrate.py on it "
         f"or pass --impl explicitly")
+
+
+# What ``--impl`` / ``TrainConfig.aggr_impl`` may name: ``auto``, the
+# edge-list reference ``segment``, and the layouts a resolver can
+# return (tests/test_auto_impl.py holds each to a route).
+AGGR_IMPLS = ("auto", "segment", "ell", "sectioned", "bdense", "flat_sum")
+
+
+def check_stored_aggr_impl(name, where: str) -> None:
+    """An ``aggr_impl`` read back from outside the program (an export
+    manifest, a checkpoint's fingerprint) must name a layout that
+    exists: the six of :data:`AGGR_IMPLS`, or ``attn_flat8``, which
+    only a resolved attention config carries."""
+    if name not in AGGR_IMPLS + ("attn_flat8",):
+        raise ValueError(
+            f"{where}: stored aggr_impl={name!r} names no aggregation "
+            f"layout of this build (removed or unknown); --impl takes "
+            f"{', '.join(AGGR_IMPLS)} — export or train again under "
+            f"one of them")
 
 
 def resolve_auto_impl(num_nodes: int,

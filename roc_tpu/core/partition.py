@@ -126,8 +126,7 @@ class PartitionPlan:
         length ``part_nodes + 1``, offsets into the part's padded edge
         slice.  Padding edges attach to the *first padded row* (or the
         last real row when the part has no padded rows) so that edge
-        destinations stay contiguous — the blocked/pallas aggregators
-        rely on "a chunk of C sorted edges spans <= C rows".  Padding
+        destinations stay contiguous and sorted.  Padding
         edges point at the dummy zero-feature source, so a real last row
         absorbing them just adds zeros.
       - ``node_offset[p]`` is the global id of the part's first row;
@@ -216,8 +215,7 @@ def padded_edge_list(graph: Graph, multiple: int = 1024
     ``(edge_src, edge_dst)`` int32 arrays padded to a multiple of
     ``multiple``.  Padding edges use the dummy source ``num_nodes`` (zero
     feature row) and the last real destination row, preserving both the
-    aggregation result and the blocked aggregator's sorted-contiguity
-    invariant."""
+    aggregation result and the sorted order of the destinations."""
     E = graph.num_edges
     Ep = _round_up(max(E, 1), multiple)
     src = np.full(Ep, graph.num_nodes, dtype=np.int32)

@@ -33,9 +33,10 @@ from ..obs.scopes import (ATTN_SCORES_SCOPE, HALO_SCOPE, LOSS_SCOPE,
                           op_scope)
 from ..ops import dense
 from ..parallel import PARTS_AXIS
-from ..ops.aggregate import (aggregate, aggregate_ell, aggregate_ell_max,
+from ..ops.aggregate import (aggregate_ell, aggregate_ell_max,
                              aggregate_ell_sect, aggregate_flat_max,
-                             aggregate_flat_sum, scan_window_rows)
+                             aggregate_flat_sum, aggregate_segment,
+                             scan_window_rows)
 from ..ops.dense import AC_MODE_NONE, AC_MODE_RELU, AC_MODE_SIGMOID
 from ..ops.loss import masked_softmax_cross_entropy
 from ..ops.norm import indegree_norm
@@ -48,13 +49,6 @@ AGGR_SUM = "sum"
 AGGR_AVG = "avg"
 AGGR_MAX = "max"
 AGGR_MIN = "min"
-
-
-def _on_cpu() -> bool:
-    """True when the default backend is CPU — Pallas TPU kernels then
-    run in interpreter mode (tests / virtual-device rigs)."""
-    import jax as _jax
-    return _jax.default_backend() == "cpu"
 
 
 @dataclass
@@ -82,7 +76,6 @@ class GraphContext:
     gather_features: Callable[[jax.Array], jax.Array] = lambda x: x
     psum: Callable[[Any], Any] = lambda x: x
     aggr_impl: str = "segment"
-    chunk: int = 512
     symmetric: bool = True
     # Fused-normalization tables (aggr_fuse, see Model.fuse_norm_
     # aggregate): per-edge weights ``w = d[dst] * d[src]`` with
@@ -292,14 +285,11 @@ class GraphContext:
                 out = jnp.zeros((self.num_rows, full.shape[1]),
                                 dtype=full.dtype)
             return out
-        if self.aggr_impl == "pallas":
-            from ..kernels.ell_spmm import ell_aggregate_pallas
-            return ell_aggregate_pallas(full, self.ell_idx,
-                                        self.ell_row_pos, self.num_rows,
-                                        interpret=_on_cpu())
-        return aggregate(full, self.edge_src, self.edge_dst,
-                         self.num_rows, impl=self.aggr_impl,
-                         chunk=self.chunk)
+        if self.aggr_impl != "segment":
+            raise ValueError(
+                f"no sum aggregation for aggr_impl={self.aggr_impl!r}")
+        return aggregate_segment(full, self.edge_src, self.edge_dst,
+                                 self.num_rows)
 
     def aggregate_sum(self, x: jax.Array) -> jax.Array:
         """Sum aggregation with the reference's backward: for a symmetric
@@ -307,7 +297,7 @@ class GraphContext:
         the same kernel + halo exchange run on the cotangent
         (``scattergather_kernel.cu:160-170``; shard-level identity:
         row-slice_p(A^T g) = A_p g for A == A^T).  Besides parity, this
-        keeps the blocked scan's backward O(chunk) memory instead of
+        keeps the chunk scans' backward O(chunk) memory instead of
         saving per-chunk residuals.  Set ``symmetric=False`` for exact
         autodiff through the forward (directed graphs)."""
         if not self.symmetric:
@@ -387,22 +377,7 @@ class GraphContext:
                 out = jnp.zeros((self.num_rows, full.shape[1]),
                                 dtype=full.dtype)
             return out
-        if self.aggr_impl == "pallas":
-            # the hand-written route (kernels/graphnorm.py): pre-scale
-            # kernel on the LOCAL rows -> halo gather -> one-launch
-            # ELL DMA kernel -> fused scale epilogue kernel.  The
-            # activation rides outside the linear operator so the
-            # symmetric vjp below stays exact.
-            from ..kernels.graphnorm import (fused_ell_aggregate_pallas,
-                                             indegree_norm_pallas)
-            interp = _on_cpu()
-            full = self._gathered_with_zero(
-                indegree_norm_pallas(x, self.in_degree,
-                                     interpret=interp))
-            return fused_ell_aggregate_pallas(
-                full, self.ell_idx, self.ell_row_pos, self.num_rows,
-                inv_sqrt_degree(self.in_degree), interpret=interp)
-        # gather-based impls (segment/blocked/scan): scale features
+        # no baked weights (or the edge-list reference): scale features
         # once per fused op, sum, scale the output
         d = inv_sqrt_degree(self.in_degree).astype(x.dtype)
         out = self._sum_fwd(x * d[:, None])
@@ -467,8 +442,7 @@ class GraphContext:
                 "whole neighborhood); use halo='gather'")
         flat8 = self.aggr_impl == "attn_flat8" and \
             self.flat8_idx is not None
-        if not flat8 and (self.aggr_impl not in ("ell", "pallas")
-                          or not self.ell_idx):
+        if not flat8 and (self.aggr_impl != "ell" or not self.ell_idx):
             raise NotImplementedError(
                 f"attention needs the ELL tables (aggr_impl='ell') or "
                 f"the flat8 layout (aggr_impl='attn_flat8'), got "
@@ -561,20 +535,16 @@ class GraphContext:
             # the resolve pass routes to past FLAT_SUM_MIN_EDGES
             out = aggregate_flat_max(full, self.flat8_idx,
                                      self.flat8_dst, self.num_rows)
-        elif self.aggr_impl in ("ell", "pallas"):
-            # "pallas" carries the same ELL tables; MAX is a cold path,
-            # so the XLA ELL reduction serves both.  aggregate_ell_max
-            # row-segments large buckets under the same 64 MiB budget
-            # as the sum path.
+        elif self.aggr_impl == "ell":
+            # aggregate_ell_max row-segments large buckets under the
+            # same 64 MiB budget as the sum path.
             out = aggregate_ell_max(full, self.ell_idx,
                                     self.ell_row_pos, self.num_rows)
         else:
-            if self.aggr_impl in ("blocked", "scan", "pallas_csr",
-                                  "sectioned", "bdense"):
-                # guard every chunked-sum impl, not just 'blocked':
+            if self.aggr_impl in ("sectioned", "bdense"):
                 # falling through to the segment path would materialize
                 # the full [E, F] per-edge matrix — an OOM on exactly
-                # the large graphs those impls target
+                # the large graphs those layouts target
                 raise NotImplementedError(
                     f"AGGR_MAX has no {self.aggr_impl!r} implementation; "
                     "use aggr_impl='ell' (big graphs; sectioned carries "
@@ -595,14 +565,14 @@ def _gctx_flatten(g: GraphContext):
                 g.bd_a, g.bd_src, g.bd_dst, g.ell_w, g.sect_w,
                 g.ring_w, g.bd_scale)
     aux = (g.num_rows, g.gathered_rows, g.gather_features, g.psum,
-           g.aggr_impl, g.chunk, g.symmetric, g.halo, g.axis_name,
+           g.aggr_impl, g.symmetric, g.halo, g.axis_name,
            g.sect_meta, g.bd_vpad, g.bd_src_vpad, g.bd_group,
            g.ring_overlap, g.head_chunk, g.flat8_win)
     return children, aux
 
 
 def _gctx_unflatten(aux, children):
-    (num_rows, gathered_rows, gather_features, psum, aggr_impl, chunk,
+    (num_rows, gathered_rows, gather_features, psum, aggr_impl,
      symmetric, halo, axis_name, sect_meta, bd_vpad, bd_src_vpad,
      bd_group, ring_overlap, head_chunk, flat8_win) = aux
     (edge_src, edge_dst, in_degree, ell_idx, ell_row_pos, ring_idx,
@@ -613,7 +583,7 @@ def _gctx_unflatten(aux, children):
         edge_src=edge_src, edge_dst=edge_dst, in_degree=in_degree,
         num_rows=num_rows, gathered_rows=gathered_rows,
         gather_features=gather_features, psum=psum,
-        aggr_impl=aggr_impl, chunk=chunk, symmetric=symmetric,
+        aggr_impl=aggr_impl, symmetric=symmetric,
         ell_idx=ell_idx, ell_row_pos=ell_row_pos, halo=halo,
         ring_idx=ring_idx, axis_name=axis_name, sect_idx=sect_idx,
         sect_sub_dst=sect_sub_dst, sect_meta=sect_meta,
@@ -673,7 +643,7 @@ class Model:
 
     def uses_max_aggregation(self) -> bool:
         """True when any scatter_gather op is MAX/MIN — those have no
-        sectioned/blocked/scan implementation and no ring form, so the
+        sectioned/bdense implementation and no ring form, so the
         trainers' impl resolver forces 'ell' and rejects halo='ring'
         up front (same policy as attention)."""
         return any(op.kind == "scatter_gather"

@@ -9,59 +9,35 @@ for free from JAX autodiff (gather/segment_sum differentiate to the
 scatter/gather pair), so our gradients are correct for any graph while
 matching the reference bit-for-bit on the symmetric graphs it supports.
 
-Three implementations, one semantics:
+One semantics, one edge-list form and the table layouts ``auto``
+chooses among (``core/ell.py resolve_auto_impl``,
+``train/trainer.py resolve_auto_impl_probed``):
 
-- ``segment``: one-shot gather + ``segment_sum``.  Materializes the
-  ``[E, F]`` per-edge feature matrix — fine for small graphs and as the
-  numerics reference for tests.
-- ``blocked``: ``lax.scan`` over edge chunks.  Exploits dst-sortedness:
-  because every vertex has a self edge (degree >= 1), the destinations
-  inside a chunk of C edges span at most C consecutive rows, so each
-  chunk reduces into a C-row window that is added back with a
-  dynamic-slice read-modify-write.  The within-chunk reduction is a
-  *one-hot selection matmul* (``onehot(dst-r0)^T @ gathered``) — entirely
-  scatter-free, so it lands on the MXU instead of XLA's serialized TPU
-  scatter path.  Memory is O(C * F) regardless of E.
-- ``scan``: ``lax.scan`` over edge chunks with a *cumsum-diff* segmented
-  reduction — the direct TPU analog of the reference's cub BlockScan
-  kernel (``scattergather_kernel.cu:20-76``).  Within a chunk, row sums
-  are prefix-sum differences at precomputed row-end offsets (O(C*F) VPU
-  work instead of the one-hot matmul's O(C^2*F) MXU work), the chunk's
-  last row travels as a carry record instead of a read-modify-write, and
-  each window is *written exactly once* (later windows overwrite the
-  provisional zero tail), so HBM traffic drops from 3x to 2x the gather
-  bytes.  Carry records are scatter-added after the scan.  (On v5e the
-  XLA row-gather dominates all impls — see benchmarks/micro_agg.py —
-  so the practical default for big graphs is ``ell``, whose reduce is
-  a dense reshape-sum.)
-- ``pallas`` (kernels/ell_spmm.py): the ELL layout driven by a
-  one-launch-per-bucket Pallas kernel — scalar-readable index blocks in
-  SMEM, per-row feature DMA HBM->VMEM with a rotating pipeline, fp32
-  VMEM accumulation; dispatched via GraphContext (needs the ELL tables,
-  not an edge list).
-- ``pallas_csr`` (kernels/spmm.py): the ``scan`` algorithm with the
-  per-chunk segmented reduction fused into a Pallas TPU kernel
-  (superseded by ``pallas``; kept as the edge-list-contract kernel).
+- ``segment`` (:func:`aggregate_segment`): one-shot gather +
+  ``segment_sum`` over the edge list.  Materializes the ``[E, F]``
+  per-edge feature matrix — fine for small graphs, and the numerics
+  reference every parity test compares against.
+- ``ell`` (:func:`aggregate_ell`): degree-bucketed ELLPACK tables, one
+  gather + width reduction per bucket; also what attention and the
+  ELL MAX (:func:`aggregate_ell_max`) read.
+- ``sectioned`` (:func:`aggregate_ell_sect`) and ``flat_sum``
+  (:func:`aggregate_flat_sum`, MAX twin :func:`aggregate_flat_max`):
+  width-8 sub-row tables walked by one chunk scan
+  (:func:`_scan_window_sum`), per source section or over one global
+  section.
+- ``bdense`` (ops/blockdense.py): dense adjacency tiles on the MXU,
+  the residual edges through ``sectioned``.
 
-All take per-edge *global* source ids and produce rows for the local
-destination range, so they drop into the shard_map step unchanged (the
-gathered feature matrix is the all-gathered global one, mirroring the
-reference's whole-region input requirement, ``scattergather.cc:70-72``).
-
-**Measured (TPU v5 lite, 2026-07-29, V=50k E=10M F=256 fp32, median of
-10; benchmarks/measured_baselines.json has the full rows):** ``ell``
-119.1 ms / 86.0 GB/s, ``sectioned`` 131.1 ms, ``scan:4096`` 260.0 ms,
-``blocked:1024`` 294.6 ms, Pallas ELL kernel 1006.2 ms — each including
-~66 ms constant fetch-barrier overhead.  At REDDIT scale (V=233k,
-E=115M — gather table past VMEM) the ranking flips: ``sectioned``
-865 ms vs ``ell`` 2006 ms per aggregation, 2708 vs 7920.8 ms per train
-epoch (core/ell.py SectionedEll explains the mechanism).  The ``auto``
-default picks by table size.
+All take source ids in *gathered* coordinates and produce rows for the
+local destination range, so they drop into the shard_map step unchanged
+(the gathered feature matrix is the all-gathered global one, mirroring
+the reference's whole-region input requirement,
+``scattergather.cc:70-72``).  Which layout wins where is a chip
+measurement: ``PERF.md`` §5–§6 and the ledger hold the current ones,
+``BASELINE.md`` the July leads.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -77,101 +53,6 @@ def aggregate_segment(feats: jax.Array, edge_src: jax.Array,
     """
     gathered = feats[edge_src]
     return jax.ops.segment_sum(gathered, edge_dst, num_segments=num_rows)
-
-
-@functools.partial(jax.jit, static_argnames=("num_rows", "chunk"))
-def aggregate_blocked(feats: jax.Array, edge_src: jax.Array,
-                      edge_dst: jax.Array, num_rows: int,
-                      chunk: int = 512) -> jax.Array:
-    """Chunked CSR aggregation with O(chunk * F) working set.
-
-    Requires edge_dst sorted ascending and every destination row to have
-    degree >= 1 over the *full* edge list (self-edge convention,
-    ``gnn.cc:756``), which bounds the dst span of any chunk of C edges by
-    C rows.  Padding edges must point at a zero source row and the last
-    local row (partition.py guarantees both).
-    """
-    E = edge_src.shape[0]
-    F = feats.shape[1]
-    assert E % chunk == 0, "pad edges to a chunk multiple"
-    n_chunks = E // chunk
-    src_c = edge_src.reshape(n_chunks, chunk)
-    dst_c = edge_dst.reshape(n_chunks, chunk)
-    # Output padded by one window so the dynamic slice never clips.
-    out0 = jnp.zeros((num_rows + chunk, F), dtype=feats.dtype)
-
-    def body(out, inputs):
-        src, dst = inputs
-        r0 = dst[0]
-        gathered = feats[src]                       # [C, F]
-        local = dst - r0                            # in [0, C)
-        # scatter-free segment reduction: sel[e, r] = (local[e] == r);
-        # sel^T @ gathered lands on the MXU (fp32 accumulation)
-        sel = (local[:, None] ==
-               lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
-               ).astype(gathered.dtype)
-        prec = (lax.Precision.HIGHEST
-                if gathered.dtype == jnp.float32 else None)
-        seg = lax.dot_general(
-            sel, gathered, (((0,), (0,)), ((), ())), precision=prec,
-            preferred_element_type=jnp.float32).astype(out.dtype)
-        window = lax.dynamic_slice(out, (r0, 0), (chunk, F))
-        out = lax.dynamic_update_slice(out, window + seg, (r0, 0))
-        return out, None
-
-    out, _ = lax.scan(body, out0, (src_c, dst_c))
-    return out[:num_rows]
-
-
-@functools.partial(jax.jit, static_argnames=("num_rows", "chunk"))
-def aggregate_scan(feats: jax.Array, edge_src: jax.Array,
-                   edge_dst: jax.Array, num_rows: int,
-                   chunk: int = 1024) -> jax.Array:
-    """Cumsum-diff segmented reduction — the TPU BlockScan analog.
-
-    Same preconditions as :func:`aggregate_blocked` (dst sorted, degree
-    >= 1 over the full edge list, padding to a chunk multiple).  Within
-    each chunk of C edges the row sums are differences of the running
-    prefix sum at per-row end offsets (O(C*F) VPU work); the chunk's
-    last row is emitted as a (row, partial-sum) carry record instead of
-    read-modify-writing the output window, and each window is written
-    exactly once — rows past the chunk's last destination are written as
-    provisional zeros that the next window overwrites.  Carry records
-    are scatter-added after the scan (duplicates accumulate, so a row
-    spanning many chunks is summed exactly).
-    """
-    E = edge_src.shape[0]
-    F = feats.shape[1]
-    assert E % chunk == 0, "pad edges to a chunk multiple"
-    C = chunk
-    n_chunks = E // C
-    src_c = edge_src.reshape(n_chunks, C)
-    dst_c = edge_dst.reshape(n_chunks, C)
-    # Output padded by one window so dynamic writes never clip.
-    out0 = jnp.zeros((num_rows + C, F), dtype=feats.dtype)
-    iota = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
-
-    def body(out, inputs):
-        src, dst = inputs
-        r0 = dst[0]
-        pos = dst[C - 1] - r0                       # last row, local
-        g = feats[src].astype(jnp.float32)          # [C, F] gather
-        S1 = jnp.concatenate(
-            [jnp.zeros((1, F), jnp.float32), jnp.cumsum(g, axis=0)])
-        local = (dst - r0)[:, None]                 # [C, 1] in [0, C)
-        # ends[j] = # edges with local dst <= j  (all dst >= r0 here)
-        ends = jnp.sum((local <= iota.T).astype(jnp.int32), axis=0)
-        starts = jnp.concatenate(
-            [jnp.zeros((1,), jnp.int32), ends[:-1]])
-        L = jnp.take(S1, ends, axis=0) - jnp.take(S1, starts, axis=0)
-        carry = lax.dynamic_slice(L, (pos, 0), (1, F))
-        L = jnp.where(iota == pos, 0.0, L).astype(out.dtype)
-        out = lax.dynamic_update_slice(out, L, (r0, 0))
-        return out, (dst[C - 1], carry[0].astype(out.dtype))
-
-    out, (rows, vecs) = lax.scan(body, out0, (src_c, dst_c))
-    out = out.at[rows].add(vecs)
-    return out[:num_rows]
 
 
 def aggregate_ell(feats: jax.Array, ell_idx, ell_row_pos: jax.Array,
@@ -326,34 +207,6 @@ def aggregate_ell_sect(feats: jax.Array, sect_idx, sect_sub_dst,
     return out[:num_rows]
 
 
-def aggregate_ell_sect_split(feats: jax.Array, sect_idx, sect_sub_dst,
-                             sect_meta, num_rows: int) -> jax.Array:
-    """:func:`aggregate_ell_sect` with the ``[N, W]`` block gather
-    replaced by W independent ``[N]``-index row gathers summed as they
-    go — a deliberately different XLA gather lowering raced against
-    the block form in benchmarks/micro_agg.py (the block gather
-    materializes the ``[N, W, F]`` transient before its width
-    reduction; the split form keeps a single ``[N, F]`` accumulator)."""
-    F = feats.shape[1]
-    out = jnp.zeros((num_rows + 1, F), dtype=feats.dtype)
-    zero = jnp.zeros((1, F), dtype=feats.dtype)
-    for (st, sz, *_), tbl, sdst in zip(sect_meta, sect_idx,
-                                       sect_sub_dst):
-        xsec = jnp.concatenate(
-            [lax.slice(feats, (st, 0), (st + sz, F)), zero], axis=0)
-        W = tbl.shape[-1]
-
-        def body(o, ch, xsec=xsec, W=W):
-            idx_ch, dst_ch = ch
-            part = xsec[idx_ch[:, 0]]
-            for j in range(1, W):
-                part = part + xsec[idx_ch[:, j]]
-            return o.at[dst_ch].add(part, indices_are_sorted=True), None
-
-        out, _ = lax.scan(body, out, (tbl, sdst))
-    return out[:num_rows]
-
-
 def aggregate_flat_sum(feats: jax.Array, flat_idx: jax.Array,
                        flat_dst: jax.Array, num_rows: int,
                        flat_w=None, win_rows: int = 0) -> jax.Array:
@@ -462,46 +315,3 @@ def aggregate_ell_max(feats: jax.Array, ell_idx, ell_row_pos: jax.Array,
     tail = jnp.full((1, F), neg, dtype=feats.dtype)
     cat = jnp.concatenate(outs + [tail], axis=0)
     return cat[ell_row_pos]
-
-
-def aggregate(feats: jax.Array, edge_src: jax.Array, edge_dst: jax.Array,
-              num_rows: int, impl: str = "segment",
-              chunk: int = 512) -> jax.Array:
-    """Dispatch over implementations; identical numerics (fp32 addition
-    order differs between impls — tests use tolerances accordingly)."""
-    if impl == "segment":
-        return aggregate_segment(feats, edge_src, edge_dst, num_rows)
-    if impl == "blocked":
-        return aggregate_blocked(feats, edge_src, edge_dst, num_rows,
-                                 chunk=chunk)
-    if impl == "scan":
-        return aggregate_scan(feats, edge_src, edge_dst, num_rows,
-                              chunk=chunk)
-    if impl == "pallas":
-        raise ValueError(
-            "impl='pallas' is the one-launch ELL kernel "
-            "(kernels/ell_spmm.py) and needs the ELL tables, not an "
-            "edge list — route through GraphContext (aggr_impl='pallas') "
-            "or call ell_aggregate_pallas directly")
-    if impl == "pallas_csr":
-        try:
-            from ..kernels.spmm import csr_spmm_pallas
-        except ImportError as e:
-            raise NotImplementedError(
-                "the pallas_csr aggregation kernel is not available in "
-                "this build; use impl='blocked'") from e
-        return csr_spmm_pallas(feats, edge_src, edge_dst, num_rows,
-                               chunk=chunk)
-    raise ValueError(f"unknown aggregate impl: {impl}")
-
-
-def aggregate_mean(feats: jax.Array, edge_src: jax.Array,
-                   edge_dst: jax.Array, num_rows: int,
-                   in_degree: jax.Array, impl: str = "segment",
-                   chunk: int = 512) -> jax.Array:
-    """Mean aggregator (AGGR_AVG of the reference's declared-but-unbuilt
-    AggrType enum, ``gnn.h:75-80``): sum / real in-degree."""
-    s = aggregate(feats, edge_src, edge_dst, num_rows, impl=impl,
-                  chunk=chunk)
-    deg = jnp.maximum(in_degree.astype(s.dtype), 1.0)
-    return s / deg[:, None]

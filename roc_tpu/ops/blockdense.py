@@ -22,8 +22,8 @@ tiles, whose reads then dominate).  The path therefore targets graphs
 with COMMUNITY structure exposed by the vertex order (real Reddit is
 community-generated; ``core/reorder.py`` / the planted-community
 generator's oracle order model the ordering quality) — ``plan_blocks``
-reports the occupancy stats that decide it, and
-``benchmarks/micro_agg.py --impls bdense`` races it.
+reports the occupancy stats that decide it (no benchmark cell runs
+it yet: ROADMAP R1).
 
 Reference cost model being attacked: the one-thread-per-edge atomic
 CSR kernel ``/root/reference/scattergather_kernel.cu:20-76``.
@@ -397,7 +397,7 @@ def plan_blocks_packed(row_ptr: np.ndarray, col_idx: np.ndarray,
                        group: int = 1,
                        census=None) -> BlockPlan:
     """:func:`plan_blocks` + the u4 packing budget policy — ONE home
-    for the rule (trainer and micro_agg share it): plan against
+    for the rule: plan against
     DOUBLE the A budget first, since :func:`pack_a_u4` halves device
     bytes and a packable graph can afford 2x the blocks within the
     stated cap; unpackable plans (multi-edge hubs past 4 bits — rare)
@@ -432,7 +432,7 @@ def pack_a_u4(plan: BlockPlan) -> Optional[BlockPlan]:
 
     The kernel detects packing from the trailing axis
     (``BLOCK // 2``) and unpacks in-register per chunk.  Applied on
-    the single-device path (make_graph_context / micro_agg) and by
+    the single-device path (make_graph_context) and by
     the stacked distributed/multihost builders — all parts pack or
     none (one uniform SPMD trailing width; multihost agrees the
     global max multiplicity via one extra O(P) collective)."""

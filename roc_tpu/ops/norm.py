@@ -43,7 +43,5 @@ def inv_sqrt_degree_np(in_degree: np.ndarray) -> np.ndarray:
 
 def indegree_norm(x: jax.Array, in_degree: jax.Array) -> jax.Array:
     """x: [V, F]; in_degree: int32 [V].  Returns x / sqrt(indegree).
-    Plain XLA: the multiply fuses into neighboring ops (the explicit
-    VMEM-tiled kernel, kernels/graphnorm.py, is reached only through
-    ``aggr_impl='pallas'``, which plumbs ``interpret`` itself)."""
+    Plain XLA: the multiply fuses into neighboring ops."""
     return x * inv_sqrt_degree(in_degree)[:, None].astype(x.dtype)

@@ -354,8 +354,7 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
         edge_dst = np.zeros((pg.num_parts, 1), dtype=np.int32)
     else:
         col_padded = remap_to_padded(pg)
-        if aggr_impl in ("ell", "pallas", "sectioned", "attn_flat8",
-                         "flat_sum", "bdense"):
+        if aggr_impl != "segment":
             # table-driven paths never read the flat edge arrays —
             # upload stubs instead of two [P, E_p] tensors
             edge_dst = np.zeros((pg.num_parts, 1), dtype=np.int32)
@@ -364,14 +363,14 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
                 np.repeat(np.arange(pg.part_nodes, dtype=np.int32),
                           np.diff(pg.part_row_ptr[p]))
                 for p in range(pg.num_parts)])
-        if aggr_impl in ("ell", "pallas"):
+        if aggr_impl == "ell":
             table = ell_from_padded_parts(
                 pg.part_row_ptr, col_padded, pg.real_nodes,
                 pg.part_nodes, dummy=pg.num_parts * pg.part_nodes)
             ell_idx = tuple(put(a) for a in table.idx)
             ell_row_pos = put(table.row_pos)
             ell_row_id = tuple(put(a) for a in table.row_id)
-            if aggr_fuse and aggr_impl == "ell":
+            if aggr_fuse:
                 from ..core.ell import ell_weight_tables
                 ell_w = tuple(put(w) for w in ell_weight_tables(
                     table, fuse_d[0], fuse_d[1]))
@@ -497,8 +496,7 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
                 if fuse_d is not None:
                     sect_w = tuple(put(w) for w in sect.weight_tables(
                         fuse_d[0], fuse_d[1]))
-        if aggr_impl in ("ell", "pallas", "sectioned", "attn_flat8",
-                         "flat_sum", "bdense"):
+        if aggr_impl != "segment":
             col_padded = np.zeros((pg.num_parts, 1), dtype=np.int32)
     return ShardedData(
         feats=put(pad_nodes(dataset.features, pg).astype(dtype)),
@@ -746,20 +744,19 @@ class DistributedTrainer:
                     # echo as the own-build path below
                     emit("plan", "bdense: injected plan has no dense "
                          "tiles — running the pure sectioned residual")
-                if config.aggr_impl in ("ell", "pallas") \
+                if config.aggr_impl == "ell" \
                         and not self.data.ell_idx:
                     raise ValueError(
                         f"injected data has no ELL tables but the "
                         f"resolved aggr_impl is "
                         f"{config.aggr_impl!r} — build it with "
                         f"aggr_impl='ell'")
-                if config.aggr_impl in ("segment", "blocked", "scan",
-                                        "pallas_csr") and \
+                if config.aggr_impl == "segment" and \
                         self.data.edge_dst.shape[-1] != \
                         self.pg.part_edges:
-                    # table-built data carries 1-element edge stubs; a
-                    # flat-edge impl would silently aggregate one fake
-                    # 0->0 edge per part
+                    # table-built data carries 1-element edge stubs;
+                    # the edge-list reference would silently aggregate
+                    # one fake 0->0 edge per part
                     raise ValueError(
                         f"injected data carries edge stubs "
                         f"(shape {tuple(self.data.edge_dst.shape)}) "
@@ -1185,7 +1182,6 @@ class DistributedTrainer:
                 x, PARTS_AXIS, axis=0, tiled=True),
             psum=self._psum_parts,
             aggr_impl=self.config.aggr_impl,
-            chunk=self.config.chunk,
             symmetric=self.symmetric,
             halo=self.config.halo,
             ring_overlap=self.config.ring_overlap,

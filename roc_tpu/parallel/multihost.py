@@ -328,8 +328,7 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
     # edge_src field and the ELL table build
     cols = {p: remap_col_to_padded(pg, partition_col(pg, src.col_slice, p))
             for p in local}
-    use_stub = aggr_impl in ("ell", "pallas", "sectioned", "attn_flat8",
-                             "flat_sum", "bdense")
+    use_stub = aggr_impl != "segment"
 
     def edge_src_build(p):
         return cols[p]
@@ -343,7 +342,7 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
     ell_row_pos = put_parts(lambda p: np.zeros(1, np.int32), (1,),
                             np.int32)
     ring_idx = ()
-    if aggr_impl in ("ell", "pallas"):
+    if aggr_impl == "ell":
         # plan from part_row_ptr — the SAME degrees part_tables' bucket
         # build sees (padding edges can inflate the last real row's
         # degree when real_nodes[p] == part_nodes; see ell_shape_plan)

@@ -36,6 +36,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..core.ell import AGGR_IMPLS, check_stored_aggr_impl
 from ..obs.events import emit
 from .predictor import SERVE_BUCKETS, Predictor, ShardSlice
 from .propagation import (PropagationCache, logits_table_cache,
@@ -518,6 +519,7 @@ def load_predictor(artifact_dir: str, dataset=None,
             f"{manifest.get('version')} != {MANIFEST_VERSION}")
     model = Model.from_spec(manifest["model"])
     mc = manifest["config"]
+    check_stored_aggr_impl(mc["aggr_impl"], artifact_dir)
     config = TrainConfig(
         verbose=verbose, memory="manual", aggr_fuse="off",
         dtype=jnp.dtype(mc["dtype"]),
@@ -650,7 +652,7 @@ def parse_args(argv: Optional[List[str]] = None):
                          "smoke dataset, matching the training CLI)")
     ap.add_argument("--dtype", default="float32",
                     choices=["float32", "bfloat16", "mixed"])
-    ap.add_argument("--impl", default="auto")
+    ap.add_argument("--impl", default="auto", choices=AGGR_IMPLS)
     ap.add_argument("--fuse", default="auto",
                     choices=["auto", "on", "off"])
     ap.add_argument("--quantize", default="off",

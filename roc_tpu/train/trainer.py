@@ -41,10 +41,12 @@ class TrainConfig:
     seed: int = 1
     eval_every: int = 5
     verbose: bool = True
-    # segment|blocked|scan|ell|sectioned|pallas|auto ("auto" picks
-    # sectioned in its measured node-count window, ell outside —
-    # core/ell.py resolve_auto_impl)
+    # segment|ell|sectioned|bdense|flat_sum|auto ("auto" picks
+    # sectioned in its measured node-count window, ell or flat_sum
+    # outside — core/ell.py resolve_auto_impl; bdense by the structure
+    # probe, resolve_auto_impl_probed)
     aggr_impl: str = "segment"
+    # padding multiple of the edge list the 'segment' reference reads
     chunk: int = 512
     # Aggregation fusion (auto|on|off): rewrite every norm ->
     # sum-aggregate -> norm [-> relu] chain into ONE fused op
@@ -92,7 +94,7 @@ class TrainConfig:
     # reference requires it, scattergather_kernel.cu:160-170).
     # None = verify host-side at setup (O(E log E)); True = trust the
     # caller (skip the check, e.g. huge graphs); False = force exact
-    # autodiff gradients (directed graphs; slow for the blocked impl).
+    # autodiff gradients (directed graphs).
     symmetric: Optional[bool] = None
     # Observability (utils/profiling.py): profiler trace directory
     # (TensorBoard format; None = off) and metrics JSONL path.
@@ -122,8 +124,7 @@ class TrainConfig:
     features: str = "hbm"
     memory: str = "manual"
     hbm_bytes: Optional[int] = None
-    # Sectioned-layout tuning (core/ell.py SectionedEll; raced by
-    # benchmarks/micro_agg.py sectw:/sectu16 specs):
+    # Sectioned-layout tuning (core/ell.py SectionedEll):
     # - sect_sub_w: neighbors per sub-row (each (row, section) pair
     #   pads to a multiple of it).
     # - sect_u16: uint16 section-local index tables (halves index
@@ -401,7 +402,7 @@ def resolve_attention_impl(model, config: TrainConfig,
     """The ONE model-driven impl policy both trainers apply: models
     whose ops need the ELL tables — attention (edge softmax over one
     bucket row, ops/attention.py) and MAX/MIN aggregation (no
-    sectioned/blocked/scan form) — get aggr_impl overridden to 'ell'
+    sectioned/bdense form) — get aggr_impl overridden to 'ell'
     with a startup echo, and halo='ring' rejected up front (the ring
     accumulator is additive; failing at jit-trace time would waste
     the whole ring-table build first).  Attention models on graphs
@@ -429,7 +430,7 @@ def resolve_attention_impl(model, config: TrainConfig,
     if config.aggr_impl == "attn_flat8":
         return config
     if why == "attention" and dataset is not None and \
-            config.aggr_impl not in ("ell", "pallas") and \
+            config.aggr_impl != "ell" and \
             dataset.graph.num_edges >= ATTN_FLAT8_MIN_EDGES:
         import dataclasses
         emit("resolve",
@@ -438,14 +439,14 @@ def resolve_attention_impl(model, config: TrainConfig,
              "layout keeps the compile small)",
              requested=config.aggr_impl, resolved="attn_flat8")
         return dataclasses.replace(config, aggr_impl="attn_flat8")
-    if config.aggr_impl in ("ell", "pallas"):
+    if config.aggr_impl == "ell":
         return config
     if why == "MAX/MIN aggregation":
         if config.aggr_impl == "segment":
             # _max_fwd has a real segment path (jax.ops.segment_max) —
             # an explicitly requested 'segment' must not be silently
-            # overridden (ADVICE r3); only the chunked-sum impls
-            # (blocked/scan/pallas_csr/sectioned) lack a MAX form
+            # overridden (ADVICE r3); only sectioned and bdense lack
+            # a MAX form
             return config
         if config.aggr_impl == "flat_sum":
             # the uniform flat layout has a MAX twin
@@ -759,26 +760,21 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
     ell_w: tuple = ()
     sect_w: tuple = ()
     bd_scale: tuple = ()
-    if aggr_impl in ("ell", "pallas", "sectioned", "attn_flat8",
-                     "flat_sum", "bdense"):
-        # these paths never read the flat edge arrays — don't upload
+    if aggr_impl != "segment":
+        # the table layouts never read the flat edge arrays — don't upload
         # two [E] int32 tensors (~920 MB at Reddit scale) they'd ignore
         edge_src = np.zeros(1, dtype=np.int32)
         edge_dst = np.zeros(1, dtype=np.int32)
     else:
         edge_src, edge_dst = padded_edge_list(g, multiple=chunk)
     ell_row_id: tuple = ()
-    if aggr_impl in ("ell", "pallas"):
-        # both consume the degree-bucketed ELL layout; "pallas" runs it
-        # through the one-launch DMA kernel (kernels/ell_spmm.py)
+    if aggr_impl == "ell":
         from ..core.ell import ell_from_graph
         table = ell_from_graph(g.row_ptr, g.col_idx, g.num_nodes)
         ell_idx = tuple(jnp.asarray(a[0]) for a in table.idx)
         ell_row_pos = jnp.asarray(table.row_pos[0])
         ell_row_id = tuple(jnp.asarray(a[0]) for a in table.row_id)
-        if fuse and aggr_impl == "ell":
-            # 'pallas' derives d in-trace instead (the fused kernel
-            # route scales rows, not table entries)
+        if fuse:
             from ..core.ell import ell_weight_tables
             ell_w = tuple(
                 jnp.asarray(w[0]) for w in ell_weight_tables(
@@ -876,7 +872,6 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
         num_rows=g.num_nodes,
         gathered_rows=g.num_nodes,
         aggr_impl=aggr_impl,
-        chunk=chunk,
         symmetric=resolve_symmetric(dataset, symmetric),
         ell_idx=ell_idx,
         ell_row_pos=ell_row_pos,
@@ -1023,7 +1018,7 @@ class Trainer:
                 edge_dst=jnp.zeros(1, jnp.int32),
                 in_degree=jnp.asarray(g.in_degree),
                 num_rows=g.num_nodes, gathered_rows=g.num_nodes,
-                aggr_impl="segment", chunk=config.chunk,
+                aggr_impl="segment",
                 head_chunk=self._head_chunk,
                 # only the scatter_gather VJP reads symmetric, and this
                 # branch is taken only when the tail has none — a

@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.ell import check_stored_aggr_impl
 from ..obs.events import emit
 from ..train.optimizer import AdamState
 
@@ -535,6 +536,11 @@ def _validate_fingerprint(header: Dict[str, Any],
                           expect: Optional[Dict[str, Any]],
                           path: str) -> None:
     saved = header.get("fingerprint") or {}
+    stored_impl = (saved.get("elastic") or {}).get("aggr_impl")
+    if stored_impl is not None:
+        # a ValueError, not CheckpointCorrupt: the rotation's fallback
+        # would only find the same name in the older checkpoints
+        check_stored_aggr_impl(stored_impl, path)
     if not expect or not saved:
         return
     ss, es = saved.get("strict") or {}, expect.get("strict") or {}

@@ -24,7 +24,7 @@ import jax  # noqa: E402
 
 def pytest_configure(config):
     config.addinivalue_line(
-        "markers", "slow: long-running test (interpret-mode kernels)")
+        "markers", "slow: long-running test")
 
 # Pin the platform in jax's own config as well: an already-imported
 # jax has latched the environment, and the 8 virtual devices only

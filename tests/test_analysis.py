@@ -140,18 +140,6 @@ def test_bare_jit_fires_and_observed_form_allowed(tmp_path):
     assert [(f.rule, f.line) for f in got] == [("bare-jit", 4)]
 
 
-def test_pallas_interpret_fires(tmp_path):
-    _plant(tmp_path, "roc_tpu/kernels/k.py",
-           "from jax.experimental import pallas as pl\n"
-           "def run(body, shape, interpret=False):\n"
-           "    bad = pl.pallas_call(body, out_shape=shape)\n"
-           "    good = pl.pallas_call(body, out_shape=shape,\n"
-           "                          interpret=interpret)\n"
-           "    return bad, good\n")
-    got = run_ast_lint(str(tmp_path), select=["pallas-interpret"])
-    assert [(f.rule, f.line) for f in got] == [("pallas-interpret", 3)]
-
-
 def test_swallowed_exception_fires_and_allows(tmp_path):
     """Recovery/streaming/checkpoint paths: bare except (any body)
     and except-with-pass-only body both fire; a handler that handles

@@ -113,18 +113,20 @@ def test_distributed_lerp_families_match_single(dataset):
                                rtol=1e-3)
 
 
-def test_distributed_blocked_impl(dataset):
-    """blocked aggregation under shard_map matches segment."""
+def test_distributed_impls_match_segment(dataset):
+    """The table layouts under shard_map match the edge-list
+    reference."""
     model = build_gcn([dataset.in_dim, 16, dataset.num_classes],
                       dropout_rate=0.0)
     outs = {}
-    for impl in ("segment", "blocked", "ell"):
+    for impl in ("segment", "sectioned", "ell"):
         cfg = _no_dropout_cfg(aggr_impl=impl, chunk=64)
         t = DistributedTrainer(model, dataset, 4, cfg)
         t.train(epochs=3)
         outs[impl] = t.evaluate()
     np.testing.assert_allclose(outs["segment"]["train_loss"],
-                               outs["blocked"]["train_loss"], rtol=1e-3)
+                               outs["sectioned"]["train_loss"],
+                               rtol=1e-3)
     np.testing.assert_allclose(outs["segment"]["train_loss"],
                                outs["ell"]["train_loss"], rtol=1e-3)
 

@@ -136,8 +136,8 @@ def test_streamable_agg_head_accepts_fused_prefix():
 
 # ---- fused vs unfused equivalence (forward + grads, fp32) ----
 
-@pytest.mark.parametrize("impl", ["segment", "blocked", "scan", "ell",
-                                  "sectioned", "bdense", "pallas"])
+@pytest.mark.parametrize("impl", ["segment", "ell", "sectioned",
+                                  "bdense", "flat_sum"])
 @pytest.mark.parametrize("build", [
     lambda: build_gcn([12, 16, 4]),
     lambda: build_gcn([12, 16, 16, 4]),      # deep: dense residual
@@ -168,12 +168,16 @@ def test_fused_weight_tables_present(dataset):
     g = make_graph_context(dataset, "bdense", bdense_min_fill=1,
                            fuse=True)
     assert len(g.bd_scale) == 2
+    g = make_graph_context(dataset, "flat_sum", fuse=True)
+    assert g.flat8_w is not None and g.flat8_w.shape == g.flat8_idx.shape
 
 
-@pytest.mark.parametrize("halo", ["gather", "ring"])
-def test_fused_matches_unfused_distributed(dataset, halo):
+@pytest.mark.parametrize("halo,impl", [
+    ("gather", "ell"), ("gather", "sectioned"), ("gather", "flat_sum"),
+    ("ring", "ell")])
+def test_fused_matches_unfused_distributed(dataset, halo, impl):
     from roc_tpu.parallel.distributed import DistributedTrainer
-    cfg = TrainConfig(aggr_impl="ell", halo=halo, memory="manual",
+    cfg = TrainConfig(aggr_impl=impl, halo=halo, memory="manual",
                       dropout_rate=0.0, verbose=False, epochs=2,
                       eval_every=1 << 30)
     t0 = DistributedTrainer(build_gcn([12, 16, 4], dropout_rate=0.0),
@@ -183,6 +187,10 @@ def test_fused_matches_unfused_distributed(dataset, halo):
                             dataset, 2,
                             dataclasses.replace(cfg, aggr_fuse="on"))
     assert t1.model.num_fused_aggregates() == 2
+    if halo == "gather":
+        # shard_dataset baked the layout's own weight tables
+        baked = t1.data.ell_w if impl == "ell" else t1.data.sect_w
+        assert baked and not (t0.data.ell_w or t0.data.sect_w)
     assert _rel_err(t0.predict(), t1.predict()) < REL
     # gradients: two full training epochs must keep params aligned
     t0.train(2)
@@ -294,18 +302,3 @@ def test_reorder_overflow_guard_fails_loudly(monkeypatch):
     monkeypatch.setattr(ro, "single_key_fits_int64", lambda v: False)
     with pytest.raises(ValueError, match="single-key int64"):
         ro.apply_graph_order(g, perm)
-
-
-def test_cli_fences_slow_pallas_impl(capsys):
-    """The known-8.4x-slower --impl pallas is rejected without
-    --allow-slow-impl (VERDICT weakness #5)."""
-    from roc_tpu.train import cli
-    rc = cli.main(["--cpu", "--impl", "pallas", "-layers", "8-8-3"])
-    assert rc == 2
-    assert "--allow-slow-impl" in capsys.readouterr().err
-    # with the flag, validation passes the fence (a later, unrelated
-    # check rejects this argv — proving the fence stood down)
-    rc = cli.main(["--cpu", "--impl", "pallas", "--allow-slow-impl",
-                   "--heads", "2", "-layers", "8-8-3"])
-    assert rc == 2
-    assert "--heads applies" in capsys.readouterr().err

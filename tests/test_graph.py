@@ -106,8 +106,8 @@ def test_partition_graph_shapes_and_content():
 
 
 def test_partition_chunk_span_invariant():
-    """A run of C consecutive local edges must span <= C local rows —
-    required by the blocked aggregator."""
+    """A run of C consecutive local edges must span <= C local rows
+    (destinations sorted, every row with a self edge)."""
     g = add_self_edges(synthetic_graph(200, 5, seed=4, power_law=True))
     for P in (1, 3, 8):
         pg = partition_graph(g, P, node_multiple=8, edge_multiple=64)

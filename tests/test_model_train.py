@@ -61,17 +61,17 @@ def test_training_converges(dataset):
 
 
 def test_aggr_impl_invariance(dataset):
-    """segment vs blocked produce the same logits (same weights, no
-    dropout)."""
+    """segment, sectioned and ell produce the same logits (same
+    weights, no dropout)."""
     model = build_gcn([dataset.in_dim, 32, dataset.num_classes])
     params = model.init_params(jax.random.PRNGKey(1))
     feats = jnp.asarray(dataset.features)
     logits = {}
-    for impl in ("segment", "blocked", "ell"):
+    for impl in ("segment", "sectioned", "ell"):
         gctx = make_graph_context(dataset, aggr_impl=impl, chunk=256)
         logits[impl] = np.asarray(
             model.apply(params, feats, gctx, train=False))
-    np.testing.assert_allclose(logits["segment"], logits["blocked"],
+    np.testing.assert_allclose(logits["segment"], logits["sectioned"],
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(logits["segment"], logits["ell"],
                                rtol=1e-4, atol=1e-4)

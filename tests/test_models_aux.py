@@ -213,7 +213,7 @@ def test_sage_pool_converges_and_validates(dataset):
     model = build_sage([dataset.in_dim, 24, dataset.num_classes],
                        dropout_rate=0.0, aggregator="pool")
     # 'auto' must resolve to 'ell' via the shared model-driven impl
-    # policy (sectioned/blocked/scan have no MAX form)
+    # policy (sectioned/bdense have no MAX form)
     cfg = TrainConfig(learning_rate=0.01, weight_decay=1e-4,
                       aggr_impl="auto", verbose=False,
                       eval_every=1 << 30)
@@ -264,6 +264,22 @@ def test_min_aggregator_matches_numpy(dataset):
         got = np.asarray(gctx.aggregate(jnp.asarray(feats), AGGR_MIN))
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6,
                                    err_msg=impl)
+
+
+@pytest.mark.parametrize("impl", ["sectioned", "bdense"])
+def test_max_has_no_sectioned_or_bdense_form(dataset, impl):
+    """A context built on ``sectioned`` / ``bdense`` tables refuses a
+    MAX reduction by name: its edge list is a one-element stub, so
+    falling through to the segment path would answer from one fake
+    edge (and at scale materialize ``[E, F]``)."""
+    gctx = make_graph_context(dataset, aggr_impl=impl,
+                              bdense_min_fill=1)
+    assert gctx.edge_src.shape == (1,)
+    feats = jnp.asarray(dataset.features)
+    for aggr in (AGGR_MAX, "min"):
+        with pytest.raises(NotImplementedError, match=impl):
+            gctx.aggregate(feats, aggr)
+    assert gctx.aggregate(feats, "sum").shape == feats.shape
 
 
 def test_checkpoint_roundtrip(dataset, tmp_path):

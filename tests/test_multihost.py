@@ -148,14 +148,14 @@ def test_distributed_trainer_on_local_shards(halo):
     ds = synthetic_dataset(16 * n_dev, 6, in_dim=12, num_classes=3,
                            seed=0)
     mesh = mh.make_parts_mesh(n_dev)
-    cfg = TrainConfig(epochs=2, verbose=False, aggr_impl="blocked",
+    cfg = TrainConfig(epochs=2, verbose=False, aggr_impl="sectioned",
                       chunk=64, halo=halo)
     tr = DistributedTrainer(build_gcn([12, 8, 3]), ds, n_dev, cfg,
                             mesh=mesh)
     pg = partition_graph(ds.graph, n_dev)
     tr.data = mh.shard_dataset_local(ds, tr.pg, mesh,
                                      dtype=jnp.float32,
-                                     aggr_impl="blocked", halo=halo)
+                                     aggr_impl="sectioned", halo=halo)
     tr.train(epochs=2)
     m = tr.evaluate()
     assert np.isfinite(m["train_loss"])

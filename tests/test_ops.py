@@ -8,8 +8,7 @@ import pytest
 
 from roc_tpu.core.graph import add_self_edges, synthetic_graph
 from roc_tpu.core.partition import padded_edge_list
-from roc_tpu.ops.aggregate import (aggregate_blocked, aggregate_mean,
-                                   aggregate_scan, aggregate_segment)
+from roc_tpu.ops.aggregate import aggregate_segment
 from roc_tpu.ops.dense import (AC_MODE_NONE, AC_MODE_RELU, dropout, linear)
 from roc_tpu.ops.loss import (masked_softmax_cross_entropy, perf_metrics,
                               summarize_metrics)
@@ -51,47 +50,6 @@ def test_aggregate_segment_matches_dense(graph, feats):
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
 
 
-def test_aggregate_blocked_matches_segment(graph, feats):
-    src, dst = _padded(graph, chunk=64)
-    x = jnp.concatenate([jnp.asarray(feats),
-                         jnp.zeros((1, feats.shape[1]))], axis=0)
-    a = aggregate_segment(x, src, dst, graph.num_nodes)
-    b = aggregate_blocked(x, src, dst, graph.num_nodes, chunk=64)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("chunk", [32, 64, 256])
-def test_aggregate_scan_matches_segment(graph, feats, chunk):
-    src, dst = _padded(graph, chunk=chunk)
-    x = jnp.concatenate([jnp.asarray(feats),
-                         jnp.zeros((1, feats.shape[1]))], axis=0)
-    a = aggregate_segment(x, src, dst, graph.num_nodes)
-    b = aggregate_scan(x, src, dst, graph.num_nodes, chunk=chunk)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_aggregate_scan_hub_row_spans_chunks():
-    """A row whose degree is many times the chunk size exercises the
-    carry-record path (partials scatter-added across chunks)."""
-    V, hub_deg, chunk = 16, 300, 32
-    rng = np.random.RandomState(0)
-    dst = np.concatenate([np.arange(V), np.full(hub_deg, 7)])
-    src = np.concatenate([np.arange(V), rng.randint(0, V, hub_deg)])
-    from roc_tpu.core.graph import from_edge_list
-    g = from_edge_list(src, dst, V)
-    psrc, pdst = padded_edge_list(g, multiple=chunk)
-    x = np.zeros((V + 1, 5), dtype=np.float32)
-    x[:V] = rng.randn(V, 5)
-    a = aggregate_segment(jnp.asarray(x), jnp.asarray(psrc),
-                          jnp.asarray(pdst), V)
-    b = aggregate_scan(jnp.asarray(x), jnp.asarray(psrc),
-                       jnp.asarray(pdst), V, chunk=chunk)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=1e-4, atol=1e-4)
-
-
 def test_aggregate_grad_is_transpose(graph, feats):
     """d(sum(A@X * G))/dX == A^T @ G — JAX must produce the exact
     transpose (the reference reuses A, valid only because A == A^T;
@@ -109,18 +67,6 @@ def test_aggregate_grad_is_transpose(graph, feats):
     got = jax.grad(f)(jnp.asarray(feats))
     want = A.T @ G
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
-
-
-def test_aggregate_mean(graph, feats):
-    A = dense_adjacency(graph)
-    deg = A.sum(axis=1, keepdims=True)
-    want = (A @ feats) / np.maximum(deg, 1.0)
-    src, dst = _padded(graph)
-    x = jnp.concatenate([jnp.asarray(feats),
-                         jnp.zeros((1, feats.shape[1]))], axis=0)
-    got = aggregate_mean(x, src, dst, graph.num_nodes,
-                         jnp.asarray(graph.in_degree))
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
 
 
 def test_indegree_norm(graph, feats):
@@ -328,14 +274,13 @@ def test_sectioned_width_variants_match_segment(sub_w):
                                rtol=1e-4, atol=1e-4)
 
 
-def test_sectioned_uint16_and_split_gather_match():
-    """uint16 section-local indices and the split-gather lowering are
-    numerics-identical to the block-gather int32 form."""
+def test_sectioned_uint16_matches_int32():
+    """uint16 section-local indices are numerics-identical to the
+    int32 form."""
     import jax.numpy as jnp
     from roc_tpu.core.graph import add_self_edges, synthetic_graph
     from roc_tpu.core.ell import sectioned_from_graph
-    from roc_tpu.ops.aggregate import (aggregate_ell_sect,
-                                       aggregate_ell_sect_split)
+    from roc_tpu.ops.aggregate import aggregate_ell_sect
     g = add_self_edges(synthetic_graph(400, 7, seed=3, power_law=True))
     F = 9
     feats = np.random.RandomState(2).rand(g.num_nodes + 1, F).astype(
@@ -353,9 +298,6 @@ def test_sectioned_uint16_and_split_gather_match():
     got16 = np.asarray(aggregate_ell_sect(x, uidx, udst, umeta,
                                           g.num_nodes))
     np.testing.assert_array_equal(got16, want)
-    gots = np.asarray(aggregate_ell_sect_split(x, sidx, sdst, meta,
-                                               g.num_nodes))
-    np.testing.assert_allclose(gots, want, rtol=1e-5, atol=1e-6)
     # a section size past the dtype's range must refuse loudly
     import pytest as _pytest
     big = sectioned_from_graph(g.row_ptr, g.col_idx, g.num_nodes,
@@ -616,6 +558,46 @@ def test_windowed_scan_grad_matches_segment(layout, weighted):
     want = np.zeros((n + 1, 3), np.float32)
     np.add.at(want, col, np.asarray(cot)[dst] * scale)
     np.testing.assert_allclose(got[:n], want[:n], rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("impl", ["ell", "bdense"])
+def test_directed_grad_matches_segment(impl):
+    """``symmetric=False`` on a directed graph: exact autodiff through
+    the layout's tables gives the edge-list reference's gradient, A^T
+    times the cotangent (``sectioned`` and ``flat_sum`` have theirs
+    in test_windowed_scan_grad_matches_segment)."""
+    from roc_tpu.core.graph import Dataset, from_edge_list
+    from roc_tpu.train.trainer import make_graph_context
+    rng = np.random.RandomState(11)
+    n, e = 200, 1500
+    # sources crowd the first 40 ids, so some [128, 128] tile fills
+    g = add_self_edges(from_edge_list(rng.randint(0, 40, e),
+                                      rng.randint(0, n, e), n))
+    ds = Dataset(graph=g, features=np.zeros((n, 3), np.float32),
+                 labels=np.zeros(n, np.int32),
+                 mask=np.ones(n, np.int32), num_classes=2,
+                 name="directed")
+    x = jnp.asarray(rng.randint(-3, 4, (n, 3)).astype(np.float32))
+    cot = jnp.asarray(rng.randint(-3, 4, (n, 3)).astype(np.float32))
+
+    def grad_of(aggr_impl):
+        gctx = make_graph_context(ds, aggr_impl, symmetric=False,
+                                  bdense_min_fill=1)
+        assert not gctx.symmetric
+        if aggr_impl == "bdense":
+            assert gctx.bd_a is not None
+        return np.asarray(jax.grad(
+            lambda v: (gctx.aggregate_sum(v) * cot).sum())(x))
+
+    want = np.zeros((n, 3), np.float32)
+    np.add.at(want, g.col_idx, np.asarray(cot)[g.edge_dst()])
+    np.testing.assert_array_equal(grad_of("segment"), want)
+    assert not np.array_equal(want, np.asarray(
+        jax.grad(lambda v: (make_graph_context(
+            ds, "segment", symmetric=True).aggregate_sum(v)
+            * cot).sum())(x)))       # the graph IS directed
+    np.testing.assert_allclose(grad_of(impl), want, rtol=1e-6,
+                               atol=1e-6)
 
 
 # ---- the table's window (core/ell.py SectionedEll.win_rows) ----

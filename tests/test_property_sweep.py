@@ -1,11 +1,11 @@
 """Cross-implementation equivalence sweep over randomized graphs.
 
-The aggregation impls (segment / blocked / scan / ell / sectioned /
-bdense incl. grouped+u4-packed) must agree on ANY graph — including
-the structures that historically broke layouts: zero-degree rows,
-hub rows (bucket width >> mean), single-node components, and
-empty-ish partitions.  The fixed fixtures
-elsewhere pin one shape each; this sweep randomizes."""
+The aggregation layouts (ell / sectioned / flat_sum / bdense incl.
+grouped+u4-packed) must agree with the segment reference on ANY graph
+— including the structures that historically broke layouts:
+zero-degree rows, hub rows (bucket width >> mean), single-node
+components, and empty-ish partitions.  The fixed fixtures elsewhere
+pin one shape each; this sweep randomizes."""
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +16,11 @@ from roc_tpu.core.graph import Dataset, Graph, from_edge_list
 from roc_tpu.models.gcn import build_gcn
 from roc_tpu.train.trainer import TrainConfig, Trainer, make_graph_context
 
-IMPLS = ("segment", "blocked", "scan", "ell", "sectioned")
+IMPLS = ("segment", "ell", "sectioned", "flat_sum")
+# block-dense variants: min_fill=1 forces tiles on any graph, the
+# planted hub's duplicate edges exercise uint8/u4 multiplicity
+# saturation and the packing fallback, group=4 the padded-run reduction
+BDENSE = {"bdense": {}, "bdense_g4": {"bdense_group": 4}}
 
 
 def _random_stress_graph(seed: int) -> Graph:
@@ -35,8 +39,9 @@ def _random_stress_graph(seed: int) -> Graph:
     return from_edge_list(src, dst, V)
 
 
+@pytest.mark.parametrize("layout", IMPLS[1:] + tuple(BDENSE))
 @pytest.mark.parametrize("seed", range(6))
-def test_aggregation_impls_agree_on_stress_graphs(seed):
+def test_aggregation_impls_agree_on_stress_graphs(seed, layout):
     g = _random_stress_graph(seed)
     rng = np.random.RandomState(seed + 100)
     ds = Dataset(graph=g,
@@ -46,26 +51,21 @@ def test_aggregation_impls_agree_on_stress_graphs(seed):
     feats = jnp.asarray(ds.features)
     model = build_gcn([16, 8, 3], dropout_rate=0.0)
     params = model.init_params(jax.random.PRNGKey(seed))
-    outs = {}
-    for impl in IMPLS:
-        gctx = make_graph_context(ds, aggr_impl=impl, chunk=64)
-        outs[impl] = np.asarray(
+
+    def forward(aggr_impl, **kw):
+        gctx = make_graph_context(ds, aggr_impl=aggr_impl, chunk=64,
+                                  **kw)
+        return gctx, np.asarray(
             model.apply(params, feats, gctx, train=False))
-    # block-dense variants: min_fill=1 forces tiles on any graph, the
-    # planted hub's duplicate edges exercise uint8/u4 multiplicity
-    # saturation and the packing fallback, group=4 the padded-run
-    # reduction
-    for label, kw in (("bdense", {}), ("bdense_g4",
-                                       {"bdense_group": 4})):
-        gctx = make_graph_context(ds, aggr_impl="bdense", chunk=64,
-                                  bdense_min_fill=1, **kw)
-        assert gctx.bd_a is not None, label
-        outs[label] = np.asarray(
-            model.apply(params, feats, gctx, train=False))
-    ref = outs["segment"]
-    for impl in list(IMPLS[1:]) + ["bdense", "bdense_g4"]:
-        np.testing.assert_allclose(outs[impl], ref, rtol=2e-4,
-                                   atol=2e-5, err_msg=impl)
+
+    if layout in BDENSE:
+        gctx, got = forward("bdense", bdense_min_fill=1,
+                            **BDENSE[layout])
+        assert gctx.bd_a is not None
+    else:
+        _, got = forward(layout)
+    np.testing.assert_allclose(got, forward("segment")[1], rtol=2e-4,
+                               atol=2e-5)
 
 
 @pytest.mark.parametrize("seed", range(3))

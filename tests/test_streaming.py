@@ -414,7 +414,7 @@ def test_autopilot_picks_ring_for_distributed():
     model = build_gcn([8, 64, 3], dropout_rate=0.0)
     cfg = TrainConfig(memory="auto", hbm_bytes=1_500_000, epochs=1,
                       eval_every=1 << 30, verbose=False, symmetric=True,
-                      aggr_impl="blocked", chunk=64)
+                      aggr_impl="sectioned", chunk=64)
     tr = DistributedTrainer(model, ds, 4, cfg)
     assert tr.config.halo == "ring"
     tr.train(epochs=1)
