@@ -627,6 +627,42 @@ def resolve_auto_impl(num_nodes: int,
     return "ell"
 
 
+# The TPU's vector registers, and the minor tile of every HBM layout,
+# are 128 lanes wide.
+LANE_WIDTH = 128
+
+
+def agg_lane_width(feat_width: int, aggr_impl: str,
+                   halo: str = "gather") -> int:
+    """Feature width a sum aggregation runs at — ONE place for the
+    rule, decided from what the code sees in its input (the operand's
+    width, the layout, the halo mode): ``flat_sum`` narrower than the
+    128 lanes runs at 128, everything else at its own width.
+    ``GraphContext.aggregate_sum`` / ``aggregate_fused`` zero-pad the
+    operand's feature axis to it and slice the result back; the spare
+    lanes hold zeros, so the real columns are the same sums in the
+    same order, bit for bit.
+
+    Why ``flat_sum``: its chunk scan gathers out of the whole
+    ``[G+1, F]`` table in HBM, and XLA stores such a table with the
+    *vertex* axis minor when ``F < 128`` — one gathered row then
+    touches ``F`` tiles; at 128 the table is row-major and a row is
+    one tile line.  Why not ``sectioned`` (nor ``bdense``, whose
+    residual is ``sectioned``'s scan): it gathers out of a section
+    block staged in VMEM, which XLA lane-pads by itself, so the pad
+    buys nothing there — raced on the v5e at Reddit shape, 41 against
+    128 wide (my chip run, PR 32): the op 944.8 ms an epoch both ways,
+    its chunk gather 1.93 / 1.94 ms, ``epoch_ms`` 2,275.06 / 2,275.14,
+    ``peak_hbm_gib`` 13.063 both.  ``ell`` as a sum layout,
+    ``segment``, the ``ring`` halo and the MAX scans have no cell and
+    no measurement, and stay unpadded.  The products numbers are in
+    PERF §6, PR 32."""
+    if (aggr_impl == "flat_sum" and halo == "gather"
+            and feat_width < LANE_WIDTH):
+        return LANE_WIDTH
+    return feat_width
+
+
 def section_sub_counts(row_ptr: np.ndarray, col_idx: np.ndarray,
                        num_rows: int, src_rows: int,
                        section_rows: int = SECTION_ROWS_DEFAULT,

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 import numpy as np
 
+from ..core.ell import agg_lane_width
 from ..obs.scopes import (ATTN_SCORES_SCOPE, HALO_SCOPE, LOSS_SCOPE,
                           op_scope)
 from ..ops import dense
@@ -165,19 +166,31 @@ class GraphContext:
     head_chunk: int = 0
     axis_name: str = PARTS_AXIS
 
-    def agg_window(self) -> dict:
+    def agg_window(self, ops=()) -> dict:
         """How far the chunk scan's destination window engaged — the
         run manifest's ``resolved`` carries it (obs/manifest.py):
         rows a chunk step reads and writes per section
         (``scan_window_rows`` of the table's ``win_rows``) against the
-        carry's height.  Empty for the layouts that scan no carry."""
+        carry's height.  Empty for the layouts that scan no carry.
+        Beside it ``agg_lane_pad``: one ``[op, F, Fp]`` entry per
+        sum-aggregating op of ``ops`` (``Model._ops``; SUM and AVG
+        ``scatter_gather``, ``fused_aggregate``) — the width ``F`` the
+        model gives the op and the width ``Fp`` its scan runs at
+        (``core/ell.py agg_lane_width``; ``Fp == F`` where the rule
+        did not engage)."""
         carry = self.num_rows + 1
         wins = [m[2] for m in self.sect_meta if len(m) > 2]
         if self.flat8_win:
             wins = [self.flat8_win]
+        pads = [[i, op.dim, self._lane_width(op.dim)]
+                for i, op in enumerate(ops)
+                if op.kind == "fused_aggregate"
+                or (op.kind == "scatter_gather"
+                    and op.attrs["aggr"] in (AGGR_SUM, AGGR_AVG))]
         return {"agg_window_rows": [scan_window_rows(w, carry)
                                     for w in wins],
-                "agg_carry_rows": carry if wins else None}
+                "agg_carry_rows": carry if wins else None,
+                "agg_lane_pad": pads}
 
     def attention_plan(self, ops, ell_idx=None, flat8_idx=None) -> dict:
         """What each attention op of ``ops`` (``Model._ops``) runs on —
@@ -247,6 +260,24 @@ class GraphContext:
         zero = jnp.zeros((1, full.shape[1]), dtype=full.dtype)
         return jnp.concatenate([full, zero], axis=0)
 
+    def _lane_width(self, feat_width: int) -> int:
+        return agg_lane_width(feat_width, self.aggr_impl, self.halo)
+
+    def _lane_padded(self, agg, x: jax.Array) -> jax.Array:
+        """``agg(x)`` at the width :func:`core.ell.agg_lane_width`
+        gives: the local ``x`` zero-padded on its feature axis (before
+        the halo, so the exchange moves lane-wide rows too), the result
+        sliced back.  The cotangent takes the same road — the slice
+        transposes to the pad — so the symmetric backward runs at the
+        padded width as well.  Zeros in the spare lanes change no
+        value: the real columns are bit-identical to the unpadded
+        scan's."""
+        F = x.shape[1]
+        Fp = self._lane_width(F)
+        if Fp == F:
+            return agg(x)
+        return agg(jnp.pad(x, ((0, 0), (0, Fp - F))))[:, :F]
+
     def _sum_fwd(self, x: jax.Array) -> jax.Array:
         """Halo exchange + local CSR sum: ``out = A_p @ gather(x)``."""
         if self.halo == "ring":
@@ -301,7 +332,7 @@ class GraphContext:
         saving per-chunk residuals.  Set ``symmetric=False`` for exact
         autodiff through the forward (directed graphs)."""
         if not self.symmetric:
-            return self._sum_fwd(x)
+            return self._lane_padded(self._sum_fwd, x)
 
         @jax.custom_vjp
         def agg(x):
@@ -314,7 +345,7 @@ class GraphContext:
             return (self._sum_fwd(g),)
 
         agg.defvjp(fwd, bwd)
-        return agg(x)
+        return self._lane_padded(agg, x)
 
     def _fused_sum_fwd(self, x: jax.Array) -> jax.Array:
         """One-pass ``D^-1/2 A D^-1/2 x`` (the GCN sandwich of
@@ -390,7 +421,7 @@ class GraphContext:
         including the shard-level identity row-slice_p(S^T g) = S_p g.
         ``symmetric=False`` falls back to exact autodiff."""
         if not self.symmetric:
-            return self._fused_sum_fwd(x)
+            return self._lane_padded(self._fused_sum_fwd, x)
 
         @jax.custom_vjp
         def agg(x):
@@ -403,7 +434,7 @@ class GraphContext:
             return (self._fused_sum_fwd(g),)
 
         agg.defvjp(fwd, bwd)
-        return agg(x)
+        return self._lane_padded(agg, x)
 
     def aggregate(self, x: jax.Array, aggr: str = AGGR_SUM) -> jax.Array:
         if aggr == AGGR_SUM:

@@ -68,7 +68,9 @@ def run_manifest(config=None, dataset=None, model=None,
     ``agg_window`` (``GraphContext.agg_window()``, and with it
     ``GraphContext.attention_plan()``) joins ``resolved``: the chunk
     scan's window rows per section and the carry's height, so a run
-    says how far the windowed scatter engaged; one ``attention`` entry
+    says how far the windowed scatter engaged; ``agg_lane_pad``, one
+    ``[op, F, Fp]`` per sum-aggregating op (the model's width and the
+    lane-padded width its scan runs at); one ``attention`` entry
     per attention op (heads, head width, layout, passes over the edge
     tables, slots a pass, carry rows) and one ``attention_backward``
     entry (the gradient rule, its edge passes, the whole-array

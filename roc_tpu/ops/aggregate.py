@@ -149,6 +149,15 @@ def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
     or taller than half the carry) the window IS the carry, and XLA
     folds the slice and the write-back away: the whole-carry scatter.
 
+    The body is width-agnostic and runs at whatever ``F`` its operands
+    have.  ``flat_sum`` through ``GraphContext`` never has one under
+    the 128 lanes: a narrower sum is zero-padded on its feature axis
+    first (``core/ell.py agg_lane_width``), because XLA stores a
+    whole ``[rows, F < 128]`` table in HBM with the vertex axis minor
+    and a row gather then touches ``F`` tiles; a section block staged
+    in VMEM is lane-padded by XLA itself, so ``sectioned`` keeps the
+    model's width (PERF §6, PR 32).
+
     xs: ``(idx [n, seg, 8], dst [n, seg])`` plus optional weights
     shaped like ``idx``."""
     carry_rows, F = out.shape
@@ -184,6 +193,9 @@ def aggregate_ell_sect(feats: jax.Array, sect_idx, sect_sub_dst,
 
     feats: [src_rows(+ optional trailing rows), F]; sections read
       ``[start, start+size)`` so an appended global dummy row is fine.
+      ``F`` is the model's own width, narrow or not: the section
+      block is lane-padded in VMEM by XLA, and padding ``F`` by hand
+      measured no gain (``core/ell.py agg_lane_width``).
     sect_idx / sect_sub_dst: SectionedEll.idx / .sub_dst as jax arrays.
     sect_meta: static tuple of (start, size, win_rows) per section
       (SectionedEll.meta); a bare (start, size) scans with the whole
@@ -226,7 +238,11 @@ def aggregate_flat_sum(feats: jax.Array, flat_idx: jax.Array,
     cache and the prewarm pass (utils/prewarm.py) cover large graphs.
 
     feats: [G+1, F] gathered features with trailing zero row (== the
-      dummy id in ``flat_idx``).
+      dummy id in ``flat_idx``).  ``GraphContext`` hands in ``F >=
+      128``: a narrower sum arrives zero-padded (``core/ell.py
+      agg_lane_width``), so the whole table is row-major in HBM and a
+      gathered row is one tile line; a direct call at any ``F`` gives
+      the same real columns, bit for bit.
     flat_idx: int32 [n_chunks, seg_rows, 8]; flat_dst: int32
       [n_chunks, seg_rows] output rows, ascending within each chunk
       (chunk padding points at ``num_rows``).

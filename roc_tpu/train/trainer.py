@@ -1070,7 +1070,7 @@ class Trainer:
         run_manifest(config=self.config, dataset=dataset, model=model,
                      extra={"modeled_step_bytes": self._modeled_bytes},
                      agg_window={
-                         **self.gctx.agg_window(),
+                         **self.gctx.agg_window(model._ops),
                          **self.gctx.attention_plan(model._ops)},
                      console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog

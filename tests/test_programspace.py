@@ -487,7 +487,10 @@ def test_flat_sum_single_scan_program(rig_dataset):
 def test_flat_sum_rig_one_scan_per_width(rig_dataset):
     """The flat-sum rig (gin_flat8, two aggregation widths F and H):
     the distributed train step's distinct scan programs == one per
-    (dtype, F-quantum) — the tentpole claim, pinned."""
+    (dtype, F-quantum) — the tentpole claim, pinned.  The quantum is
+    the width the scan runs at (``core/ell.py agg_lane_width``): both
+    of the rig's widths sit under the 128 lanes, so both layers share
+    ONE scan program."""
     spec = rig_configs()["gin_flat8"]
     if spec.parts > len(jax.devices()):
         pytest.skip(f"needs {spec.parts} devices")
@@ -505,7 +508,10 @@ def test_flat_sum_rig_one_scan_per_width(rig_dataset):
     widths = {op.dim for op in tr.model._ops
               if op.kind == "scatter_gather"}
     assert len(widths) == 2          # GIN aggregates at F and H
-    assert len(shapes) == len(widths), shapes
+    from roc_tpu.core.ell import agg_lane_width
+    quanta = {agg_lane_width(w, "flat_sum") for w in widths}
+    assert quanta == {128}
+    assert len(shapes) == len(quanta), shapes
 
 
 # -------------------------------------------- program budget ratchet
