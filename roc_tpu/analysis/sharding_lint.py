@@ -1255,13 +1255,11 @@ def audit_rig(name: str, spec, tr, ds,
 
     # mesh-portability: modeled per-device HBM at every (parts,
     # model) shape of the rig, from the planner's per-axis model
-    layer_dims = _layer_dims_of(tr, ds)
     shapes = []
     for p, m in candidate_mesh_shapes():
         ax = per_axis_plan_bytes(
             int(ds.graph.num_nodes), int(ds.graph.num_edges),
-            layer_dims,
-            parts=p, model=m,
+            tr.model._ops, parts=p, model=m,
             halo=getattr(tr.config, "halo", "gather"),
             features=getattr(tr.config, "features", "hbm"),
             remat=bool(getattr(tr.config, "remat", False)))
@@ -1289,21 +1287,6 @@ def audit_rig(name: str, spec, tr, ds,
     if budget is not None:
         report["delta"] = measured - budget
     return findings, report
-
-
-def _layer_dims_of(tr, ds) -> List[int]:
-    """CLI-style layer dims for the plan model, reconstructed from
-    the parameter matrices (in-dim, hiddens..., classes) — coarse on
-    MLP-per-layer models, which is fine: the plan model itself is
-    coarse by design."""
-    import jax
-    C = int(ds.num_classes)
-    F = int(ds.in_dim)
-    mats = [tuple(int(d) for d in leaf.shape)
-            for leaf in jax.tree_util.tree_leaves(tr.params)
-            if len(getattr(leaf, "shape", ())) == 2]
-    hiddens = sorted({s[1] for s in mats} - {C, F})
-    return [F] + hiddens + [C]
 
 
 # ------------------------------------------------------------ stage

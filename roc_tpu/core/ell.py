@@ -424,6 +424,19 @@ def chunk_window_rows(sub_dst: np.ndarray, num_rows: int) -> int:
 # transient [seg, 8, F] at 64 MiB for F=256 fp32 — the same bound the
 # attention flat8 tables use (they are the same layout).
 FLAT_SEG_ROWS = 8192
+# sub-rows a chunk of a section's scan holds (the sweep below chose it)
+SECT_SEG_ROWS = 131_072
+
+
+def scan_chunk_rows(aggr_impl: str, num_edges: int) -> int:
+    """Sub-rows one step of a width-8 scan layout gathers at most: the
+    layout's chunk height, or every sub-row of a graph smaller than
+    one chunk; 0 for the layouts that scan no chunks.  What the memory
+    plan charges a step's scratch by (``core/memory.py``): the
+    ``[rows, 8, F]`` gathered block and its ``[rows, F]`` sum."""
+    rows = {"sectioned": SECT_SEG_ROWS, "bdense": SECT_SEG_ROWS,
+            "flat_sum": FLAT_SEG_ROWS}.get(aggr_impl, 0)
+    return min(rows, -(-num_edges // 64) * 8)
 
 # Edge count past which the resolve pass routes an 'ell'-bound auto
 # resolution to the uniform 'flat_sum' layout instead: the per-width
@@ -711,7 +724,7 @@ def _resolve_chunks(counts, seg_rows: int, chunks_plan,
 def sectioned_from_graph(row_ptr: np.ndarray, col_idx: np.ndarray,
                          num_rows: int, src_rows: int = None,
                          section_rows: int = SECTION_ROWS_DEFAULT,
-                         seg_rows: int = 131_072,
+                         seg_rows: int = SECT_SEG_ROWS,
                          chunks_plan=None, counts=None,
                          sub_w: int = 8) -> SectionedEll:
     """Build the sectioned layout from a dst-major CSR.
@@ -809,7 +822,7 @@ def sectioned_from_graph(row_ptr: np.ndarray, col_idx: np.ndarray,
 
 
 def sectioned_plan(counts_max: np.ndarray,
-                   seg_rows: int = 131_072) -> Tuple[int, list]:
+                   seg_rows: int = SECT_SEG_ROWS) -> Tuple[int, list]:
     """(seg_rows, per-section chunk counts) from elementwise-maxed
     per-partition sub-row counts — THE single place the uniform-shape
     agreement math lives (used by the all-parts builder and the
@@ -837,7 +850,7 @@ def sectioned_from_padded_parts(part_row_ptr: np.ndarray,
                                 real_nodes: np.ndarray,
                                 part_nodes: int, src_rows: int,
                                 section_rows: int = SECTION_ROWS_DEFAULT,
-                                seg_rows: int = 131_072,
+                                seg_rows: int = SECT_SEG_ROWS,
                                 sub_w: int = 8) -> SectionedEll:
     """Uniform stacked per-part sectioned tables for the SPMD step:
     ``idx[s]`` is ``[P, n_chunks_s, seg_rows, sub_w]`` and

@@ -15,8 +15,14 @@ budget:
 - ``features``: HBM-resident input features vs host-resident features
   streamed through the first layer (core/streaming.py — the direct
   analog of the reference's ZC->FB staging);
-- ``remat``: recompute activations in backward instead of saving them
-  (``jax.checkpoint``).
+- ``remat``: compute each run of ops between two aggregations again in
+  the backward instead of keeping its insides (one checkpoint a run,
+  ``models/builder.py Model.apply``).
+
+What a step keeps for its backward is read off the model's op list by
+ONE rule for every family (:func:`op_residuals`): the plan charges what
+this op list keeps, so a second ``linear`` reading a kept input, or a
+``lerp``, costs what it costs.
 
 :func:`choose_memory_plan` estimates the footprint of each viable
 combination (cheapest-first) and returns the first that fits, so a
@@ -29,22 +35,166 @@ print (``gnn.cc:48-60``).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-# Activation-liveness factors: a GCN-family layer keeps roughly this
-# many [V_p, H] intermediates alive for backward (dropout out, linear
-# out, two norms, aggregation out, relu out) without remat; with
-# jax.checkpoint the layer boundaries survive plus the saved
-# aggregation outputs (the default save_aggregates policy,
-# train/trainer.py remat_policy — recomputing the halo gather + CSR
-# sum would dominate the remat overhead).
-_ACT_FACTOR_SAVED = 6
-_ACT_FACTOR_REMAT_SAVE_AGG = 3   # layer boundaries + saved aggregates
-_ACT_FACTOR_REMAT_FULL = 2       # layer boundaries only
 # Default usable fraction of physical HBM (XLA reserves workspace,
 # and the estimate is deliberately coarse).
 _USABLE = 0.85
 _DEFAULT_HBM = 16 * 1024**3  # v5e physical per chip
+_MASK_BYTES = 1              # a dropout / ReLU predicate, per element
+_STAT_BYTES = 4              # fp32 by-products (softmax, attention)
+
+# the model op kinds that aggregate over edges (obs/scopes.py AGG_KINDS:
+# this module imports nothing, so the tuple is repeated); under remat
+# they are the boundaries of the runs computed again
+AGG_KINDS = ("scatter_gather", "fused_aggregate", "gat")
+
+
+def op_residuals(i: int, op: Any, itemsize: int
+                 ) -> List[Tuple[Tuple[str, int], int, int]]:
+    """What model op ``i`` keeps from its forward for its backward, by
+    the op's kind alone — the ONE rule the plan charges activations by,
+    for every model family: ``[(key, width, bytes per element), ...]``.
+    ``key`` names the array, so that an array two ops keep is charged
+    once: ``("t", j)`` is model tensor ``j`` (op ``j``'s output, in the
+    compute dtype), ``("m", i)`` a by-product of op ``i`` itself.
+
+    | kind | keeps |
+    | --- | --- |
+    | ``linear`` | its input (for dW; dX needs W alone); its output too under a fused activation |
+    | ``dropout`` (rate > 0) | the keep mask, a byte an element |
+    | ``activation`` | its output (ReLU's sign, sigmoid's and ELU's value) |
+    | ``add``, ``lerp``, ``indegree_norm`` | nothing: linear in their inputs with constants |
+    | ``mul`` | both inputs; ``scale_add``: the scaled one (for d eps) |
+    | ``scatter_gather`` SUM / AVG, ``fused_aggregate`` | nothing: the backward is the same sum over the cotangent (a fused ReLU keeps the output) |
+    | ``scatter_gather`` MAX / MIN | input and output (where the max sat) |
+    | ``gat`` | input, output and the fp32 row sums of the hand-written backward |
+    """
+    def t(j):
+        return ("t", j)
+
+    kind, attrs = op.kind, getattr(op, "attrs", None) or {}
+    if kind == "linear":
+        out = [(t(op.inputs[0]), attrs.get("in_dim", op.dim), itemsize)]
+        if attrs.get("activation", "none") != "none":
+            out.append((t(i), op.dim, itemsize))
+        return out
+    if kind == "dropout":
+        return ([(("m", i), op.dim, _MASK_BYTES)]
+                if attrs.get("rate", 0) > 0 else [])
+    if kind == "activation":
+        return [(t(i), op.dim, itemsize)]
+    if kind == "mul":
+        return [(t(j), op.dim, itemsize) for j in op.inputs]
+    if kind == "scale_add":
+        return [(t(op.inputs[1]), op.dim, itemsize)]
+    if kind == "fused_aggregate":
+        return ([(t(i), op.dim, itemsize)]
+                if attrs.get("activation", "none") != "none" else [])
+    if kind == "scatter_gather":
+        if attrs.get("aggr", "sum") in ("max", "min"):
+            return [(t(op.inputs[0]), op.dim, itemsize),
+                    (t(i), op.dim, itemsize)]
+        return []
+    if kind == "gat":
+        return [(t(op.inputs[0]), op.dim, itemsize),
+                (t(i), op.dim, itemsize), (("m", i), op.dim, _STAT_BYTES)]
+    return []
+
+
+def remat_segments(ops: Sequence[Any]) -> List[Tuple[int, int]]:
+    """``[(lo, hi), ...]``: the maximal runs ``ops[lo:hi]`` of
+    consecutive model ops that do not aggregate over edges — what
+    ``Model.apply(remat=True)`` makes one checkpoint each,
+    and what the plan charges a step under remat by.  The ONE home of
+    the boundary rule."""
+    out, lo = [], None
+    for i in range(1, len(ops) + 1):
+        inside = i < len(ops) and ops[i].kind not in AGG_KINDS
+        if inside and lo is None:
+            lo = i
+        elif not inside and lo is not None:
+            out.append((lo, i))
+            lo = None
+    return out
+
+
+def saved_for_backward(ops: Sequence[Any], itemsize: int,
+                       remat: bool = False
+                       ) -> Tuple[List[Tuple[int, int, int]], int]:
+    """``(kept, recompute_row_bytes)``: the arrays a train step holds
+    from its forward into its backward, as ``[(op index, arrays, bytes
+    per vertex row), ...]`` — one entry per op that is the first to
+    keep an array, so a second ``linear`` reading a kept input, or a
+    ``lerp``, adds nothing — and the bytes per vertex row the backward
+    holds besides while it computes one run again (0 without remat).
+
+    Without remat: every op's :func:`op_residuals`, and the loss's (the
+    logits and their fp32 softmax).  Under remat
+    (:func:`remat_segments`): what each run reads from outside itself,
+    its dropout masks (the key does not pass the run's barrier, so XLA
+    folds the second draw into the first and keeps the mask: a byte an
+    element, and no random bits drawn twice), the aggregations' own
+    residuals and the loss's; and, one run at a time, that run's other
+    residuals — the largest is charged.  The input features (tensor 0)
+    are the plan's ``features`` component and are not charged again.
+
+    Not charged: the cotangent a ``linear``'s weight gradient waits
+    for.  With memory to spare XLA puts those products off to the end
+    of the backward (the TPU compiler's buffer assignment at 4 and 16
+    layers: 2 bytes an element a layer more than this rule), under
+    pressure it does not (the chip at 48 layers: PERF.md section 6,
+    PR 33)."""
+    seen = {("t", 0)}
+    kept: List[Tuple[int, int, int]] = []
+
+    def charge(i, items):
+        new = [(k, w, b) for k, w, b in dict.fromkeys(items)
+               if k not in seen]
+        seen.update(k for k, _, _ in new)
+        if new:
+            kept.append((i, len(new), sum(w * b for _, w, b in new)))
+
+    runs = dict(remat_segments(ops)) if remat else {}
+    inside = {k for lo, hi in runs.items() for k in range(lo, hi)}
+    for i in range(1, len(ops)):
+        if i in runs:
+            charge(i, [(("t", j), ops[j].dim, itemsize)
+                       for op in ops[i:runs[i]] for j in op.inputs
+                       if j < i])
+        if i not in inside or ops[i].kind == "dropout":
+            charge(i, op_residuals(i, ops[i], itemsize))
+    if len(ops) > 1:
+        last = len(ops) - 1
+        charge(last, [(("t", last), ops[last].dim, itemsize),
+                      (("m", last), ops[last].dim, _STAT_BYTES)])
+    recompute = 0
+    for lo, hi in runs.items():
+        items = dict.fromkeys(
+            r for k in range(lo, hi)
+            for r in op_residuals(k, ops[k], itemsize)
+            if r[0] not in seen)
+        recompute = max(recompute, sum(w * b for _, w, b in items))
+    return kept, recompute
+
+
+def param_elems(ops: Sequence[Any]) -> int:
+    """Trainable scalars of the op list: every ``linear``'s matrix,
+    every ``gat``'s two attention vectors, every ``scale_add``'s eps."""
+    n = 0
+    for op in ops:
+        if op.kind == "linear":
+            n += op.attrs["in_dim"] * op.dim
+        elif op.kind == "gat":
+            n += 2 * op.dim
+        elif op.kind == "scale_add":
+            n += 1
+    return n
+
+
+def model_depth(ops: Sequence[Any]) -> Dict[str, int]:
+    return {"aggregating_ops": sum(op.kind in AGG_KINDS for op in ops),
+            "linear_ops": sum(op.kind == "linear" for op in ops)}
 
 
 def charged_table_bytes(aggr_impl: str, uses_attention: bool,
@@ -111,73 +261,90 @@ class MemoryPlan:
                 f"{self.budget_bytes / gib:.2f} GiB budget; {self.reason}")
 
 
-def estimate_plan_bytes(num_nodes: int, num_edges: int,
-                        layer_dims: Sequence[int], num_parts: int = 1,
-                        dtype_bytes: int = 4, halo: str = "gather",
-                        features: str = "hbm", remat: bool = False,
-                        ring_padding: float = 1.7,
-                        remat_policy: str = "save_aggregates",
-                        extra_table_bytes: int = 0) -> int:
-    """Coarse per-device peak-HBM estimate for one train step.
+def plan_components(num_nodes: int, num_edges: int,
+                    ops: Sequence[Any], num_parts: int = 1,
+                    dtype_bytes: int = 4, halo: str = "gather",
+                    features: str = "hbm", remat: bool = False,
+                    ring_padding: float = 1.7,
+                    extra_table_bytes: int = 0,
+                    param_bytes: int = 4,
+                    scan_rows: int = 0, kept=None) -> Dict[str, int]:
+    """Coarse per-device peak-HBM estimate for one train step, by
+    component: ``params_opt`` (master parameters, Adam's two moments,
+    the gradients, the compute-dtype copy), ``features``, ``tables``,
+    ``activations`` (:func:`saved_for_backward`) and ``transient`` (the
+    halo's gathered matrix or the ring's two buffers; a scan layout's
+    chunk, ``scan_rows`` sub-rows of 8 gathered rows and their sum,
+    ``core/ell.py scan_chunk_rows``; under remat the run being computed
+    again).
 
-    ``layer_dims`` is the CLI layer spec (in-dim, hidden..., classes).
-    Deliberately simple and slightly pessimistic — the policy needs
-    ordering between plans, not byte-exact numbers.
+    ``ops`` is the model's op list (``Model._ops``: ``kind``,
+    ``inputs``, ``dim``, ``attrs``), first the input.  Deliberately
+    simple — the policy needs ordering between plans and a slope in
+    depth, not byte-exact numbers; of a layout's scratch it knows one
+    chunk, not what XLA reserves beside it (PERF.md section 5).
 
     ``extra_table_bytes`` covers impl-specific resident tables the
     generic ``E*4`` term misses — today the bdense A-table, whose
     worst case is exactly ``bdense_a_budget`` (the planner's device-
-    byte cap)."""
+    byte cap).  ``kept``: :func:`saved_for_backward`'s result where the
+    caller has it already."""
     V_p = -(-num_nodes // num_parts)
     E_p = -(-num_edges // num_parts)
     b = dtype_bytes
-    F = layer_dims[0]
-    hiddens = list(layer_dims[1:])
-    h_max = max(hiddens + [F])
-
-    # replicated params + Adam m/v
-    w = sum(layer_dims[i] * layer_dims[i + 1]
-            for i in range(len(layer_dims) - 1))
-    total = 3 * w * b
-
-    # input features
-    if features == "hbm":
-        total += V_p * F * b
-    else:
-        total += 65536 * F * b  # one streamed block + dY reuse
-
+    F = ops[0].dim
+    h_max = max(op.dim for op in ops)
+    w = param_elems(ops)
+    out = {"params_opt": w * (4 * param_bytes + b)}
+    # input features: resident, or one streamed block + dY reuse
+    out["features"] = (V_p if features == "hbm" else 65536) * F * b
     # edge tables: ELL idx ~ E_p int32 (+ row positions)
-    total += E_p * 4 + V_p * 4 + extra_table_bytes
+    out["tables"] = E_p * 4 + V_p * 4 + extra_table_bytes
     if halo == "ring":
-        total += int(2 * E_p * 4 * ring_padding)  # src+dst flat tables
-
-    # live activations
-    if remat:
-        act = (_ACT_FACTOR_REMAT_FULL if remat_policy == "full"
-               else _ACT_FACTOR_REMAT_SAVE_AGG)
-    else:
-        act = _ACT_FACTOR_SAVED
-    act_bytes = sum(V_p * h * b * act for h in hiddens)
-    if features == "hbm":
-        # first dropout output is [V_p, F]
-        act_bytes += V_p * F * b * (1 if remat else 2)
-    total += act_bytes
-
+        out["tables"] += int(2 * E_p * 4 * ring_padding)  # src+dst flat
+    kept, recompute = kept or saved_for_backward(ops, b, remat)
+    out["activations"] = V_p * sum(row for _, _, row in kept)
+    if features != "hbm":
+        # the streamed head's insides never sit whole on the device
+        head = {i for i, op in enumerate(ops[:3]) if i}
+        out["activations"] -= V_p * sum(
+            row for i, _, row in kept if i in head and ops[i].dim == F)
     # halo transient: the gathered global matrix vs two ring buffers
-    if halo == "gather":
-        total += num_parts * V_p * h_max * b
-    else:
-        total += 2 * V_p * h_max * b
-    return total
+    out["transient"] = ((num_parts if halo == "gather" else 2)
+                        * V_p * h_max * b + V_p * recompute
+                        + scan_rows * 9 * h_max * b)
+    return out
+
+
+def estimate_plan_bytes(num_nodes: int, num_edges: int,
+                        ops: Sequence[Any], **kw) -> int:
+    """:func:`plan_components`, summed."""
+    return sum(plan_components(num_nodes, num_edges, ops, **kw).values())
+
+
+def describe_plan(num_nodes: int, num_edges: int, ops: Sequence[Any],
+                  **kw) -> Dict[str, Any]:
+    """The resolved plan for the run manifest's ``memory_plan``: the
+    estimate by component, what each op was charged (``[op index, kind,
+    arrays, bytes per vertex row]``), remat, and the model's depth."""
+    remat = bool(kw.get("remat", False))
+    kept, recompute = saved_for_backward(
+        ops, kw.get("dtype_bytes", 4), remat)
+    comps = plan_components(num_nodes, num_edges, ops,
+                            kept=(kept, recompute), **kw)
+    return {"est_bytes": sum(comps.values()), "components": comps,
+            "saved": [[i, ops[i].kind, n, row] for i, n, row in kept],
+            "saved_arrays": sum(n for _, n, _ in kept),
+            "recompute_row_bytes": recompute, "remat": remat,
+            "remat_runs": len(remat_segments(ops)) if remat else 0,
+            **model_depth(ops)}
 
 
 def per_axis_plan_bytes(num_nodes: int, num_edges: int,
-                        layer_dims: Sequence[int], parts: int = 1,
+                        ops: Sequence[Any], parts: int = 1,
                         model: int = 1, dtype_bytes: int = 4,
                         halo: str = "gather", features: str = "hbm",
-                        remat: bool = False,
-                        remat_policy: str = "save_aggregates",
-                        ring_padding: float = 1.7
+                        remat: bool = False, ring_padding: float = 1.7
                         ) -> Dict[str, Dict[str, int]]:
     """Per-component, per-mesh-axis byte attribution of one train
     step on an abstract ``(parts, model)`` mesh — the planner-side
@@ -185,9 +352,9 @@ def per_axis_plan_bytes(num_nodes: int, num_edges: int,
     (analysis/sharding_lint.py) and the "modeled per-device HBM"
     column of the mesh-portability report.
 
-    Same coarse accounting as :func:`estimate_plan_bytes` (whose
-    ``parts``-only totals this reproduces at ``model=1``), but each
-    component reports WHICH axes divide it: params/opt-state and
+    :func:`plan_components`' accounting (whose per-device numbers at
+    ``parts`` partitions these are, times ``parts``), with each
+    component reporting WHICH axes divide it: params/opt-state and
     activations split over ``model`` on their feature axis (the 2-D
     design's pjit'd dense ops), vertex-scale tensors split over
     ``parts``, edge/halo index tables split over ``parts`` only —
@@ -198,14 +365,12 @@ def per_axis_plan_bytes(num_nodes: int, num_edges: int,
     "model_div": m, "per_device": total // (p*m)}}`` plus a
     ``"total"`` row; ``replicated`` in a component marks the axes
     (divisor 1 while the mesh axis is >1) it is replicated over."""
-    V_p = -(-num_nodes // max(parts, 1))
-    E_p = -(-num_edges // max(parts, 1))
-    b = dtype_bytes
-    F = layer_dims[0]
-    hiddens = list(layer_dims[1:])
-    h_max = max(hiddens + [F])
-    w = sum(layer_dims[i] * layer_dims[i + 1]
-            for i in range(len(layer_dims) - 1))
+    parts = max(parts, 1)
+    c = plan_components(num_nodes, num_edges, ops, num_parts=parts,
+                        dtype_bytes=dtype_bytes, halo=halo,
+                        features=features, remat=remat,
+                        ring_padding=ring_padding,
+                        param_bytes=dtype_bytes)
 
     def comp(total: int, parts_div: int, model_div: int
              ) -> Dict[str, int]:
@@ -219,57 +384,35 @@ def per_axis_plan_bytes(num_nodes: int, num_edges: int,
                 "model_div": model_div, "per_device": per_dev,
                 "replicated": rep}
 
-    out: Dict[str, Dict[str, int]] = {}
     # params + Adam m/v: feature-axis (model) sharded on the 2-D
     # mesh, replicated over parts either way (the reference reads
-    # weights whole in every task)
-    out["params"] = comp(w * b, 1, model)
-    out["opt_state"] = comp(2 * w * b, 1, model)
-    if features == "hbm":
-        out["features"] = comp(num_nodes * F * b, parts, model)
-    else:
-        out["features"] = comp(65536 * F * b * parts, parts, model)
-    # edge/halo index tables: int32 per edge + row positions — no
-    # feature axis, so the model axis replicates them
-    tab = E_p * 4 * parts + V_p * 4 * parts
-    if halo == "ring":
-        tab += int(2 * E_p * 4 * ring_padding) * parts
-    out["tables"] = comp(tab, parts, 1)
-    if remat:
-        act = (_ACT_FACTOR_REMAT_FULL if remat_policy == "full"
-               else _ACT_FACTOR_REMAT_SAVE_AGG)
-    else:
-        act = _ACT_FACTOR_SAVED
-    act_bytes = sum(num_nodes * h * b * act for h in hiddens)
-    if features == "hbm":
-        act_bytes += num_nodes * F * b * (1 if remat else 2)
-    out["activations"] = comp(act_bytes, parts, model)
-    # halo transient: the gathered whole-region matrix is per-device
-    # [P * V_p, h] — replicated over parts BY DESIGN (that is what a
-    # gather is), feature-sharded over model; the ring keeps two
-    # block buffers instead
-    if halo == "gather":
-        out["halo"] = comp(parts * V_p * h_max * b * parts, parts,
-                           model)
-    else:
-        out["halo"] = comp(2 * V_p * h_max * b * parts, parts, model)
-    total = sum(c["bytes"] for c in out.values())
-    per_dev = sum(c["per_device"] for c in out.values())
+    # weights whole in every task); every other component is a
+    # partition's share, so the whole is ``parts`` of them.  Edge/halo
+    # index tables carry no feature axis: the model axis replicates
+    # them.  The gathered whole-region matrix is per-device [P * V_p,
+    # h] — replicated over parts BY DESIGN (that is what a gather is)
+    out = {"params_opt": comp(c["params_opt"], 1, model),
+           "features": comp(c["features"] * parts, parts, model),
+           "tables": comp(c["tables"] * parts, parts, 1),
+           "activations": comp(c["activations"] * parts, parts, model),
+           "transient": comp(c["transient"] * parts, parts, model)}
+    total = sum(v["bytes"] for v in out.values())
+    per_dev = sum(v["per_device"] for v in out.values())
     out["total"] = {"bytes": int(total), "per_device": int(per_dev),
-                    "replicated": sorted({a for c in out.values()
-                                          for a in c.get("replicated",
+                    "replicated": sorted({a for v in out.values()
+                                          for a in v.get("replicated",
                                                          [])})}
     return out
 
 
 def choose_memory_plan(num_nodes: int, num_edges: int,
-                       layer_dims: Sequence[int], num_parts: int = 1,
+                       ops: Sequence[Any], num_parts: int = 1,
                        dtype_bytes: int = 4,
                        hbm_bytes: Optional[int] = None,
                        head_streamable: bool = True,
-                       remat_policy: str = "save_aggregates",
-                       extra_table_bytes: int = 0
-                       ) -> MemoryPlan:
+                       extra_table_bytes: int = 0,
+                       param_bytes: int = 4,
+                       scan_rows: int = 0) -> MemoryPlan:
     """First-fit over plans ordered cheapest-compute-first.
 
     Order: gather/hbm -> gather/hbm+remat -> ring (P>1, +-remat) ->
@@ -291,9 +434,10 @@ def choose_memory_plan(num_nodes: int, num_edges: int,
     est = {}
     for name, halo, feats, remat in cands:
         est[name] = estimate_plan_bytes(
-            num_nodes, num_edges, layer_dims, num_parts, dtype_bytes,
+            num_nodes, num_edges, ops, num_parts=num_parts,
+            dtype_bytes=dtype_bytes, param_bytes=param_bytes,
+            scan_rows=scan_rows if halo == "gather" else 0,
             halo=halo, features=feats, remat=remat,
-            remat_policy=remat_policy,
             # ring runs never build the bdense A-table (the ring
             # tables fully describe the aggregation) — charging them
             # would push ring plans into remat for phantom bytes

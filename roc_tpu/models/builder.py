@@ -26,12 +26,12 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 import numpy as np
 
 from ..core.ell import agg_lane_width
+from ..core.memory import remat_segments
 from ..obs.scopes import (ATTN_SCORES_SCOPE, HALO_SCOPE, LOSS_SCOPE,
-                          op_scope)
+                          RECOMPUTE_SCOPE, op_scope)
 from ..ops import dense
 from ..parallel import PARTS_AXIS
 from ..ops.aggregate import (aggregate_ell, aggregate_ell_max,
@@ -638,6 +638,39 @@ jax.tree_util.register_pytree_node(GraphContext, _gctx_flatten,
                                    _gctx_unflatten)
 
 
+def _computed_again(run):
+    """``run(params, *xs) -> outputs`` as the backward keeps it under
+    remat: its inputs and nothing of its insides, which the backward
+    computes again — under the ``roc.recompute`` scope — when the
+    outputs' cotangent arrives, and not before.  Two barriers hold
+    XLA to that: ``jax.checkpoint`` alone leaves the scheduler free to
+    start a run's recomputation early and to finish its gradients
+    late, and the TPU compiler did both — three runs' recomputed
+    products were live at the peak, and remat saved a tenth of what it
+    should (PERF.md section 6, PR 33)."""
+
+    @jax.custom_vjp
+    def seg(p, *xs):
+        return run(p, *xs)
+
+    def fwd(p, *xs):
+        return run(p, *xs), (p, xs)
+
+    def bwd(res, g):
+        (p, xs), g = jax.lax.optimization_barrier((res, g))
+        with jax.named_scope(RECOMPUTE_SCOPE):
+            _, pull = jax.vjp(run, p, *xs)
+        # the second barrier: every gradient of the run, the weights'
+        # too, before the backward moves on — left alone, XLA puts the
+        # weight-gradient products off to the end of the backward and
+        # holds each run's cotangent (and what was computed again for
+        # it) until then
+        return jax.lax.optimization_barrier(pull(g))
+
+    seg.defvjp(fwd, bwd)
+    return seg
+
+
 @dataclass(frozen=True)
 class TensorHandle:
     """Symbolic tensor produced by builder calls (the analog of the
@@ -1068,108 +1101,160 @@ class Model:
 
     def apply(self, params: Dict[str, jax.Array], feats: jax.Array,
               gctx: GraphContext, key: Optional[jax.Array] = None,
-              train: bool = True) -> jax.Array:
-        """Run the recorded op list; returns the logits tensor."""
+              train: bool = True, remat: bool = False) -> jax.Array:
+        """Run the recorded op list; returns the logits tensor.
+
+        ``remat`` (the trainers' ``config.remat``, train steps only):
+        every run of ops between two aggregations
+        (``core/memory.py remat_segments``) is a checkpoint of its own
+        (:func:`_computed_again`), so the backward keeps only what such
+        a run reads — the aggregations' outputs and the few earlier
+        tensors a run reaches back for — and computes a run's insides
+        again when its turn comes, one run at a time.  The aggregations
+        stay outside: their symmetric backward keeps nothing, and a
+        checkpoint that
+        took one in would keep its input in place of its output and
+        pay the gather and the sum twice for the same bytes.  One
+        checkpoint around the whole objective, which this replaces,
+        kept the aggregates AND computed every run again before the
+        first transpose: more memory than no remat at all (PERF.md
+        section 6, PR 33)."""
         if (train and key is None and
                 any(op.kind == "dropout" and op.attrs["rate"] > 0
                     for op in self._ops)):
             raise ValueError(
                 "a PRNG key is required in train mode for models with "
                 "dropout; pass key= or use train=False")
-        vals: List[Optional[jax.Array]] = [None] * len(self._ops)
-        vals[0] = feats
-        n_dropout = 0
-        # the output head = the LAST linear (the classifier in every
-        # model family; the loss marker may sit on a later norm /
-        # propagation op, e.g. GCN's final indegree_norm)
-        head_idx = max((i for i, op in enumerate(self._ops)
-                        if op.kind == "linear"), default=-1)
-        for i, op in enumerate(self._ops[1:], start=1):
-            x = vals[op.inputs[0]] if op.inputs else None
-            # one scope per model op (obs/scopes.py): roc.agg.op<i> for
-            # the aggregating kinds, roc.dense.op<i>.<kind> for the rest.
-            # Entered here, outside the aggregations' custom_vjp, it is
-            # still on the name stack when JAX traces their backward
-            # (tests/test_scopes.py holds that)
-            with jax.named_scope(op_scope(i, op.kind)):
-                if op.kind == "dropout":
-                    if train and key is not None:
-                        sub = jax.random.fold_in(key, n_dropout)
-                    else:
-                        sub = None
-                    n_dropout += 1
-                    vals[i] = dense.dropout(x, op.attrs["rate"], sub, train)
-                elif op.kind == "linear":
-                    if gctx.head_chunk and i == head_idx \
-                            and x.shape[0] > gctx.head_chunk:
-                        # the classification head, chunked on the vertex
-                        # axis: the compiled matmul is [head_chunk, C]
-                        # regardless of V_p, so the head subprogram stays
-                        # small and shape-stable (bit-identical values —
-                        # each output row's dot product is unchanged; dW
-                        # differs only in fp summation order)
-                        vals[i] = dense.linear_chunked(
-                            x, params[op.param], op.attrs["activation"],
-                            gctx.head_chunk)
-                    else:
-                        vals[i] = dense.linear(x, params[op.param],
-                                               op.attrs["activation"])
-                elif op.kind == "indegree_norm":
-                    vals[i] = indegree_norm(x, gctx.in_degree)
-                elif op.kind == "scatter_gather":
-                    # named so the remat policy can SAVE aggregation
-                    # outputs: recomputing the halo gather + CSR sum in
-                    # backward is the one thing worth activation memory
-                    # (train/trainer.py remat_policy="save_aggregates")
-                    vals[i] = checkpoint_name(
-                        gctx.aggregate(x, op.attrs["aggr"]), "aggregate")
-                elif op.kind == "fused_aggregate":
-                    # norm -> sum -> norm [-> relu] in one op (fuse_norm_
-                    # aggregate).  The activation sits OUTSIDE the
-                    # symmetric custom_vjp (relu is nonlinear) but inside
-                    # this op's fusion scope, so XLA folds it into the
-                    # aggregation epilogue.  Same checkpoint name as
-                    # scatter_gather: the remat policy saves fused
-                    # aggregations identically.
-                    y = checkpoint_name(gctx.aggregate_fused(x),
-                                        "aggregate")
-                    if op.attrs.get("activation",
-                                    AC_MODE_NONE) != AC_MODE_NONE:
-                        y = dense.activation(y, op.attrs["activation"])
-                    vals[i] = y
-                elif op.kind == "gat":
-                    vals[i] = checkpoint_name(
-                        gctx.gat_attention(
-                            x, params[f"{op.param}_src"],
-                            params[f"{op.param}_dst"],
-                            neg_slope=op.attrs["neg_slope"]), "aggregate")
-                elif op.kind == "activation":
-                    vals[i] = dense.activation(x, op.attrs["mode"])
-                elif op.kind == "add":
-                    vals[i] = vals[op.inputs[0]] + vals[op.inputs[1]]
-                elif op.kind == "scale_add":
-                    eps = params[op.param].astype(vals[op.inputs[0]].dtype)
-                    vals[i] = (vals[op.inputs[0]]
-                               + eps * vals[op.inputs[1]])
-                elif op.kind == "mul":
-                    vals[i] = vals[op.inputs[0]] * vals[op.inputs[1]]
-                elif op.kind == "lerp":
-                    al = op.attrs["alpha"]
-                    vals[i] = ((1.0 - al) * vals[op.inputs[0]]
-                               + al * vals[op.inputs[1]])
-                else:
-                    raise ValueError(f"unknown op kind {op.kind}")
-        out_idx = self._loss_op if self._loss_op is not None else -1
+        ops = self._ops
+        vals: Dict[int, jax.Array] = {0: feats}
+        out_idx = (self._loss_op if self._loss_op is not None
+                   else len(ops) - 1)
+        if not (remat and train):
+            for i in range(1, len(ops)):
+                vals[i] = self._eval_op(i, vals, params, gctx, key, train)
+            return vals[out_idx]
+        read_at = {}                     # tensor -> last op that reads it
+        for i, op in enumerate(ops):
+            for j in op.inputs:
+                read_at[j] = i
+        segments = dict(remat_segments(ops))
+        i = 1
+        while i < len(ops):
+            hi = segments.get(i)
+            if hi is None:               # an aggregation: kept outside
+                vals[i] = self._eval_op(i, vals, params, gctx, key, train)
+                i += 1
+                continue
+            ins = sorted({j for op in ops[i:hi] for j in op.inputs
+                          if j < i})
+            outs = [k for k in range(i, hi)
+                    if read_at.get(k, -1) >= hi or k == out_idx]
+
+            names = {ops[k].param for k in range(i, hi)
+                     if ops[k].kind in ("linear", "scale_add")}
+
+            def run(p, *xs, lo=i, hi=hi, ins=ins, outs=outs):
+                local = dict(zip(ins, xs))
+                for k in range(lo, hi):
+                    local[k] = self._eval_op(k, local, p, gctx, key, train)
+                return tuple(local[k] for k in outs)
+
+            vals.update(zip(outs, _computed_again(run)(
+                {k: params[k] for k in names}, *(vals[j] for j in ins))))
+            i = hi
         return vals[out_idx]
+
+    def _eval_op(self, i: int, vals, params, gctx: GraphContext,
+                 key: Optional[jax.Array], train: bool) -> jax.Array:
+        """Model op ``i`` on the tensors ``vals`` holds (index ->
+        array), under the op's program scope."""
+        op = self._ops[i]
+        x = vals[op.inputs[0]] if op.inputs else None
+        # one scope per model op (obs/scopes.py): roc.agg.op<i> for
+        # the aggregating kinds, roc.dense.op<i>.<kind> for the rest.
+        # Entered here, outside the aggregations' custom_vjp, it is
+        # still on the name stack when JAX traces their backward
+        # (tests/test_scopes.py holds that)
+        with jax.named_scope(op_scope(i, op.kind)):
+            if op.kind == "dropout":
+                sub = None
+                if train and key is not None:
+                    # the stream of the op's ordinal among the dropouts
+                    sub = jax.random.fold_in(key, sum(
+                        o.kind == "dropout" for o in self._ops[:i]))
+                return dense.dropout(x, op.attrs["rate"], sub, train)
+            if op.kind == "linear":
+                # the output head = the LAST linear (the classifier in
+                # every model family; the loss marker may sit on a
+                # later norm / propagation op, e.g. GCN's final
+                # indegree_norm)
+                head = not any(o.kind == "linear"
+                               for o in self._ops[i + 1:])
+                if gctx.head_chunk and head \
+                        and x.shape[0] > gctx.head_chunk:
+                    # the classification head, chunked on the vertex
+                    # axis: the compiled matmul is [head_chunk, C]
+                    # regardless of V_p, so the head subprogram stays
+                    # small and shape-stable (bit-identical values —
+                    # each output row's dot product is unchanged; dW
+                    # differs only in fp summation order)
+                    return dense.linear_chunked(
+                        x, params[op.param], op.attrs["activation"],
+                        gctx.head_chunk)
+                return dense.linear(x, params[op.param],
+                                    op.attrs["activation"])
+            if op.kind == "indegree_norm":
+                return indegree_norm(x, gctx.in_degree)
+            if op.kind == "scatter_gather":
+                return gctx.aggregate(x, op.attrs["aggr"])
+            if op.kind == "fused_aggregate":
+                # norm -> sum -> norm [-> relu] in one op (fuse_norm_
+                # aggregate).  The activation sits OUTSIDE the
+                # symmetric custom_vjp (relu is nonlinear) but inside
+                # this op's fusion scope, so XLA folds it into the
+                # aggregation epilogue.
+                y = gctx.aggregate_fused(x)
+                if op.attrs.get("activation",
+                                AC_MODE_NONE) != AC_MODE_NONE:
+                    y = dense.activation(y, op.attrs["activation"])
+                return y
+            if op.kind == "gat":
+                return gctx.gat_attention(
+                    x, params[f"{op.param}_src"],
+                    params[f"{op.param}_dst"],
+                    neg_slope=op.attrs["neg_slope"])
+            if op.kind == "activation":
+                return dense.activation(x, op.attrs["mode"])
+            if op.kind == "add":
+                return vals[op.inputs[0]] + vals[op.inputs[1]]
+            if op.kind == "scale_add":
+                eps = params[op.param].astype(vals[op.inputs[0]].dtype)
+                return vals[op.inputs[0]] + eps * vals[op.inputs[1]]
+            if op.kind == "mul":
+                return vals[op.inputs[0]] * vals[op.inputs[1]]
+            if op.kind == "lerp":
+                # the two scalars in float32 whatever the compute dtype
+                # (XLA fuses the casts away): as weak-typed factors of
+                # bfloat16 arrays they would round to 8 bits, 0.2% off
+                # each — a gain error that compounds down a deep stack
+                # and that the loss, exponential in the logits' scale,
+                # shows tenfold (PERF.md section 6, PR 33)
+                al = op.attrs["alpha"]
+                a, b = vals[op.inputs[0]], vals[op.inputs[1]]
+                return ((1.0 - al) * a.astype(jnp.float32)
+                        + al * b.astype(jnp.float32)).astype(a.dtype)
+            raise ValueError(f"unknown op kind {op.kind}")
 
     def loss_fn(self, params: Dict[str, jax.Array], feats: jax.Array,
                 labels: jax.Array, mask: jax.Array, gctx: GraphContext,
                 key: Optional[jax.Array] = None,
-                train: bool = True) -> Tuple[jax.Array, jax.Array]:
+                train: bool = True, remat: bool = False
+                ) -> Tuple[jax.Array, jax.Array]:
         """(summed masked CE, logits) — the differentiable objective whose
         gradient equals the reference's ``softmax - onehot`` on train rows
-        (``softmax_kernel.cu:19-33``)."""
-        logits = self.apply(params, feats, gctx, key=key, train=train)
+        (``softmax_kernel.cu:19-33``).  ``remat``: :meth:`apply`'s."""
+        logits = self.apply(params, feats, gctx, key=key, train=train,
+                            remat=remat)
         with jax.named_scope(LOSS_SCOPE):
             loss = masked_softmax_cross_entropy(logits, labels, mask)
         return gctx.psum(loss), logits

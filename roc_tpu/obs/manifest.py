@@ -74,7 +74,13 @@ def run_manifest(config=None, dataset=None, model=None,
     per attention op (heads, head width, layout, passes over the edge
     tables, slots a pass, carry rows) and one ``attention_backward``
     entry (the gradient rule, its edge passes, the whole-array
-    cotangents it scatters into).
+    cotangents it scatters into); and ``memory_plan``
+    (``train/trainer.py modeled_plan``): the memory plan's estimate
+    for the resolved configuration by component (parameters + Adam,
+    features, tables, kept activations, transient), what each model op
+    was charged (``[op, kind, arrays, bytes per vertex row]``), remat
+    and the runs it computes again, and the model's depth
+    (aggregating ops, ``linear`` ops).
 
     Everything is best-effort: a missing backend or detached checkout
     degrades to nulls, never to an exception at trainer setup."""

@@ -10,6 +10,17 @@ written by hand:
     jit(step)/jvp(roc.agg.op03)/while/body/...             forward
     jit(step)/transpose(jvp(roc.agg.op03))/while/body/...  backward
 
+Under ``--remat`` every run of ops between two aggregations is a
+checkpoint (``models/builder.py _computed_again``), and the backward
+holds two kinds of operation under each scope of such a run: the
+transposes, and the forward operations computed again to feed them.
+Both are traced under ``roc.recompute``; the second kind is a direction
+of its own, ``recompute``: work the backward pass does, not work of the
+gradient.
+
+    jit(step)/transpose(jvp(roc.recompute))/jvp(roc.dense.op09.add)/add         recompute
+    jit(step)/transpose(jvp(transpose(jvp(roc.dense.op07.linear))))/dot_general  backward
+
 The scopes are metadata only: the lowered program, the executable and
 the compile-cache key are the same with and without them.  The device
 trace names an operation by its instruction (``%fusion.28 = ...``), not
@@ -77,26 +88,40 @@ def op_scope(index: int, kind: str) -> str:
     return f"{PREFIX}{DENSE}.op{index:02d}.{kind}"
 
 
+# entered around a run's second forward inside the backward
+RECOMPUTE_SCOPE = PREFIX + "recompute"
+
+
 def parse_op_name(op_name: str
                   ) -> Optional[Tuple[str, Optional[int], str]]:
-    """``(class, op index or None, "fwd" | "bwd")`` of an ``op_name``
-    path, None when no component is a ``roc.`` scope.  The innermost
-    ``roc.`` component gives the class (a ``roc.halo`` inside a
-    ``roc.agg.op03`` is halo); the op index is the innermost one any
+    """``(class, op index or None, "fwd" | "bwd" | "recompute")`` of an
+    ``op_name`` path, None when no component is a ``roc.`` scope.  The
+    innermost ``roc.`` component gives the class (a ``roc.halo`` inside
+    a ``roc.agg.op03`` is halo); the op index is the innermost one any
     component carries (that halo belongs to op 3); the direction is
     ``bwd`` where JAX wrapped a component in ``transpose(`` (the
-    primitive of that name has no parenthesis)."""
+    primitive of that name has no parenthesis) — except under
+    ``roc.recompute``, where the step's own ``transpose(jvp(...))``
+    wrapper is around everything: there one ``transpose(`` is a forward
+    operation computed again inside the backward (``recompute``: only
+    a step under remat has any) and a second one its transpose."""
     found = _SCOPE.findall(op_name)
     if not found:
         return None
     index = next((int(i) for _, i in reversed(found) if i), None)
-    return (found[-1][0], index,
-            "bwd" if "transpose(" in op_name else "fwd")
+    transposes = op_name.count("transpose(")
+    if RECOMPUTE_SCOPE in op_name:
+        # the whole backward sits in the step's one transpose(jvp(...))
+        # wrapper; a run's second forward carries that one alone
+        way = "recompute" if transposes <= 1 else "bwd"
+    else:
+        way = "bwd" if transposes else "fwd"
+    return (found[-1][0], index, way)
 
 
 def parse_op_phase(op_name: str
                    ) -> Optional[Tuple[str, Optional[int], str, str]]:
-    """``("agg", op index, phase, "fwd" | "bwd")`` of an operation
+    """``("agg", op index, phase, "fwd" | "bwd" | "recompute")`` of an operation
     inside an attention phase (the innermost ``roc.attn.`` component),
     None for every other operation — one under ``roc.halo`` inside an
     attention op included: its class is not ``agg``."""

@@ -49,7 +49,7 @@ from ..obs.scopes import ALLREDUCE_SCOPE, LOSS_SCOPE, OPT_SCOPE
 from ..ops.loss import masked_softmax_cross_entropy, perf_metrics, summarize_metrics
 from ..train.optimizer import AdamConfig, adam_init, adam_update
 from ..train.trainer import (TrainConfig, cast_floats, compute_dtype_of,
-                             remat_policy, resolve_symmetric)
+                             resolve_symmetric)
 
 
 # THE names of the mesh axes — defined in parallel/__init__ (the
@@ -789,10 +789,11 @@ class DistributedTrainer:
         self.adam_cfg = AdamConfig(weight_decay=config.weight_decay)
         # observability: per-device modeled bytes for the compile
         # observer's modeled-vs-actual check, edges for edges/sec
-        from ..train.trainer import modeled_step_bytes
+        from ..train.trainer import modeled_plan
         self._obs_edges = int(dataset.graph.num_edges)
-        self._modeled_bytes = modeled_step_bytes(
-            model, dataset, config, num_parts=num_parts)
+        self._plan = modeled_plan(model, dataset, config,
+                                  num_parts=num_parts)
+        self._modeled_bytes = self._plan["est_bytes"]
         # dataset identity for the checkpoint config fingerprint; the
         # elastic half (num_parts + quantized plan shapes) reads
         # self.pg directly (utils/checkpoint.trainer_fingerprint)
@@ -815,7 +816,8 @@ class DistributedTrainer:
                          **self._gctx().attention_plan(
                              model._ops, ell_idx=self.data.ell_idx,
                              flat8_idx=next(iter(self.data.sect_idx),
-                                            None))},
+                                            None)),
+                         "memory_plan": self._plan},
                      console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
         # annotate=True routes every phase span through
@@ -1281,14 +1283,12 @@ class DistributedTrainer:
                 with jax.named_scope(OPT_SCOPE):
                     p = cast_floats(p, self.compute)
                 logits = self.model.apply(p, feats, gctx, key=part_key,
-                                          train=True)
+                                          train=True,
+                                          remat=self.config.remat)
                 with jax.named_scope(LOSS_SCOPE):
                     return masked_softmax_cross_entropy(logits, labels,
                                                         mask)
 
-            if self.config.remat:
-                local_loss = jax.checkpoint(
-                    local_loss, policy=remat_policy(self.config))
             local_l, grads = jax.value_and_grad(local_loss)(params)
             # the reference's replica-sum gradient allreduce
             # (optimizer_kernel.cu:88-94) as an ICI psum

@@ -644,6 +644,9 @@ def parse_args(argv: Optional[List[str]] = None):
     ap.add_argument("--hops", type=int, default=None)
     ap.add_argument("--alpha", type=float, default=None)
     ap.add_argument("--lam", type=float, default=None)
+    ap.add_argument("--star", action="store_true",
+                    help="for --model gcn2: the GCNII* form "
+                         "(train/cli.py --star)")
     ap.add_argument("--heads", type=int, default=1)
     ap.add_argument("-dropout", type=float, default=0.5)
     ap.add_argument("-seed", type=int, default=1)
@@ -722,6 +725,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         kwargs["alpha"] = args.alpha if args.alpha is not None else 0.1
     if args.model == "gcn2":
         kwargs["lam"] = args.lam if args.lam is not None else 0.5
+        kwargs["star"] = args.star
     model = model_builders()[args.model](
         layers, dropout_rate=args.dropout, **kwargs)
     dt, cdt = resolve_dtypes(args.dtype)

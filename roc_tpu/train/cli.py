@@ -80,6 +80,13 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap.add_argument("--lam", type=float, default=None,
                     help="for --model gcn2: identity-mapping decay "
                          "(beta_l = log(lam/l + 1); default 0.5)")
+    ap.add_argument("--star", action="store_true",
+                    help="for --model gcn2: the GCNII* form, as the "
+                         "OGB ogbn-arxiv leaderboard's GCNII rows run "
+                         "it — separate weights for the propagated "
+                         "and the initial-residual branch, (P H) W1 + "
+                         "H_0 W2 (default: GCNII, one shared weight a "
+                         "layer)")
     ap.add_argument("--learn-eps", action="store_true",
                     help="for --model gin: learnable per-layer "
                          "epsilon self-weight (zero-init GIN-0) "
@@ -394,6 +401,10 @@ def main(argv: Optional[List[str]] = None,
         print("error: --lam applies to --model gcn2 only",
               file=sys.stderr)
         return 2
+    if args.star and args.model != "gcn2":
+        print("error: --star applies to --model gcn2 only",
+              file=sys.stderr)
+        return 2
     if args.hops is not None and args.model not in ("sgc", "appnp"):
         # same sentinel policy as --alpha/--heads/--learn-eps: a
         # propagation depth on a fixed-depth model must fail, not be
@@ -485,6 +496,7 @@ def main(argv: Optional[List[str]] = None,
         kwargs["alpha"] = args.alpha
     if args.model == "gcn2":
         kwargs["lam"] = args.lam
+        kwargs["star"] = args.star
     model = build[args.model](layers, dropout_rate=args.dropout,
                               **kwargs)
     dt, cdt = resolve_dtypes(args.dtype)

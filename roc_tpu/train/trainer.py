@@ -102,9 +102,14 @@ class TrainConfig:
     metrics_path: Optional[str] = None
     # Memory policy (the TPU analog of the reference's FB-cache +
     # zero-copy residency design, resourcemanager.h:30, types.cu:22-32):
-    # - remat: rematerialize the forward pass in backward instead of
-    #   saving activations — one extra forward of FLOPs for O(layers)
-    #   less activation memory.
+    # - remat: compute each run of ops between two aggregations again
+    #   in the backward instead of keeping its insides (one
+    #   checkpoint a run, models/builder.py Model.apply): the step
+    #   keeps the aggregations' outputs and little else, and pays the
+    #   dense and elementwise forward twice.  The aggregations are
+    #   never computed again: a checkpoint that took one in would keep
+    #   its input in place of its output, the same bytes, and pay the
+    #   gather and the sum twice — so there is no policy to choose.
     # - features: "hbm" keeps the input features device-resident;
     #   "host" keeps them in host RAM and streams the first layer
     #   (dropout -> linear) through HBM in row blocks, forward AND
@@ -114,13 +119,7 @@ class TrainConfig:
     #   core/memory.choose_memory_plan over the dataset/model shapes
     #   and overrides them with the first plan that fits hbm_bytes
     #   (None = detect), echoing the decision at setup.
-    # - remat_policy: "full" recomputes everything; "save_aggregates"
-    #   saves the scatter_gather outputs (the halo gather + CSR sum is
-    #   by far the most expensive recompute: at products scale a full
-    #   remat spends ~2/3 of its overhead re-aggregating) and
-    #   recomputes only the cheap dense/elementwise ops.
     remat: bool = False
-    remat_policy: str = "save_aggregates"
     features: str = "hbm"
     memory: str = "manual"
     hbm_bytes: Optional[int] = None
@@ -374,21 +373,6 @@ def cast_floats(tree, dtype):
         if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
 
 
-def remat_policy(config: TrainConfig):
-    """jax.checkpoint policy for ``config.remat_policy``: None (full
-    recompute) or save-named-aggregates (models/builder.py tags every
-    scatter_gather output with checkpoint_name 'aggregate').  An
-    unknown name raises — a typo must not silently change the memory
-    footprint."""
-    if config.remat_policy == "full":
-        return None
-    if config.remat_policy != "save_aggregates":
-        raise ValueError(
-            f"unknown remat_policy {config.remat_policy!r}; expected "
-            "'save_aggregates' or 'full'")
-    return jax.checkpoint_policies.save_only_these_names("aggregate")
-
-
 # Attention models switch from the per-width bucket layout to the
 # uniform flat8 layout past this edge count: the bucket path's
 # Python-unrolled checkpointed scans (one per large width bucket,
@@ -507,36 +491,44 @@ def resolve_fuse(model: Model, config: TrainConfig) -> Model:
     return fused
 
 
-def model_layer_dims(model: Model) -> List[int]:
-    """The CLI-style layer spec (in-dim, linear out-dims...) recovered
-    from the built model — the shape vocabulary core/memory.py's
-    estimator speaks."""
-    return [model._ops[0].dim] + [op.dim for op in model._ops
-                                  if op.kind == "linear"]
-
-
-def modeled_step_bytes(model: Model, dataset: Dataset,
-                       config: TrainConfig,
-                       num_parts: int = 1) -> int:
-    """The memory model's peak-HBM estimate for the RESOLVED config —
-    the number the compile observer (obs/compile_watch.py) holds
-    against XLA's actual ``memory_analysis()`` so the planner and the
-    residency can never silently disagree again (round-5 advisor).
-    Computed for manual configs too: the autopilot only runs under
-    ``memory='auto'``, but the modeled-vs-actual delta is evidence on
-    every run."""
-    from ..core.memory import charged_table_bytes, estimate_plan_bytes
-    a_tab = charged_table_bytes(
-        config.aggr_impl, model.uses_attention(),
-        model.uses_max_aggregation(), config.bdense_a_budget)
-    return estimate_plan_bytes(
-        dataset.graph.num_nodes, dataset.graph.num_edges,
-        model_layer_dims(model), num_parts=num_parts,
+def _plan_kwargs(model: Model, dataset: Dataset, config: TrainConfig,
+                 num_parts: int) -> Dict[str, Any]:
+    """What both the autopilot and the resolved-plan echo hand
+    core/memory.py: the shapes, the dtypes' sizes, the impl-specific
+    table charge and the scan layouts' chunk height."""
+    from ..core.ell import scan_chunk_rows
+    from ..core.memory import charged_table_bytes
+    g = dataset.graph
+    return dict(
+        num_nodes=g.num_nodes, num_edges=g.num_edges, ops=model._ops,
+        num_parts=num_parts,
+        scan_rows=(0 if model.uses_attention()
+                   or model.uses_max_aggregation()
+                   else scan_chunk_rows(config.aggr_impl,
+                                        -(-g.num_edges // num_parts))),
         dtype_bytes=jnp.dtype(compute_dtype_of(config)).itemsize,
+        param_bytes=jnp.dtype(config.dtype).itemsize,
+        extra_table_bytes=charged_table_bytes(
+            config.aggr_impl, model.uses_attention(),
+            model.uses_max_aggregation(), config.bdense_a_budget))
+
+
+def modeled_plan(model: Model, dataset: Dataset, config: TrainConfig,
+                 num_parts: int = 1) -> Dict[str, Any]:
+    """The memory model's estimate for the RESOLVED config, by
+    component, with what each op was charged, remat and the model's
+    depth (``core/memory.py describe_plan``) — the run manifest's
+    ``memory_plan``.  Its ``est_bytes`` is the number the compile
+    observer (obs/compile_watch.py) holds against XLA's actual
+    ``memory_analysis()`` so the planner and the residency can never
+    silently disagree again (round-5 advisor).  Computed for manual
+    configs too: the autopilot only runs under ``memory='auto'``, but
+    the modeled-vs-actual delta is evidence on every run."""
+    from ..core.memory import describe_plan
+    return describe_plan(
         halo=config.halo if num_parts > 1 else "gather",
         features=config.features, remat=config.remat,
-        remat_policy=config.remat_policy,
-        extra_table_bytes=a_tab)
+        **_plan_kwargs(model, dataset, config, num_parts))
 
 
 def resolve_symmetric(dataset: Dataset,
@@ -557,8 +549,7 @@ def apply_memory_autopilot(model: Model, dataset: Dataset,
     if config.memory != "auto":
         return config
     import dataclasses
-    from ..core.memory import charged_table_bytes, choose_memory_plan
-    dims = model_layer_dims(model)
+    from ..core.memory import choose_memory_plan
     # bdense keeps an A-table resident next to the model; the resolve
     # pass (resolve_config) runs aggr_impl='auto' (incl. the bdense
     # structure probe) BEFORE this autopilot, so a probe-selected
@@ -569,18 +560,11 @@ def apply_memory_autopilot(model: Model, dataset: Dataset,
     # because it must see the chosen halo) rewrites their impl away
     # from bdense.  charged_table_bytes (core/memory.py) is the ONE
     # home for the charge rule.
-    a_tab = charged_table_bytes(
-        config.aggr_impl, model.uses_attention(),
-        model.uses_max_aggregation(), config.bdense_a_budget)
     plan = choose_memory_plan(
-        dataset.graph.num_nodes, dataset.graph.num_edges, dims,
-        num_parts=num_parts,
-        dtype_bytes=jnp.dtype(compute_dtype_of(config)).itemsize,
         hbm_bytes=config.hbm_bytes,
         head_streamable=(model.streamable_head() is not None
                          or model.streamable_agg_head() is not None),
-        remat_policy=config.remat_policy,
-        extra_table_bytes=a_tab)
+        **_plan_kwargs(model, dataset, config, num_parts))
     # a plan that doesn't fit echoes even with verbose off — running
     # anyway is a deliberate gamble the operator must see
     emit("plan", plan.echo(), console=config.verbose or not plan.fits,
@@ -909,7 +893,8 @@ class Trainer:
         # observability: edge count for edges/sec and the memory
         # model's estimate the compile observer checks XLA against
         self._obs_edges = int(dataset.graph.num_edges)
-        self._modeled_bytes = modeled_step_bytes(model, dataset, config)
+        self._plan = modeled_plan(model, dataset, config)
+        self._modeled_bytes = self._plan["est_bytes"]
         # dataset identity for the checkpoint config fingerprint
         # (utils/checkpoint.trainer_fingerprint strict half)
         self._fp_dataset = {"V": int(dataset.graph.num_nodes),
@@ -1071,7 +1056,8 @@ class Trainer:
                      extra={"modeled_step_bytes": self._modeled_bytes},
                      agg_window={
                          **self.gctx.agg_window(model._ops),
-                         **self.gctx.attention_plan(model._ops)},
+                         **self.gctx.attention_plan(model._ops),
+                         "memory_plan": self._plan},
                      console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
         # annotate=True routes every phase span through
@@ -1092,11 +1078,9 @@ class Trainer:
             with jax.named_scope(OPT_SCOPE):
                 p = cast_floats(p, self.compute)
             loss, _ = self.model.loss_fn(p, feats, labels, mask,
-                                         gctx, key=key, train=True)
+                                         gctx, key=key, train=True,
+                                         remat=self.config.remat)
             return loss
-        if self.config.remat:
-            objective = jax.checkpoint(
-                objective, policy=remat_policy(self.config))
         loss, grads = jax.value_and_grad(objective)(params)
         with jax.named_scope(OPT_SCOPE):
             params, opt_state = adam_update(params, grads, opt_state,
@@ -1120,11 +1104,9 @@ class Trainer:
             with jax.named_scope(OPT_SCOPE):
                 p = cast_floats(p, self.compute)
             loss, _ = self._tail_model.loss_fn(
-                p, yy, labels, mask, gctx, key=key, train=True)
+                p, yy, labels, mask, gctx, key=key, train=True,
+                remat=self.config.remat)
             return loss
-        if self.config.remat:
-            objective = jax.checkpoint(
-                objective, policy=remat_policy(self.config))
         loss, (gp, gy) = jax.value_and_grad(objective, argnums=(0, 1))(
             params, y)
         return loss, gp, gy

@@ -1,0 +1,197 @@
+"""The memory plan read off the model's op list (``core/memory.py``):
+the one rule of what an op keeps for its backward, the three accepted
+configurations' shapes resolving to the plans they resolved to before
+the rule, a second ``linear`` on a kept input costing nothing, the depth
+at which ``remat`` turns on being exactly where the estimate crosses the
+budget, the runs remat computes again, and the ``memory_plan`` a trainer
+writes into its manifest."""
+
+import jax.numpy as jnp
+import pytest
+
+from roc_tpu.core import memory as M
+from roc_tpu.core.ell import scan_chunk_rows
+from roc_tpu.core.graph import synthetic_dataset
+from roc_tpu.models.gat import build_gat
+from roc_tpu.models.gcn import build_gcn
+from roc_tpu.models.gcn2 import build_gcn2
+from roc_tpu.models.sage import build_sage
+from roc_tpu.train.trainer import TrainConfig, Trainer, modeled_plan
+
+GIB = 2**30
+BUDGET = int(15.75 * GIB * 0.85)         # what a v5e chip reports, usable
+ARXIV = (169_343, 2_501_829)
+
+
+def gcn2_star(depth, width=256):
+    return build_gcn2([128] + [width] * depth + [40], alpha=0.5, lam=1.0,
+                      dropout_rate=0.1, star=True).fuse_norm_aggregate()
+
+
+def plan(model, V, E, parts=1, impl="sectioned", **kw):
+    return M.choose_memory_plan(
+        V, E, model._ops, num_parts=parts, dtype_bytes=2, param_bytes=4,
+        hbm_bytes=BUDGET,
+        scan_rows=scan_chunk_rows(impl, -(-E // parts)), **kw)
+
+
+@pytest.mark.parametrize("name,build,V,E,parts,impl", [
+    ("gcn-reddit", lambda: build_gcn([602, 256, 41]).fuse_norm_aggregate(),
+     232_965, 114_848_857, 1, "sectioned"),
+    ("gcn-products",
+     lambda: build_gcn([100, 256, 256, 47]).fuse_norm_aggregate(),
+     2_449_029, 126_167_309, 4, "flat_sum"),
+    ("gat-arxiv", lambda: build_gat(
+        [128, 750, 750, 40], dropout_rate=0.75, heads=3, skip=True,
+        activation="relu", input_dropout=0.1), *ARXIV, 1, "ell"),
+    ("gcn2-arxiv", lambda: gcn2_star(16), *ARXIV, 1, "sectioned"),
+])
+def test_accepted_shapes_resolve_to_the_plain_plan(name, build, V, E,
+                                                   parts, impl):
+    """Gathered halo, features on the device, no remat: what each
+    accepted cell's ``plan`` line has always said."""
+    p = plan(build(), V, E, parts, impl)
+    assert (p.halo, p.features, p.remat, p.fits) == (
+        "gather", "hbm", False, True), (name, p.echo())
+    assert p.est_bytes < 0.5 * BUDGET
+
+
+def test_a_second_linear_on_a_kept_input_adds_no_activation():
+    """GCNII*'s two products a layer: ``W1`` reads ``P H`` (kept),
+    ``W2`` reads ``H_0`` (kept once, by the first layer): the starred
+    form keeps exactly what the shared-weight form keeps, less nothing,
+    plus nothing, whatever the depth; and a ``lerp`` or an ``add`` keeps
+    nothing at all."""
+    for depth in (2, 8):
+        star = gcn2_star(depth)
+        kept, _ = M.saved_for_backward(star._ops, 2)
+        by_kind = {}
+        for i, n, row in kept:
+            k = star._ops[i].kind
+            by_kind[k] = by_kind.get(k, 0) + n
+        assert set(by_kind) == {"dropout", "linear", "activation"}
+        # per layer: P H, the first product's charge; the second
+        # product adds none
+        per_linear = {i: n for i, n, _ in kept
+                      if star._ops[i].kind == "linear"}
+        second = [i for i, op in enumerate(star._ops)
+                  if op.kind == "linear"
+                  and star._ops[op.inputs[0]].kind == "activation"
+                  and i < len(star._ops) - 1 and op.inputs[0] == 3]
+        assert len(second) == depth
+        # H_0 is charged once, by the ReLU that makes it
+        assert all(i not in per_linear for i in second)
+    # the same bytes a vertex row as two separately-built charges
+    ops = gcn2_star(1)._ops
+    assert [o.kind for o in ops[4:12]] == [
+        "dropout", "fused_aggregate", "lerp", "linear", "linear", "add",
+        "lerp", "activation"]
+    one = dict((i, row) for i, _, row in M.saved_for_backward(ops, 2)[0])
+    assert one[7] == 256 * 2               # P H, the first product's input
+    assert 8 not in one and 6 not in one and 9 not in one and 10 not in one
+
+
+def test_residual_rule_by_kind():
+    ops = build_sage([12, 16, 5])._ops
+    kinds = {op.kind for op in ops}
+    assert "scatter_gather" in kinds
+    for i, op in enumerate(ops):
+        got = M.op_residuals(i, op, 2)
+        if op.kind in ("add", "lerp", "indegree_norm", "input"):
+            assert got == []
+        if op.kind == "dropout":
+            assert got == [(("m", i), op.dim, 1)]
+        if op.kind == "linear":
+            assert got[0] == (("t", op.inputs[0]), op.attrs["in_dim"], 2)
+    gat = build_gat([12, 16, 5], heads=2)._ops
+    (g,) = [i for i, op in enumerate(gat) if op.kind == "gat"][:1]
+    assert [k for k, _, _ in M.op_residuals(g, gat[g], 2)] == [
+        ("t", gat[g].inputs[0]), ("t", g), ("m", g)]
+
+
+def test_depth_sweep_flips_remat_where_the_estimate_crosses_the_budget():
+    V, E = ARXIV
+    flipped = None
+    for depth in range(8, 97, 4):     # past ~100 remat does not fit either
+        model = gcn2_star(depth)
+        p = plan(model, V, E)
+        plain = p.candidates["gather/hbm"]
+        assert p.remat == (plain > BUDGET), (depth, p.echo())
+        assert p.fits and p.features == "hbm"
+        if p.remat:
+            assert p.est_bytes == p.candidates["gather/hbm/remat"]
+            assert p.est_bytes < 0.7 * plain
+            flipped = flipped or depth
+    assert flipped == 64          # the estimate crosses at 61 layers
+    # the estimate grows by the same bytes for every layer added
+    a, b, c = (plan(gcn2_star(d), V, E).candidates["gather/hbm"]
+               for d in (16, 17, 18))
+    assert b - a == c - b > 0
+
+
+def test_remat_runs_are_the_stretches_between_aggregations():
+    ops = gcn2_star(3)._ops
+    runs = M.remat_segments(ops)
+    assert len(runs) == 4 and runs[0][0] == 1 and runs[-1][1] == len(ops)
+    agg = {i for i, op in enumerate(ops) if op.kind in M.AGG_KINDS}
+    covered = {k for lo, hi in runs for k in range(lo, hi)}
+    assert covered | agg == set(range(1, len(ops))) and not covered & agg
+    kept, again = M.saved_for_backward(ops, 2, remat=True)
+    # a run keeps what it reads from outside itself: the aggregation's
+    # output; H_0 once; its dropout mask; nothing else of its insides,
+    # which are charged once, for the largest run, as what is computed
+    # again
+    assert sum(n for _, n, _ in kept) < sum(
+        n for _, n, _ in M.saved_for_backward(ops, 2)[0])
+    assert again > 0
+    assert M.saved_for_backward(ops, 2)[1] == 0
+
+
+def test_components_sum_and_scale():
+    ops = gcn2_star(4)._ops
+    kw = dict(dtype_bytes=2, param_bytes=4, scan_rows=131_072)
+    c = M.plan_components(*ARXIV, ops, **kw)
+    assert set(c) == {"params_opt", "features", "tables", "activations",
+                      "transient"}
+    assert M.estimate_plan_bytes(*ARXIV, ops, **kw) == sum(c.values())
+    w = M.param_elems(ops)
+    assert w == 128 * 256 + 4 * 2 * 256 * 256 + 256 * 40
+    assert c["params_opt"] == w * (4 * 4 + 2)
+    assert c["features"] == ARXIV[0] * 128 * 2
+    # one chunk of the scan: 131,072 sub-rows of 8 gathered rows + sum
+    bare = M.plan_components(*ARXIV, ops, dtype_bytes=2, param_bytes=4)
+    assert c["transient"] - bare["transient"] == 131_072 * 9 * 256 * 2
+    assert M.model_depth(ops) == {"aggregating_ops": 4, "linear_ops": 10}
+    assert scan_chunk_rows("sectioned", 2_501_829) == 131_072
+    assert scan_chunk_rows("sectioned", 1_000) == 128
+    assert scan_chunk_rows("sectioned", 114_848_857) == 131_072
+    assert scan_chunk_rows("flat_sum", 31_541_828) == 8_192
+    assert scan_chunk_rows("ell", 10**9) == 0
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_trainer_writes_the_plan_into_its_manifest(remat, tmp_path):
+    import json
+    from roc_tpu.obs.events import configure
+    ds = synthetic_dataset(300, 6, in_dim=12, num_classes=4, seed=2)
+    model = build_gcn2([12, 16, 16, 16, 4], dropout_rate=0.1, star=True)
+    path = str(tmp_path / "events.jsonl")
+    configure(jsonl_path=path)
+    try:
+        tr = Trainer(model, ds, TrainConfig(
+            verbose=False, remat=remat, dtype=jnp.float32,
+            compute_dtype=jnp.bfloat16))
+    finally:
+        configure(jsonl_path=None)
+    with open(path) as f:
+        man = [json.loads(ln) for ln in f if '"manifest"' in ln][-1]
+    mem = man["resolved"]["memory_plan"]
+    assert mem == json.loads(json.dumps(modeled_plan(
+        tr.model, ds, tr.config)))
+    assert mem["remat"] is remat and man["resolved"]["remat"] is remat
+    assert mem["remat_runs"] == (4 if remat else 0)
+    assert (mem["aggregating_ops"], mem["linear_ops"]) == (3, 8)
+    assert mem["est_bytes"] == sum(mem["components"].values()) \
+        == man["modeled_step_bytes"]
+    assert all(tr.model._ops[i].kind == kind
+               for i, kind, _, _ in mem["saved"])

@@ -141,29 +141,31 @@ def test_adam_matches_reference_formula():
     np.testing.assert_allclose(np.asarray(new_p["w"])[0], want, rtol=1e-6)
 
 
-def test_remat_policies_train_identically():
-    """remat with either policy must produce the same parameters as
-    no-remat (checkpointing changes memory, not math)."""
+@pytest.mark.parametrize("family", ["gcn", "gcn_deep", "gcn2star"])
+def test_remat_trains_identically(family):
+    """remat must produce the same parameters as no-remat
+    (checkpointing a run of ops changes memory, not math) — dropout on,
+    so the recomputed runs draw the forward's own masks."""
     from roc_tpu.core.graph import synthetic_dataset
     from roc_tpu.models.gcn import build_gcn
+    from roc_tpu.models.gcn2 import build_gcn2
     from roc_tpu.train.trainer import TrainConfig, Trainer
     ds = synthetic_dataset(200, 6, in_dim=12, num_classes=3, seed=9)
+    build = {"gcn": lambda: build_gcn([12, 8, 3], dropout_rate=0.3),
+             "gcn_deep": lambda: build_gcn([12, 8, 8, 8, 3],
+                                           dropout_rate=0.3),
+             "gcn2star": lambda: build_gcn2([12, 8, 8, 8, 3],
+                                            dropout_rate=0.3,
+                                            star=True)}[family]
     results = {}
-    for name, kw in [("none", dict(remat=False)),
-                     ("full", dict(remat=True, remat_policy="full")),
-                     ("save_agg", dict(remat=True,
-                                       remat_policy="save_aggregates"))]:
-        model = build_gcn([12, 8, 3], dropout_rate=0.0)
+    for remat in (False, True):
         cfg = TrainConfig(learning_rate=0.05, epochs=3,
                           eval_every=1 << 30, verbose=False,
-                          symmetric=True, **kw)
-        tr = Trainer(model, ds, cfg)
+                          symmetric=True, remat=remat)
+        tr = Trainer(build(), ds, cfg)
         tr.train()
-        results[name] = tr.params
-    for k in results["none"]:
-        np.testing.assert_allclose(np.asarray(results["none"][k]),
-                                   np.asarray(results["full"][k]),
-                                   rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(results["none"][k]),
-                                   np.asarray(results["save_agg"][k]),
+        results[remat] = tr.params
+    for k in results[False]:
+        np.testing.assert_allclose(np.asarray(results[False][k]),
+                                   np.asarray(results[True][k]),
                                    rtol=1e-5, atol=1e-5)

@@ -344,7 +344,8 @@ def test_resolve_prefetch():
 # ---- memory autopilot ----
 
 def test_choose_memory_plan_tiers():
-    dims = [602, 256, 41]
+    # the plan reads the model's op list (core/memory.py op_residuals)
+    dims = build_gcn([602, 256, 41])._ops
     # small graph, generous budget -> plain gather/hbm
     p = choose_memory_plan(10_000, 100_000, dims, num_parts=1,
                            hbm_bytes=1 << 34)
