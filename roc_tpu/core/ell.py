@@ -287,6 +287,13 @@ class SectionedEll:
     - ``sub_dst[s]``: int32 ``[n_chunks, seg_rows]`` the output row of
       each sub-row, ascending within each chunk (scatter-add with
       ``indices_are_sorted``); chunk padding points at ``num_rows``;
+    - ``n_chunks`` and ``seg_rows`` are the section's own, read off its
+      sub-row count by :func:`fit_chunks`: as few chunks as the cap on
+      a chunk's height allows (:data:`SECT_SEG_ROWS`;
+      :data:`FLAT_SEG_ROWS` for the flat layouts), each as tall as the
+      section needs and no taller — a scan step costs its slots, not
+      its edges, so chunk padding is paid for in full.  The arrays'
+      shapes carry both; no field repeats them;
     - ``win_rows[s]``: how tall a chunk's run of real destinations can
       be in this section (:func:`chunk_window_rows` over ``sub_dst``;
       stacked tables: over every part, SPMD shapes must agree).
@@ -310,7 +317,6 @@ class SectionedEll:
     num_rows: int
     src_rows: int
     section_rows: int
-    seg_rows: int
     sec_starts: Tuple[int, ...]
     sec_sizes: Tuple[int, ...]
     idx: Tuple[np.ndarray, ...]
@@ -419,21 +425,53 @@ def chunk_window_rows(sub_dst: np.ndarray, num_rows: int) -> int:
     return -(-max(span, 1) // WIN_ROWS_MULTIPLE) * WIN_ROWS_MULTIPLE
 
 
-# Uniform flat-sum layout (aggregate_flat_sum): chunk granularity of
-# the single global section.  8192 bounds the per-chunk gathered
-# transient [seg, 8, F] at 64 MiB for F=256 fp32 — the same bound the
-# attention flat8 tables use (they are the same layout).
+# Uniform flat-sum layout (aggregate_flat_sum): the cap on a chunk's
+# height in the single global section (fit_chunks derives the height
+# under it).  8192 bounds the per-chunk gathered transient [seg, 8, F]
+# at 64 MiB for F=256 fp32 — the same bound the attention flat8 tables
+# use (they are the same layout).
 FLAT_SEG_ROWS = 8192
-# sub-rows a chunk of a section's scan holds (the sweep below chose it)
+# The cap on the sub-rows a chunk of a section's scan holds — not the
+# height itself, which fit_chunks reads off the section's sub-row
+# count.  The sweep below chose it at Reddit scale, 4.1M sub-rows a
+# section, where a section of 18-32 chunks keeps the cap exactly.
 SECT_SEG_ROWS = 131_072
+
+
+def fit_chunks(sub_rows: int, cap: int) -> Tuple[int, int]:
+    """``(n_chunks, seg_rows)`` of a section holding ``sub_rows``
+    sub-rows under the cap ``cap`` on a chunk's height — the ONE place
+    a chunk's height is decided (single-device, stacked and multihost
+    builders; native and numpy).
+
+    As few scan steps as the cap allows, ``n = ceil(sub_rows / cap)``,
+    and each as tall as the section needs: ``ceil(sub_rows / n)``
+    rounded up to ``cap // 16`` (a multiple of 8, the sublane tile;
+    coarse enough that graphs of one size share a compiled scan).
+    From sixteen chunks on that round-up restores the cap
+    (``sub_rows / n > cap * (n - 1) / n >= cap * 15 / 16``), so a
+    large section's tables are what a fixed height of ``cap`` gives:
+    Reddit's 18-32 chunks of 131,072 and a products partition's 515
+    of 8,192 are untouched.  A section of a few chunks stops gathering
+    a last chunk that is mostly padding (ogbn-arxiv under
+    ``sectioned``: 209,069 sub-rows in 2 x 106,496, not 2 x 131,072).
+    A section that fits one chunk is as tall as its sub-rows, to the
+    8: there is no second chunk to share a shape with."""
+    n = max(1, -(-int(sub_rows) // cap))
+    need = -(-int(sub_rows) // n)
+    g = 8 if n == 1 else max(8, cap // 16)
+    return n, min(cap, max(8, -(-need // g) * g))
 
 
 def scan_chunk_rows(aggr_impl: str, num_edges: int) -> int:
     """Sub-rows one step of a width-8 scan layout gathers at most: the
-    layout's chunk height, or every sub-row of a graph smaller than
-    one chunk; 0 for the layouts that scan no chunks.  What the memory
-    plan charges a step's scratch by (``core/memory.py``): the
-    ``[rows, 8, F]`` gathered block and its ``[rows, F]`` sum."""
+    layout's cap on a chunk's height, or every sub-row of a graph
+    smaller than one chunk; 0 for the layouts that scan no chunks.
+    What the memory plan charges a step's scratch by
+    (``core/memory.py``): the ``[rows, 8, F]`` gathered block and its
+    ``[rows, F]`` sum.  The plan runs before any table exists, so it
+    charges the cap: :func:`fit_chunks` never builds a taller chunk,
+    and a section of a few chunks may run a shorter one."""
     rows = {"sectioned": SECT_SEG_ROWS, "bdense": SECT_SEG_ROWS,
             "flat_sum": FLAT_SEG_ROWS}.get(aggr_impl, 0)
     return min(rows, -(-num_edges // 64) * 8)
@@ -703,21 +741,23 @@ def section_sub_counts(row_ptr: np.ndarray, col_idx: np.ndarray,
 
 def _resolve_chunks(counts, seg_rows: int, chunks_plan,
                     first_section: int = 0) -> list:
-    """Per-section chunk counts from sub-row totals, honoring (and
-    validating against) an SPMD plan — the ONE place this logic lives
-    (native and numpy builders both call it)."""
+    """Per-section ``(n_chunks, seg_rows)`` from sub-row totals:
+    :func:`fit_chunks` under the cap ``seg_rows``, or the SPMD plan's
+    entry, validated against the section's own total — the ONE place
+    this logic lives (native and numpy builders both call it)."""
     out = []
     for i, c in enumerate(counts):
         s = first_section + i
-        n = max(1, -(-int(c) // seg_rows))
-        if chunks_plan is not None:
-            if n > chunks_plan[s]:
-                raise ValueError(
-                    f"section {s}: needs {n} chunks > planned "
-                    f"{chunks_plan[s]} — the plan must come from "
-                    f"section_sub_counts over the same edges")
-            n = int(chunks_plan[s])
-        out.append(n)
+        if chunks_plan is None:
+            out.append(fit_chunks(c, seg_rows))
+            continue
+        n, seg = (int(v) for v in chunks_plan[s])
+        if int(c) > n * seg:
+            raise ValueError(
+                f"section {s}: {int(c)} sub-rows > planned {n} chunks "
+                f"of {seg} — the plan must come from "
+                f"section_sub_counts over the same edges")
+        out.append((n, seg))
     return out
 
 
@@ -732,10 +772,13 @@ def sectioned_from_graph(row_ptr: np.ndarray, col_idx: np.ndarray,
     ``src_rows`` is the source-id space (defaults to ``num_rows``;
     the distributed gathered space when they differ).  ``section_rows``
     defaults to 64 MiB worth of fp32 rows at F=256 — pass less for
-    wider feature matrices.  ``chunks_plan`` (per-section chunk counts,
-    from :func:`section_sub_counts` maxed across partitions) forces
-    uniform shapes for SPMD stacking; a section needing more chunks
-    than its plan raises.  ``sub_w`` is the sub-row width (neighbors
+    wider feature matrices.  ``seg_rows`` caps a chunk's height; each
+    section's chunk count and height come from its own sub-row total
+    (:func:`fit_chunks`).  ``chunks_plan`` (per-section ``(n_chunks,
+    seg_rows)``, :func:`sectioned_plan` of :func:`section_sub_counts`
+    maxed across partitions) forces uniform shapes for SPMD stacking
+    instead; a section holding more sub-rows than its plan raises.
+    ``sub_w`` is the sub-row width (neighbors
     gathered per table row; each (row, section) pair pads to a
     multiple of it).  Host-side prep uses the native two-pass builder
     (native/rocio.cc roc_sectioned_counts/_fill: 1.1 s at Reddit
@@ -759,7 +802,7 @@ def sectioned_from_graph(row_ptr: np.ndarray, col_idx: np.ndarray,
             counts = native.sectioned_counts(row_ptr, col_idx, num_rows,
                                              section_rows, n_sec, sub_w)
         chunks = _resolve_chunks(counts, seg_rows, chunks_plan)
-        slots = np.asarray([n * seg_rows for n in chunks],
+        slots = np.asarray([n * seg for n, seg in chunks],
                            dtype=np.int64)
         idx_flat, sub_flat = native.sectioned_fill(
             row_ptr, col_idx, num_rows, section_rows,
@@ -768,13 +811,12 @@ def sectioned_from_graph(row_ptr: np.ndarray, col_idx: np.ndarray,
         for s in range(n_sec):
             n = int(slots[s])
             idxs.append(idx_flat[off:off + n].reshape(
-                chunks[s], seg_rows, sub_w))
-            dsts.append(sub_flat[off:off + n].reshape(
-                chunks[s], seg_rows))
+                *chunks[s], sub_w))
+            dsts.append(sub_flat[off:off + n].reshape(chunks[s]))
             off += n
         return SectionedEll(
             num_rows=num_rows, src_rows=src_rows,
-            section_rows=section_rows, seg_rows=seg_rows,
+            section_rows=section_rows,
             sec_starts=tuple(s * section_rows for s in range(n_sec)),
             sec_sizes=tuple(all_sizes),
             idx=tuple(idxs), sub_dst=tuple(dsts), sub_w=sub_w)
@@ -794,10 +836,10 @@ def sectioned_from_graph(row_ptr: np.ndarray, col_idx: np.ndarray,
         sub_rows = padded[nz] // sub_w
         total_sub = int(sub_rows.sum())
         sec_size = all_sizes[s]
-        n_chunks = _resolve_chunks(
+        n_chunks, seg = _resolve_chunks(
             [total_sub], seg_rows, chunks_plan, first_section=s)[0]
-        pad = n_chunks * seg_rows - total_sub
-        tbl = np.full((n_chunks * seg_rows, sub_w), sec_size,
+        pad = n_chunks * seg - total_sub
+        tbl = np.full((n_chunks * seg, sub_w), sec_size,
                       dtype=np.int32)
         start_sub = np.zeros(len(nz) + 1, dtype=np.int64)
         np.cumsum(sub_rows, out=start_sub[1:])
@@ -812,26 +854,25 @@ def sectioned_from_graph(row_ptr: np.ndarray, col_idx: np.ndarray,
              np.full(pad, num_rows, np.int64)]).astype(np.int32)
         starts.append(s * section_rows)
         sizes.append(sec_size)
-        idxs.append(tbl.reshape(n_chunks, seg_rows, sub_w))
-        dsts.append(sub_dst.reshape(n_chunks, seg_rows))
+        idxs.append(tbl.reshape(n_chunks, seg, sub_w))
+        dsts.append(sub_dst.reshape(n_chunks, seg))
     return SectionedEll(
         num_rows=num_rows, src_rows=src_rows,
-        section_rows=section_rows, seg_rows=seg_rows,
+        section_rows=section_rows,
         sec_starts=tuple(starts), sec_sizes=tuple(sizes),
         idx=tuple(idxs), sub_dst=tuple(dsts), sub_w=sub_w)
 
 
 def sectioned_plan(counts_max: np.ndarray,
-                   seg_rows: int = SECT_SEG_ROWS) -> Tuple[int, list]:
-    """(seg_rows, per-section chunk counts) from elementwise-maxed
+                   seg_rows: int = SECT_SEG_ROWS) -> list:
+    """Per-section ``(n_chunks, seg_rows)`` from elementwise-maxed
     per-partition sub-row counts — THE single place the uniform-shape
     agreement math lives (used by the all-parts builder and the
     multi-host partition-local path; a divergence between the two
-    would only surface as a chunks_plan error at scale)."""
-    max_sub = int(np.max(counts_max)) if np.size(counts_max) else 1
-    seg = max(8, min(seg_rows, -(-max_sub // 8) * 8))
-    plan = [max(1, -(-int(c) // seg)) for c in np.asarray(counts_max)]
-    return seg, plan
+    would only surface as a chunks_plan error at scale).
+    :func:`fit_chunks` of each section's largest part under the cap
+    ``seg_rows``, so every part keeps one shape."""
+    return [fit_chunks(c, seg_rows) for c in np.asarray(counts_max)]
 
 
 def clean_part_ptr(part_row_ptr: np.ndarray, real_nodes: int,
@@ -856,10 +897,11 @@ def sectioned_from_padded_parts(part_row_ptr: np.ndarray,
     ``idx[s]`` is ``[P, n_chunks_s, seg_rows, sub_w]`` and
     ``sub_dst[s]`` ``[P, n_chunks_s, seg_rows]`` — same static shapes
     on every device.
-    ``seg_rows`` shrinks to fit small graphs; per-section chunk counts
-    are the max over partitions (metadata pass + plan), so partitions
-    with fewer edges carry padding chunks that gather the section's
-    zero row into the dummy output row.
+    Each section's chunk count and height fit its largest part
+    (metadata pass + :func:`sectioned_plan`, under the cap
+    ``seg_rows``), so partitions with fewer edges carry padding
+    sub-rows that gather the section's zero row into the dummy output
+    row.
 
     ``part_col`` is ``[P, part_edges]`` in gathered-row coordinates;
     padding edges are excluded via the real row extents."""
@@ -871,18 +913,18 @@ def sectioned_from_padded_parts(part_row_ptr: np.ndarray,
     counts = np.stack([
         section_sub_counts(ptrs[p], cols[p], part_nodes, src_rows,
                            section_rows, sub_w) for p in range(P)])
-    seg_rows, plan = sectioned_plan(counts.max(axis=0), seg_rows)
+    plan = sectioned_plan(counts.max(axis=0), seg_rows)
     per_part = [
         sectioned_from_graph(ptrs[p], cols[p], part_nodes,
                              src_rows=src_rows,
                              section_rows=section_rows,
-                             seg_rows=seg_rows, chunks_plan=plan,
+                             chunks_plan=plan,
                              counts=counts[p], sub_w=sub_w)
         for p in range(P)]
     first = per_part[0]
     return SectionedEll(
         num_rows=part_nodes, src_rows=src_rows,
-        section_rows=section_rows, seg_rows=seg_rows,
+        section_rows=section_rows,
         sec_starts=first.sec_starts, sec_sizes=first.sec_sizes,
         idx=tuple(np.stack([pp.idx[s] for pp in per_part])
                   for s in range(len(first.idx))),

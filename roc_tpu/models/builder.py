@@ -166,7 +166,7 @@ class GraphContext:
     head_chunk: int = 0
     axis_name: str = PARTS_AXIS
 
-    def agg_window(self, ops=()) -> dict:
+    def agg_window(self, ops=(), tables=None, edges=None) -> dict:
         """How far the chunk scan's destination window engaged — the
         run manifest's ``resolved`` carries it (obs/manifest.py):
         rows a chunk step reads and writes per section
@@ -177,7 +177,15 @@ class GraphContext:
         ``scatter_gather``, ``fused_aggregate``) — the width ``F`` the
         model gives the op and the width ``Fp`` its scan runs at
         (``core/ell.py agg_lane_width``; ``Fp == F`` where the rule
-        did not engage)."""
+        did not engage).  And how far ``core/ell.py fit_chunks``
+        engaged: ``agg_chunk_rows``, one ``[n_chunks, seg_rows]`` per
+        section of the sum scan's index tables, and ``agg_slot_fill``,
+        the graph's ``edges`` stored edges over the slots a pass
+        gathers (None where the tables hold another edge set:
+        ``bdense``'s residual).  The distributed trainer, whose tables
+        live outside its context, hands them in as ``tables``
+        (stacked: the trailing axes are read, every part's slots
+        counted)."""
         carry = self.num_rows + 1
         wins = [m[2] for m in self.sect_meta if len(m) > 2]
         if self.flat8_win:
@@ -187,10 +195,21 @@ class GraphContext:
                 if op.kind == "fused_aggregate"
                 or (op.kind == "scatter_gather"
                     and op.attrs["aggr"] in (AGGR_SUM, AGGR_AVG))]
+        if tables is None:
+            tables = ((self.flat8_idx,) if self.flat8_win
+                      else self.sect_idx)
+        if not wins:
+            tables = ()
+        slots = sum(int(t.size) for t in tables)
         return {"agg_window_rows": [scan_window_rows(w, carry)
                                     for w in wins],
                 "agg_carry_rows": carry if wins else None,
-                "agg_lane_pad": pads}
+                "agg_lane_pad": pads,
+                "agg_chunk_rows": [list(t.shape[-3:-1])
+                                   for t in tables],
+                "agg_slot_fill": (
+                    round(edges / slots, 4) if edges and slots
+                    and self.aggr_impl != "bdense" else None)}
 
     def attention_plan(self, ops, ell_idx=None, flat8_idx=None) -> dict:
         """What each attention op of ``ops`` (``Model._ops``) runs on —

@@ -70,7 +70,10 @@ def run_manifest(config=None, dataset=None, model=None,
     scan's window rows per section and the carry's height, so a run
     says how far the windowed scatter engaged; ``agg_lane_pad``, one
     ``[op, F, Fp]`` per sum-aggregating op (the model's width and the
-    lane-padded width its scan runs at); one ``attention`` entry
+    lane-padded width its scan runs at); ``agg_chunk_rows``, one
+    ``[n_chunks, seg_rows]`` per section of the sum scan's tables, and
+    ``agg_slot_fill``, stored edges over the slots a pass gathers (how
+    far ``core/ell.py fit_chunks`` engaged); one ``attention`` entry
     per attention op (heads, head width, layout, passes over the edge
     tables, slots a pass, carry rows) and one ``attention_backward``
     entry (the gradient rule, its edge passes, the whole-array

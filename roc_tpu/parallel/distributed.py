@@ -812,7 +812,9 @@ class DistributedTrainer:
                                 self.data.bd_occupancy),
                             "partition": self._partition_stats},
                      agg_window={
-                         **self._gctx().agg_window(model._ops),
+                         **self._gctx().agg_window(
+                             model._ops, tables=self.data.sect_idx,
+                             edges=int(dataset.graph.num_edges)),
                          **self._gctx().attention_plan(
                              model._ops, ell_idx=self.data.ell_idx,
                              flat8_idx=next(iter(self.data.sect_idx),

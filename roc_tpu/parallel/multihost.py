@@ -392,7 +392,8 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
         # baked fused weights multihost (shard_dataset_local has no
         # fuse path for any impl) — the builder's generic d-scaling
         # fallback covers fused configs when flat8_w is None
-        from ..core.ell import (clean_part_ptr, section_sub_counts,
+        from ..core.ell import (FLAT_SEG_ROWS, clean_part_ptr,
+                                section_sub_counts,
                                 sectioned_from_graph, sectioned_plan)
         src_rows = P * pn
         ptrs = {p: clean_part_ptr(pg.part_row_ptr[p], pg.real_nodes[p],
@@ -401,15 +402,15 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
             ptrs[p], cols[p][:int(ptrs[p][-1])], pn, src_rows,
             src_rows) for p in local}
         counts_max = _allreduce_part_vec_max(mesh, local, cnts)
-        seg, plan = sectioned_plan(counts_max, seg_rows=8192)
+        plan = sectioned_plan(counts_max, seg_rows=FLAT_SEG_ROWS)
         sects = {p: sectioned_from_graph(
             ptrs[p], cols[p][:int(ptrs[p][-1])], pn, src_rows=src_rows,
-            section_rows=src_rows, seg_rows=seg, chunks_plan=plan,
+            section_rows=src_rows, chunks_plan=plan,
             counts=cnts[p]) for p in local}
         sect_idx = (put_parts(lambda p: sects[p].idx[0],
-                              (plan[0], seg, 8), np.int32),)
+                              (*plan[0], 8), np.int32),)
         sect_sub_dst = (put_parts(lambda p: sects[p].sub_dst[0],
-                                  (plan[0], seg), np.int32),)
+                                  plan[0], np.int32),)
         if aggr_impl == "flat_sum":
             flat_win = agreed_win_rows(sects)[0]
 
@@ -432,10 +433,10 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
             ptrs[p], colmap[p], pn, src_rows,
             sec_rows, sub_w=sect_sub_w) for p in local}
         counts_max = _allreduce_part_vec_max(mesh, local, cnts)
-        seg, plan = sectioned_plan(counts_max)
+        plan = sectioned_plan(counts_max)
         sects = {p: sectioned_from_graph(
             ptrs[p], colmap[p], pn, src_rows=src_rows,
-            section_rows=sec_rows, seg_rows=seg, chunks_plan=plan,
+            section_rows=sec_rows, chunks_plan=plan,
             counts=cnts[p], sub_w=sect_sub_w) for p in local}
         if sect_u16:
             sects = {p: s.with_idx_dtype(np.uint16)
@@ -443,10 +444,10 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
         first = sects[local[0]]
         return (
             tuple(put_parts(lambda p, s=s: sects[p].idx[s],
-                            (plan[s], seg, sect_sub_w), idx_np_dtype)
+                            (*plan[s], sect_sub_w), idx_np_dtype)
                   for s in range(len(first.idx))),
             tuple(put_parts(lambda p, s=s: sects[p].sub_dst[s],
-                            (plan[s], seg), np.int32)
+                            plan[s], np.int32)
                   for s in range(len(first.sub_dst))),
             tuple(zip(first.sec_starts, first.sec_sizes,
                       agreed_win_rows(sects))))

@@ -1055,7 +1055,9 @@ class Trainer:
         run_manifest(config=self.config, dataset=dataset, model=model,
                      extra={"modeled_step_bytes": self._modeled_bytes},
                      agg_window={
-                         **self.gctx.agg_window(model._ops),
+                         **self.gctx.agg_window(
+                             model._ops,
+                             edges=int(dataset.graph.num_edges)),
                          **self.gctx.attention_plan(model._ops),
                          "memory_plan": self._plan},
                      console=config.verbose)

@@ -247,6 +247,62 @@ def test_plan_line_carries_agg_lane_pad(tmp_path, ds, impl, parts,
                                    [ops[1], 5, pad5]]
 
 
+@pytest.mark.parametrize("impl,parts", [
+    ("sectioned", 1), ("sectioned", 4), ("flat_sum", 1),
+    ("flat_sum", 4), ("bdense", 1), ("ell", 1)])
+def test_plan_line_carries_agg_chunk_rows(tmp_path, ds, impl, parts,
+                                          monkeypatch):
+    """``resolved`` says how far the chunk fit engaged (ISSUE 34): one
+    ``[n_chunks, seg_rows]`` per section of the sum scan's tables —
+    ``core/ell.py fit_chunks`` of the section's own sub-row count —
+    and the stored edges over the slots a pass gathers; from both
+    trainers, nothing for a layout that scans no chunks."""
+    import roc_tpu.core.ell as E
+    from roc_tpu.obs.events import configure
+    # three sections on this 300-node graph
+    monkeypatch.setattr(E, "SECTION_ROWS_DEFAULT", 128)
+    p = str(tmp_path / "ev.jsonl")
+    # bdense: only the diagonal tiles qualify, the rest is residual
+    cfg = TrainConfig(aggr_impl=impl, verbose=False, symmetric=True,
+                      bdense_min_fill=300)
+    model = build_gcn([12, 16, 5])
+    try:
+        configure(jsonl_path=p, console=False)
+        if parts > 1:
+            tr = DistributedTrainer(model, ds, parts, cfg)
+            tables = tr.data.sect_idx
+        else:
+            tr = Trainer(model, ds, cfg)
+            tables = (tr.gctx.sect_idx if impl != "flat_sum"
+                      else (tr.gctx.flat8_idx,))
+    finally:
+        configure(jsonl_path=None)
+    res = [json.loads(line) for line in open(p)
+           if json.loads(line)["cat"] == "manifest"][-1]["resolved"]
+    if impl == "ell":
+        assert res["agg_chunk_rows"] == []
+        assert res["agg_slot_fill"] is None
+        return
+    assert len(tables) == (1 if impl == "flat_sum" else 3)
+    assert res["agg_chunk_rows"] == [list(t.shape[-3:-1])
+                                     for t in tables]
+    if impl == "bdense":     # the tables hold the residual only
+        assert res["agg_slot_fill"] is None
+        return
+    g = ds.graph
+    slots = sum(int(np.prod(t.shape)) for t in tables)
+    assert res["agg_slot_fill"] == round(g.num_edges / slots, 4)
+    assert 0.2 < res["agg_slot_fill"] <= 1.0
+    if parts == 1:
+        counts = E.section_sub_counts(
+            g.row_ptr, g.col_idx, g.num_nodes, g.num_nodes,
+            128 if impl == "sectioned" else g.num_nodes)
+        cap = (E.SECT_SEG_ROWS if impl == "sectioned"
+               else E.FLAT_SEG_ROWS)
+        assert res["agg_chunk_rows"] == [list(E.fit_chunks(c, cap))
+                                         for c in counts]
+
+
 def test_agg_lane_pad_skips_max_and_attention(ds):
     from roc_tpu.models.builder import AGGR_AVG, AGGR_MAX, Model
     m = Model(in_dim=12)

@@ -107,6 +107,49 @@ def test_ell_widths(graph):
             assert got == want
 
 
+@pytest.mark.parametrize("plan", [None, "fitted", "roomy"])
+def test_sectioned_native_matches_numpy_per_section_heights(
+        plan, monkeypatch):
+    """Each section's chunks have a height of their own
+    (core/ell.py fit_chunks): native and numpy builders agree on every
+    shape and byte — fitted from the section's own count, or held to an
+    SPMD plan whose entries differ (``roomy``: a chunk more than
+    needed, all padding)."""
+    import roc_tpu.core.ell as ell_mod
+    from roc_tpu import native
+    from roc_tpu.core.graph import from_edge_list
+    if not native.available():
+        pytest.skip("native library unavailable")
+    # sources crowd the low ids: the sections' sub-row counts fall off
+    rng = np.random.RandomState(21)
+    n, e = 500, 6000
+    src = np.minimum(rng.exponential(90, e).astype(np.int64), n - 1)
+    g = from_edge_list(src, rng.randint(0, n, e), n)
+    counts = native.sectioned_counts(g.row_ptr, g.col_idx, n, 100, 5)
+    kw = dict(section_rows=100, seg_rows=256)
+    if plan is not None:
+        fitted = ell_mod.sectioned_plan(counts, 256)
+        kw["chunks_plan"] = (fitted if plan == "fitted" else
+                             [(c + 1, seg) for c, seg in fitted])
+
+    def build():
+        return ell_mod.sectioned_from_graph(g.row_ptr, g.col_idx, n,
+                                            **kw)
+
+    got = build()
+    monkeypatch.setattr(native, "available", lambda: False)
+    want = build()
+    heights = [a.shape[1] for a in got.idx]
+    assert len(set(heights)) >= 3 and all(h % 8 == 0 for h in heights)
+    assert [a.shape[:2] for a in got.idx] == [
+        (c + (plan == "roomy"), seg)
+        for c, seg in ell_mod.sectioned_plan(counts, 256)]
+    assert got.win_rows == want.win_rows
+    for a, b in zip(got.idx + got.sub_dst, want.idx + want.sub_dst):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert a.tobytes() == b.tobytes()
+
+
 def test_sectioned_native_matches_numpy():
     """The native sectioned prep (counts + fill) must produce
     byte-identical tables to the numpy builder across multi-section,

@@ -351,9 +351,24 @@ def _win_graph(case):
         n = 600
         rows = [np.unique(np.r_[v, rng.randint(0, n, 4)])
                 for v in range(n)]
-        return _csr(rows) + (n, dict(section_rows=256, seg_rows=16,
-                                     chunks_plan=[120, 120, 120]))
+        return _csr(rows) + (n, dict(section_rows=256,
+                                     chunks_plan=[(120, 16)] * 3))
+    if case == "fit":
+        # four 300-source sections holding 3600 / 1200 / 300 / 40
+        # sub-rows: under a cap of 256 each gets a height of its own
+        # (core/ell.py fit_chunks), the last a single short chunk
+        n = 1200
+        per_row = [(20, range(n)), (5, range(n)), (3, range(300)),
+                   (2, range(500, 540))]
+        rows = [np.concatenate(
+            [s * 300 + rng.choice(300, k, replace=False)
+             for s, (k, who) in enumerate(per_row) if v in who])
+            for v in range(n)]
+        return _csr(rows) + (n, dict(section_rows=300, seg_rows=256))
     raise AssertionError(case)
+
+
+_FIT_CHUNKS = [(15, 240), (5, 240), (2, 160), (1, 40)]
 
 
 def _spans(sub_dst, num_rows):
@@ -396,14 +411,15 @@ _TOL = {"float32": 1e-6, "bfloat16": 1e-2}
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("case", ["hub", "gaps", "trailing_pad",
                                   "full_window", "flat_hub",
-                                  "short_carry"])
+                                  "short_carry", "fit"])
 def test_windowed_scan_matches_segment(case, dtype, weighted):
     """The windowed scan == the segment reference, for every way a
     chunk's destination run can look: (a) a hub row over three or more
     chunks, (b) destinations that jump, (c) a trailing all-padding
     chunk, (d) a window as tall as the carry, the flat layout's
-    single section, and a window past half a short carry (the scan
-    takes the whole carry)."""
+    single section, a window past half a short carry (the scan
+    takes the whole carry), and sections whose chunks are fitted to
+    different heights."""
     from roc_tpu.core.ell import flat_sum_from_graph, sectioned_from_graph
     from roc_tpu.ops.aggregate import (aggregate_ell_sect,
                                        aggregate_flat_sum,
@@ -427,6 +443,8 @@ def test_windowed_scan_matches_segment(case, dtype, weighted):
                    for d in sect.sub_dst) > 100
     if case == "trailing_pad":
         assert all(s[-1] == 0 for s in spans)
+    if case == "fit":
+        assert [d.shape for d in sect.sub_dst] == _FIT_CHUNKS
     # the windowed path is what runs — or, on the short carry, not
     assert all(scan_window_rows(w, carry) ==
                (carry if case == "short_carry" else w)
@@ -459,21 +477,38 @@ def test_windowed_scan_matches_segment(case, dtype, weighted):
                                rtol=_TOL[dtype], atol=_TOL[dtype])
 
 
+def _local_graph(n):
+    """Every row gathers itself and six of the first 200 nodes: past
+    those 200 a block of sources is read by its own rows alone, so a
+    part's tables hold nothing in the sections of the other parts'
+    rows, and the busy first sections need taller chunks than the
+    rest."""
+    from roc_tpu.core.graph import from_edge_list
+    rng = np.random.RandomState(13)
+    dst = np.repeat(np.arange(n), 7)
+    src = np.c_[np.arange(n), rng.randint(0, 200, (n, 6))].reshape(-1)
+    return from_edge_list(src, dst, n)
+
+
 @pytest.mark.parametrize("weighted", [False, True],
                          ids=["plain", "weighted"])
-@pytest.mark.parametrize("layout", ["sectioned", "flat_sum"])
+@pytest.mark.parametrize("layout", ["sectioned", "flat_sum",
+                                    "sectioned_fit"])
 @pytest.mark.parametrize("parts", [2, 4])
 def test_windowed_scan_stacked_parts(parts, layout, weighted):
     """(e) stacked [P, ...] tables over unequal parts: every part scans
     with the one window all parts agreed on, and still equals its own
-    segment reference."""
+    segment reference — also where the sections' chunks are fitted to
+    different heights and a part holds nothing in a section."""
     from roc_tpu.core.ell import (flat_sum_from_padded_parts,
                                   sectioned_from_padded_parts)
     from roc_tpu.core.partition import partition_graph
     from roc_tpu.ops.aggregate import (aggregate_ell_sect,
                                        aggregate_flat_sum)
     from roc_tpu.parallel.distributed import remap_to_padded
-    g = add_self_edges(synthetic_graph(1400, 9, seed=5, power_law=True))
+    fit = layout == "sectioned_fit"
+    g = (_local_graph(1400) if fit else add_self_edges(
+        synthetic_graph(1400, 9, seed=5, power_law=True)))
     pg = partition_graph(g, parts, node_multiple=8, edge_multiple=8)
     assert len(set(int(r) for r in pg.real_nodes)) > 1  # unequal parts
     cols = remap_to_padded(pg)
@@ -485,7 +520,12 @@ def test_windowed_scan_stacked_parts(parts, layout, weighted):
     else:
         sect = sectioned_from_padded_parts(
             pg.part_row_ptr, cols, pg.real_nodes, pg.part_nodes,
-            src_rows=src_rows, section_rows=128, seg_rows=16)
+            src_rows=src_rows, section_rows=100 if fit else 128,
+            seg_rows=64 if fit else 16)
+    if fit:
+        assert len({d.shape[2] for d in sect.sub_dst}) > 1
+        assert any((d[p] == pg.part_nodes).all()
+                   for d in sect.sub_dst for p in range(parts))
     assert all(2 * w <= pg.part_nodes + 1 for w in sect.win_rows)
     x = _win_inputs(src_rows, 4, "float32")
     w = None
@@ -519,20 +559,24 @@ def test_windowed_scan_stacked_parts(parts, layout, weighted):
 
 @pytest.mark.parametrize("weighted", [False, True],
                          ids=["plain", "weighted"])
-@pytest.mark.parametrize("layout", ["sectioned", "flat_sum"])
+@pytest.mark.parametrize("layout", ["sectioned", "flat_sum",
+                                    "sectioned_fit"])
 def test_windowed_scan_grad_matches_segment(layout, weighted):
     """Exact autodiff THROUGH the scan (what symmetric=False runs): the
     slice / scatter-add / update-slice body transposes to the segment
-    reference's gradient."""
+    reference's gradient — at one chunk height, and at a height per
+    section."""
     from roc_tpu.core.ell import flat_sum_from_graph, sectioned_from_graph
     from roc_tpu.ops.aggregate import (aggregate_ell_sect,
                                        aggregate_flat_sum)
-    row_ptr, col, n, kw = _win_graph("hub")
+    fit = layout == "sectioned_fit"
+    row_ptr, col, n, kw = _win_graph("fit" if fit else "hub")
     if layout == "flat_sum":
         sect = flat_sum_from_graph(row_ptr, col, n, seg_rows=8)
     else:
         sect = sectioned_from_graph(row_ptr, col, n, **kw)
     assert all(2 * w <= n + 1 for w in sect.win_rows)
+    assert len({d.shape[1] for d in sect.sub_dst}) == (3 if fit else 1)
     x = _win_inputs(n, 3, "float32")
     d_dst = d_src = w = None
     if weighted:
@@ -600,9 +644,75 @@ def test_directed_grad_matches_segment(impl):
                                atol=1e-6)
 
 
+# ---- a chunk's height (core/ell.py fit_chunks) ----
+
+_H, _HF = 131_072, 8_192     # SECT_SEG_ROWS, FLAT_SEG_ROWS
+
+
+@pytest.mark.parametrize("sub_rows, cap, want", [
+    # gcn2-arxiv.fullgraph's three sections (graph_seed 22)
+    (209_069, _H, (2, 106_496)), (208_477, _H, (2, 106_496)),
+    (153_460, _H, (2, 81_920)),
+    # gcn-reddit.fullgraph's four: the cap, as before the fit
+    (4_154_254, _H, (32, _H)), (4_096_082, _H, (32, _H)),
+    (4_149_388, _H, (32, _H)), (2_350_688, _H, (18, _H)),
+    # a products partition's single flat_sum section
+    (4_215_000, _HF, (515, _HF)),
+    (0, _H, (1, 8)), (1, _H, (1, 8)), (_H, _H, (1, _H)),
+    (_H + 1, _H, (2, 73_728)), (_H - 9, _H, (1, _H - 8)),
+    (0, _HF, (1, 8)), (_HF, _HF, (1, _HF)), (_HF + 1, _HF, (2, 4_608)),
+    # caps the CPU rigs use, some under the 8-row tile
+    (3, 4, (1, 4)), (9, 4, (3, 4)), (100, 64, (2, 56)),
+    (40, 64, (1, 40)), (65, 16, (5, 16)),
+])
+def test_fit_chunks_rule(sub_rows, cap, want):
+    from roc_tpu.core.ell import (FLAT_SEG_ROWS, SECT_SEG_ROWS,
+                                  fit_chunks)
+    assert (_H, _HF) == (SECT_SEG_ROWS, FLAT_SEG_ROWS)
+    assert fit_chunks(sub_rows, cap) == want
+
+
+@pytest.mark.parametrize("cap", [_H, _HF, 4096, 64, 16, 4])
+def test_fit_chunks_bounds(cap):
+    """Over a sweep of counts: the chunks hold the section, as many
+    scan steps as a fixed height of ``cap`` takes and never more, no
+    chunk past the cap, heights on the 8-row tile, never more slots
+    than the fixed height gave, and from sixteen chunks on the cap
+    itself — so a large section's tables are what they were."""
+    from roc_tpu.core.ell import fit_chunks
+    rng = np.random.RandomState(cap)
+    counts = np.unique(np.r_[
+        0, 1, cap - 1, cap, cap + 1, 15 * cap, 15 * cap + 1, 16 * cap,
+        rng.randint(0, 40 * cap, 400), rng.randint(0, 3 * cap, 200)])
+    for c in counts:
+        n, seg = fit_chunks(int(c), cap)
+        assert n == max(1, -(-int(c) // cap))
+        assert c <= n * seg <= n * cap and 1 <= seg <= cap
+        assert seg % 8 == 0 or seg == cap
+        if n >= 16 and cap % 16 == 0:
+            assert seg == cap
+        if n == 1:
+            assert seg == min(cap, max(8, -(-int(c) // 8) * 8))
+
+
+@pytest.mark.parametrize("counts_max", [
+    (209_069, 208_477, 153_460), (70, 3, 0), (5000, 12, 130_000),
+    (4_154_254, 2_350_688)])
+def test_sectioned_plan_fits_each_section(counts_max):
+    """The SPMD plan is the same rule on each section's largest part,
+    and no section is taller than under the one shared height the
+    plan used to return (``ceil8`` of the largest count, capped)."""
+    from roc_tpu.core.ell import SECT_SEG_ROWS, fit_chunks, sectioned_plan
+    plan = sectioned_plan(np.asarray(counts_max))
+    assert plan == [fit_chunks(c, SECT_SEG_ROWS) for c in counts_max]
+    shared = max(8, min(SECT_SEG_ROWS, -(-max(counts_max) // 8) * 8))
+    for c, (n, seg) in zip(counts_max, plan):
+        assert seg <= shared and n <= max(1, -(-c // shared))
+
+
 # ---- the table's window (core/ell.py SectionedEll.win_rows) ----
 
-@pytest.mark.parametrize("case", ["hub", "gaps", "trailing_pad"])
+@pytest.mark.parametrize("case", ["hub", "gaps", "trailing_pad", "fit"])
 @pytest.mark.parametrize("builder", ["native", "numpy"])
 def test_win_rows_bounds_every_chunk(case, builder, monkeypatch):
     """win_rows covers every chunk's real span and stays below the
@@ -619,6 +729,8 @@ def test_win_rows_bounds_every_chunk(case, builder, monkeypatch):
     sect = E.sectioned_from_graph(row_ptr, col, n, **kw)
     assert sect.win_rows == other.win_rows
     assert len(sect.win_rows) == len(sect.sub_dst)
+    if case == "fit":
+        assert [d.shape for d in sect.sub_dst] == _FIT_CHUNKS
     for w, d in zip(sect.win_rows, sect.sub_dst):
         largest = int(_spans(d, n).max())
         assert largest <= w < largest + E.WIN_ROWS_MULTIPLE

@@ -454,10 +454,14 @@ def _scan_shapes(closed_jaxpr):
 def test_flat_sum_single_scan_program(rig_dataset):
     """THE consolidation pin: a flat_sum config with ONE aggregation
     width compiles exactly ONE scan program into its train step —
-    forward and symmetric-vjp backward share the shape, and the shape
-    is independent of the degree distribution (a skewed dataset
-    enumerates the identical scan set; the per-bucket ELL unroll
-    would have compiled one program per width bucket)."""
+    forward and symmetric-vjp backward share the shape, and the degree
+    distribution moves nothing in it but the chunk's height (a skewed
+    dataset enumerates one scan too; the per-bucket ELL unroll would
+    have compiled one program per width bucket).  The height is the
+    graph's own sub-row count, to the 8, while that is under the cap,
+    and the cap's from sixteen chunks on (``core/ell.py
+    fit_chunks``)."""
+    import re
     from roc_tpu.analysis.programspace import _C, _F
     from roc_tpu.core.graph import synthetic_dataset
     from roc_tpu.models.sgc import build_sgc
@@ -475,13 +479,25 @@ def test_flat_sum_single_scan_program(rig_dataset):
             tr.labels, tr.mask, tr.gctx)
         return _scan_shapes(jaxpr)
 
-    shapes = shapes_for(rig_dataset)
-    assert len(shapes) == 1, shapes
-    # degree-distribution independence: a much more skewed graph of
-    # the same size yields the same single scan shape
+    def one_scan(ds):
+        """(the scan's operand shapes with the chunk height masked,
+        the chunk height)"""
+        shapes = shapes_for(ds)
+        assert len(shapes) == 1, shapes
+        (avals,) = shapes
+        (seg,) = {int(m) for a in avals
+                  for m in re.findall(r"\[1,(\d+)(?:,8)?\]", a)}
+        subs = int((-(-np.diff(ds.graph.row_ptr) // 8)).sum())
+        assert seg == -(-subs // 8) * 8
+        return tuple(a.replace(f"[1,{seg}", "[1,seg") for a in avals), seg
+
+    masked, seg = one_scan(rig_dataset)
+    # a much more skewed graph of the same size: the same single scan
+    # at its own height
     skew = synthetic_dataset(num_nodes=256, avg_degree=12, in_dim=_F,
                              num_classes=_C, seed=7)
-    assert shapes_for(skew) == shapes
+    masked_skew, seg_skew = one_scan(skew)
+    assert masked_skew == masked and seg_skew != seg
 
 
 def test_flat_sum_rig_one_scan_per_width(rig_dataset):
