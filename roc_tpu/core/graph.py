@@ -29,7 +29,7 @@ from __future__ import annotations
 import os
 import struct
 from dataclasses import dataclass
-from typing import Optional
+from typing import Any, Optional
 
 import numpy as np
 
@@ -388,6 +388,9 @@ class Dataset:
     mask: np.ndarray      # int32 [V] of MASK_* values
     num_classes: int
     name: str = "dataset"
+    # a typed graph's kinds and relations (core/relations.py
+    # derive_typed over ``graph``), for a typed model; None otherwise
+    typed: Any = None
 
     @property
     def in_dim(self) -> int:

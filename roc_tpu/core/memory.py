@@ -47,7 +47,8 @@ _STAT_BYTES = 4              # fp32 by-products (softmax, attention)
 # the model op kinds that aggregate over edges (obs/scopes.py AGG_KINDS:
 # this module imports nothing, so the tuple is repeated); under remat
 # they are the boundaries of the runs computed again
-AGG_KINDS = ("scatter_gather", "fused_aggregate", "gat")
+AGG_KINDS = ("scatter_gather", "fused_aggregate", "gat",
+             "rel_aggregate")
 
 
 def op_residuals(i: int, op: Any, itemsize: int
@@ -69,11 +70,15 @@ def op_residuals(i: int, op: Any, itemsize: int
     | ``scatter_gather`` SUM / AVG, ``fused_aggregate`` | nothing: the backward is the same sum over the cotangent (a fused ReLU keeps the output) |
     | ``scatter_gather`` MAX / MIN | input and output (where the max sat) |
     | ``gat`` | input, output and the fp32 row sums of the hand-written backward |
+    | ``rel_linear``, ``root_linear`` | their input (for the dW of each relation / kind); a stacked input is charged at its own height (``row_scale``) |
+    | ``rel_aggregate``, ``typed_input`` | nothing: the relation sum's backward is the pass over the transposed table, the assembly a concatenation |
     """
     def t(j):
         return ("t", j)
 
     kind, attrs = op.kind, getattr(op, "attrs", None) or {}
+    if kind in ("rel_linear", "root_linear"):
+        return [(t(op.inputs[0]), attrs["in_dim"], itemsize)]
     if kind == "linear":
         out = [(t(op.inputs[0]), attrs.get("in_dim", op.dim), itemsize)]
         if attrs.get("activation", "none") != "none":
@@ -148,12 +153,21 @@ def saved_for_backward(ops: Sequence[Any], itemsize: int,
     seen = {("t", 0)}
     kept: List[Tuple[int, int, int]] = []
 
+    def scaled(k, w, b):
+        # an array as tall as the op that made it: a typed model's
+        # stacked tensors hold ``row_scale`` rows a vertex (the input,
+        # tensor 0, its feature rows alone); 1 everywhere else
+        scale = (getattr(ops[k[1]], "attrs", None) or {}).get(
+            "row_scale", 1)
+        return w * b if scale == 1 else round(w * b * scale, 1)
+
     def charge(i, items):
         new = [(k, w, b) for k, w, b in dict.fromkeys(items)
                if k not in seen]
         seen.update(k for k, _, _ in new)
         if new:
-            kept.append((i, len(new), sum(w * b for _, w, b in new)))
+            kept.append((i, len(new),
+                         sum(scaled(k, w, b) for k, w, b in new)))
 
     runs = dict(remat_segments(ops)) if remat else {}
     inside = {k for lo, hi in runs.items() for k in range(lo, hi)}
@@ -166,24 +180,40 @@ def saved_for_backward(ops: Sequence[Any], itemsize: int,
             charge(i, op_residuals(i, ops[i], itemsize))
     if len(ops) > 1:
         last = len(ops) - 1
+        # the loss's fp32 softmax covers the rows that can carry a
+        # label: all of them, or a typed model's kind 0
+        # (``label_scale`` on its input op)
+        labelled = (getattr(ops[0], "attrs", None) or {}).get(
+            "label_scale", 1)
         charge(last, [(("t", last), ops[last].dim, itemsize),
-                      (("m", last), ops[last].dim, _STAT_BYTES)])
+                      (("m", last), ops[last].dim,
+                       _STAT_BYTES * labelled)])
     recompute = 0
     for lo, hi in runs.items():
         items = dict.fromkeys(
             r for k in range(lo, hi)
             for r in op_residuals(k, ops[k], itemsize)
             if r[0] not in seen)
-        recompute = max(recompute, sum(w * b for _, w, b in items))
+        recompute = max(recompute,
+                        sum(scaled(k, w, b) for k, w, b in items))
     return kept, recompute
 
 
 def param_elems(ops: Sequence[Any]) -> int:
     """Trainable scalars of the op list: every ``linear``'s matrix,
-    every ``gat``'s two attention vectors, every ``scale_add``'s eps."""
+    every ``gat``'s two attention vectors, every ``scale_add``'s eps;
+    of a typed model the embedding tables' rows, a matrix a relation
+    and a matrix and a bias a kind."""
     n = 0
     for op in ops:
-        if op.kind == "linear":
+        if op.kind == "typed_input":
+            n += op.attrs["embed_rows"] * op.dim
+        elif op.kind == "rel_linear":
+            n += (op.attrs["n_rel"] * op.attrs["in_dim"]
+                  * op.attrs["out_dim"])
+        elif op.kind == "root_linear":
+            n += op.attrs["n_kinds"] * (op.attrs["in_dim"] + 1) * op.dim
+        elif op.kind == "linear":
             n += op.attrs["in_dim"] * op.dim
         elif op.kind == "gat":
             n += 2 * op.dim
@@ -293,26 +323,35 @@ def plan_components(num_nodes: int, num_edges: int,
     E_p = -(-num_edges // num_parts)
     b = dtype_bytes
     F = ops[0].dim
-    h_max = max(op.dim for op in ops)
+
+    def scale(op):
+        return (getattr(op, "attrs", None) or {}).get("row_scale", 1)
+
+    # the widest array a step holds whole, in elements a vertex row (a
+    # typed model's stacked tensors are taller than V)
+    h_max = max(op.dim * scale(op) for op in ops)
     w = param_elems(ops)
     out = {"params_opt": w * (4 * param_bytes + b)}
-    # input features: resident, or one streamed block + dY reuse
-    out["features"] = (V_p if features == "hbm" else 65536) * F * b
+    # input features: resident, or one streamed block + dY reuse (a
+    # typed model's input holds the rows of the kinds that have any)
+    out["features"] = int((V_p * scale(ops[0]) if features == "hbm"
+                           else 65536) * F * b)
     # edge tables: ELL idx ~ E_p int32 (+ row positions)
     out["tables"] = E_p * 4 + V_p * 4 + extra_table_bytes
     if halo == "ring":
         out["tables"] += int(2 * E_p * 4 * ring_padding)  # src+dst flat
     kept, recompute = kept or saved_for_backward(ops, b, remat)
-    out["activations"] = V_p * sum(row for _, _, row in kept)
+    out["activations"] = int(V_p * sum(row for _, _, row in kept))
     if features != "hbm":
         # the streamed head's insides never sit whole on the device
         head = {i for i, op in enumerate(ops[:3]) if i}
         out["activations"] -= V_p * sum(
             row for i, _, row in kept if i in head and ops[i].dim == F)
     # halo transient: the gathered global matrix vs two ring buffers
-    out["transient"] = ((num_parts if halo == "gather" else 2)
-                        * V_p * h_max * b + V_p * recompute
-                        + scan_rows * 9 * h_max * b)
+    out["transient"] = int((num_parts if halo == "gather" else 2)
+                           * V_p * h_max * b + V_p * recompute
+                           + scan_rows * 9 * max(op.dim for op in ops)
+                           * b)
     return out
 
 

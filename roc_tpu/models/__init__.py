@@ -15,8 +15,9 @@ def model_builders() -> Dict[str, Callable]:
     from .gcn import build_gcn
     from .gcn2 import build_gcn2
     from .gin import build_gin
+    from .rgcn import build_rgcn
     from .sage import build_sage
     from .sgc import build_sgc
     return {"gcn": build_gcn, "sage": build_sage, "gin": build_gin,
             "gat": build_gat, "sgc": build_sgc, "appnp": build_appnp,
-            "gcn2": build_gcn2}
+            "gcn2": build_gcn2, "rgcn": build_rgcn}

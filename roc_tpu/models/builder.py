@@ -30,8 +30,9 @@ import numpy as np
 
 from ..core.ell import agg_lane_width
 from ..core.memory import remat_segments
-from ..obs.scopes import (ATTN_SCORES_SCOPE, HALO_SCOPE, LOSS_SCOPE,
-                          RECOMPUTE_SCOPE, op_scope)
+from ..core.relations import ORDER_PASSES, TRANSFORM_FIRST
+from ..obs.scopes import (ATTN_SCORES_SCOPE, EMBED_SCOPE, HALO_SCOPE,
+                          LOSS_SCOPE, RECOMPUTE_SCOPE, op_scope)
 from ..ops import dense
 from ..parallel import PARTS_AXIS
 from ..ops.aggregate import (aggregate_ell, aggregate_ell_max,
@@ -165,6 +166,17 @@ class GraphContext:
     # ops/dense.py linear_chunked).
     head_chunk: int = 0
     axis_name: str = PARTS_AXIS
+    # Typed graph (core/relations.py): one entry per relation pass the
+    # resolved orders run — ``rel_meta`` static ``(pass name, rows
+    # summed into, rows gathered out of, win_rows)``; under 'flat_sum'
+    # ``rel_idx [n_chunks, 8 * seg]`` (slot-major: unpadded at rest,
+    # ops/aggregate.py _scan_window_sum) / ``rel_dst [n_chunks, seg]``
+    # / ``rel_w`` fp32 like ``rel_idx`` (each slot's ``1 / deg_r(v)``),
+    # under 'segment' the forward passes' flat edge lists ``[E']``.
+    rel_idx: Tuple[jax.Array, ...] = ()
+    rel_dst: Tuple[jax.Array, ...] = ()
+    rel_w: Tuple[jax.Array, ...] = ()
+    rel_meta: Tuple[Tuple[Any, ...], ...] = ()
 
     def agg_window(self, ops=(), tables=None, edges=None) -> dict:
         """How far the chunk scan's destination window engaged — the
@@ -468,6 +480,88 @@ class GraphContext:
             return -self._max_fwd(-x)
         raise ValueError(f"unknown aggregator: {aggr}")
 
+    def _rel_pass(self, x: jax.Array, name: str) -> jax.Array:
+        """One relation pass (core/relations.py) over ``x``, whose
+        rows are what the pass gathers out of: the weighted sum into
+        the pass's own row space."""
+        k = next(i for i, m in enumerate(self.rel_meta) if m[0] == name)
+        _, out_rows, _, win = self.rel_meta[k]
+        if self.aggr_impl == "segment":
+            g = x[self.rel_idx[k]] * self.rel_w[k][:, None]
+            return jax.ops.segment_sum(
+                g, self.rel_dst[k], num_segments=out_rows,
+                indices_are_sorted=True).astype(x.dtype)
+        zero = jnp.zeros((1, x.shape[1]), dtype=x.dtype)
+        return aggregate_flat_sum(
+            jnp.concatenate([x, zero], axis=0), self.rel_idx[k],
+            self.rel_dst[k], out_rows, flat_w=self.rel_w[k],
+            win_rows=win, weights_fp32=True, slot_major=True)
+
+    def rel_aggregate(self, x: jax.Array, order: str) -> jax.Array:
+        """The relation aggregation ``sum_r mean_r(.)`` of a typed
+        graph as ONE weighted sum over the union edge list, on the
+        side of the product ``order`` names: ``transform_first`` sums
+        src-stacked rows into vertices, ``gather_first`` vertices
+        into dst-stacked rows.  Its backward is the pass over the
+        transposed table with the same per-edge weights — exact for
+        any graph, scatter-free, and it keeps nothing; 'segment', the
+        edge-list reference, is autodiff through the forward."""
+        fwd_pass, bwd_pass = ORDER_PASSES[order]
+        if self.aggr_impl == "segment":
+            return self._rel_pass(x, fwd_pass)
+
+        @jax.custom_vjp
+        def agg(x):
+            return self._rel_pass(x, fwd_pass)
+
+        def fwd(x):
+            return agg(x), None
+
+        def bwd(_, g):
+            return (self._rel_pass(g, bwd_pass),)
+
+        agg.defvjp(fwd, bwd)
+        return self._lane_padded(agg, x)
+
+    def relation_plan(self, ops=(), typed=None) -> dict:
+        """What the typed graph resolved to, for the run manifest's
+        ``resolved`` beside :meth:`agg_window`: the kinds, one
+        ``relations`` entry a relation, one ``rel_layers`` entry a
+        relational layer (its ``rel_order``, the widths on the two
+        sides, the width its scan runs at, stacked rows, table slots a
+        forward / backward pass gathers and ``agg_slot_fill``, stored
+        relation edges over the forward's slots), and the trainable
+        input rows.  Empty for an untyped model."""
+        if typed is None:
+            return {}
+        slots = {m[0]: int(np.prod(t.shape)) for m, t
+                 in zip(self.rel_meta, self.rel_idx)}
+        layers = []
+        for i, op in enumerate(ops):
+            if op.kind != "rel_aggregate":
+                continue
+            order = op.attrs["order"]
+            f, b = (slots.get(p) for p in ORDER_PASSES[order])
+            layers.append({
+                "op": i, "layer": op.attrs["layer"], "rel_order": order,
+                "in_dim": op.attrs["in_dim"],
+                "out_dim": op.attrs["out_dim"], "gather_width": op.dim,
+                "scan_width": self._lane_width(op.dim),
+                "stacked_rows": (typed.src_rows
+                                 if order == TRANSFORM_FIRST
+                                 else typed.dst_rows),
+                "slots_fwd": f, "slots_bwd": b,
+                "agg_slot_fill": (round(typed.num_edges / f, 4)
+                                  if f and self.aggr_impl != "segment"
+                                  else None)})
+        emb = [op for op in ops if op.kind == "typed_input"]
+        rows = sum(op.attrs["embed_rows"] for op in emb)
+        return {"node_types": list(typed.node_types),
+                "relations": typed.describe(),
+                "relation_edges": typed.num_edges,
+                "rel_layers": layers, "embedding_rows": rows,
+                "embedding_bytes": rows * emb[0].dim * 4 if emb else 0}
+
     def gat_attention(self, x: jax.Array, a_src: jax.Array,
                       a_dst: jax.Array,
                       neg_slope: float = 0.2) -> jax.Array:
@@ -613,22 +707,22 @@ def _gctx_flatten(g: GraphContext):
                 g.ell_row_pos, g.ring_idx, g.sect_idx, g.sect_sub_dst,
                 g.ell_row_id, g.flat8_idx, g.flat8_dst, g.flat8_w,
                 g.bd_a, g.bd_src, g.bd_dst, g.ell_w, g.sect_w,
-                g.ring_w, g.bd_scale)
+                g.ring_w, g.bd_scale, g.rel_idx, g.rel_dst, g.rel_w)
     aux = (g.num_rows, g.gathered_rows, g.gather_features, g.psum,
            g.aggr_impl, g.symmetric, g.halo, g.axis_name,
            g.sect_meta, g.bd_vpad, g.bd_src_vpad, g.bd_group,
-           g.ring_overlap, g.head_chunk, g.flat8_win)
+           g.ring_overlap, g.head_chunk, g.flat8_win, g.rel_meta)
     return children, aux
 
 
 def _gctx_unflatten(aux, children):
     (num_rows, gathered_rows, gather_features, psum, aggr_impl,
      symmetric, halo, axis_name, sect_meta, bd_vpad, bd_src_vpad,
-     bd_group, ring_overlap, head_chunk, flat8_win) = aux
+     bd_group, ring_overlap, head_chunk, flat8_win, rel_meta) = aux
     (edge_src, edge_dst, in_degree, ell_idx, ell_row_pos, ring_idx,
      sect_idx, sect_sub_dst, ell_row_id, flat8_idx,
      flat8_dst, flat8_w, bd_a, bd_src, bd_dst, ell_w, sect_w, ring_w,
-     bd_scale) = children
+     bd_scale, rel_idx, rel_dst, rel_w) = children
     return GraphContext(
         edge_src=edge_src, edge_dst=edge_dst, in_degree=in_degree,
         num_rows=num_rows, gathered_rows=gathered_rows,
@@ -643,7 +737,9 @@ def _gctx_unflatten(aux, children):
         bd_src_vpad=bd_src_vpad,
         bd_group=bd_group, ring_overlap=ring_overlap,
         head_chunk=head_chunk,
-        ell_w=ell_w, sect_w=sect_w, ring_w=ring_w, bd_scale=bd_scale)
+        ell_w=ell_w, sect_w=sect_w, ring_w=ring_w, bd_scale=bd_scale,
+        rel_idx=rel_idx, rel_dst=rel_dst, rel_w=rel_w,
+        rel_meta=rel_meta)
 
 
 # GraphContext is a pytree so the graph tables travel as jit ARGUMENTS.
@@ -717,6 +813,80 @@ class Model:
         self._n_gat = 0
         self._n_eps = 0
         self._loss_op: Optional[int] = None
+        # a typed model's kinds (models/rgcn.py): ``{"node_types",
+        # "embed_types", "relations"}`` — None for every other family
+        self.typed: Optional[Dict[str, Any]] = None
+
+    def uses_relations(self) -> bool:
+        """True for a typed model (``rel_aggregate`` ops): it runs on
+        the relation tables of ``core/relations.py``, on one chip."""
+        return any(op.kind == "rel_aggregate" for op in self._ops)
+
+    def rel_orders(self) -> Tuple[str, ...]:
+        """The resolved side of the mean of each relational layer, in
+        layer order."""
+        return tuple(op.attrs["order"] for op in self._ops
+                     if op.kind == "rel_aggregate")
+
+    def with_rel_orders(self, resolve) -> "Model":
+        """The model with each relational layer's product on the side
+        of its mean that ``resolve(in_dim, out_dim)`` names
+        (``core/relations.py resolve_rel_order``): a layer is the
+        adjacent pair ``rel_linear -> rel_aggregate``
+        (``transform_first``: stacked products, then the sum into
+        vertices) or ``rel_aggregate -> rel_linear``
+        (``gather_first``: stacked means, then their products), and
+        the rewrite swaps the pair in place — op indices, consumers
+        and parameter names are untouched.  Returns ``self`` when
+        nothing changes (``resolve_config`` is idempotent)."""
+        ty = self.typed
+        ops = [_Op(o.kind, o.inputs, o.dim, o.param, dict(o.attrs))
+               for o in self._ops]
+        changed = False
+        for i, op in enumerate(ops[:-1]):
+            nxt = ops[i + 1]
+            if {op.kind, nxt.kind} != {"rel_linear", "rel_aggregate"} \
+                    or nxt.inputs != (i,):
+                continue
+            a = op.attrs
+            order = resolve(a["in_dim"], a["out_dim"])
+            if order == a["order"]:
+                continue
+            changed = True
+            lin = op if op.kind == "rel_linear" else nxt
+            base = {"layer": a["layer"], "in_dim": a["in_dim"],
+                    "out_dim": a["out_dim"], "order": order,
+                    "n_rel": a["n_rel"]}
+            scale = self._stack_scale(order)
+            if order == TRANSFORM_FIRST:
+                ops[i] = _Op("rel_linear", op.inputs, a["out_dim"],
+                             lin.param, {**base, "row_scale": scale})
+                ops[i + 1] = _Op("rel_aggregate", (i,), a["out_dim"],
+                                 attrs=dict(base))
+            else:
+                ops[i] = _Op("rel_aggregate", op.inputs, a["in_dim"],
+                             attrs={**base, "row_scale": scale})
+                ops[i + 1] = _Op("rel_linear", (i,), a["out_dim"],
+                                 lin.param, dict(base))
+        if not changed:
+            return self
+        new = Model(in_dim=ops[0].dim)
+        new._ops = ops
+        new._n_linear, new._n_gat, new._n_eps = (
+            self._n_linear, self._n_gat, self._n_eps)
+        new._loss_op = self._loss_op
+        new.typed = ty
+        return new
+
+    def labelled(self, *arrays):
+        """``arrays`` (logits, labels, mask: a row a vertex) cut to
+        the rows that can carry a label: untouched, or a typed model's
+        kind 0 — the loss and the metrics read those rows alone (the
+        mask is ``None`` everywhere else), so their fp32 softmax is a
+        kind tall, not ``V``."""
+        if not self.typed:
+            return arrays
+        return tuple(a[:self.typed["node_types"][0]] for a in arrays)
 
     def uses_attention(self) -> bool:
         """True when the op list contains a gat op — such models run
@@ -899,6 +1069,59 @@ class Model:
         return self._append("lerp", (a.idx, b.idx), a.dim,
                             attrs={"alpha": float(alpha)})
 
+    # ---- typed graphs (models/rgcn.py) ----
+
+    def typed_input(self, t: TensorHandle, node_types, embed_types,
+                    relations) -> TensorHandle:
+        """``h^0`` of a typed graph, ``[V, F]`` in kind order: the
+        file's feature rows for the kinds that have them (the model's
+        input holds those rows alone, in kind order) and a trainable
+        table ``embed_<k>`` ``[n_k, F]`` for each kind of
+        ``embed_types``.  Declares the model typed: ``relations`` are
+        the ordered kind pairs of ``core/relations.py derive_typed``."""
+        self.typed = {"node_types": tuple(int(n) for n in node_types),
+                      "embed_types": tuple(int(k) for k in embed_types),
+                      "relations": tuple((int(s), int(d))
+                                         for s, d in relations)}
+        ty = self.typed
+        rows = sum(ty["node_types"][k] for k in ty["embed_types"])
+        V = sum(ty["node_types"])
+        self._ops[0].attrs["row_scale"] = (V - rows) / V
+        # kind 0 alone carries labels (Model.labelled)
+        self._ops[0].attrs["label_scale"] = ty["node_types"][0] / V
+        return self._append("typed_input", (t.idx,), t.dim,
+                            param="embed",
+                            attrs={"embed_rows": rows})
+
+    def rel_conv(self, t: TensorHandle, out_dim: int,
+                 layer: int) -> TensorHandle:
+        """``sum_r mean_r(t W_r)`` over the relations into each
+        vertex: a bias-free ``rel<layer>_<s>_<d>`` ``[in, out]`` a
+        relation and ONE relation aggregation
+        (``GraphContext.rel_aggregate``), recorded product first;
+        :meth:`with_rel_orders` puts the product on the cheaper
+        side."""
+        ty = self.typed
+        base = {"layer": layer, "in_dim": t.dim, "out_dim": out_dim,
+                "order": TRANSFORM_FIRST, "n_rel": len(ty["relations"])}
+        p = self._append("rel_linear", (t.idx,), out_dim,
+                         param=f"rel{layer}",
+                         attrs={**base, "row_scale": self._stack_scale(
+                             TRANSFORM_FIRST)})
+        return self._append("rel_aggregate", (p.idx,), out_dim,
+                            attrs=dict(base))
+
+    def root_linear(self, t: TensorHandle, out_dim: int,
+                    layer: int) -> TensorHandle:
+        """A vertex's own term: ``root<layer>_<k>`` ``[in, out]`` and
+        the bias ``root<layer>_<k>_b`` ``[out]`` of its kind ``k``,
+        over the kind's row range."""
+        return self._append("root_linear", (t.idx,), out_dim,
+                            param=f"root{layer}",
+                            attrs={"layer": layer, "in_dim": t.dim,
+                                   "n_kinds": len(
+                                       self.typed["node_types"])})
+
     def softmax_cross_entropy(self, t: TensorHandle) -> TensorHandle:
         """Marks ``t`` as the logits fed to the masked CE loss (labels and
         mask arrive as apply() arguments, unlike the reference which binds
@@ -1014,7 +1237,7 @@ class Model:
     # ---- serving support ----
 
     GRAPH_OP_KINDS = ("scatter_gather", "fused_aggregate", "gat",
-                      "indegree_norm")
+                      "indegree_norm", "rel_aggregate")
 
     def precompute_split(self):
         """``(prefix_ops, head_model)`` when the op list is a
@@ -1069,6 +1292,10 @@ class Model:
                     for op in self._ops[1:]],
             "loss_op": self._loss_op,
             "counters": [self._n_linear, self._n_gat, self._n_eps],
+            **({"typed": {k: [list(x) if isinstance(x, tuple) else x
+                              for x in v]
+                          for k, v in self.typed.items()}}
+               if self.typed else {}),
         }
 
     @classmethod
@@ -1083,6 +1310,12 @@ class Model:
         c = spec.get("counters") or [0, 0, 0]
         model._n_linear, model._n_gat, model._n_eps = (
             int(c[0]), int(c[1]), int(c[2]))
+        ty = spec.get("typed")
+        if ty:
+            model.typed = {
+                "node_types": tuple(ty["node_types"]),
+                "embed_types": tuple(ty["embed_types"]),
+                "relations": tuple(tuple(r) for r in ty["relations"])}
         return model
 
     # ---- params ----
@@ -1102,6 +1335,18 @@ class Model:
             elif op.kind == "scale_add":
                 # learnable GIN eps: zero-init (the paper's GIN-0)
                 params[op.param] = jnp.zeros((), dtype=dtype)
+            elif op.kind in ("typed_input", "rel_linear", "root_linear"):
+                # Glorot-uniform like every matrix here (an embedding
+                # table over its logical [rows, F] shape, as torch's
+                # xavier_uniform_ on the OGB script's tables); biases 0
+                for name, shape in self._typed_param_shapes(op):
+                    if len(shape) == 1:
+                        params[name] = jnp.zeros(shape, dtype=dtype)
+                        continue
+                    key, sub = jax.random.split(key)
+                    s = float(np.sqrt(6.0 / (shape[0] + shape[1])))
+                    params[name] = jax.random.uniform(
+                        sub, shape, dtype=dtype, minval=-s, maxval=s)
             elif op.kind == "gat":
                 # per head, the attention vectors are the [2*dh] -> 1
                 # projection of the GAT paper split at the concat
@@ -1115,6 +1360,81 @@ class Model:
                         sub, (heads, dh), dtype=dtype, minval=-s,
                         maxval=s)
         return params
+
+    def _stack_scale(self, order: str) -> float:
+        """Rows of the order's stacked tensor a vertex: the src stack
+        under ``transform_first``, the dst stack under
+        ``gather_first`` (core/relations.py)."""
+        ty = self.typed
+        end = 0 if order == TRANSFORM_FIRST else 1
+        return (sum(ty["node_types"][r[end]] for r in ty["relations"])
+                / sum(ty["node_types"]))
+
+    def _typed_param_shapes(self, op: _Op):
+        """``[(name, shape), ...]`` of a typed op's parameters, in
+        construction order."""
+        ty = self.typed
+        if op.kind == "typed_input":
+            return [(f"{op.param}_{k}", (ty["node_types"][k], op.dim))
+                    for k in ty["embed_types"]]
+        if op.kind == "rel_linear":
+            a = op.attrs
+            return [(f"{op.param}_{s}_{d}", (a["in_dim"], a["out_dim"]))
+                    for s, d in ty["relations"]]
+        out = []
+        for k in range(len(ty["node_types"])):
+            out += [(f"{op.param}_{k}", (op.attrs["in_dim"], op.dim)),
+                    (f"{op.param}_{k}_b", (op.dim,))]
+        return out
+
+    def _kind_ranges(self):
+        off = np.concatenate([[0], np.cumsum(self.typed["node_types"])])
+        return [(int(lo), int(hi)) for lo, hi in zip(off[:-1], off[1:])]
+
+    def _eval_typed(self, op: _Op, x: jax.Array, params,
+                    gctx: GraphContext) -> jax.Array:
+        ty = self.typed
+        ranges = self._kind_ranges()
+        if op.kind == "typed_input":
+            # roc.embed inside the op's own dense scope: assembling
+            # h^0, and (JAX's transpose wrapper) slicing its cotangent
+            # back into the tables' gradients
+            with jax.named_scope(EMBED_SCOPE):
+                blocks, at = [], 0
+                for k, n in enumerate(ty["node_types"]):
+                    if k in ty["embed_types"]:
+                        blocks.append(
+                            params[f"{op.param}_{k}"].astype(x.dtype))
+                    else:
+                        blocks.append(x[at:at + n])
+                        at += n
+                return jnp.concatenate(blocks, axis=0)
+        n_types = ty["node_types"]
+        if op.kind == "root_linear":
+            kinds = range(len(n_types))
+            return dense.segment_linear(
+                x, ranges, n_types, [(k, k) for k in kinds],
+                [params[f"{op.param}_{k}"] for k in kinds],
+                [params[f"{op.param}_{k}_b"] for k in kinds])
+        if op.kind == "rel_aggregate":
+            return gctx.rel_aggregate(x, op.attrs["order"])
+        # rel_linear: a block of rows a relation, on either side of the
+        # mean (dense.segment_linear: segments in, segments out)
+        rels = ty["relations"]
+        ws = [params[f"{op.param}_{s}_{d}"] for s, d in rels]
+        if op.attrs["order"] == TRANSFORM_FIRST:
+            # vertices in (a kind's rows, once a relation out of it),
+            # the src stack out
+            return dense.segment_linear(
+                x, [ranges[s] for s, _ in rels],
+                [n_types[s] for s, _ in rels],
+                [(r, r) for r in range(len(rels))], ws)
+        # the dst stack in, vertices out: a kind's rows sum the
+        # products of the relations into it
+        at = np.concatenate([[0], np.cumsum([n_types[d] for _, d in rels])])
+        return dense.segment_linear(
+            x, [(int(at[r]), int(at[r + 1])) for r in range(len(rels))],
+            n_types, [(r, d) for r, (_, d) in enumerate(rels)], ws)
 
     # ---- interpreter ----
 
@@ -1171,6 +1491,10 @@ class Model:
 
             names = {ops[k].param for k in range(i, hi)
                      if ops[k].kind in ("linear", "scale_add")}
+            names |= {n for k in range(i, hi)
+                      if ops[k].kind in ("typed_input", "rel_linear",
+                                         "root_linear")
+                      for n, _ in self._typed_param_shapes(ops[k])}
 
             def run(p, *xs, lo=i, hi=hi, ins=ins, outs=outs):
                 local = dict(zip(ins, xs))
@@ -1242,6 +1566,9 @@ class Model:
                     x, params[f"{op.param}_src"],
                     params[f"{op.param}_dst"],
                     neg_slope=op.attrs["neg_slope"])
+            if op.kind in ("typed_input", "rel_linear", "root_linear",
+                           "rel_aggregate"):
+                return self._eval_typed(op, x, params, gctx)
             if op.kind == "activation":
                 return dense.activation(x, op.attrs["mode"])
             if op.kind == "add":
@@ -1275,5 +1602,6 @@ class Model:
         logits = self.apply(params, feats, gctx, key=key, train=train,
                             remat=remat)
         with jax.named_scope(LOSS_SCOPE):
-            loss = masked_softmax_cross_entropy(logits, labels, mask)
+            loss = masked_softmax_cross_entropy(
+                *self.labelled(logits, labels, mask))
         return gctx.psum(loss), logits

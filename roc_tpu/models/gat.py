@@ -16,7 +16,10 @@ expressed with the builder ops::
 With ``skip=True, activation="relu"`` and an ``input_dropout`` of its
 own this is the full-batch GAT of the OGB ogbn-arxiv leaderboard (DGL
 ``examples/pytorch/ogb/ogbn-arxiv``: every ``GATConv`` carries a
-bias-free ``res_fc``), less its BatchNorm, which the op set lacks.
+bias-free ``res_fc``), less its BatchNorm, which the op set lacks,
+and less the bias of each ``GATConv``: ``ops/dense.py linear`` takes a
+bias since the typed models needed one (``models/rgcn.py``), the
+builder's ``linear`` op still passes none.
 
 The edge softmax runs on one of two layouts (ops/attention.py has both
 mechanisms): the degree-bucketed ELL tables (``aggr_impl='ell'``, every

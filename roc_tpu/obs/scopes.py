@@ -29,7 +29,7 @@ map (``ObservedJit.instruction_scopes``) that joins the two.
 
 | class | what runs under it | name |
 | --- | --- | --- |
-| ``agg`` | a model op that aggregates over edges (``scatter_gather``, ``fused_aggregate``, ``gat``) | ``roc.agg.op<i>`` |
+| ``agg`` | a model op that aggregates over edges (``scatter_gather``, ``fused_aggregate``, ``gat``, a typed graph's ``rel_aggregate``) | ``roc.agg.op<i>`` |
 | ``halo`` | the feature halo exchange inside an aggregation (all-gather, ring hops) | ``roc.halo`` |
 | ``dense`` | every other model op | ``roc.dense.op<i>.<kind>`` |
 | ``loss`` | masked cross-entropy and the metric reductions | ``roc.loss`` |
@@ -37,6 +37,19 @@ map (``ObservedJit.instruction_scopes``) that joins the two.
 | ``allreduce`` | the gradient / loss / metric ``psum`` across partitions | ``roc.allreduce`` |
 
 ``<i>`` is the op's index in ``Model._ops``, two digits.
+
+A typed model (``models/rgcn.py``) adds two names, each nested inside
+a scope of the table above, so the classes stay six and whatever
+reads classes sees ``dense`` and ``opt`` as before: ``roc.embed``
+inside ``roc.dense.op<i>.typed_input`` (assembling ``h^0`` from the
+feature rows and the trainable tables; its backward slices the
+cotangent into the tables' gradients), and ``roc.opt.embed`` inside
+``roc.opt`` (the embedding tables' Adam update and their casts to the
+compute dtype: the optimizer's stream over the tables, apart from the
+weights').  Its per-relation and per-kind products are
+``roc.dense.op<i>.rel_linear`` / ``.root_linear``.  The relation
+aggregation's per-slot ``1 / deg`` weights are applied in register
+inside the chunk scan: no separate work, no scope of their own.
 
 An attention op (``gat``) splits its ``roc.agg.op<i>`` into three
 phases, each a scope nested inside it (``ops/attention.py``); the
@@ -61,7 +74,8 @@ AGG, HALO, DENSE, LOSS, OPT, ALLREDUCE = (
 CLASSES = (AGG, HALO, DENSE, LOSS, OPT, ALLREDUCE)
 # the model op kinds whose scope class is ``agg``; every other kind is
 # ``dense``
-AGG_KINDS = ("scatter_gather", "fused_aggregate", "gat")
+AGG_KINDS = ("scatter_gather", "fused_aggregate", "gat",
+             "rel_aggregate")
 
 ATTN_PHASES = ("scores", "stats", "gather")
 
@@ -69,6 +83,11 @@ HALO_SCOPE = PREFIX + HALO
 LOSS_SCOPE = PREFIX + LOSS
 OPT_SCOPE = PREFIX + OPT
 ALLREDUCE_SCOPE = PREFIX + ALLREDUCE
+# a typed model's two nested names (module docstring)
+EMBED_SCOPE = PREFIX + "embed"
+OPT_EMBED_SCOPE = OPT_SCOPE + ".embed"
+# parameters whose optimizer work runs under OPT_EMBED_SCOPE
+EMBED_PARAM_PREFIX = "embed_"
 # entered inside an attention op's own ``roc.agg.op<i>``
 ATTN_SCORES_SCOPE, ATTN_STATS_SCOPE, ATTN_GATHER_SCOPE = (
     f"{PREFIX}attn.{phase}" for phase in ATTN_PHASES)

@@ -128,7 +128,8 @@ def scan_window_rows(win_rows: int, carry_rows: int) -> int:
 
 
 def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
-                     win_rows: int) -> jax.Array:
+                     win_rows: int, slot_major: bool = False
+                     ) -> jax.Array:
     """The chunk scan of the width-8 sub-row layouts — one body for
     :func:`aggregate_ell_sect` (per section) and
     :func:`aggregate_flat_sum` (its single global section).  Each step
@@ -159,16 +160,36 @@ def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
     model's width (PERF §6, PR 32).
 
     xs: ``(idx [n, seg, 8], dst [n, seg])`` plus optional weights
-    shaped like ``idx``."""
+    shaped like ``idx``, in the table's dtype or wider.
+
+    ``slot_major``: ``idx`` (and the weights) arrive ``[n, 8 * seg]``,
+    a chunk's ``[8, seg]`` transpose flattened, and a step reshapes
+    and transposes its own chunk back before the gather — the same
+    step on the same values.  What it changes is where the tables
+    live between steps: the TPU tiles an array's two minor axes ``(8,
+    128)``, so the scan's operand ``[n, seg, 8]`` is held with its
+    8-wide axis padded to 128 lanes, sixteen times its bytes (3.5 GiB
+    for a 58M-slot int32 table, and as much again for its weights; a
+    ``[n, 8, seg]`` operand is no better: XLA's layout assignment puts
+    the 8-wide axis minor again), while ``[n, 8 * seg]`` has no
+    narrow axis to pad and only the 256 KiB chunk in flight is."""
     carry_rows, F = out.shape
     win = scan_window_rows(win_rows, carry_rows)
 
     def body(o, ch):
         idx_ch, dst_ch = ch[0], ch[1]
+        if slot_major:
+            idx_ch = idx_ch.reshape(8, -1).T
         g = table[idx_ch]
         if len(ch) > 2:
-            g = g * ch[2][:, :, None]
+            g = g * (ch[2].reshape(8, -1).T if slot_major
+                     else ch[2])[:, :, None]
         part = g.sum(axis=1)
+        if part.dtype != o.dtype:
+            # fp32 weights over a narrower table (the relation means,
+            # ``weights_fp32``): the products and the width-8 sum are
+            # fp32, the partial is rounded once on its way to the carry
+            part = part.astype(o.dtype)
         # clamped so the slice never clips (an all-padding chunk, or a
         # run that ends at the carry's last rows)
         r0 = jnp.minimum(dst_ch[0], carry_rows - win)
@@ -221,7 +242,9 @@ def aggregate_ell_sect(feats: jax.Array, sect_idx, sect_sub_dst,
 
 def aggregate_flat_sum(feats: jax.Array, flat_idx: jax.Array,
                        flat_dst: jax.Array, num_rows: int,
-                       flat_w=None, win_rows: int = 0) -> jax.Array:
+                       flat_w=None, win_rows: int = 0,
+                       weights_fp32: bool = False,
+                       slot_major: bool = False) -> jax.Array:
     """Uniform width-8 sub-row SUM — the sum-path twin of the
     attention layout's ``gat_aggregate_flat8`` (ops/attention.py) and
     the compile-wall fix for the per-bucket ELL unroll: every row's
@@ -253,13 +276,24 @@ def aggregate_flat_sum(feats: jax.Array, flat_idx: jax.Array,
     win_rows: static height of a chunk's destination window
       (``SectionedEll.win_rows[0]``; 0 = the whole carry) — the scan
       is :func:`_scan_window_sum`, shared with the sectioned layout.
+    weights_fp32: keep ``flat_w`` in fp32 instead of rounding it to the
+      table's dtype — the relation means' ``1 / deg`` (bfloat16 would
+      put up to 0.4% of gain error on a whole row; the baked
+      ``D^-1/2 A D^-1/2`` entries of the fused path stay as they
+      were).  ``feats`` and ``num_rows`` may be different index
+      spaces: the table's ids index ``feats``, ``flat_dst`` the
+      output.
+    slot_major: ``flat_idx`` / ``flat_w`` are ``[n_chunks, 8 *
+      seg_rows]`` (:func:`_scan_window_sum`): the relation tables'
+      form, unpadded at rest.
     """
     F = feats.shape[1]
     out = jnp.zeros((num_rows + 1, F), dtype=feats.dtype)
     xs = (flat_idx, flat_dst)
     if flat_w is not None:
-        xs += (flat_w.astype(feats.dtype),)
-    return _scan_window_sum(out, feats, xs, win_rows)[:num_rows]
+        xs += (flat_w if weights_fp32 else flat_w.astype(feats.dtype),)
+    return _scan_window_sum(out, feats, xs, win_rows,
+                            slot_major)[:num_rows]
 
 
 def aggregate_flat_max(feats: jax.Array, flat_idx: jax.Array,

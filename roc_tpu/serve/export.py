@@ -114,6 +114,16 @@ def _full_logits_host(model, dataset, config, params) -> np.ndarray:
                       dtype=np.float32)
 
 
+# a typed model's serving export is out of scope (ROADMAP, Reach): the
+# predictor's gather paths, propagation cache and shard plan know one
+# index space, one weight a layer and no trainable input tables
+TYPED_REFUSAL = (
+    "a typed graph's model (--model rgcn: embed_<k> tables, a weight "
+    "a relation) has no serving export: serve/export.py and the "
+    "predictor know one homogeneous graph")
+_TYPED_PARAMS = ("embed_", "rel0_", "root0_")
+
+
 def build_predictor(model, dataset, config, params=None,
                     backend: str = "auto",
                     buckets: Sequence[int] = SERVE_BUCKETS,
@@ -130,6 +140,8 @@ def build_predictor(model, dataset, config, params=None,
 
     from ..train.trainer import (resolve_config, resolve_symmetric)
     import dataclasses
+    if model.uses_relations():
+        raise NotImplementedError(TYPED_REFUSAL)
     model, config, _ = resolve_config(model, dataset, config)
     config = dataclasses.replace(
         config, symmetric=resolve_symmetric(dataset, config.symmetric))
@@ -736,6 +748,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.checkpoint:
         from ..utils.checkpoint import restore_params_only
         params, fp, epoch = restore_params_only(args.checkpoint)
+        if any(k.startswith(_TYPED_PARAMS) for k in params):
+            print(f"error: {args.checkpoint}: {TYPED_REFUSAL}",
+                  file=sys.stderr)
+            return 2
         strict = (fp or {}).get("strict") or {}
         import jax.numpy as jnp
         if strict.get("dtype") and \

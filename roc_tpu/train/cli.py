@@ -50,8 +50,21 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     # TPU-era flags
     ap.add_argument("--model",
                     choices=["gcn", "sage", "gin", "gat", "sgc",
-                             "appnp", "gcn2"],
+                             "appnp", "gcn2", "rgcn"],
                     default="gcn")
+    ap.add_argument("--node-types", type=str, default=None,
+                    help="for --model rgcn: the typed graph's vertex "
+                         "kinds as comma-separated counts in id order "
+                         "(kinds are contiguous id ranges; they must "
+                         "sum to V).  Kind 0 carries the file's "
+                         "features and the labels.  The relations are "
+                         "derived: every ordered pair of kinds with a "
+                         "non-self edge in the file is one relation")
+    ap.add_argument("--embed-types", type=str, default=None,
+                    help="for --model rgcn: comma-separated kinds "
+                         "(never 0) whose input is a trainable "
+                         "embedding table of the input width instead "
+                         "of the file's feature rows")
     ap.add_argument("--heads", type=int, default=1,
                     help="attention heads for --model gat (hidden "
                          "dims must divide by it; output layer stays "
@@ -442,6 +455,54 @@ def main(argv: Optional[List[str]] = None,
                   f"initial residual adds H_0 into every layer), got "
                   f"{layers[1:-1]}", file=sys.stderr)
             return 2
+    node_types = embed_types = ()
+    if (args.node_types is not None
+            or args.embed_types is not None) and args.model != "rgcn":
+        print("error: --node-types/--embed-types apply to --model rgcn "
+              "only (the other families read one homogeneous graph)",
+              file=sys.stderr)
+        return 2
+    if args.model == "rgcn":
+        from ..core.relations import parse_kinds
+        if args.node_types is None:
+            print("error: --model rgcn needs --node-types (the typed "
+                  "graph's kind counts, in id order)", file=sys.stderr)
+            return 2
+        try:
+            node_types = parse_kinds(args.node_types, "--node-types")
+            embed_types = (parse_kinds(args.embed_types, "--embed-types")
+                           if args.embed_types else ())
+        except ValueError as e:
+            print(f"error: {e}", file=sys.stderr)
+            return 2
+        bad = [k for k in embed_types if not 0 < k < len(node_types)]
+        if bad or min(node_types) < 1:
+            print(f"error: --embed-types {bad} name no kind of "
+                  f"{len(node_types)} (kind 0 carries the file's "
+                  f"features and the labels), or an empty kind in "
+                  f"--node-types", file=sys.stderr)
+            return 2
+        # out of scope for a typed graph, each refused by name
+        if args.parts > 1:
+            print("error: --model rgcn runs on one chip: a typed graph "
+                  "has no vertex partitioner and no distributed step "
+                  "yet (--parts 1)", file=sys.stderr)
+            return 2
+        if args.halo == "ring":
+            print("error: --halo ring is a --parts > 1 exchange; a "
+                  "typed graph (--model rgcn) runs on one chip",
+                  file=sys.stderr)
+            return 2
+        if args.reorder != "none":
+            print("error: --reorder would scatter a typed graph's "
+                  "kinds, which are contiguous id ranges",
+                  file=sys.stderr)
+            return 2
+        if args.impl not in ("auto", "flat_sum", "segment"):
+            print(f"error: the relation aggregation has no "
+                  f"{args.impl!r} layout; --impl takes auto, flat_sum "
+                  f"or segment for --model rgcn", file=sys.stderr)
+            return 2
     if args.model == "gat":
         if args.heads < 1:
             print("error: --heads must be >= 1", file=sys.stderr)
@@ -463,6 +524,13 @@ def main(argv: Optional[List[str]] = None,
     else:
         ds = synthetic_dataset(512, 8, in_dim=layers[0],
                                num_classes=layers[-1], seed=args.seed)
+    if args.model == "rgcn":
+        from ..core.relations import derive_typed
+        try:
+            ds.typed = derive_typed(ds.graph, node_types)
+        except ValueError as e:
+            print(f"error: --node-types: {e}", file=sys.stderr)
+            return 2
     perm = None
     if args.reorder != "none":
         from ..core.reorder import ORDERINGS, apply_vertex_order
@@ -497,6 +565,9 @@ def main(argv: Optional[List[str]] = None,
     if args.model == "gcn2":
         kwargs["lam"] = args.lam
         kwargs["star"] = args.star
+    if args.model == "rgcn":
+        kwargs = {"node_types": node_types, "embed_types": embed_types,
+                  "relations": ds.typed.relations}
     model = build[args.model](layers, dropout_rate=args.dropout,
                               **kwargs)
     dt, cdt = resolve_dtypes(args.dtype)

@@ -26,6 +26,8 @@ from typing import Any, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs.scopes import EMBED_PARAM_PREFIX, OPT_EMBED_SCOPE
+
 
 class AdamState(NamedTuple):
     step: jax.Array      # int32 scalar
@@ -81,8 +83,21 @@ def adam_update(params: Any, grads: Any, state: AdamState, lr: jax.Array,
     flat_g = treedef.flatten_up_to(grads)
     flat_m = treedef.flatten_up_to(state.m)
     flat_v = treedef.flatten_up_to(state.v)
-    out = [upd(w, g, m, v) for w, g, m, v in
-           zip(flat_p, flat_g, flat_m, flat_v)]
+    embeds = set()
+    if isinstance(params, dict):
+        # a typed model's embedding tables update under roc.opt.embed
+        # (nested in the caller's roc.opt): metadata only
+        embeds = {i for i, k in enumerate(sorted(params))
+                  if str(k).startswith(EMBED_PARAM_PREFIX)}
+
+    def scoped(i, *leaf):
+        if i not in embeds:
+            return upd(*leaf)
+        with jax.named_scope(OPT_EMBED_SCOPE):
+            return upd(*leaf)
+
+    out = [scoped(i, w, g, m, v) for i, (w, g, m, v) in
+           enumerate(zip(flat_p, flat_g, flat_m, flat_v))]
     new_p = treedef.unflatten([o[0] for o in out])
     new_m = treedef.unflatten([o[1] for o in out])
     new_v = treedef.unflatten([o[2] for o in out])

@@ -499,6 +499,19 @@ def _plan_kwargs(model: Model, dataset: Dataset, config: TrainConfig,
     from ..core.ell import scan_chunk_rows
     from ..core.memory import charged_table_bytes
     g = dataset.graph
+    rel_bytes = 0
+    if model.uses_relations() and dataset.typed is not None:
+        # the relation tables of the passes the resolved orders run,
+        # read off the degrees before any table exists: an index and
+        # an fp32 weight a slot, an output row a sub-row ('segment':
+        # the forward edge lists)
+        from ..core.relations import ORDER_PASSES
+        for order in dict.fromkeys(model.rel_orders()):
+            fwd, bwd = ORDER_PASSES[order]
+            rel_bytes += (12 * dataset.typed.num_edges
+                          if config.aggr_impl == "segment" else
+                          68 * (dataset.typed.pass_sub_rows(fwd)
+                                + dataset.typed.pass_sub_rows(bwd)))
     return dict(
         num_nodes=g.num_nodes, num_edges=g.num_edges, ops=model._ops,
         num_parts=num_parts,
@@ -508,7 +521,7 @@ def _plan_kwargs(model: Model, dataset: Dataset, config: TrainConfig,
                                         -(-g.num_edges // num_parts))),
         dtype_bytes=jnp.dtype(compute_dtype_of(config)).itemsize,
         param_bytes=jnp.dtype(config.dtype).itemsize,
-        extra_table_bytes=charged_table_bytes(
+        extra_table_bytes=rel_bytes + charged_table_bytes(
             config.aggr_impl, model.uses_attention(),
             model.uses_max_aggregation(), config.bdense_a_budget))
 
@@ -664,6 +677,62 @@ def resolve_auto_impl_early(model: Model, config: TrainConfig, graph,
     return dc_replace(config, aggr_impl=impl), census
 
 
+def resolve_relations(model: Model, dataset: Dataset,
+                      config: TrainConfig, num_parts: int = 1):
+    """The typed-graph half of the resolve pass (no-op for every
+    other family): the relations read off the graph
+    (``core/relations.py derive_typed``, kept on ``dataset.typed``)
+    must be the ones the model was built for; ``aggr_impl='auto'``
+    resolves to 'flat_sum' — the one table layout the relation
+    aggregation has (its two index spaces are one global section
+    each; 'segment' stays the edge-list reference) — and each layer's
+    product goes to the side of its mean that gathers the narrower
+    rows at the width that layout runs them
+    (``resolve_rel_order``), echoed like every other resolution."""
+    if not model.uses_relations():
+        return model, config
+    if num_parts > 1:
+        raise NotImplementedError(
+            "a typed graph (--model rgcn) runs on one chip: the vertex "
+            "partitioner (core/partition.py) and the distributed step "
+            "(parallel/distributed.py) know one index space and no "
+            "relation tables; use --parts 1")
+    from ..core.ell import agg_lane_width
+    from ..core.relations import derive_typed, resolve_rel_order
+    if dataset.typed is None:
+        dataset.typed = derive_typed(dataset.graph,
+                                     model.typed["node_types"])
+    if (dataset.typed.node_types != model.typed["node_types"]
+            or dataset.typed.relations != model.typed["relations"]):
+        raise ValueError(
+            f"the model was built for kinds "
+            f"{list(model.typed['node_types'])} and relations "
+            f"{list(model.typed['relations'])}; the graph holds "
+            f"{list(dataset.typed.node_types)} and "
+            f"{list(dataset.typed.relations)}")
+    impl = config.aggr_impl
+    if impl == "auto":
+        impl = "flat_sum"
+        emit("resolve", "aggr_impl='auto' -> 'flat_sum' (typed graph: "
+             "one uniform scan over the stacked relation tables)",
+             console=config.verbose, resolved="flat_sum",
+             num_edges=int(dataset.typed.num_edges))
+    elif impl not in ("segment", "flat_sum"):
+        raise NotImplementedError(
+            f"the relation aggregation has no {impl!r} layout; --impl "
+            f"takes auto, flat_sum or segment for --model rgcn")
+    config = dc_replace(config, aggr_impl=impl)
+    resolved = model.with_rel_orders(
+        lambda i, o: resolve_rel_order(
+            i, o, lambda f: agg_lane_width(f, impl, "gather")))
+    if resolved is not model:
+        emit("resolve", "rel_order: " + ", ".join(
+            f"layer {l} {o}" for l, o in enumerate(
+                resolved.rel_orders())),
+            console=config.verbose, rel_order=list(resolved.rel_orders()))
+    return resolved, config
+
+
 def resolve_config(model: Model, dataset: Dataset, config: TrainConfig,
                    num_parts: int = 1, multiprocess: bool = False):
     """THE config resolve pass — fuse rewrite, ``aggr_impl='auto'``
@@ -686,6 +755,7 @@ def resolve_config(model: Model, dataset: Dataset, config: TrainConfig,
 
     Returns ``(model, config, bd_census)``."""
     model = resolve_fuse(model, config)
+    model, config = resolve_relations(model, dataset, config, num_parts)
     out_rows = (-(-dataset.graph.num_nodes // num_parts)
                 if num_parts > 1 else None)
     config, bd_census = resolve_auto_impl_early(
@@ -708,7 +778,8 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
                        verbose: bool = False,
                        fuse: bool = False,
                        bd_census=None,
-                       head_chunk: int = 0) -> GraphContext:
+                       head_chunk: int = 0,
+                       rel_orders=()) -> GraphContext:
     """Single-device GraphContext: edges padded to the chunk multiple,
     dummy source id == num_nodes (the appended zero row).
     ``sect_sub_w``/``sect_u16`` tune the sectioned layout and
@@ -721,8 +792,15 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
     scales) for models rewritten by ``Model.fuse_norm_aggregate``;
     ``bd_census`` reuses a probe census from an earlier
     :func:`resolve_auto_impl_probed` call (the trainers resolve
-    'auto' before the memory autopilot and pass it through)."""
+    'auto' before the memory autopilot and pass it through).
+
+    ``rel_orders`` (a typed model's ``Model.rel_orders()``): build the
+    relation tables of ``dataset.typed`` for the passes those orders
+    run, and none of the homogeneous tables."""
     g = dataset.graph
+    if rel_orders:
+        return _relation_context(dataset, aggr_impl, rel_orders,
+                                 head_chunk)
     if aggr_impl == "auto":
         aggr_impl, bd_census = resolve_auto_impl_probed(
             g, bdense_min_fill=bdense_min_fill,
@@ -879,6 +957,82 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
     )
 
 
+def _relation_context(dataset: Dataset, aggr_impl: str, rel_orders,
+                      head_chunk: int) -> GraphContext:
+    """The GraphContext of a typed graph: per relation pass
+    (``core/relations.py``) the flat width-8 table, its output rows
+    and each slot's ``1 / deg_r(v)`` — the builder of the homogeneous
+    'flat_sum' tables (``core/ell.py flat_sum_from_graph``) over the
+    pass's own CSR — or, under 'segment', the forward passes' edge
+    lists.  The union's symmetry is never read: every backward pass
+    has its transposed table."""
+    from ..core.ell import flat_sum_from_graph
+    from ..core.relations import ORDER_PASSES
+    typed, g = dataset.typed, dataset.graph
+    idx, dst, w, meta = [], [], [], []
+    for order in dict.fromkeys(rel_orders):
+        fwd, bwd = ORDER_PASSES[order]
+        for name in ((fwd,) if aggr_impl == "segment" else (fwd, bwd)):
+            row_ptr, col, n_into, n_out = typed.pass_csr(name)
+            if aggr_impl == "segment":
+                into = np.repeat(np.arange(n_into, dtype=np.int32),
+                                 np.diff(row_ptr))
+                t_idx, t_dst, win = col, into, 0
+                t_w = typed.slot_weights(name, col[:, None], into)[:, 0]
+            else:
+                sect = flat_sum_from_graph(row_ptr, col, n_into,
+                                           src_rows=n_out)
+                t_idx, t_dst = sect.idx[0], sect.sub_dst[0]
+                win = sect.win_rows[0]
+                # slot-major at rest: [n_chunks, 8 * seg_rows]
+                n = t_idx.shape[0]
+                t_w = typed.slot_weights(name, t_idx, t_dst).transpose(
+                    0, 2, 1).reshape(n, -1)
+                t_idx = t_idx.transpose(0, 2, 1).reshape(n, -1)
+            idx.append(jnp.asarray(t_idx))
+            dst.append(jnp.asarray(t_dst))
+            w.append(jnp.asarray(t_w))
+            meta.append((name, n_into, n_out, win))
+    return GraphContext(
+        edge_src=jnp.zeros(1, jnp.int32), edge_dst=jnp.zeros(1, jnp.int32),
+        in_degree=jnp.asarray(g.in_degree), num_rows=g.num_nodes,
+        gathered_rows=g.num_nodes, aggr_impl=aggr_impl, symmetric=True,
+        head_chunk=head_chunk, rel_idx=tuple(idx), rel_dst=tuple(dst),
+        rel_w=tuple(w), rel_meta=tuple(meta))
+
+
+def model_features(model: Model, dataset: Dataset) -> np.ndarray:
+    """The feature rows the model's input holds: all of them, or for a
+    typed model the rows of the kinds that have any (kind order), the
+    trainable kinds' rows left on the host."""
+    if not model.typed:
+        return dataset.features
+    off = np.concatenate([[0], np.cumsum(model.typed["node_types"])])
+    keep = [np.arange(off[k], off[k + 1])
+            for k in range(len(off) - 1)
+            if k not in model.typed["embed_types"]]
+    return np.asarray(dataset.features)[np.concatenate(keep)]
+
+
+def cast_params(params, dtype):
+    """The step's compute-dtype copy of the parameters, under
+    ``roc.opt``; a typed model's embedding tables under
+    ``roc.opt.embed`` inside it, so their stream can be told from the
+    weights' (obs/scopes.py)."""
+    from ..obs.scopes import EMBED_PARAM_PREFIX, OPT_EMBED_SCOPE
+    with jax.named_scope(OPT_SCOPE):
+        if not any(k.startswith(EMBED_PARAM_PREFIX) for k in params):
+            return cast_floats(params, dtype)
+        out = {}
+        for k, v in params.items():
+            if k.startswith(EMBED_PARAM_PREFIX):
+                with jax.named_scope(OPT_EMBED_SCOPE):
+                    out[k] = cast_floats(v, dtype)
+            else:
+                out[k] = cast_floats(v, dtype)
+        return out
+
+
 class Trainer:
     """Owns params + optimizer state and the jitted step functions."""
 
@@ -989,7 +1143,7 @@ class Trainer:
                                              donate_argnums=(0, 1, 2),
                                              verbose=config.verbose)
         else:
-            self.feats = jnp.asarray(dataset.features,
+            self.feats = jnp.asarray(model_features(model, dataset),
                                      dtype=self.compute)
         if self._head is not None and not any(
                 op.kind in ("scatter_gather", "gat", "fused_aggregate")
@@ -1021,7 +1175,8 @@ class Trainer:
                 verbose=config.verbose,
                 fuse=model.num_fused_aggregates() > 0,
                 bd_census=bd_census,
-                head_chunk=self._head_chunk)
+                head_chunk=self._head_chunk,
+                rel_orders=model.rel_orders())
             if config.aggr_impl == "auto":
                 # attention/MAX models reach here with 'auto' already
                 # rewritten by resolve_attention_impl; any other
@@ -1059,6 +1214,8 @@ class Trainer:
                              model._ops,
                              edges=int(dataset.graph.num_edges)),
                          **self.gctx.attention_plan(model._ops),
+                         **self.gctx.relation_plan(model._ops,
+                                                   dataset.typed),
                          "memory_plan": self._plan},
                      console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
@@ -1077,8 +1234,7 @@ class Trainer:
         def objective(p):
             # mixed precision: compute in self.compute; the astype vjp
             # returns fp32 cotangents, so grads/Adam stay in dtype
-            with jax.named_scope(OPT_SCOPE):
-                p = cast_floats(p, self.compute)
+            p = cast_params(p, self.compute)
             loss, _ = self.model.loss_fn(p, feats, labels, mask,
                                          gctx, key=key, train=True,
                                          remat=self.config.remat)
@@ -1090,12 +1246,12 @@ class Trainer:
         return params, opt_state, loss
 
     def _eval_step_impl(self, params, feats, labels, mask, gctx):
-        with jax.named_scope(OPT_SCOPE):
-            params = cast_floats(params, self.compute)
+        params = cast_params(params, self.compute)
         logits = self.model.apply(params, feats, gctx, key=None,
                                   train=False)
         with jax.named_scope(LOSS_SCOPE):
-            return perf_metrics(logits, labels, mask), logits
+            return perf_metrics(
+                *self.model.labelled(logits, labels, mask)), logits
 
     # ---- host-feature streaming path (config.features == "host") ----
 

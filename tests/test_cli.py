@@ -204,3 +204,54 @@ def test_cli_gcn2_model_trains_and_validates():
     assert _run(["--model", "gcn2", "-layers", "12-16-24-4",
                  "-e", "1"]) == 2
     assert _run(["--model", "gcn2", "-layers", "12-4", "-e", "1"]) == 2
+
+
+# ---- a typed graph (--model rgcn): kinds on the command line ----
+
+@pytest.mark.parametrize("argv,msg", [
+    (["--model", "rgcn", "-layers", "8-8-3"], "needs --node-types"),
+    (["--model", "gcn", "--node-types", "300,212", "-layers", "8-8-3"],
+     "apply to --model rgcn only"),
+    (["--model", "sage", "--embed-types", "1", "-layers", "8-8-3"],
+     "apply to --model rgcn only"),
+    (["--model", "rgcn", "--node-types", "300,x", "-layers", "8-8-3"],
+     "comma-separated integers"),
+    (["--model", "rgcn", "--node-types", "300,212", "--embed-types", "0",
+      "-layers", "8-8-3"], "kind 0 carries"),
+    (["--model", "rgcn", "--node-types", "300,212", "--embed-types", "2",
+      "-layers", "8-8-3"], "name no kind"),
+    (["--model", "rgcn", "--node-types", "300,212", "--parts", "2",
+      "-layers", "8-8-3"], "runs on one chip"),
+    (["--model", "rgcn", "--node-types", "300,212", "--halo", "ring",
+      "-layers", "8-8-3"], "--halo ring"),
+    (["--model", "rgcn", "--node-types", "300,212", "--reorder", "bfs",
+      "-layers", "8-8-3"], "contiguous id ranges"),
+    (["--model", "rgcn", "--node-types", "300,212", "--impl", "ell",
+      "-layers", "8-8-3"], "no 'ell' layout"),
+    # the kinds must count the graph's vertices (the synthetic smoke
+    # graph holds 512)
+    (["--model", "rgcn", "--node-types", "300,200", "-layers", "8-8-3"],
+     "the graph holds 512"),
+])
+def test_typed_flag_validation_fails_fast(argv, msg, capsys):
+    assert _run(argv) == 2
+    assert msg in capsys.readouterr().err
+
+
+def test_cli_rgcn_on_the_synthetic_graph(capsys):
+    """Two kinds over the homogeneous smoke graph: every ordered pair
+    that occurs is a relation, kind 1 trains an embedding table, and
+    ``auto`` takes the flat scan."""
+    seen = {}
+    rc = cli.main(["--cpu", "--no-compile-cache", "--model", "rgcn",
+                   "-layers", "8-8-3", "--node-types", "300,212",
+                   "--embed-types", "1", "-decay", "0", "-e", "5",
+                   "--eval-every", "5"],
+                  inspect=lambda tr: seen.update(tr=tr))
+    assert rc == 0
+    tr = seen["tr"]
+    assert tr.model.typed["relations"] == ((0, 0), (0, 1), (1, 0), (1, 1))
+    assert tr.config.aggr_impl == "flat_sum"
+    assert tr.params["embed_1"].shape == (212, 8)
+    assert tr.feats.shape == (300, 8)
+    assert "[INFER]" in capsys.readouterr().out

@@ -195,3 +195,71 @@ def test_trainer_writes_the_plan_into_its_manifest(remat, tmp_path):
         == man["modeled_step_bytes"]
     assert all(tr.model._ops[i].kind == kind
                for i, kind, _, _ in mem["saved"])
+
+
+# ------------------------------------------------------ a typed model
+
+MAG = (736_389, 1_134_649, 8_740, 59_965)
+MAG_RELATIONS = ((0, 0), (0, 1), (0, 3), (1, 0), (1, 2), (2, 1), (3, 0))
+MAG_V, MAG_E = sum(MAG), 44_161_757
+# the relation tables of both gather_first passes at ogbn-mag's size:
+# 7,243,006 + 6,180,819 width-8 sub-rows (my host build, PR 35), an
+# index and a weight a slot, an output row a sub-row
+MAG_TABLE_BYTES = 68 * (7_243_006 + 6_180_819)
+
+
+def rgcn_mag(order):
+    from roc_tpu.models.rgcn import build_rgcn
+    return build_rgcn([128, 64, 349], 0.5, node_types=MAG,
+                      embed_types=(1, 2, 3), relations=MAG_RELATIONS
+                      ).with_rel_orders(lambda i, o: order)
+
+
+@pytest.mark.parametrize("order", ["gather_first", "transform_first"])
+def test_typed_residual_rules_and_heights(order):
+    """``rel_linear`` and ``root_linear`` keep their input, the
+    relation sum and the input assembly nothing; a stacked tensor is
+    charged at its own height, the input at the feature rows it
+    holds."""
+    ops = rgcn_mag(order)._ops
+    stacked = 4_547_170 / MAG_V
+    for i, op in enumerate(ops):
+        got = M.op_residuals(i, op, 2)
+        if op.kind in ("rel_aggregate", "typed_input"):
+            assert got == []
+        if op.kind in ("rel_linear", "root_linear"):
+            assert got == [(("t", op.inputs[0]), op.attrs["in_dim"], 2)]
+    tall = [op for op in ops if op.attrs.get("row_scale", 1) > 1]
+    assert [op.kind for op in tall] == [
+        "rel_aggregate" if order == "gather_first" else "rel_linear"] * 2
+    assert all(op.attrs["row_scale"] == pytest.approx(stacked)
+               for op in tall)
+    assert ops[0].attrs["row_scale"] == pytest.approx(MAG[0] / MAG_V)
+    kept, _ = M.saved_for_backward(ops, 2)
+    by_op = {i: row for i, _, row in kept}
+    if order == "gather_first":
+        # the stacked means a relation's dW needs: 2.34 rows a vertex
+        lin = [i for i, op in enumerate(ops) if op.kind == "rel_linear"]
+        assert by_op[lin[0]] == pytest.approx(128 * 2 * stacked, abs=0.1)
+        assert by_op[lin[1]] == pytest.approx(64 * 2 * stacked, abs=0.1)
+
+
+def test_typed_plan_charges_the_embedding_tables_and_fits():
+    """154,366,772 parameters at 18 bytes are most of the plan; with
+    the relation tables beside them ``auto`` resolves the plain plan,
+    inside the chip."""
+    model = rgcn_mag("gather_first")
+    assert M.param_elems(model._ops) == 154_366_772
+    c = M.plan_components(MAG_V, MAG_E, model._ops, dtype_bytes=2,
+                          param_bytes=4, scan_rows=8192,
+                          extra_table_bytes=MAG_TABLE_BYTES)
+    assert c["params_opt"] == 154_366_772 * 18
+    assert c["features"] == MAG[0] * 128 * 2          # kind 0's rows
+    assert c["tables"] > MAG_TABLE_BYTES
+    # second only to the activations, 73x the deepest accepted cell's
+    assert sorted(c, key=c.get)[-2] == "params_opt"
+    p = plan(model, MAG_V, MAG_E, impl="flat_sum",
+             extra_table_bytes=MAG_TABLE_BYTES)
+    assert (p.halo, p.features, p.remat, p.fits) == (
+        "gather", "hbm", False, True), p.echo()
+    assert 0.55 * BUDGET < p.est_bytes < BUDGET
