@@ -70,7 +70,7 @@ def op_residuals(i: int, op: Any, itemsize: int
     | ``scatter_gather`` SUM / AVG, ``fused_aggregate`` | nothing: the backward is the same sum over the cotangent (a fused ReLU keeps the output) |
     | ``scatter_gather`` MAX / MIN | input and output (where the max sat) |
     | ``gat`` | input, output and the fp32 row sums of the hand-written backward |
-    | ``rel_linear``, ``root_linear`` | their input (for the dW of each relation / kind); a stacked input is charged at its own height (``row_scale``) |
+    | ``rel_linear``, ``root_linear`` | their input (for the dW of each relation / kind); a stacked input is charged at its own height (``row_scale``), the loss program's cut last layer (``Model.loss_cut``) at its cut ones |
     | ``rel_aggregate``, ``typed_input`` | nothing: the relation sum's backward is the pass over the transposed table, the assembly a concatenation |
     """
     def t(j):
@@ -182,7 +182,8 @@ def saved_for_backward(ops: Sequence[Any], itemsize: int,
         last = len(ops) - 1
         # the loss's fp32 softmax covers the rows that can carry a
         # label: all of them, or a typed model's kind 0
-        # (``label_scale`` on its input op)
+        # (``label_scale`` on its input op; gone from a cut op list,
+        # whose last op is those rows already: ``row_scale``)
         labelled = (getattr(ops[0], "attrs", None) or {}).get(
             "label_scale", 1)
         charge(last, [(("t", last), ops[last].dim, itemsize),

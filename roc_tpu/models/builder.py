@@ -168,7 +168,9 @@ class GraphContext:
     axis_name: str = PARTS_AXIS
     # Typed graph (core/relations.py): one entry per relation pass the
     # resolved orders run — ``rel_meta`` static ``(pass name, rows
-    # summed into, rows gathered out of, win_rows)``; under 'flat_sum'
+    # summed into, rows gathered out of, win_rows, rels)``, ``rels``
+    # None for the table of every relation or the relation indices a
+    # cut layer sums (Model.loss_cut); under 'flat_sum'
     # ``rel_idx [n_chunks, 8 * seg]`` (slot-major: unpadded at rest,
     # ops/aggregate.py _scan_window_sum) / ``rel_dst [n_chunks, seg]``
     # / ``rel_w`` fp32 like ``rel_idx`` (each slot's ``1 / deg_r(v)``),
@@ -480,12 +482,14 @@ class GraphContext:
             return -self._max_fwd(-x)
         raise ValueError(f"unknown aggregator: {aggr}")
 
-    def _rel_pass(self, x: jax.Array, name: str) -> jax.Array:
+    def _rel_pass(self, x: jax.Array, name: str, rels) -> jax.Array:
         """One relation pass (core/relations.py) over ``x``, whose
         rows are what the pass gathers out of: the weighted sum into
-        the pass's own row space."""
-        k = next(i for i, m in enumerate(self.rel_meta) if m[0] == name)
-        _, out_rows, _, win = self.rel_meta[k]
+        the pass's own row space, over the table of the relations
+        ``rels`` (None: all)."""
+        k = next(i for i, m in enumerate(self.rel_meta)
+                 if m[0] == name and m[4] == rels)
+        _, out_rows, _, win, _ = self.rel_meta[k]
         if self.aggr_impl == "segment":
             g = x[self.rel_idx[k]] * self.rel_w[k][:, None]
             return jax.ops.segment_sum(
@@ -497,7 +501,8 @@ class GraphContext:
             self.rel_dst[k], out_rows, flat_w=self.rel_w[k],
             win_rows=win, weights_fp32=True, slot_major=True)
 
-    def rel_aggregate(self, x: jax.Array, order: str) -> jax.Array:
+    def rel_aggregate(self, x: jax.Array, order: str,
+                      rels=None) -> jax.Array:
         """The relation aggregation ``sum_r mean_r(.)`` of a typed
         graph as ONE weighted sum over the union edge list, on the
         side of the product ``order`` names: ``transform_first`` sums
@@ -505,25 +510,28 @@ class GraphContext:
         into dst-stacked rows.  Its backward is the pass over the
         transposed table with the same per-edge weights — exact for
         any graph, scatter-free, and it keeps nothing; 'segment', the
-        edge-list reference, is autodiff through the forward."""
+        edge-list reference, is autodiff through the forward.
+        ``rels``: the relations a cut layer sums (``Model.loss_cut``),
+        forward and backward over the tables of that subset, whose
+        stacks hold those relations' blocks alone; None sums all."""
         fwd_pass, bwd_pass = ORDER_PASSES[order]
         if self.aggr_impl == "segment":
-            return self._rel_pass(x, fwd_pass)
+            return self._rel_pass(x, fwd_pass, rels)
 
         @jax.custom_vjp
         def agg(x):
-            return self._rel_pass(x, fwd_pass)
+            return self._rel_pass(x, fwd_pass, rels)
 
         def fwd(x):
             return agg(x), None
 
         def bwd(_, g):
-            return (self._rel_pass(g, bwd_pass),)
+            return (self._rel_pass(g, bwd_pass, rels),)
 
         agg.defvjp(fwd, bwd)
         return self._lane_padded(agg, x)
 
-    def relation_plan(self, ops=(), typed=None) -> dict:
+    def relation_plan(self, ops=(), typed=None, loss_ops=None) -> dict:
         """What the typed graph resolved to, for the run manifest's
         ``resolved`` beside :meth:`agg_window`: the kinds, one
         ``relations`` entry a relation, one ``rel_layers`` entry a
@@ -531,17 +539,28 @@ class GraphContext:
         sides, the width its scan runs at, stacked rows, table slots a
         forward / backward pass gathers and ``agg_slot_fill``, stored
         relation edges over the forward's slots), and the trainable
-        input rows.  Empty for an untyped model."""
+        input rows.  ``ops`` is the eval program's op list and every
+        key above is that program's; ``loss_ops`` the loss program's
+        (``Model.loss_cut``; ``ops`` again when nothing is cut), and
+        what that one runs of the layer is beside them:
+        ``train_relations`` of ``n_rel``, their ``train_edges``, the
+        slots its passes gather (``train_slots_fwd`` / ``_bwd``) and
+        the rows the layer hands on (``train_out_rows``).  Empty for
+        an untyped model."""
         if typed is None:
             return {}
-        slots = {m[0]: int(np.prod(t.shape)) for m, t
+        slots = {(m[0], m[4]): int(np.prod(t.shape)) for m, t
                  in zip(self.rel_meta, self.rel_idx)}
         layers = []
         for i, op in enumerate(ops):
             if op.kind != "rel_aggregate":
                 continue
             order = op.attrs["order"]
-            f, b = (slots.get(p) for p in ORDER_PASSES[order])
+            fwd, bwd = ORDER_PASSES[order]
+            f, b = slots.get((fwd, None)), slots.get((bwd, None))
+            cut = (loss_ops or ops)[i].attrs
+            rels = cut.get("rels")
+            kinds = cut.get("kinds", range(len(typed.node_types)))
             layers.append({
                 "op": i, "layer": op.attrs["layer"], "rel_order": order,
                 "in_dim": op.attrs["in_dim"],
@@ -553,7 +572,15 @@ class GraphContext:
                 "slots_fwd": f, "slots_bwd": b,
                 "agg_slot_fill": (round(typed.num_edges / f, 4)
                                   if f and self.aggr_impl != "segment"
-                                  else None)})
+                                  else None),
+                "train_relations": (len(rels) if rels
+                                    else op.attrs["n_rel"]),
+                "train_edges": (typed.restrict(rels) if rels
+                                else typed).num_edges,
+                "train_slots_fwd": slots.get((fwd, rels)),
+                "train_slots_bwd": slots.get((bwd, rels)),
+                "train_out_rows": sum(typed.node_types[k]
+                                      for k in kinds)})
         emb = [op for op in ops if op.kind == "typed_input"]
         rows = sum(op.attrs["embed_rows"] for op in emb)
         return {"node_types": list(typed.node_types),
@@ -839,9 +866,7 @@ class Model:
         the rewrite swaps the pair in place — op indices, consumers
         and parameter names are untouched.  Returns ``self`` when
         nothing changes (``resolve_config`` is idempotent)."""
-        ty = self.typed
-        ops = [_Op(o.kind, o.inputs, o.dim, o.param, dict(o.attrs))
-               for o in self._ops]
+        ops = self._copied_ops()
         changed = False
         for i, op in enumerate(ops[:-1]):
             nxt = ops[i + 1]
@@ -868,15 +893,83 @@ class Model:
                              attrs={**base, "row_scale": scale})
                 ops[i + 1] = _Op("rel_linear", (i,), a["out_dim"],
                                  lin.param, dict(base))
-        if not changed:
-            return self
+        return self._with_ops(ops) if changed else self
+
+    def _copied_ops(self) -> List[_Op]:
+        return [_Op(o.kind, o.inputs, o.dim, o.param, dict(o.attrs))
+                for o in self._ops]
+
+    def _with_ops(self, ops: List[_Op]) -> "Model":
+        """This model over the rewritten op list ``ops``."""
         new = Model(in_dim=ops[0].dim)
         new._ops = ops
         new._n_linear, new._n_gat, new._n_eps = (
             self._n_linear, self._n_gat, self._n_eps)
         new._loss_op = self._loss_op
-        new.typed = ty
+        new.typed = self.typed
         return new
+
+    def loss_cut(self) -> "Model":
+        """The model the *loss* program runs (:meth:`loss_fn` in train
+        mode; the eval / predict program runs ``self``, every row of
+        every kind): where the loss reads a proper subset of the rows
+        (:meth:`labelled`: a typed model's kind 0), the last relational
+        layer computes those rows alone.  Its ``rel_aggregate`` sums
+        only the relations that end in the labelled kind (``rels``,
+        indices into ``typed["relations"]``, over the tables of
+        ``core/relations.py TypedGraph.restrict``), its ``rel_linear``
+        multiplies those relations' blocks, its ``root_linear`` the
+        labelled kind's rows (``kinds``), and the ``add`` joins arrays
+        a kind tall — what is left out is values no output of the loss
+        program depends on, so loss and gradients are the uncut op
+        list's.  THE description of the cut: the interpreter, the
+        relation tables (``train/trainer.py``), the ``plan`` line and
+        the memory plan (``row_scale``: each array at its new height)
+        read these attrs.  Op indices, parameter names and layer 1 are
+        untouched; ``self`` when every row is labelled, when no
+        relation ends in the labelled kind, or when the op list does
+        not end in ``add(rel pair, root_linear)`` of one tensor."""
+        ty, ops, out = self.typed, self._ops, self._loss_op
+        if not ty or len(ty["node_types"]) < 2 or out is None \
+                or ops[out].kind != "add":
+            return self
+        readers = {j: [i for i, op in enumerate(ops) if j in op.inputs]
+                   for j in range(len(ops))}
+        pair = {"rel_linear", "rel_aggregate"}
+        hi = next((j for j in ops[out].inputs if ops[j].kind in pair), 0)
+        ro = next((j for j in ops[out].inputs
+                   if ops[j].kind == "root_linear"), 0)
+        lo = hi - 1
+        keep = tuple(r for r, (_, d) in enumerate(ty["relations"])
+                     if d == 0)
+        if not (hi and ro and keep
+                and {ops[lo].kind, ops[hi].kind} == pair
+                and ops[hi].inputs == (lo,)
+                and ops[lo].inputs == ops[ro].inputs
+                and "rels" not in ops[hi].attrs
+                and [readers[j] for j in (lo, hi, ro, out)]
+                == [[hi], [out], [out], []]):
+            return self
+        V = sum(ty["node_types"])
+        rows = ty["node_types"][0] / V
+        ops = self._copied_ops()
+        # the loss reads every row the cut list's last op makes
+        ops[0].attrs.pop("label_scale", None)
+        order = ops[hi].attrs["order"]
+        ops[lo].attrs.update(rels=keep, kinds=(0,),
+                             row_scale=self._stack_scale(order, keep))
+        ops[hi].attrs.update(rels=keep, kinds=(0,), row_scale=rows)
+        ops[ro].attrs.update(kinds=(0,), row_scale=rows)
+        ops[out].attrs.update(row_scale=rows)
+        return self._with_ops(ops)
+
+    def rel_cuts(self) -> Tuple[Tuple[str, Tuple[int, ...]], ...]:
+        """``(order, rels)`` of each cut relational layer
+        (:meth:`loss_cut`): the relation tables it needs beside the
+        whole ones."""
+        return tuple((op.attrs["order"], op.attrs["rels"])
+                     for op in self._ops
+                     if op.kind == "rel_aggregate" and "rels" in op.attrs)
 
     def labelled(self, *arrays):
         """``arrays`` (logits, labels, mask: a row a vertex) cut to
@@ -1361,13 +1454,16 @@ class Model:
                         maxval=s)
         return params
 
-    def _stack_scale(self, order: str) -> float:
+    def _stack_scale(self, order: str, rels=None) -> float:
         """Rows of the order's stacked tensor a vertex: the src stack
         under ``transform_first``, the dst stack under
-        ``gather_first`` (core/relations.py)."""
+        ``gather_first`` (core/relations.py), of the relations
+        ``rels`` (None: all)."""
         ty = self.typed
         end = 0 if order == TRANSFORM_FIRST else 1
-        return (sum(ty["node_types"][r[end]] for r in ty["relations"])
+        kept = (ty["relations"] if rels is None
+                else [ty["relations"][r] for r in rels])
+        return (sum(ty["node_types"][r[end]] for r in kept)
                 / sum(ty["node_types"]))
 
     def _typed_param_shapes(self, op: _Op):
@@ -1410,17 +1506,23 @@ class Model:
                         at += n
                 return jnp.concatenate(blocks, axis=0)
         n_types = ty["node_types"]
+        # the rows and relations the layer computes: all of them, or a
+        # cut layer's (loss_cut)
+        kinds = list(op.attrs.get("kinds", range(len(n_types))))
         if op.kind == "root_linear":
-            kinds = range(len(n_types))
             return dense.segment_linear(
-                x, ranges, n_types, [(k, k) for k in kinds],
+                x, [ranges[k] for k in kinds],
+                [n_types[k] for k in kinds],
+                [(j, j) for j in range(len(kinds))],
                 [params[f"{op.param}_{k}"] for k in kinds],
                 [params[f"{op.param}_{k}_b"] for k in kinds])
         if op.kind == "rel_aggregate":
-            return gctx.rel_aggregate(x, op.attrs["order"])
+            return gctx.rel_aggregate(x, op.attrs["order"],
+                                      op.attrs.get("rels"))
         # rel_linear: a block of rows a relation, on either side of the
         # mean (dense.segment_linear: segments in, segments out)
-        rels = ty["relations"]
+        rels = [ty["relations"][r] for r in op.attrs.get(
+            "rels", range(len(ty["relations"])))]
         ws = [params[f"{op.param}_{s}_{d}"] for s, d in rels]
         if op.attrs["order"] == TRANSFORM_FIRST:
             # vertices in (a kind's rows, once a relation out of it),
@@ -1434,7 +1536,8 @@ class Model:
         at = np.concatenate([[0], np.cumsum([n_types[d] for _, d in rels])])
         return dense.segment_linear(
             x, [(int(at[r]), int(at[r + 1])) for r in range(len(rels))],
-            n_types, [(r, d) for r, (_, d) in enumerate(rels)], ws)
+            [n_types[k] for k in kinds],
+            [(r, kinds.index(d)) for r, (_, d) in enumerate(rels)], ws)
 
     # ---- interpreter ----
 
@@ -1598,9 +1701,13 @@ class Model:
                 ) -> Tuple[jax.Array, jax.Array]:
         """(summed masked CE, logits) — the differentiable objective whose
         gradient equals the reference's ``softmax - onehot`` on train rows
-        (``softmax_kernel.cu:19-33``).  ``remat``: :meth:`apply`'s."""
-        logits = self.apply(params, feats, gctx, key=key, train=train,
-                            remat=remat)
+        (``softmax_kernel.cu:19-33``).  ``remat``: :meth:`apply`'s.
+        In train mode the op list is :meth:`loss_cut`'s, and the logits
+        are the rows the loss reads: every row, or a typed model's
+        labelled kind."""
+        model = self.loss_cut() if train else self
+        logits = model.apply(params, feats, gctx, key=key, train=train,
+                             remat=remat)
         with jax.named_scope(LOSS_SCOPE):
             loss = masked_softmax_cross_entropy(
                 *self.labelled(logits, labels, mask))

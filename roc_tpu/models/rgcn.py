@@ -32,6 +32,19 @@ layer (``core/relations.py resolve_rel_order``) — at ``64 -> 349``
 gathering the 64-wide means first reads a third of the bytes of
 gathering 349-wide products.
 
+Which program runs which relations: the eval / predict program
+(``Model.apply(train=False)``) runs every layer over all relations and
+returns a logit row for every vertex of every kind.  The loss program
+(``Model.loss_fn`` in train mode, ``models/builder.py
+Model.loss_cut``) reads kind 0's rows alone, so its LAST layer sums
+only the relations that end in kind 0, over tables of that subset
+(``core/relations.py TypedGraph.restrict``), multiplies only their
+blocks and kind 0's root weight, and hands the loss an array a kind
+tall; every earlier layer runs whole (its other kinds feed the last
+layer's sources).  Loss and gradients are the whole layer's: what the
+cut leaves out no output of that program depends on, and the
+parameters it leaves unused get the zero gradient they had.
+
 ``layers`` follows the CLI convention ``F-H-...-C``.
 """
 

@@ -20,6 +20,7 @@ import numpy as np
 
 from ..core.graph import Dataset
 from ..core.partition import padded_edge_list
+from ..core.relations import ORDER_PASSES
 from ..models.builder import GraphContext, Model
 from ..obs.events import emit
 from ..obs.metrics_registry import MetricsRegistry
@@ -491,6 +492,15 @@ def resolve_fuse(model: Model, config: TrainConfig) -> Model:
     return fused
 
 
+def _relation_tables(rel_orders, rel_cuts):
+    """``(order, rels)`` of every relation table set a typed model's
+    two programs scan: the whole one (``rels`` None) of each resolved
+    order (``Model.rel_orders()``), then the loss program's cut ones
+    (``Model.loss_cut().rel_cuts()``)."""
+    return ([(order, None) for order in dict.fromkeys(rel_orders)]
+            + list(dict.fromkeys(rel_cuts)))
+
+
 def _plan_kwargs(model: Model, dataset: Dataset, config: TrainConfig,
                  num_parts: int) -> Dict[str, Any]:
     """What both the autopilot and the resolved-plan echo hand
@@ -501,19 +511,25 @@ def _plan_kwargs(model: Model, dataset: Dataset, config: TrainConfig,
     g = dataset.graph
     rel_bytes = 0
     if model.uses_relations() and dataset.typed is not None:
-        # the relation tables of the passes the resolved orders run,
-        # read off the degrees before any table exists: an index and
-        # an fp32 weight a slot, an output row a sub-row ('segment':
-        # the forward edge lists)
-        from ..core.relations import ORDER_PASSES
-        for order in dict.fromkeys(model.rel_orders()):
+        # the relation tables of the passes the resolved orders run —
+        # the whole ones and the loss program's cut ones — read off
+        # the degrees before any table exists: an index and an fp32
+        # weight a slot, an output row a sub-row ('segment': the
+        # forward edge lists)
+        for order, rels in _relation_tables(
+                model.rel_orders(), model.loss_cut().rel_cuts()):
             fwd, bwd = ORDER_PASSES[order]
-            rel_bytes += (12 * dataset.typed.num_edges
+            typed = (dataset.typed if rels is None
+                     else dataset.typed.restrict(rels))
+            rel_bytes += (12 * typed.num_edges
                           if config.aggr_impl == "segment" else
-                          68 * (dataset.typed.pass_sub_rows(fwd)
-                                + dataset.typed.pass_sub_rows(bwd)))
+                          68 * (typed.pass_sub_rows(fwd)
+                                + typed.pass_sub_rows(bwd)))
     return dict(
-        num_nodes=g.num_nodes, num_edges=g.num_edges, ops=model._ops,
+        # the train step is the peak: its op list, each array at the
+        # height the loss program gives it (Model.loss_cut)
+        num_nodes=g.num_nodes, num_edges=g.num_edges,
+        ops=model.loss_cut()._ops,
         num_parts=num_parts,
         scan_rows=(0 if model.uses_attention()
                    or model.uses_max_aggregation()
@@ -779,7 +795,7 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
                        fuse: bool = False,
                        bd_census=None,
                        head_chunk: int = 0,
-                       rel_orders=()) -> GraphContext:
+                       rel_orders=(), rel_cuts=()) -> GraphContext:
     """Single-device GraphContext: edges padded to the chunk multiple,
     dummy source id == num_nodes (the appended zero row).
     ``sect_sub_w``/``sect_u16`` tune the sectioned layout and
@@ -796,11 +812,14 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
 
     ``rel_orders`` (a typed model's ``Model.rel_orders()``): build the
     relation tables of ``dataset.typed`` for the passes those orders
-    run, and none of the homogeneous tables."""
+    run, and none of the homogeneous tables; ``rel_cuts`` (its
+    ``Model.loss_cut().rel_cuts()``): beside them the tables of the
+    relation subsets the loss program's cut layers sum."""
     g = dataset.graph
     if rel_orders:
-        return _relation_context(dataset, aggr_impl, rel_orders,
-                                 head_chunk)
+        return _relation_context(
+            dataset, aggr_impl, _relation_tables(rel_orders, rel_cuts),
+            head_chunk)
     if aggr_impl == "auto":
         aggr_impl, bd_census = resolve_auto_impl_probed(
             g, bdense_min_fill=bdense_min_fill,
@@ -957,7 +976,7 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
     )
 
 
-def _relation_context(dataset: Dataset, aggr_impl: str, rel_orders,
+def _relation_context(dataset: Dataset, aggr_impl: str, tables,
                       head_chunk: int) -> GraphContext:
     """The GraphContext of a typed graph: per relation pass
     (``core/relations.py``) the flat width-8 table, its output rows
@@ -965,12 +984,16 @@ def _relation_context(dataset: Dataset, aggr_impl: str, rel_orders,
     'flat_sum' tables (``core/ell.py flat_sum_from_graph``) over the
     pass's own CSR — or, under 'segment', the forward passes' edge
     lists.  The union's symmetry is never read: every backward pass
-    has its transposed table."""
+    has its transposed table.  ``tables``: ``(order, rels)`` a table
+    set (:func:`_relation_tables`), the whole graph's (``rels`` None)
+    before any cut of it, whose passes then mask the whole one's
+    sorted edges (``TypedGraph.restrict``)."""
     from ..core.ell import flat_sum_from_graph
-    from ..core.relations import ORDER_PASSES
-    typed, g = dataset.typed, dataset.graph
+    g = dataset.graph
     idx, dst, w, meta = [], [], [], []
-    for order in dict.fromkeys(rel_orders):
+    for order, rels in tables:
+        typed = (dataset.typed if rels is None
+                 else dataset.typed.restrict(rels))
         fwd, bwd = ORDER_PASSES[order]
         for name in ((fwd,) if aggr_impl == "segment" else (fwd, bwd)):
             row_ptr, col, n_into, n_out = typed.pass_csr(name)
@@ -992,7 +1015,7 @@ def _relation_context(dataset: Dataset, aggr_impl: str, rel_orders,
             idx.append(jnp.asarray(t_idx))
             dst.append(jnp.asarray(t_dst))
             w.append(jnp.asarray(t_w))
-            meta.append((name, n_into, n_out, win))
+            meta.append((name, n_into, n_out, win, rels))
     return GraphContext(
         edge_src=jnp.zeros(1, jnp.int32), edge_dst=jnp.zeros(1, jnp.int32),
         in_degree=jnp.asarray(g.in_degree), num_rows=g.num_nodes,
@@ -1176,7 +1199,8 @@ class Trainer:
                 fuse=model.num_fused_aggregates() > 0,
                 bd_census=bd_census,
                 head_chunk=self._head_chunk,
-                rel_orders=model.rel_orders())
+                rel_orders=model.rel_orders(),
+                rel_cuts=model.loss_cut().rel_cuts())
             if config.aggr_impl == "auto":
                 # attention/MAX models reach here with 'auto' already
                 # rewritten by resolve_attention_impl; any other
@@ -1214,8 +1238,9 @@ class Trainer:
                              model._ops,
                              edges=int(dataset.graph.num_edges)),
                          **self.gctx.attention_plan(model._ops),
-                         **self.gctx.relation_plan(model._ops,
-                                                   dataset.typed),
+                         **self.gctx.relation_plan(
+                             model._ops, dataset.typed,
+                             model.loss_cut()._ops),
                          "memory_plan": self._plan},
                      console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog

@@ -244,6 +244,35 @@ def test_typed_residual_rules_and_heights(order):
         assert by_op[lin[1]] == pytest.approx(64 * 2 * stacked, abs=0.1)
 
 
+def test_typed_plan_charges_the_loss_programs_cut_heights():
+    """The train step is the peak and runs ``Model.loss_cut``'s op
+    list: layer 2's stack holds the three relations into papers, its
+    products, root term, sum, logits and softmax are papers tall."""
+    model = rgcn_mag("gather_first")
+    cut = model.loss_cut()._ops
+    whole, _ = M.saved_for_backward(model._ops, 2)
+    kept, _ = M.saved_for_backward(cut, 2)
+    assert [(i, n) for i, n, _ in kept] == [(i, n) for i, n, _ in whole]
+    by_op, was = ({i: row for i, _, row in k} for k in (kept, whole))
+    papers, last = MAG[0] / MAG_V, len(cut) - 1
+    lin = last - 2
+    assert cut[lin].kind == "rel_linear" and cut[last].kind == "add"
+    # the stacked means of three relations into papers, 64 wide
+    assert by_op[lin] == pytest.approx(64 * 2 * 3 * papers, abs=0.1)
+    assert was[lin] == pytest.approx(64 * 2 * 4_547_170 / MAG_V, abs=0.1)
+    # logits in bfloat16 and their fp32 softmax, papers tall both
+    assert by_op[last] == pytest.approx(349 * 6 * papers, abs=0.1)
+    assert was[last] == pytest.approx(349 * (2 + 4 * papers), abs=0.1)
+    assert all(by_op[i] == was[i] for i in by_op if i < lin)
+    gib = [sum(M.plan_components(
+        MAG_V, MAG_E, ops, dtype_bytes=2, param_bytes=4, scan_rows=8192,
+        extra_table_bytes=MAG_TABLE_BYTES + extra).values()) / 2**30
+        for ops, extra in ((model._ops, 0),
+                           (cut, 68 * (4_229_910 + 4_095_324)))]
+    # the two cut tables cost 0.53 GiB, the heights give back 1.24
+    assert gib[0] - gib[1] == pytest.approx(0.71, abs=0.02)
+
+
 def test_typed_plan_charges_the_embedding_tables_and_fits():
     """154,366,772 parameters at 18 bytes are most of the plan; with
     the relation tables beside them ``auto`` resolves the plain plan,

@@ -10,9 +10,12 @@ can reach for it ('flat_sum'; 'segment' is the in-program reference)
 and both sides of the mean; the hand-written backward against autodiff
 of the 'segment' path; ``--dtype mixed`` inside the tolerances of the
 cell ``rgcn-mag.fullgraph-typed``; the scopes of a compiled train step;
-the ``plan`` line; the derivation of relations from kind counts; and
-the typed refusals."""
+the ``plan`` line; the derivation of relations from kind counts; the
+loss program's cut last layer (``Model.loss_cut``: the relations into
+kind 0 alone) against the uncut op list, its tables, and the programs
+it must leave alone; and the typed refusals."""
 
+import hashlib
 import json
 import os
 import sys
@@ -262,6 +265,72 @@ def test_pass_tables_hold_every_relation_edge_once(ds, name):
     assert ty.pass_sub_rows(name) * 8 <= sect.idx[0].size
 
 
+INTO_PAPERS = tuple(r for r, (_, d) in enumerate(RELATIONS) if d == 0)
+
+
+@pytest.mark.parametrize("name", ["tf_fwd", "tf_bwd", "gf_fwd", "gf_bwd"])
+def test_cut_tables_hold_every_edge_into_kind_0_once(ds, name):
+    """The restricted graph's pass is the relation edges into kind 0,
+    each once, each at the weight the whole table gives it; its sorted
+    order is the whole pass's, masked — what its own sort would give."""
+    from roc_tpu.core.ell import flat_sum_from_graph
+    ty = ds.typed
+    assert INTO_PAPERS == (0, 3, 6)
+    cut = ty.restrict(INTO_PAPERS)
+    assert cut is ty.restrict(INTO_PAPERS)
+    assert cut.relations == tuple(RELATIONS[r] for r in INTO_PAPERS)
+    into_papers = ty.e_dst < KINDS[0]
+    assert cut.num_edges == int(into_papers.sum()) < ty.num_edges
+    assert cut.dst_nodes == KINDS[0] and cut.num_nodes == V
+    assert cut.pass_rows(name) == {
+        "tf_fwd": (KINDS[0], cut.src_rows),
+        "tf_bwd": (cut.src_rows, KINDS[0]),
+        "gf_fwd": (3 * KINDS[0], V), "gf_bwd": (V, 3 * KINDS[0])}[name]
+
+    def weighted_edges(graph):
+        """{(relation, u, v): weight} read off the pass's own table."""
+        row_ptr, col, n_into, n_out = graph.pass_csr(name)
+        sect = flat_sum_from_graph(row_ptr, col, n_into, src_rows=n_out)
+        idx, sub = sect.idx[0], sect.sub_dst[0]
+        w = graph.slot_weights(name, idx, sub)
+        row = np.broadcast_to(sub[..., None], idx.shape)
+        real = idx != n_out
+        into, out_of, w = row[real], idx[real], w[real]
+        stacked, vertex = ((out_of, into) if name in ("tf_fwd", "gf_bwd")
+                           else (into, out_of))
+        off = graph.src_off if name[:2] == "tf" else graph.dst_off
+        r = np.searchsorted(off, stacked, side="right") - 1
+        end = 0 if name[:2] == "tf" else 1
+        lo = graph.offsets[[p[end] for p in graph.relations]]
+        other = stacked - off[r] + lo[r]
+        u, v = (other, vertex) if name[:2] == "tf" else (vertex, other)
+        keys = list(zip((graph.relations[i] for i in r), u.tolist(),
+                        v.tolist()))
+        assert len(set(keys)) == len(keys) == graph.num_edges
+        return dict(zip(keys, w.tolist()))
+
+    whole, part = weighted_edges(ty), weighted_edges(cut)
+    assert part == {k: w for k, w in whole.items() if k[0][1] == 0}
+    for graph in (ty, cut):
+        into, _ = graph.pass_edges(name)
+        # gf_fwd's counts are the degrees derive_typed made, not recounted
+        np.testing.assert_array_equal(
+            graph._pass_counts(name),
+            np.bincount(into, minlength=graph.pass_rows(name)[0]))
+        if name == "tf_fwd":        # stored order is the pass's
+            assert graph._pass_order(name) is None
+            assert (np.diff(into) >= 0).all()
+        else:
+            np.testing.assert_array_equal(
+                graph._pass_order(name), np.argsort(into, kind="stable"))
+
+
+@pytest.mark.parametrize("bad", [(), (3, 0), (0, 0), (0, 7), (-1, 2)])
+def test_restrict_takes_increasing_relation_indices(ds, bad):
+    with pytest.raises(ValueError, match="increasing"):
+        ds.typed.restrict(bad)
+
+
 # ------------------------------------------------ model, parameters
 
 def test_parameter_names_and_op_list():
@@ -428,6 +497,150 @@ def test_mixed_precision_is_inside_the_cells_tolerances(ref, ds, plain,
     assert got["row_rel_l2_median"] <= tol["row_rel_l2_median"], got
 
 
+# ------------------------------------------- the loss program's cut
+
+def test_loss_cut_is_read_off_the_relations_and_the_labelled_kind():
+    for order in REL_ORDERS:
+        model = _model(order)
+        cut = model.loss_cut()
+        assert cut is not model and cut.loss_cut() is cut
+        assert [op.kind for op in cut._ops] == [op.kind
+                                                for op in model._ops]
+        assert [op.inputs for op in cut._ops] == [op.inputs
+                                                  for op in model._ops]
+        assert cut.rel_cuts() == ((order, INTO_PAPERS),)
+        assert model.rel_cuts() == ()
+        changed = [i for i, (a, b) in enumerate(zip(model._ops, cut._ops))
+                   if a.attrs != b.attrs]
+        last = len(model._ops) - 1
+        # the input (label_scale goes) and the last layer's four ops
+        assert changed == [0, last - 3, last - 2, last - 1, last]
+        rows = KINDS[0] / V
+        stack = sum(KINDS[RELATIONS[r][order == GATHER_FIRST]]
+                    for r in INTO_PAPERS) / V
+        assert [cut._ops[i].attrs["row_scale"] for i in changed[1:]] == \
+            pytest.approx([stack, rows, rows, rows])
+        assert "label_scale" in model._ops[0].attrs
+        assert "label_scale" not in cut._ops[0].attrs
+        assert sorted(cut.init_params(jax.random.PRNGKey(5))) == \
+            sorted(PARAM_NAMES)
+
+
+def test_a_model_whose_every_kind_is_labelled_resolves_to_no_cut():
+    one = build_rgcn(LAYERS, 0.5, node_types=(V,), embed_types=(),
+                     relations=((0, 0),))
+    assert one.loss_cut() is one and one.rel_cuts() == ()
+    # nor one no relation of which ends in the labelled kind
+    away = build_rgcn(LAYERS, 0.5, node_types=KINDS, embed_types=EMBED,
+                      relations=((0, 1), (1, 2)))
+    assert away.loss_cut() is away
+    from roc_tpu.models.gcn import build_gcn
+    gcn = build_gcn(LAYERS, 0.5)
+    assert gcn.loss_cut() is gcn
+
+
+def _cut_pair(ds, impl, order):
+    """(loss, gradients) of the train-mode objective — dropout on, one
+    key — through the uncut op list and through ``loss_fn``'s cut
+    one, float32, on one context that holds both table sets."""
+    key = ("cut", impl, order)
+    if key in _cache:
+        return _cache[key]
+    from roc_tpu.ops.loss import masked_softmax_cross_entropy
+    model, params = _model(order), _params()
+    gctx = make_graph_context(ds, impl, rel_orders=model.rel_orders(),
+                              rel_cuts=model.loss_cut().rel_cuts())
+    feats = jnp.asarray(model_features(model, ds))
+    labels, mask = jnp.asarray(ds.labels), jnp.asarray(ds.mask)
+    drop = jax.random.PRNGKey(11)
+
+    def uncut(p):
+        logits = model.apply(p, feats, gctx, key=drop, train=True)
+        assert logits.shape == (V, CLASSES)
+        return masked_softmax_cross_entropy(
+            *model.labelled(logits, labels, mask))
+
+    def cut(p):
+        loss, logits = model.loss_fn(p, feats, labels, mask, gctx,
+                                     key=drop, train=True)
+        assert logits.shape == (KINDS[0], CLASSES)
+        return loss
+
+    with jax.default_matmul_precision("highest"):
+        out = [jax.value_and_grad(f)(params) for f in (uncut, cut)]
+    _cache[key] = [(float(l), {k: np.asarray(v) for k, v in g.items()})
+                   for l, g in out]
+    return _cache[key]
+
+
+@pytest.mark.parametrize("impl,order", CASES)
+def test_cut_loss_is_the_uncut_op_lists(ds, impl, order):
+    (want, _), (got, _) = _cut_pair(ds, impl, order)
+    assert got == pytest.approx(want, rel=1e-6)
+
+
+@pytest.mark.parametrize("impl,order,name", [
+    (i, o, n) for i, o in CASES for n in PARAM_NAMES])
+def test_cut_gradient_is_the_uncut_op_lists(ds, impl, order, name):
+    (_, want), (_, got) = _cut_pair(ds, impl, order)
+    if name in DEAD:
+        assert not want[name].any() and not got[name].any()
+        return
+    assert np.abs(want[name]).max() > 1e-7
+    np.testing.assert_allclose(got[name], want[name], rtol=1e-6,
+                               atol=1e-6 * np.abs(want[name]).max())
+
+
+def _lowered_sha(tr, step):
+    """SHA-256 of a trainer's lowered train or eval program."""
+    if step == "train":
+        args = (tr.params, tr.opt_state, jax.random.PRNGKey(0),
+                jnp.float32(0.01), tr.feats, tr.labels, tr.mask, tr.gctx)
+        fn = jax.jit(tr._train_step_impl)
+    else:
+        args = (tr.params, tr.feats, tr.labels, tr.mask, tr.gctx)
+        fn = jax.jit(tr._eval_step_impl)
+    return hashlib.sha256(fn.lower(*args).as_text().encode()).hexdigest()
+
+
+def _untyped(family):
+    from roc_tpu.core.graph import synthetic_dataset
+    from roc_tpu.models.gat import build_gat
+    from roc_tpu.models.gcn import build_gcn
+    from roc_tpu.models.gcn2 import build_gcn2
+    data = synthetic_dataset(num_nodes=96, avg_degree=4, in_dim=F,
+                             num_classes=CLASSES, seed=1)
+    model = {"gcn": lambda: build_gcn(LAYERS, 0.5),
+             "gat": lambda: build_gat(LAYERS, 0.5),
+             "gcn2": lambda: build_gcn2([F, H, H, CLASSES],
+                                        dropout_rate=0.5)}[family]()
+    return model, data
+
+
+@pytest.mark.parametrize("family,step", [
+    ("rgcn", "eval"), ("gcn", "train"), ("gat", "train"),
+    ("gcn2", "train"), ("gcn", "eval")])
+def test_programs_the_cut_must_leave_alone(ds, monkeypatch, family, step):
+    """Lowered with ``loss_cut`` on the tree and with it answering
+    ``self``: the eval program of the typed model and both programs of
+    the families that label every row hash equal; the typed train
+    program does not (the cut reaches it)."""
+    from roc_tpu.models.builder import Model
+    cfg = TrainConfig(verbose=False, aggr_impl="auto", weight_decay=0.0)
+
+    def sha(which):
+        model, data = (_model(), ds) if family == "rgcn" \
+            else _untyped(family)
+        return _lowered_sha(Trainer(model, data, cfg), which)
+
+    with_cut = sha(step)
+    typed_train = sha("train") if family == "rgcn" else None
+    monkeypatch.setattr(Model, "loss_cut", lambda self: self)
+    assert sha(step) == with_cut
+    if family == "rgcn":
+        assert sha("train") != typed_train
+
+
 # ----------------------------------------------------- the normal path
 
 @pytest.fixture(scope="module")
@@ -452,7 +665,8 @@ def test_auto_resolves_to_the_flat_scan_and_trains(ds, trainer):
 
 
 def test_plan_line_carries_relations_orders_and_embedding_rows(ds, trainer):
-    plan = trainer.gctx.relation_plan(trainer.model._ops, ds.typed)
+    plan = trainer.gctx.relation_plan(trainer.model._ops, ds.typed,
+                                      trainer.model.loss_cut()._ops)
     assert plan["node_types"] == list(KINDS)
     assert [(r["src"], r["dst"]) for r in plan["relations"]] == \
         list(RELATIONS)
@@ -468,6 +682,25 @@ def test_plan_line_carries_relations_orders_and_embedding_rows(ds, trainer):
     assert plan["embedding_rows"] == sum(KINDS[k] for k in EMBED)
     assert plan["embedding_bytes"] == plan["embedding_rows"] * F * 4
     assert plan["relation_edges"] == ds.typed.num_edges
+    # what the loss program runs of each layer: all of layer 1, of
+    # layer 2 the three relations into kind 0 and kind 0's rows
+    first, last = plan["rel_layers"]
+    assert (first["train_relations"], first["train_edges"],
+            first["train_slots_fwd"], first["train_slots_bwd"],
+            first["train_out_rows"]) == (
+        len(RELATIONS), ds.typed.num_edges, first["slots_fwd"],
+        first["slots_bwd"], V)
+    cut = ds.typed.restrict(INTO_PAPERS)
+    assert (last["train_relations"], last["train_edges"],
+            last["train_out_rows"]) == (3, cut.num_edges, KINDS[0])
+    assert cut.num_edges <= last["train_slots_fwd"] < last["slots_fwd"]
+    assert cut.num_edges <= last["train_slots_bwd"] < last["slots_bwd"]
+    assert last["train_slots_fwd"] >= 8 * cut.pass_sub_rows("gf_fwd")
+    # and the plan without a loss program's op list: nothing cut
+    same = trainer.gctx.relation_plan(trainer.model._ops, ds.typed)
+    assert all(l["train_slots_fwd"] == l["slots_fwd"]
+               and l["train_relations"] == len(RELATIONS)
+               for l in same["rel_layers"])
 
 
 def test_scopes_of_the_compiled_train_step(trainer):
