@@ -422,14 +422,27 @@ def load_dataset(prefix: str, in_dim: int, num_classes: int,
     """Load a reference-layout dataset directory: ``<prefix>.add_self_edge.lux``
     (falling back to ``<prefix>.lux`` + in-framework self-edge insertion),
     ``.feats.csv``/``.feats.bin``, ``.label``, ``.mask``."""
+    from ..obs.events import span
     lux = prefix + ".add_self_edge.lux"
-    if os.path.exists(lux):
-        graph = load_lux(lux)
-    else:
-        graph = add_self_edges(load_lux(prefix + ".lux"))
-    feats = load_features(prefix, graph.num_nodes, in_dim)
-    labels = load_labels(prefix, graph.num_nodes, num_classes)
-    mask = load_mask(prefix, graph.num_nodes)
+    with span("setup.load.graph") as s:
+        if os.path.exists(lux):
+            graph = load_lux(lux)
+        else:
+            lux = prefix + ".lux"
+            graph = add_self_edges(load_lux(lux))
+        s["file_bytes"] = os.path.getsize(lux)
+    with span("setup.load.features") as s:
+        # the .bin cache where a load has written it, else the CSV
+        cached = prefix + ".feats.bin"
+        s["file_bytes"] = os.path.getsize(
+            cached if os.path.exists(cached) else prefix + ".feats.csv")
+        feats = load_features(prefix, graph.num_nodes, in_dim)
+    with span("setup.load.labels") as s:
+        labels = load_labels(prefix, graph.num_nodes, num_classes)
+        s["file_bytes"] = os.path.getsize(prefix + ".label")
+    with span("setup.load.mask") as s:
+        mask = load_mask(prefix, graph.num_nodes)
+        s["file_bytes"] = os.path.getsize(prefix + ".mask")
     return Dataset(graph=graph, features=feats, labels=labels, mask=mask,
                    num_classes=num_classes,
                    name=name or os.path.basename(prefix))

@@ -55,7 +55,12 @@ from typing import Any, Dict, List, Optional
 #             restores onto a different partition count
 #   timeline  clock-sync handshakes and per-phase span batches the
 #             cross-process trace merger consumes
-#             (obs/timeline.py; python -m roc_tpu.timeline)
+#             (obs/timeline.py; python -m roc_tpu.timeline).  A batch
+#             is ``kind="spans"``, ``spans=[[name, mono0, ms], ...]``;
+#             a lap may carry a fourth element, a dict of per-span
+#             args (the serving tier's rids; :func:`span`'s ``parent``
+#             and counters), and a batch :func:`flush_spans` wrote
+#             says which ``phase`` of the run it covers ("setup")
 #   serve     inference-tier lifecycle (roc_tpu/serve): artifact
 #             export/prewarm reports, server open/close summaries
 #             (query/batch counts, latency percentiles), propagation-
@@ -209,6 +214,11 @@ class EventLog:
         self.ring: collections.deque = collections.deque(
             maxlen=flight_ring_events() if ring_events is None
             else ring_events)
+        # finished :func:`span` laps waiting for :func:`flush_spans`;
+        # bounded, so a process that opens spans and never builds a
+        # trainer (which flushes) cannot grow without limit
+        self.spans: collections.deque = collections.deque(
+            maxlen=SPAN_BUFFER_LAPS)
 
     def emit(self, cat: str, msg: str, console: bool = True,
              **fields: Any) -> Dict[str, Any]:
@@ -233,6 +243,19 @@ class EventLog:
                               f"failed: {e!r} (further failures "
                               f"silent)", file=sys.stderr)
         return record
+
+    def flush_spans(self, phase: str) -> Optional[Dict[str, Any]]:
+        """Emit the buffered :func:`span` laps as ONE ``timeline`` /
+        ``spans`` event and empty the buffer; None when it held
+        nothing."""
+        with self._lock:
+            laps = list(self.spans)
+            self.spans.clear()
+        if not laps:
+            return None
+        return self.emit("timeline", f"spans: {len(laps)} laps ({phase})",
+                         console=False, kind="spans", phase=phase,
+                         spans=laps)
 
     def add_sink(self, sink) -> None:
         with self._lock:
@@ -293,6 +316,68 @@ def emit(cat: str, msg: str, console: bool = True,
     of the stderr stream (it still lands in the JSONL artifact) — the
     call-site analog of today's ``if config.verbose:`` gates."""
     return get_bus().emit(cat, msg, console=console, **fields)
+
+
+# ---------------------------------------------------------- phase spans
+
+# laps the buffer holds between two flushes (a trainer's set-up writes
+# a few dozen)
+SPAN_BUFFER_LAPS = 4096
+
+_SPAN_STACK = threading.local()
+
+
+class span(dict):
+    """``with span(name, **counters) as s:`` records one lap of a named
+    phase on the bus's clock (``time.monotonic()``, the ``mono`` every
+    event carries) into the bus's span buffer, as the four-element lap
+    ``[name, mono0, ms, {"parent": <enclosing span's name or None>,
+    **counters}]`` the ``timeline`` category carries.  ``s`` is the
+    dict of counters: the body may add to it (``s["h2d_bytes"] +=
+    a.nbytes``; a counter not given yet reads 0), and after the block
+    ``s.ms`` holds the lap's duration.  Nesting is per thread.  A body
+    that raises is still recorded.  :func:`flush_spans` writes the
+    buffer out.
+
+    No switch turns it off: a lap costs two clock reads and a list
+    append.  It adds no device sync: a span around ``jnp.asarray`` is
+    the host's time inside the call.  The laps are not entered as
+    ``jax.profiler.TraceAnnotation``s — the phases this names (a
+    trainer's set-up) run before any profiler session exists;
+    ``utils/profiling.py EpochTimer.annotate`` stays the
+    ``--profile-dir`` path for the epoch loop's phases."""
+
+    def __init__(self, name: str, **counters: Any):
+        super().__init__(counters)
+        self.name = name
+        self.ms: Optional[float] = None
+
+    def __missing__(self, key: str) -> int:
+        return 0
+
+    def __enter__(self) -> "span":
+        stack = _SPAN_STACK.__dict__.setdefault("names", [])
+        self._parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self._mono0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.ms = (time.monotonic() - self._mono0) * 1e3
+        _SPAN_STACK.names.pop()
+        bus = get_bus()
+        with bus._lock:
+            bus.spans.append([self.name, round(self._mono0, 6),
+                              round(self.ms, 3),
+                              {"parent": self._parent, **self}])
+
+
+def flush_spans(phase: str) -> Optional[Dict[str, Any]]:
+    """Write the :class:`span` laps recorded since the last flush as
+    one ``timeline`` event (``kind="spans"``, ``phase=phase``,
+    ``console=False``) on the global bus.  Without a JSONL sink it
+    reaches the flight-recorder ring only."""
+    return get_bus().flush_spans(phase)
 
 
 # ------------------------------------------------ crash flight recorder

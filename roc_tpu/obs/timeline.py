@@ -16,9 +16,15 @@ load directly:
 
 - one *process* lane per ``(host, proc)`` stream, named
   ``proc<p>@<host>``;
-- a ``phases`` thread per lane with the span laps (compile / train /
-  eval / head_forward / tail_grad / head_wgrad / update) the trainers
-  flush as ``timeline``-category span batches;
+- a ``phases`` thread per lane with the span laps the trainers flush
+  as ``timeline``-category span batches: the epoch loop's (compile /
+  train / eval / head_forward / tail_grad / head_wgrad / update) and
+  the set-up's own (``setup.load`` / ``.resolve`` / ``.symmetry`` /
+  ``.tables`` / ``.upload`` / ``.params`` / ..., the batch with
+  ``phase="setup"`` — obs/events.py ``span``).  A lap is ``[name,
+  mono0, ms]`` or ``[name, mono0, ms, {args}]``: the fourth element
+  (a set-up lap's ``parent`` and counters, the serving tier's rids)
+  becomes the drawn span's args;
 - an ``h2d`` thread with the StagingPool per-block wait/stage spans;
 - a ``markers`` thread with instant events for stall heartbeats,
   resilience faults/recoveries/preemptions, rebalance decisions, and

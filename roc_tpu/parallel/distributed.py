@@ -44,12 +44,12 @@ from ..core.ell import ell_from_padded_parts
 from ..core.graph import Dataset, MASK_NONE
 from ..core.partition import PartitionedGraph, partition_graph
 from ..models.builder import GraphContext, Model
-from ..obs.events import emit
+from ..obs.events import emit, flush_spans, span
 from ..obs.scopes import ALLREDUCE_SCOPE, LOSS_SCOPE, OPT_SCOPE
 from ..ops.loss import masked_softmax_cross_entropy, perf_metrics, summarize_metrics
 from ..train.optimizer import AdamConfig, adam_init, adam_update
 from ..train.trainer import (TrainConfig, cast_floats, compute_dtype_of,
-                             resolve_symmetric)
+                             resolve_symmetric, upload)
 
 
 # THE names of the mesh axes — defined in parallel/__init__ (the
@@ -273,15 +273,18 @@ def _sectioned_tables(ptrs: np.ndarray, cols: np.ndarray,
                             sectioned_from_padded_parts)
     if section_rows is None:
         section_rows = default_section_rows(sect_u16)
-    sect = sectioned_from_padded_parts(
-        ptrs, cols, pg.real_nodes, pg.part_nodes, src_rows=src_rows,
-        section_rows=section_rows, sub_w=sect_sub_w)
-    if sect_u16:
-        sect = sect.with_idx_dtype(np.uint16)
+    with span("setup.tables", table="sectioned") as s:
+        sect = sectioned_from_padded_parts(
+            ptrs, cols, pg.real_nodes, pg.part_nodes, src_rows=src_rows,
+            section_rows=section_rows, sub_w=sect_sub_w)
+        if sect_u16:
+            sect = sect.with_idx_dtype(np.uint16)
+        s["sub_rows"] = sum(a.size for a in sect.sub_dst)
     sect_w = ()
     if fuse_d is not None:
-        sect_w = tuple(put(w) for w in
-                       sect.weight_tables(fuse_d[0], fuse_d[1]))
+        with span("setup.tables", table="sect_w"):
+            sect_w = sect.weight_tables(fuse_d[0], fuse_d[1])
+        sect_w = tuple(put(w) for w in sect_w)
     return (tuple(put(a) for a in sect.idx),
             tuple(put(a) for a in sect.sub_dst),
             sect.meta,
@@ -314,6 +317,12 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
     sh = NamedSharding(mesh, P(PARTS_AXIS))
     if put is None:
         put = lambda x: jax.device_put(x, sh)
+    hand_over = put
+
+    def put(x, what="tables"):
+        # every hand-over of this build under a ``setup.upload`` span
+        return upload(x, what, put=hand_over)
+
     ell_idx = ()
     ell_row_pos = put(np.zeros((pg.num_parts, 1), dtype=np.int32))
     ell_row_id = ()
@@ -343,37 +352,44 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
         # ring tables fully describe the aggregation — skip the O(E)
         # per-edge array construction entirely and upload stubs
         from .ring import build_ring_tables, ring_weight_tables
-        rt = build_ring_tables(pg)
+        with span("setup.tables", table="ring"):
+            rt = build_ring_tables(pg)
         ring_idx = (put(rt.src), put(rt.dst))
         if aggr_fuse:
             from ..ops.norm import inv_sqrt_degree_np as _inv
-            ring_w = (put(ring_weight_tables(
-                pg, rt, _inv(dataset.graph.in_degree))),)
+            with span("setup.tables", table="ring_w"):
+                ring_w = ring_weight_tables(
+                    pg, rt, _inv(dataset.graph.in_degree))
+            ring_w = (put(ring_w),)
         ring_padding_ratio = rt.padding_ratio
         col_padded = np.zeros((pg.num_parts, 1), dtype=np.int32)
         edge_dst = np.zeros((pg.num_parts, 1), dtype=np.int32)
     else:
-        col_padded = remap_to_padded(pg)
-        if aggr_impl != "segment":
-            # table-driven paths never read the flat edge arrays —
-            # upload stubs instead of two [P, E_p] tensors
-            edge_dst = np.zeros((pg.num_parts, 1), dtype=np.int32)
-        else:
-            edge_dst = np.stack([
-                np.repeat(np.arange(pg.part_nodes, dtype=np.int32),
-                          np.diff(pg.part_row_ptr[p]))
-                for p in range(pg.num_parts)])
+        with span("setup.tables", table="edge_list"):
+            col_padded = remap_to_padded(pg)
+            if aggr_impl != "segment":
+                # table-driven paths never read the flat edge arrays —
+                # upload stubs instead of two [P, E_p] tensors
+                edge_dst = np.zeros((pg.num_parts, 1), dtype=np.int32)
+            else:
+                edge_dst = np.stack([
+                    np.repeat(np.arange(pg.part_nodes, dtype=np.int32),
+                              np.diff(pg.part_row_ptr[p]))
+                    for p in range(pg.num_parts)])
         if aggr_impl == "ell":
-            table = ell_from_padded_parts(
-                pg.part_row_ptr, col_padded, pg.real_nodes,
-                pg.part_nodes, dummy=pg.num_parts * pg.part_nodes)
+            with span("setup.tables", table="ell") as s:
+                table = ell_from_padded_parts(
+                    pg.part_row_ptr, col_padded, pg.real_nodes,
+                    pg.part_nodes, dummy=pg.num_parts * pg.part_nodes)
+                s["slots"] = sum(a.size for a in table.idx)
             ell_idx = tuple(put(a) for a in table.idx)
             ell_row_pos = put(table.row_pos)
             ell_row_id = tuple(put(a) for a in table.row_id)
             if aggr_fuse:
                 from ..core.ell import ell_weight_tables
-                ell_w = tuple(put(w) for w in ell_weight_tables(
-                    table, fuse_d[0], fuse_d[1]))
+                with span("setup.tables", table="ell_w"):
+                    ell_w = ell_weight_tables(table, fuse_d[0], fuse_d[1])
+                ell_w = tuple(put(w) for w in ell_w)
         elif aggr_impl == "sectioned":
             sect_idx, sect_sub_dst, sect_meta, sect_w = \
                 _sectioned_tables(
@@ -416,17 +432,18 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
             # needs multi-edge hubs past 4 bits plus a saturated
             # budget, and the native census is seconds even at
             # Reddit scale
-            plans = _mk(bdense_a_budget * 2
-                        if bdense_a_budget is not None else None)
-            packable = all(pl.n_blocks == 0
-                           or int(pl.a_blocks.max()) <= U4_MAX
-                           for pl in plans)
-            if packable:
-                plans = [pack_a_u4(pl) for pl in plans]
-            elif bdense_a_budget is not None and any(
-                    pl.a_blocks.nbytes > bdense_a_budget
-                    for pl in plans):
-                plans = _mk(bdense_a_budget)
+            with span("setup.tables", table="bdense"):
+                plans = _mk(bdense_a_budget * 2
+                            if bdense_a_budget is not None else None)
+                packable = all(pl.n_blocks == 0
+                               or int(pl.a_blocks.max()) <= U4_MAX
+                               for pl in plans)
+                if packable:
+                    plans = [pack_a_u4(pl) for pl in plans]
+                elif bdense_a_budget is not None and any(
+                        pl.a_blocks.nbytes > bdense_a_budget
+                        for pl in plans):
+                    plans = _mk(bdense_a_budget)
             bd_occupancy = tuple(pl.occupancy() for pl in plans)
             nblk_max = max(pl.n_blocks for pl in plans)
             if nblk_max:
@@ -434,20 +451,22 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
                 bd_src_vpad = plans[0].src_vpad
                 n_dst_tiles = bd_vpad // BLOCK
                 a_w = BLOCK // 2 if packable else BLOCK
-                a = np.zeros((pg.num_parts, nblk_max, BLOCK, a_w),
-                             dtype=np.uint8)
-                sblk = np.zeros((pg.num_parts, nblk_max),
-                                dtype=np.int32)
-                # padding blocks target the dummy output tile (index
-                # n_dst_tiles) — zero A keeps them numerically inert,
-                # the dummy dst keeps even rounding noise off real rows
-                dblk = np.full((pg.num_parts, nblk_max), n_dst_tiles,
-                               dtype=np.int32)
-                for p, pl in enumerate(plans):
-                    nb = pl.n_blocks
-                    a[p, :nb] = pl.a_blocks
-                    sblk[p, :nb] = pl.src_blk
-                    dblk[p, :nb] = pl.dst_blk
+                with span("setup.tables", table="bdense_stack"):
+                    a = np.zeros((pg.num_parts, nblk_max, BLOCK, a_w),
+                                 dtype=np.uint8)
+                    sblk = np.zeros((pg.num_parts, nblk_max),
+                                    dtype=np.int32)
+                    # padding blocks target the dummy output tile
+                    # (index n_dst_tiles) — zero A keeps them
+                    # numerically inert, the dummy dst keeps even
+                    # rounding noise off real rows
+                    dblk = np.full((pg.num_parts, nblk_max), n_dst_tiles,
+                                   dtype=np.int32)
+                    for p, pl in enumerate(plans):
+                        nb = pl.n_blocks
+                        a[p, :nb] = pl.a_blocks
+                        sblk[p, :nb] = pl.src_blk
+                        dblk[p, :nb] = pl.dst_blk
                 bd_tabs = (put(a), put(sblk), put(dblk))
                 if aggr_fuse:
                     # in-register tile scales (ops/blockdense.py):
@@ -463,11 +482,13 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
                     bd_scale = (put(dd), put(ds))
             # residual scattered edges -> the stacked sectioned tables
             # (every edge, when no tile qualifies anywhere)
-            e_res = max(max(pl.res_col.shape[0] for pl in plans), 1)
-            res_ptrs = np.stack([pl.res_row_ptr for pl in plans])
-            res_cols = np.zeros((pg.num_parts, e_res), dtype=np.int32)
-            for p, pl in enumerate(plans):
-                res_cols[p, :pl.res_col.shape[0]] = pl.res_col
+            with span("setup.tables", table="bdense_residual"):
+                e_res = max(max(pl.res_col.shape[0] for pl in plans), 1)
+                res_ptrs = np.stack([pl.res_row_ptr for pl in plans])
+                res_cols = np.zeros((pg.num_parts, e_res),
+                                    dtype=np.int32)
+                for p, pl in enumerate(plans):
+                    res_cols[p, :pl.res_col.shape[0]] = pl.res_col
             sect_idx, sect_sub_dst, sect_meta, sect_w = \
                 _sectioned_tables(
                     res_ptrs, res_cols, pg, src_rows=src_rows,
@@ -486,22 +507,29 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
             # the same way.
             from ..core.ell import flat_sum_from_padded_parts
             src_rows = pg.num_parts * pg.part_nodes
-            sect = flat_sum_from_padded_parts(
-                pg.part_row_ptr, col_padded, pg.real_nodes,
-                pg.part_nodes, src_rows=src_rows)
+            with span("setup.tables", table="flat_sum") as s:
+                sect = flat_sum_from_padded_parts(
+                    pg.part_row_ptr, col_padded, pg.real_nodes,
+                    pg.part_nodes, src_rows=src_rows)
+                s["sub_rows"] = sum(a.size for a in sect.sub_dst)
             sect_idx = tuple(put(a) for a in sect.idx)
             sect_sub_dst = tuple(put(a) for a in sect.sub_dst)
             if aggr_impl == "flat_sum":
                 flat_win = sect.win_rows[0]
                 if fuse_d is not None:
-                    sect_w = tuple(put(w) for w in sect.weight_tables(
-                        fuse_d[0], fuse_d[1]))
+                    with span("setup.tables", table="flat_sum_w"):
+                        sect_w = sect.weight_tables(fuse_d[0], fuse_d[1])
+                    sect_w = tuple(put(w) for w in sect_w)
         if aggr_impl != "segment":
             col_padded = np.zeros((pg.num_parts, 1), dtype=np.int32)
+    with span("setup.tables", table="padded_rows"):
+        feats = pad_nodes(dataset.features, pg).astype(dtype)
+        labels = pad_nodes(dataset.labels, pg)
+        mask = pad_nodes(dataset.mask, pg, fill=MASK_NONE)
     return ShardedData(
-        feats=put(pad_nodes(dataset.features, pg).astype(dtype)),
-        labels=put(pad_nodes(dataset.labels, pg)),
-        mask=put(pad_nodes(dataset.mask, pg, fill=MASK_NONE)),
+        feats=put(feats, "features"),
+        labels=put(labels, "labels"),
+        mask=put(mask, "mask"),
         edge_src=put(col_padded),
         edge_dst=put(edge_dst),
         in_degree=put(pg.part_in_degree),
@@ -654,12 +682,16 @@ class DistributedTrainer:
                     "online rebalancing is single-controller only "
                     "(every SPMD process would need to agree on the "
                     "re-split and reshard over DCN)")
-        self.pg = pg if pg is not None else partition_graph(
-            dataset.graph, num_parts,
-            node_multiple=8, edge_multiple=config.chunk,
-            method=self._partition_method,
-            cost_weights=self._costmodel.search_weights(
-                **self._phi_flags))
+        if pg is None:
+            with span("setup.partition", parts=num_parts,
+                      method=self._partition_method):
+                pg = partition_graph(
+                    dataset.graph, num_parts,
+                    node_multiple=8, edge_multiple=config.chunk,
+                    method=self._partition_method,
+                    cost_weights=self._costmodel.search_weights(
+                        **self._phi_flags))
+        self.pg = pg
         self.data = data if data is not None else self._build_data(
             self.pg)
         if config.aggr_impl == "bdense" and config.halo != "ring" \
@@ -780,47 +812,53 @@ class DistributedTrainer:
                  pair_edges=int(self.data.ring_idx[0].shape[2]),
                  padding_ratio=ratio,
                  ring_overlap=bool(config.ring_overlap))
-        key = jax.random.PRNGKey(config.seed)
-        self.key, init_key = jax.random.split(key)
-        host_params = model.init_params(init_key, dtype=config.dtype)
-        self.params = put_replicated(host_params, self.mesh)
-        self.opt_state = put_replicated(adam_init(host_params),
-                                        self.mesh)
+        with span("setup.params") as s:
+            key = jax.random.PRNGKey(config.seed)
+            self.key, init_key = jax.random.split(key)
+            host_params = model.init_params(init_key, dtype=config.dtype)
+            self.params = put_replicated(host_params, self.mesh)
+            self.opt_state = put_replicated(adam_init(host_params),
+                                            self.mesh)
+            s["param_bytes"] = sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(self.params))
         self.adam_cfg = AdamConfig(weight_decay=config.weight_decay)
         # observability: per-device modeled bytes for the compile
         # observer's modeled-vs-actual check, edges for edges/sec
         from ..train.trainer import modeled_plan
         self._obs_edges = int(dataset.graph.num_edges)
-        self._plan = modeled_plan(model, dataset, config,
-                                  num_parts=num_parts)
+        with span("setup.resolve"):
+            self._plan = modeled_plan(model, dataset, config,
+                                      num_parts=num_parts)
         self._modeled_bytes = self._plan["est_bytes"]
         # dataset identity for the checkpoint config fingerprint; the
         # elastic half (num_parts + quantized plan shapes) reads
         # self.pg directly (utils/checkpoint.trainer_fingerprint)
         self._fp_dataset = {"V": int(dataset.graph.num_nodes),
                             "E": int(dataset.graph.num_edges)}
-        self._build_steps()
-        # split-quality record: per-part padded shapes + halo rows +
-        # imbalance ratios, into the manifest (every run records the
-        # split it actually trained on) and the costmodel event stream
-        self._partition_stats = self._emit_partition_stats()
+        with span("setup.steps"):
+            self._build_steps()
         from ..obs.manifest import run_manifest
-        run_manifest(config=self.config, dataset=dataset, model=model,
-                     num_parts=num_parts,
-                     extra={"modeled_step_bytes": self._modeled_bytes,
-                            "bd_occupancy": list(
-                                self.data.bd_occupancy),
-                            "partition": self._partition_stats},
-                     agg_window={
-                         **self._gctx().agg_window(
-                             model._ops, tables=self.data.sect_idx,
-                             edges=int(dataset.graph.num_edges)),
-                         **self._gctx().attention_plan(
-                             model._ops, ell_idx=self.data.ell_idx,
-                             flat8_idx=next(iter(self.data.sect_idx),
-                                            None)),
-                         "memory_plan": self._plan},
-                     console=config.verbose)
+        with span("setup.manifest"):
+            # split-quality record: per-part padded shapes + halo rows
+            # + imbalance ratios, into the manifest (every run records
+            # the split it actually trained on) and the costmodel
+            # event stream
+            self._partition_stats = self._emit_partition_stats()
+            run_manifest(
+                config=self.config, dataset=dataset, model=model,
+                num_parts=num_parts,
+                extra={"modeled_step_bytes": self._modeled_bytes,
+                       "bd_occupancy": list(self.data.bd_occupancy),
+                       "partition": self._partition_stats},
+                agg_window={
+                    **self._gctx().agg_window(
+                        model._ops, tables=self.data.sect_idx,
+                        edges=int(dataset.graph.num_edges)),
+                    **self._gctx().attention_plan(
+                        model._ops, ell_idx=self.data.ell_idx,
+                        flat8_idx=next(iter(self.data.sect_idx), None)),
+                    "memory_plan": self._plan},
+                console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
         # annotate=True routes every phase span through
         # jax.profiler.TraceAnnotation so --profile-dir device
@@ -828,6 +866,8 @@ class DistributedTrainer:
         self.timer = EpochTimer(
             annotate=bool(config.profile_dir))
         self.metrics_log = MetricsLog(config.metrics_path)
+        # set-up's spans, cli.main's among them, as one batch
+        flush_spans("setup")
 
     def _build_data(self, pg) -> ShardedData:
         """Build + upload the sharded tables for ``pg`` with the
@@ -1152,6 +1192,8 @@ class DistributedTrainer:
              gain=None if gain is None else round(gain, 4),
              recompile=recompile, part_edges=pg2.part_edges,
              part_nodes=pg2.part_nodes)
+        # the rebuild's table and upload laps, as a batch of their own
+        flush_spans("repartition")
 
     # ---- step builders ----
 

@@ -316,7 +316,7 @@ def main(argv: Optional[List[str]] = None,
     checks placement and timing on the objects the run actually
     used."""
     args = parse_args(argv)
-    from ..obs.events import emit, install_excepthook
+    from ..obs.events import emit, install_excepthook, span
     # crash flight recorder: an unhandled exception dumps the last
     # telemetry window (obs/events.py ring buffer) before the
     # traceback — dead runs stop taking their evidence with them
@@ -518,29 +518,36 @@ def main(argv: Optional[List[str]] = None,
                   file=sys.stderr)
             return 2
 
-    if args.file:
-        ds = load_dataset(args.file, in_dim=layers[0],
-                          num_classes=layers[-1])
-    else:
-        ds = synthetic_dataset(512, 8, in_dim=layers[0],
-                               num_classes=layers[-1], seed=args.seed)
+    # set-up's phases go under spans (obs/events.py span) from here on;
+    # the trainer's constructor flushes them with its own as one batch
+    with span("setup.load") as s:
+        if args.file:
+            ds = load_dataset(args.file, in_dim=layers[0],
+                              num_classes=layers[-1])
+        else:
+            ds = synthetic_dataset(512, 8, in_dim=layers[0],
+                                   num_classes=layers[-1], seed=args.seed)
+        s.update(nodes=ds.graph.num_nodes, edges=ds.graph.num_edges)
     if args.model == "rgcn":
         from ..core.relations import derive_typed
         try:
-            ds.typed = derive_typed(ds.graph, node_types)
+            with span("setup.typed") as s:
+                ds.typed = derive_typed(ds.graph, node_types)
+                s.update(relations=len(ds.typed.relations),
+                         edges=ds.typed.num_edges)
         except ValueError as e:
             print(f"error: --node-types: {e}", file=sys.stderr)
             return 2
     perm = None
     if args.reorder != "none":
         from ..core.reorder import ORDERINGS, apply_vertex_order
-        t0 = time.time()
-        ds, perm = apply_vertex_order(
-            ds, ORDERINGS[args.reorder](ds.graph),
-            order_name=args.reorder)
+        with span("setup.reorder") as s:
+            ds, perm = apply_vertex_order(
+                ds, ORDERINGS[args.reorder](ds.graph),
+                order_name=args.reorder)
         emit("plan", f"reorder={args.reorder} applied in "
-             f"{time.time() - t0:.1f}s", reorder=args.reorder,
-             reorder_s=round(time.time() - t0, 2))
+             f"{s.ms / 1e3:.1f}s", reorder=args.reorder,
+             reorder_s=round(s.ms / 1e3, 2))
     # config echo, like gnn.cc:48-60 (the structured run manifest is
     # emitted by the trainer once the config is RESOLVED)
     emit("run", f"dataset={ds.name} V={ds.graph.num_nodes} "

@@ -22,7 +22,7 @@ from ..core.graph import Dataset
 from ..core.partition import padded_edge_list
 from ..core.relations import ORDER_PASSES
 from ..models.builder import GraphContext, Model
-from ..obs.events import emit
+from ..obs.events import emit, flush_spans, span
 from ..obs.metrics_registry import MetricsRegistry
 from ..obs.scopes import LOSS_SCOPE, OPT_SCOPE
 from ..ops.loss import perf_metrics, summarize_metrics
@@ -554,17 +554,21 @@ def modeled_plan(model: Model, dataset: Dataset, config: TrainConfig,
     configs too: the autopilot only runs under ``memory='auto'``, but
     the modeled-vs-actual delta is evidence on every run."""
     from ..core.memory import describe_plan
-    return describe_plan(
-        halo=config.halo if num_parts > 1 else "gather",
-        features=config.features, remat=config.remat,
-        **_plan_kwargs(model, dataset, config, num_parts))
+    with span("setup.resolve.plan"):
+        return describe_plan(
+            halo=config.halo if num_parts > 1 else "gather",
+            features=config.features, remat=config.remat,
+            **_plan_kwargs(model, dataset, config, num_parts))
 
 
 def resolve_symmetric(dataset: Dataset,
                       symmetric: Optional[bool]) -> bool:
     if symmetric is None:
         from ..core.graph import check_symmetric
-        return check_symmetric(dataset.graph)
+        with span("setup.symmetry",
+                  edges=dataset.graph.num_edges) as s:
+            s["symmetric"] = check_symmetric(dataset.graph)
+        return s["symmetric"]
     return symmetric
 
 
@@ -589,11 +593,12 @@ def apply_memory_autopilot(model: Model, dataset: Dataset,
     # because it must see the chosen halo) rewrites their impl away
     # from bdense.  charged_table_bytes (core/memory.py) is the ONE
     # home for the charge rule.
-    plan = choose_memory_plan(
-        hbm_bytes=config.hbm_bytes,
-        head_streamable=(model.streamable_head() is not None
-                         or model.streamable_agg_head() is not None),
-        **_plan_kwargs(model, dataset, config, num_parts))
+    with span("setup.resolve.plan"):
+        plan = choose_memory_plan(
+            hbm_bytes=config.hbm_bytes,
+            head_streamable=(model.streamable_head() is not None
+                             or model.streamable_agg_head() is not None),
+            **_plan_kwargs(model, dataset, config, num_parts))
     # a plan that doesn't fit echoes even with verbose off — running
     # anyway is a deliberate gamble the operator must see
     emit("plan", plan.echo(), console=config.verbose or not plan.fits,
@@ -646,10 +651,11 @@ def resolve_auto_impl_probed(graph, out_rows: Optional[int] = None, *,
     if (impl != "sectioned" or multiprocess
             or graph.num_edges < _BD.BDENSE_AUTO_MIN_EDGES):
         return impl, None
-    probe = _BD.probe_dense_frac(
-        graph.row_ptr, graph.col_idx, graph.num_nodes,
-        min_fill=bdense_min_fill, a_budget_bytes=bdense_a_budget,
-        group=bdense_group, return_census=True)
+    with span("setup.resolve.probe"):
+        probe = _BD.probe_dense_frac(
+            graph.row_ptr, graph.col_idx, graph.num_nodes,
+            min_fill=bdense_min_fill, a_budget_bytes=bdense_a_budget,
+            group=bdense_group, return_census=True)
     if probe is None:
         return impl, None
     frac, census = probe
@@ -770,17 +776,34 @@ def resolve_config(model: Model, dataset: Dataset, config: TrainConfig,
     asserts exactly that (tests/test_programspace.py).
 
     Returns ``(model, config, bd_census)``."""
-    model = resolve_fuse(model, config)
-    model, config = resolve_relations(model, dataset, config, num_parts)
-    out_rows = (-(-dataset.graph.num_nodes // num_parts)
-                if num_parts > 1 else None)
-    config, bd_census = resolve_auto_impl_early(
-        model, config, dataset.graph, out_rows=out_rows,
-        multiprocess=multiprocess)
-    config = apply_memory_autopilot(model, dataset, config,
-                                    num_parts=num_parts)
-    config = resolve_attention_impl(model, config, dataset)
+    with span("setup.resolve"):
+        model = resolve_fuse(model, config)
+        with span("setup.resolve.relations"):
+            model, config = resolve_relations(model, dataset, config,
+                                              num_parts)
+        out_rows = (-(-dataset.graph.num_nodes // num_parts)
+                    if num_parts > 1 else None)
+        config, bd_census = resolve_auto_impl_early(
+            model, config, dataset.graph, out_rows=out_rows,
+            multiprocess=multiprocess)
+        config = apply_memory_autopilot(model, dataset, config,
+                                        num_parts=num_parts)
+        config = resolve_attention_impl(model, config, dataset)
     return model, config, bd_census
+
+
+def upload(tree, what: str, put=jnp.asarray):
+    """Hand every array of the pytree ``tree`` to the device through
+    ``put``, under one ``setup.upload`` span that counts the bytes
+    handed over.  The span closes when ``put`` returns, which is when
+    the host buffer has been handed over, not when the device holds
+    it: it adds no sync."""
+    with span("setup.upload", what=what) as s:
+        def one(a):
+            out = put(a)
+            s["h2d_bytes"] += out.nbytes
+            return out
+        return jax.tree_util.tree_map(one, tree)
 
 
 def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
@@ -847,44 +870,64 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
         edge_src = np.zeros(1, dtype=np.int32)
         edge_dst = np.zeros(1, dtype=np.int32)
     else:
-        edge_src, edge_dst = padded_edge_list(g, multiple=chunk)
+        with span("setup.tables", table="edge_list", edges=g.num_edges):
+            edge_src, edge_dst = padded_edge_list(g, multiple=chunk)
     ell_row_id: tuple = ()
+
+    def sectioned(row_ptr, col_idx):
+        """The sectioned tables of one CSR and their weights: the
+        numpy build under ``setup.tables``, then the hand-over."""
+        from ..core.ell import default_section_rows, sectioned_from_graph
+        with span("setup.tables", table="sectioned",
+                  edges=col_idx.shape[0]) as s:
+            sect = sectioned_from_graph(
+                row_ptr, col_idx, g.num_nodes,
+                section_rows=default_section_rows(sect_u16),
+                sub_w=sect_sub_w)
+            if sect_u16:
+                sect = sect.with_idx_dtype(np.uint16)
+            s["sub_rows"] = sum(a.size for a in sect.sub_dst)
+        with span("setup.upload", what="tables") as s:
+            idx, sub_dst, meta = sect.as_jax()
+            s["h2d_bytes"] = sum(a.nbytes for a in idx + sub_dst)
+        w = ()
+        if fuse:
+            with span("setup.tables", table="sect_w"):
+                w = sect.weight_tables(d_np, d_np)
+            w = upload(tuple(w), "tables")
+        return idx, sub_dst, meta, w
+
     if aggr_impl == "ell":
         from ..core.ell import ell_from_graph
-        table = ell_from_graph(g.row_ptr, g.col_idx, g.num_nodes)
-        ell_idx = tuple(jnp.asarray(a[0]) for a in table.idx)
-        ell_row_pos = jnp.asarray(table.row_pos[0])
-        ell_row_id = tuple(jnp.asarray(a[0]) for a in table.row_id)
+        with span("setup.tables", table="ell", edges=g.num_edges) as s:
+            table = ell_from_graph(g.row_ptr, g.col_idx, g.num_nodes)
+            s["slots"] = sum(a.size for a in table.idx)
+        ell_idx, ell_row_pos, ell_row_id = upload(
+            (tuple(a[0] for a in table.idx), table.row_pos[0],
+             tuple(a[0] for a in table.row_id)), "tables")
         if fuse:
             from ..core.ell import ell_weight_tables
-            ell_w = tuple(
-                jnp.asarray(w[0]) for w in ell_weight_tables(
-                    table, d_np[None, :], d_np))
+            with span("setup.tables", table="ell_w"):
+                ell_w = ell_weight_tables(table, d_np[None, :], d_np)
+            ell_w = upload(tuple(w[0] for w in ell_w), "tables")
     elif aggr_impl == "sectioned":
-        from ..core.ell import default_section_rows, sectioned_from_graph
-        sect = sectioned_from_graph(
-            g.row_ptr, g.col_idx, g.num_nodes,
-            section_rows=default_section_rows(sect_u16),
-            sub_w=sect_sub_w)
-        if sect_u16:
-            sect = sect.with_idx_dtype(np.uint16)
-        sect_idx, sect_sub_dst, sect_meta = sect.as_jax()
-        if fuse:
-            sect_w = tuple(jnp.asarray(w)
-                           for w in sect.weight_tables(d_np, d_np))
+        sect_idx, sect_sub_dst, sect_meta, sect_w = sectioned(
+            g.row_ptr, g.col_idx)
     elif aggr_impl == "bdense":
         # block-dense MXU aggregation: dense [128,128] adjacency tiles
         # as uint8 multiplicity tables, scattered residual through the
         # sectioned gather (ops/blockdense.py — wins when the vertex
         # order concentrates edges into tiles; the occupancy echo
         # makes a mis-fit choice visible)
-        from ..core.ell import default_section_rows, sectioned_from_graph
         from ..ops.blockdense import BLOCK, plan_blocks_packed
-        plan = plan_blocks_packed(g.row_ptr, g.col_idx, g.num_nodes,
-                                  min_fill=bdense_min_fill,
-                                  a_budget_bytes=bdense_a_budget,
-                                  group=bdense_group,
-                                  census=bd_census)
+        with span("setup.tables", table="bdense",
+                  edges=g.num_edges) as s:
+            plan = plan_blocks_packed(g.row_ptr, g.col_idx, g.num_nodes,
+                                      min_fill=bdense_min_fill,
+                                      a_budget_bytes=bdense_a_budget,
+                                      group=bdense_group,
+                                      census=bd_census)
+            s["blocks"] = plan.n_blocks
         packed = plan.a_blocks.shape[-1] == BLOCK // 2
         occ = plan.occupancy()
         if plan.n_blocks:
@@ -894,9 +937,8 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
                  f"{1 - occ['dense_frac']:.0%} via sectioned"
                  f"{', A u4-packed' if packed else ''})",
                  console=verbose, packed=packed, **occ)
-            bd_a = jnp.asarray(plan.a_blocks)
-            bd_src = jnp.asarray(plan.src_blk)
-            bd_dst = jnp.asarray(plan.dst_blk)
+            bd_a, bd_src, bd_dst = upload(
+                (plan.a_blocks, plan.src_blk, plan.dst_blk), "tables")
             bd_vpad = plan.vpad
         else:
             # no tile qualifies: running the zero-block kernel every
@@ -912,20 +954,12 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
             dd[:g.num_nodes] = d_np
             ds = np.zeros(plan.src_vpad, np.float32)
             ds[:g.num_nodes] = d_np
-            bd_scale = (jnp.asarray(dd), jnp.asarray(ds))
+            bd_scale = upload((dd, ds), "tables")
         if plan.res_col.shape[0]:
             # same tuning knobs as the 'sectioned' branch — bdense's
             # residual must not silently drop user-selected config
-            sect = sectioned_from_graph(
-                plan.res_row_ptr, plan.res_col, g.num_nodes,
-                section_rows=default_section_rows(sect_u16),
-                sub_w=sect_sub_w)
-            if sect_u16:
-                sect = sect.with_idx_dtype(np.uint16)
-            sect_idx, sect_sub_dst, sect_meta = sect.as_jax()
-            if fuse:
-                sect_w = tuple(jnp.asarray(w)
-                               for w in sect.weight_tables(d_np, d_np))
+            sect_idx, sect_sub_dst, sect_meta, sect_w = sectioned(
+                plan.res_row_ptr, plan.res_col)
     elif aggr_impl in ("attn_flat8", "flat_sum"):
         # the uniform flat layout: ONE section spanning all sources
         # (global ids, dummy == num_nodes == the appended zero row),
@@ -936,20 +970,26 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
         # FLAT_SEG_ROWS bounds the per-chunk transient [seg, 8, F] at
         # 64 MiB for F=256 fp32.
         from ..core.ell import flat_sum_from_graph
-        sect = flat_sum_from_graph(g.row_ptr, g.col_idx, g.num_nodes)
-        flat8_idx = jnp.asarray(sect.idx[0])
-        flat8_dst = jnp.asarray(sect.sub_dst[0])
+        with span("setup.tables", table="flat_sum",
+                  edges=g.num_edges) as s:
+            sect = flat_sum_from_graph(g.row_ptr, g.col_idx, g.num_nodes)
+            s["sub_rows"] = sect.sub_dst[0].size
+        flat8_idx, flat8_dst = upload((sect.idx[0], sect.sub_dst[0]),
+                                      "tables")
         if aggr_impl == "flat_sum":
             flat8_win = sect.win_rows[0]
             if fuse:
                 # baked D^-1/2 A D^-1/2 entries of the single section
                 # — zero runtime normalization on the fused flat path
-                flat8_w = jnp.asarray(
-                    sect.weight_tables(d_np, d_np)[0])
+                with span("setup.tables", table="flat_sum_w"):
+                    flat8_w = sect.weight_tables(d_np, d_np)[0]
+                flat8_w = upload(flat8_w, "tables")
+    edge_src, edge_dst, in_degree = upload(
+        (edge_src, edge_dst, g.in_degree), "tables")
     return GraphContext(
-        edge_src=jnp.asarray(edge_src),
-        edge_dst=jnp.asarray(edge_dst),
-        in_degree=jnp.asarray(g.in_degree),
+        edge_src=edge_src,
+        edge_dst=edge_dst,
+        in_degree=in_degree,
         num_rows=g.num_nodes,
         gathered_rows=g.num_nodes,
         aggr_impl=aggr_impl,
@@ -992,33 +1032,41 @@ def _relation_context(dataset: Dataset, aggr_impl: str, tables,
     g = dataset.graph
     idx, dst, w, meta = [], [], [], []
     for order, rels in tables:
-        typed = (dataset.typed if rels is None
-                 else dataset.typed.restrict(rels))
+        typed, cut = dataset.typed, "whole"
+        if rels is not None:
+            cut = "cut"
+            with span("setup.tables", table="rel.cut.restrict"):
+                typed = typed.restrict(rels)
         fwd, bwd = ORDER_PASSES[order]
         for name in ((fwd,) if aggr_impl == "segment" else (fwd, bwd)):
-            row_ptr, col, n_into, n_out = typed.pass_csr(name)
-            if aggr_impl == "segment":
-                into = np.repeat(np.arange(n_into, dtype=np.int32),
-                                 np.diff(row_ptr))
-                t_idx, t_dst, win = col, into, 0
-                t_w = typed.slot_weights(name, col[:, None], into)[:, 0]
-            else:
-                sect = flat_sum_from_graph(row_ptr, col, n_into,
-                                           src_rows=n_out)
-                t_idx, t_dst = sect.idx[0], sect.sub_dst[0]
-                win = sect.win_rows[0]
-                # slot-major at rest: [n_chunks, 8 * seg_rows]
-                n = t_idx.shape[0]
-                t_w = typed.slot_weights(name, t_idx, t_dst).transpose(
-                    0, 2, 1).reshape(n, -1)
-                t_idx = t_idx.transpose(0, 2, 1).reshape(n, -1)
-            idx.append(jnp.asarray(t_idx))
-            dst.append(jnp.asarray(t_dst))
-            w.append(jnp.asarray(t_w))
+            with span("setup.tables", table=f"rel.{cut}.{name}") as s:
+                row_ptr, col, n_into, n_out = typed.pass_csr(name)
+                if aggr_impl == "segment":
+                    into = np.repeat(np.arange(n_into, dtype=np.int32),
+                                     np.diff(row_ptr))
+                    t_idx, t_dst, win = col, into, 0
+                    t_w = typed.slot_weights(
+                        name, col[:, None], into)[:, 0]
+                else:
+                    sect = flat_sum_from_graph(row_ptr, col, n_into,
+                                               src_rows=n_out)
+                    t_idx, t_dst = sect.idx[0], sect.sub_dst[0]
+                    win = sect.win_rows[0]
+                    # slot-major at rest: [n_chunks, 8 * seg_rows]
+                    n = t_idx.shape[0]
+                    t_w = typed.slot_weights(
+                        name, t_idx, t_dst).transpose(
+                        0, 2, 1).reshape(n, -1)
+                    t_idx = t_idx.transpose(0, 2, 1).reshape(n, -1)
+                s.update(edges=col.shape[0], sub_rows=t_dst.size)
+            t_idx, t_dst, t_w = upload((t_idx, t_dst, t_w), "tables")
+            idx.append(t_idx)
+            dst.append(t_dst)
+            w.append(t_w)
             meta.append((name, n_into, n_out, win, rels))
     return GraphContext(
         edge_src=jnp.zeros(1, jnp.int32), edge_dst=jnp.zeros(1, jnp.int32),
-        in_degree=jnp.asarray(g.in_degree), num_rows=g.num_nodes,
+        in_degree=upload(g.in_degree, "tables"), num_rows=g.num_nodes,
         gathered_rows=g.num_nodes, aggr_impl=aggr_impl, symmetric=True,
         head_chunk=head_chunk, rel_idx=tuple(idx), rel_dst=tuple(dst),
         rel_w=tuple(w), rel_meta=tuple(meta))
@@ -1070,18 +1118,15 @@ class Trainer:
         # observability: edge count for edges/sec and the memory
         # model's estimate the compile observer checks XLA against
         self._obs_edges = int(dataset.graph.num_edges)
-        self._plan = modeled_plan(model, dataset, config)
+        with span("setup.resolve"):
+            self._plan = modeled_plan(model, dataset, config)
         self._modeled_bytes = self._plan["est_bytes"]
         # dataset identity for the checkpoint config fingerprint
         # (utils/checkpoint.trainer_fingerprint strict half)
         self._fp_dataset = {"V": int(dataset.graph.num_nodes),
                             "E": int(dataset.graph.num_edges)}
-        self.labels = jnp.asarray(dataset.labels)
-        self.mask = jnp.asarray(dataset.mask)
-        key = jax.random.PRNGKey(config.seed)
-        self.key, init_key = jax.random.split(key)
-        self.params = model.init_params(init_key, dtype=config.dtype)
-        self.opt_state = adam_init(self.params)
+        self.labels = upload(dataset.labels, "labels")
+        self.mask = upload(dataset.mask, "mask")
         self.adam_cfg = AdamConfig(weight_decay=config.weight_decay)
         # (parts, model) mesh knob: a single-device Trainer hosts only
         # the model axis (parts is always 1 here — partitioning is the
@@ -1094,11 +1139,20 @@ class Trainer:
         _, self._mesh_model = resolve_mesh(
             config, num_parts=1, num_devices=len(jax.devices()))
         self.mesh = None
-        if self._mesh_model > 1:
-            from ..parallel.distributed import make_mesh, put_replicated
-            self.mesh = make_mesh(1, model=self._mesh_model)
-            self.params = put_replicated(self.params, self.mesh)
-            self.opt_state = put_replicated(self.opt_state, self.mesh)
+        with span("setup.params") as s:
+            key = jax.random.PRNGKey(config.seed)
+            self.key, init_key = jax.random.split(key)
+            self.params = model.init_params(init_key, dtype=config.dtype)
+            self.opt_state = adam_init(self.params)
+            if self._mesh_model > 1:
+                from ..parallel.distributed import (make_mesh,
+                                                    put_replicated)
+                self.mesh = make_mesh(1, model=self._mesh_model)
+                self.params = put_replicated(self.params, self.mesh)
+                self.opt_state = put_replicated(self.opt_state,
+                                                self.mesh)
+            s["param_bytes"] = sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(self.params))
         self._head = None
         self._head_chunk = resolve_head_chunk(
             config, dataset.graph.num_nodes)
@@ -1130,44 +1184,48 @@ class Trainer:
             from ..core.streaming import StreamedHead
             depth = resolve_prefetch(config)
             self._head = StreamedHead(rate, prefetch=depth)
-            feats_np = np.asarray(dataset.features)
-            if prefix_ops is not None:
-                from ..core.streaming import stream_prefix_to_host
-                feats_np = stream_prefix_to_host(
-                    dataset.graph, prefix_ops, feats_np,
-                    prefetch=depth)
-            # host copy in the COMPUTE dtype (ml_dtypes bf16 under
-            # mixed): device_put then ships 2-byte blocks — the
-            # host-link transfer is this tier's dominant per-epoch
-            # cost, so staging fp32 and casting on device would
-            # forfeit half the mode's bandwidth win
-            self.feats_host = np.ascontiguousarray(
-                feats_np.astype(jnp.dtype(self.compute), copy=False))
+            with span("setup.tables", table="feats_host"):
+                feats_np = np.asarray(dataset.features)
+                if prefix_ops is not None:
+                    from ..core.streaming import stream_prefix_to_host
+                    feats_np = stream_prefix_to_host(
+                        dataset.graph, prefix_ops, feats_np,
+                        prefetch=depth)
+                # host copy in the COMPUTE dtype (ml_dtypes bf16 under
+                # mixed): device_put then ships 2-byte blocks — the
+                # host-link transfer is this tier's dominant per-epoch
+                # cost, so staging fp32 and casting on device would
+                # forfeit half the mode's bandwidth win
+                self.feats_host = np.ascontiguousarray(
+                    feats_np.astype(jnp.dtype(self.compute), copy=False))
             self.feats = None
             from ..obs.compile_watch import ObservedJit
-            # y (arg 1) is donated: the projected [V, H] activation is
-            # rebuilt by the streamed head every step and never read
-            # after this call — undonated it doubled its residency
-            # across the tail (found by roc-lint jaxpr-non-donated)
-            self._tail_grad = ObservedJit(
-                self._tail_grad_impl, name="tail_grad",
-                donate_argnums=(1,),
-                modeled_bytes=self._modeled_bytes,
-                verbose=config.verbose)
-            self._tail_eval = ObservedJit(self._tail_eval_impl,
-                                          name="tail_eval",
-                                          verbose=config.verbose)
-            # grads (arg 2) are donated too: they are rebuilt every
-            # step and never read after the update — undonated they'd
-            # hold a param-sized buffer alive across the whole apply
-            # (found by roc-lint jaxpr-non-donated)
-            self._apply_update = ObservedJit(self._apply_update_impl,
-                                             name="apply_update",
-                                             donate_argnums=(0, 1, 2),
-                                             verbose=config.verbose)
+            with span("setup.steps"):
+                # y (arg 1) is donated: the projected [V, H] activation
+                # is rebuilt by the streamed head every step and never
+                # read after this call — undonated it doubled its
+                # residency across the tail (found by roc-lint
+                # jaxpr-non-donated)
+                self._tail_grad = ObservedJit(
+                    self._tail_grad_impl, name="tail_grad",
+                    donate_argnums=(1,),
+                    modeled_bytes=self._modeled_bytes,
+                    verbose=config.verbose)
+                self._tail_eval = ObservedJit(self._tail_eval_impl,
+                                              name="tail_eval",
+                                              verbose=config.verbose)
+                # grads (arg 2) are donated too: they are rebuilt every
+                # step and never read after the update — undonated
+                # they'd hold a param-sized buffer alive across the
+                # whole apply (found by roc-lint jaxpr-non-donated)
+                self._apply_update = ObservedJit(
+                    self._apply_update_impl, name="apply_update",
+                    donate_argnums=(0, 1, 2), verbose=config.verbose)
         else:
-            self.feats = jnp.asarray(model_features(model, dataset),
-                                     dtype=self.compute)
+            with span("setup.upload", what="features") as s:
+                self.feats = jnp.asarray(model_features(model, dataset),
+                                         dtype=self.compute)
+                s["h2d_bytes"] = self.feats.nbytes
         if self._head is not None and not any(
                 op.kind in ("scatter_gather", "gat", "fused_aggregate")
                 for op in self._tail_model._ops):
@@ -1178,7 +1236,7 @@ class Trainer:
             self.gctx = GraphContext(
                 edge_src=jnp.zeros(1, jnp.int32),
                 edge_dst=jnp.zeros(1, jnp.int32),
-                in_degree=jnp.asarray(g.in_degree),
+                in_degree=upload(g.in_degree, "tables"),
                 num_rows=g.num_nodes, gathered_rows=g.num_nodes,
                 aggr_impl="segment",
                 head_chunk=self._head_chunk,
@@ -1216,33 +1274,36 @@ class Trainer:
         # ObservedJit records lower/compile wall time + XLA cost/memory
         # introspection on the first call (obs/compile_watch.py).
         from ..obs.compile_watch import ObservedJit
-        self._train_step = ObservedJit(self._train_step_impl,
-                                       name="train_step",
-                                       donate_argnums=(0, 1),
-                                       modeled_bytes=self._modeled_bytes,
-                                       verbose=config.verbose)
-        # eval and predict share ONE compiled program: the eval step
-        # returns (metrics, logits) — the logits already exist inside
-        # the step, so outputting them costs one [V, C] buffer write
-        # per eval while removing a whole compiled program from every
-        # config's space (program-space consolidation, ISSUE 7;
-        # evaluate() fetches only the metrics leaf)
-        self._eval_step = ObservedJit(self._eval_step_impl,
-                                      name="eval_step",
-                                      verbose=config.verbose)
+        with span("setup.steps"):
+            self._train_step = ObservedJit(
+                self._train_step_impl, name="train_step",
+                donate_argnums=(0, 1),
+                modeled_bytes=self._modeled_bytes,
+                verbose=config.verbose)
+            # eval and predict share ONE compiled program: the eval
+            # step returns (metrics, logits) — the logits already exist
+            # inside the step, so outputting them costs one [V, C]
+            # buffer write per eval while removing a whole compiled
+            # program from every config's space (program-space
+            # consolidation, ISSUE 7; evaluate() fetches only the
+            # metrics leaf)
+            self._eval_step = ObservedJit(self._eval_step_impl,
+                                          name="eval_step",
+                                          verbose=config.verbose)
         from ..obs.manifest import run_manifest
-        run_manifest(config=self.config, dataset=dataset, model=model,
-                     extra={"modeled_step_bytes": self._modeled_bytes},
-                     agg_window={
-                         **self.gctx.agg_window(
-                             model._ops,
-                             edges=int(dataset.graph.num_edges)),
-                         **self.gctx.attention_plan(model._ops),
-                         **self.gctx.relation_plan(
-                             model._ops, dataset.typed,
-                             model.loss_cut()._ops),
-                         "memory_plan": self._plan},
-                     console=config.verbose)
+        with span("setup.manifest"):
+            run_manifest(
+                config=self.config, dataset=dataset, model=model,
+                extra={"modeled_step_bytes": self._modeled_bytes},
+                agg_window={
+                    **self.gctx.agg_window(
+                        model._ops, edges=int(dataset.graph.num_edges)),
+                    **self.gctx.attention_plan(model._ops),
+                    **self.gctx.relation_plan(
+                        model._ops, dataset.typed,
+                        model.loss_cut()._ops),
+                    "memory_plan": self._plan},
+                console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
         # annotate=True routes every phase span through
         # jax.profiler.TraceAnnotation so a --profile-dir trace's host
@@ -1250,6 +1311,8 @@ class Trainer:
         self.timer = EpochTimer(
             annotate=bool(config.profile_dir))
         self.metrics_log = MetricsLog(config.metrics_path)
+        # set-up's spans, cli.main's among them, as one batch
+        flush_spans("setup")
 
     def _train_step_impl(self, params, opt_state, key, lr, feats,
                          labels, mask, gctx):
