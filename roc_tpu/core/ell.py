@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass
-from typing import Optional, Sequence, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -296,7 +296,17 @@ class SectionedEll:
       shapes carry both; no field repeats them;
     - ``win_rows[s]``: how tall a chunk's run of real destinations can
       be in this section (:func:`chunk_window_rows` over ``sub_dst``;
-      stacked tables: over every part, SPMD shapes must agree).
+      stacked tables: over every part, SPMD shapes must agree);
+    - ``bands[s]``: beside it, how far apart the destinations of
+      neighbouring sub-rows can lie: one ``(tile, band)`` pair per tile
+      height of :data:`SEG_SUM_TILES` that divides the section's
+      ``seg_rows`` — no run of ``tile`` consecutive sub-rows of a chunk
+      reaches more than ``band`` destination rows
+      (:func:`chunk_band_rows`; stacked tables: the max over parts).
+      A section of a dense graph holds many sub-rows a destination
+      row and its bands are a fraction of their tiles; that is what
+      lets the scan sum a row's sub-rows on the MXU before the carry
+      sees them (ops/aggregate.py ``scan_seg_sum``).
 
     The aggregation is a ``lax.scan`` over chunks carrying the output:
     gather-sum from the section slice, then a sorted scatter-add of the
@@ -323,25 +333,29 @@ class SectionedEll:
     sub_dst: Tuple[np.ndarray, ...]
     sub_w: int = 8
     win_rows: Tuple[int, ...] = ()
+    bands: Tuple[Tuple[Tuple[int, int], ...], ...] = ()
 
     def __post_init__(self):
         # derived from the table, never configured: every builder
-        # (native, numpy, stacked) gets it from the one pass here
+        # (native, numpy, stacked) gets both from the one pass here
         if not self.win_rows:
             self.win_rows = tuple(chunk_window_rows(d, self.num_rows)
                                   for d in self.sub_dst)
+        if not self.bands:
+            self.bands = tuple(chunk_bands(d, self.num_rows)
+                               for d in self.sub_dst)
 
     @property
     def padded_edges(self) -> int:
         return sum(a.size for a in self.idx)
 
     @property
-    def meta(self) -> Tuple[Tuple[int, int, int], ...]:
-        """Static ``(start, size, win_rows)`` per section — the
+    def meta(self) -> Tuple[Tuple[Any, ...], ...]:
+        """Static ``(start, size, win_rows, bands)`` per section — the
         ``sect_meta`` of :func:`roc_tpu.ops.aggregate.
         aggregate_ell_sect`."""
         return tuple(zip(self.sec_starts, self.sec_sizes,
-                         self.win_rows))
+                         self.win_rows, self.bands))
 
     def as_jax(self):
         """(idx, sub_dst, meta) in the calling convention of
@@ -420,9 +434,81 @@ def chunk_window_rows(sub_dst: np.ndarray, num_rows: int) -> int:
     destinations — their partials are exactly zero (dummy source row,
     weight 0) — and an all-padding chunk needs no rows."""
     sub_dst = np.asarray(sub_dst)
-    last = np.where(sub_dst < num_rows, sub_dst, -1).max(axis=-1)
-    span = int(np.maximum(last - sub_dst[..., 0] + 1, 0).max())
+    # a chunk is its own single tile
+    first, last = _tile_spans(sub_dst, num_rows, sub_dst.shape[-1])
+    span = int(np.maximum(last - first + 1, 0).max())
     return -(-max(span, 1) // WIN_ROWS_MULTIPLE) * WIN_ROWS_MULTIPLE
+
+
+# Tile heights the chunk scan's segmented sum may cut a chunk into
+# (ops/aggregate.py scan_seg_sum picks among those that divide the
+# chunk's height), and what a band rounds up to: the bf16 sublane
+# packing twice over, coarse enough that graphs of one shape share a
+# compiled scan and fine enough that a band of 163 rows runs as one
+# of 192, not 256.
+SEG_SUM_TILES = (256, 512, 1024, 2048, 4096, 8192)
+BAND_ROWS_MULTIPLE = 32
+
+
+def _tile_spans(sub_dst: np.ndarray, num_rows: int, tile: int):
+    """``(first, last)`` per tile of ``tile`` consecutive sub-rows:
+    the tile's first destination and its last real one (-1: none)."""
+    t = sub_dst.reshape(*sub_dst.shape[:-1], -1, tile)
+    # ascending, padding at the tail: a tile's last sub-row is its
+    # last destination unless the tile holds padding (a chunk's tail)
+    last = t[..., -1].copy()
+    pad = last >= num_rows
+    if pad.any():
+        tp = t[pad]
+        last[pad] = np.where(tp < num_rows, tp, -1).max(axis=-1)
+    return t[..., 0], last
+
+
+def _band(first: np.ndarray, last: np.ndarray) -> int:
+    span = int(np.maximum(last - first + 1, 0).max()) if first.size else 0
+    return -(-max(span, 1) // BAND_ROWS_MULTIPLE) * BAND_ROWS_MULTIPLE
+
+
+def chunk_band_rows(sub_dst: np.ndarray, num_rows: int, tile: int) -> int:
+    """Rows the destinations of ``tile`` consecutive sub-rows of a
+    chunk can span in ``sub_dst`` (``[..., n_chunks, seg_rows]``,
+    ascending within a chunk, chunk padding == ``num_rows`` at the
+    tail; ``tile`` divides ``seg_rows``): the largest ``last real dst
+    - first dst + 1`` over every tile of every chunk (and part),
+    rounded up to :data:`BAND_ROWS_MULTIPLE`.  Padding is no
+    destination, and an all-padding tile spans nothing — the same
+    convention as :func:`chunk_window_rows`, one level down."""
+    return _band(*_tile_spans(np.asarray(sub_dst), num_rows, tile))
+
+
+def chunk_bands(sub_dst: np.ndarray, num_rows: int
+                ) -> Tuple[Tuple[int, int], ...]:
+    """``(tile, chunk_band_rows(sub_dst, num_rows, tile))`` for every
+    tile of :data:`SEG_SUM_TILES` that divides the chunk height, in
+    ONE pass over ``sub_dst``: the smallest tile's spans are read off
+    the table, each taller tile's are its two halves' (the first
+    half's first row, the later of the two last ones)."""
+    sub_dst = np.asarray(sub_dst)
+    seg = sub_dst.shape[-1]
+    tiles = [t for t in SEG_SUM_TILES if seg % t == 0]
+    out = []
+    for i, t in enumerate(tiles):
+        if i == 0:
+            first, last = _tile_spans(sub_dst, num_rows, t)
+        else:
+            first = first[..., ::2]
+            last = np.maximum(last[..., ::2], last[..., 1::2])
+        out.append((t, _band(first, last)))
+    return tuple(out)
+
+
+def max_bands(per_part) -> Tuple[Tuple[int, int], ...]:
+    """One section's bands over its parts: tables stacked for SPMD
+    share a chunk plan, hence their tiles, and must compile one
+    shape, so each tile takes the widest band any part needs (as
+    ``win_rows`` does)."""
+    return tuple((tb[0][0], max(b for _, b in tb))
+                 for tb in zip(*per_part))
 
 
 # Uniform flat-sum layout (aggregate_flat_sum): the cap on a chunk's
@@ -932,4 +1018,6 @@ def sectioned_from_padded_parts(part_row_ptr: np.ndarray,
                       for s in range(len(first.sub_dst))),
         sub_w=sub_w,
         win_rows=tuple(max(pp.win_rows[s] for pp in per_part)
-                       for s in range(len(first.win_rows))))
+                       for s in range(len(first.win_rows))),
+        bands=tuple(max_bands([pp.bands[s] for pp in per_part])
+                    for s in range(len(first.bands))))

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.ell import agg_lane_width
+from ..core.ell import LANE_WIDTH, agg_lane_width
 from ..core.memory import remat_segments
 from ..core.relations import ORDER_PASSES, TRANSFORM_FIRST
 from ..obs.scopes import (ATTN_SCORES_SCOPE, EMBED_SCOPE, HALO_SCOPE,
@@ -38,7 +38,8 @@ from ..parallel import PARTS_AXIS
 from ..ops.aggregate import (aggregate_ell, aggregate_ell_max,
                              aggregate_ell_sect, aggregate_flat_max,
                              aggregate_flat_sum, aggregate_segment,
-                             scan_window_rows)
+                             scan_seg_sum, scan_window_rows,
+                             seg_sum_updates)
 from ..ops.dense import AC_MODE_NONE, AC_MODE_RELU, AC_MODE_SIGMOID
 from ..ops.loss import masked_softmax_cross_entropy
 from ..ops.norm import indegree_norm
@@ -104,7 +105,7 @@ class GraphContext:
     ell_row_id: Tuple[jax.Array, ...] = ()
     # Sectioned layout (aggr_impl == "sectioned"): per-section
     # [n_chunks, seg_rows, 8] sub-row tables + [n_chunks, seg_rows]
-    # output rows, with static (start, size, win_rows) metadata
+    # output rows, with static (start, size, win_rows, bands) metadata
     # (core/ell.py SectionedEll.meta — measured 2.3x over "ell" at
     # Reddit scale)
     sect_idx: Tuple[jax.Array, ...] = ()
@@ -121,12 +122,14 @@ class GraphContext:
     # for the flat_sum form (shape mirrors flat8_idx; None = derive d
     # from in_degree and pre/post-scale in-op).  flat8_win is the
     # table's static destination-window height for the sum scan
-    # (SectionedEll.win_rows[0]; 0 = the whole carry), set for
-    # "flat_sum" only — attention and MAX never read it.
+    # (SectionedEll.win_rows[0]; 0 = the whole carry) and flat8_bands
+    # its tile bands (SectionedEll.bands[0]), set for "flat_sum" only
+    # — attention and MAX never read them.
     flat8_idx: Optional[jax.Array] = None
     flat8_dst: Optional[jax.Array] = None
     flat8_w: Optional[jax.Array] = None
     flat8_win: int = 0
+    flat8_bands: Tuple[Tuple[int, int], ...] = ()
     # Block-dense MXU layout (aggr_impl == "bdense"): dense [128,128]
     # adjacency tiles as uint8 multiplicity tables + tile ids, with
     # the residual (scattered) edges in the sect_* sectioned tables
@@ -168,9 +171,9 @@ class GraphContext:
     axis_name: str = PARTS_AXIS
     # Typed graph (core/relations.py): one entry per relation pass the
     # resolved orders run — ``rel_meta`` static ``(pass name, rows
-    # summed into, rows gathered out of, win_rows, rels)``, ``rels``
-    # None for the table of every relation or the relation indices a
-    # cut layer sums (Model.loss_cut); under 'flat_sum'
+    # summed into, rows gathered out of, win_rows, rels, bands)``,
+    # ``rels`` None for the table of every relation or the relation
+    # indices a cut layer sums (Model.loss_cut); under 'flat_sum'
     # ``rel_idx [n_chunks, 8 * seg]`` (slot-major: unpadded at rest,
     # ops/aggregate.py _scan_window_sum) / ``rel_dst [n_chunks, seg]``
     # / ``rel_w`` fp32 like ``rel_idx`` (each slot's ``1 / deg_r(v)``),
@@ -196,14 +199,25 @@ class GraphContext:
         section of the sum scan's index tables, and ``agg_slot_fill``,
         the graph's ``edges`` stored edges over the slots a pass
         gathers (None where the tables hold another edge set:
-        ``bdense``'s residual).  The distributed trainer, whose tables
-        live outside its context, hands them in as ``tables``
-        (stacked: the trailing axes are read, every part's slots
-        counted)."""
+        ``bdense``'s residual).  And how far the scan's segmented sum
+        engaged (``ops/aggregate.py scan_seg_sum``), one entry per
+        table the sum scan walks — the sections, or a typed graph's
+        relation passes: ``agg_seg_sum``, the ``[T, B]`` a chunk step
+        sums its partials at on the MXU or None where it scatters
+        them one by one, and ``agg_carry_updates``, ``[before,
+        after]`` rows a pass adds into the carry's window without and
+        with it.  Both are read at the widest sum op's width (a
+        narrower op may pick a taller tile of the same table).  The
+        distributed trainer, whose tables live outside its context,
+        hands them in as ``tables`` (stacked: the trailing axes are
+        read, every part's slots counted)."""
         carry = self.num_rows + 1
         wins = [m[2] for m in self.sect_meta if len(m) > 2]
+        # a hand-made sect_meta may stop at the window: no bands known
+        bands = [m[3] if len(m) > 3 else () for m in self.sect_meta
+                 if len(m) > 2]
         if self.flat8_win:
-            wins = [self.flat8_win]
+            wins, bands = [self.flat8_win], [self.flat8_bands]
         pads = [[i, op.dim, self._lane_width(op.dim)]
                 for i, op in enumerate(ops)
                 if op.kind == "fused_aggregate"
@@ -215,10 +229,22 @@ class GraphContext:
         if not wins:
             tables = ()
         slots = sum(int(t.size) for t in tables)
+        width = max((p[2] for p in pads), default=LANE_WIDTH)
+        # (n_chunks, seg_rows, window, bands) of every scanned table
+        scans = [(*t.shape[-3:-1], scan_window_rows(w, carry), b)
+                 for t, w, b in zip(tables, wins, bands)]
+        scans += [(*d.shape, scan_window_rows(m[3], m[1] + 1), m[5])
+                  for m, d in zip(self.rel_meta, self.rel_dst)
+                  if self.aggr_impl == "flat_sum"]
+        segs = [scan_seg_sum(seg, w, b, width) for _, seg, w, b in scans]
         return {"agg_window_rows": [scan_window_rows(w, carry)
                                     for w in wins],
                 "agg_carry_rows": carry if wins else None,
                 "agg_lane_pad": pads,
+                "agg_seg_sum": [s and list(s) for s in segs],
+                "agg_carry_updates": [
+                    seg_sum_updates(n, seg, s)
+                    for (n, seg, _, _), s in zip(scans, segs)],
                 "agg_chunk_rows": [list(t.shape[-3:-1])
                                    for t in tables],
                 "agg_slot_fill": (
@@ -329,7 +355,8 @@ class GraphContext:
         if self.aggr_impl == "flat_sum":
             return aggregate_flat_sum(full, self.flat8_idx,
                                       self.flat8_dst, self.num_rows,
-                                      win_rows=self.flat8_win)
+                                      win_rows=self.flat8_win,
+                                      bands=self.flat8_bands)
         if self.aggr_impl == "bdense":
             from ..ops.blockdense import aggregate_block_dense
             out = None
@@ -418,7 +445,8 @@ class GraphContext:
             return aggregate_flat_sum(full, self.flat8_idx,
                                       self.flat8_dst, self.num_rows,
                                       flat_w=self.flat8_w,
-                                      win_rows=self.flat8_win)
+                                      win_rows=self.flat8_win,
+                                      bands=self.flat8_bands)
         if self.aggr_impl == "bdense" and self.bd_scale:
             from ..ops.blockdense import aggregate_block_dense
             full = self._gathered_with_zero(x)
@@ -489,7 +517,7 @@ class GraphContext:
         ``rels`` (None: all)."""
         k = next(i for i, m in enumerate(self.rel_meta)
                  if m[0] == name and m[4] == rels)
-        _, out_rows, _, win, _ = self.rel_meta[k]
+        _, out_rows, _, win, _, bands = self.rel_meta[k]
         if self.aggr_impl == "segment":
             g = x[self.rel_idx[k]] * self.rel_w[k][:, None]
             return jax.ops.segment_sum(
@@ -499,7 +527,8 @@ class GraphContext:
         return aggregate_flat_sum(
             jnp.concatenate([x, zero], axis=0), self.rel_idx[k],
             self.rel_dst[k], out_rows, flat_w=self.rel_w[k],
-            win_rows=win, weights_fp32=True, slot_major=True)
+            win_rows=win, bands=bands, weights_fp32=True,
+            slot_major=True)
 
     def rel_aggregate(self, x: jax.Array, order: str,
                       rels=None) -> jax.Array:
@@ -738,14 +767,16 @@ def _gctx_flatten(g: GraphContext):
     aux = (g.num_rows, g.gathered_rows, g.gather_features, g.psum,
            g.aggr_impl, g.symmetric, g.halo, g.axis_name,
            g.sect_meta, g.bd_vpad, g.bd_src_vpad, g.bd_group,
-           g.ring_overlap, g.head_chunk, g.flat8_win, g.rel_meta)
+           g.ring_overlap, g.head_chunk, g.flat8_win, g.flat8_bands,
+           g.rel_meta)
     return children, aux
 
 
 def _gctx_unflatten(aux, children):
     (num_rows, gathered_rows, gather_features, psum, aggr_impl,
      symmetric, halo, axis_name, sect_meta, bd_vpad, bd_src_vpad,
-     bd_group, ring_overlap, head_chunk, flat8_win, rel_meta) = aux
+     bd_group, ring_overlap, head_chunk, flat8_win, flat8_bands,
+     rel_meta) = aux
     (edge_src, edge_dst, in_degree, ell_idx, ell_row_pos, ring_idx,
      sect_idx, sect_sub_dst, ell_row_id, flat8_idx,
      flat8_dst, flat8_w, bd_a, bd_src, bd_dst, ell_w, sect_w, ring_w,
@@ -760,6 +791,7 @@ def _gctx_unflatten(aux, children):
         sect_sub_dst=sect_sub_dst, sect_meta=sect_meta,
         ell_row_id=ell_row_id, flat8_idx=flat8_idx,
         flat8_dst=flat8_dst, flat8_w=flat8_w, flat8_win=flat8_win,
+        flat8_bands=flat8_bands,
         bd_a=bd_a, bd_src=bd_src, bd_dst=bd_dst, bd_vpad=bd_vpad,
         bd_src_vpad=bd_src_vpad,
         bd_group=bd_group, ring_overlap=ring_overlap,

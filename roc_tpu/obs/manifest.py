@@ -73,7 +73,11 @@ def run_manifest(config=None, dataset=None, model=None,
     lane-padded width its scan runs at); ``agg_chunk_rows``, one
     ``[n_chunks, seg_rows]`` per section of the sum scan's tables, and
     ``agg_slot_fill``, stored edges over the slots a pass gathers (how
-    far ``core/ell.py fit_chunks`` engaged); one ``attention`` entry
+    far ``core/ell.py fit_chunks`` engaged); ``agg_seg_sum``, the
+    ``[T, B]`` (or null) each scanned table's chunk step sums its
+    partials at on the MXU, and ``agg_carry_updates``, ``[before,
+    after]`` rows a pass adds into its carry without and with it (how
+    far ``ops/aggregate.py scan_seg_sum`` engaged); one ``attention`` entry
     per attention op (heads, head width, layout, passes over the edge
     tables, slots a pass, carry rows) and one ``attention_backward``
     entry (the gradient rule, its edge passes, the whole-array

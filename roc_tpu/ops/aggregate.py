@@ -24,7 +24,8 @@ chooses among (``core/ell.py resolve_auto_impl``,
   (:func:`aggregate_flat_sum`, MAX twin :func:`aggregate_flat_max`):
   width-8 sub-row tables walked by one chunk scan
   (:func:`_scan_window_sum`), per source section or over one global
-  section.
+  section; where the table is dense the scan sums a row's sub-rows
+  on the MXU before the carry sees them (:func:`scan_seg_sum`).
 - ``bdense`` (ops/blockdense.py): dense adjacency tiles on the MXU,
   the residual edges through ``sectioned``.
 
@@ -127,9 +128,132 @@ def scan_window_rows(win_rows: int, carry_rows: int) -> int:
     return win_rows if 0 < 2 * win_rows <= carry_rows else carry_rows
 
 
+# What a chunk step's ways of adding ``seg_rows`` sorted partials into
+# its window cost on the v5e, bfloat16, 256 lanes (my chip runs, PR 39,
+# ``chiprun_out/pr39/mb1.json`` and ``mb2.json``: one jitted scan over
+# a Reddit section at the cell's shapes — 32 chunks of 131,072
+# sub-rows, windows 7,680 and 13,440 — each tile of 512 ... 4,096
+# under each residual form; PERF §6, PR 39 has the table):
+# - the serial window scatter, one sorted read-modify-write a sub-row:
+#   1.4311 ms a step, 10.9 ns an update at any width (1.17 ms at 41
+#   lanes, a sixth of the bytes; 13.1 at a products chunk of 8,192);
+# - the banded one-hot product and the residual — ``nt * B`` float32
+#   slab rows added into a float32 copy of the window by the row
+#   scatter (its indices overlap, so they are not sorted): a least-
+#   squares fit over the five measured (tile, band) points, 0.2562 -
+#   0.3924 ms a step, reads 169 TFLOP/s of the chip's 197 and 10.8 ns
+#   a slab row, to within 2 us, beside 0.05 - 0.08 ms that does not
+#   scale (the window's slice, conversion and write-back).
+# Of the residual forms raced at Reddit's shapes (tiles 512 ... 4,096,
+# ms a step): the row scatter 0.26 - 0.28 with float32 slabs (0.28 -
+# 0.30 with slabs rounded to bfloat16 first, and worse rows);
+# ``lax.scatter_add`` with ``[B, F]`` update windows 0.51 at 1,024 and
+# 0.30 at 4,096; an inner loop of ``dynamic_slice`` + add +
+# ``dynamic_update_slice`` 0.60 at 512 down to 0.27 at 4,096 (2-5 us
+# a slab).  The row scatter in float32 is kept.
+SEG_SUM_UPDATE_NS = 10.9
+SEG_SUM_SLAB_ROW_NS = 10.8
+SEG_SUM_TFLOPS = 169.0
+# engage only where the model says the step's combine costs under
+# three quarters of the scatter's: the model is two slopes, not the
+# chip (it leaves out what does not scale, and reads a products chunk
+# at half its measured time)
+SEG_SUM_MARGIN = 0.75
+
+
+def scan_seg_sum(seg_rows: int, win_rows: int, bands, F: int):
+    """``(T, B)`` — the tile height and band the chunk step's
+    segmented sum runs at (:func:`_seg_sum`) — or None where the
+    serial scatter stays.  Derived, no knob: ``bands`` is the table's
+    own (``core/ell.py chunk_bands``: per tile height ``T`` dividing
+    ``seg_rows``, the most destination rows ``T`` consecutive sub-rows
+    span), ``win_rows`` the window the step runs in, ``F`` the width.
+
+    A tile's ``T`` partials become ``B`` slab rows on the MXU, so the
+    step adds ``seg_rows / T * B`` rows where it added ``seg_rows``,
+    and pays ``2 * seg_rows * B * F`` FLOP for it (``F`` in whole
+    128-lane registers).  The tile with the least modelled time wins
+    (the constants above), and the rule engages only where that is
+    well under the scatter's.  It therefore reads the table: Reddit's
+    sections (10-17.7 sub-rows a destination row; a tile of 512 spans
+    58-88 rows) engage at a tenth to an eighth of their 131,072 rows a
+    step; a products partition (6.9 a row) at 8 x 224 for 8,192; ogbn-arxiv
+    (1.1-1.4 a row: a tile of 1,024 spans 988 rows) and the typed
+    graph's stacked relation rows (a tile spans more rows than it has
+    sub-rows) have nothing to reduce and keep the scatter.  A band
+    taller than the window has no slab to place and is skipped."""
+    lanes = -(-F // 128) * 128
+    best = None
+    for T, B in bands:
+        if seg_rows % T or B > win_rows:
+            continue
+        ns = (seg_rows // T * B * SEG_SUM_SLAB_ROW_NS
+              + 2 * seg_rows * B * lanes / (SEG_SUM_TFLOPS * 1e3))
+        if best is None or ns < best[0]:
+            best = (ns, T, B)
+    if best is None or best[0] >= (SEG_SUM_MARGIN * seg_rows
+                                   * SEG_SUM_UPDATE_NS):
+        return None
+    return best[1:]
+
+
+def seg_sum_updates(n_chunks: int, seg_rows: int, seg) -> list:
+    """``[before, after]``: rows a pass over ``n_chunks`` chunks adds
+    into the carry's window one by one without and with the
+    segmented sum ``seg`` (:func:`scan_seg_sum`'s ``(T, B)`` or
+    None) — the ``plan`` line's ``agg_carry_updates``."""
+    before = n_chunks * seg_rows
+    return [before, before if seg is None
+            else n_chunks * (seg_rows // seg[0]) * seg[1]]
+
+
+def _seg_sum(w: jax.Array, part: jax.Array, loc: jax.Array,
+             T: int, B: int) -> jax.Array:
+    """``w.at[loc].add(part)`` for ascending ``loc`` with a row's
+    sub-rows summed on the MXU first: the ``[S, F]`` partials are cut
+    into ``S / T`` tiles; a tile's destinations lie within ``B`` rows
+    of its first (the table's band), so a ``[B, T]`` one-hot of
+    ``loc - base`` times the tile's ``[T, F]`` partials is its
+    ``[B, F]`` slab of per-row sums, accumulated in float32.  The
+    one-hot is exact in any dtype; a float32 ``part`` multiplies at
+    ``Precision.HIGHEST`` (the fp32 runs and the tests' ``segment``
+    reference), a bfloat16 one in the single default pass (0/1 x
+    bf16 is exact).  The slabs are added at rows ``base + [0, B)`` by
+    the row scatter — slabs overlap where a row's sub-rows cross a
+    tile boundary, so this is an accumulation of ``S / T * B`` rows,
+    not a placement — still in float32, into a float32 copy of the
+    window, which is rounded to the carry's dtype once: a destination
+    row takes one rounding a chunk.  That is what the serial scatter
+    takes on the chip too (XLA:TPU adds a bfloat16 scatter's updates
+    in float32 and rounds the window at the end; rounding the slabs
+    first read 10% worse rows at Reddit, PERF §6, PR 39), and fewer
+    than XLA:CPU's one a sub-row.
+
+    ``base`` is clamped so a slab never leaves the window.  Chunk
+    padding (``loc`` clamped to the window's last row, partial exactly
+    zero) falls inside a tile's band only where the slab ends at that
+    row, and adds its zero there; anywhere else its one-hot column is
+    empty and it is dropped."""
+    win, F = w.shape
+    nt = part.shape[0] // T
+    loc = loc.reshape(nt, T)
+    base = jnp.minimum(loc[:, 0], win - B)
+    band = jnp.arange(B, dtype=loc.dtype)
+    sel = ((loc - base[:, None])[:, None, :]
+           == band[None, :, None]).astype(part.dtype)
+    slab = jnp.einsum(
+        "tbj,tjf->tbf", sel, part.reshape(nt, T, F),
+        preferred_element_type=jnp.float32,
+        precision=(lax.Precision.HIGHEST
+                   if part.dtype == jnp.float32 else None))
+    rows = (base[:, None] + band[None, :]).reshape(nt * B)
+    return w.astype(jnp.float32).at[rows].add(
+        slab.reshape(nt * B, F)).astype(w.dtype)
+
+
 def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
-                     win_rows: int, slot_major: bool = False
-                     ) -> jax.Array:
+                     win_rows: int = 0, bands=(),
+                     slot_major: bool = False) -> jax.Array:
     """The chunk scan of the width-8 sub-row layouts — one body for
     :func:`aggregate_ell_sect` (per section) and
     :func:`aggregate_flat_sum` (its single global section).  Each step
@@ -150,6 +274,19 @@ def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
     or taller than half the carry) the window IS the carry, and XLA
     folds the slice and the write-back away: the whole-carry scatter.
 
+    Between the width-8 sum and the window sits the step's one
+    decision (:func:`scan_seg_sum`, read off the table's ``bands``
+    and ``F``; nothing to configure).  Where a destination row holds
+    many sub-rows the partials are summed by row on the MXU first
+    (:func:`_seg_sum`: a banded one-hot product a tile of sorted
+    partials, float32 accumulation) and the window takes ``seg_rows /
+    T * B`` slab rows instead of ``seg_rows`` serial updates — a
+    tenth at Reddit.  The slabs and the window meet in float32, so a
+    row is rounded to the carry's dtype once a chunk, and chunk
+    padding either adds its zero to the window's last row as before
+    or matches no row of a band and is dropped.  Where the rule
+    returns None the body is the scatter's, token for token.
+
     The body is width-agnostic and runs at whatever ``F`` its operands
     have.  ``flat_sum`` through ``GraphContext`` never has one under
     the 128 lanes: a narrower sum is zero-padded on its feature axis
@@ -160,7 +297,9 @@ def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
     model's width (PERF §6, PR 32).
 
     xs: ``(idx [n, seg, 8], dst [n, seg])`` plus optional weights
-    shaped like ``idx``, in the table's dtype or wider.
+    shaped like ``idx``, in the table's dtype or wider.  ``bands``:
+    the section's ``SectionedEll.bands`` entry (``()``: none known,
+    the scatter).
 
     ``slot_major``: ``idx`` (and the weights) arrive ``[n, 8 * seg]``,
     a chunk's ``[8, seg]`` transpose flattened, and a step reshapes
@@ -175,6 +314,7 @@ def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
     narrow axis to pad and only the 256 KiB chunk in flight is."""
     carry_rows, F = out.shape
     win = scan_window_rows(win_rows, carry_rows)
+    seg = scan_seg_sum(xs[1].shape[-1], win, bands, F)
 
     def body(o, ch):
         idx_ch, dst_ch = ch[0], ch[1]
@@ -194,8 +334,12 @@ def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
         # run that ends at the carry's last rows)
         r0 = jnp.minimum(dst_ch[0], carry_rows - win)
         w = lax.dynamic_slice(o, (r0, 0), (win, F))
-        w = w.at[jnp.minimum(dst_ch - r0, win - 1)].add(
-            part, indices_are_sorted=True)
+        if seg is None:
+            w = w.at[jnp.minimum(dst_ch - r0, win - 1)].add(
+                part, indices_are_sorted=True)
+        else:
+            w = _seg_sum(w, part, jnp.minimum(dst_ch - r0, win - 1),
+                         *seg)
         return lax.dynamic_update_slice(o, w, (r0, 0)), None
 
     return lax.scan(body, out, xs)[0]
@@ -218,9 +362,10 @@ def aggregate_ell_sect(feats: jax.Array, sect_idx, sect_sub_dst,
       block is lane-padded in VMEM by XLA, and padding ``F`` by hand
       measured no gain (``core/ell.py agg_lane_width``).
     sect_idx / sect_sub_dst: SectionedEll.idx / .sub_dst as jax arrays.
-    sect_meta: static tuple of (start, size, win_rows) per section
-      (SectionedEll.meta); a bare (start, size) scans with the whole
-      carry as its window.
+    sect_meta: static tuple of (start, size, win_rows, bands) per
+      section (SectionedEll.meta); a bare (start, size) scans with the
+      whole carry as its window, and without ``bands`` the partials
+      are scattered one by one.
     sect_w (optional): per-section edge weights shaped like
       ``sect_idx`` (SectionedEll.weight_tables — the baked fused-norm
       scales), applied in-register before the width reduction.
@@ -236,13 +381,13 @@ def aggregate_ell_sect(feats: jax.Array, sect_idx, sect_sub_dst,
         xs = (tbl, sdst)
         if weighted:
             xs += (sect_w[si].astype(feats.dtype),)
-        out = _scan_window_sum(out, xsec, xs, win[0] if win else 0)
+        out = _scan_window_sum(out, xsec, xs, *win)
     return out[:num_rows]
 
 
 def aggregate_flat_sum(feats: jax.Array, flat_idx: jax.Array,
                        flat_dst: jax.Array, num_rows: int,
-                       flat_w=None, win_rows: int = 0,
+                       flat_w=None, win_rows: int = 0, bands=(),
                        weights_fp32: bool = False,
                        slot_major: bool = False) -> jax.Array:
     """Uniform width-8 sub-row SUM — the sum-path twin of the
@@ -276,6 +421,9 @@ def aggregate_flat_sum(feats: jax.Array, flat_idx: jax.Array,
     win_rows: static height of a chunk's destination window
       (``SectionedEll.win_rows[0]``; 0 = the whole carry) — the scan
       is :func:`_scan_window_sum`, shared with the sectioned layout.
+    bands: the table's tile bands (``SectionedEll.bands[0]``), from
+      which the scan decides whether to sum a row's sub-rows on the
+      MXU first (:func:`scan_seg_sum`); ``()`` keeps the scatter.
     weights_fp32: keep ``flat_w`` in fp32 instead of rounding it to the
       table's dtype — the relation means' ``1 / deg`` (bfloat16 would
       put up to 0.4% of gain error on a whole row; the baked
@@ -292,7 +440,7 @@ def aggregate_flat_sum(feats: jax.Array, flat_idx: jax.Array,
     xs = (flat_idx, flat_dst)
     if flat_w is not None:
         xs += (flat_w if weights_fp32 else flat_w.astype(feats.dtype),)
-    return _scan_window_sum(out, feats, xs, win_rows,
+    return _scan_window_sum(out, feats, xs, win_rows, bands,
                             slot_major)[:num_rows]
 
 

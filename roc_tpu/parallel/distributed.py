@@ -219,16 +219,19 @@ class ShardedData:
     ring_idx: Tuple[jax.Array, ...] = ()  # (src, dst) [P, S, pair_edges]
     # sectioned layout (aggr_impl == "sectioned"): per section
     # [P, n_chunks_s, seg_rows, 8] / [P, n_chunks_s, seg_rows], plus
-    # the static (start, size, win_rows) metadata (SectionedEll.meta).
+    # the static (start, size, win_rows, bands) metadata
+    # (SectionedEll.meta).
     # For aggr_impl == "attn_flat8" / "flat_sum" the same slots carry
     # the SINGLE-section uniform width-8 tables (ids in gathered
     # coordinates, dummy == P*part_nodes; the step body routes them to
     # GraphContext flat8_idx/flat8_dst) with no sect_meta; flat_sum's
-    # static window height rides flat_win (-> GraphContext.flat8_win)
+    # static window height rides flat_win (-> GraphContext.flat8_win),
+    # its tile bands flat_bands (-> flat8_bands)
     sect_idx: Tuple[jax.Array, ...] = ()
     sect_sub_dst: Tuple[jax.Array, ...] = ()
     sect_meta: Tuple[Tuple[int, ...], ...] = ()
     flat_win: int = 0
+    flat_bands: Tuple[Tuple[int, int], ...] = ()
     # block-dense MXU layout (aggr_impl == "bdense"): per-partition
     # dense [128,128] tiles over (local dst rows x gathered source
     # coords), padded to a uniform block count; () or
@@ -331,6 +334,7 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
     sect_sub_dst = ()
     sect_meta = ()
     flat_win = 0
+    flat_bands = ()
     bd_tabs = ()
     bd_vpad = 0
     bd_src_vpad = 0
@@ -516,6 +520,7 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
             sect_sub_dst = tuple(put(a) for a in sect.sub_dst)
             if aggr_impl == "flat_sum":
                 flat_win = sect.win_rows[0]
+                flat_bands = sect.bands[0]
                 if fuse_d is not None:
                     with span("setup.tables", table="flat_sum_w"):
                         sect_w = sect.weight_tables(fuse_d[0], fuse_d[1])
@@ -541,6 +546,7 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
         sect_sub_dst=sect_sub_dst,
         sect_meta=sect_meta,
         flat_win=flat_win,
+        flat_bands=flat_bands,
         bd_tabs=bd_tabs,
         bd_vpad=bd_vpad,
         bd_src_vpad=bd_src_vpad,
@@ -1050,7 +1056,8 @@ class DistributedTrainer:
                 sh(data.ell_row_pos), sh(data.ell_row_id),
                 sh(data.ring_idx), sh(data.sect_idx),
                 sh(data.sect_sub_dst), sh(data.sect_meta),
-                data.flat_win, sh(data.bd_tabs), data.bd_vpad,
+                data.flat_win, data.flat_bands, sh(data.bd_tabs),
+                data.bd_vpad,
                 data.bd_src_vpad, data.bd_group, sh(data.ell_w),
                 sh(data.sect_w), sh(data.ring_w), sh(data.bd_scale))
 
@@ -1233,6 +1240,7 @@ class DistributedTrainer:
             ring_overlap=self.config.ring_overlap,
             sect_meta=self.data.sect_meta,
             flat8_win=self.data.flat_win,
+            flat8_bands=self.data.flat_bands,
             bd_vpad=self.data.bd_vpad,
             bd_src_vpad=self.data.bd_src_vpad,
             # the DATA's group, validated == config at init: the

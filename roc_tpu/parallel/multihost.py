@@ -372,16 +372,26 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
     sect_sub_dst = ()
     sect_meta = ()
     flat_win = 0
+    flat_bands = ()
 
-    def agreed_win_rows(sects):
-        """Per-section destination-window heights every host compiles
-        with: the max over ALL parts of each table's own ``win_rows``.
-        The windows exist only once the tables are built, so this is a
-        second O(P * n_sec) exchange after the chunk plan's — same
-        collective, same place in every host's sequence."""
-        return tuple(int(w) for w in _allreduce_part_vec_max(
-            mesh, local, {p: np.asarray(sects[p].win_rows)
-                          for p in local}))
+    def agreed_windows(sects):
+        """Per-section ``(win_rows, bands)`` every host compiles with:
+        the max over ALL parts of each table's own destination-window
+        height and of each tile's band (the parts share the chunk
+        plan, hence the tiles).  Both exist only once the tables are
+        built, so this is a second O(P * n_sec) exchange after the
+        chunk plan's — same collective, same place in every host's
+        sequence."""
+        first = sects[local[0]]
+        agreed = [int(v) for v in _allreduce_part_vec_max(
+            mesh, local, {p: np.asarray(
+                list(sects[p].win_rows)
+                + [b for tb in sects[p].bands for _, b in tb])
+                for p in local})]
+        rest = iter(agreed[len(first.win_rows):])
+        bands = [tuple((t, next(rest)) for t, _ in tb)
+                 for tb in first.bands]
+        return tuple(zip(agreed[:len(first.win_rows)], bands))
 
     if aggr_impl in ("attn_flat8", "flat_sum"):
         # the uniform flat layout (attention's attn_flat8 and the sum
@@ -412,7 +422,7 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
         sect_sub_dst = (put_parts(lambda p: sects[p].sub_dst[0],
                                   plan[0], np.int32),)
         if aggr_impl == "flat_sum":
-            flat_win = agreed_win_rows(sects)[0]
+            flat_win, flat_bands = agreed_windows(sects)[0]
 
     def local_sectioned_tables(ptrs, colmap):
         """Stacked sectioned tables from per-part (ptr, cols) dicts —
@@ -449,8 +459,9 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
             tuple(put_parts(lambda p, s=s: sects[p].sub_dst[s],
                             plan[s], np.int32)
                   for s in range(len(first.sub_dst))),
-            tuple(zip(first.sec_starts, first.sec_sizes,
-                      agreed_win_rows(sects))))
+            tuple((st, sz, *wb) for st, sz, wb in zip(
+                first.sec_starts, first.sec_sizes,
+                agreed_windows(sects))))
 
     if aggr_impl == "sectioned":
         from ..core.ell import clean_part_ptr
@@ -570,6 +581,7 @@ def shard_dataset_local(dataset, pg, mesh: Mesh, dtype=None,
         sect_sub_dst=sect_sub_dst,
         sect_meta=sect_meta,
         flat_win=flat_win,
+        flat_bands=flat_bands,
         bd_tabs=bd_tabs,
         bd_vpad=bd_vpad,
         bd_src_vpad=bd_src_vpad,

@@ -859,6 +859,7 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
     sect_meta: tuple = ()
     flat8_idx = flat8_dst = flat8_w = None
     flat8_win = 0
+    flat8_bands = ()
     bd_a = bd_src = bd_dst = None
     bd_vpad = 0
     ell_w: tuple = ()
@@ -978,6 +979,7 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
                                       "tables")
         if aggr_impl == "flat_sum":
             flat8_win = sect.win_rows[0]
+            flat8_bands = sect.bands[0]
             if fuse:
                 # baked D^-1/2 A D^-1/2 entries of the single section
                 # — zero runtime normalization on the fused flat path
@@ -1004,6 +1006,7 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
         flat8_dst=flat8_dst,
         flat8_w=flat8_w,
         flat8_win=flat8_win,
+        flat8_bands=flat8_bands,
         head_chunk=head_chunk,
         bd_a=bd_a,
         bd_src=bd_src,
@@ -1044,14 +1047,14 @@ def _relation_context(dataset: Dataset, aggr_impl: str, tables,
                 if aggr_impl == "segment":
                     into = np.repeat(np.arange(n_into, dtype=np.int32),
                                      np.diff(row_ptr))
-                    t_idx, t_dst, win = col, into, 0
+                    t_idx, t_dst, win, bands = col, into, 0, ()
                     t_w = typed.slot_weights(
                         name, col[:, None], into)[:, 0]
                 else:
                     sect = flat_sum_from_graph(row_ptr, col, n_into,
                                                src_rows=n_out)
                     t_idx, t_dst = sect.idx[0], sect.sub_dst[0]
-                    win = sect.win_rows[0]
+                    win, bands = sect.win_rows[0], sect.bands[0]
                     # slot-major at rest: [n_chunks, 8 * seg_rows]
                     n = t_idx.shape[0]
                     t_w = typed.slot_weights(
@@ -1063,7 +1066,7 @@ def _relation_context(dataset: Dataset, aggr_impl: str, tables,
             idx.append(t_idx)
             dst.append(t_dst)
             w.append(t_w)
-            meta.append((name, n_into, n_out, win, rels))
+            meta.append((name, n_into, n_out, win, rels, bands))
     return GraphContext(
         edge_src=jnp.zeros(1, jnp.int32), edge_dst=jnp.zeros(1, jnp.int32),
         in_degree=upload(g.in_degree, "tables"), num_rows=g.num_nodes,

@@ -736,8 +736,9 @@ def test_win_rows_bounds_every_chunk(case, builder, monkeypatch):
         assert largest <= w < largest + E.WIN_ROWS_MULTIPLE
         assert w % E.WIN_ROWS_MULTIPLE == 0
     assert sect.meta == tuple(zip(sect.sec_starts, sect.sec_sizes,
-                                  sect.win_rows))
+                                  sect.win_rows, sect.bands))
     assert sect.with_idx_dtype(np.uint16).win_rows == sect.win_rows
+    assert sect.with_idx_dtype(np.uint16).bands == sect.bands
 
 
 @pytest.mark.parametrize("parts", [2, 4])
