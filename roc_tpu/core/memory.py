@@ -48,7 +48,7 @@ _STAT_BYTES = 4              # fp32 by-products (softmax, attention)
 # this module imports nothing, so the tuple is repeated); under remat
 # they are the boundaries of the runs computed again
 AGG_KINDS = ("scatter_gather", "fused_aggregate", "gat",
-             "rel_aggregate")
+             "rel_aggregate", "soft_aggregate")
 
 
 def op_residuals(i: int, op: Any, itemsize: int
@@ -72,6 +72,8 @@ def op_residuals(i: int, op: Any, itemsize: int
     | ``gat`` | input, output and the fp32 row sums of the hand-written backward |
     | ``rel_linear``, ``root_linear`` | their input (for the dW of each relation / kind); a stacked input is charged at its own height (``row_scale``), the loss program's cut last layer (``Model.loss_cut``) at its cut ones |
     | ``rel_aggregate``, ``typed_input`` | nothing: the relation sum's backward is the pass over the transposed table, the assembly a concatenation |
+    | ``batch_norm`` | its input (the hand-written backward computes ``xhat`` again from it) and two float32 ``[F]`` vectors, mean and ``1 / sqrt(var + eps)``, which weigh nothing a vertex row |
+    | ``soft_aggregate`` | its input (``e`` is computed again from it and the kept ``[F]`` shift) and the float32 denominator a vertex row |
     """
     def t(j):
         return ("t", j)
@@ -104,6 +106,11 @@ def op_residuals(i: int, op: Any, itemsize: int
     if kind == "gat":
         return [(t(op.inputs[0]), op.dim, itemsize),
                 (t(i), op.dim, itemsize), (("m", i), op.dim, _STAT_BYTES)]
+    if kind == "batch_norm":
+        return [(t(op.inputs[0]), op.dim, itemsize)]
+    if kind == "soft_aggregate":
+        return [(t(op.inputs[0]), op.dim, itemsize),
+                (("m", i), op.dim, _STAT_BYTES)]
     return []
 
 
@@ -201,10 +208,13 @@ def saved_for_backward(ops: Sequence[Any], itemsize: int,
 
 
 def param_elems(ops: Sequence[Any]) -> int:
-    """Trainable scalars of the op list: every ``linear``'s matrix,
-    every ``gat``'s two attention vectors, every ``scale_add``'s eps;
-    of a typed model the embedding tables' rows, a matrix a relation
-    and a matrix and a bias a kind."""
+    """Trainable scalars of the op list: every ``linear``'s matrix
+    (and its bias where it has one), every ``gat``'s two attention
+    vectors, every ``scale_add``'s eps, every ``batch_norm``'s scale
+    and shift — its running statistics are state, not parameters: no
+    gradient, no Adam moments, no compute copy, and 8 bytes a channel
+    that no plan needs; of a typed model the embedding tables' rows, a
+    matrix a relation and a matrix and a bias a kind."""
     n = 0
     for op in ops:
         if op.kind == "typed_input":
@@ -215,7 +225,10 @@ def param_elems(ops: Sequence[Any]) -> int:
         elif op.kind == "root_linear":
             n += op.attrs["n_kinds"] * (op.attrs["in_dim"] + 1) * op.dim
         elif op.kind == "linear":
-            n += op.attrs["in_dim"] * op.dim
+            n += (op.attrs["in_dim"]
+                  + bool(op.attrs.get("bias"))) * op.dim
+        elif op.kind == "batch_norm":
+            n += 2 * op.dim
         elif op.kind == "gat":
             n += 2 * op.dim
         elif op.kind == "scale_add":
@@ -328,9 +341,14 @@ def plan_components(num_nodes: int, num_edges: int,
     def scale(op):
         return (getattr(op, "attrs", None) or {}).get("row_scale", 1)
 
+    def held(op):
+        # a softmax aggregation gathers a [V, 2F] table (numerator and
+        # denominator side by side) and sums into as wide a carry
+        return op.dim * (2 if op.kind == "soft_aggregate" else 1)
+
     # the widest array a step holds whole, in elements a vertex row (a
     # typed model's stacked tensors are taller than V)
-    h_max = max(op.dim * scale(op) for op in ops)
+    h_max = max(held(op) * scale(op) for op in ops)
     w = param_elems(ops)
     out = {"params_opt": w * (4 * param_bytes + b)}
     # input features: resident, or one streamed block + dY reuse (a
@@ -351,7 +369,7 @@ def plan_components(num_nodes: int, num_edges: int,
     # halo transient: the gathered global matrix vs two ring buffers
     out["transient"] = int((num_parts if halo == "gather" else 2)
                            * V_p * h_max * b + V_p * recompute
-                           + scan_rows * 9 * max(op.dim for op in ops)
+                           + scan_rows * 9 * max(held(op) for op in ops)
                            * b)
     return out
 

@@ -11,6 +11,7 @@ from typing import Callable, Dict
 def model_builders() -> Dict[str, Callable]:
     """Lazily imported so ``import roc_tpu.models`` stays jax-light."""
     from .appnp import build_appnp
+    from .deepergcn import build_deepergcn
     from .gat import build_gat
     from .gcn import build_gcn
     from .gcn2 import build_gcn2
@@ -20,4 +21,5 @@ def model_builders() -> Dict[str, Callable]:
     from .sgc import build_sgc
     return {"gcn": build_gcn, "sage": build_sage, "gin": build_gin,
             "gat": build_gat, "sgc": build_sgc, "appnp": build_appnp,
-            "gcn2": build_gcn2, "rgcn": build_rgcn}
+            "gcn2": build_gcn2, "rgcn": build_rgcn,
+            "deepergcn": build_deepergcn}

@@ -31,9 +31,10 @@ import numpy as np
 from ..core.ell import LANE_WIDTH, agg_lane_width
 from ..core.memory import remat_segments
 from ..core.relations import ORDER_PASSES, TRANSFORM_FIRST
-from ..obs.scopes import (ATTN_SCORES_SCOPE, EMBED_SCOPE, HALO_SCOPE,
+from ..obs.scopes import (ALLREDUCE_SCOPE, ATTN_SCORES_SCOPE,
+                          BN_PARAM_PREFIX, EMBED_SCOPE, HALO_SCOPE,
                           LOSS_SCOPE, RECOMPUTE_SCOPE, op_scope)
-from ..ops import dense
+from ..ops import dense, softagg
 from ..parallel import PARTS_AXIS
 from ..ops.aggregate import (aggregate_ell, aggregate_ell_max,
                              aggregate_ell_sect, aggregate_flat_max,
@@ -42,7 +43,8 @@ from ..ops.aggregate import (aggregate_ell, aggregate_ell_max,
                              seg_sum_updates)
 from ..ops.dense import AC_MODE_NONE, AC_MODE_RELU, AC_MODE_SIGMOID
 from ..ops.loss import masked_softmax_cross_entropy
-from ..ops.norm import indegree_norm
+from ..ops.norm import (BN_EPS, BN_MOMENTUM, batch_norm_eval,
+                        batch_norm_train, indegree_norm, running_update)
 
 # AggrType mirror (gnn.h:75-80); the reference declares SUM/AVG/MAX/MIN
 # but implements only SUM.  Here SUM and AVG ride the symmetric-vjp CSR
@@ -52,6 +54,20 @@ AGGR_SUM = "sum"
 AGGR_AVG = "avg"
 AGGR_MAX = "max"
 AGGR_MIN = "min"
+
+# a batch_norm op's entries of the parameter dict, ``bn_<n>_<suffix>``:
+# what Adam trains, and the state no optimizer sees
+BN_TRAINED = ("scale", "shift")
+BN_STATE = ("mean", "var")
+
+# the softmax aggregation's backward is the pass over the TRANSPOSED
+# table; on a symmetric graph that is the forward's own table, and no
+# transposed sum table is built for a directed one (ROADMAP R2(c))
+SOFT_DIRECTED_REFUSAL = (
+    "the softmax-weighted aggregation (soft_aggregate; --model "
+    "deepergcn) needs a symmetric stored graph: its hand-written "
+    "backward gathers g / den over the transposed graph, and no "
+    "transposed sum table is built for a directed one")
 
 
 @dataclass
@@ -182,6 +198,43 @@ class GraphContext:
     rel_dst: Tuple[jax.Array, ...] = ()
     rel_w: Tuple[jax.Array, ...] = ()
     rel_meta: Tuple[Tuple[Any, ...], ...] = ()
+    # What an op that reduces over the vertex axis (``batch_norm``; the
+    # softmax aggregation's shift) has to know about the rows:
+    # ``real_rows`` the traced int32 count of this partition's real
+    # rows, which come first (None: every row is real — one device has
+    # no padding), ``total_rows`` the static count of real rows over
+    # all partitions (0: ``num_rows``).
+    real_rows: Optional[jax.Array] = None
+    total_rows: int = 0
+
+    @property
+    def partitioned(self) -> bool:
+        """Whether the rows are one partition's of several (the
+        gathered matrix is taller than the local one)."""
+        return self.gathered_rows != self.num_rows
+
+    @property
+    def counted_rows(self) -> int:
+        """Real rows over all partitions: what a moment divides by."""
+        return self.total_rows or self.num_rows
+
+    def valid_rows(self) -> Optional[jax.Array]:
+        """``[num_rows]`` bool, True on the real rows; None where every
+        row is real."""
+        if self.real_rows is None:
+            return None
+        return jnp.arange(self.num_rows, dtype=jnp.int32) < self.real_rows
+
+    def batch_norm(self, x: jax.Array, scale: jax.Array,
+                   shift: jax.Array):
+        """Training-mode batch normalization over the real vertex rows
+        of every partition (``ops/norm.py batch_norm_train``): ``(y,
+        mean, var)``.  Across partitions the two moments are one
+        ``psum`` of ``[2, F]`` under ``roc.allreduce``."""
+        return batch_norm_train(
+            x, scale, shift, count=self.counted_rows,
+            valid=self.valid_rows(),
+            psum=self.psum if self.partitioned else None)
 
     def agg_window(self, ops=(), tables=None, edges=None) -> dict:
         """How far the chunk scan's destination window engaged — the
@@ -223,6 +276,10 @@ class GraphContext:
                 if op.kind == "fused_aggregate"
                 or (op.kind == "scatter_gather"
                     and op.attrs["aggr"] in (AGGR_SUM, AGGR_AVG))]
+        # a softmax aggregation's forward sum runs at its table's width
+        pads += [[i, op.dim, self._lane_width(2 * op.dim)]
+                 for i, op in enumerate(ops)
+                 if op.kind == "soft_aggregate"]
         if tables is None:
             tables = ((self.flat8_idx,) if self.flat8_win
                       else self.sect_idx)
@@ -510,6 +567,78 @@ class GraphContext:
             return -self._max_fwd(-x)
         raise ValueError(f"unknown aggregator: {aggr}")
 
+    def soft_plan(self, ops=(), compute=jnp.float32) -> dict:
+        """What the softmax-weighted aggregations of ``ops`` run as,
+        for the run manifest's ``resolved`` beside :meth:`agg_window`:
+        the ops, the temperature, the lanes a forward / backward pass
+        gathers (numerator and denominator side by side forward), the
+        dtype the gathered table is stored in (the compute dtype) and
+        what the hand-written rule keeps.
+        Empty for a model without such an op."""
+        soft = [(i, op) for i, op in enumerate(ops)
+                if op.kind == "soft_aggregate"]
+        if not soft:
+            return {}
+        width = max(op.dim for _, op in soft)
+        return {"soft_aggregate": {
+            "ops": [i for i, _ in soft], "count": len(soft),
+            "t": soft[0][1].attrs["t"], "eps": soft[0][1].attrs["eps"],
+            "width": width,
+            "gather_lanes_fwd": self._lane_width(2 * width),
+            "gather_lanes_bwd": self._lane_width(width),
+            "passes_fwd": 1, "passes_bwd": 1,
+            "e_dtype": jnp.dtype(compute).name,
+            "keeps": ["input", "shift[F] float32", "den float32"],
+            "rule": "detached weights, transposed pass"}}
+
+    def soft_aggregate(self, x: jax.Array, t: float,
+                       eps: float) -> jax.Array:
+        """``z + sum_u sg(w_vu) m_u`` — GENConv's ``softmax_sg``
+        aggregation and its self term (``ops/softagg.py`` has the
+        equations): the forward ONE pass of the sum aggregation
+        (:meth:`_sum_fwd`: whatever layout and halo the graph resolved
+        to) over the ``[rows, 2F]`` table ``[e * m, e]`` and a
+        division, the backward the published rule — the weights carry
+        no gradient — as ONE pass of the same sum over ``g / den`` and
+        a product with ``e``.  That rule is NOT the derivative of the
+        forward (autodiff would differentiate the softmax), and it is
+        the pass over the transposed graph: the stored graph must be
+        symmetric."""
+        if not self.symmetric:
+            raise NotImplementedError(SOFT_DIRECTED_REFUSAL)
+        F = x.shape[1]
+        valid = self.valid_rows()
+
+        def small_gather(row):
+            # the halo's all-gather over one [1, F] row a partition:
+            # a collective that is neither halo nor gradient
+            with jax.named_scope(ALLREDUCE_SCOPE):
+                return self.gather_features(row)
+
+        def lanes(a):
+            return self._lane_padded(self._sum_fwd, a)
+
+        def forward(z):
+            z = softagg.as_stored(z)
+            c = softagg.channel_max(
+                z, eps, valid,
+                small_gather if self.partitioned else lambda row: row)
+            out = lanes(softagg.table(z, c, t, eps))
+            y, den = softagg.combine(z, out[:, :F], out[:, F:])
+            return y, (z, c, den)
+
+        @jax.custom_vjp
+        def agg(z):
+            return forward(z)[0]
+
+        def bwd(res, g):
+            z, c, den = res
+            r = lanes(softagg.cotangent_over_den(g, den))
+            return (softagg.input_cotangent(g, r, z, c, t, eps),)
+
+        agg.defvjp(forward, bwd)
+        return agg(x)
+
     def _rel_pass(self, x: jax.Array, name: str, rels) -> jax.Array:
         """One relation pass (core/relations.py) over ``x``, whose
         rows are what the pass gathers out of: the weighted sum into
@@ -763,12 +892,13 @@ def _gctx_flatten(g: GraphContext):
                 g.ell_row_pos, g.ring_idx, g.sect_idx, g.sect_sub_dst,
                 g.ell_row_id, g.flat8_idx, g.flat8_dst, g.flat8_w,
                 g.bd_a, g.bd_src, g.bd_dst, g.ell_w, g.sect_w,
-                g.ring_w, g.bd_scale, g.rel_idx, g.rel_dst, g.rel_w)
+                g.ring_w, g.bd_scale, g.rel_idx, g.rel_dst, g.rel_w,
+                g.real_rows)
     aux = (g.num_rows, g.gathered_rows, g.gather_features, g.psum,
            g.aggr_impl, g.symmetric, g.halo, g.axis_name,
            g.sect_meta, g.bd_vpad, g.bd_src_vpad, g.bd_group,
            g.ring_overlap, g.head_chunk, g.flat8_win, g.flat8_bands,
-           g.rel_meta)
+           g.rel_meta, g.total_rows)
     return children, aux
 
 
@@ -776,11 +906,11 @@ def _gctx_unflatten(aux, children):
     (num_rows, gathered_rows, gather_features, psum, aggr_impl,
      symmetric, halo, axis_name, sect_meta, bd_vpad, bd_src_vpad,
      bd_group, ring_overlap, head_chunk, flat8_win, flat8_bands,
-     rel_meta) = aux
+     rel_meta, total_rows) = aux
     (edge_src, edge_dst, in_degree, ell_idx, ell_row_pos, ring_idx,
      sect_idx, sect_sub_dst, ell_row_id, flat8_idx,
      flat8_dst, flat8_w, bd_a, bd_src, bd_dst, ell_w, sect_w, ring_w,
-     bd_scale, rel_idx, rel_dst, rel_w) = children
+     bd_scale, rel_idx, rel_dst, rel_w, real_rows) = children
     return GraphContext(
         edge_src=edge_src, edge_dst=edge_dst, in_degree=in_degree,
         num_rows=num_rows, gathered_rows=gathered_rows,
@@ -798,7 +928,7 @@ def _gctx_unflatten(aux, children):
         head_chunk=head_chunk,
         ell_w=ell_w, sect_w=sect_w, ring_w=ring_w, bd_scale=bd_scale,
         rel_idx=rel_idx, rel_dst=rel_dst, rel_w=rel_w,
-        rel_meta=rel_meta)
+        rel_meta=rel_meta, real_rows=real_rows, total_rows=total_rows)
 
 
 # GraphContext is a pytree so the graph tables travel as jit ARGUMENTS.
@@ -871,6 +1001,7 @@ class Model:
         self._n_linear = 0
         self._n_gat = 0
         self._n_eps = 0
+        self._n_bn = 0
         self._loss_op: Optional[int] = None
         # a typed model's kinds (models/rgcn.py): ``{"node_types",
         # "embed_types", "relations"}`` — None for every other family
@@ -937,6 +1068,7 @@ class Model:
         new._ops = ops
         new._n_linear, new._n_gat, new._n_eps = (
             self._n_linear, self._n_gat, self._n_eps)
+        new._n_bn = self._n_bn
         new._loss_op = self._loss_op
         new.typed = self.typed
         return new
@@ -1085,6 +1217,7 @@ class Model:
         fused._n_linear = self._n_linear
         fused._n_gat = self._n_gat
         fused._n_eps = self._n_eps
+        fused._n_bn = self._n_bn
         new_ops = [ops[0]]
         remap = {0: 0}
         skip_until = 0
@@ -1119,12 +1252,42 @@ class Model:
         return self._append("dropout", (t.idx,), t.dim, attrs={"rate": rate})
 
     def linear(self, t: TensorHandle, out_dim: int,
-               activation: str = AC_MODE_NONE) -> TensorHandle:
+               activation: str = AC_MODE_NONE,
+               bias: bool = False) -> TensorHandle:
+        """``t @ linear_<n>`` (bias-free, the reference's); ``bias``
+        adds the row vector ``linear_<n>_b`` — ``torch.nn.Linear``'s,
+        asked for by the families whose published parameter count
+        holds it (models/deepergcn.py)."""
         name = f"linear_{self._n_linear}"
         self._n_linear += 1
+        attrs = {"activation": activation, "in_dim": t.dim}
+        if bias:
+            attrs["bias"] = True
         return self._append("linear", (t.idx,), out_dim, param=name,
-                            attrs={"activation": activation,
-                                   "in_dim": t.dim})
+                            attrs=attrs)
+
+    def batch_norm(self, t: TensorHandle) -> TensorHandle:
+        """``torch.nn.BatchNorm1d`` over the vertex axis
+        (``ops/norm.py``): in train mode the moments over ALL real
+        vertex rows, in eval mode the running statistics.  Parameters
+        ``bn_<n>_scale`` / ``bn_<n>_shift`` and — state, not
+        parameters: :meth:`state_names` — ``bn_<n>_mean`` /
+        ``bn_<n>_var``, all float32 whatever the compute dtype."""
+        name = f"{BN_PARAM_PREFIX}{self._n_bn}"
+        self._n_bn += 1
+        return self._append("batch_norm", (t.idx,), t.dim, param=name,
+                            attrs={"eps": BN_EPS,
+                                   "momentum": BN_MOMENTUM})
+
+    def soft_aggregate(self, t: TensorHandle, temperature: float,
+                       eps: float = 1e-7) -> TensorHandle:
+        """``t + sum_u sg(softmax_u(temperature * m_u)) * m_u`` with
+        ``m = relu(t) + eps``: GENConv's ``softmax_sg`` neighbour
+        aggregation with its self term
+        (``GraphContext.soft_aggregate``)."""
+        return self._append("soft_aggregate", (t.idx,), t.dim,
+                            attrs={"t": float(temperature),
+                                   "eps": float(eps)})
 
     def indegree_norm(self, t: TensorHandle) -> TensorHandle:
         return self._append("indegree_norm", (t.idx,), t.dim)
@@ -1362,7 +1525,8 @@ class Model:
     # ---- serving support ----
 
     GRAPH_OP_KINDS = ("scatter_gather", "fused_aggregate", "gat",
-                      "indegree_norm", "rel_aggregate")
+                      "indegree_norm", "rel_aggregate",
+                      "soft_aggregate")
 
     def precompute_split(self):
         """``(prefix_ops, head_model)`` when the op list is a
@@ -1416,7 +1580,8 @@ class Model:
                      "attrs": dict(op.attrs)}
                     for op in self._ops[1:]],
             "loss_op": self._loss_op,
-            "counters": [self._n_linear, self._n_gat, self._n_eps],
+            "counters": [self._n_linear, self._n_gat, self._n_eps]
+            + ([self._n_bn] if self._n_bn else []),
             **({"typed": {k: [list(x) if isinstance(x, tuple) else x
                               for x in v]
                           for k, v in self.typed.items()}}
@@ -1435,6 +1600,7 @@ class Model:
         c = spec.get("counters") or [0, 0, 0]
         model._n_linear, model._n_gat, model._n_eps = (
             int(c[0]), int(c[1]), int(c[2]))
+        model._n_bn = int(c[3]) if len(c) > 3 else 0
         ty = spec.get("typed")
         if ty:
             model.typed = {
@@ -1457,6 +1623,19 @@ class Model:
                 s = float(np.sqrt(6.0 / (in_dim + op.dim)))
                 params[op.param] = jax.random.uniform(
                     sub, (in_dim, op.dim), dtype=dtype, minval=-s, maxval=s)
+                if op.attrs.get("bias"):
+                    # torch.nn.Linear's: U(-1/sqrt(in), 1/sqrt(in))
+                    key, sub = jax.random.split(key)
+                    b = float(1.0 / np.sqrt(in_dim))
+                    params[f"{op.param}_b"] = jax.random.uniform(
+                        sub, (op.dim,), dtype=dtype, minval=-b, maxval=b)
+            elif op.kind == "batch_norm":
+                # torch's: scale 1, shift 0, running mean 0, variance 1
+                # — float32 whatever the parameters' dtype
+                for suffix, fill in zip(BN_TRAINED + BN_STATE,
+                                        (1.0, 0.0, 0.0, 1.0)):
+                    params[f"{op.param}_{suffix}"] = jnp.full(
+                        (op.dim,), fill, dtype=jnp.float32)
             elif op.kind == "scale_add":
                 # learnable GIN eps: zero-init (the paper's GIN-0)
                 params[op.param] = jnp.zeros((), dtype=dtype)
@@ -1485,6 +1664,30 @@ class Model:
                         sub, (heads, dh), dtype=dtype, minval=-s,
                         maxval=s)
         return params
+
+    def state_names(self) -> Tuple[str, ...]:
+        """The entries of the parameter dict that are state, not
+        parameters: every ``batch_norm``'s running mean and variance.
+        They travel with the parameters (checkpoints, ``predict``, the
+        benchmark's reference read one dict), a train step hands new
+        ones back (:meth:`loss_and_state`), and nothing optimizes
+        them: no gradient, no Adam moments, no weight decay, no
+        compute-dtype copy (``train/trainer.py split_state``)."""
+        return tuple(f"{op.param}_{s}" for op in self._ops
+                     if op.kind == "batch_norm" for s in BN_STATE)
+
+    def _op_param_names(self, op: _Op) -> List[str]:
+        """Every entry of the parameter dict ``op`` reads."""
+        if op.kind == "linear":
+            return [op.param] + ([f"{op.param}_b"]
+                                 if op.attrs.get("bias") else [])
+        if op.kind == "scale_add":
+            return [op.param]
+        if op.kind == "batch_norm":
+            return [f"{op.param}_{s}" for s in BN_TRAINED + BN_STATE]
+        if op.kind in ("typed_input", "rel_linear", "root_linear"):
+            return [n for n, _ in self._typed_param_shapes(op)]
+        return []
 
     def _stack_scale(self, order: str, rels=None) -> float:
         """Rows of the order's stacked tensor a vertex: the src stack
@@ -1576,7 +1779,21 @@ class Model:
     def apply(self, params: Dict[str, jax.Array], feats: jax.Array,
               gctx: GraphContext, key: Optional[jax.Array] = None,
               train: bool = True, remat: bool = False) -> jax.Array:
-        """Run the recorded op list; returns the logits tensor.
+        """Run the recorded op list; returns the logits tensor
+        (:meth:`apply_stateful` without the statistics)."""
+        return self.apply_stateful(params, feats, gctx, key=key,
+                                   train=train, remat=remat)[0]
+
+    def apply_stateful(self, params: Dict[str, jax.Array],
+                       feats: jax.Array, gctx: GraphContext,
+                       key: Optional[jax.Array] = None,
+                       train: bool = True, remat: bool = False
+                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """Run the recorded op list; returns ``(logits, state)``:
+        ``state`` holds, in train mode, the new value of every entry
+        of :meth:`state_names` (each ``batch_norm``'s running
+        statistics after this step's moments) and is empty in eval
+        mode, which reads the running statistics and moves nothing.
 
         ``remat`` (the trainers' ``config.remat``, train steps only):
         every run of ops between two aggregations
@@ -1601,12 +1818,14 @@ class Model:
                 "dropout; pass key= or use train=False")
         ops = self._ops
         vals: Dict[int, jax.Array] = {0: feats}
+        state: Dict[str, jax.Array] = {}
         out_idx = (self._loss_op if self._loss_op is not None
                    else len(ops) - 1)
         if not (remat and train):
             for i in range(1, len(ops)):
-                vals[i] = self._eval_op(i, vals, params, gctx, key, train)
-            return vals[out_idx]
+                vals[i] = self._eval_op(i, vals, params, gctx, key, train,
+                                        state)
+            return vals[out_idx], state
         read_at = {}                     # tensor -> last op that reads it
         for i, op in enumerate(ops):
             for j in op.inputs:
@@ -1616,7 +1835,8 @@ class Model:
         while i < len(ops):
             hi = segments.get(i)
             if hi is None:               # an aggregation: kept outside
-                vals[i] = self._eval_op(i, vals, params, gctx, key, train)
+                vals[i] = self._eval_op(i, vals, params, gctx, key, train,
+                                        state)
                 i += 1
                 continue
             ins = sorted({j for op in ops[i:hi] for j in op.inputs
@@ -1624,28 +1844,34 @@ class Model:
             outs = [k for k in range(i, hi)
                     if read_at.get(k, -1) >= hi or k == out_idx]
 
-            names = {ops[k].param for k in range(i, hi)
-                     if ops[k].kind in ("linear", "scale_add")}
-            names |= {n for k in range(i, hi)
-                      if ops[k].kind in ("typed_input", "rel_linear",
-                                         "root_linear")
-                      for n, _ in self._typed_param_shapes(ops[k])}
+            names = {n for k in range(i, hi)
+                     for n in self._op_param_names(ops[k])}
 
             def run(p, *xs, lo=i, hi=hi, ins=ins, outs=outs):
                 local = dict(zip(ins, xs))
+                moved: Dict[str, jax.Array] = {}
                 for k in range(lo, hi):
-                    local[k] = self._eval_op(k, local, p, gctx, key, train)
-                return tuple(local[k] for k in outs)
+                    local[k] = self._eval_op(k, local, p, gctx, key, train,
+                                             moved)
+                # a run's new statistics leave it as outputs, like its
+                # tensors (their cotangent is zero)
+                return tuple(local[k] for k in outs), moved
 
-            vals.update(zip(outs, _computed_again(run)(
-                {k: params[k] for k in names}, *(vals[j] for j in ins))))
+            got, moved = _computed_again(run)(
+                {k: params[k] for k in names}, *(vals[j] for j in ins))
+            vals.update(zip(outs, got))
+            state.update(moved)
             i = hi
-        return vals[out_idx]
+        return vals[out_idx], state
 
     def _eval_op(self, i: int, vals, params, gctx: GraphContext,
-                 key: Optional[jax.Array], train: bool) -> jax.Array:
+                 key: Optional[jax.Array], train: bool,
+                 state: Optional[Dict[str, jax.Array]] = None
+                 ) -> jax.Array:
         """Model op ``i`` on the tensors ``vals`` holds (index ->
-        array), under the op's program scope."""
+        array), under the op's program scope.  A ``batch_norm`` in
+        train mode writes its new running statistics into ``state``
+        (name -> array)."""
         op = self._ops[i]
         x = vals[op.inputs[0]] if op.inputs else None
         # one scope per model op (obs/scopes.py): roc.agg.op<i> for
@@ -1678,9 +1904,30 @@ class Model:
                     # differs only in fp summation order)
                     return dense.linear_chunked(
                         x, params[op.param], op.attrs["activation"],
-                        gctx.head_chunk)
+                        gctx.head_chunk,
+                        bias=params.get(f"{op.param}_b"))
                 return dense.linear(x, params[op.param],
-                                    op.attrs["activation"])
+                                    op.attrs["activation"],
+                                    bias=params.get(f"{op.param}_b"))
+            if op.kind == "batch_norm":
+                scale, shift, mean, var = (
+                    params[f"{op.param}_{s}"]
+                    for s in BN_TRAINED + BN_STATE)
+                if not train:
+                    return batch_norm_eval(x, scale, shift, mean, var,
+                                           op.attrs["eps"])
+                y, bmean, bvar = gctx.batch_norm(x, scale, shift)
+                if state is not None:
+                    new = running_update(
+                        mean, var, jax.lax.stop_gradient(bmean),
+                        jax.lax.stop_gradient(bvar),
+                        gctx.counted_rows, op.attrs["momentum"])
+                    state[f"{op.param}_mean"] = new[0]
+                    state[f"{op.param}_var"] = new[1]
+                return y
+            if op.kind == "soft_aggregate":
+                return gctx.soft_aggregate(x, op.attrs["t"],
+                                           op.attrs["eps"])
             if op.kind == "indegree_norm":
                 return indegree_norm(x, gctx.in_degree)
             if op.kind == "scatter_gather":
@@ -1737,10 +1984,31 @@ class Model:
         In train mode the op list is :meth:`loss_cut`'s, and the logits
         are the rows the loss reads: every row, or a typed model's
         labelled kind."""
+        loss, logits, _ = self._objective(params, feats, labels, mask,
+                                          gctx, key, train, remat)
+        return loss, logits
+
+    def loss_and_state(self, params: Dict[str, jax.Array],
+                       feats: jax.Array, labels: jax.Array,
+                       mask: jax.Array, gctx: GraphContext,
+                       key: Optional[jax.Array] = None,
+                       remat: bool = False
+                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """The train step's objective with what it moves beside it:
+        ``(loss, state)`` — :meth:`loss_fn`'s loss in train mode and
+        :meth:`apply_stateful`'s new statistics (empty for a model
+        without any), the auxiliary output of the trainers'
+        ``value_and_grad``."""
+        loss, _, state = self._objective(params, feats, labels, mask,
+                                         gctx, key, True, remat)
+        return loss, state
+
+    def _objective(self, params, feats, labels, mask, gctx, key, train,
+                   remat):
         model = self.loss_cut() if train else self
-        logits = model.apply(params, feats, gctx, key=key, train=train,
-                             remat=remat)
+        logits, state = model.apply_stateful(
+            params, feats, gctx, key=key, train=train, remat=remat)
         with jax.named_scope(LOSS_SCOPE):
             loss = masked_softmax_cross_entropy(
                 *self.labelled(logits, labels, mask))
-        return gctx.psum(loss), logits
+        return gctx.psum(loss), logits, state

@@ -29,7 +29,7 @@ map (``ObservedJit.instruction_scopes``) that joins the two.
 
 | class | what runs under it | name |
 | --- | --- | --- |
-| ``agg`` | a model op that aggregates over edges (``scatter_gather``, ``fused_aggregate``, ``gat``, a typed graph's ``rel_aggregate``) | ``roc.agg.op<i>`` |
+| ``agg`` | a model op that aggregates over edges (``scatter_gather``, ``fused_aggregate``, ``gat``, a typed graph's ``rel_aggregate``, the softmax-weighted ``soft_aggregate``) | ``roc.agg.op<i>`` |
 | ``halo`` | the feature halo exchange inside an aggregation (all-gather, ring hops) | ``roc.halo`` |
 | ``dense`` | every other model op | ``roc.dense.op<i>.<kind>`` |
 | ``loss`` | masked cross-entropy and the metric reductions | ``roc.loss`` |
@@ -50,6 +50,20 @@ weights').  Its per-relation and per-kind products are
 ``roc.dense.op<i>.rel_linear`` / ``.root_linear``.  The relation
 aggregation's per-slot ``1 / deg`` weights are applied in register
 inside the chunk scan: no separate work, no scope of their own.
+
+A ``batch_norm`` op (``roc.dense.op<i>.batch_norm``) names the one
+part of it that reduces over the vertex axis: ``roc.bn.stats``, the
+float32 sums of ``x`` and ``x * x`` over the real rows (in the backward
+those of ``g`` and ``g * xhat``) — and, across partitions, their
+``psum``, which sits under ``roc.allreduce`` inside it: the one
+collective of a step between a layer's dense ops.  A softmax-weighted
+aggregation (``soft_aggregate``, ``GraphContext.soft_aggregate``) keeps
+the class ``agg`` under its ``roc.agg.op<i>`` and names its elementwise
+part ``roc.sagg.weights``: ``relu + eps``, the per-channel shift,
+``exp``, the product ``e * m``, the division by the denominator, and in
+the backward ``g / den`` and the product with ``e``; the chunk scan and
+the halo stay outside it.  Neither name is a class: whatever reads
+classes sees ``dense`` and ``agg`` as before.
 
 An attention op (``gat``) splits its ``roc.agg.op<i>`` into three
 phases, each a scope nested inside it (``ops/attention.py``); the
@@ -75,7 +89,7 @@ CLASSES = (AGG, HALO, DENSE, LOSS, OPT, ALLREDUCE)
 # the model op kinds whose scope class is ``agg``; every other kind is
 # ``dense``
 AGG_KINDS = ("scatter_gather", "fused_aggregate", "gat",
-             "rel_aggregate")
+             "rel_aggregate", "soft_aggregate")
 
 ATTN_PHASES = ("scores", "stats", "gather")
 
@@ -88,6 +102,14 @@ EMBED_SCOPE = PREFIX + "embed"
 OPT_EMBED_SCOPE = OPT_SCOPE + ".embed"
 # parameters whose optimizer work runs under OPT_EMBED_SCOPE
 EMBED_PARAM_PREFIX = "embed_"
+# a batch_norm op's moment reductions (inside its own dense scope) and
+# a softmax-weighted aggregation's elementwise part (inside its agg
+# scope): names, not classes (module docstring)
+BN_STATS_SCOPE = PREFIX + "bn.stats"
+SAGG_WEIGHTS_SCOPE = PREFIX + "sagg.weights"
+# a batch_norm op's parameters (scale, shift) and statistics (running
+# mean, variance): kept in float32 whatever the compute dtype
+BN_PARAM_PREFIX = "bn_"
 # entered inside an attention op's own ``roc.agg.op<i>``
 ATTN_SCORES_SCOPE, ATTN_STATS_SCOPE, ATTN_GATHER_SCOPE = (
     f"{PREFIX}attn.{phase}" for phase in ATTN_PHASES)

@@ -12,8 +12,10 @@ Semantics parity notes:
   which is X·W in our row-major layout).  Optional fused activation
   mirrors ``ActiMode`` (``gnn.h:82-86``).  ``bias=`` adds a row vector
   to the fp32 accumulator before the output cast: beyond the reference,
-  and read by the typed models' per-kind root products alone
-  (``models/rgcn.py``); the builder's plain ``linear`` op passes none.
+  and read by the typed models' per-kind root products
+  (``models/rgcn.py``) and by the builder's ``linear`` op where a
+  family asks for one (``Model.linear(bias=True)``:
+  ``models/deepergcn.py``); every other ``linear`` passes none.
 - Dropout: inverted dropout with scale 1/(1-rate) in train mode (cuDNN's
   convention, ``dropout_kernel.cu:98-99``), identity in infer mode
   (``dropout_kernel.cu:160-180``).  We thread an explicit PRNG key —
@@ -165,7 +167,8 @@ def _precision(x: jax.Array):
 
 def linear_chunked(x: jax.Array, w: jax.Array,
                    activation: str = AC_MODE_NONE,
-                   block: int = 65536) -> jax.Array:
+                   block: int = 65536,
+                   bias: Optional[jax.Array] = None) -> jax.Array:
     """:func:`linear` evaluated as a ``lax.scan`` over ``block``-row
     vertex chunks — the chunked output head (models/builder.py,
     ``TrainConfig.head_chunk``).  The compiled matmul body is
@@ -184,12 +187,12 @@ def linear_chunked(x: jax.Array, w: jax.Array,
     V, in_dim = x.shape
     n = -(-V // block)
     if n <= 1:
-        return linear(x, w, activation)
+        return linear(x, w, activation, bias=bias)
     vp = n * block
     xp = jnp.pad(x, ((0, vp - V), (0, 0))) if vp != V else x
 
     def body(_, xb):
-        return None, linear(xb, w, activation)
+        return None, linear(xb, w, activation, bias=bias)
 
     _, yb = jax.lax.scan(body, None, xp.reshape(n, block, in_dim))
     return yb.reshape(vp, -1)[:V]

@@ -1,4 +1,5 @@
-"""In-degree normalization (the reference's InDegreeNorm / GraphNorm op).
+"""In-degree normalization (the reference's InDegreeNorm / GraphNorm op)
+and batch normalization over the vertex axis.
 
 Reference (``graphnorm_kernel.cu:45-55``): ``out[v,:] = in[v,:] /
 sqrt(indegree(v))`` with the in-degree read off CSR row pointers; applied
@@ -12,13 +13,27 @@ degrees are static for a fixed graph, so we fold the rsqrt at trace time
 and let XLA fuse the multiply into neighboring ops — cheaper than the
 reference's per-element kernel and numerically identical (same
 ``1/sqrt(deg)`` scalar per row).
+
+Batch normalization (:func:`batch_norm_train`, :func:`batch_norm_eval`)
+is beyond the reference: the one op here that reduces over the vertex
+axis, and the one with state that is not a parameter (the running
+statistics).  ``torch.nn.BatchNorm1d``'s arithmetic: biased variance
+for the normalization, unbiased for the running estimate, ``eps`` inside
+the square root.
 """
 
 from __future__ import annotations
 
+from typing import Callable, Optional
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..obs.scopes import ALLREDUCE_SCOPE, BN_STATS_SCOPE
+
+BN_EPS = 1e-5
+BN_MOMENTUM = 0.1
 
 
 def inv_sqrt_degree(in_degree: jax.Array) -> jax.Array:
@@ -45,3 +60,108 @@ def indegree_norm(x: jax.Array, in_degree: jax.Array) -> jax.Array:
     """x: [V, F]; in_degree: int32 [V].  Returns x / sqrt(indegree).
     Plain XLA: the multiply fuses into neighboring ops."""
     return x * inv_sqrt_degree(in_degree)[:, None].astype(x.dtype)
+
+
+def batch_norm_eval(x: jax.Array, scale: jax.Array, shift: jax.Array,
+                    mean: jax.Array, var: jax.Array,
+                    eps: float = BN_EPS) -> jax.Array:
+    """Inference mode: ``scale * (x - mean) / sqrt(var + eps) + shift``
+    on the running statistics it is handed, in float32, rounded once to
+    ``x``'s dtype."""
+    r = jax.lax.rsqrt(var.astype(jnp.float32) + eps)
+    y = ((x.astype(jnp.float32) - mean.astype(jnp.float32))
+         * (r * scale.astype(jnp.float32)) + shift.astype(jnp.float32))
+    return y.astype(x.dtype)
+
+
+def batch_norm_train(x: jax.Array, scale: jax.Array, shift: jax.Array,
+                     count: int, valid: Optional[jax.Array] = None,
+                     psum: Optional[Callable] = None,
+                     eps: float = BN_EPS):
+    """Training mode over the rows of ``x`` ``[rows, F]``: ``(y, mean,
+    var)`` with ``mean`` and the biased ``var`` the moments over the
+    ``count`` real rows — of every partition: ``psum`` (None on one
+    device) sums a ``[2, F]`` array across them, ``valid`` ``[rows]``
+    (None: all) keeps a partition's padding rows out.
+
+    How the moments are taken: ONE pass, the float32 sums of ``x`` and
+    ``x * x`` whatever ``x``'s dtype (``var = E[x^2] - E[x]^2``, floored
+    at 0), so the forward reads ``x`` twice (sums, then normalize) and
+    one ``[2, F]`` collective serves both moments; the two-pass form
+    (``E[(x - mean)^2]``) reads it three times and needs two
+    collectives.  The one-pass form loses ``log2(1 + mean^2 / var)``
+    bits of the variance to cancellation: under 10 of float32's 24 for
+    ``|mean| < 30 sigma``, where pre-activation residual streams stay
+    (the tests hold it to the reference's two-pass form at 1e-4).
+
+    The backward is written by hand and keeps ``x`` (in its own dtype)
+    and two ``[F]`` vectors; autodiff would keep the float32 ``xhat``.
+    With ``xhat = (x - mean) r``, ``r = 1 / sqrt(var + eps)``:
+    ``d shift = sum g``, ``d scale = sum g xhat``, ``dx = scale r (g -
+    d shift / count - xhat d scale / count)`` — the two sums again ONE
+    float32 pass and one ``[2, F]`` ``psum``.  The parameter gradients
+    returned are the partition's own sums (the trainers' gradient
+    all-reduce adds them up, as every other parameter's); ``mean`` and
+    ``var`` carry no gradient (they feed the running statistics)."""
+    n = float(count)
+
+    def masked(a):
+        a = a.astype(jnp.float32)
+        return a if valid is None else jnp.where(valid[:, None], a, 0.0)
+
+    def two_sums(a, b):
+        """``psum([sum a, sum a * b])`` over the rows, float32."""
+        with jax.named_scope(BN_STATS_SCOPE):
+            s = jnp.stack([a.sum(axis=0), (a * b).sum(axis=0)])
+            if psum is None:
+                return s, s
+            with jax.named_scope(ALLREDUCE_SCOPE):
+                return s, psum(s)
+
+    def moments(x):
+        xf = masked(x)
+        _, s = two_sums(xf, xf)
+        mean = s[0] / n
+        var = jnp.maximum(s[1] / n - mean * mean, 0.0)
+        return xf, mean, var
+
+    def normalized(x, scale, shift):
+        xf, mean, var = moments(x)
+        r = jax.lax.rsqrt(var + eps)
+        y = ((xf - mean) * (r * scale.astype(jnp.float32))
+             + shift.astype(jnp.float32))
+        return y.astype(x.dtype), mean, var, r
+
+    @jax.custom_vjp
+    def bn(x, scale, shift):
+        return normalized(x, scale, shift)[:3]
+
+    def fwd(x, scale, shift):
+        y, mean, var, r = normalized(x, scale, shift)
+        return (y, mean, var), (x, scale, mean, r)
+
+    def bwd(res, cts):
+        x, scale, mean, r = res
+        g = masked(cts[0])
+        xhat = (masked(x) - mean) * r
+        own, total = two_sums(g, xhat)
+        dx = (scale.astype(jnp.float32) * r) * (
+            g - total[0] / n - xhat * (total[1] / n))
+        if valid is not None:
+            dx = jnp.where(valid[:, None], dx, 0.0)
+        return (dx.astype(x.dtype), own[1].astype(scale.dtype),
+                own[0].astype(scale.dtype))
+
+    bn.defvjp(fwd, bwd)
+    return bn(x, scale, shift)
+
+
+def running_update(running_mean: jax.Array, running_var: jax.Array,
+                   mean: jax.Array, var: jax.Array, count: int,
+                   momentum: float = BN_MOMENTUM):
+    """The running statistics after one training step:
+    ``(1 - momentum) * running + momentum * batch``, the variance from
+    the unbiased estimate ``var * count / (count - 1)``."""
+    unbiased = var * (count / max(count - 1, 1))
+    return ((1.0 - momentum) * running_mean + momentum * mean,
+            (1.0 - momentum) * running_var + momentum * unbiased)

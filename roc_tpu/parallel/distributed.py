@@ -48,8 +48,9 @@ from ..obs.events import emit, flush_spans, span
 from ..obs.scopes import ALLREDUCE_SCOPE, LOSS_SCOPE, OPT_SCOPE
 from ..ops.loss import masked_softmax_cross_entropy, perf_metrics, summarize_metrics
 from ..train.optimizer import AdamConfig, adam_init, adam_update
-from ..train.trainer import (TrainConfig, cast_floats, compute_dtype_of,
-                             resolve_symmetric, upload)
+from ..train.trainer import (TrainConfig, batch_norm_plan, cast_compute,
+                             compute_dtype_of, resolve_symmetric,
+                             split_state, upload)
 
 
 # THE names of the mesh axes — defined in parallel/__init__ (the
@@ -258,6 +259,13 @@ class ShardedData:
     sect_w: Tuple[jax.Array, ...] = ()
     ring_w: Tuple[jax.Array, ...] = ()
     bd_scale: Tuple[jax.Array, ...] = ()
+    # () or ([P] int32,): each partition's count of real rows (they
+    # come first; the rest is padding), for the ops that reduce over
+    # the vertex axis — attached by the trainer for a model that has
+    # one (DistributedTrainer._with_real_rows); it rides the steps'
+    # ``fuse_tabs`` slot, so every other model's programs are
+    # unchanged
+    real_rows: Tuple[jax.Array, ...] = ()
 
 
 def _sectioned_tables(ptrs: np.ndarray, cols: np.ndarray,
@@ -698,8 +706,8 @@ class DistributedTrainer:
                     cost_weights=self._costmodel.search_weights(
                         **self._phi_flags))
         self.pg = pg
-        self.data = data if data is not None else self._build_data(
-            self.pg)
+        self.data = self._with_real_rows(
+            data if data is not None else self._build_data(self.pg))
         if config.aggr_impl == "bdense" and config.halo != "ring" \
                 and data is None:
             # own build only: injected data carries no plan to report
@@ -823,8 +831,10 @@ class DistributedTrainer:
             self.key, init_key = jax.random.split(key)
             host_params = model.init_params(init_key, dtype=config.dtype)
             self.params = put_replicated(host_params, self.mesh)
-            self.opt_state = put_replicated(adam_init(host_params),
-                                            self.mesh)
+            self.opt_state = put_replicated(
+                adam_init(split_state(host_params,
+                                      model.state_names())[0]),
+                self.mesh)
             s["param_bytes"] = sum(
                 x.nbytes for x in jax.tree_util.tree_leaves(self.params))
         self.adam_cfg = AdamConfig(weight_decay=config.weight_decay)
@@ -863,6 +873,9 @@ class DistributedTrainer:
                     **self._gctx().attention_plan(
                         model._ops, ell_idx=self.data.ell_idx,
                         flat8_idx=next(iter(self.data.sect_idx), None)),
+                    **self._gctx().soft_plan(model._ops, self.compute),
+                    **batch_norm_plan(model._ops,
+                                      dataset.graph.num_nodes),
                     "memory_plan": self._plan},
                 console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
@@ -874,6 +887,20 @@ class DistributedTrainer:
         self.metrics_log = MetricsLog(config.metrics_path)
         # set-up's spans, cli.main's among them, as one batch
         flush_spans("setup")
+
+    def _with_real_rows(self, data: ShardedData) -> ShardedData:
+        """``data`` with each partition's real-row count attached —
+        for a model that reduces over the vertex axis (``batch_norm``,
+        the softmax aggregation's shift), whose moments must leave
+        partition padding out; any other model's data is returned as
+        it came, and its step programs are what they were."""
+        if not any(op.kind in ("batch_norm", "soft_aggregate")
+                   for op in self.model._ops):
+            return data
+        counts = np.asarray([max(r - l + 1, 0)
+                             for l, r in self.pg.bounds], dtype=np.int32)
+        return dc_replace(data, real_rows=(jax.device_put(
+            counts, NamedSharding(self.mesh, P(PARTS_AXIS))),))
 
     def _build_data(self, pg) -> ShardedData:
         """Build + upload the sharded tables for ``pg`` with the
@@ -1180,7 +1207,8 @@ class DistributedTrainer:
         data2 = self._build_data(pg2)
         recompile = (self._static_signature(pg2, data2)
                      != self._static_signature(self.pg, self.data))
-        self.pg, self.data = pg2, data2
+        self.pg = pg2
+        self.data = self._with_real_rows(data2)
         self._phi_cache = None
         self._rebalances += 1
         if recompile:
@@ -1246,6 +1274,7 @@ class DistributedTrainer:
             # the DATA's group, validated == config at init: the
             # tables define what the kernel may assume
             bd_group=self.data.bd_group,
+            total_rows=int(self._dataset.graph.num_nodes),
         )
 
     def _local_gctx(self, edge_src, edge_dst, in_degree, ell_idx,
@@ -1272,8 +1301,12 @@ class DistributedTrainer:
         there.  ~2x the all-gather bytes on ICI; only the 2-D path
         pays it."""
         flat = self.config.aggr_impl in ("attn_flat8", "flat_sum")
-        ell_w, sect_w, ring_w, bd_scale = fuse_tabs
+        ell_w, sect_w, ring_w, bd_scale, *real = fuse_tabs
         extra = {}
+        if real and real[0]:
+            # ([P] int32,) with the parts axis collapsed to 1: this
+            # partition's count, a scalar
+            extra["real_rows"] = real[0][0][0]
         if pid is not None:
             extra["gather_features"] = functools.partial(
                 _gather_by_psum, pid=pid, num_parts=self.pg.num_parts)
@@ -1329,19 +1362,27 @@ class DistributedTrainer:
             part_key = jax.random.fold_in(
                 key, lax.axis_index(PARTS_AXIS) if pid is None else pid)
 
+            # the running statistics ride in ``params`` but are no
+            # parameters: gradient, all-reduce, Adam and the cast see
+            # the rest (every partition computes the same new ones —
+            # their moments are psum'd inside the op)
+            params, state = split_state(params,
+                                        self.model.state_names())
+
             def local_loss(p):
                 # mixed precision: fp32 master params cast per step;
                 # astype's vjp keeps grads (and the psum) in fp32
                 with jax.named_scope(OPT_SCOPE):
-                    p = cast_floats(p, self.compute)
-                logits = self.model.apply(p, feats, gctx, key=part_key,
-                                          train=True,
-                                          remat=self.config.remat)
+                    p = {**cast_compute(p, self.compute), **state}
+                logits, moved = self.model.apply_stateful(
+                    p, feats, gctx, key=part_key, train=True,
+                    remat=self.config.remat)
                 with jax.named_scope(LOSS_SCOPE):
-                    return masked_softmax_cross_entropy(logits, labels,
-                                                        mask)
+                    return masked_softmax_cross_entropy(
+                        logits, labels, mask), moved
 
-            local_l, grads = jax.value_and_grad(local_loss)(params)
+            (local_l, state), grads = jax.value_and_grad(
+                local_loss, has_aux=True)(params)
             # the reference's replica-sum gradient allreduce
             # (optimizer_kernel.cu:88-94) as an ICI psum
             with jax.named_scope(ALLREDUCE_SCOPE):
@@ -1350,7 +1391,7 @@ class DistributedTrainer:
             with jax.named_scope(OPT_SCOPE):
                 params, opt_state = adam_update(params, grads, opt_state,
                                                 lr, self.adam_cfg)
-            return params, opt_state, loss
+            return {**params, **state}, opt_state, loss
 
         return _shard_map(
             step, mesh=mesh,
@@ -1377,7 +1418,7 @@ class DistributedTrainer:
             ell_row_pos, ell_row_id, ring_idx, sect_idx, sect_sub_dst,
             bd_tabs, fuse_tabs, pid=pid)
         with jax.named_scope(OPT_SCOPE):
-            params = cast_floats(params, self.compute)
+            params = cast_compute(params, self.compute)
         return self.model.apply(params, feats, gctx, key=None,
                                 train=False)
 
@@ -1430,7 +1471,7 @@ class DistributedTrainer:
                 d.mask, d.edge_src, d.edge_dst, d.in_degree,
                 d.ell_idx, d.ell_row_pos, d.ell_row_id, d.ring_idx,
                 d.sect_idx, d.sect_sub_dst, d.bd_tabs,
-                (d.ell_w, d.sect_w, d.ring_w, d.bd_scale),
+                (d.ell_w, d.sect_w, d.ring_w, d.bd_scale, d.real_rows),
                 step_key, lr, *extra)
 
         return run_epoch_loop(self, epochs, do_step, self.evaluate)
@@ -1447,7 +1488,8 @@ class DistributedTrainer:
             self.params, d.feats, d.labels, d.mask, d.edge_src,
             d.edge_dst, d.in_degree, d.ell_idx, d.ell_row_pos,
             d.ell_row_id, d.ring_idx, d.sect_idx, d.sect_sub_dst,
-            d.bd_tabs, (d.ell_w, d.sect_w, d.ring_w, d.bd_scale),
+            d.bd_tabs,
+            (d.ell_w, d.sect_w, d.ring_w, d.bd_scale, d.real_rows),
             *extra)
 
     def _eval(self, epoch: int) -> Dict[str, float]:

@@ -122,6 +122,16 @@ TYPED_REFUSAL = (
     "a relation) has no serving export: serve/export.py and the "
     "predictor know one homogeneous graph")
 _TYPED_PARAMS = ("embed_", "rel0_", "root0_")
+# a model with batch statistics (ROADMAP R14): the export's forwards
+# cast every entry of the parameter dict to the compute dtype and its
+# quantized tables know weights, not running statistics; folding a
+# ``batch_norm`` into the neighbouring ``linear`` at export time is the
+# way in, and is not built
+STATE_REFUSAL = (
+    "a model with batch statistics (--model deepergcn: batch_norm's "
+    "running mean and variance ride in the parameter dict as float32 "
+    "state) has no serving export: serve/export.py casts and quantizes "
+    "every entry as a weight")
 
 
 def build_predictor(model, dataset, config, params=None,
@@ -142,6 +152,8 @@ def build_predictor(model, dataset, config, params=None,
     import dataclasses
     if model.uses_relations():
         raise NotImplementedError(TYPED_REFUSAL)
+    if model.state_names():
+        raise NotImplementedError(STATE_REFUSAL)
     model, config, _ = resolve_config(model, dataset, config)
     config = dataclasses.replace(
         config, symmetric=resolve_symmetric(dataset, config.symmetric))

@@ -50,8 +50,23 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     # TPU-era flags
     ap.add_argument("--model",
                     choices=["gcn", "sage", "gin", "gat", "sgc",
-                             "appnp", "gcn2", "rgcn"],
-                    default="gcn")
+                             "appnp", "gcn2", "rgcn", "deepergcn"],
+                    default="gcn",
+                    help="model family (roc_tpu/models): gcn (the "
+                         "reference's), sage, gin, gat, sgc, appnp, "
+                         "gcn2 (GCNII / GCNII*, arXiv:2007.02133), "
+                         "rgcn (R-GCN on a typed graph, "
+                         "arXiv:1703.06103), deepergcn (DeeperGCN, "
+                         "arXiv:2006.07739, as deep_gcns_torch's "
+                         "examples/ogb/ogbn_arxiv runs it: res+ "
+                         "blocks of BatchNorm -> ReLU -> dropout -> "
+                         "GENConv with the softmax_sg aggregation; "
+                         "-layers F-H-...-H-C, one H a layer)")
+    ap.add_argument("--t", type=float, default=None, dest="temperature",
+                    help="for --model deepergcn: the temperature of "
+                         "the softmax neighbour aggregation (default "
+                         "0.1, the ogbn-arxiv command's; fixed, the "
+                         "script's learn_t is off)")
     ap.add_argument("--node-types", type=str, default=None,
                     help="for --model rgcn: the typed graph's vertex "
                          "kinds as comma-separated counts in id order "
@@ -455,6 +470,25 @@ def main(argv: Optional[List[str]] = None,
                   f"initial residual adds H_0 into every layer), got "
                   f"{layers[1:-1]}", file=sys.stderr)
             return 2
+    if args.temperature is not None and args.model != "deepergcn":
+        print("error: --t applies to --model deepergcn only",
+              file=sys.stderr)
+        return 2
+    if args.model == "deepergcn":
+        if args.temperature is None:
+            args.temperature = 0.1
+        if args.temperature <= 0.0:
+            print("error: --t must be > 0", file=sys.stderr)
+            return 2
+        if len(layers) < 3:
+            print("error: deepergcn needs at least one GENConv layer "
+                  "(F-H-C)", file=sys.stderr)
+            return 2
+        if any(h != layers[1] for h in layers[1:-1]):
+            print(f"error: deepergcn hidden widths must all match (the "
+                  f"residual adds h^l into every layer), got "
+                  f"{layers[1:-1]}", file=sys.stderr)
+            return 2
     node_types = embed_types = ()
     if (args.node_types is not None
             or args.embed_types is not None) and args.model != "rgcn":
@@ -575,6 +609,8 @@ def main(argv: Optional[List[str]] = None,
     if args.model == "rgcn":
         kwargs = {"node_types": node_types, "embed_types": embed_types,
                   "relations": ds.typed.relations}
+    if args.model == "deepergcn":
+        kwargs["t"] = args.temperature
     model = build[args.model](layers, dropout_rate=args.dropout,
                               **kwargs)
     dt, cdt = resolve_dtypes(args.dtype)

@@ -1075,6 +1075,26 @@ def _relation_context(dataset: Dataset, aggr_impl: str, tables,
         rel_w=tuple(w), rel_meta=tuple(meta))
 
 
+def batch_norm_plan(ops, num_nodes: int) -> Dict[str, Any]:
+    """The ``batch_norm`` entry of the run manifest's ``resolved``:
+    how many such ops, their width, the rows their moments count (the
+    real vertices of every partition, never padding) and the bytes of
+    running statistics that travel with the parameters.  Empty for a
+    model without the op."""
+    bns = [(i, op) for i, op in enumerate(ops)
+           if op.kind == "batch_norm"]
+    if not bns:
+        return {}
+    return {"batch_norm": {
+        "ops": [i for i, _ in bns], "count": len(bns),
+        "width": max(op.dim for _, op in bns),
+        "rows_counted": int(num_nodes),
+        "eps": bns[0][1].attrs["eps"],
+        "momentum": bns[0][1].attrs["momentum"],
+        "moments": "float32 sums of x and x*x, one pass",
+        "stats_bytes": sum(2 * op.dim * 4 for _, op in bns)}}
+
+
 def model_features(model: Model, dataset: Dataset) -> np.ndarray:
     """The feature rows the model's input holds: all of them, or for a
     typed model the rows of the kinds that have any (kind order), the
@@ -1088,15 +1108,42 @@ def model_features(model: Model, dataset: Dataset) -> np.ndarray:
     return np.asarray(dataset.features)[np.concatenate(keep)]
 
 
+def split_state(params, names):
+    """``(trainable, state)``: the parameter dict without and with only
+    the entries ``names`` (``Model.state_names()``: the batch-norm
+    running statistics).  The optimizer, the gradient and the
+    compute-dtype cast see ``trainable`` alone; a step merges the new
+    state back (``{**trainable, **state}``).  With no ``names`` the
+    first is ``params`` itself."""
+    if not names:
+        return params, {}
+    return ({k: v for k, v in params.items() if k not in names},
+            {k: params[k] for k in names})
+
+
+def cast_compute(params, dtype):
+    """:func:`cast_floats` over a parameter dict, leaving a
+    ``batch_norm``'s entries (``bn_*``: scale, shift and the running
+    statistics) in float32: they are ``[F]`` vectors read by float32
+    arithmetic, and a bfloat16 copy would only round them."""
+    from ..obs.scopes import BN_PARAM_PREFIX
+    if not isinstance(params, dict) or not any(
+            k.startswith(BN_PARAM_PREFIX) for k in params):
+        return cast_floats(params, dtype)
+    return {k: (v if k.startswith(BN_PARAM_PREFIX)
+                else cast_floats(v, dtype)) for k, v in params.items()}
+
+
 def cast_params(params, dtype):
     """The step's compute-dtype copy of the parameters, under
     ``roc.opt``; a typed model's embedding tables under
     ``roc.opt.embed`` inside it, so their stream can be told from the
-    weights' (obs/scopes.py)."""
+    weights' (obs/scopes.py); a ``batch_norm``'s entries stay float32
+    (:func:`cast_compute`)."""
     from ..obs.scopes import EMBED_PARAM_PREFIX, OPT_EMBED_SCOPE
     with jax.named_scope(OPT_SCOPE):
         if not any(k.startswith(EMBED_PARAM_PREFIX) for k in params):
-            return cast_floats(params, dtype)
+            return cast_compute(params, dtype)
         out = {}
         for k, v in params.items():
             if k.startswith(EMBED_PARAM_PREFIX):
@@ -1146,7 +1193,8 @@ class Trainer:
             key = jax.random.PRNGKey(config.seed)
             self.key, init_key = jax.random.split(key)
             self.params = model.init_params(init_key, dtype=config.dtype)
-            self.opt_state = adam_init(self.params)
+            self.opt_state = adam_init(
+                split_state(self.params, model.state_names())[0])
             if self._mesh_model > 1:
                 from ..parallel.distributed import (make_mesh,
                                                     put_replicated)
@@ -1159,6 +1207,11 @@ class Trainer:
         self._head = None
         self._head_chunk = resolve_head_chunk(
             config, dataset.graph.num_nodes)
+        if config.features == "host" and model.state_names():
+            raise NotImplementedError(
+                "features='host' streams a stateless head: a model with "
+                "batch statistics (batch_norm) trains with "
+                "features='hbm'")
         if config.features == "host":
             # host-resident features streamed through the first layer
             # (the reference's ZC tier, types.cu:22-32)
@@ -1305,6 +1358,9 @@ class Trainer:
                     **self.gctx.relation_plan(
                         model._ops, dataset.typed,
                         model.loss_cut()._ops),
+                    **self.gctx.soft_plan(model._ops, self.compute),
+                    **batch_norm_plan(model._ops,
+                                      dataset.graph.num_nodes),
                     "memory_plan": self._plan},
                 console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog
@@ -1322,19 +1378,23 @@ class Trainer:
         # gctx arrives as a jit ARGUMENT (GraphContext is a pytree):
         # closure-capturing it would embed the edge/ELL tables as HLO
         # constants — see the register_pytree_node note in builder.py
+        # the running statistics ride in ``params`` but are no
+        # parameters: the gradient, Adam and the cast see the rest
+        params, state = split_state(params, self.model.state_names())
+
         def objective(p):
             # mixed precision: compute in self.compute; the astype vjp
             # returns fp32 cotangents, so grads/Adam stay in dtype
-            p = cast_params(p, self.compute)
-            loss, _ = self.model.loss_fn(p, feats, labels, mask,
-                                         gctx, key=key, train=True,
-                                         remat=self.config.remat)
-            return loss
-        loss, grads = jax.value_and_grad(objective)(params)
+            p = {**cast_params(p, self.compute), **state}
+            return self.model.loss_and_state(
+                p, feats, labels, mask, gctx, key=key,
+                remat=self.config.remat)
+        (loss, state), grads = jax.value_and_grad(
+            objective, has_aux=True)(params)
         with jax.named_scope(OPT_SCOPE):
             params, opt_state = adam_update(params, grads, opt_state,
                                             lr, self.adam_cfg)
-        return params, opt_state, loss
+        return {**params, **state}, opt_state, loss
 
     def _eval_step_impl(self, params, feats, labels, mask, gctx):
         params = cast_params(params, self.compute)

@@ -255,3 +255,56 @@ def test_cli_rgcn_on_the_synthetic_graph(capsys):
     assert tr.params["embed_1"].shape == (212, 8)
     assert tr.feats.shape == (300, 8)
     assert "[INFER]" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("argv,msg", [
+    (["--model", "gcn", "--t", "0.1", "-layers", "8-8-3"],
+     "--t applies to --model deepergcn only"),
+    (["--model", "deepergcn", "--t", "0", "-layers", "8-8-8-3"],
+     "--t must be > 0"),
+    (["--model", "deepergcn", "-layers", "8-3"],
+     "at least one GENConv layer"),
+    (["--model", "deepergcn", "-layers", "8-8-16-3"],
+     "hidden widths must all match"),
+])
+def test_deepergcn_flag_validation_fails_fast(argv, msg, capsys):
+    assert _run(argv) == 2
+    assert msg in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("extra", [[], ["--parts", "2"],
+                                   ["--dtype", "mixed"]])
+def test_cli_deepergcn_trains_evaluates_and_resumes(extra, tmp_path,
+                                                    capsys):
+    """--model deepergcn through resolve_config and either trainer:
+    trains, prints an inference pass, saves; the resumed run's eval
+    reads the saved running statistics (same [INFER] line)."""
+    ck = str(tmp_path / "ck.npz")
+    base = ["--model", "deepergcn", "-layers", "12-16-16-16-4", "--t",
+            "0.1", "-decay", "0", "--eval-every", "3"] + extra
+    seen = {}
+    rc = cli.main(["--cpu", "--no-compile-cache", "-e", "3",
+                   "--checkpoint", ck] + base,
+                  inspect=lambda tr: seen.update(tr=tr))
+    assert rc == 0
+    tr = seen["tr"]
+    assert [op.kind for op in tr.model._ops].count("batch_norm") == 3
+    assert [op.kind for op in tr.model._ops].count("soft_aggregate") == 3
+    assert np.abs(np.asarray(tr.params["bn_0_mean"])).max() > 0
+    assert set(tr.opt_state.m) == set(tr.params) - set(
+        tr.model.state_names())
+    first = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("[INFER][2]")]
+    assert first
+    assert _run(["-e", "3", "--resume", ck, "--eval-only"] + base) == 0
+    again = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("[INFER]")]
+    assert len(again) == 1
+
+
+def test_help_lists_deepergcn_with_its_source(capsys):
+    with pytest.raises(SystemExit):
+        cli.parse_args(["--help"])
+    out = capsys.readouterr().out
+    assert "deepergcn" in out and "arXiv:2006.07739" in " ".join(
+        out.split())

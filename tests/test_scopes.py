@@ -18,6 +18,7 @@ import pytest
 
 from roc_tpu.core.graph import synthetic_dataset
 from roc_tpu.models.builder import Model
+from roc_tpu.models.deepergcn import build_deepergcn
 from roc_tpu.models.gat import build_gat
 from roc_tpu.models.gcn import build_gcn
 from roc_tpu.models.gin import build_gin
@@ -33,10 +34,12 @@ from roc_tpu.train.trainer import TrainConfig, Trainer
 
 LAYERS = [12, 8, 5]
 BUILDERS = {"gcn": build_gcn, "sage": build_sage, "gin": build_gin,
-            "sgc": build_sgc, "gat": build_gat}
+            "sgc": build_sgc, "gat": build_gat,
+            "deepergcn": build_deepergcn}
 # attention needs the ELL tables: the trainers force that layout
 CASES = [(fam, impl) for fam in ("gcn", "sage", "gin", "sgc")
-         for impl in ("sectioned", "flat_sum", "segment")] + [("gat", "ell")]
+         for impl in ("sectioned", "flat_sum", "segment")] + [("gat", "ell")] \
+    + [("deepergcn", impl) for impl in ("sectioned", "flat_sum")]
 # instructions traced from a primitive (their op_name starts "jit(")
 # that may sit outside every roc. scope: the argument plumbing of jit
 # and shard_map, value_and_grad's seed and, in the distributed step, the
@@ -293,3 +296,46 @@ def test_profile_dir_writes_the_scope_map_beside_the_trace(tmp_path):
         p[0] for p in map(parse_op_name, got["scopes"].values()) if p}
     assert any(name.endswith(".xplane.pb")
                for _, _, names in os.walk(prof) for name in names)
+
+
+@pytest.mark.parametrize("parts", [1, 4])
+def test_batch_norm_and_softmax_aggregation_name_their_parts(ds, parts):
+    """``roc.bn.stats`` sits inside ``roc.dense.op<i>.batch_norm`` and
+    ``roc.sagg.weights`` inside the softmax aggregation's
+    ``roc.agg.op<i>``, forward and backward, and neither changes the
+    op's class; across partitions the moments' ``psum`` is under
+    ``roc.allreduce`` inside the batch_norm's scope — the one
+    collective of the step between a layer's dense ops — and the
+    shift's all-gather under ``roc.allreduce`` inside the
+    aggregation's."""
+    from roc_tpu.obs.scopes import (ALLREDUCE_SCOPE, BN_STATS_SCOPE,
+                                    SAGG_WEIGHTS_SCOPE)
+    model, train, evalm = _steps(ds, "deepergcn", "sectioned", parts)
+    kind_of = {i: op.kind for i, op in enumerate(model._ops)}
+    names = list(train["scopes"].values())
+    for scope, kind, cls in ((BN_STATS_SCOPE, "batch_norm", DENSE),
+                             (SAGG_WEIGHTS_SCOPE, "soft_aggregate", AGG)):
+        under = [n for n in names if scope in n]
+        assert under
+        parsed = [parse_op_name(n) for n in under]
+        assert {kind_of[i] for _, i, _ in parsed} == {kind}
+        assert {way for _, _, way in parsed} == {"fwd", "bwd"}
+        plain = [p for n, p in zip(under, parsed)
+                 if ALLREDUCE_SCOPE not in n]
+        assert plain and {c for c, _, _ in plain} == {cls}
+    inside_bn = [n for n in names
+                 if ".batch_norm" in n and ALLREDUCE_SCOPE in n]
+    inside_agg = [n for n in names
+                  if ALLREDUCE_SCOPE in n and "roc.agg.op" in n]
+    if parts == 1:
+        assert not inside_bn and not inside_agg
+    else:
+        assert inside_bn and all(BN_STATS_SCOPE in n for n in inside_bn)
+        assert any("psum" in n or "all-reduce" in n or "all_reduce" in n
+                   for n in inside_bn)
+        assert {parse_op_name(n)[0] for n in inside_bn} == {ALLREDUCE}
+        assert inside_agg and all(SAGG_WEIGHTS_SCOPE in n
+                                  for n in inside_agg)
+    # eval reads the running statistics: no moment reduction there
+    assert not [n for n in evalm["scopes"].values()
+                if BN_STATS_SCOPE in n]
