@@ -272,13 +272,18 @@ def ell_from_graph(row_ptr: np.ndarray, col_idx: np.ndarray,
 class SectionedEll:
     """Source-sectioned width-8 sub-row layout — the fast-gather form.
 
-    Measured on TPU v5 lite (2026-07-29, V=233k E=115M F=256 fp32):
-    XLA's gather+reduce runs ~9.3 ns/row when the gather TABLE is
-    <= ~64 MiB (VMEM-resident) and the index block is shaped ``[N, 8]``
-    with large N, vs ~15.7-17.4 ns/row for whole-table gathers — so
-    splitting the source rows into <= ``section_rows`` sections and
-    rewriting every ELL row as width-8 sub-rows cut the Reddit-scale
-    aggregation from 2006 ms to 865 ms (2.3x).  Layout per section:
+    Measured on TPU v5 lite: a gather out of a table that stays in
+    VMEM (<= ~64 MiB) costs a slot 3.18 ns at 256 bf16 lanes, whatever
+    the chunk's height (PERF §5, PR 34 / 40), against 9.1-10.2 ns a
+    row out of a whole table in HBM (products, PR 39) — so splitting
+    the source rows into <= ``section_rows`` sections and rewriting
+    every ELL row as width-8 sub-rows cut the Reddit-scale
+    aggregation from 2006 ms to 865 ms (2.3x, July).  Since PR 41 a
+    section's slots are summed by a kernel that holds the section in
+    VMEM and never writes the gathered rows out (ops/aggregate.py
+    ``_gather_sum``): 2.41 ms a Reddit chunk step of 131,072 sub-rows
+    at 256 lanes, where XLA's gather and reduce took 4.65 (my chip
+    run, PR 41).  Layout per section:
 
     - ``idx[s]``: int32 ``[n_chunks, seg_rows, 8]`` section-LOCAL source
       ids (dummy = the section's appended zero row); each original row's
@@ -309,7 +314,9 @@ class SectionedEll:
       sees them (ops/aggregate.py ``scan_seg_sum``).
 
     The aggregation is a ``lax.scan`` over chunks carrying the output:
-    gather-sum from the section slice, then a sorted scatter-add of the
+    the sub-rows' partials out of the section slice (the gather-sum
+    kernel, ``ops/aggregate.py gather_sum_form``), then a sorted
+    scatter-add of the
     ``[seg_rows, F]`` partials into the ``[win_rows, F]`` window of the
     carry that starts at the chunk's first destination — the carry
     itself is only sliced and updated in place, so a chunk step costs

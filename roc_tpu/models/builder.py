@@ -39,8 +39,8 @@ from ..parallel import PARTS_AXIS
 from ..ops.aggregate import (aggregate_ell, aggregate_ell_max,
                              aggregate_ell_sect, aggregate_flat_max,
                              aggregate_flat_sum, aggregate_segment,
-                             scan_seg_sum, scan_window_rows,
-                             seg_sum_updates)
+                             gather_sum_slots, scan_seg_sum,
+                             scan_window_rows, seg_sum_updates)
 from ..ops.dense import AC_MODE_NONE, AC_MODE_RELU, AC_MODE_SIGMOID
 from ..ops.loss import masked_softmax_cross_entropy
 from ..ops.norm import (BN_EPS, BN_MOMENTUM, batch_norm_eval,
@@ -236,7 +236,8 @@ class GraphContext:
             valid=self.valid_rows(),
             psum=self.psum if self.partitioned else None)
 
-    def agg_window(self, ops=(), tables=None, edges=None) -> dict:
+    def agg_window(self, ops=(), tables=None, edges=None,
+                   compute=jnp.float32) -> dict:
         """How far the chunk scan's destination window engaged — the
         run manifest's ``resolved`` carries it (obs/manifest.py):
         rows a chunk step reads and writes per section
@@ -263,14 +264,25 @@ class GraphContext:
         narrower op may pick a taller tile of the same table).  The
         distributed trainer, whose tables live outside its context,
         hands them in as ``tables`` (stacked: the trailing axes are
-        read, every part's slots counted)."""
+        read, every part's slots counted).  And how each of those
+        tables' chunk steps make their partials
+        (``ops/aggregate.py gather_sum_form``, read off the table's
+        rows, the widest sum op's width and the ``compute`` dtype the
+        table is held in): ``agg_gather_sum``, one ``[form, slots]`` a
+        table — ``"fused"`` where the kernel sums a sub-row's 8 slots
+        in VMEM, ``"two_pass"`` where they go through HBM — and the
+        slots a pass gathers."""
         carry = self.num_rows + 1
         wins = [m[2] for m in self.sect_meta if len(m) > 2]
         # a hand-made sect_meta may stop at the window: no bands known
         bands = [m[3] if len(m) > 3 else () for m in self.sect_meta
                  if len(m) > 2]
+        # rows of the table a scan gathers out of: the section and its
+        # zero row, or the gathered matrix and its
+        rows = [m[1] + 1 for m in self.sect_meta if len(m) > 2]
         if self.flat8_win:
             wins, bands = [self.flat8_win], [self.flat8_bands]
+            rows = [self.gathered_rows + 1]
         pads = [[i, op.dim, self._lane_width(op.dim)]
                 for i, op in enumerate(ops)
                 if op.kind == "fused_aggregate"
@@ -287,13 +299,17 @@ class GraphContext:
             tables = ()
         slots = sum(int(t.size) for t in tables)
         width = max((p[2] for p in pads), default=LANE_WIDTH)
-        # (n_chunks, seg_rows, window, bands) of every scanned table
-        scans = [(*t.shape[-3:-1], scan_window_rows(w, carry), b)
-                 for t, w, b in zip(tables, wins, bands)]
-        scans += [(*d.shape, scan_window_rows(m[3], m[1] + 1), m[5])
+        # (n_chunks, seg_rows, window, bands, table rows, sub-row
+        # width) of every scanned table
+        scans = [(*t.shape[-3:-1], scan_window_rows(w, carry), b, r,
+                  t.shape[-1])
+                 for t, w, b, r in zip(tables, wins, bands, rows)]
+        scans += [(*d.shape, scan_window_rows(m[3], m[1] + 1), m[5],
+                   m[2] + 1, 8)
                   for m, d in zip(self.rel_meta, self.rel_dst)
                   if self.aggr_impl == "flat_sum"]
-        segs = [scan_seg_sum(seg, w, b, width) for _, seg, w, b in scans]
+        segs = [scan_seg_sum(seg, w, b, width)
+                for _, seg, w, b, _, _ in scans]
         return {"agg_window_rows": [scan_window_rows(w, carry)
                                     for w in wins],
                 "agg_carry_rows": carry if wins else None,
@@ -301,7 +317,10 @@ class GraphContext:
                 "agg_seg_sum": [s and list(s) for s in segs],
                 "agg_carry_updates": [
                     seg_sum_updates(n, seg, s)
-                    for (n, seg, _, _), s in zip(scans, segs)],
+                    for (n, seg, *_), s in zip(scans, segs)],
+                "agg_gather_sum": [
+                    gather_sum_slots(n, seg, r, width, compute, sub_w)
+                    for n, seg, _, _, r, sub_w in scans],
                 "agg_chunk_rows": [list(t.shape[-3:-1])
                                    for t in tables],
                 "agg_slot_fill": (

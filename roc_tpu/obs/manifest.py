@@ -77,7 +77,10 @@ def run_manifest(config=None, dataset=None, model=None,
     ``[T, B]`` (or null) each scanned table's chunk step sums its
     partials at on the MXU, and ``agg_carry_updates``, ``[before,
     after]`` rows a pass adds into its carry without and with it (how
-    far ``ops/aggregate.py scan_seg_sum`` engaged); one ``attention`` entry
+    far ``ops/aggregate.py scan_seg_sum`` engaged); ``agg_gather_sum``,
+    ``[form, slots]`` a scanned table (``"fused"``: the kernel sums a
+    sub-row's 8 slots in VMEM; ``"two_pass"``: they go through HBM;
+    ``ops/aggregate.py gather_sum_form``); one ``attention`` entry
     per attention op (heads, head width, layout, passes over the edge
     tables, slots a pass, carry rows) and one ``attention_backward``
     entry (the gradient rule, its edge passes, the whole-array

@@ -24,8 +24,10 @@ chooses among (``core/ell.py resolve_auto_impl``,
   (:func:`aggregate_flat_sum`, MAX twin :func:`aggregate_flat_max`):
   width-8 sub-row tables walked by one chunk scan
   (:func:`_scan_window_sum`), per source section or over one global
-  section; where the table is dense the scan sums a row's sub-rows
-  on the MXU before the carry sees them (:func:`scan_seg_sum`).
+  section; a table that fits VMEM has a sub-row's slots summed there
+  by a Pallas kernel (:func:`_gather_sum`, :func:`gather_sum_form`),
+  and where the table is dense the scan sums a row's sub-rows on
+  the MXU before the carry sees them (:func:`scan_seg_sum`).
 - ``bdense`` (ops/blockdense.py): dense adjacency tiles on the MXU,
   the residual edges through ``sectioned``.
 
@@ -40,9 +42,14 @@ measurement: ``PERF.md`` §5–§6 and the ledger hold the current ones,
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.custom_derivatives import SymbolicZero
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def aggregate_segment(feats: jax.Array, edge_src: jax.Array,
@@ -251,14 +258,286 @@ def _seg_sum(w: jax.Array, part: jax.Array, loc: jax.Array,
         slab.reshape(nt * B, F)).astype(w.dtype)
 
 
+# What a chunk step's ``[seg_rows, F]`` partials cost on the v5e (my
+# chip run, PR 41, ``chiprun_out/pr41/race.json``: one jitted scan a
+# form at the cells' shapes, window and segmented sum as in the cells;
+# ms a chunk step, the parent's gather + reduce first; PERF §6, PR 41):
+# - a VMEM-sized table: Reddit's section (65,537 rows, 131,072
+#   sub-rows, bf16 weights) 4.653 at 256 lanes and 3.301 at 41;
+#   arxiv's (56,449 rows, 106,496 sub-rows) 5.512 at 256 weighted,
+#   5.457 unweighted, 3.287 at 128.  The kernel below holding the
+#   table in VMEM: 2.406 at Reddit's 256 lanes with two bfloat16
+#   columns a 32-bit word (3.947 with the table widened to float32:
+#   the kernel is bound by its row loads, one vreg a 128-lane word
+#   row), 2.049 at 41, 5.07 / 4.61 / 2.41 at arxiv's with float32
+#   words; tiles of 256 ... 1,024 sub-rows within 1%.  XLA's own
+#   forms lose there: eight ``[seg, F]`` gathers summed 6.692 / 3.158,
+#   one slot-major ``[8, seg, F]`` gather summed 7.693 / 4.277 —
+#   XLA keeps the gather a fusion of its own in every form, so the
+#   gathered rows round-trip through HBM whichever way they are summed.
+#   In the Reddit cell the kernel takes 2.281 / 2.073 ms a chunk step
+#   at 256 / 41 lanes, 2.18 / 1.98 ns a slot (the traced run, PERF §5).
+# - a table in HBM (products' 2,449,030 rows, typed 1,939,744, chunks
+#   of 8,192): 0.821 / 0.695 at products' 256 / 128 lanes, 0.719 typed
+#   (fp32 weights, slot-major); slot-major gather + float32 sum over
+#   the leading axis 0.751 / 0.666 / 0.686 — the eight-gather form
+#   the same to 0.3%.  No kernel holds such a table.
+# The rule: a table whose kernel needs no more VMEM than the most it
+# was measured holding is summed by the kernel; any other is gathered
+# slot-major and summed over the leading axis in float32.  Both round a
+# partial once, from float32.  The kernel's VMEM is the table's words,
+# its float32 accumulator and its two output blocks
+# (:func:`_vmem_bytes`); the most measured is the float32 Reddit
+# section at 256 lanes, 64.0 + 0.5 + 1.0 MiB, run under a limit 16 MiB
+# above that.  The constant is the v5e's: 128 MiB of VMEM a core.  A
+# chip with less needs its own (as ``core/ell.py`` keeps the section
+# bound by TPU kind).
+GATHER_SUM_VMEM_BYTES = 66 << 20
+GATHER_SUM_TILE = 512            # sub-rows a grid step
+LANES = 128
+
+
+def _vmem_lanes(F: int, dtype) -> int:
+    """32-bit lanes a row of a ``[rows, F]`` table of ``dtype`` takes in
+    the kernel's VMEM: two bfloat16 columns a word past one vreg (in
+    groups of 256 columns), one float32 word a column otherwise."""
+    lanes = -(-F // LANES) * LANES
+    if jnp.dtype(dtype) == jnp.bfloat16 and lanes > LANES:
+        return -(-F // (2 * LANES)) * LANES
+    return lanes
+
+
+def _vmem_bytes(rows: int, F: int, dtype, tile: int = GATHER_SUM_TILE
+                ) -> int:
+    """VMEM the kernel holds for a ``[rows, F]`` table of ``dtype``
+    (its partials in ``dtype`` too) at ``tile`` sub-rows a grid step:
+    the table's words (rows padded to 8), the float32 accumulator
+    ``[tile, cols]`` and the double-buffered ``[tile, F]`` output
+    blocks, each row padded to whole 128-lane registers."""
+    lanes = _vmem_lanes(F, dtype)
+    padded = -(-F // LANES) * LANES
+    cols = 2 * lanes if lanes != padded else lanes
+    return (-(-rows // 8) * 8 * lanes * 4 + tile * cols * 4
+            + 2 * tile * padded * jnp.dtype(dtype).itemsize)
+
+
+def gather_sum_form(rows: int, F: int, dtype=jnp.bfloat16) -> str:
+    """How a chunk step of :func:`_scan_window_sum` makes its partials
+    out of a ``[rows, F]`` table of ``dtype``: ``"fused"`` — what the
+    kernel holds fits its VMEM (:func:`_vmem_bytes` against
+    :data:`GATHER_SUM_VMEM_BYTES`) and :func:`_gather_sum` sums a
+    sub-row's 8 slots there, writing only ``[seg_rows, F]`` — or
+    ``"two_pass"``: the gathered slots reach HBM and are read back by
+    the sum.  Read off the shapes; no knob."""
+    fits = _vmem_bytes(rows, F, dtype) <= GATHER_SUM_VMEM_BYTES
+    return "fused" if fits else "two_pass"
+
+
+def gather_sum_slots(n_chunks: int, seg_rows: int, rows: int, F: int,
+                     dtype=jnp.bfloat16, width: int = 8) -> list:
+    """``[form, slots]`` for a table of ``rows`` rows scanned in
+    ``n_chunks`` chunks of ``seg_rows`` sub-rows of ``width`` slots at
+    feature width ``F`` — the ``plan`` line's ``agg_gather_sum``."""
+    return [gather_sum_form(rows, F, dtype),
+            n_chunks * seg_rows * width]
+
+
+def _vmem_words(table: jax.Array) -> jax.Array:
+    """``table`` as the kernel holds it: float32 words, or — a
+    bfloat16 table wider than 128 lanes — column ``256 g + j`` in the
+    low half of word ``128 g + j`` and column ``256 g + 128 + j`` in
+    its high half (zero-padded to whole groups), so a row is half the
+    loads and unpacks into 128-lane-aligned pieces.  The rows are
+    zero-padded to a multiple of 8, as VMEM holds them anyway: sections
+    a row apart in size then share one compiled kernel (the last row
+    stays a zero dummy)."""
+    R, F = table.shape
+    if R % 8:
+        table = jnp.concatenate(
+            [table, jnp.zeros((-R % 8, F), table.dtype)], axis=0)
+        R += -R % 8
+    L = _vmem_lanes(F, table.dtype)
+    if L == -(-F // LANES) * LANES:
+        return table.astype(jnp.float32)
+    bits = lax.bitcast_convert_type(
+        jnp.pad(table, ((0, 0), (0, 2 * L - F))), jnp.uint16)
+    bits = bits.astype(jnp.uint32).reshape(R, L // LANES, 2, LANES)
+    return (bits[:, :, 0] | (bits[:, :, 1] << 16)).reshape(R, L)
+
+
+def _gather_sum_kernel(*refs, weighted: bool, packed: bool, F: int,
+                       unroll: int):
+    """One grid step: ``T`` sub-rows' partials.  The table's words are
+    copied into VMEM once a call (the grid runs in order and the
+    scratch persists); a sub-row's 8 ids (and weights) are read from
+    SMEM, its 8 rows loaded one by one, each widened to float32 (a
+    bfloat16 column is exact in float32, and so is its product with a
+    bfloat16 weight) and summed in float32; the tile is rounded to the
+    output's dtype once.  The ids (and weights) arrive slot-major,
+    ``[8, T]``.  ``unroll`` sub-rows an iteration of the
+    loop: 8 on the chip, 1 interpreted (a fifth of the compile time
+    off the TPU, where the loop's speed does not matter)."""
+    if weighted:
+        idx_ref, w_ref, tab_hbm, out_ref, tab, acc = refs
+    else:
+        idx_ref, tab_hbm, out_ref, tab, acc = refs
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        pltpu.sync_copy(tab_hbm, tab)
+
+    groups = tab.shape[1] // LANES
+
+    def slot(r, k):
+        v = tab[pl.ds(idx_ref[k, r], 1), :]
+        if packed:
+            lo = lax.bitcast_convert_type(v << 16, jnp.float32)
+            hi = lax.bitcast_convert_type(
+                v & jnp.uint32(0xFFFF0000), jnp.float32)
+            v = jnp.concatenate(
+                [p[:, g * LANES:(g + 1) * LANES]
+                 for g in range(groups) for p in (lo, hi)], axis=1)
+        return v * w_ref[k, r] if weighted else v
+
+    def row(u, q):
+        r = q * unroll + u
+        # a loop unrolled whole is traced once and lowered step by step
+        # by Mosaic: the code Python's unrolling gave (PERF §6, PR 41),
+        # two traces of a slot where that took 64 an iteration of rows
+        acc[pl.ds(r, 1), :] = lax.fori_loop(
+            1, idx_ref.shape[0], lambda k, s: s + slot(r, k),
+            slot(r, 0), unroll=True)
+        return q
+
+    def rows(q, carry):
+        lax.fori_loop(0, unroll, row, q, unroll=True)
+        return carry
+
+    lax.fori_loop(0, acc.shape[0] // unroll, rows, 0)
+    out_ref[...] = acc[:, :F].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("F", "dtype", "interpret"))
+def _gather_sum_call(words: jax.Array, idx: jax.Array, w, F: int,
+                     dtype, interpret=None) -> jax.Array:
+    """``[seg, F]`` partials of the slot-major ``[8, seg]`` ids ``idx``
+    (weights ``w`` like it, or None) out of the table's VMEM ``words``
+    (:func:`_vmem_words`) — the Pallas call; interpreted off the TPU.
+    Slot-major because a ``[seg, 8]`` operand is held with its 8-wide
+    axis padded to 128 lanes, and flattening it is a relayout that
+    XLA:TPU takes 20 s to compile at 131,072 sub-rows; a ``[8, seg]``
+    tile of ids is what SMEM holds as it is.  A chunk whose height the
+    tile does not divide is padded with the table's last row (the zero
+    dummy) at weight 0."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    width, seg = idx.shape
+    idx = idx.astype(jnp.int32)     # SMEM holds 32-bit words
+    R, L = words.shape
+    T = min(GATHER_SUM_TILE, -(-seg // 128) * 128)
+    pad = -(-seg // T) * T - seg
+    if pad:
+        idx = jnp.concatenate(
+            [idx, jnp.full((width, pad), R - 1, idx.dtype)], axis=1)
+        if w is not None:
+            w = jnp.concatenate([w, jnp.zeros((width, pad), w.dtype)],
+                                axis=1)
+    smem = pl.BlockSpec((width, T), lambda i: (0, i),
+                        memory_space=pltpu.SMEM)
+    args, specs = [idx], [smem]
+    if w is not None:
+        args.append(w.astype(jnp.float32))
+        specs.append(smem)
+    packed = words.dtype == jnp.uint32
+    cols = 2 * L if packed else L
+    word_bytes = R * (-(-L // LANES) * LANES) * 4
+    out = pl.pallas_call(
+        functools.partial(_gather_sum_kernel, weighted=w is not None,
+                          packed=packed, F=F, unroll=1 if interpret else 8),
+        out_shape=jax.ShapeDtypeStruct((seg + pad, F), dtype),
+        grid=((seg + pad) // T,),
+        in_specs=specs + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((T, F), lambda i: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((R, L), words.dtype),
+                        pltpu.VMEM((T, cols), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_bytes(R, F, dtype, T) + (16 << 20)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * width * (seg + pad) * cols, transcendentals=0,
+            bytes_accessed=word_bytes + (seg + pad) * (
+                width * 4 * (2 if w is not None else 1)
+                + F * jnp.dtype(dtype).itemsize)),
+        name="agg_gather_sum",
+        interpret=interpret,
+    )(*args, words)
+    return out[:seg] if pad else out
+
+
+def _two_pass_sum(table, idx, w, dtype):
+    """``sum_k table[idx[k]] (* w[k])`` over slot-major ``[8, seg]``
+    ids and weights in XLA: one ``[8, seg, F]`` gather (it reaches
+    HBM), each row times its weight in float32, summed over the slot
+    axis in float32 and rounded once to ``dtype`` — the partials of a
+    table the kernel cannot hold, and the kernel's tangent."""
+    g = table[idx].astype(jnp.float32)
+    if w is not None:
+        g = g * w.astype(jnp.float32)[:, :, None]
+    return g.sum(axis=0).astype(dtype)
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(4,))
+def _gather_sum(table, words, idx, w, dtype):
+    """:func:`_two_pass_sum`'s value computed by the kernel from
+    ``words``, the table's VMEM form (:func:`_vmem_words`, made once a
+    scan and handed in under ``stop_gradient``).  Linear in ``table``:
+    the tangent is :func:`_two_pass_sum` of the tangent, which JAX
+    transposes into the scatter a directed graph's autodiff needs.  The
+    weights carry no gradient: every caller's are constants (the baked
+    norm scales, ``1/deg``, the softmax aggregation's detached ones)."""
+    return _gather_sum_call(words, idx, w, table.shape[1], dtype)
+
+
+def _gather_sum_jvp(dtype, primals, tangents):
+    table, words, idx, w = primals
+    dtable, _, _, dw = tangents
+    if w is not None and not isinstance(dw, SymbolicZero):
+        raise TypeError("the gather-sum kernel's weights carry no "
+                        "gradient (ops/aggregate.py _gather_sum)")
+    out = _gather_sum(table, words, idx, w, dtype)
+    if isinstance(dtable, SymbolicZero):
+        return out, jnp.zeros(out.shape, dtype)
+    return out, _two_pass_sum(dtable, idx, w, dtype)
+
+
+_gather_sum.defjvp(_gather_sum_jvp, symbolic_zeros=True)
+
+
 def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
                      win_rows: int = 0, bands=(),
                      slot_major: bool = False) -> jax.Array:
     """The chunk scan of the width-8 sub-row layouts — one body for
     :func:`aggregate_ell_sect` (per section) and
     :func:`aggregate_flat_sum` (its single global section).  Each step
-    gather-sums a chunk's ``[seg_rows, 8]`` ids out of ``table`` and
-    adds the ``[seg_rows, F]`` partials into the carry ``out``.
+    sums the rows of a chunk's ``[seg_rows, 8]`` ids out of ``table``
+    into ``[seg_rows, F]`` partials and adds them into the carry
+    ``out``.
+
+    How the partials are made is read off the table
+    (:func:`gather_sum_form`; nothing to configure).  A table whose
+    words fit the kernel's VMEM — every section, any table at the
+    tests' sizes — is summed by :func:`_gather_sum`: the table is
+    copied into VMEM once a step (:func:`_vmem_words`: two bfloat16
+    columns a 32-bit word past 128 lanes), a sub-row's 8 rows are
+    loaded, widened and summed in float32 there, and only the
+    partials, rounded once, reach HBM — no ``[seg_rows * 8, F]``
+    gather written and read back, no separate reduce.  A table in HBM
+    (products' flat table, the typed relation passes) is gathered
+    slot-major, ``[8, seg_rows, F]``, and summed over that leading
+    axis in float32, rounded once: the gathered rows still round-trip
+    through HBM (no kernel holds the table), but the sum reads whole
+    rows instead of reducing across sublanes.  The constants and the
+    race they come from sit above :data:`GATHER_SUM_VMEM_BYTES`.
 
     The destinations of a chunk are ascending, so its real ones lie
     in one run of at most ``win_rows`` rows (core/ell.py
@@ -274,7 +553,7 @@ def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
     or taller than half the carry) the window IS the carry, and XLA
     folds the slice and the write-back away: the whole-carry scatter.
 
-    Between the width-8 sum and the window sits the step's one
+    Between the width-8 sum and the window sits the step's other
     decision (:func:`scan_seg_sum`, read off the table's ``bands``
     and ``F``; nothing to configure).  Where a destination row holds
     many sub-rows the partials are summed by row on the MXU first
@@ -297,14 +576,17 @@ def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
     model's width (PERF §6, PR 32).
 
     xs: ``(idx [n, seg, 8], dst [n, seg])`` plus optional weights
-    shaped like ``idx``, in the table's dtype or wider.  ``bands``:
+    shaped like ``idx``, in the table's dtype or wider (a product is
+    formed in float32 either way: a bfloat16 weight times a bfloat16
+    column is exact there).  ``bands``:
     the section's ``SectionedEll.bands`` entry (``()``: none known,
     the scatter).
 
     ``slot_major``: ``idx`` (and the weights) arrive ``[n, 8 * seg]``,
-    a chunk's ``[8, seg]`` transpose flattened, and a step reshapes
-    and transposes its own chunk back before the gather — the same
-    step on the same values.  What it changes is where the tables
+    a chunk's ``[8, seg]`` transpose flattened, which is the order
+    both forms read a chunk in (a ``[seg, 8]`` chunk is transposed by
+    the step) — the same step on the same values.  What it changes is
+    where the tables
     live between steps: the TPU tiles an array's two minor axes ``(8,
     128)``, so the scan's operand ``[n, seg, 8]`` is held with its
     8-wide axis padded to 128 lanes, sixteen times its bytes (3.5 GiB
@@ -315,21 +597,18 @@ def _scan_window_sum(out: jax.Array, table: jax.Array, xs,
     carry_rows, F = out.shape
     win = scan_window_rows(win_rows, carry_rows)
     seg = scan_seg_sum(xs[1].shape[-1], win, bands, F)
+    fused = gather_sum_form(*table.shape, table.dtype) == "fused"
+    if fused:
+        words = lax.stop_gradient(_vmem_words(table))
 
     def body(o, ch):
-        idx_ch, dst_ch = ch[0], ch[1]
-        if slot_major:
-            idx_ch = idx_ch.reshape(8, -1).T
-        g = table[idx_ch]
-        if len(ch) > 2:
-            g = g * (ch[2].reshape(8, -1).T if slot_major
-                     else ch[2])[:, :, None]
-        part = g.sum(axis=1)
-        if part.dtype != o.dtype:
-            # fp32 weights over a narrower table (the relation means,
-            # ``weights_fp32``): the products and the width-8 sum are
-            # fp32, the partial is rounded once on its way to the carry
-            part = part.astype(o.dtype)
+        dst_ch = ch[1]
+        # both forms read a chunk slot-major, [8, seg]
+        idx8, w8 = [None if a is None else
+                    a.reshape(8, -1) if slot_major else a.T
+                    for a in (ch[0], ch[2] if len(ch) > 2 else None)]
+        part = (_gather_sum(table, words, idx8, w8, o.dtype) if fused
+                else _two_pass_sum(table, idx8, w8, o.dtype))
         # clamped so the slice never clips (an all-padding chunk, or a
         # run that ends at the carry's last rows)
         r0 = jnp.minimum(dst_ch[0], carry_rows - win)
@@ -350,11 +629,13 @@ def aggregate_ell_sect(feats: jax.Array, sect_idx, sect_sub_dst,
                        sect_w=None) -> jax.Array:
     """Source-sectioned width-8 aggregation (core/ell.py SectionedEll —
     the measured numbers and the why live on that dataclass).  Per
-    section: slice the <= 64 MiB source block out of ``feats`` (XLA
-    keeps it VMEM-resident), ``lax.scan`` over sub-row chunks carrying
-    the output — gather-sum ``xsec[idx].sum(1)`` hits the fast gather
-    path, then a sorted scatter-add of the ``[seg_rows, F]`` partials
-    into the chunk's destination window (:func:`_scan_window_sum`).
+    section: slice the <= 64 MiB source block out of ``feats``,
+    ``lax.scan`` over sub-row chunks carrying the output — a chunk's
+    ``[seg_rows, F]`` partials summed by the kernel that holds the
+    section in VMEM (:func:`_gather_sum`: a sub-row's 8 rows in
+    float32, nothing gathered written to HBM), then a sorted
+    scatter-add of the partials into the chunk's destination window
+    (:func:`_scan_window_sum`).
 
     feats: [src_rows(+ optional trailing rows), F]; sections read
       ``[start, start+size)`` so an appended global dummy row is fine.
@@ -368,7 +649,8 @@ def aggregate_ell_sect(feats: jax.Array, sect_idx, sect_sub_dst,
       are scattered one by one.
     sect_w (optional): per-section edge weights shaped like
       ``sect_idx`` (SectionedEll.weight_tables — the baked fused-norm
-      scales), applied in-register before the width reduction.
+      scales), each slot's row times its weight in float32 inside the
+      kernel.
     """
     F = feats.shape[1]
     out = jnp.zeros((num_rows + 1, F), dtype=feats.dtype)
@@ -399,9 +681,13 @@ def aggregate_flat_sum(feats: jax.Array, flat_idx: jax.Array,
     section spanning all sources, so ids are global/gathered
     coordinates), and the aggregation is ONE ``lax.scan`` whose body
     shape depends only on (dtype, seg_rows, F) — never on the degree
-    distribution.  ``aggregate_ell``'s per-width Python unroll
-    compiles one gather+reduce program per degree bucket (doubled by
-    autodiff); this path compiles exactly one scan program per
+    distribution.  Its partials (:func:`_scan_window_sum`): a table in
+    HBM — any graph past the kernel's VMEM, products' 2.45M rows —
+    is gathered slot-major and summed over the slot axis in float32;
+    a table small enough for VMEM is summed by the kernel.
+    ``aggregate_ell``'s per-width Python unroll compiles one
+    gather+reduce program per degree bucket (doubled by autodiff);
+    this path compiles exactly one scan program per
     (dtype, F-quantum), which is what lets the persistent compile
     cache and the prewarm pass (utils/prewarm.py) cover large graphs.
 
@@ -416,8 +702,8 @@ def aggregate_flat_sum(feats: jax.Array, flat_idx: jax.Array,
       (chunk padding points at ``num_rows``).
     flat_w (optional): fp32 shaped like ``flat_idx`` — the baked
       ``D^-1/2 A D^-1/2`` fused-normalization entries
-      (``SectionedEll.weight_tables`` of the single section), applied
-      in-register before the width reduction.
+      (``SectionedEll.weight_tables`` of the single section), each
+      slot's product formed in float32 before the width sum.
     win_rows: static height of a chunk's destination window
       (``SectionedEll.win_rows[0]``; 0 = the whole carry) — the scan
       is :func:`_scan_window_sum`, shared with the sectioned layout.

@@ -869,7 +869,8 @@ class DistributedTrainer:
                 agg_window={
                     **self._gctx().agg_window(
                         model._ops, tables=self.data.sect_idx,
-                        edges=int(dataset.graph.num_edges)),
+                        edges=int(dataset.graph.num_edges),
+                        compute=self.compute),
                     **self._gctx().attention_plan(
                         model._ops, ell_idx=self.data.ell_idx,
                         flat8_idx=next(iter(self.data.sect_idx), None)),

@@ -1353,7 +1353,8 @@ class Trainer:
                 extra={"modeled_step_bytes": self._modeled_bytes},
                 agg_window={
                     **self.gctx.agg_window(
-                        model._ops, edges=int(dataset.graph.num_edges)),
+                        model._ops, edges=int(dataset.graph.num_edges),
+                        compute=self.compute),
                     **self.gctx.attention_plan(model._ops),
                     **self.gctx.relation_plan(
                         model._ops, dataset.typed,

@@ -780,6 +780,27 @@ def test_scan_window_rows_rule():
     assert scan_window_rows(4096, 1000) == 1000
 
 
+def _outside_kernel(text: str, root: str = "_gather_sum_call") -> str:
+    """The lowered module ``text`` without the functions the Pallas
+    call ``root`` (ops/aggregate.py) is lowered into, one a kernel
+    shape, or that they call."""
+    import re
+    funcs = {}
+    for chunk in re.split(r"(?m)^\s*func\.func ", text)[1:]:
+        funcs[re.search(r"@([\w.$-]+)\(", chunk).group(1)] = chunk
+    # one function a kernel shape: root, root_1, ...
+    todo = [f for f in funcs if re.fullmatch(rf"{root}(_\d+)?", f)]
+    kernel = set()
+    while todo:
+        name = todo.pop()
+        if name in kernel or name not in funcs:
+            continue
+        kernel.add(name)
+        todo.extend(re.findall(r"call @([\w.$-]+)\(", funcs[name]))
+    assert kernel, "the scan no longer calls the kernel"
+    return "\n".join(c for f, c in funcs.items() if f not in kernel)
+
+
 @pytest.mark.parametrize("layout", ["sectioned", "flat_sum",
                                     "sectioned_short"])
 def test_scan_program_touches_carry_only_by_slices(layout):
@@ -810,6 +831,9 @@ def test_scan_program_touches_carry_only_by_slices(layout):
     wins = {scan_window_rows(w, n + 1) for w in sect.win_rows}
     assert wins == ({n + 1} if short else set(sect.win_rows))
     text = jax.jit(fn).lower(_win_inputs(n, F, "float32")).as_text()
+    # the gather-sum kernel's own operands and blocks (interpreted off
+    # the chip) are not the scan's: its functions are left out
+    text = _outside_kernel(text)
     carry = f"tensor<{n + 1}x{F}xf32>"
     scatters = re.findall(
         r'"stablehlo\.scatter"\((%\w+),.*?\}\) : \(([^,]*),[^)]*\) -> '

@@ -446,8 +446,12 @@ def _scan_shapes(closed_jaxpr):
     from roc_tpu.analysis.jaxpr_lint import iter_eqns
     shapes = set()
     for eqn in iter_eqns(closed_jaxpr):
-        if eqn.primitive.name == "scan":
-            shapes.add(tuple(str(v.aval) for v in eqn.invars))
+        avals = tuple(str(v.aval) for v in eqn.invars)
+        # a loop over refs is inside a Pallas kernel's body (the
+        # gather-sum's rows), not a program of its own
+        if eqn.primitive.name == "scan" and not any(
+                a.startswith("Ref<") for a in avals):
+            shapes.add(avals)
     return shapes
 
 

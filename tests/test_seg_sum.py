@@ -341,7 +341,7 @@ def test_flat_sum_engaged_under_the_lane_pad():
 
 def _scatter_update_rows(text):
     return [int(m) for m in re.findall(
-        r'"stablehlo\.scatter"\(%\w+, %\w+, %\w+\).*?\}\) : '
+        r'"stablehlo\.scatter"\(%[\w#]+, %[\w#]+, %[\w#]+\).*?\}\) : '
         r'\(tensor<[^>]*>, tensor<[^>]*>, tensor<(\d+)x', text, flags=re.S)]
 
 
@@ -439,3 +439,32 @@ def test_plan_line_carries_the_counters_through_cli(impl, tmp_path):
     # a 512-vertex graph of degree ~10 has nothing to reduce
     assert res["agg_seg_sum"] == [None] * len(chunks)
     assert res["agg_carry_updates"] == [[n * s, n * s] for n, s in chunks]
+
+
+@pytest.mark.parametrize("impl, dtype", [("sectioned", "mixed"),
+                                         ("flat_sum", "float32")])
+def test_plan_line_carries_agg_gather_sum_through_cli(impl, dtype,
+                                                      tmp_path):
+    """The ``plan`` line says how each scanned table's chunk steps make
+    their partials (ops/aggregate.py ``gather_sum_form``): a small
+    graph's tables fit the kernel's VMEM, every slot of a pass fused."""
+    from roc_tpu.obs import events
+    from roc_tpu.ops.aggregate import gather_sum_slots
+    from roc_tpu.train import cli
+    ev = str(tmp_path / "events.jsonl")
+    try:
+        assert cli.main(["--cpu", "--no-compile-cache", "--model", "gcn",
+                         "-layers", "16-8-4", "-e", "1", "--impl", impl,
+                         "--dtype", dtype, "--events", ev]) == 0
+    finally:
+        events.get_bus().close()
+        events.configure(console=False)
+    with open(ev) as f:
+        res = [json.loads(line) for line in f]
+    res = [r for r in res if r["cat"] == "manifest"][-1]["resolved"]
+    chunks = res["agg_chunk_rows"]
+    assert res["agg_gather_sum"] == [["fused", n * s * 8]
+                                     for n, s in chunks]
+    # read at the widest sum op's lanes and the compute dtype
+    assert gather_sum_slots(*chunks[0], 513, 128, jnp.bfloat16) \
+        == res["agg_gather_sum"][0]
