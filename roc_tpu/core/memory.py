@@ -48,7 +48,7 @@ _STAT_BYTES = 4              # fp32 by-products (softmax, attention)
 # this module imports nothing, so the tuple is repeated); under remat
 # they are the boundaries of the runs computed again
 AGG_KINDS = ("scatter_gather", "fused_aggregate", "gat",
-             "rel_aggregate", "soft_aggregate")
+             "transformer_attention", "rel_aggregate", "soft_aggregate")
 
 
 def op_residuals(i: int, op: Any, itemsize: int
@@ -74,6 +74,8 @@ def op_residuals(i: int, op: Any, itemsize: int
     | ``rel_aggregate``, ``typed_input`` | nothing: the relation sum's backward is the pass over the transposed table, the assembly a concatenation |
     | ``batch_norm`` | its input (the hand-written backward computes ``xhat`` again from it) and two float32 ``[F]`` vectors, mean and ``1 / sqrt(var + eps)``, which weigh nothing a vertex row |
     | ``soft_aggregate`` | its input (``e`` is computed again from it and the kept ``[F]`` shift) and the float32 denominator a vertex row |
+    | ``transformer_attention`` | its three inputs (``q``, the ``[k | v]`` table, ``r``: both backward passes recompute the scores from the first two, the gate reads the third) and, float32 a vertex row, the attention's output ``m`` (``K * d``), the row max and denominator (``2 K``) and the gate ``beta`` (1) |
+    | ``layer_norm`` | its input and two float32 scalars a row (mean, ``1 / sqrt(var + eps)``) |
     """
     def t(j):
         return ("t", j)
@@ -111,6 +113,15 @@ def op_residuals(i: int, op: Any, itemsize: int
     if kind == "soft_aggregate":
         return [(t(op.inputs[0]), op.dim, itemsize),
                 (("m", i), op.dim, _STAT_BYTES)]
+    if kind == "transformer_attention":
+        heads, dh = attrs["heads"], attrs["head_width"]
+        return [(t(op.inputs[0]), heads * dh, itemsize),
+                (t(op.inputs[1]), 2 * heads * dh, itemsize),
+                (t(op.inputs[2]), op.dim, itemsize),
+                (("m", i), heads * dh + 2 * heads + 1, _STAT_BYTES)]
+    if kind == "layer_norm":
+        return [(t(op.inputs[0]), op.dim, itemsize),
+                (("m", i), 2, _STAT_BYTES)]
     return []
 
 
@@ -210,11 +221,13 @@ def saved_for_backward(ops: Sequence[Any], itemsize: int,
 def param_elems(ops: Sequence[Any]) -> int:
     """Trainable scalars of the op list: every ``linear``'s matrix
     (and its bias where it has one), every ``gat``'s two attention
-    vectors, every ``scale_add``'s eps, every ``batch_norm``'s scale
-    and shift — its running statistics are state, not parameters: no
-    gradient, no Adam moments, no compute copy, and 8 bytes a channel
-    that no plan needs; of a typed model the embedding tables' rows, a
-    matrix a relation and a matrix and a bias a kind."""
+    vectors, every ``transformer_attention``'s gate vector, every
+    ``scale_add``'s eps, every ``layer_norm``'s and ``batch_norm``'s
+    scale and shift — a ``batch_norm``'s running statistics are state,
+    not parameters: no gradient, no Adam moments, no compute copy, and
+    8 bytes a channel that no plan needs; of a typed model the
+    embedding tables' rows, a matrix a relation and a matrix and a bias
+    a kind."""
     n = 0
     for op in ops:
         if op.kind == "typed_input":
@@ -227,8 +240,10 @@ def param_elems(ops: Sequence[Any]) -> int:
         elif op.kind == "linear":
             n += (op.attrs["in_dim"]
                   + bool(op.attrs.get("bias"))) * op.dim
-        elif op.kind == "batch_norm":
+        elif op.kind in ("batch_norm", "layer_norm"):
             n += 2 * op.dim
+        elif op.kind == "transformer_attention":
+            n += 3 * op.dim
         elif op.kind == "gat":
             n += 2 * op.dim
         elif op.kind == "scale_add":

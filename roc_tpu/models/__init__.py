@@ -15,6 +15,7 @@ def model_builders() -> Dict[str, Callable]:
     from .gat import build_gat
     from .gcn import build_gcn
     from .gcn2 import build_gcn2
+    from .gtrans import build_gtrans
     from .gin import build_gin
     from .rgcn import build_rgcn
     from .sage import build_sage
@@ -22,4 +23,4 @@ def model_builders() -> Dict[str, Callable]:
     return {"gcn": build_gcn, "sage": build_sage, "gin": build_gin,
             "gat": build_gat, "sgc": build_sgc, "appnp": build_appnp,
             "gcn2": build_gcn2, "rgcn": build_rgcn,
-            "deepergcn": build_deepergcn}
+            "deepergcn": build_deepergcn, "gtrans": build_gtrans}

@@ -31,9 +31,10 @@ import numpy as np
 from ..core.ell import LANE_WIDTH, agg_lane_width
 from ..core.memory import remat_segments
 from ..core.relations import ORDER_PASSES, TRANSFORM_FIRST
-from ..obs.scopes import (ALLREDUCE_SCOPE, ATTN_SCORES_SCOPE,
-                          BN_PARAM_PREFIX, EMBED_SCOPE, HALO_SCOPE,
-                          LOSS_SCOPE, RECOMPUTE_SCOPE, op_scope)
+from ..obs.scopes import (ALLREDUCE_SCOPE, ATTN_GATE_SCOPE,
+                          ATTN_SCORES_SCOPE, BN_PARAM_PREFIX, EMBED_SCOPE,
+                          HALO_SCOPE, LN_PARAM_PREFIX, LOSS_SCOPE,
+                          RECOMPUTE_SCOPE, op_scope)
 from ..ops import dense, softagg
 from ..parallel import PARTS_AXIS
 from ..ops.aggregate import (aggregate_ell, aggregate_ell_max,
@@ -43,8 +44,9 @@ from ..ops.aggregate import (aggregate_ell, aggregate_ell_max,
                              scan_window_rows, seg_sum_updates)
 from ..ops.dense import AC_MODE_NONE, AC_MODE_RELU, AC_MODE_SIGMOID
 from ..ops.loss import masked_softmax_cross_entropy
-from ..ops.norm import (BN_EPS, BN_MOMENTUM, batch_norm_eval,
-                        batch_norm_train, indegree_norm, running_update)
+from ..ops.norm import (BN_EPS, BN_MOMENTUM, LN_EPS, batch_norm_eval,
+                        batch_norm_train, indegree_norm, layer_norm,
+                        running_update)
 
 # AggrType mirror (gnn.h:75-80); the reference declares SUM/AVG/MAX/MIN
 # but implements only SUM.  Here SUM and AVG ride the symmetric-vjp CSR
@@ -59,6 +61,11 @@ AGGR_MIN = "min"
 # what Adam trains, and the state no optimizer sees
 BN_TRAINED = ("scale", "shift")
 BN_STATE = ("mean", "var")
+# a layer_norm op's entries, ``ln_<n>_<suffix>`` (float32, trained)
+LN_TRAINED = ("scale", "shift")
+# the key stream of the dot-product attention's edge dropout, apart
+# from the dropout ops' own (which fold in their ordinal)
+EDGE_DROPOUT_STREAM = 0x65646765
 
 # the softmax aggregation's backward is the pass over the TRANSPOSED
 # table; on a symmetric graph that is the forward's own table, and no
@@ -68,6 +75,25 @@ SOFT_DIRECTED_REFUSAL = (
     "deepergcn) needs a symmetric stored graph: its hand-written "
     "backward gathers g / den over the transposed graph, and no "
     "transposed sum table is built for a directed one")
+
+# the dot-product attention's gradient is two passes over the bucket
+# tables; the one over the transposed graph is the forward's own table
+# on a symmetric graph only (ROADMAP R2)
+TFATTN_DIRECTED_REFUSAL = (
+    "the dot-product attention (transformer_attention; --model gtrans) "
+    "needs a symmetric stored graph: its hand-written backward's pass "
+    "for the keys and values walks each row's own bucket row as the "
+    "rows it feeds, and no by-source table is built for a directed one")
+TFATTN_FLAT8_REFUSAL = (
+    "the dot-product attention (transformer_attention; --model gtrans) "
+    "runs on the bucketed ELL tables alone: the uniform width-8 flat "
+    "layout (aggr_impl='attn_flat8', where attention routes at >= 20M "
+    "stored edges) has no dot-product score and no two-pass backward")
+TFATTN_PARTITION_REFUSAL = (
+    "the dot-product attention (transformer_attention; --model gtrans) "
+    "runs on one partition: its per-edge dropout mask hashes global "
+    "vertex ids, which a partition's padded row space does not carry "
+    "(--parts 1)")
 
 
 @dataclass
@@ -346,7 +372,14 @@ class GraphContext:
         those scatter-add into, once a scan step each (features,
         source and destination scores).  Empty for a model without
         attention.  The distributed trainer, whose tables live outside its context,
-        hands them in (stacked: the shapes' trailing axes are read)."""
+        hands them in (stacked: the shapes' trailing axes are read).
+
+        A dot-product attention op (``transformer_attention``) adds to
+        its entry ``score: "dot"``, the lanes its forward gathers a
+        slot (``gather_lanes_fwd``: the ``[k | v]`` row), its output
+        width, one ``[name, lanes]`` a backward pass (``bwd_passes``)
+        and ``edge_dropout`` (the rate and the mask's rule); its
+        ``attention_backward`` rule is ``transposed_two_pass``."""
         from ..ops.attention import resolve_dh_chunk
         flat8 = self.aggr_impl == "attn_flat8"
         ell_idx = self.ell_idx if ell_idx is None else ell_idx
@@ -354,6 +387,24 @@ class GraphContext:
         transposed = self.symmetric and not flat8
         out, back = [], []
         for i, op in enumerate(ops):
+            if op.kind == "transformer_attention":
+                heads, dh = op.attrs["heads"], op.attrs["head_width"]
+                lanes = 2 * heads * dh
+                out.append({
+                    "op": i, "heads": heads, "head_width": dh,
+                    "layout": self.aggr_impl, "edge_passes": 1,
+                    "padded_slots_per_pass": sum(
+                        int(np.prod(a.shape[-2:])) for a in ell_idx),
+                    "carry_rows": None, "score": "dot",
+                    "gather_lanes_fwd": lanes, "out_width": op.dim,
+                    "bwd_passes": [["dq", lanes], ["dk_dv", lanes]],
+                    "edge_dropout": {
+                        "p": op.attrs["rate"],
+                        "mask": "hash(dst, src, head) under the "
+                                "step's key"}})
+                back.append({"op": i, "rule": "transposed_two_pass",
+                             "edge_passes": 2, "scatters": 0})
+                continue
             if op.kind != "gat":
                 continue
             heads = int(op.attrs.get("heads", 1))
@@ -867,6 +918,79 @@ class GraphContext:
                                  self.ell_row_id, self.ell_row_pos,
                                  self.num_rows, neg_slope=neg_slope)
 
+    def transformer_attention(self, q: jax.Array, kv: jax.Array,
+                              r: jax.Array, w_beta: jax.Array,
+                              heads: int, concat: bool = True,
+                              rate: float = 0.0,
+                              seed: Optional[jax.Array] = None
+                              ) -> jax.Array:
+        """The Graph Transformer layer's aggregation and root path
+        (``ops/attention.py``, ``models/gtrans.py`` has the equations):
+        ``m`` the dot-product attention of the rows' queries ``q``
+        ``[rows, K*d]`` over their neighbours' ``[k | v]`` rows ``kv``
+        ``[rows, 2*K*d]``, heads concatenated or (``concat`` False)
+        averaged; ``beta = sigmoid(w_beta . [m; r; m - r])`` a row; out
+        ``beta r + (1 - beta) m``.  ``rate`` > 0 with a ``seed``
+        (``uint32[2]`` from the step's key) drops edges of the softmax
+        per head (:func:`ops.attention.edge_keep_scale`); eval passes
+        neither.  The attention's gradient is the hand-written two-pass
+        rule (``dot_ell_backward``); the gate's is autodiff.  Needs the
+        ELL tables of a symmetric graph on one partition: anything
+        else is refused by name."""
+        if self.aggr_impl == "attn_flat8":
+            raise NotImplementedError(TFATTN_FLAT8_REFUSAL)
+        if self.aggr_impl != "ell" or not self.ell_idx:
+            raise NotImplementedError(
+                f"the dot-product attention needs the ELL tables "
+                f"(aggr_impl='ell'), got {self.aggr_impl!r}")
+        if not self.symmetric:
+            raise NotImplementedError(TFATTN_DIRECTED_REFUSAL)
+        if self.partitioned:
+            raise NotImplementedError(TFATTN_PARTITION_REFUSAL)
+        from ..ops.attention import (dot_ell_backward, dot_ell_forward,
+                                     edge_keep_scale)
+        d = q.shape[1] // heads
+        keep = None
+        if seed is not None and rate > 0:
+            def keep(dst, src):
+                return edge_keep_scale(dst, src, heads, seed, rate)
+        tables = (self.ell_idx, self.ell_row_id, self.ell_row_pos,
+                  self.num_rows)
+
+        def forward(q, kv, residuals):
+            # both passes of the backward recompute the scores from the
+            # values the forward read: pin them (softagg.as_stored)
+            q, kv = softagg.as_stored(q), softagg.as_stored(kv)
+            got = dot_ell_forward(self._gathered_with_zero(kv), q, d,
+                                  *tables, keep=keep,
+                                  residuals=residuals)
+            return got, q, kv
+
+        @jax.custom_vjp
+        def attend(q, kv):
+            return forward(q, kv, False)[0]
+
+        def fwd(q, kv):
+            (m, stats), q, kv = forward(q, kv, True)
+            return m, (q, kv, m, stats)
+
+        def bwd(res, g):
+            return dot_ell_backward(*res, g, d, self._gathered_with_zero,
+                                    *tables, keep=keep)
+
+        attend.defvjp(fwd, bwd)
+        m = attend(q, kv)
+        f32 = jnp.float32
+        with jax.named_scope(ATTN_GATE_SCOPE):
+            if not concat:
+                m = m.reshape(m.shape[0], heads, d).mean(axis=1)
+            rf = r.astype(f32)
+            w = w_beta.astype(f32).reshape(3, -1)
+            beta = jax.nn.sigmoid((m * w[0] + rf * w[1]
+                                   + (m - rf) * w[2]).sum(axis=1))
+            beta = beta[:, None]
+            return (beta * rf + (1.0 - beta) * m).astype(r.dtype)
+
     def _max_fwd(self, x: jax.Array) -> jax.Array:
         """Neighbor max; rows with no neighbors yield 0.  Dummy/padding
         sources are masked out (their zero rows must not win the max)."""
@@ -1165,10 +1289,20 @@ class Model:
         return tuple(a[:self.typed["node_types"][0]] for a in arrays)
 
     def uses_attention(self) -> bool:
-        """True when the op list contains a gat op — such models run
-        on the ELL tables or the flat8 tables (train/trainer.py
-        resolve_attention_impl picks by edge count)."""
-        return any(op.kind == "gat" for op in self._ops)
+        """True when the op list contains an attention op (``gat``,
+        ``transformer_attention``) — such models run on the ELL tables
+        or, additive attention alone, the flat8 tables
+        (train/trainer.py resolve_attention_impl picks by edge
+        count)."""
+        return any(op.kind in ("gat", "transformer_attention")
+                   for op in self._ops)
+
+    def uses_dot_attention(self) -> bool:
+        """True when the op list contains a ``transformer_attention``:
+        the bucketed ELL tables of a symmetric graph on one partition,
+        nothing else (``GraphContext.transformer_attention``)."""
+        return any(op.kind == "transformer_attention"
+                   for op in self._ops)
 
     def uses_max_aggregation(self) -> bool:
         """True when any scatter_gather op is MAX/MIN — those have no
@@ -1333,6 +1467,46 @@ class Model:
         return self._append("gat", (t.idx,), t.dim, param=name,
                             attrs={"neg_slope": neg_slope,
                                    "heads": heads})
+
+    def transformer_attention(self, q: TensorHandle, kv: TensorHandle,
+                              r: TensorHandle, heads: int,
+                              concat: bool = True,
+                              rate: float = 0.0) -> TensorHandle:
+        """The Graph Transformer layer's attention and gated root path
+        over three projections of one input: the queries ``q`` (``K *
+        d`` wide), the keys and values side by side ``kv`` (``2 * K *
+        d``: the table the edges gather) and the root ``r`` (``K * d``
+        with ``concat``, else ``d``: the heads are averaged).
+        ``rate``: the attention dropout, per edge and head, in training
+        (``GraphContext.transformer_attention``).  Adds the gate's
+        ``tfattn_<n>_beta`` ``[3 * out]`` to the params."""
+        F = q.dim
+        if F % heads or kv.dim != 2 * F:
+            raise ValueError(
+                f"transformer_attention: queries {F} wide must split "
+                f"into {heads} heads and [k | v] be {2 * F} wide, got "
+                f"{kv.dim}")
+        out = F if concat else F // heads
+        if r.dim != out:
+            raise ValueError(f"transformer_attention: the root path is "
+                             f"{r.dim} wide, the output {out}")
+        if not 0.0 <= rate < 1.0:
+            raise ValueError(f"attention dropout {rate} not in [0, 1)")
+        n = sum(op.kind == "transformer_attention" for op in self._ops)
+        return self._append(
+            "transformer_attention", (q.idx, kv.idx, r.idx), out,
+            param=f"tfattn_{n}",
+            attrs={"heads": heads, "head_width": F // heads,
+                   "concat": concat, "rate": float(rate)})
+
+    def layer_norm(self, t: TensorHandle) -> TensorHandle:
+        """``torch.nn.LayerNorm`` over each row's channels
+        (``ops/norm.py layer_norm``): parameters ``ln_<n>_scale`` /
+        ``ln_<n>_shift``, float32 whatever the compute dtype."""
+        n = sum(op.kind == "layer_norm" for op in self._ops)
+        return self._append("layer_norm", (t.idx,), t.dim,
+                            param=f"{LN_PARAM_PREFIX}{n}",
+                            attrs={"eps": LN_EPS})
 
     def relu(self, t: TensorHandle) -> TensorHandle:
         return self._append("activation", (t.idx,), t.dim,
@@ -1545,7 +1719,7 @@ class Model:
 
     GRAPH_OP_KINDS = ("scatter_gather", "fused_aggregate", "gat",
                       "indegree_norm", "rel_aggregate",
-                      "soft_aggregate")
+                      "soft_aggregate", "transformer_attention")
 
     def precompute_split(self):
         """``(prefix_ops, head_model)`` when the op list is a
@@ -1655,6 +1829,19 @@ class Model:
                                         (1.0, 0.0, 0.0, 1.0)):
                     params[f"{op.param}_{suffix}"] = jnp.full(
                         (op.dim,), fill, dtype=jnp.float32)
+            elif op.kind == "layer_norm":
+                # torch's: scale 1, shift 0 — float32 whatever the
+                # parameters' dtype
+                for suffix, fill in zip(LN_TRAINED, (1.0, 0.0)):
+                    params[f"{op.param}_{suffix}"] = jnp.full(
+                        (op.dim,), fill, dtype=jnp.float32)
+            elif op.kind == "transformer_attention":
+                # the gate's bias-free Linear(3 * out, 1): torch's
+                # default, U(-1/sqrt(in), 1/sqrt(in))
+                key, sub = jax.random.split(key)
+                b = float(1.0 / np.sqrt(3 * op.dim))
+                params[f"{op.param}_beta"] = jax.random.uniform(
+                    sub, (3 * op.dim,), dtype=dtype, minval=-b, maxval=b)
             elif op.kind == "scale_add":
                 # learnable GIN eps: zero-init (the paper's GIN-0)
                 params[op.param] = jnp.zeros((), dtype=dtype)
@@ -1704,6 +1891,10 @@ class Model:
             return [op.param]
         if op.kind == "batch_norm":
             return [f"{op.param}_{s}" for s in BN_TRAINED + BN_STATE]
+        if op.kind == "layer_norm":
+            return [f"{op.param}_{s}" for s in LN_TRAINED]
+        if op.kind == "transformer_attention":
+            return [f"{op.param}_beta"]
         if op.kind in ("typed_input", "rel_linear", "root_linear"):
             return [n for n, _ in self._typed_param_shapes(op)]
         return []
@@ -1830,8 +2021,8 @@ class Model:
         first transpose: more memory than no remat at all (PERF.md
         section 6, PR 33)."""
         if (train and key is None and
-                any(op.kind == "dropout" and op.attrs["rate"] > 0
-                    for op in self._ops)):
+                any(op.kind in ("dropout", "transformer_attention")
+                    and op.attrs["rate"] > 0 for op in self._ops)):
             raise ValueError(
                 "a PRNG key is required in train mode for models with "
                 "dropout; pass key= or use train=False")
@@ -1947,6 +2138,23 @@ class Model:
             if op.kind == "soft_aggregate":
                 return gctx.soft_aggregate(x, op.attrs["t"],
                                            op.attrs["eps"])
+            if op.kind == "layer_norm":
+                return layer_norm(x, *(params[f"{op.param}_{s}"]
+                                       for s in LN_TRAINED),
+                                  op.attrs["eps"])
+            if op.kind == "transformer_attention":
+                seed = None
+                if train and key is not None and op.attrs["rate"] > 0:
+                    # a stream of the op's ordinal among these ops
+                    sub = jax.random.fold_in(jax.random.fold_in(
+                        key, EDGE_DROPOUT_STREAM), sum(
+                        o.kind == op.kind for o in self._ops[:i]))
+                    seed = jax.random.bits(sub, (2,), jnp.uint32)
+                q, kv, r = (vals[j] for j in op.inputs)
+                return gctx.transformer_attention(
+                    q, kv, r, params[f"{op.param}_beta"],
+                    op.attrs["heads"], op.attrs["concat"],
+                    op.attrs["rate"], seed)
             if op.kind == "indegree_norm":
                 return indegree_norm(x, gctx.in_degree)
             if op.kind == "scatter_gather":

@@ -29,7 +29,7 @@ map (``ObservedJit.instruction_scopes``) that joins the two.
 
 | class | what runs under it | name |
 | --- | --- | --- |
-| ``agg`` | a model op that aggregates over edges (``scatter_gather``, ``fused_aggregate``, ``gat``, a typed graph's ``rel_aggregate``, the softmax-weighted ``soft_aggregate``) | ``roc.agg.op<i>`` |
+| ``agg`` | a model op that aggregates over edges (``scatter_gather``, ``fused_aggregate``, ``gat``, the dot-product ``transformer_attention``, a typed graph's ``rel_aggregate``, the softmax-weighted ``soft_aggregate``) | ``roc.agg.op<i>`` |
 | ``halo`` | the feature halo exchange inside an aggregation (all-gather, ring hops) | ``roc.halo`` |
 | ``dense`` | every other model op | ``roc.dense.op<i>.<kind>`` |
 | ``loss`` | masked cross-entropy and the metric reductions | ``roc.loss`` |
@@ -75,6 +75,17 @@ and :func:`parse_op_phase` gives the finer rows:
 | ``scores`` | ``s = a_src . z``, ``t = a_dst . z``, their per-edge gather, LeakyReLU, the padding mask | ``roc.attn.scores`` |
 | ``stats`` | the softmax statistics: row max, ``exp``, denominator | ``roc.attn.stats`` |
 | ``gather`` | the feature gather, the weighted sum (numerator) and the division | ``roc.attn.gather`` |
+
+A dot-product attention op (``transformer_attention``) takes the same
+three phases for its forward and both passes of its backward (the
+``[k | v]`` gather and the ``K`` scores under ``scores``, the softmax
+under ``stats``, the weighted sums under ``gather``), and names its
+gated root path ``roc.attn.gate``: the head mean, the ``beta`` logit,
+its sigmoid and ``beta r + (1 - beta) m``.  A ``layer_norm`` op
+(``roc.dense.op<i>.layer_norm``) names its row moments ``roc.ln.stats``
+(in the backward the two row sums of ``dx``).  Neither is a class nor
+a phase: whatever reads classes or phases sees ``agg``, ``dense`` and
+the three phases as before.
 """
 
 from __future__ import annotations
@@ -89,7 +100,7 @@ CLASSES = (AGG, HALO, DENSE, LOSS, OPT, ALLREDUCE)
 # the model op kinds whose scope class is ``agg``; every other kind is
 # ``dense``
 AGG_KINDS = ("scatter_gather", "fused_aggregate", "gat",
-             "rel_aggregate", "soft_aggregate")
+             "transformer_attention", "rel_aggregate", "soft_aggregate")
 
 ATTN_PHASES = ("scores", "stats", "gather")
 
@@ -108,11 +119,19 @@ EMBED_PARAM_PREFIX = "embed_"
 BN_STATS_SCOPE = PREFIX + "bn.stats"
 SAGG_WEIGHTS_SCOPE = PREFIX + "sagg.weights"
 # a batch_norm op's parameters (scale, shift) and statistics (running
-# mean, variance): kept in float32 whatever the compute dtype
+# mean, variance), a layer_norm op's scale and shift: kept in float32
+# whatever the compute dtype
 BN_PARAM_PREFIX = "bn_"
+LN_PARAM_PREFIX = "ln_"
+FLOAT32_PARAM_PREFIXES = (BN_PARAM_PREFIX, LN_PARAM_PREFIX)
 # entered inside an attention op's own ``roc.agg.op<i>``
 ATTN_SCORES_SCOPE, ATTN_STATS_SCOPE, ATTN_GATHER_SCOPE = (
     f"{PREFIX}attn.{phase}" for phase in ATTN_PHASES)
+# a dot-product attention op's gated root path (inside its agg scope)
+# and a layer_norm op's row moments (inside its dense scope): names,
+# not phases (module docstring)
+ATTN_GATE_SCOPE = PREFIX + "attn.gate"
+LN_STATS_SCOPE = PREFIX + "ln.stats"
 
 _SCOPE = re.compile(r"roc\.(" + "|".join(CLASSES) + r")(?:\.op(\d+))?")
 _PHASE = re.compile(r"roc\.attn\.(" + "|".join(ATTN_PHASES) + r")")

@@ -35,6 +35,18 @@ Every operation sits under one of three phase scopes (obs/scopes.py
 gather, LeakyReLU, the padding mask), ``stats`` (row max, ``exp``,
 denominator) and ``gather`` (the feature gather, the weighted sum —
 the numerator — and the division).
+
+Dot-product attention (the Graph Transformer layer of UniMP, Shi et
+al., IJCAI'21; PyTorch Geometric's ``TransformerConv``) shares the
+bucket loop and nothing of the tiles: its score is a function of two
+VECTORS, ``s_ij = q_i . k_j / sqrt(d)`` per head, so no per-vertex
+scalar carries the destination's part of the gradient.  Its backward
+on a symmetric graph is two scatter-free passes over the forward's own
+tables — attention's dQ / dK-dV split on a graph
+(:func:`dot_ell_backward`) — with ``rho_i = G_i . m_i`` per vertex.
+Attention dropout is a per-edge mask drawn by a counter-based hash of
+``(dst, src, head)`` under the step's key (:func:`edge_keep_scale`), so
+the pass over the transposed table draws the forward's mask again.
 """
 
 from __future__ import annotations
@@ -379,6 +391,270 @@ def gat_ell_backward(x: jax.Array, a_src: jax.Array, a_dst: jax.Array,
                                preferred_element_type=f32)
     return (x_bar, a_src_bar.astype(a_src.dtype),
             a_dst_bar.astype(a_dst.dtype))
+
+
+_U32 = jnp.uint32
+
+
+def _fmix32(h):
+    """MurmurHash3's 32-bit finalizer: every input bit moves every
+    output bit."""
+    h = h ^ (h >> 16)
+    h = h * _U32(0x85EBCA6B)
+    h = h ^ (h >> 13)
+    h = h * _U32(0xC2B2AE35)
+    return h ^ (h >> 16)
+
+
+def edge_keep_scale(dst, src, heads: int, seed, rate: float):
+    """``D_ij^h / (1 - rate)``, float32, ``D ~ Bernoulli(1 - rate)``,
+    for the edge ``dst <- src`` (broadcastable int32 arrays of global
+    vertex ids) and each of ``heads`` heads on a new trailing axis.  A
+    function of the edge, the head and ``seed`` (``uint32[2]``, drawn
+    from the step's key) alone — not of where the edge sits in a table
+    — so the forward and the pass over the transposed table draw the
+    same mask."""
+    h = _fmix32(seed[0] ^ (dst.astype(_U32) * _U32(0x9E3779B1)))
+    h = _fmix32(h ^ (src.astype(_U32) * _U32(0x85EBCA77)))
+    head = jnp.arange(heads, dtype=_U32) * _U32(0xC2B2AE3D)
+    h = _fmix32(h[..., None] ^ seed[1] ^ head)
+    cut = _U32(min(int(round(rate * 2.0 ** 32)), 2 ** 32 - 1))
+    return jnp.where(h >= cut, jnp.float32(1.0 / (1.0 - rate)),
+                     jnp.float32(0.0))
+
+
+def _heads(a, d):
+    """The head slices ``[..., d]`` of ``a`` ``[..., K*d]``, a list.
+    Lane slices: at a head width of 128 each is a whole vreg column, and
+    no ``[..., K, d]`` view exists whose second-minor axis (the head
+    count) the chip's ``(8, 128)`` tiles would pad — XLA lays such a
+    view out anew in HBM, a copy of the whole gathered tile."""
+    return [a[..., h * d:(h + 1) * d] for h in range(a.shape[-1] // d)]
+
+
+def _per_row(slots, rows):
+    """``[r, w, K]`` float32: per head the dot product of each slot's
+    ``[r, w, d]`` slice with its row's ``[r, d]`` slice."""
+    return jnp.stack([jnp.einsum("rwd,rd->rw", a, b,
+                                 preferred_element_type=jnp.float32)
+                      for a, b in zip(slots, rows)], axis=-1)
+
+
+def _over_slots(weights, slots):
+    """``[r, K*d]`` float32: per head the sum over the width axis of the
+    ``[r, w, K]`` weights times the slots' ``[r, w, d]`` slice, the
+    weights rounded to the slices' dtype."""
+    return jnp.concatenate(
+        [jnp.einsum("rw,rwd->rd", weights[..., h].astype(a.dtype), a,
+                    preferred_element_type=jnp.float32)
+         for h, a in enumerate(slots)], axis=-1)
+
+
+def _dot_forward_tile(kv_full, q_pad, d, scale, keep, residuals=False):
+    """``tile(idx_seg, rid_seg)`` of the dot-product attention forward:
+    one gather of the ``[k | v]`` rows a slot, the ``K`` scores against
+    the row's own query, the masked softmax and the weighted sum in
+    float32, and the per-edge dropout ``keep(dst, src)`` (None: none).
+    Returns ``(m [r, F] fp32,)``, with ``residuals`` also the row
+    statistics ``[r, 2K]`` fp32 (row max, denominator)."""
+    dummy = kv_full.shape[0] - 1
+    F = q_pad.shape[1]
+
+    def tile(idx_seg, rid_seg):
+        with jax.named_scope(_SCORES):
+            kv = kv_full[idx_seg]                       # [r, w, 2F]
+            s = _per_row(_heads(kv[..., :F], d),
+                         _heads(q_pad[rid_seg], d)) * scale
+            valid = (idx_seg != dummy)[:, :, None]
+            s = jnp.where(valid, s, -jnp.inf)
+        with jax.named_scope(_STATS):
+            m = jnp.max(s, axis=1, keepdims=True)
+            m = jnp.where(jnp.isfinite(m), m, 0.0)
+            e = jnp.where(valid, jnp.exp(s - m), 0.0)
+            den = jnp.maximum(e.sum(axis=1, keepdims=True), 1e-20)
+        with jax.named_scope(_GATHER):
+            t = e / den
+            if keep is not None:
+                t = t * keep(rid_seg[:, None], idx_seg)
+            out = _over_slots(t, _heads(kv[..., F:], d))
+        if not residuals:
+            return (out,)
+        with jax.named_scope(_STATS):
+            return out, jnp.concatenate([m[:, 0], den[:, 0]], axis=1)
+
+    return tile
+
+
+def _dot_query_tile(kv_full, q_pad, g_pad, st_pad, d, scale, keep):
+    """Pass A of the backward, ``tile(idx_seg, rid_seg)``: at row ``i``
+    over its own slots ``j``, with ``alpha_ij`` recomputed from the
+    row's kept statistics,
+
+        ds_ij = alpha_ij (D_ij / (1 - p) G_i . v_j - rho_i)
+        q_bar_i = sum_j ds_ij k_j / sqrt(d)
+
+    ``g_pad`` ``[num_rows+1, F]`` the cotangent of ``m``, ``st_pad``
+    ``[num_rows+1, 3K]`` fp32 (row max, denominator, ``rho``), a zero
+    row last in each."""
+    dummy = kv_full.shape[0] - 1
+    F = q_pad.shape[1]
+
+    def tile(idx_seg, rid_seg):
+        with jax.named_scope(_SCORES):
+            kv = kv_full[idx_seg]
+            k = _heads(kv[..., :F], d)
+            s = _per_row(k, _heads(q_pad[rid_seg], d)) * scale
+            valid = (idx_seg != dummy)[:, :, None]
+            mx, den, rho = jnp.split(st_pad[rid_seg], 3, axis=1)
+        with jax.named_scope(_STATS):
+            alpha = jnp.where(valid, jnp.exp(s - mx[:, None, :]), 0.0
+                              ) / jnp.maximum(den, 1e-20)[:, None, :]
+        with jax.named_scope(_GATHER):
+            g = g_pad[rid_seg].astype(kv.dtype)
+            gv = _per_row(_heads(kv[..., F:], d), _heads(g, d))
+            if keep is not None:
+                gv = gv * keep(rid_seg[:, None], idx_seg)
+            ds = alpha * (gv - rho[:, None, :])
+            return (_over_slots(ds, k) * scale,)
+
+    return tile
+
+
+def _dot_key_tile(pg_full, st_full, kv_pad, d, scale, keep):
+    """Pass B of the backward, ``tile(idx_seg, rid_seg)``: at row ``j``
+    over its own slots ``i`` — on a symmetric graph the rows ``j``
+    feeds — with ``alpha_ij`` and ``D_ij`` recomputed from row ``i``'s
+    gathered query and statistics,
+
+        v_bar_j = sum_i t_ij G_i
+        k_bar_j = sum_i ds_ij q_i / sqrt(d)
+
+    ``pg_full`` ``[G+1, 2F]`` the rows ``[q | G]`` through the halo,
+    ``st_full`` ``[G+1, 3K]`` fp32 their (row max, denominator,
+    ``rho``), ``kv_pad`` ``[num_rows+1, 2F]`` the rows' own ``[k |
+    v]``.  Returns ``([k_bar | v_bar] [r, 2F] fp32,)``."""
+    dummy = pg_full.shape[0] - 1
+    F = pg_full.shape[1] // 2
+
+    def tile(idx_seg, rid_seg):
+        with jax.named_scope(_SCORES):
+            pg = pg_full[idx_seg]                       # [r, w, 2F]
+            q = _heads(pg[..., :F], d)
+            g = _heads(pg[..., F:], d)
+            own = kv_pad[rid_seg].astype(pg.dtype)
+            s = _per_row(q, _heads(own[:, :F], d)) * scale
+            mx, den, rho = jnp.split(st_full[idx_seg], 3, axis=2)
+            valid = (idx_seg != dummy)[:, :, None]
+        with jax.named_scope(_STATS):
+            # the dummy slot's statistics are 0: keep it out of the
+            # division
+            alpha = jnp.where(valid, jnp.exp(s - mx), 0.0) / jnp.where(
+                valid, jnp.maximum(den, 1e-20), 1.0)
+        with jax.named_scope(_GATHER):
+            drop = (keep(idx_seg, rid_seg[:, None]) if keep is not None
+                    else 1.0)
+            v_bar = _over_slots(alpha * drop, g)
+            gv = _per_row(g, _heads(own[:, F:], d))
+            ds = alpha * (drop * gv - rho)
+            return (jnp.concatenate([_over_slots(ds, q) * scale, v_bar],
+                                    axis=1),)
+
+    return tile
+
+
+def _dot_slot_elems(kv_width: int, heads: int) -> int:
+    """Elements a (row, width) slot holds while a dot-product tile is
+    in flight: the gathered ``[k | v]`` (or ``[q | G]``) row and four
+    fp32 score tensors a head."""
+    return kv_width + 4 * heads
+
+
+def _padded_rows(a: jax.Array) -> jax.Array:
+    """``a`` with a zero row appended: what a padding bucket row (row id
+    ``num_rows``) reads."""
+    return jnp.concatenate([a, jnp.zeros((1,) + a.shape[1:], a.dtype)])
+
+
+def dot_ell_forward(kv_full: jax.Array, q: jax.Array, head_width: int,
+                    ell_idx, ell_row_id, ell_row_pos: jax.Array,
+                    num_rows: int, keep=None, residuals: bool = False,
+                    budget_elems: int = 1 << 24):
+    """Dot-product attention over the ELL buckets, K heads side by
+    side: ``m_i^h = sum_j t_ij^h v_j^h`` with ``t = softmax_j(q_i^h .
+    k_j^h / sqrt(d)) * D_ij^h / (1 - p)``.  ``kv_full`` ``[G+1, 2F]``
+    the ``[k | v]`` rows through the halo with a zero row last; ``q``
+    ``[num_rows, F]`` the rows' own queries; ``keep(dst, src)`` the
+    dropout multiplier (:func:`edge_keep_scale`; None: none).  Returns
+    ``m`` ``[num_rows, F]`` fp32, and with ``residuals`` the row
+    statistics ``[num_rows, 2K]`` fp32 beside it.  Rows with no stored
+    edge read 0.  Nothing is differentiated through: the gradient is
+    :func:`dot_ell_backward`."""
+    F = q.shape[1]
+    K = F // head_width
+    parts = _bucket_rows(
+        _dot_forward_tile(kv_full, _padded_rows(q), head_width,
+                          head_width ** -0.5, keep, residuals),
+        ell_idx, ell_row_id, num_rows, kv_full.shape[0] - 1,
+        _dot_slot_elems(kv_full.shape[1], K), budget_elems)
+    with jax.named_scope(_GATHER):
+        m = _in_row_order(parts[0], ell_row_pos)
+    if not residuals:
+        return m
+    with jax.named_scope(_STATS):
+        return m, _in_row_order(parts[1], ell_row_pos)
+
+
+def dot_ell_backward(q: jax.Array, kv: jax.Array, m: jax.Array,
+                     stats: jax.Array, g: jax.Array, head_width: int,
+                     halo, ell_idx, ell_row_id, ell_row_pos: jax.Array,
+                     num_rows: int, keep=None,
+                     budget_elems: int = 1 << 24):
+    """The backward of :func:`dot_ell_forward` on a SYMMETRIC graph as
+    two scatter-free passes over the forward's own tables: pass A
+    (``dq``) at each row over its own slots, gathering ``[k | v]``;
+    pass B (``dk``, ``dv``) at each row over the rows it feeds,
+    gathering ``[q | G]`` and their (row max, denominator, ``rho``)
+    through ``halo`` (``GraphContext._gathered_with_zero``).  With
+    ``rho_i = G_i . m_i`` per head — ``sum_j t_ij G_i . v_j``, no pass
+    needed — and ``ds_ij = alpha_ij (D_ij / (1 - p) G_i . v_j -
+    rho_i)``:
+
+        q_bar_i = sum_j ds_ij k_j / sqrt(d)             pass A
+        k_bar_j = sum_i ds_ij q_i / sqrt(d)             pass B
+        v_bar_j = sum_i t_ij G_i                        pass B
+
+    ``q`` ``[num_rows, F]``, ``kv`` ``[num_rows, 2F]`` the op's inputs;
+    ``m``, ``stats`` the forward's; ``g`` the cotangent of ``m``.
+    Returns ``(q_bar, kv_bar)`` in the inputs' dtypes.  Wrong on a
+    directed graph: the rows ``j`` feeds are then not the rows in
+    ``j``'s own bucket row."""
+    F = q.shape[1]
+    K = F // head_width
+    d = head_width
+    f32 = jnp.float32
+    with jax.named_scope(_SCORES):
+        rho = jnp.einsum("vkd,vkd->vk", g.reshape(num_rows, K, d),
+                         m.reshape(num_rows, K, d),
+                         preferred_element_type=f32)
+        st = jnp.concatenate([stats, rho], axis=1)      # [rows, 3K]
+        st_full = halo(st)
+    with jax.named_scope(_GATHER):
+        kv_full = halo(kv)
+        pg_full = halo(jnp.concatenate([q, g.astype(q.dtype)], axis=1))
+        tables = (ell_idx, ell_row_id, num_rows)
+        (qa,) = _bucket_rows(
+            _dot_query_tile(kv_full, _padded_rows(q), _padded_rows(g),
+                            _padded_rows(st), d, d ** -0.5, keep),
+            *tables, kv_full.shape[0] - 1,
+            _dot_slot_elems(kv_full.shape[1], K), budget_elems)
+        (kb,) = _bucket_rows(
+            _dot_key_tile(pg_full, st_full, _padded_rows(kv), d,
+                          d ** -0.5, keep),
+            *tables, pg_full.shape[0] - 1,
+            _dot_slot_elems(pg_full.shape[1], K), budget_elems)
+        q_bar = _in_row_order(qa, ell_row_pos)
+        kv_bar = _in_row_order(kb, ell_row_pos)
+    return q_bar.astype(q.dtype), kv_bar.astype(kv.dtype)
 
 
 def resolve_dh_chunk(num_rows: int, heads: int, dh: int,

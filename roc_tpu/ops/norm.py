@@ -19,7 +19,8 @@ is beyond the reference: the one op here that reduces over the vertex
 axis, and the one with state that is not a parameter (the running
 statistics).  ``torch.nn.BatchNorm1d``'s arithmetic: biased variance
 for the normalization, unbiased for the running estimate, ``eps`` inside
-the square root.
+the square root.  Layer normalization (:func:`layer_norm`) normalizes
+each row over its channels instead: no state, no vertex reduction.
 """
 
 from __future__ import annotations
@@ -30,10 +31,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs.scopes import ALLREDUCE_SCOPE, BN_STATS_SCOPE
+from ..obs.scopes import ALLREDUCE_SCOPE, BN_STATS_SCOPE, LN_STATS_SCOPE
 
 BN_EPS = 1e-5
 BN_MOMENTUM = 0.1
+LN_EPS = 1e-5
 
 
 def inv_sqrt_degree(in_degree: jax.Array) -> jax.Array:
@@ -154,6 +156,61 @@ def batch_norm_train(x: jax.Array, scale: jax.Array, shift: jax.Array,
 
     bn.defvjp(fwd, bwd)
     return bn(x, scale, shift)
+
+
+def layer_norm(x: jax.Array, scale: jax.Array, shift: jax.Array,
+               eps: float = LN_EPS) -> jax.Array:
+    """``torch.nn.LayerNorm`` over the channels of each row of ``x``
+    ``[rows, F]``: ``scale * (x - mean) / sqrt(var + eps) + shift``,
+    ``mean`` and the biased ``var`` the row's own — the same in train
+    and eval, no state, no collective (a row is never split across
+    partitions).  The moments are float32 whatever ``x``'s dtype, two
+    passes over the row (under ``roc.ln.stats``); the result is rounded
+    once to ``x``'s dtype.
+
+    The backward is written by hand and keeps ``x`` and two float32
+    scalars a row (mean, ``1 / sqrt(var + eps)``); autodiff would keep
+    the float32 ``xhat``.  With ``xhat = (x - mean) r`` and ``h = scale
+    * g``: ``dx = r (h - mean_F(h) - xhat mean_F(h xhat))``, ``d scale
+    = sum_rows g xhat``, ``d shift = sum_rows g``."""
+    f32 = jnp.float32
+    n = x.shape[1]
+
+    def stats(x):
+        with jax.named_scope(LN_STATS_SCOPE):
+            xf = x.astype(f32)
+            mean = xf.sum(axis=1, keepdims=True) / n
+            var = jnp.square(xf - mean).sum(axis=1, keepdims=True) / n
+            return xf, mean, jax.lax.rsqrt(var + eps)
+
+    def normalized(x, scale, shift):
+        xf, mean, r = stats(x)
+        y = (xf - mean) * r * scale.astype(f32) + shift.astype(f32)
+        return y.astype(x.dtype), mean, r
+
+    @jax.custom_vjp
+    def ln(x, scale, shift):
+        return normalized(x, scale, shift)[0]
+
+    def fwd(x, scale, shift):
+        y, mean, r = normalized(x, scale, shift)
+        return y, (x, scale, mean, r)
+
+    def bwd(res, g):
+        x, scale, mean, r = res
+        gf = g.astype(f32)
+        xhat = (x.astype(f32) - mean) * r
+        h = gf * scale.astype(f32)
+        with jax.named_scope(LN_STATS_SCOPE):
+            h_mean = h.sum(axis=1, keepdims=True) / n
+            hx_mean = (h * xhat).sum(axis=1, keepdims=True) / n
+        dx = r * (h - h_mean - xhat * hx_mean)
+        return (dx.astype(x.dtype),
+                (gf * xhat).sum(axis=0).astype(scale.dtype),
+                gf.sum(axis=0).astype(scale.dtype))
+
+    ln.defvjp(fwd, bwd)
+    return ln(x, scale, shift)
 
 
 def running_update(running_mean: jax.Array, running_var: jax.Array,

@@ -634,6 +634,9 @@ class DistributedTrainer:
             model, dataset, config, num_parts=num_parts,
             multiprocess=jax.process_count() > 1)
         self.model = model
+        if model.uses_dot_attention():
+            from ..models.builder import TFATTN_PARTITION_REFUSAL
+            raise NotImplementedError(TFATTN_PARTITION_REFUSAL)
         if config.features == "host":
             raise NotImplementedError(
                 "features='host' streaming is single-device only; the "

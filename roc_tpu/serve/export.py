@@ -132,6 +132,15 @@ STATE_REFUSAL = (
     "running mean and variance ride in the parameter dict as float32 "
     "state) has no serving export: serve/export.py casts and quantizes "
     "every entry as a weight")
+# a model with dot-product attention: the predictor's cached propagation
+# and quantized tables hold a layer's weights and a fixed neighbour sum,
+# and neither a per-row LayerNorm nor the per-row gate between the
+# attention and its root path folds into them
+DOT_ATTENTION_REFUSAL = (
+    "a model with dot-product attention (--model gtrans: "
+    "transformer_attention's gated root path and layer_norm) has no "
+    "serving export: serve/export.py cannot fold LayerNorm or the gate "
+    "into its quantized tables")
 
 
 def build_predictor(model, dataset, config, params=None,
@@ -154,6 +163,8 @@ def build_predictor(model, dataset, config, params=None,
         raise NotImplementedError(TYPED_REFUSAL)
     if model.state_names():
         raise NotImplementedError(STATE_REFUSAL)
+    if model.uses_dot_attention():
+        raise NotImplementedError(DOT_ATTENTION_REFUSAL)
     model, config, _ = resolve_config(model, dataset, config)
     config = dataclasses.replace(
         config, symmetric=resolve_symmetric(dataset, config.symmetric))

@@ -50,7 +50,8 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     # TPU-era flags
     ap.add_argument("--model",
                     choices=["gcn", "sage", "gin", "gat", "sgc",
-                             "appnp", "gcn2", "rgcn", "deepergcn"],
+                             "appnp", "gcn2", "rgcn", "deepergcn",
+                             "gtrans"],
                     default="gcn",
                     help="model family (roc_tpu/models): gcn (the "
                          "reference's), sage, gin, gat, sgc, appnp, "
@@ -61,7 +62,15 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
                          "examples/ogb/ogbn_arxiv runs it: res+ "
                          "blocks of BatchNorm -> ReLU -> dropout -> "
                          "GENConv with the softmax_sg aggregation; "
-                         "-layers F-H-...-H-C, one H a layer)")
+                         "-layers F-H-...-H-C, one H a layer), gtrans "
+                         "(UniMP's Graph Transformer, arXiv:2009.03509, "
+                         "as PyG's TransformerConv with beta: dot-product "
+                         "attention over each vertex's neighbours, a "
+                         "gated root path, LayerNorm + ReLU between "
+                         "layers; -layers F-H-...-C with H the "
+                         "concatenated width of --heads heads, the "
+                         "output layer's heads C wide and averaged; "
+                         "-dropout is the attention dropout)")
     ap.add_argument("--t", type=float, default=None, dest="temperature",
                     help="for --model deepergcn: the temperature of "
                          "the softmax neighbour aggregation (default "
@@ -83,7 +92,8 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap.add_argument("--heads", type=int, default=1,
                     help="attention heads for --model gat (hidden "
                          "dims must divide by it; output layer stays "
-                         "single-head)")
+                         "single-head) and --model gtrans (every "
+                         "layer; the output layer averages them)")
     ap.add_argument("--skip", action="store_true",
                     help="for --model gat: add a bias-free linear map "
                          "of each layer's input to its attention "
@@ -405,8 +415,8 @@ def main(argv: Optional[List[str]] = None,
         except ValueError as e:
             print(f"error: --fault: {e}", file=sys.stderr)
             return 2
-    if args.model != "gat" and args.heads != 1:
-        print("error: --heads applies to --model gat only",
+    if args.model not in ("gat", "gtrans") and args.heads != 1:
+        print("error: --heads applies to --model gat/gtrans only",
               file=sys.stderr)
         return 2
     if args.model != "gat" and (args.skip or args.act is not None
@@ -537,7 +547,7 @@ def main(argv: Optional[List[str]] = None,
                   f"{args.impl!r} layout; --impl takes auto, flat_sum "
                   f"or segment for --model rgcn", file=sys.stderr)
             return 2
-    if args.model == "gat":
+    if args.model in ("gat", "gtrans"):
         if args.heads < 1:
             print("error: --heads must be >= 1", file=sys.stderr)
             return 2
@@ -550,6 +560,15 @@ def main(argv: Optional[List[str]] = None,
                 not 0.0 <= args.input_dropout < 1.0:
             print("error: --input-dropout must be in [0, 1)",
                   file=sys.stderr)
+            return 2
+    if args.model == "gtrans":
+        if not 0.0 <= args.dropout < 1.0:
+            print("error: -dropout (the attention dropout of --model "
+                  "gtrans) must be in [0, 1)", file=sys.stderr)
+            return 2
+        if args.parts > 1:
+            from ..models.builder import TFATTN_PARTITION_REFUSAL
+            print(f"error: {TFATTN_PARTITION_REFUSAL}", file=sys.stderr)
             return 2
 
     # set-up's phases go under spans (obs/events.py span) from here on;
@@ -611,6 +630,8 @@ def main(argv: Optional[List[str]] = None,
                   "relations": ds.typed.relations}
     if args.model == "deepergcn":
         kwargs["t"] = args.temperature
+    if args.model == "gtrans":
+        kwargs["heads"] = args.heads
     model = build[args.model](layers, dropout_rate=args.dropout,
                               **kwargs)
     dt, cdt = resolve_dtypes(args.dtype)

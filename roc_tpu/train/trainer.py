@@ -393,7 +393,15 @@ def resolve_attention_impl(model, config: TrainConfig,
     the whole ring-table build first).  Attention models on graphs
     past ``ATTN_FLAT8_MIN_EDGES`` route to the uniform 'attn_flat8'
     layout instead (compile size at scale; pass ``dataset`` to enable
-    the scale check)."""
+    the scale check) — additive attention only: a dot-product attention
+    model (``transformer_attention``) on that layout, asked for or
+    routed to, is refused by name (``TFATTN_FLAT8_REFUSAL``)."""
+    if model.uses_dot_attention() and (
+            config.aggr_impl == "attn_flat8"
+            or (dataset is not None and config.aggr_impl != "ell"
+                and dataset.graph.num_edges >= ATTN_FLAT8_MIN_EDGES)):
+        from ..models.builder import TFATTN_FLAT8_REFUSAL
+        raise NotImplementedError(TFATTN_FLAT8_REFUSAL)
     why = ("attention" if model.uses_attention()
            else "MAX/MIN aggregation" if model.uses_max_aggregation()
            else None)
@@ -1124,14 +1132,15 @@ def split_state(params, names):
 def cast_compute(params, dtype):
     """:func:`cast_floats` over a parameter dict, leaving a
     ``batch_norm``'s entries (``bn_*``: scale, shift and the running
-    statistics) in float32: they are ``[F]`` vectors read by float32
-    arithmetic, and a bfloat16 copy would only round them."""
-    from ..obs.scopes import BN_PARAM_PREFIX
+    statistics) and a ``layer_norm``'s (``ln_*``: scale, shift) in
+    float32: they are ``[F]`` vectors read by float32 arithmetic, and a
+    bfloat16 copy would only round them."""
+    from ..obs.scopes import FLOAT32_PARAM_PREFIXES as kept
     if not isinstance(params, dict) or not any(
-            k.startswith(BN_PARAM_PREFIX) for k in params):
+            k.startswith(kept) for k in params):
         return cast_floats(params, dtype)
-    return {k: (v if k.startswith(BN_PARAM_PREFIX)
-                else cast_floats(v, dtype)) for k, v in params.items()}
+    return {k: (v if k.startswith(kept) else cast_floats(v, dtype))
+            for k, v in params.items()}
 
 
 def cast_params(params, dtype):
