@@ -44,6 +44,9 @@ the same four, over the relations that end in that kind — stacks of
 those relations' blocks, the destination vertices the id prefix they
 end in, every slot still weighing its edge's own ``1 / deg_r(v)``, the
 sorted order of each pass masked out of the whole graph's.
+
+The same choice of side on a homogeneous graph, for a GCN-form layer's
+linear and its normalized sum, is :func:`resolve_agg_order`.
 """
 
 from __future__ import annotations
@@ -59,6 +62,10 @@ REL_ORDERS = (TRANSFORM_FIRST, GATHER_FIRST)
 # the passes an order runs: (forward, backward)
 ORDER_PASSES = {TRANSFORM_FIRST: ("tf_fwd", "tf_bwd"),
                 GATHER_FIRST: ("gf_fwd", "gf_bwd")}
+# the two sides of a GCN-form layer's linear its sum can run on
+# (resolve_agg_order)
+LINEAR_FIRST = "linear_first"
+AGGREGATE_FIRST = "aggregate_first"
 
 
 @dataclass
@@ -359,3 +366,32 @@ def resolve_rel_order(in_dim: int, out_dim: int, lane_width) -> str:
     no stacked table of products is built for the scan to read."""
     return (TRANSFORM_FIRST
             if lane_width(out_dim) < lane_width(in_dim) else GATHER_FIRST)
+
+
+def agg_passes(order: str, trained_input: bool) -> int:
+    """Sum passes a train step makes for a GCN-form layer: the forward
+    and its transpose, which carries the cotangent to whatever
+    parameter feeds the summed rows — ``W`` under ``linear_first``,
+    an earlier layer's under ``aggregate_first`` with a
+    ``trained_input``.  Summed first, an input no parameter precedes
+    (the model's features, a dropout of them) takes no gradient, and
+    autodiff transposes nothing."""
+    return 1 if order == AGGREGATE_FIRST and not trained_input else 2
+
+
+def resolve_agg_order(in_dim: int, out_dim: int, trained_input: bool,
+                      lane_width) -> str:
+    """Which side of a GCN-form layer's bias-free linear its normalized
+    sum runs on — ONE place for the rule, the homogeneous twin of
+    :func:`resolve_rel_order`.  ``Â (X W) = (Â X) W`` exactly: no bias,
+    and the degree norms are diagonal.  A pass gathers one row an edge
+    at the width the layout runs the summed rows at (``lane_width``,
+    ``core/ell.py agg_lane_width``), so a side costs its passes
+    (:func:`agg_passes`) times that width: ``linear_first`` two at
+    ``out_dim``, ``aggregate_first`` one or two at ``in_dim``.  The sum
+    moves first only where that is strictly cheaper; on a tie the
+    reference's order stays.  DGL's ``GraphConv`` makes the same
+    choice on the widths alone (``W`` first when ``in > out``)."""
+    first = agg_passes(AGGREGATE_FIRST, trained_input) * lane_width(in_dim)
+    last = agg_passes(LINEAR_FIRST, trained_input) * lane_width(out_dim)
+    return AGGREGATE_FIRST if first < last else LINEAR_FIRST

@@ -22,7 +22,7 @@ requirement, ``scattergather.cc:70-72``).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +30,8 @@ import numpy as np
 
 from ..core.ell import LANE_WIDTH, agg_lane_width
 from ..core.memory import remat_segments
-from ..core.relations import ORDER_PASSES, TRANSFORM_FIRST
+from ..core.relations import (AGGREGATE_FIRST, LINEAR_FIRST, ORDER_PASSES,
+                              TRANSFORM_FIRST)
 from ..obs.scopes import (ALLREDUCE_SCOPE, ATTN_GATE_SCOPE,
                           ATTN_SCORES_SCOPE, BN_PARAM_PREFIX, EMBED_SCOPE,
                           HALO_SCOPE, LN_PARAM_PREFIX, LOSS_SCOPE,
@@ -1144,6 +1145,27 @@ class _Op:
     attrs: Dict[str, Any] = field(default_factory=dict)
 
 
+class GcnLayer(NamedTuple):
+    """A GCN-form layer of an op list (:meth:`Model.gcn_layers`): the
+    bias-free ``linear`` op, the normalized sum ``ops[lo..hi]`` beside
+    it (one ``fused_aggregate``, or ``indegree_norm -> scatter_gather
+    -> indegree_norm``), the side the sum runs on, the linear's two
+    widths, and whether a parameter is among the ancestors of the
+    layer's input."""
+    linear: int
+    lo: int
+    hi: int
+    order: str
+    in_dim: int
+    out_dim: int
+    trained: bool
+
+    @property
+    def sum_op(self) -> int:
+        """The op that sums over the edges."""
+        return self.lo if self.lo == self.hi else self.lo + 1
+
+
 class Model:
     """Builder + interpreter.  Mirrors the reference Model API
     (``gnn.h:162-203``); see module docstring."""
@@ -1208,6 +1230,106 @@ class Model:
                              attrs={**base, "row_scale": scale})
                 ops[i + 1] = _Op("rel_linear", (i,), a["out_dim"],
                                  lin.param, dict(base))
+        return self._with_ops(ops) if changed else self
+
+    def _norm_sum(self, i: int, src: int, readers) -> Optional[int]:
+        """The last op of the normalized sum ``D^-1/2 A D^-1/2`` that
+        starts at op ``i`` and reads op ``src`` alone: a SUM
+        ``fused_aggregate`` (``i``), or the ``indegree_norm ->
+        scatter_gather(SUM) -> indegree_norm`` chain
+        :meth:`fuse_norm_aggregate` folds (``i + 2``: its inner tensors
+        read once and neither the loss's).  None for anything else."""
+        ops = self._ops
+        if i >= len(ops) or ops[i].inputs != (src,):
+            return None
+        if ops[i].kind == "fused_aggregate":
+            return (i if ops[i].attrs.get("aggr", AGGR_SUM) == AGGR_SUM
+                    else None)
+        if (ops[i].kind == "indegree_norm" and i + 2 < len(ops)
+                and ops[i + 1].kind == "scatter_gather"
+                and ops[i + 1].inputs == (i,)
+                and ops[i + 1].attrs.get("aggr", AGGR_SUM) == AGGR_SUM
+                and ops[i + 2].kind == "indegree_norm"
+                and ops[i + 2].inputs == (i + 1,)
+                and readers[i] == readers[i + 1] == 1
+                and self._loss_op not in (i, i + 1)):
+            return i + 2
+        return None
+
+    def gcn_layers(self) -> List[GcnLayer]:
+        """Every GCN-form layer of the op list, in op order: a
+        bias-free ``linear`` and a normalized sum (:meth:`_norm_sum`)
+        right after it — ``linear_first``: the linear has no activation
+        and the sum is its one reader, not the loss's — or right before
+        it, where :meth:`with_agg_order` moved the sum onto the
+        linear's input (``aggregate_first``, marked on the linear)."""
+        ops = self._ops
+        readers = [0] * len(ops)
+        trained = [False] * len(ops)
+        for i, op in enumerate(ops):
+            for j in op.inputs:
+                readers[j] += 1
+            trained[i] = op.param is not None or any(
+                trained[j] for j in op.inputs)
+        out = []
+        for i, op in enumerate(ops):
+            if op.kind != "linear" or op.attrs.get("bias"):
+                continue
+            if op.attrs.get("agg_order") == AGGREGATE_FIRST:
+                hi = op.inputs[0]
+                lo = hi if ops[hi].kind == "fused_aggregate" else hi - 2
+                out.append(GcnLayer(i, lo, hi, AGGREGATE_FIRST,
+                                    op.attrs["in_dim"], op.dim,
+                                    trained[ops[lo].inputs[0]]))
+                continue
+            if (op.attrs.get("activation", AC_MODE_NONE) != AC_MODE_NONE
+                    or readers[i] != 1
+                    or self._loss_op == i):
+                continue
+            hi = self._norm_sum(i + 1, i, readers)
+            if hi is not None:
+                out.append(GcnLayer(i, i + 1, hi, LINEAR_FIRST,
+                                    op.attrs["in_dim"], op.dim,
+                                    trained[op.inputs[0]]))
+        return out
+
+    def with_agg_order(self, resolve) -> "Model":
+        """The model with each ``linear_first`` GCN-form layer
+        (:meth:`gcn_layers`) whose sum ``resolve(in_dim, out_dim,
+        trained)`` puts first (``core/relations.py resolve_agg_order``)
+        rewritten in place to the same normalized sum over the linear's
+        input, then the linear: ``Â (X W) = (Â X) W``, the linear being
+        bias-free and the norms diagonal.  A fused sum's activation
+        moves onto the linear; an unfused chain's ``relu`` stays the op
+        it was, now reading the linear.  The linear keeps its name and
+        every later op its index and its inputs; the marker
+        ``agg_order`` on the linear says the layer was moved.  Returns
+        ``self`` when nothing changes (``resolve_config`` is
+        idempotent)."""
+        ops = self._copied_ops()
+        changed = False
+        for layer in self.gcn_layers():
+            if layer.order != LINEAR_FIRST or resolve(
+                    layer.in_dim, layer.out_dim,
+                    layer.trained) != AGGREGATE_FIRST:
+                continue
+            changed = True
+            lin, lo, hi = layer.linear, layer.lo, layer.hi
+            src, attrs = ops[lin].inputs, {**ops[lin].attrs,
+                                           "agg_order": AGGREGATE_FIRST}
+            if lo == hi:
+                act = ops[lo].attrs.get("activation", AC_MODE_NONE)
+                ops[lin] = _Op("fused_aggregate", src, layer.in_dim,
+                               attrs={**ops[lo].attrs,
+                                      "activation": AC_MODE_NONE})
+                attrs["activation"] = act
+            else:
+                ops[lin] = _Op("indegree_norm", src, layer.in_dim)
+                ops[lo] = _Op("scatter_gather", (lin,), layer.in_dim,
+                              attrs=dict(ops[lo + 1].attrs))
+                ops[lo + 1] = _Op("indegree_norm", (lo,), layer.in_dim)
+            ops[hi] = _Op("linear", (hi - 1,), layer.out_dim,
+                          self._ops[lin].param, attrs)
         return self._with_ops(ops) if changed else self
 
     def _copied_ops(self) -> List[_Op]:
@@ -1354,16 +1476,8 @@ class Model:
         chains: Dict[int, Tuple[int, str]] = {}
         i = 1
         while i + 2 < n:
-            o0, o1, o2 = ops[i], ops[i + 1], ops[i + 2]
-            ok = (o0.kind == "indegree_norm"
-                  and o1.kind == "scatter_gather"
-                  and o1.inputs == (i,)
-                  and o1.attrs.get("aggr", AGGR_SUM) == AGGR_SUM
-                  and o2.kind == "indegree_norm"
-                  and o2.inputs == (i + 1,)
-                  and consumers[i] == 1 and consumers[i + 1] == 1
-                  and loss not in (i, i + 1))
-            if not ok:
+            if not (ops[i].kind == "indegree_norm" and self._norm_sum(
+                    i, ops[i].inputs[0], consumers) is not None):
                 i += 1
                 continue
             end, act = i + 2, AC_MODE_NONE

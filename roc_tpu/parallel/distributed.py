@@ -48,7 +48,8 @@ from ..obs.events import emit, flush_spans, span
 from ..obs.scopes import ALLREDUCE_SCOPE, LOSS_SCOPE, OPT_SCOPE
 from ..ops.loss import masked_softmax_cross_entropy, perf_metrics, summarize_metrics
 from ..train.optimizer import AdamConfig, adam_init, adam_update
-from ..train.trainer import (TrainConfig, batch_norm_plan, cast_compute,
+from ..train.trainer import (TrainConfig, agg_order_plan,
+                             batch_norm_plan, cast_compute,
                              compute_dtype_of, resolve_symmetric,
                              split_state, upload)
 
@@ -880,6 +881,8 @@ class DistributedTrainer:
                     **self._gctx().soft_plan(model._ops, self.compute),
                     **batch_norm_plan(model._ops,
                                       dataset.graph.num_nodes),
+                    **agg_order_plan(model, self.config.aggr_impl,
+                                     self.config.halo),
                     "memory_plan": self._plan},
                 console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog

@@ -20,7 +20,8 @@ import numpy as np
 
 from ..core.graph import Dataset
 from ..core.partition import padded_edge_list
-from ..core.relations import ORDER_PASSES
+from ..core.relations import (AGGREGATE_FIRST, ORDER_PASSES, agg_passes,
+                              resolve_agg_order)
 from ..models.builder import GraphContext, Model
 from ..obs.events import emit, flush_spans, span
 from ..obs.metrics_registry import MetricsRegistry
@@ -763,14 +764,75 @@ def resolve_relations(model: Model, dataset: Dataset,
     return resolved, config
 
 
+def resolve_agg_orders(model: Model, dataset: Dataset,
+                       config: TrainConfig, num_parts: int = 1) -> Model:
+    """Each GCN-form layer's normalized sum on the side of its
+    bias-free linear that ``core/relations.py resolve_agg_order``
+    names (``Model.with_agg_order``), read off the layer's widths, the
+    lanes the resolved layout runs them at and whether a parameter
+    precedes the layer's input.  Returns the SAME model when nothing
+    moves.
+
+    Not where the host tier streams the input head out of core
+    (``features='host'``, ``core/streaming.py StreamedHead``): it
+    streams ``dropout -> linear`` from the features (or after a
+    parameter-free prefix), and a sum moved between the two leaves no
+    head to stream.  Under ``memory='auto'`` the autopilot picks that
+    tier only where nothing else fits, so the sums move only where the
+    rewritten model fits the budget without it; elsewhere the
+    reference's order stays, and the autopilot plans that (its head
+    streamed, or under remat, whose runs the rewrite also moves)."""
+    from ..core.ell import agg_lane_width
+    # 'auto' is left here only to the attention and MAX models, whose
+    # layout resolve_attention_impl settles after the plan
+    if config.features == "host" or config.aggr_impl == "auto":
+        return model
+    halo = config.halo if num_parts > 1 else "gather"
+    ordered = model.with_agg_order(
+        lambda i, o, trained: resolve_agg_order(
+            i, o, trained,
+            lambda f: agg_lane_width(f, config.aggr_impl, halo)))
+    if ordered is model:
+        return model
+    if config.memory == "auto":
+        from ..core.memory import choose_memory_plan
+        with span("setup.resolve.plan"):
+            fits = choose_memory_plan(
+                hbm_bytes=config.hbm_bytes, head_streamable=False,
+                **_plan_kwargs(ordered, dataset, config, num_parts)).fits
+        if not fits:
+            return model
+    return ordered
+
+
+def agg_order_plan(model: Model, aggr_impl: str,
+                   halo: str = "gather") -> Dict[str, Any]:
+    """The ``plan`` line's ``agg_order``: one ``[op, order, in_lanes,
+    out_lanes, passes]`` a GCN-form layer (``Model.gcn_layers``) — the
+    op that sums, the side of the linear it runs on
+    (``resolve_agg_orders``), the lanes the linear's input and output
+    widths run at in the sum scan (``core/ell.py agg_lane_width``) and
+    the sum passes a train step makes for the layer
+    (``core/relations.py agg_passes``).  Empty for a model without
+    one."""
+    from ..core.ell import agg_lane_width
+    rows = [[l.sum_op, l.order, agg_lane_width(l.in_dim, aggr_impl, halo),
+             agg_lane_width(l.out_dim, aggr_impl, halo),
+             agg_passes(l.order, l.trained)]
+            for l in model.gcn_layers()]
+    return {"agg_order": rows} if rows else {}
+
+
 def resolve_config(model: Model, dataset: Dataset, config: TrainConfig,
                    num_parts: int = 1, multiprocess: bool = False):
     """THE config resolve pass — fuse rewrite, ``aggr_impl='auto'``
-    (incl. the bdense structure probe), memory autopilot, attention
+    (incl. the bdense structure probe), the side of each GCN-form
+    layer's linear its sum runs on, memory autopilot, attention
     impl — in the ONE order that makes the memory plan honest: the
     probe runs first so an auto→bdense outcome re-enters
     ``choose_memory_plan`` with the A-budget charged
-    (``core/memory.charged_table_bytes``), and the attention rewrite
+    (``core/memory.charged_table_bytes``), the sums move before the
+    plan so it charges the ops that run, and the attention rewrite
     runs last because it must see the chosen halo.  Shared by BOTH
     trainer constructors and the program-space auditor
     (``analysis/programspace.py``) so the statically enumerated
@@ -778,8 +840,9 @@ def resolve_config(model: Model, dataset: Dataset, config: TrainConfig,
     never diverge at the resolve layer.
 
     Idempotent by construction: a resolved config re-entering this
-    pass is unchanged (fuse finds no new chains on a fused model,
-    ``memory`` is already 'manual', ``aggr_impl`` concrete), so
+    pass is unchanged (fuse finds no new chains on a fused model, no
+    moved sum is a ``linear_first`` layer any more, ``memory`` is
+    already 'manual', ``aggr_impl`` concrete), so
     re-resolving yields the identical program-key set — the auditor
     asserts exactly that (tests/test_programspace.py).
 
@@ -794,8 +857,21 @@ def resolve_config(model: Model, dataset: Dataset, config: TrainConfig,
         config, bd_census = resolve_auto_impl_early(
             model, config, dataset.graph, out_rows=out_rows,
             multiprocess=multiprocess)
-        config = apply_memory_autopilot(model, dataset, config,
-                                        num_parts=num_parts)
+        ordered = resolve_agg_orders(model, dataset, config, num_parts)
+        planned = apply_memory_autopilot(ordered, dataset, config,
+                                         num_parts=num_parts)
+        if planned.halo != config.halo:
+            # the rule read the lanes of the halo asked for; the ring
+            # the plan chose runs every width unpadded
+            ordered = resolve_agg_orders(model, dataset, planned,
+                                         num_parts)
+        if ordered is not model:
+            moved = [l.sum_op for l in ordered.gcn_layers()
+                     if l.order == AGGREGATE_FIRST]
+            emit("resolve", "agg_order: the sum of op(s) "
+                 + ", ".join(map(str, moved)) + " runs before its "
+                 "linear", console=config.verbose, agg_order=moved)
+        model, config = ordered, planned
         config = resolve_attention_impl(model, config, dataset)
     return model, config, bd_census
 
@@ -1371,6 +1447,8 @@ class Trainer:
                     **self.gctx.soft_plan(model._ops, self.compute),
                     **batch_norm_plan(model._ops,
                                       dataset.graph.num_nodes),
+                    **agg_order_plan(model, self.gctx.aggr_impl,
+                                     self.gctx.halo),
                     "memory_plan": self._plan},
                 console=config.verbose)
         from ..utils.profiling import EpochTimer, MetricsLog

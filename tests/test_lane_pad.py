@@ -223,7 +223,10 @@ def test_other_layouts_lower_to_the_parents_text(ds, monkeypatch):
 def test_plan_line_carries_agg_lane_pad(tmp_path, ds, impl, parts,
                                         fuse):
     """``resolved`` (the benchmark's ``plan`` line) lists one
-    ``[op, F, Fp]`` per sum-aggregating op, from both trainers."""
+    ``[op, F, Fp]`` per sum-aggregating op, from both trainers.  The
+    first layer sums its 12-wide input before its linear
+    (``core/relations.py resolve_agg_order``: 12, or 128 lanes, once
+    against twice 160)."""
     from roc_tpu.obs.events import configure
     p = str(tmp_path / "ev.jsonl")
     cfg = TrainConfig(aggr_impl=impl, aggr_fuse=fuse, verbose=False,
@@ -243,7 +246,8 @@ def test_plan_line_carries_agg_lane_pad(tmp_path, ds, impl, parts,
     ops = [i for i, op in enumerate(tr.model._ops) if op.kind == kind]
     assert len(ops) == 2
     pad5 = 128 if impl == "flat_sum" else 5
-    assert res["agg_lane_pad"] == [[ops[0], 160, 160],
+    pad12 = 128 if impl == "flat_sum" else 12
+    assert res["agg_lane_pad"] == [[ops[0], 12, pad12],
                                    [ops[1], 5, pad5]]
 
 

@@ -17,6 +17,7 @@ import jax
 import pytest
 
 from roc_tpu.core.graph import synthetic_dataset
+from roc_tpu.core.relations import agg_passes
 from roc_tpu.models.builder import Model
 from roc_tpu.models.deepergcn import build_deepergcn
 from roc_tpu.models.gat import build_gat
@@ -235,17 +236,23 @@ def test_scopes_live_in_metadata_only(ds):
     """The acceptance check of ISSUE 25: the lowered program (locations
     off, the default) holds no ``roc.`` at all — same instructions, same
     executable, same compile-cache key — while the compiled text shows
-    each aggregation under ``jvp(`` and ``transpose(jvp(``."""
+    each aggregation under ``jvp(`` and, where it has a backward pass,
+    ``transpose(jvp(`` (a sum of the dropped-out features, moved
+    before its linear, has none: ``core/relations.py agg_passes``)."""
     tr = Trainer(build_gcn(LAYERS), ds,
                  TrainConfig(verbose=False, aggr_impl="sectioned"))
     tr.train(epochs=1)
     step = tr._train_step
     assert "roc." not in step._lowered.as_text()
     text = step._compiled.as_text()
+    once = {l.sum_op for l in tr.model.gcn_layers()
+            if agg_passes(l.order, l.trained) == 1}
+    assert once
     for i, op in enumerate(tr.model._ops):
         if op.kind in AGG_KINDS:
             assert f"/jvp(roc.agg.op{i:02d})/" in text
-            assert f"/transpose(jvp(roc.agg.op{i:02d}))/" in text
+            assert (f"/transpose(jvp(roc.agg.op{i:02d}))/"
+                    in text) == (i not in once)
 
 
 STALE = """HloModule jit_step, is_scheduled=true
