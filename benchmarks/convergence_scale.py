@@ -104,8 +104,8 @@ def run_config(ds, args, dtype_name: str) -> dict:
     tr.sync()
     train_s = time.time() - t0
     m = tr.evaluate()
-    bd_tiles = (int(tr.gctx.bd_a.shape[0])
-                if tr.gctx.bd_a is not None else 0)
+    bd_a = getattr(tr.gctx.tables, "a", None)
+    bd_tiles = int(bd_a.shape[0]) if bd_a is not None else 0
     return {"dtype": dtype_name,
             "impl": tr.gctx.aggr_impl,
             **({"bdense_tiles": bd_tiles}

@@ -150,10 +150,11 @@ def ring_overlap(args):
     from jax.sharding import PartitionSpec as P
     from roc_tpu.core.graph import synthetic_dataset
     from roc_tpu.core.partition import partition_graph
+    from roc_tpu.models.tables import build_tables
     from roc_tpu.obs.events import emit
     from roc_tpu.parallel import ring as R
     from roc_tpu.parallel.distributed import (_shard_map, make_mesh,
-                                              pad_nodes)
+                                              pad_nodes, stacked_view)
     parts = min(args.parts, len(jax.devices()))
     if parts < 2:
         return {"skipped": f"{len(jax.devices())} device(s)"}
@@ -161,7 +162,7 @@ def ring_overlap(args):
     ds = synthetic_dataset(V, 8, in_dim=args.dim, num_classes=4,
                            seed=2)
     pg = partition_graph(ds.graph, parts, node_multiple=8)
-    rt = R.build_ring_tables(pg)
+    rt = build_tables("ring", stacked_view(pg, np.asarray, remap=False))
     mesh = make_mesh(parts)
     x = jnp.asarray(pad_nodes(
         np.random.RandomState(3).rand(V, args.dim).astype(np.float32),

@@ -15,7 +15,7 @@ from .core.partition import (PartitionedGraph, edge_balanced_bounds,
                              partition_graph)
 from .core.costmodel import (PartitionCostModel, cost_balanced_bounds,
                              partition_static_stats)
-from .core.ell import EllTable, ell_from_graph, ell_from_padded_parts
+from .core.ell import EllTable, ell_from_graph
 from .models.builder import (AGGR_AVG, AGGR_MAX, AGGR_SUM, GraphContext,
                              Model)
 from .models.gcn import build_gcn

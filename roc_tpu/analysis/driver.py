@@ -124,6 +124,7 @@ def build_trace_findings(select: Optional[List[str]] = None,
     import stays inside so the AST-only path never touches jax."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from ..core.graph import synthetic_dataset
     from ..models.gcn import build_gcn
@@ -184,8 +185,6 @@ def build_trace_findings(select: Optional[List[str]] = None,
             TrainConfig(verbose=False, symmetric=True,
                         dtype=jnp.float32,
                         compute_dtype=jnp.bfloat16))
-        d = dtr.data
-        fuse_tabs = (d.ell_w, d.sect_w, d.ring_w, d.bd_scale)
         dctx = dict(ctx)
         dctx["halo"] = dtr.config.halo
         # shard_map body avals are block-local: scale-relative rules
@@ -194,17 +193,11 @@ def build_trace_findings(select: Optional[List[str]] = None,
         dctx["mesh_parts"] = parts
         units.append(JaxprUnit(
             "dist_train_step", jax.make_jaxpr(dtr._train_step._jit)(
-                dtr.params, dtr.opt_state, d.feats, d.labels, d.mask,
-                d.edge_src, d.edge_dst, d.in_degree, d.ell_idx,
-                d.ell_row_pos, d.ell_row_id, d.ring_idx, d.sect_idx,
-                d.sect_sub_dst, d.bd_tabs, fuse_tabs, key, lr),
+                *dtr._train_args(key, lr)),
             **dctx))
         units.append(JaxprUnit(
             "dist_eval_step", jax.make_jaxpr(dtr._eval_step._jit)(
-                dtr.params, d.feats, d.labels, d.mask, d.edge_src,
-                d.edge_dst, d.in_degree, d.ell_idx, d.ell_row_pos,
-                d.ell_row_id, d.ring_idx, d.sect_idx, d.sect_sub_dst,
-                d.bd_tabs, fuse_tabs),
+                *dtr._eval_args()),
             **dctx))
 
     findings = run_jaxpr_lint(units, select=select)
@@ -221,8 +214,10 @@ def build_trace_findings(select: Optional[List[str]] = None,
     if len(jax.devices()) > 1 and collective_selected:
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.distributed import PARTS_AXIS, _shard_map
-        from ..parallel.ring import build_ring_tables, ring_aggregate
+        from ..models.tables import build_tables
+        from ..parallel.distributed import (PARTS_AXIS, _shard_map,
+                                            stacked_view)
+        from ..parallel.ring import ring_aggregate
         axes = {PARTS_AXIS: parts}
         # the dist steps' traced collectives (gradient psum, halo
         # gather, metrics reduction) re-use the jaxprs above
@@ -233,7 +228,8 @@ def build_trace_findings(select: Optional[List[str]] = None,
         # the ring-halo subroutine, traced standalone: the gather-halo
         # trainer above never emits a ppermute, and the ring schedule
         # is exactly what the cycle rule exists to verify
-        rt = build_ring_tables(dtr.pg)
+        rt = build_tables("ring", stacked_view(dtr.pg, np.asarray,
+                                               remap=False))
         ring_fn = _shard_map(
             lambda x, s_, d_: ring_aggregate(
                 x[0], s_[0], d_[0], axis_name=PARTS_AXIS),

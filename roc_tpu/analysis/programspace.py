@@ -372,28 +372,17 @@ def candidate_programs(tr) -> List["Candidate"]:
             aot=lambda j=jitfn, a=args: j.lower(*a).compile()))
 
     if getattr(tr, "pg", None) is not None:       # distributed
-        d = tr.data
-        fuse = (d.ell_w, d.sect_w, d.ring_w, d.bd_scale)
-        graph_args = (d.edge_src, d.edge_dst, d.in_degree, d.ell_idx,
-                      d.ell_row_pos, d.ell_row_id, d.ring_idx,
-                      d.sect_idx, d.sect_sub_dst, d.bd_tabs, fuse)
-        graph_roles = ("tables",) * len(graph_args)
-        # 2-D partial-auto steps take the trailing parts-sharded
-        # partition-index vector (distributed._build_steps); the
-        # enumerated args must carry it or the keys (and make_jaxpr
-        # arity) diverge from the live programs
-        pids = (() if getattr(tr, "_pids", None) is None
-                else (tr._pids,))
-        pid_roles = ("data",) * len(pids)
+        # the steps' own argument builders: the enumerated args are the
+        # live programs' (a 2-D partial-auto step's trailing
+        # parts-sharded partition-index vector included), or the keys
+        # and make_jaxpr arity would diverge
+        pid_roles = ("data",) * len(tr._pid_args())
         add("dist_train_step", tr._train_step._jit,
-            (tr.params, tr.opt_state, d.feats, d.labels, d.mask)
-            + graph_args + (tr.key, lr) + pids, donate=(0, 1),
-            roles=("params", "opt_state", "data", "data", "data")
-            + graph_roles + ("other", "other") + pid_roles)
-        add("dist_eval_step", tr._eval_step._jit,
-            (tr.params, d.feats, d.labels, d.mask) + graph_args
-            + pids,
-            roles=("params", "data", "data", "data") + graph_roles
+            tr._train_args(tr.key, lr), donate=(0, 1),
+            roles=("params", "opt_state", "data", "data", "data",
+                   "tables", "other", "other") + pid_roles)
+        add("dist_eval_step", tr._eval_step._jit, tr._eval_args(),
+            roles=("params", "data", "data", "data", "tables")
             + pid_roles)
     elif tr._head is None:                        # plain single-device
         add("train_step", tr._train_step._jit,

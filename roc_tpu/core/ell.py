@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, Optional, Tuple
 
 import numpy as np
 
@@ -60,42 +60,28 @@ class EllTable:
     row_pos: np.ndarray
     row_id: Tuple[np.ndarray, ...] = ()
 
-    @property
-    def num_parts(self) -> int:
-        return self.row_pos.shape[0]
 
-    def device_view(self, p: int) -> "EllTable":
-        """Single-partition slice (keeps the leading axis)."""
-        return EllTable(widths=self.widths,
-                        idx=tuple(a[p:p + 1] for a in self.idx),
-                        row_pos=self.row_pos[p:p + 1],
-                        row_id=tuple(a[p:p + 1] for a in self.row_id))
-
-
-def ell_weight_tables(table: EllTable, d_dst: np.ndarray,
+def ell_weight_tables(idx, row_id, d_dst: np.ndarray,
                       d_src: np.ndarray) -> Tuple[np.ndarray, ...]:
-    """Baked fused-normalization weights for an :class:`EllTable` —
-    one fp32 array per bucket, shaped like ``idx``:
-    ``w[p, r, j] = d_dst[p, row_id[p, r]] * d_src[idx[p, r, j]]``
-    (the per-edge entries of ``D^-1/2 A D^-1/2`` in ELL layout, so
-    the fused aggregation needs ZERO runtime normalization —
-    ops/aggregate.py aggregate_ell ``ell_w``).
+    """Baked fused-normalization weights for one part's ELL buckets
+    (``idx`` / ``row_id``, one array a bucket) — one fp32 array per
+    bucket, shaped like ``idx``: ``w[r, j] = d_dst[row_id[r]] *
+    d_src[idx[r, j]]`` (the per-edge entries of ``D^-1/2 A D^-1/2`` in
+    ELL layout, so the fused aggregation needs ZERO runtime
+    normalization — ops/aggregate.py aggregate_ell ``ell_w``).
 
-    d_dst: [P, part_nodes] inv-sqrt in-degrees of local output rows.
+    d_dst: [rows] inv-sqrt in-degrees of the part's output rows.
     d_src: [gathered_rows] the same in gathered-source coordinates
-      (single-device: == d_dst[0]; distributed: the padded global
-      layout).  Padding bucket rows (``row_id == part_nodes``) and
-      padding entries (``idx == gathered_rows`` dummy) weigh 0.
+      (single-device: == d_dst; distributed: the padded global
+      layout).  Padding bucket rows (``row_id == rows``) and padding
+      entries (``idx == gathered_rows`` dummy) weigh 0.
     """
-    d_dst = np.asarray(d_dst, dtype=np.float32)
-    P = table.num_parts
-    dd = np.concatenate([d_dst, np.zeros((P, 1), np.float32)], axis=1)
-    ds = np.concatenate([np.asarray(d_src, dtype=np.float32),
+    dd = np.concatenate([np.asarray(d_dst, np.float32),
                          np.zeros(1, np.float32)])
-    parts = np.arange(P)[:, None]
-    return tuple(
-        (dd[parts, rid][:, :, None] * ds[idx]).astype(np.float32)
-        for idx, rid in zip(table.idx, table.row_id))
+    ds = np.concatenate([np.asarray(d_src, np.float32),
+                         np.zeros(1, np.float32)])
+    return tuple((dd[rid][:, None] * ds[i]).astype(np.float32)
+                 for i, rid in zip(idx, row_id))
 
 
 def row_widths(deg: np.ndarray, min_width: int) -> np.ndarray:
@@ -214,58 +200,19 @@ def place_ell_part(buckets: dict, widths: Tuple[int, ...],
     return idx_arrays, row_pos, rid_arrays
 
 
-def stack_ell(per_part_buckets: Sequence[dict], part_nodes: int,
-              dummy: int) -> EllTable:
-    """Unify bucket structure across partitions and stack into the
-    equal-shape arrays shard_map needs."""
-    P = len(per_part_buckets)
-    widths = sorted({w for b in per_part_buckets for w in b})
-    rows_per_width = {
-        w: max((b[w][0].shape[0] if w in b else 0
-                for b in per_part_buckets), default=0)
-        for w in widths}
-    # drop empty widths, keep at least one so shapes exist
-    widths = tuple(w for w in widths if rows_per_width[w] > 0) or (8,)
-    rows_per_width = {w: max(rows_per_width.get(w, 0), 1) for w in widths}
-
-    per_part = [place_ell_part(b, widths, rows_per_width, part_nodes,
-                               dummy) for b in per_part_buckets]
-    idx_arrays = tuple(
-        np.stack([per_part[p][0][wi] for p in range(P)])
-        for wi in range(len(widths)))
-    row_pos = np.stack([per_part[p][1] for p in range(P)])
-    row_id = tuple(
-        np.stack([per_part[p][2][wi] for p in range(P)])
-        for wi in range(len(widths)))
-    return EllTable(widths=widths, idx=idx_arrays, row_pos=row_pos,
-                    row_id=row_id)
-
-
-def ell_from_padded_parts(part_row_ptr: np.ndarray,
-                          part_col_idx: np.ndarray,
-                          real_nodes: np.ndarray,
-                          part_nodes: int, dummy: int,
-                          min_width: int = 8) -> EllTable:
-    """EllTable for a PartitionedGraph's local CSRs (col indices already
-    remapped to gathered-row coordinates; padding rows/edges excluded by
-    slicing to the real row count — the local row_ptr bounds the real
-    edge extent)."""
-    per_part = []
-    for p in range(part_row_ptr.shape[0]):
-        n = int(real_nodes[p])
-        ptr = part_row_ptr[p, :n + 1].astype(np.int64)
-        per_part.append(build_ell(ptr, part_col_idx[p],
-                                  min_width=min_width))
-    return stack_ell(per_part, part_nodes, dummy)
-
-
 def ell_from_graph(row_ptr: np.ndarray, col_idx: np.ndarray,
                    num_nodes: int, min_width: int = 8) -> EllTable:
     """Single-device EllTable (P == 1); dummy = num_nodes (the appended
     zero row)."""
-    b = build_ell(np.asarray(row_ptr), np.asarray(col_idx),
-                  min_width=min_width)
-    return stack_ell([b], num_nodes, dummy=num_nodes)
+    row_ptr = np.asarray(row_ptr)
+    widths, rows_per_width = ell_shape_plan(
+        row_ptr[None], np.asarray([num_nodes]), min_width)
+    idx, row_pos, row_id = place_ell_part(
+        build_ell(row_ptr, np.asarray(col_idx), min_width=min_width),
+        widths, rows_per_width, num_nodes, num_nodes)
+    return EllTable(widths=widths, idx=tuple(a[None] for a in idx),
+                    row_pos=row_pos[None],
+                    row_id=tuple(a[None] for a in row_id))
 
 
 @dataclass
@@ -590,8 +537,8 @@ def flat_sum_from_graph(row_ptr: np.ndarray, col_idx: np.ndarray,
     section spanning all ``src_rows`` sources (ids global, dummy ==
     ``src_rows``, sub-rows of a row consecutive/ascending) — the
     layout :func:`roc_tpu.ops.aggregate.aggregate_flat_sum` scans.
-    Shared with the attention flat8 build (train/trainer.py
-    ``make_graph_context``): one builder, two consumers."""
+    The same tables serve attention's flat8 layout (``models/tables.py
+    FlatTables``): one builder, two consumers."""
     if src_rows is None:
         src_rows = num_rows
     return sectioned_from_graph(row_ptr, col_idx, num_rows,
@@ -608,9 +555,7 @@ def flat_sum_from_padded_parts(part_row_ptr: np.ndarray,
                                ) -> SectionedEll:
     """Stacked per-part flat-sum tables (``[P, n_chunks, seg_rows, 8]``
     — SPMD-uniform shapes like every other stacked layout); the
-    distributed twin of :func:`flat_sum_from_graph`, shared by the
-    'flat_sum' and 'attn_flat8' branches of
-    ``parallel/distributed.shard_dataset``."""
+    stacked twin of :func:`flat_sum_from_graph`."""
     return sectioned_from_padded_parts(
         part_row_ptr, part_col, real_nodes, part_nodes,
         src_rows=src_rows, section_rows=src_rows, seg_rows=seg_rows)
@@ -657,8 +602,8 @@ SECTIONED_BOUNDS_BY_KIND = {
 def default_section_rows(sect_u16: bool = False) -> int:
     """Default section size for the sectioned layout; uint16
     section-local ids need the dummy id (== section size) to fit in
-    the dtype.  The ONE place for that rule — the single-device,
-    shard_dataset, and shard_dataset_local builders all call it."""
+    the dtype.  The ONE place for that rule — every view's sectioned
+    tables (``models/tables.py build_tables``) read it."""
     return min(SECTION_ROWS_DEFAULT, 65_535) if sect_u16 \
         else SECTION_ROWS_DEFAULT
 
@@ -893,7 +838,7 @@ def sectioned_from_graph(row_ptr: np.ndarray, col_idx: np.ndarray,
         # native two-pass fill (counts -> plan -> fill): 45x the numpy
         # path at Reddit scale and byte-identical tables (tested).
         # counts= lets plan-building callers (sectioned_from_padded_
-        # parts, shard_dataset_local) skip the second CSR walk.
+        # parts, models/tables.py) skip the second CSR walk.
         if counts is None:
             counts = native.sectioned_counts(row_ptr, col_idx, num_rows,
                                              section_rows, n_sec, sub_w)
@@ -963,9 +908,9 @@ def sectioned_plan(counts_max: np.ndarray,
                    seg_rows: int = SECT_SEG_ROWS) -> list:
     """Per-section ``(n_chunks, seg_rows)`` from elementwise-maxed
     per-partition sub-row counts — THE single place the uniform-shape
-    agreement math lives (used by the all-parts builder and the
-    multi-host partition-local path; a divergence between the two
-    would only surface as a chunks_plan error at scale).
+    agreement math lives (used by every view of ``models/tables.py
+    build_tables`` and by :func:`sectioned_from_padded_parts`; a
+    divergence would only surface as a chunks_plan error at scale).
     :func:`fit_chunks` of each section's largest part under the cap
     ``seg_rows``, so every part keeps one shape."""
     return [fit_chunks(c, seg_rows) for c in np.asarray(counts_max)]

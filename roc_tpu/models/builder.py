@@ -21,7 +21,7 @@ requirement, ``scattergather.cc:70-72``).
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -30,6 +30,8 @@ import numpy as np
 
 from ..core.ell import LANE_WIDTH, agg_lane_width
 from ..core.memory import remat_segments
+from ..models.tables import (EllTables, FlatTables, GraphArrays, RingTables,
+                           Tables)
 from ..core.relations import (AGGREGATE_FIRST, LINEAR_FIRST, ORDER_PASSES,
                               TRANSFORM_FIRST)
 from ..obs.scopes import (ALLREDUCE_SCOPE, ATTN_GATE_SCOPE,
@@ -38,10 +40,7 @@ from ..obs.scopes import (ALLREDUCE_SCOPE, ATTN_GATE_SCOPE,
                           RECOMPUTE_SCOPE, op_scope)
 from ..ops import dense, softagg
 from ..parallel import PARTS_AXIS
-from ..ops.aggregate import (aggregate_ell, aggregate_ell_max,
-                             aggregate_ell_sect, aggregate_flat_max,
-                             aggregate_flat_sum, aggregate_segment,
-                             gather_sum_form, gather_sum_slots,
+from ..ops.aggregate import (gather_sum_form, gather_sum_slots,
                              scan_seg_sum, scan_window_rows,
                              seg_sum_updates)
 from ..ops.dense import AC_MODE_NONE, AC_MODE_RELU, AC_MODE_SIGMOID
@@ -102,10 +101,10 @@ TFATTN_PARTITION_REFUSAL = (
 class GraphContext:
     """Per-device view of the (partitioned) graph inside a step function.
 
-    edge_src: int32 [E_local] source ids in *row-coordinate space* — i.e.
-      indices into the feature matrix produced by ``gather_features``,
-      with the dummy zero row at index ``gathered_rows``.
-    edge_dst: int32 [E_local] local destination rows (sorted ascending).
+    tables: one layout's tables (``models/tables.py``): source ids in
+      *row-coordinate space* — indices into the feature matrix
+      produced by ``gather_features``, with the dummy zero row at index
+      ``gathered_rows`` — and the rows they sum into.
     in_degree: int32 [num_rows] real in-degrees of local rows.
     num_rows: static local row count (padded).
     gathered_rows: static row count of the gathered feature matrix
@@ -115,88 +114,23 @@ class GraphContext:
     psum: metric/loss reduction across shards (identity single-device).
     """
 
-    edge_src: jax.Array
-    edge_dst: jax.Array
+    tables: Tables
     in_degree: jax.Array
     num_rows: int
     gathered_rows: int
     gather_features: Callable[[jax.Array], jax.Array] = lambda x: x
     psum: Callable[[Any], Any] = lambda x: x
+    # the resolved layout's name: what the plan lines report and the
+    # lane rule (core/ell.py agg_lane_width) reads; the ops read the
+    # tables themselves
     aggr_impl: str = "segment"
     symmetric: bool = True
-    # Fused-normalization tables (aggr_fuse, see Model.fuse_norm_
-    # aggregate): per-edge weights ``w = d[dst] * d[src]`` with
-    # ``d = inv_sqrt_degree`` baked host-side into the aggregation
-    # tables (core/ell.py ell_weight_tables / SectionedEll.
-    # weight_tables, parallel/ring.py ring_weight_tables).  Shapes
-    # mirror the index tables they weight.  Empty = derive ``d`` from
-    # ``in_degree`` at trace time and pre/post-scale the features
-    # instead (exact same numbers, two extra fused multiplies).
-    ell_w: Tuple[jax.Array, ...] = ()
-    sect_w: Tuple[jax.Array, ...] = ()
-    ring_w: Optional[jax.Array] = None
-    # bdense in-register tile scales: (d_dst [vpad], d_src [src_vpad])
-    # fp32 — applied per [128, F] tile inside the einsum chunk body
-    # (ops/blockdense.py), keeping the integer A-tables (and their u4
-    # packing) intact
-    bd_scale: Tuple[jax.Array, ...] = ()
-    # ELL layout (aggr_impl == "ell"): tuple of [rows_b, width_b] index
-    # arrays + [num_rows] output permutation (core/ell.py)
-    ell_idx: Tuple[jax.Array, ...] = ()
-    ell_row_pos: Optional[jax.Array] = None
-    # forward row map per bucket ([rows_b], padding = num_rows) —
-    # needed only by attention aggregation (EllTable.row_id)
-    ell_row_id: Tuple[jax.Array, ...] = ()
-    # Sectioned layout (aggr_impl == "sectioned"): per-section
-    # [n_chunks, seg_rows, 8] sub-row tables + [n_chunks, seg_rows]
-    # output rows, with static (start, size, win_rows, bands) metadata
-    # (core/ell.py SectionedEll.meta — measured 2.3x over "ell" at
-    # Reddit scale)
-    sect_idx: Tuple[jax.Array, ...] = ()
-    sect_sub_dst: Tuple[jax.Array, ...] = ()
-    sect_meta: Tuple[Tuple[int, ...], ...] = ()
-    # Uniform width-8 flat layout: one [n_chunks, seg_rows, 8]
-    # global-id table + [n_chunks, seg_rows] output rows, whose
-    # compile size is degree-distribution-independent.  Two consumers:
-    # aggr_impl == "attn_flat8" (large-graph GAT, ops/attention.py
-    # gat_aggregate_flat8) and aggr_impl == "flat_sum" (the sum/max
-    # path's uniform-scan consolidation, ops/aggregate.py
-    # aggregate_flat_sum — ONE scan program instead of one per degree
-    # bucket).  flat8_w carries the baked fused-normalization weights
-    # for the flat_sum form (shape mirrors flat8_idx; None = derive d
-    # from in_degree and pre/post-scale in-op).  flat8_win is the
-    # table's static destination-window height for the sum scan
-    # (SectionedEll.win_rows[0]; 0 = the whole carry) and flat8_bands
-    # its tile bands (SectionedEll.bands[0]), set for "flat_sum" only
-    # — attention and MAX never read them.
-    flat8_idx: Optional[jax.Array] = None
-    flat8_dst: Optional[jax.Array] = None
-    flat8_w: Optional[jax.Array] = None
-    flat8_win: int = 0
-    flat8_bands: Tuple[Tuple[int, int], ...] = ()
-    # Block-dense MXU layout (aggr_impl == "bdense"): dense [128,128]
-    # adjacency tiles as uint8 multiplicity tables + tile ids, with
-    # the residual (scattered) edges in the sect_* sectioned tables
-    # (ops/blockdense.py; wins on community graphs whose vertex order
-    # concentrates edges — see plan_blocks.occupancy)
-    bd_a: Optional[jax.Array] = None
-    bd_src: Optional[jax.Array] = None
-    bd_dst: Optional[jax.Array] = None
-    bd_vpad: int = 0
-    # blocks reduced per output-tile update (>1 requires a
-    # pad_plan_groups-padded plan — cuts output RMW traffic group-x)
-    bd_group: int = 1
-    # source tile space when it differs from bd_vpad (distributed:
-    # dst tiles cover local rows, src tiles the gathered coordinates)
-    bd_src_vpad: int = 0
     # halo exchange mode: "gather" = one-shot all_gather of the full
     # feature matrix (the reference's whole-region requirement);
     # "ring" = ppermute rotation overlapping per-shard aggregation
-    # (parallel/ring.py) — O(V/P * F) peak memory instead of O(V * F)
+    # (parallel/ring.py, the tables a RingTables) — O(V/P * F) peak
+    # memory instead of O(V * F)
     halo: str = "gather"
-    # flat per-source-shard ring edge lists: (src, dst), each int32
-    # [S, pair_edges] — this device's slice (parallel/ring.py)
-    ring_idx: Tuple[jax.Array, ...] = ()
     # double-buffered ring schedule (ppermute issued before the local
     # scatter-accumulate, parallel/ring.py ring_aggregate): identical
     # numerics either way; False keeps the strictly sequential hop
@@ -213,19 +147,6 @@ class GraphContext:
     # ops/dense.py linear_chunked).
     head_chunk: int = 0
     axis_name: str = PARTS_AXIS
-    # Typed graph (core/relations.py): one entry per relation pass the
-    # resolved orders run — ``rel_meta`` static ``(pass name, rows
-    # summed into, rows gathered out of, win_rows, rels, bands)``,
-    # ``rels`` None for the table of every relation or the relation
-    # indices a cut layer sums (Model.loss_cut); under 'flat_sum'
-    # ``rel_idx [n_chunks, 8 * seg]`` (slot-major: unpadded at rest,
-    # ops/aggregate.py _scan_window_sum) / ``rel_dst [n_chunks, seg]``
-    # / ``rel_w`` fp32 like ``rel_idx`` (each slot's ``1 / deg_r(v)``),
-    # under 'segment' the forward passes' flat edge lists ``[E']``.
-    rel_idx: Tuple[jax.Array, ...] = ()
-    rel_dst: Tuple[jax.Array, ...] = ()
-    rel_w: Tuple[jax.Array, ...] = ()
-    rel_meta: Tuple[Tuple[Any, ...], ...] = ()
     # What an op that reduces over the vertex axis (``batch_norm``; the
     # softmax aggregation's shift) has to know about the rows:
     # ``real_rows`` the traced int32 count of this partition's real
@@ -264,7 +185,7 @@ class GraphContext:
             valid=self.valid_rows(),
             psum=self.psum if self.partitioned else None)
 
-    def agg_window(self, ops=(), tables=None, edges=None,
+    def agg_window(self, ops=(), edges=None,
                    compute=jnp.float32) -> dict:
         """How far the chunk scan's destination window engaged — the
         run manifest's ``resolved`` carries it (obs/manifest.py):
@@ -293,11 +214,10 @@ class GraphContext:
         where the segmented sum runs, the sub-rows where the serial
         scatter does (``ops/aggregate.py seg_sum_updates``).  Both are
         read at the widest sum op's width (a narrower op may pick a
-        taller tile of the same table).  The
-        distributed trainer, whose tables live outside its context,
-        hands them in as ``tables`` (stacked: the trailing axes are
-        read, every part's slots counted).  And how each of those
-        tables' chunk steps make their partials
+        taller tile of the same table).  The distributed trainer reads
+        its stacked tables through a context that holds them: the
+        trailing axes are read, every part's slots counted.  And how
+        each of those tables' chunk steps make their partials
         (``ops/aggregate.py gather_sum_form``, read off the table's
         rows, the widest sum op's width and the ``compute`` dtype the
         table is held in): ``agg_gather_sum``, one ``[form, slots]`` a
@@ -306,16 +226,12 @@ class GraphContext:
         ``"two_pass"`` where they go through HBM — and the slots a
         pass gathers."""
         carry = self.num_rows + 1
-        wins = [m[2] for m in self.sect_meta if len(m) > 2]
-        # a hand-made sect_meta may stop at the window: no bands known
-        bands = [m[3] if len(m) > 3 else () for m in self.sect_meta
-                 if len(m) > 2]
-        # rows of the table a scan gathers out of: the section and its
-        # zero row, or the gathered matrix and its
-        rows = [m[1] + 1 for m in self.sect_meta if len(m) > 2]
-        if self.flat8_win:
-            wins, bands = [self.flat8_win], [self.flat8_bands]
-            rows = [self.gathered_rows + 1]
+        # the chunk tables the sum scan walks: (idx, win_rows, bands,
+        # rows of the table a scan gathers out of — the section and its
+        # zero row, or the gathered matrix and its)
+        scanned = self.tables.windows(self.gathered_rows)
+        wins = [w for _, w, _, _ in scanned]
+        tables = [t for t, _, _, _ in scanned]
         pads = [[i, op.dim, self._lane_width(op.dim)]
                 for i, op in enumerate(ops)
                 if op.kind == "fused_aggregate"
@@ -325,22 +241,13 @@ class GraphContext:
         pads += [[i, op.dim, self._lane_width(2 * op.dim)]
                  for i, op in enumerate(ops)
                  if op.kind == "soft_aggregate"]
-        if tables is None:
-            tables = ((self.flat8_idx,) if self.flat8_win
-                      else self.sect_idx)
-        if not wins:
-            tables = ()
         slots = sum(int(t.size) for t in tables)
         width = max((p[2] for p in pads), default=LANE_WIDTH)
         # (n_chunks, seg_rows, window, bands, table rows, sub-row
         # width) of every scanned table
         scans = [(*t.shape[-3:-1], scan_window_rows(w, carry), b, r,
-                  t.shape[-1])
-                 for t, w, b, r in zip(tables, wins, bands, rows)]
-        scans += [(*d.shape, scan_window_rows(m[3], m[1] + 1), m[5],
-                   m[2] + 1, 8)
-                  for m, d in zip(self.rel_meta, self.rel_dst)
-                  if self.aggr_impl == "flat_sum"]
+                  t.shape[-1]) for t, w, b, r in scanned]
+        scans += self.tables.pass_scans()
         forms = [gather_sum_form(r, width, compute)
                  for _, _, _, _, r, _ in scans]
         # a fused table's kernel adds its own sums: no segmented sum
@@ -361,9 +268,9 @@ class GraphContext:
                                    for t in tables],
                 "agg_slot_fill": (
                     round(edges / slots, 4) if edges and slots
-                    and self.aggr_impl != "bdense" else None)}
+                    and self.tables.holds_edges else None)}
 
-    def attention_plan(self, ops, ell_idx=None, flat8_idx=None) -> dict:
+    def attention_plan(self, ops) -> dict:
         """What each attention op of ``ops`` (``Model._ops``) runs on —
         the run manifest's ``resolved`` carries it beside
         :meth:`agg_window`: heads, head width, the layout, forward
@@ -381,8 +288,8 @@ class GraphContext:
         then transposes it) and the whole ``[G+1, .]`` cotangents
         those scatter-add into, once a scan step each (features,
         source and destination scores).  Empty for a model without
-        attention.  The distributed trainer, whose tables live outside its context,
-        hands them in (stacked: the shapes' trailing axes are read).
+        attention.  The distributed trainer's context holds the stacked
+        tables: the shapes' trailing axes are read.
 
         A dot-product attention op (``transformer_attention``) adds to
         its entry ``score: "dot"``, the lanes its forward gathers a
@@ -391,9 +298,9 @@ class GraphContext:
         and ``edge_dropout`` (the rate and the mask's rule); its
         ``attention_backward`` rule is ``transposed_two_pass``."""
         from ..ops.attention import resolve_dh_chunk
-        flat8 = self.aggr_impl == "attn_flat8"
-        ell_idx = self.ell_idx if ell_idx is None else ell_idx
-        flat8_idx = self.flat8_idx if flat8_idx is None else flat8_idx
+        flat8 = isinstance(self.tables, FlatTables)
+        ell_idx = (self.tables.idx if isinstance(self.tables, EllTables)
+                   else ())
         transposed = self.symmetric and not flat8
         out, back = [], []
         for i, op in enumerate(ops):
@@ -422,7 +329,7 @@ class GraphContext:
             if flat8:
                 chunk = resolve_dh_chunk(self.num_rows, heads, dh)
                 passes = 2 if chunk is None else 2 + -(-dh // chunk)
-                slots = int(np.prod(flat8_idx.shape[-3:]))
+                slots = int(np.prod(self.tables.idx.shape[-3:]))
             else:
                 passes = 1
                 slots = sum(int(np.prod(a.shape[-2:]))
@@ -447,7 +354,7 @@ class GraphContext:
         return ({"attention": out, "attention_backward": back}
                 if out else {})
 
-    def _gathered_with_zero(self, x: jax.Array) -> jax.Array:
+    def gathered_with_zero(self, x: jax.Array) -> jax.Array:
         """Halo exchange (under its own ``roc.halo`` scope, inside the
         aggregation's) + the appended dummy zero source row that
         padding table entries point at."""
@@ -476,48 +383,7 @@ class GraphContext:
 
     def _sum_fwd(self, x: jax.Array) -> jax.Array:
         """Halo exchange + local CSR sum: ``out = A_p @ gather(x)``."""
-        if self.halo == "ring":
-            from ..parallel.ring import ring_aggregate
-            return ring_aggregate(x, self.ring_idx[0], self.ring_idx[1],
-                                  axis_name=self.axis_name,
-                                  overlap=self.ring_overlap)
-        full = self._gathered_with_zero(x)
-        if self.aggr_impl == "ell":
-            return aggregate_ell(full, self.ell_idx, self.ell_row_pos,
-                                 self.num_rows)
-        if self.aggr_impl == "sectioned":
-            return aggregate_ell_sect(full, self.sect_idx,
-                                      self.sect_sub_dst, self.sect_meta,
-                                      self.num_rows)
-        if self.aggr_impl == "flat_sum":
-            return aggregate_flat_sum(full, self.flat8_idx,
-                                      self.flat8_dst, self.num_rows,
-                                      win_rows=self.flat8_win,
-                                      bands=self.flat8_bands)
-        if self.aggr_impl == "bdense":
-            from ..ops.blockdense import aggregate_block_dense
-            out = None
-            if self.bd_a is not None:
-                out = aggregate_block_dense(
-                    full, self.bd_a, self.bd_src, self.bd_dst,
-                    self.num_rows, self.bd_vpad,
-                    out_dtype=full.dtype,
-                    src_vpad=self.bd_src_vpad,
-                    group=self.bd_group)
-            if self.sect_idx:
-                res = aggregate_ell_sect(
-                    full, self.sect_idx, self.sect_sub_dst,
-                    self.sect_meta, self.num_rows)
-                out = res if out is None else out + res
-            if out is None:  # zero-edge graph
-                out = jnp.zeros((self.num_rows, full.shape[1]),
-                                dtype=full.dtype)
-            return out
-        if self.aggr_impl != "segment":
-            raise ValueError(
-                f"no sum aggregation for aggr_impl={self.aggr_impl!r}")
-        return aggregate_segment(full, self.edge_src, self.edge_dst,
-                                 self.num_rows)
+        return self.tables.sum(self, x)
 
     def aggregate_sum(self, x: jax.Array) -> jax.Array:
         """Sum aggregation with the reference's backward: for a symmetric
@@ -547,65 +413,16 @@ class GraphContext:
     def _fused_sum_fwd(self, x: jax.Array) -> jax.Array:
         """One-pass ``D^-1/2 A D^-1/2 x`` (the GCN sandwich of
         norm -> sum-aggregate -> norm folded into the aggregation,
-        Model.fuse_norm_aggregate): table-driven impls read the baked
-        per-edge weights when present (zero runtime normalization);
-        otherwise ``d = inv_sqrt_degree(in_degree)`` is derived at
-        trace time and the features are scaled once before / the
-        output once after the plain sum — the same numbers as the
-        unfused chain, still inside ONE op so the multiplies fuse
-        into the aggregation's reads/writes."""
+        Model.fuse_norm_aggregate): tables with baked per-edge weights
+        read them (zero runtime normalization); otherwise ``d =
+        inv_sqrt_degree(in_degree)`` is derived at trace time and the
+        features are scaled once before / the output once after the
+        plain sum — the same numbers as the unfused chain, still
+        inside ONE op so the multiplies fuse into the aggregation's
+        reads/writes."""
         from ..ops.norm import inv_sqrt_degree
-        if self.halo == "ring":
-            from ..parallel.ring import ring_aggregate
-            if self.ring_w is not None:
-                return ring_aggregate(
-                    x, self.ring_idx[0], self.ring_idx[1],
-                    axis_name=self.axis_name, weights=self.ring_w,
-                    overlap=self.ring_overlap)
-            d = inv_sqrt_degree(self.in_degree).astype(x.dtype)
-            out = ring_aggregate(x * d[:, None], self.ring_idx[0],
-                                 self.ring_idx[1],
-                                 axis_name=self.axis_name,
-                                 overlap=self.ring_overlap)
-            return out * d[:, None]
-        if self.aggr_impl == "ell" and self.ell_w:
-            full = self._gathered_with_zero(x)
-            return aggregate_ell(full, self.ell_idx, self.ell_row_pos,
-                                 self.num_rows, ell_w=self.ell_w)
-        if self.aggr_impl == "sectioned" and self.sect_w:
-            full = self._gathered_with_zero(x)
-            return aggregate_ell_sect(full, self.sect_idx,
-                                      self.sect_sub_dst, self.sect_meta,
-                                      self.num_rows, sect_w=self.sect_w)
-        if self.aggr_impl == "flat_sum" and self.flat8_w is not None:
-            full = self._gathered_with_zero(x)
-            return aggregate_flat_sum(full, self.flat8_idx,
-                                      self.flat8_dst, self.num_rows,
-                                      flat_w=self.flat8_w,
-                                      win_rows=self.flat8_win,
-                                      bands=self.flat8_bands)
-        if self.aggr_impl == "bdense" and self.bd_scale:
-            from ..ops.blockdense import aggregate_block_dense
-            full = self._gathered_with_zero(x)
-            out = None
-            if self.bd_a is not None:
-                out = aggregate_block_dense(
-                    full, self.bd_a, self.bd_src, self.bd_dst,
-                    self.num_rows, self.bd_vpad,
-                    out_dtype=full.dtype,
-                    src_vpad=self.bd_src_vpad,
-                    group=self.bd_group,
-                    scale_dst=self.bd_scale[0],
-                    scale_src=self.bd_scale[1])
-            if self.sect_idx:
-                res = aggregate_ell_sect(
-                    full, self.sect_idx, self.sect_sub_dst,
-                    self.sect_meta, self.num_rows, sect_w=self.sect_w)
-                out = res if out is None else out + res
-            if out is None:  # zero-edge graph
-                out = jnp.zeros((self.num_rows, full.shape[1]),
-                                dtype=full.dtype)
-            return out
+        if self.tables.weighted:
+            return self.tables.sum(self, x, fused=True)
         # no baked weights (or the edge-list reference): scale features
         # once per fused op, sum, scale the output
         d = inv_sqrt_degree(self.in_degree).astype(x.dtype)
@@ -719,26 +536,6 @@ class GraphContext:
         agg.defvjp(forward, bwd)
         return agg(x)
 
-    def _rel_pass(self, x: jax.Array, name: str, rels) -> jax.Array:
-        """One relation pass (core/relations.py) over ``x``, whose
-        rows are what the pass gathers out of: the weighted sum into
-        the pass's own row space, over the table of the relations
-        ``rels`` (None: all)."""
-        k = next(i for i, m in enumerate(self.rel_meta)
-                 if m[0] == name and m[4] == rels)
-        _, out_rows, _, win, _, bands = self.rel_meta[k]
-        if self.aggr_impl == "segment":
-            g = x[self.rel_idx[k]] * self.rel_w[k][:, None]
-            return jax.ops.segment_sum(
-                g, self.rel_dst[k], num_segments=out_rows,
-                indices_are_sorted=True).astype(x.dtype)
-        zero = jnp.zeros((1, x.shape[1]), dtype=x.dtype)
-        return aggregate_flat_sum(
-            jnp.concatenate([x, zero], axis=0), self.rel_idx[k],
-            self.rel_dst[k], out_rows, flat_w=self.rel_w[k],
-            win_rows=win, bands=bands, weights_fp32=True,
-            slot_major=True)
-
     def rel_aggregate(self, x: jax.Array, order: str,
                       rels=None) -> jax.Array:
         """The relation aggregation ``sum_r mean_r(.)`` of a typed
@@ -753,18 +550,19 @@ class GraphContext:
         forward and backward over the tables of that subset, whose
         stacks hold those relations' blocks alone; None sums all."""
         fwd_pass, bwd_pass = ORDER_PASSES[order]
-        if self.aggr_impl == "segment":
-            return self._rel_pass(x, fwd_pass, rels)
+        t = self.tables
+        if t.edge_list:
+            return t.apply(x, fwd_pass, rels)
 
         @jax.custom_vjp
         def agg(x):
-            return self._rel_pass(x, fwd_pass, rels)
+            return t.apply(x, fwd_pass, rels)
 
         def fwd(x):
             return agg(x), None
 
         def bwd(_, g):
-            return (self._rel_pass(g, bwd_pass, rels),)
+            return (t.apply(g, bwd_pass, rels),)
 
         agg.defvjp(fwd, bwd)
         return self._lane_padded(agg, x)
@@ -787,8 +585,7 @@ class GraphContext:
         an untyped model."""
         if typed is None:
             return {}
-        slots = {(m[0], m[4]): int(np.prod(t.shape)) for m, t
-                 in zip(self.rel_meta, self.rel_idx)}
+        slots = self.tables.slots()
         layers = []
         for i, op in enumerate(ops):
             if op.kind != "rel_aggregate":
@@ -809,7 +606,7 @@ class GraphContext:
                                  else typed.dst_rows),
                 "slots_fwd": f, "slots_bwd": b,
                 "agg_slot_fill": (round(typed.num_edges / f, 4)
-                                  if f and self.aggr_impl != "segment"
+                                  if f and not self.tables.edge_list
                                   else None),
                 "train_relations": (len(rels) if rels
                                     else op.attrs["n_rel"]),
@@ -844,14 +641,14 @@ class GraphContext:
         statistics ride the same halo as the forward's features);
         ``symmetric=False`` and the flat layout are exact autodiff
         through the forward."""
-        if self.halo == "ring":
+        t = self.tables
+        if isinstance(t, RingTables):
             raise NotImplementedError(
                 "attention is not supported with halo='ring' (the ring "
                 "accumulator is additive; the edge softmax needs the "
                 "whole neighborhood); use halo='gather'")
-        flat8 = self.aggr_impl == "attn_flat8" and \
-            self.flat8_idx is not None
-        if not flat8 and (self.aggr_impl != "ell" or not self.ell_idx):
+        flat8 = isinstance(t, FlatTables)
+        if not flat8 and not (isinstance(t, EllTables) and t.idx):
             raise NotImplementedError(
                 f"attention needs the ELL tables (aggr_impl='ell') or "
                 f"the flat8 layout (aggr_impl='attn_flat8'), got "
@@ -864,8 +661,7 @@ class GraphContext:
         if flat8 or not self.symmetric:
             return self._gat_fwd(x, a_src, a_dst, neg_slope, flat8)
         from ..ops.attention import gat_ell_backward, gat_ell_forward
-        tables = (self.ell_idx, self.ell_row_id, self.ell_row_pos,
-                  self.num_rows)
+        tables = (t.idx, t.row_id, t.row_pos, self.num_rows)
 
         @jax.custom_vjp
         def attn(x, a_src, a_dst):
@@ -878,7 +674,7 @@ class GraphContext:
             return out, (x, a_src, a_dst, out, c, stats)
 
         def bwd(res, g):
-            return gat_ell_backward(*res, g, self._gathered_with_zero,
+            return gat_ell_backward(*res, g, self.gathered_with_zero,
                                     *tables, neg_slope=neg_slope)
 
         attn.defvjp(fwd, bwd)
@@ -890,7 +686,7 @@ class GraphContext:
         score: ``(full [G+1, K*dh], s_full [G+1, K], d_local
         [num_rows+1, K])``, a dummy slot last in each."""
         K, dh = a_src.shape
-        full = self._gathered_with_zero(x)
+        full = self.gathered_with_zero(x)
         with jax.named_scope(ATTN_SCORES_SCOPE):
             # the K scores a vertex are kept in fp32 whatever the
             # compute dtype: they feed exp(), where a bf16 rounding of
@@ -915,18 +711,18 @@ class GraphContext:
         from ..ops.attention import (gat_aggregate_ell,
                                      gat_aggregate_flat8,
                                      resolve_dh_chunk)
+        t = self.tables
         full, s_full, d_local = self._gat_scores(x, a_src, a_dst)
         if flat8:
             K, dh = a_src.shape
             return gat_aggregate_flat8(full, s_full, d_local,
-                                       self.flat8_idx, self.flat8_dst,
-                                       self.num_rows,
+                                       t.idx, t.dst, self.num_rows,
                                        neg_slope=neg_slope,
                                        dh_chunk=resolve_dh_chunk(
                                            self.num_rows, K, dh))
-        return gat_aggregate_ell(full, s_full, d_local, self.ell_idx,
-                                 self.ell_row_id, self.ell_row_pos,
-                                 self.num_rows, neg_slope=neg_slope)
+        return gat_aggregate_ell(full, s_full, d_local, t.idx,
+                                 t.row_id, t.row_pos, self.num_rows,
+                                 neg_slope=neg_slope)
 
     def transformer_attention(self, q: jax.Array, kv: jax.Array,
                               r: jax.Array, w_beta: jax.Array,
@@ -947,9 +743,10 @@ class GraphContext:
         rule (``dot_ell_backward``); the gate's is autodiff.  Needs the
         ELL tables of a symmetric graph on one partition: anything
         else is refused by name."""
-        if self.aggr_impl == "attn_flat8":
+        t = self.tables
+        if isinstance(t, FlatTables):
             raise NotImplementedError(TFATTN_FLAT8_REFUSAL)
-        if self.aggr_impl != "ell" or not self.ell_idx:
+        if not (isinstance(t, EllTables) and t.idx):
             raise NotImplementedError(
                 f"the dot-product attention needs the ELL tables "
                 f"(aggr_impl='ell'), got {self.aggr_impl!r}")
@@ -964,14 +761,13 @@ class GraphContext:
         if seed is not None and rate > 0:
             def keep(dst, src):
                 return edge_keep_scale(dst, src, heads, seed, rate)
-        tables = (self.ell_idx, self.ell_row_id, self.ell_row_pos,
-                  self.num_rows)
+        tables = (t.idx, t.row_id, t.row_pos, self.num_rows)
 
         def forward(q, kv, residuals):
             # both passes of the backward recompute the scores from the
             # values the forward read: pin them (softagg.as_stored)
             q, kv = softagg.as_stored(q), softagg.as_stored(kv)
-            got = dot_ell_forward(self._gathered_with_zero(kv), q, d,
+            got = dot_ell_forward(self.gathered_with_zero(kv), q, d,
                                   *tables, keep=keep,
                                   residuals=residuals)
             return got, q, kv
@@ -985,7 +781,7 @@ class GraphContext:
             return m, (q, kv, m, stats)
 
         def bwd(res, g):
-            return dot_ell_backward(*res, g, d, self._gathered_with_zero,
+            return dot_ell_backward(*res, g, d, self.gathered_with_zero,
                                     *tables, keep=keep)
 
         attend.defvjp(fwd, bwd)
@@ -1004,84 +800,31 @@ class GraphContext:
     def _max_fwd(self, x: jax.Array) -> jax.Array:
         """Neighbor max; rows with no neighbors yield 0.  Dummy/padding
         sources are masked out (their zero rows must not win the max)."""
-        if self.halo == "ring":
-            raise NotImplementedError(
-                "AGGR_MAX is not supported with halo='ring' (the ring "
-                "accumulator is additive); use halo='gather'")
-        full = self._gathered_with_zero(x)
-        dummy = full.shape[0] - 1
-        neg = jnp.asarray(-jnp.inf, dtype=full.dtype)
-        if self.aggr_impl == "flat_sum":
-            # the uniform-scan MAX twin (ops/aggregate.py): one scan
-            # program, scatter-max combine — the large-graph MAX path
-            # the resolve pass routes to past FLAT_SUM_MIN_EDGES
-            out = aggregate_flat_max(full, self.flat8_idx,
-                                     self.flat8_dst, self.num_rows)
-        elif self.aggr_impl == "ell":
-            # aggregate_ell_max row-segments large buckets under the
-            # same 64 MiB budget as the sum path.
-            out = aggregate_ell_max(full, self.ell_idx,
-                                    self.ell_row_pos, self.num_rows)
-        else:
-            if self.aggr_impl in ("sectioned", "bdense"):
-                # falling through to the segment path would materialize
-                # the full [E, F] per-edge matrix — an OOM on exactly
-                # the large graphs those layouts target
-                raise NotImplementedError(
-                    f"AGGR_MAX has no {self.aggr_impl!r} implementation; "
-                    "use aggr_impl='ell' (big graphs; sectioned carries "
-                    "no ELL tables and its additive carry can't max) or "
-                    "'segment' — the segment path materializes the full "
-                    "[E, F] per-edge matrix")
-            g = full[self.edge_src]
-            g = jnp.where((self.edge_src != dummy)[:, None], g, neg)
-            out = jax.ops.segment_max(g, self.edge_dst,
-                                      num_segments=self.num_rows)
+        if isinstance(self.tables, RingTables):
+            return self.tables.max(None, self.num_rows, self.aggr_impl)
+        full = self.gathered_with_zero(x)
+        out = self.tables.max(full, self.num_rows, self.aggr_impl)
         return jnp.where(jnp.isfinite(out), out, 0.0).astype(full.dtype)
 
 
+# the context's traced arrays: one GraphArrays (models/tables.py), which
+# keeps the parameter order programs have always had; every other
+# field is static aux data
+_GRAPH_FIELDS = ("tables", "in_degree", "real_rows")
+_STATIC_FIELDS = tuple(f.name for f in fields(GraphContext)
+                       if f.name not in _GRAPH_FIELDS)
+
+
 def _gctx_flatten(g: GraphContext):
-    children = (g.edge_src, g.edge_dst, g.in_degree, g.ell_idx,
-                g.ell_row_pos, g.ring_idx, g.sect_idx, g.sect_sub_dst,
-                g.ell_row_id, g.flat8_idx, g.flat8_dst, g.flat8_w,
-                g.bd_a, g.bd_src, g.bd_dst, g.ell_w, g.sect_w,
-                g.ring_w, g.bd_scale, g.rel_idx, g.rel_dst, g.rel_w,
-                g.real_rows)
-    aux = (g.num_rows, g.gathered_rows, g.gather_features, g.psum,
-           g.aggr_impl, g.symmetric, g.halo, g.axis_name,
-           g.sect_meta, g.bd_vpad, g.bd_src_vpad, g.bd_group,
-           g.ring_overlap, g.head_chunk, g.flat8_win, g.flat8_bands,
-           g.rel_meta, g.total_rows)
-    return children, aux
+    return ((GraphArrays(g.tables, g.in_degree, g.real_rows),),
+            tuple(getattr(g, n) for n in _STATIC_FIELDS))
 
 
 def _gctx_unflatten(aux, children):
-    (num_rows, gathered_rows, gather_features, psum, aggr_impl,
-     symmetric, halo, axis_name, sect_meta, bd_vpad, bd_src_vpad,
-     bd_group, ring_overlap, head_chunk, flat8_win, flat8_bands,
-     rel_meta, total_rows) = aux
-    (edge_src, edge_dst, in_degree, ell_idx, ell_row_pos, ring_idx,
-     sect_idx, sect_sub_dst, ell_row_id, flat8_idx,
-     flat8_dst, flat8_w, bd_a, bd_src, bd_dst, ell_w, sect_w, ring_w,
-     bd_scale, rel_idx, rel_dst, rel_w, real_rows) = children
-    return GraphContext(
-        edge_src=edge_src, edge_dst=edge_dst, in_degree=in_degree,
-        num_rows=num_rows, gathered_rows=gathered_rows,
-        gather_features=gather_features, psum=psum,
-        aggr_impl=aggr_impl, symmetric=symmetric,
-        ell_idx=ell_idx, ell_row_pos=ell_row_pos, halo=halo,
-        ring_idx=ring_idx, axis_name=axis_name, sect_idx=sect_idx,
-        sect_sub_dst=sect_sub_dst, sect_meta=sect_meta,
-        ell_row_id=ell_row_id, flat8_idx=flat8_idx,
-        flat8_dst=flat8_dst, flat8_w=flat8_w, flat8_win=flat8_win,
-        flat8_bands=flat8_bands,
-        bd_a=bd_a, bd_src=bd_src, bd_dst=bd_dst, bd_vpad=bd_vpad,
-        bd_src_vpad=bd_src_vpad,
-        bd_group=bd_group, ring_overlap=ring_overlap,
-        head_chunk=head_chunk,
-        ell_w=ell_w, sect_w=sect_w, ring_w=ring_w, bd_scale=bd_scale,
-        rel_idx=rel_idx, rel_dst=rel_dst, rel_w=rel_w,
-        rel_meta=rel_meta, real_rows=real_rows, total_rows=total_rows)
+    (graph,) = children
+    return GraphContext(tables=graph.tables, in_degree=graph.in_degree,
+                        real_rows=graph.real_rows,
+                        **dict(zip(_STATIC_FIELDS, aux)))
 
 
 # GraphContext is a pytree so the graph tables travel as jit ARGUMENTS.

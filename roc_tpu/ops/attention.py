@@ -346,7 +346,7 @@ def gat_ell_backward(x: jax.Array, a_src: jax.Array, a_dst: jax.Array,
 
     x [num_rows, F], a_src / a_dst [K, dh]: the op's inputs; out, c,
     stats: :func:`gat_ell_forward`'s; g: the cotangent of ``out``;
-    ``halo``: GraphContext._gathered_with_zero, through which ``g``
+    ``halo``: GraphContext.gathered_with_zero, through which ``g``
     and the packed per-vertex scalars reach the rows of other
     partitions as the forward's features did.  Returns the cotangents
     of ``(x, a_src, a_dst)``.  False on a directed graph: the rows
@@ -614,7 +614,7 @@ def dot_ell_backward(q: jax.Array, kv: jax.Array, m: jax.Array,
     (``dq``) at each row over its own slots, gathering ``[k | v]``;
     pass B (``dk``, ``dv``) at each row over the rows it feeds,
     gathering ``[q | G]`` and their (row max, denominator, ``rho``)
-    through ``halo`` (``GraphContext._gathered_with_zero``).  With
+    through ``halo`` (``GraphContext.gathered_with_zero``).  With
     ``rho_i = G_i . m_i`` per head — ``sum_j t_ij G_i . v_j``, no pass
     needed — and ``ds_ij = alpha_ij (D_ij / (1 - p) G_i . v_j -
     rho_i)``:

@@ -431,11 +431,10 @@ def pack_a_u4(plan: BlockPlan) -> Optional[BlockPlan]:
     fall back to uint8 rather than saturate silently).
 
     The kernel detects packing from the trailing axis
-    (``BLOCK // 2``) and unpacks in-register per chunk.  Applied on
-    the single-device path (make_graph_context) and by
-    the stacked distributed/multihost builders — all parts pack or
-    none (one uniform SPMD trailing width; multihost agrees the
-    global max multiplicity via one extra O(P) collective)."""
+    (``BLOCK // 2``) and unpacks in-register per chunk.  Applied by
+    every view of ``models/tables.py build_tables`` — all parts pack or
+    none (one uniform SPMD trailing width, agreed from the max
+    multiplicity over every part)."""
     if plan.n_blocks and plan.a_blocks.max() > U4_MAX:
         return None
     # an EMPTY plan packs too (to [0, 128, 64]): the stacked

@@ -49,7 +49,7 @@ def inv_sqrt_degree_np(in_degree: np.ndarray) -> np.ndarray:
     """Host-side :func:`inv_sqrt_degree` (fp32) — the d vector the
     fused-aggregation weight-table builders bake into the tables
     (core/ell.py ell_weight_tables / SectionedEll.weight_tables,
-    parallel/ring.py ring_weight_tables).  Must stay numerically
+    parallel/ring.py ring_part_weights).  Must stay numerically
     identical to the traced form: same max(deg, 1) clamp, same
     zero-degree mapping."""
     deg = np.asarray(in_degree, dtype=np.float32)

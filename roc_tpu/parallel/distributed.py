@@ -40,9 +40,11 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.ell import ell_from_padded_parts
 from ..core.graph import Dataset, MASK_NONE
 from ..core.partition import PartitionedGraph, partition_graph
+from ..models.tables import (BlockDenseTables, EdgeList, GraphArrays,
+                           RingTables, Tables, View, build_tables,
+                           tables_type, upload)
 from ..models.builder import GraphContext, Model
 from ..obs.events import emit, flush_spans, span
 from ..obs.scopes import ALLREDUCE_SCOPE, LOSS_SCOPE, OPT_SCOPE
@@ -51,7 +53,7 @@ from ..train.optimizer import AdamConfig, adam_init, adam_update
 from ..train.trainer import (TrainConfig, agg_order_plan,
                              batch_norm_plan, cast_compute,
                              compute_dtype_of, resolve_symmetric,
-                             split_state, upload)
+                             split_state)
 
 
 # THE names of the mesh axes — defined in parallel/__init__ (the
@@ -208,99 +210,79 @@ def unpad_nodes(arr: np.ndarray, pg: PartitionedGraph) -> np.ndarray:
 
 @dataclass
 class ShardedData:
-    """Device-resident sharded training data (leading axis = parts)."""
-    feats: jax.Array       # [P, part_nodes, F]   P('parts')
-    labels: jax.Array      # [P, part_nodes]      P('parts')
-    mask: jax.Array        # [P, part_nodes]      P('parts')
-    edge_src: jax.Array    # [P, part_edges]      P('parts'), padded coords
-    edge_dst: jax.Array    # [P, part_edges]      P('parts'), local rows
-    in_degree: jax.Array   # [P, part_nodes]      P('parts')
-    ell_idx: Tuple[jax.Array, ...] = ()   # per bucket [P, rows_b, width_b]
-    ell_row_pos: jax.Array = None         # [P, part_nodes]
-    ell_row_id: Tuple[jax.Array, ...] = ()  # per bucket [P, rows_b]
-    ring_idx: Tuple[jax.Array, ...] = ()  # (src, dst) [P, S, pair_edges]
-    # sectioned layout (aggr_impl == "sectioned"): per section
-    # [P, n_chunks_s, seg_rows, 8] / [P, n_chunks_s, seg_rows], plus
-    # the static (start, size, win_rows, bands) metadata
-    # (SectionedEll.meta).
-    # For aggr_impl == "attn_flat8" / "flat_sum" the same slots carry
-    # the SINGLE-section uniform width-8 tables (ids in gathered
-    # coordinates, dummy == P*part_nodes; the step body routes them to
-    # GraphContext flat8_idx/flat8_dst) with no sect_meta; flat_sum's
-    # static window height rides flat_win (-> GraphContext.flat8_win),
-    # its tile bands flat_bands (-> flat8_bands)
-    sect_idx: Tuple[jax.Array, ...] = ()
-    sect_sub_dst: Tuple[jax.Array, ...] = ()
-    sect_meta: Tuple[Tuple[int, ...], ...] = ()
-    flat_win: int = 0
-    flat_bands: Tuple[Tuple[int, int], ...] = ()
-    # block-dense MXU layout (aggr_impl == "bdense"): per-partition
-    # dense [128,128] tiles over (local dst rows x gathered source
-    # coords), padded to a uniform block count; () or
-    # (a [P,nblk,128,128] u8, src_blk [P,nblk], dst_blk [P,nblk]).
-    # The residual scattered edges ride the sect_* tables above.
-    bd_tabs: Tuple[jax.Array, ...] = ()
-    bd_vpad: int = 0        # dst tile space (covers part_nodes)
-    bd_src_vpad: int = 0    # src tile space (covers gathered rows)
-    bd_occupancy: Tuple[dict, ...] = ()   # per-part plan stats
-    # the pad_plan_groups alignment the tables were built for: the
-    # kernel's ``group`` MUST match it (the trainer validates injected
-    # data — a mismatched group would reduce across dst-tile
-    # boundaries and mis-aggregate with no shape error)
-    bd_group: int = 1
+    """Device-resident sharded training data: every array carries a
+    leading parts axis, sharded ``P('parts')``."""
+    feats: jax.Array       # [P, part_nodes, F]
+    labels: jax.Array      # [P, part_nodes]
+    mask: jax.Array        # [P, part_nodes]
+    # one layout's tables (models/tables.py), ids in gathered
+    # coordinates (dummy == P * part_nodes); a partition's own is
+    # ``tree_map(lambda a: a[0], tables)`` inside the steps
+    tables: Tables
+    in_degree: jax.Array   # [P, part_nodes]
+    # [P] int32: each partition's count of real rows (they come first;
+    # the rest is padding), for the ops that reduce over the vertex
+    # axis — attached by the trainer for a model that has one
+    # (DistributedTrainer._with_real_rows); None for every other model,
+    # whose programs take no such argument
+    real_rows: Optional[jax.Array] = None
+    # per-part block-dense plan stats (core/costmodel.py phi, the run
+    # manifest); empty for the other layouts
+    bd_occupancy: Tuple[dict, ...] = ()
     # padded slots / real edges of the ring tables (halo='ring' only);
     # surfaced so trainer setup can echo the SPMD-uniformity cost
     ring_padding_ratio: Optional[float] = None
-    # fused-normalization weight tables (aggr_fuse, shapes mirror the
-    # index tables they weight): per-bucket ell weights, per-section
-    # sectioned weights, () or ([P, S, pair_edges],) ring weights,
-    # () or (d_dst [P, vpad], d_src [P, src_vpad]) bdense tile scales.
-    # Empty = the step derives d from in_degree and scales in-op.
-    ell_w: Tuple[jax.Array, ...] = ()
-    sect_w: Tuple[jax.Array, ...] = ()
-    ring_w: Tuple[jax.Array, ...] = ()
-    bd_scale: Tuple[jax.Array, ...] = ()
-    # () or ([P] int32,): each partition's count of real rows (they
-    # come first; the rest is padding), for the ops that reduce over
-    # the vertex axis — attached by the trainer for a model that has
-    # one (DistributedTrainer._with_real_rows); it rides the steps'
-    # ``fuse_tabs`` slot, so every other model's programs are
-    # unchanged
-    real_rows: Tuple[jax.Array, ...] = ()
+
+    @property
+    def graph(self) -> GraphArrays:
+        """The steps' one graph argument."""
+        return GraphArrays(self.tables, self.in_degree, self.real_rows)
 
 
-def _sectioned_tables(ptrs: np.ndarray, cols: np.ndarray,
-                      pg: PartitionedGraph, src_rows: int,
-                      section_rows: Optional[int], sect_sub_w: int,
-                      sect_u16: bool, put,
-                      fuse_d: Optional[Tuple[np.ndarray,
-                                             np.ndarray]] = None):
-    """Build + upload the stacked per-part sectioned tables — shared
-    by the 'sectioned' branch (whole CSR) and the 'bdense' branch
-    (residual CSR), so tuning knobs apply to both in one place.
-    ``fuse_d`` = (d_dst [P, part_nodes], d_src [gathered_rows]) also
-    bakes + uploads the fused-normalization weight tables.
-    Returns (sect_idx, sect_sub_dst, sect_meta, sect_w)."""
-    from ..core.ell import (default_section_rows,
-                            sectioned_from_padded_parts)
-    if section_rows is None:
-        section_rows = default_section_rows(sect_u16)
-    with span("setup.tables", table="sectioned") as s:
-        sect = sectioned_from_padded_parts(
-            ptrs, cols, pg.real_nodes, pg.part_nodes, src_rows=src_rows,
-            section_rows=section_rows, sub_w=sect_sub_w)
-        if sect_u16:
-            sect = sect.with_idx_dtype(np.uint16)
-        s["sub_rows"] = sum(a.size for a in sect.sub_dst)
-    sect_w = ()
-    if fuse_d is not None:
-        with span("setup.tables", table="sect_w"):
-            sect_w = sect.weight_tables(fuse_d[0], fuse_d[1])
-        sect_w = tuple(put(w) for w in sect_w)
-    return (tuple(put(a) for a in sect.idx),
-            tuple(put(a) for a in sect.sub_dst),
-            sect.meta,
-            sect_w)
+def parts_view(pg, parts, global_cols, put, agree,
+               remap: bool = True) -> View:
+    """The :class:`models.tables.View` of partitions ``parts`` of ``pg``
+    (all of them single-controller, one process's multi-host): a
+    part's padded rows, sources in gathered coordinates (dummy ``P *
+    part_nodes``).  ``global_cols(p)``: part ``p``'s padded column
+    array in global ids; ``put``: per-part arrays to one ``[P, ...]``
+    device array; ``agree``: the elementwise max over every part.
+    ``remap`` False (the ring, whose pairs read global ids) skips the
+    O(E) remap of the columns to gathered coordinates."""
+    pn = pg.part_nodes
+    cols = {}
+    if remap:
+        with span("setup.tables", table="edge_list"):
+            cols = {p: remap_col_to_padded(pg, global_cols(p))
+                    for p in parts}
+
+    def edges(p):
+        return cols[p], np.repeat(np.arange(pn, dtype=np.int32),
+                                  np.diff(pg.part_row_ptr[p]))
+
+    return View(rows=pn, src_rows=pg.num_parts * pn,
+                row_ptr=pg.part_row_ptr, real_rows=pg.real_nodes,
+                in_degree=pg.part_in_degree, num_edges=pg.num_edges,
+                cols=cols.__getitem__, edges=edges, put=put,
+                parts=tuple(parts), agree=agree, stacked=True, plan=pg,
+                global_cols=global_cols)
+
+
+def stacked_view(pg, put, remap: bool = True) -> View:
+    """:func:`parts_view` of every partition of ``pg`` in one process:
+    a part's arrays stacked on a leading parts axis and handed to
+    ``put`` under a ``setup.upload`` span (``np.asarray``: the host
+    tables), the parts agreed by a max over the stack."""
+    parts = range(pg.num_parts)
+
+    def stacked(per, what="tables"):
+        return upload(np.stack([per[p] for p in parts]), what, put=put)
+
+    def agree(per):
+        return np.max(np.stack([per[p] for p in parts]), axis=0)
+
+    return parts_view(pg, parts, lambda p: pg.part_col_idx[p], stacked,
+                      agree, remap=remap)
 
 
 def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
@@ -314,259 +296,42 @@ def shard_dataset(dataset: Dataset, pg: PartitionedGraph,
                   bdense_group: int = 1,
                   aggr_fuse: bool = False
                   ) -> ShardedData:
-    """Build + upload the stacked per-part arrays.  ``put`` overrides
-    the upload (default: replicated-process ``device_put`` with the
-    parts sharding); parallel/multihost.py passes a local-shards-only
-    uploader for multi-host runs.  ``sect_sub_w``/``sect_u16`` tune the
-    sectioned layout exactly like the single-device path
-    (train/trainer.py build_graph_context) — user-selected config is
-    never silently dropped.
+    """Build + upload the stacked per-part arrays: the tables through
+    ``models/tables.py build_tables`` over every partition of ``pg``
+    (``halo='ring'``: the ring's, which replace the layout's).
+    ``put`` overrides the upload of a stacked array (default:
+    ``device_put`` with the parts sharding).  ``sect_sub_w`` /
+    ``sect_u16`` / ``bdense_*`` tune the layouts exactly like the
+    single-device path (train/trainer.py make_graph_context).
 
     ``aggr_fuse=True`` bakes the symmetric ``D^-1/2`` scales into the
     tables (fused-aggregation weight tables / bdense tile scales) for
     models rewritten by ``Model.fuse_norm_aggregate``; without them
-    the fused step still runs correctly via in-op scaling."""
+    the fused step still runs correctly via in-op scaling.
+    ``section_rows`` (None: ``core/ell.py default_section_rows``)
+    sizes the sectioned tables' source sections."""
     sh = NamedSharding(mesh, P(PARTS_AXIS))
     if put is None:
         put = lambda x: jax.device_put(x, sh)
-    hand_over = put
-
-    def put(x, what="tables"):
-        # every hand-over of this build under a ``setup.upload`` span
-        return upload(x, what, put=hand_over)
-
-    ell_idx = ()
-    ell_row_pos = put(np.zeros((pg.num_parts, 1), dtype=np.int32))
-    ell_row_id = ()
-    ring_idx = ()
-    sect_idx = ()
-    sect_sub_dst = ()
-    sect_meta = ()
-    flat_win = 0
-    flat_bands = ()
-    bd_tabs = ()
-    bd_vpad = 0
-    bd_src_vpad = 0
-    bd_occupancy = ()
-    ring_padding_ratio = None
-    ell_w = ()
-    sect_w = ()
-    ring_w = ()
-    bd_scale = ()
-    fuse_d = None
-    if aggr_fuse:
-        # d in both coordinate systems the tables index with: local
-        # padded rows per part (padding rows have degree 0 -> 0) and
-        # the flattened gathered layout
-        from ..ops.norm import inv_sqrt_degree_np
-        d_parts = inv_sqrt_degree_np(pg.part_in_degree)
-        fuse_d = (d_parts, d_parts.reshape(-1))
-    if halo == "ring":
-        # ring tables fully describe the aggregation — skip the O(E)
-        # per-edge array construction entirely and upload stubs
-        from .ring import build_ring_tables, ring_weight_tables
-        with span("setup.tables", table="ring"):
-            rt = build_ring_tables(pg)
-        ring_idx = (put(rt.src), put(rt.dst))
-        if aggr_fuse:
-            from ..ops.norm import inv_sqrt_degree_np as _inv
-            with span("setup.tables", table="ring_w"):
-                ring_w = ring_weight_tables(
-                    pg, rt, _inv(dataset.graph.in_degree))
-            ring_w = (put(ring_w),)
-        ring_padding_ratio = rt.padding_ratio
-        col_padded = np.zeros((pg.num_parts, 1), dtype=np.int32)
-        edge_dst = np.zeros((pg.num_parts, 1), dtype=np.int32)
-    else:
-        with span("setup.tables", table="edge_list"):
-            col_padded = remap_to_padded(pg)
-            if aggr_impl != "segment":
-                # table-driven paths never read the flat edge arrays —
-                # upload stubs instead of two [P, E_p] tensors
-                edge_dst = np.zeros((pg.num_parts, 1), dtype=np.int32)
-            else:
-                edge_dst = np.stack([
-                    np.repeat(np.arange(pg.part_nodes, dtype=np.int32),
-                              np.diff(pg.part_row_ptr[p]))
-                    for p in range(pg.num_parts)])
-        if aggr_impl == "ell":
-            with span("setup.tables", table="ell") as s:
-                table = ell_from_padded_parts(
-                    pg.part_row_ptr, col_padded, pg.real_nodes,
-                    pg.part_nodes, dummy=pg.num_parts * pg.part_nodes)
-                s["slots"] = sum(a.size for a in table.idx)
-            ell_idx = tuple(put(a) for a in table.idx)
-            ell_row_pos = put(table.row_pos)
-            ell_row_id = tuple(put(a) for a in table.row_id)
-            if aggr_fuse:
-                from ..core.ell import ell_weight_tables
-                with span("setup.tables", table="ell_w"):
-                    ell_w = ell_weight_tables(table, fuse_d[0], fuse_d[1])
-                ell_w = tuple(put(w) for w in ell_w)
-        elif aggr_impl == "sectioned":
-            sect_idx, sect_sub_dst, sect_meta, sect_w = \
-                _sectioned_tables(
-                    pg.part_row_ptr, col_padded, pg,
-                    src_rows=pg.num_parts * pg.part_nodes,
-                    section_rows=section_rows, sect_sub_w=sect_sub_w,
-                    sect_u16=sect_u16, put=put, fuse_d=fuse_d)
-        elif aggr_impl == "bdense":
-            # per-partition block-dense plans over the RECTANGULAR
-            # tile space (local dst rows x gathered source coords —
-            # ops/blockdense.py plan_blocks num_cols).  Stacked to a
-            # uniform block count: short partitions pad with zero-A
-            # tiles scattered into the dummy output tile, so every
-            # device runs the same program (SPMD uniformity, exactly
-            # the sectioned tables' padding-chunk scheme).
-            from ..core.ell import clean_part_ptr
-            from ..ops.blockdense import (BLOCK, U4_MAX, pack_a_u4,
-                                          plan_blocks)
-            src_rows = pg.num_parts * pg.part_nodes
-            ptrs = [clean_part_ptr(pg.part_row_ptr[p],
-                                   pg.real_nodes[p], pg.part_nodes)
-                    for p in range(pg.num_parts)]
-
-            def _mk(budget):
-                # group>1 plans arrive per-part group-aligned, so
-                # the stacked tail padding below extends in WHOLE
-                # dummy-dst groups (nb and nblk_max multiples)
-                return [plan_blocks(
-                    ptrs[p], col_padded[p][:int(ptrs[p][-1])],
-                    pg.part_nodes, min_fill=bdense_min_fill,
-                    a_budget_bytes=budget,
-                    num_cols=src_rows, group=bdense_group)
-                    for p in range(pg.num_parts)]
-
-            # same 2x-budget-then-pack policy as plan_blocks_packed,
-            # decided ACROSS parts: the stacked table needs one
-            # uniform trailing width, so pack all parts or none
-            # (pack_a_u4 packs empty parts too).  The unpackable AND
-            # over-budget case re-runs the census — accepted: it
-            # needs multi-edge hubs past 4 bits plus a saturated
-            # budget, and the native census is seconds even at
-            # Reddit scale
-            with span("setup.tables", table="bdense"):
-                plans = _mk(bdense_a_budget * 2
-                            if bdense_a_budget is not None else None)
-                packable = all(pl.n_blocks == 0
-                               or int(pl.a_blocks.max()) <= U4_MAX
-                               for pl in plans)
-                if packable:
-                    plans = [pack_a_u4(pl) for pl in plans]
-                elif bdense_a_budget is not None and any(
-                        pl.a_blocks.nbytes > bdense_a_budget
-                        for pl in plans):
-                    plans = _mk(bdense_a_budget)
-            bd_occupancy = tuple(pl.occupancy() for pl in plans)
-            nblk_max = max(pl.n_blocks for pl in plans)
-            if nblk_max:
-                bd_vpad = plans[0].vpad
-                bd_src_vpad = plans[0].src_vpad
-                n_dst_tiles = bd_vpad // BLOCK
-                a_w = BLOCK // 2 if packable else BLOCK
-                with span("setup.tables", table="bdense_stack"):
-                    a = np.zeros((pg.num_parts, nblk_max, BLOCK, a_w),
-                                 dtype=np.uint8)
-                    sblk = np.zeros((pg.num_parts, nblk_max),
-                                    dtype=np.int32)
-                    # padding blocks target the dummy output tile
-                    # (index n_dst_tiles) — zero A keeps them
-                    # numerically inert, the dummy dst keeps even
-                    # rounding noise off real rows
-                    dblk = np.full((pg.num_parts, nblk_max), n_dst_tiles,
-                                   dtype=np.int32)
-                    for p, pl in enumerate(plans):
-                        nb = pl.n_blocks
-                        a[p, :nb] = pl.a_blocks
-                        sblk[p, :nb] = pl.src_blk
-                        dblk[p, :nb] = pl.dst_blk
-                bd_tabs = (put(a), put(sblk), put(dblk))
-                if aggr_fuse:
-                    # in-register tile scales (ops/blockdense.py):
-                    # dst covers local padded rows, src the gathered
-                    # layout (identical on every part — replicated
-                    # rows keep the stacked-upload convention)
-                    dd = np.zeros((pg.num_parts, bd_vpad), np.float32)
-                    dd[:, :pg.part_nodes] = fuse_d[0]
-                    ds1 = np.zeros(bd_src_vpad, np.float32)
-                    ds1[:src_rows] = fuse_d[1]
-                    ds = np.broadcast_to(
-                        ds1, (pg.num_parts, bd_src_vpad)).copy()
-                    bd_scale = (put(dd), put(ds))
-            # residual scattered edges -> the stacked sectioned tables
-            # (every edge, when no tile qualifies anywhere)
-            with span("setup.tables", table="bdense_residual"):
-                e_res = max(max(pl.res_col.shape[0] for pl in plans), 1)
-                res_ptrs = np.stack([pl.res_row_ptr for pl in plans])
-                res_cols = np.zeros((pg.num_parts, e_res),
-                                    dtype=np.int32)
-                for p, pl in enumerate(plans):
-                    res_cols[p, :pl.res_col.shape[0]] = pl.res_col
-            sect_idx, sect_sub_dst, sect_meta, sect_w = \
-                _sectioned_tables(
-                    res_ptrs, res_cols, pg, src_rows=src_rows,
-                    section_rows=section_rows, sect_sub_w=sect_sub_w,
-                    sect_u16=sect_u16, put=put, fuse_d=fuse_d)
-        elif aggr_impl in ("attn_flat8", "flat_sum"):
-            # the uniform flat layout, sharded: per-partition SINGLE-
-            # section tables over gathered coordinates (one uniform
-            # scan shape per device — the same compile-size fix as the
-            # single-chip path, train/trainer.py make_graph_context).
-            # The flat tables ride the sect_* slots (ShardedData
-            # docstring); the step body routes them to the
-            # GraphContext flat8 fields.  FLAT_SEG_ROWS bounds the
-            # per-chunk transient like there.  For the fused flat_sum
-            # path the baked D^-1/2 weight tables ride the sect_w slot
-            # the same way.
-            from ..core.ell import flat_sum_from_padded_parts
-            src_rows = pg.num_parts * pg.part_nodes
-            with span("setup.tables", table="flat_sum") as s:
-                sect = flat_sum_from_padded_parts(
-                    pg.part_row_ptr, col_padded, pg.real_nodes,
-                    pg.part_nodes, src_rows=src_rows)
-                s["sub_rows"] = sum(a.size for a in sect.sub_dst)
-            sect_idx = tuple(put(a) for a in sect.idx)
-            sect_sub_dst = tuple(put(a) for a in sect.sub_dst)
-            if aggr_impl == "flat_sum":
-                flat_win = sect.win_rows[0]
-                flat_bands = sect.bands[0]
-                if fuse_d is not None:
-                    with span("setup.tables", table="flat_sum_w"):
-                        sect_w = sect.weight_tables(fuse_d[0], fuse_d[1])
-                    sect_w = tuple(put(w) for w in sect_w)
-        if aggr_impl != "segment":
-            col_padded = np.zeros((pg.num_parts, 1), dtype=np.int32)
+    view = stacked_view(pg, put, remap=halo != "ring")
+    notes: Dict = {}
+    tables = build_tables(
+        "ring" if halo == "ring" else aggr_impl, view, fuse=aggr_fuse,
+        notes=notes, sect_sub_w=sect_sub_w, sect_u16=sect_u16,
+        section_rows=section_rows, bdense_min_fill=bdense_min_fill,
+        bdense_a_budget=bdense_a_budget, bdense_group=bdense_group)
     with span("setup.tables", table="padded_rows"):
         feats = pad_nodes(dataset.features, pg).astype(dtype)
         labels = pad_nodes(dataset.labels, pg)
         mask = pad_nodes(dataset.mask, pg, fill=MASK_NONE)
     return ShardedData(
-        feats=put(feats, "features"),
-        labels=put(labels, "labels"),
-        mask=put(mask, "mask"),
-        edge_src=put(col_padded),
-        edge_dst=put(edge_dst),
-        in_degree=put(pg.part_in_degree),
-        ell_idx=ell_idx,
-        ell_row_pos=ell_row_pos,
-        ell_row_id=ell_row_id,
-        ring_idx=ring_idx,
-        sect_idx=sect_idx,
-        sect_sub_dst=sect_sub_dst,
-        sect_meta=sect_meta,
-        flat_win=flat_win,
-        flat_bands=flat_bands,
-        bd_tabs=bd_tabs,
-        bd_vpad=bd_vpad,
-        bd_src_vpad=bd_src_vpad,
-        bd_occupancy=bd_occupancy,
-        bd_group=bdense_group if bd_tabs else 1,
-        ring_padding_ratio=ring_padding_ratio,
-        ell_w=ell_w,
-        sect_w=sect_w,
-        ring_w=ring_w,
-        bd_scale=bd_scale,
-    )
+        feats=upload(feats, "features", put=put),
+        labels=upload(labels, "labels", put=put),
+        mask=upload(mask, "mask", put=put),
+        tables=tables,
+        in_degree=upload(pg.part_in_degree, "tables", put=put),
+        bd_occupancy=notes.get("bd_occupancy", ()),
+        ring_padding_ratio=notes.get("ring_padding_ratio"))
 
 
 def put_replicated(tree, mesh: Mesh):
@@ -712,122 +477,59 @@ class DistributedTrainer:
         self.pg = pg
         self.data = self._with_real_rows(
             data if data is not None else self._build_data(self.pg))
-        if config.aggr_impl == "bdense" and config.halo != "ring" \
-                and data is None:
-            # own build only: injected data carries no plan to report
-            # (an empty bd_tabs there means the CALLER never planned,
-            # not that no tile qualified)
+        tables = self.data.tables
+        if data is not None:
+            # the autopilot / auto-resolution above may have settled on
+            # a different halo/aggr_impl than the caller built tables
+            # for — fail HERE with the mismatch, not mid-step with an
+            # opaque shape error
+            layout = "ring" if config.halo == "ring" else config.aggr_impl
+            want = tables_type(layout)
+            if not isinstance(tables, want):
+                raise ValueError(
+                    f"injected data carries the {tables.what} tables "
+                    f"but the resolved layout {layout!r} reads the "
+                    f"{want.what} tables — build it with the same "
+                    f"aggr_impl and halo (note: attention/sum models "
+                    f"at >=20M edges auto-route to the flat layouts)")
+        if isinstance(tables, BlockDenseTables):
+            if tables.a is not None \
+                    and tables.group != config.bdense_group:
+                # a group mismatch would reduce across dst-tile
+                # boundaries (or trip the kernel's alignment check) —
+                # fail here with the cause, not mid-step
+                raise ValueError(
+                    f"injected data was built with bdense_group="
+                    f"{tables.group} but the config wants "
+                    f"bdense_group={config.bdense_group} — build "
+                    f"it with shard_dataset(..., bdense_group="
+                    f"{config.bdense_group})")
             for p, occ in enumerate(self.data.bd_occupancy):
                 emit("plan", f"bdense part {p}: {occ['n_blocks']} "
                      f"blocks, dense_frac={occ['dense_frac']}, "
                      f"mean_fill={occ['mean_fill']}",
                      console=config.verbose, part=p, **occ)
-            if not self.data.bd_tabs:
+            if tables.a is None:
                 # changes the effective execution path — echoes
                 # unconditionally, like the single-device fallback
                 # (train/trainer.py)
                 emit("plan", "bdense: no [128,128] tile reaches "
                      f"min_fill={config.bdense_min_fill} on any "
                      "partition — running the pure sectioned residual")
-        if data is not None:
-            # the autopilot / auto-resolution above may have settled on
-            # a different halo/aggr_impl than the caller built tables
-            # for — fail HERE with the mismatch, not mid-step with an
-            # opaque shape error
-            if config.halo == "ring" and not self.data.ring_idx:
-                raise ValueError(
-                    "injected data has no ring tables but the resolved "
-                    "config wants halo='ring' (build it with "
-                    "shard_dataset_local(..., halo='ring') or pass "
-                    "memory/halo explicitly)")
-            if config.halo != "ring":
-                if config.aggr_impl in ("sectioned", "attn_flat8",
-                                        "flat_sum", "bdense") \
-                        and not self.data.sect_idx:
-                    raise ValueError(
-                        f"injected data has no sectioned/flat tables "
-                        f"but the resolved aggr_impl is "
-                        f"{config.aggr_impl!r} — build it with the "
-                        f"same aggr_impl (note: attention/sum models "
-                        f"at >=20M edges auto-route to the flat "
-                        f"layouts)")
-                if config.aggr_impl in ("sectioned", "bdense") \
-                        and self.data.sect_idx \
-                        and not self.data.sect_meta:
-                    # flat8-built tables carry sect_idx but no
-                    # sect_meta — aggregate_ell_sect would zip over
-                    # () and return all-zero aggregations silently
-                    raise ValueError(
-                        f"injected data carries flat8-style tables "
-                        f"(no section metadata) but the resolved "
-                        f"aggr_impl is {config.aggr_impl!r} — build "
-                        f"it with the same aggr_impl")
-                if config.aggr_impl == "bdense" \
-                        and self.data.bd_tabs \
-                        and self.data.bd_group != config.bdense_group:
-                    # a group mismatch would reduce across dst-tile
-                    # boundaries (or trip the kernel's alignment
-                    # check) — fail here with the cause, not mid-step
-                    raise ValueError(
-                        f"injected data was built with bdense_group="
-                        f"{self.data.bd_group} but the config wants "
-                        f"bdense_group={config.bdense_group} — build "
-                        f"it with shard_dataset(..., bdense_group="
-                        f"{config.bdense_group})")
-                if config.aggr_impl == "bdense" \
-                        and not self.data.bd_tabs \
-                        and not self.data.bd_occupancy:
-                    # sectioned-built data passes the two checks above
-                    # (sect_idx + sect_meta both present) but would
-                    # silently run residual-only; a genuine bdense
-                    # build always records per-part occupancy, even
-                    # when no tile qualifies and bd_tabs stays empty
-                    raise ValueError(
-                        "injected data carries no block-dense plan "
-                        "but the resolved aggr_impl is 'bdense' — "
-                        "build it with shard_dataset(..., "
-                        "aggr_impl='bdense')")
-                if config.aggr_impl == "bdense" \
-                        and not self.data.bd_tabs:
-                    # planned, but no [128,128] tile reached min_fill:
-                    # the step runs the pure sectioned residual — same
-                    # echo as the own-build path below
-                    emit("plan", "bdense: injected plan has no dense "
-                         "tiles — running the pure sectioned residual")
-                if config.aggr_impl == "ell" \
-                        and not self.data.ell_idx:
-                    raise ValueError(
-                        f"injected data has no ELL tables but the "
-                        f"resolved aggr_impl is "
-                        f"{config.aggr_impl!r} — build it with "
-                        f"aggr_impl='ell'")
-                if config.aggr_impl == "segment" and \
-                        self.data.edge_dst.shape[-1] != \
-                        self.pg.part_edges:
-                    # table-built data carries 1-element edge stubs;
-                    # the edge-list reference would silently aggregate
-                    # one fake 0->0 edge per part
-                    raise ValueError(
-                        f"injected data carries edge stubs "
-                        f"(shape {tuple(self.data.edge_dst.shape)}) "
-                        f"but the resolved aggr_impl "
-                        f"{config.aggr_impl!r} reads the flat edge "
-                        f"arrays — build the data with the same "
-                        f"aggr_impl")
-        if config.halo == "ring" and self.data.ring_idx:
+        if isinstance(tables, RingTables):
             # startup echo like the reference's config print
             # (gnn.cc:48-60): make the SPMD padding cost visible, and
             # say out loud that ring tables subsume the aggr impl
             ratio = self.data.ring_padding_ratio
             emit("plan", f"halo=ring: P={self.pg.num_parts} "
-                 f"pair_edges={self.data.ring_idx[0].shape[2]} "
+                 f"pair_edges={tables.src.shape[2]} "
                  f"padding_ratio="
                  f"{'?' if ratio is None else format(ratio, '.2f')} "
                  f"overlap={'on' if config.ring_overlap else 'off'} "
                  f"(aggr_impl={config.aggr_impl!r} unused: ring tables "
                  f"drive the aggregation)", console=config.verbose,
                  num_parts=self.pg.num_parts,
-                 pair_edges=int(self.data.ring_idx[0].shape[2]),
+                 pair_edges=int(tables.src.shape[2]),
                  padding_ratio=ratio,
                  ring_overlap=bool(config.ring_overlap))
         with span("setup.params") as s:
@@ -858,6 +560,8 @@ class DistributedTrainer:
         with span("setup.steps"):
             self._build_steps()
         from ..obs.manifest import run_manifest
+        # the plan entries read the stacked tables' trailing axes
+        stacked = dc_replace(self._gctx(), tables=tables)
         with span("setup.manifest"):
             # split-quality record: per-part padded shapes + halo rows
             # + imbalance ratios, into the manifest (every run records
@@ -871,13 +575,10 @@ class DistributedTrainer:
                        "bd_occupancy": list(self.data.bd_occupancy),
                        "partition": self._partition_stats},
                 agg_window={
-                    **self._gctx().agg_window(
-                        model._ops, tables=self.data.sect_idx,
-                        edges=int(dataset.graph.num_edges),
+                    **stacked.agg_window(
+                        model._ops, edges=int(dataset.graph.num_edges),
                         compute=self.compute),
-                    **self._gctx().attention_plan(
-                        model._ops, ell_idx=self.data.ell_idx,
-                        flat8_idx=next(iter(self.data.sect_idx), None)),
+                    **stacked.attention_plan(model._ops),
                     **self._gctx().soft_plan(model._ops, self.compute),
                     **batch_norm_plan(model._ops,
                                       dataset.graph.num_nodes),
@@ -906,8 +607,8 @@ class DistributedTrainer:
             return data
         counts = np.asarray([max(r - l + 1, 0)
                              for l, r in self.pg.bounds], dtype=np.int32)
-        return dc_replace(data, real_rows=(jax.device_put(
-            counts, NamedSharding(self.mesh, P(PARTS_AXIS))),))
+        return dc_replace(data, real_rows=jax.device_put(
+            counts, NamedSharding(self.mesh, P(PARTS_AXIS))))
 
     def _build_data(self, pg) -> ShardedData:
         """Build + upload the sharded tables for ``pg`` with the
@@ -975,9 +676,9 @@ class DistributedTrainer:
         # parts-sharded partition-index vector (_step_auto explains
         # why axis_index cannot be used there)
         extra = (psh,) if self._step_auto() else ()
-        return ((params_sh, opt_sh) + (psh,) * 14 + (rep, rep) + extra,
+        return ((params_sh, opt_sh) + (psh,) * 4 + (rep, rep) + extra,
                 (params_sh, opt_sh, rep),
-                (params_sh,) + (psh,) * 14 + extra,
+                (params_sh,) + (psh,) * 4 + extra,
                 (rep, psh))
 
     def _build_steps(self) -> None:
@@ -1069,31 +770,18 @@ class DistributedTrainer:
     @staticmethod
     def _static_signature(pg, data: ShardedData):
         """Everything the compiled step specializes on: padded shape
-        statics plus every table's (shape, dtype) and the static aux
-        the GraphContext pytree carries.  Two partitions with equal
+        statics plus every array's (shape, dtype) and the tables'
+        static aux (their tree structure).  Two partitions with equal
         signatures trace to the same executable, so the repartition
         path may keep the compiled step; any difference forces a
         rebuild (stale trace-time constants would otherwise
         mis-aggregate silently)."""
-        def sh(x):
-            if x is None:
-                return None
-            if isinstance(x, (tuple, list)):
-                return tuple(sh(v) for v in x)
-            if hasattr(x, "shape"):
-                return (tuple(x.shape), str(x.dtype))
-            return x
+        arrays = (data.feats, data.labels, data.mask, data.in_degree,
+                  data.tables)
         return (pg.part_nodes, pg.part_edges, pg.num_parts,
-                sh(data.feats), sh(data.labels), sh(data.mask),
-                sh(data.edge_src), sh(data.edge_dst),
-                sh(data.in_degree), sh(data.ell_idx),
-                sh(data.ell_row_pos), sh(data.ell_row_id),
-                sh(data.ring_idx), sh(data.sect_idx),
-                sh(data.sect_sub_dst), sh(data.sect_meta),
-                data.flat_win, data.flat_bands, sh(data.bd_tabs),
-                data.bd_vpad,
-                data.bd_src_vpad, data.bd_group, sh(data.ell_w),
-                sh(data.sect_w), sh(data.ring_w), sh(data.bd_scale))
+                jax.tree_util.tree_structure(arrays),
+                tuple((tuple(a.shape), str(a.dtype))
+                      for a in jax.tree_util.tree_leaves(arrays)))
 
     def _phi(self) -> np.ndarray:
         """Cached per-partition feature matrix for the CURRENT split
@@ -1258,12 +946,13 @@ class DistributedTrainer:
         return jax.tree_util.tree_map(_psum_wide, t)
 
     def _gctx(self) -> GraphContext:
-        """GraphContext for *inside* the shard_map body (local blocks)."""
+        """GraphContext for *inside* the shard_map body (local blocks);
+        the arrays are filled per call (:meth:`_local_gctx`)."""
         from ..train.trainer import resolve_head_chunk
         pgr = self.pg
         return GraphContext(
+            tables=None, in_degree=None,
             head_chunk=resolve_head_chunk(self.config, pgr.part_nodes),
-            edge_src=None, edge_dst=None, in_degree=None,  # filled per-call
             num_rows=pgr.part_nodes,
             gathered_rows=pgr.num_parts * pgr.part_nodes,
             gather_features=lambda x: lax.all_gather(
@@ -1273,31 +962,14 @@ class DistributedTrainer:
             symmetric=self.symmetric,
             halo=self.config.halo,
             ring_overlap=self.config.ring_overlap,
-            sect_meta=self.data.sect_meta,
-            flat8_win=self.data.flat_win,
-            flat8_bands=self.data.flat_bands,
-            bd_vpad=self.data.bd_vpad,
-            bd_src_vpad=self.data.bd_src_vpad,
-            # the DATA's group, validated == config at init: the
-            # tables define what the kernel may assume
-            bd_group=self.data.bd_group,
             total_rows=int(self._dataset.graph.num_nodes),
         )
 
-    def _local_gctx(self, edge_src, edge_dst, in_degree, ell_idx,
-                    ell_row_pos, ell_row_id, ring_idx, sect_idx,
-                    sect_sub_dst, bd_tabs=(),
-                    fuse_tabs=((), (), (), ()),
-                    pid=None) -> GraphContext:
-        """Local-block GraphContext for a shard_map body: slice the
-        parts axis off every table.  attn_flat8 and flat_sum carry
-        their single-section uniform tables in the sect slots
-        (ShardedData docstring) and route them to the flat8 fields
-        the builder reads (flat_sum's baked weight tables ride the
-        sect_w slot -> flat8_w); bdense carries its residual there
-        and its dense tiles in bd_tabs.  ``fuse_tabs`` = (ell_w,
-        sect_w, ring_w, bd_scale) — the baked fused-normalization
-        weights (empty tuples when unfused).
+    def _local_gctx(self, graph: GraphArrays, pid=None) -> GraphContext:
+        """Local-block GraphContext for a shard_map body: every array
+        of ``graph`` arrives with the parts axis collapsed to 1, which
+        is sliced off (``real_rows`` becomes this partition's count, a
+        scalar).
 
         ``pid`` (partial-auto 2-D steps only) is this block's traced
         partition index; it swaps ``gather_features`` for the
@@ -1307,42 +979,42 @@ class DistributedTrainer:
         blocks is the same gathered matrix, and psum IS supported
         there.  ~2x the all-gather bytes on ICI; only the 2-D path
         pays it."""
-        flat = self.config.aggr_impl in ("attn_flat8", "flat_sum")
-        ell_w, sect_w, ring_w, bd_scale, *real = fuse_tabs
+        # sliced in the order the steps have always sliced them: an
+        # edge list, the degrees, the real-row count, then any other
+        # layout's tables
+        one = functools.partial(jax.tree_util.tree_map, lambda a: a[0])
+        lead = isinstance(graph.tables, EdgeList)
+        tables = one(graph.tables) if lead else None
+        in_degree, real_rows = one(graph.in_degree), one(graph.real_rows)
+        if not lead:
+            tables = one(graph.tables)
         extra = {}
-        if real and real[0]:
-            # ([P] int32,) with the parts axis collapsed to 1: this
-            # partition's count, a scalar
-            extra["real_rows"] = real[0][0][0]
         if pid is not None:
             extra["gather_features"] = functools.partial(
                 _gather_by_psum, pid=pid, num_parts=self.pg.num_parts)
-        return dc_replace(
-            self._gctx(), edge_src=edge_src, edge_dst=edge_dst,
-            in_degree=in_degree,
-            ell_idx=tuple(a[0] for a in ell_idx),
-            ell_row_pos=ell_row_pos[0],
-            ell_row_id=tuple(a[0] for a in ell_row_id),
-            ring_idx=tuple(a[0] for a in ring_idx),
-            sect_idx=() if flat else tuple(a[0] for a in sect_idx),
-            sect_sub_dst=(() if flat
-                          else tuple(a[0] for a in sect_sub_dst)),
-            # halo='ring' uploads empty sect stubs (the ring tables
-            # fully describe the aggregation) — the flat8 fields must
-            # stay None so the builder routes to ring_aggregate
-            flat8_idx=sect_idx[0][0] if flat and sect_idx else None,
-            flat8_dst=(sect_sub_dst[0][0]
-                       if flat and sect_sub_dst else None),
-            flat8_w=(sect_w[0][0]
-                     if flat and sect_w else None),
-            bd_a=bd_tabs[0][0] if bd_tabs else None,
-            bd_src=bd_tabs[1][0] if bd_tabs else None,
-            bd_dst=bd_tabs[2][0] if bd_tabs else None,
-            ell_w=tuple(a[0] for a in ell_w),
-            sect_w=() if flat else tuple(a[0] for a in sect_w),
-            ring_w=ring_w[0][0] if ring_w else None,
-            bd_scale=tuple(a[0] for a in bd_scale),
-            **extra)
+        return dc_replace(self._gctx(), tables=tables,
+                          in_degree=in_degree, real_rows=real_rows,
+                          **extra)
+
+    def _pid_args(self) -> tuple:
+        """The partial-auto steps' trailing parts-sharded
+        partition-index vector (``_build_steps``); none on the 1-D
+        mesh."""
+        return () if self._pids is None else (self._pids,)
+
+    def _train_args(self, key, lr) -> tuple:
+        """The train step's arguments: THE one place its signature is
+        spelled out (the trainer, the program-space rigs and the
+        analysis driver all call through it)."""
+        d = self.data
+        return (self.params, self.opt_state, d.feats, d.labels, d.mask,
+                d.graph, key, lr) + self._pid_args()
+
+    def _eval_args(self) -> tuple:
+        """The eval (and predict) step's arguments."""
+        d = self.data
+        return (self.params, d.feats, d.labels, d.mask,
+                d.graph) + self._pid_args()
 
     def _build_train_step(self):
         mesh = self.mesh
@@ -1355,17 +1027,12 @@ class DistributedTrainer:
         # in for lax.axis_index which has no lowering under a GSPMD
         # auto axis (_step_auto).  The 1-D signature — and hence the
         # rigs' program keys — is untouched.
-        def step(params, opt_state, feats, labels, mask, edge_src,
-                 edge_dst, in_degree, ell_idx, ell_row_pos, ell_row_id,
-                 ring_idx, sect_idx, sect_sub_dst, bd_tabs, fuse_tabs,
-                 key, lr, *pids):
+        def step(params, opt_state, feats, labels, mask, graph, key, lr,
+                 *pids):
             # local blocks arrive with the parts axis collapsed to 1
             feats, labels, mask = feats[0], labels[0], mask[0]
             pid = pids[0][0] if pids else None
-            gctx = self._local_gctx(
-                edge_src[0], edge_dst[0], in_degree[0], ell_idx,
-                ell_row_pos, ell_row_id, ring_idx, sect_idx,
-                sect_sub_dst, bd_tabs, fuse_tabs, pid=pid)
+            gctx = self._local_gctx(graph, pid=pid)
             part_key = jax.random.fold_in(
                 key, lax.axis_index(PARTS_AXIS) if pid is None else pid)
 
@@ -1403,16 +1070,11 @@ class DistributedTrainer:
         return _shard_map(
             step, mesh=mesh,
             in_specs=(spec_r, spec_r, spec_p, spec_p, spec_p, spec_p,
-                      spec_p, spec_p, spec_p, spec_p, spec_p, spec_p,
-                      spec_p, spec_p, spec_p, spec_p, spec_r, spec_r)
-            + ((spec_p,) if auto else ()),
+                      spec_r, spec_r) + ((spec_p,) if auto else ()),
             out_specs=(spec_r, spec_r, spec_r),
             axis_names=frozenset({PARTS_AXIS}) if auto else frozenset())
 
-    def _local_forward(self, params, feats, edge_src, edge_dst,
-                       in_degree, ell_idx, ell_row_pos, ell_row_id,
-                       ring_idx, sect_idx, sect_sub_dst, bd_tabs,
-                       fuse_tabs=((), (), (), ()), pid=None):
+    def _local_forward(self, params, feats, graph, pid=None):
         """Shared shard_map body: slice the parts axis off the local
         blocks, assemble the local GraphContext, run the inference
         forward — eval (adds metrics+psum) and predict (adds
@@ -1420,10 +1082,7 @@ class DistributedTrainer:
         ONE place.  ``pid`` threads the partial-auto partition index
         through to :meth:`_local_gctx`."""
         feats = feats[0]
-        gctx = self._local_gctx(
-            edge_src[0], edge_dst[0], in_degree[0], ell_idx,
-            ell_row_pos, ell_row_id, ring_idx, sect_idx, sect_sub_dst,
-            bd_tabs, fuse_tabs, pid=pid)
+        gctx = self._local_gctx(graph, pid=pid)
         with jax.named_scope(OPT_SCOPE):
             params = cast_compute(params, self.compute)
         return self.model.apply(params, feats, gctx, key=None,
@@ -1435,15 +1094,11 @@ class DistributedTrainer:
         spec_r = P()
         auto = self._step_auto()
 
-        def step(params, feats, labels, mask, *graph_args):
-            pid = None
-            if auto:
-                # trailing parts-sharded partition-index vector, same
-                # contract as the train step
-                *graph_args, pids = graph_args
-                pid = pids[0]
-            logits = self._local_forward(params, feats, *graph_args,
-                                         pid=pid)
+        def step(params, feats, labels, mask, graph, *pids):
+            # the partial-auto variant's trailing parts-sharded
+            # partition-index vector: same contract as the train step
+            pid = pids[0][0] if pids else None
+            logits = self._local_forward(params, feats, graph, pid=pid)
             with jax.named_scope(LOSS_SCOPE):
                 m = perf_metrics(logits, labels[0], mask[0])
             # (replicated metrics, sharded logits): predict() reuses
@@ -1455,9 +1110,7 @@ class DistributedTrainer:
 
         return _shard_map(
             step, mesh=mesh,
-            in_specs=(spec_r, spec_p, spec_p, spec_p, spec_p, spec_p,
-                      spec_p, spec_p, spec_p, spec_p, spec_p, spec_p,
-                      spec_p, spec_p, spec_p)
+            in_specs=(spec_r, spec_p, spec_p, spec_p, spec_p)
             + ((spec_p,) if auto else ()),
             out_specs=(spec_r, spec_p),
             axis_names=frozenset({PARTS_AXIS}) if auto else frozenset())
@@ -1471,15 +1124,8 @@ class DistributedTrainer:
             # read self.data PER STEP, not once per train() call — an
             # epoch-boundary repartition swaps the sharded tables
             # mid-run and the next step must train on the new split
-            d = self.data
-            extra = () if self._pids is None else (self._pids,)
             self.params, self.opt_state, _ = self._train_step(
-                self.params, self.opt_state, d.feats, d.labels,
-                d.mask, d.edge_src, d.edge_dst, d.in_degree,
-                d.ell_idx, d.ell_row_pos, d.ell_row_id, d.ring_idx,
-                d.sect_idx, d.sect_sub_dst, d.bd_tabs,
-                (d.ell_w, d.sect_w, d.ring_w, d.bd_scale, d.real_rows),
-                step_key, lr, *extra)
+                *self._train_args(step_key, lr))
 
         return run_epoch_loop(self, epochs, do_step, self.evaluate)
 
@@ -1489,15 +1135,7 @@ class DistributedTrainer:
         sync(self.params)
 
     def _run_eval_step(self):
-        d = self.data
-        extra = () if self._pids is None else (self._pids,)
-        return self._eval_step(
-            self.params, d.feats, d.labels, d.mask, d.edge_src,
-            d.edge_dst, d.in_degree, d.ell_idx, d.ell_row_pos,
-            d.ell_row_id, d.ring_idx, d.sect_idx, d.sect_sub_dst,
-            d.bd_tabs,
-            (d.ell_w, d.sect_w, d.ring_w, d.bd_scale, d.real_rows),
-            *extra)
+        return self._eval_step(*self._eval_args())
 
     def _eval(self, epoch: int) -> Dict[str, float]:
         # fetch ONLY the metrics: the shared eval/predict program also

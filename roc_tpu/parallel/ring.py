@@ -17,16 +17,17 @@ once and peak memory is O(V/P * F) instead of O(V * F).
 Per-(partition, source-shard) edge groups are stored as FLAT dst-sorted
 edge lists padded to the max pair edge count — SPMD needs identical
 shapes on every device, and for edge-balanced partitions of power-law
-graphs this pads ~1.5-1.7x (the padding ratio is computed and stored on
-the table; a uniform per-pair ELL layout was measured at ~8x on the
-same graphs and replaced by this one).  The per-step local op is a
+graphs this pads ~1.5-1.7x (the build reports the padding ratio as
+``ring_padding_ratio``; a uniform per-pair ELL layout was measured at
+~8x on the same graphs and replaced by this one).  The tables are built
+part by part from these helpers by ``models/tables.py build_tables``
+(layout ``'ring'``).  The per-step local op is a
 chunked gather + sorted scatter-add — padding edges gather the zero row
 into the last output row, so they are numeric no-ops.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
 from typing import Optional
 
 import jax
@@ -49,29 +50,6 @@ def ring_hop_perm(num_shards: int):
     a partial cover) deadlocks or drops shards at P>=2 on real
     hardware, where no trace-time error exists to catch it."""
     return [(i, (i + 1) % num_shards) for i in range(num_shards)]
-
-
-@dataclass
-class RingTables:
-    """Flat per-(partition, source-shard) edge lists, uniform shapes.
-
-    src: int32 [P, S, pair_edges] source ids *local to the source
-      shard* (dummy = part_nodes, the zero row appended to the rotating
-      buffer).
-    dst: int32 [P, S, pair_edges] local destination rows, sorted
-      ascending within each pair; padding uses ``part_nodes - 1`` (keeps
-      the sort; the gathered zero row adds nothing).
-    padding_ratio: padded slots / real edges (>= 1.0), reported so the
-      memory-policy layer can echo the cost of SPMD uniformity.
-    """
-
-    src: np.ndarray
-    dst: np.ndarray
-    padding_ratio: float
-
-    @property
-    def pair_edges(self) -> int:
-        return int(self.src.shape[2])
 
 
 def build_ring_pairs(pg: PartitionedGraph, p: int,
@@ -122,54 +100,23 @@ def round_pair_edges(max_pair: int) -> int:
     return -(-max(max_pair, 1) // 8) * 8
 
 
-def build_ring_tables(pg: PartitionedGraph) -> RingTables:
-    """Split each partition's local CSR by source shard into flat
-    dst-sorted edge lists padded to the max pair size (single-host
-    form; the multi-host path builds per-partition pairs locally and
-    agrees on ``pair_edges`` with an O(P) collective —
-    parallel/multihost.py)."""
-    P = pg.num_parts
-    all_pairs = {p: build_ring_pairs(pg, p) for p in range(P)}
-    max_pair = max((d.shape[0] for pairs in all_pairs.values()
-                    for _, d in pairs.values()), default=1)
-    total_real = sum(d.shape[0] for pairs in all_pairs.values()
-                     for _, d in pairs.values())
-    pair_edges = round_pair_edges(max_pair)
-    src = np.empty((P, P, pair_edges), dtype=np.int32)
-    dst = np.empty((P, P, pair_edges), dtype=np.int32)
-    for p, pairs in all_pairs.items():
-        src[p], dst[p] = pack_ring_part(pairs, P, pair_edges,
-                                        pg.part_nodes)
-    ratio = (P * P * pair_edges) / max(total_real, 1)
-    return RingTables(src=src, dst=dst, padding_ratio=float(ratio))
-
-
-def ring_weight_tables(pg: PartitionedGraph, rt: RingTables,
-                       d_global: np.ndarray) -> np.ndarray:
-    """Baked fused-normalization weights for the ring tables
-    (:func:`ring_aggregate` ``weights``): fp32 ``[P, S, pair_edges]``
-    with ``w = d[dst_global] * d[src_global]`` — the per-edge entries
-    of ``D^-1/2 A D^-1/2`` in ring layout, so the fused aggregation
-    runs the rotation with ZERO runtime normalization.  Padding slots
-    (dummy source id ``part_nodes``) weigh 0; ``d_global`` is the
-    inv-sqrt in-degree vector over ORIGINAL vertex ids [V]."""
-    P, S, pe = rt.src.shape
-    offsets = np.asarray([l for l, _ in pg.bounds] + [pg.num_nodes],
-                         dtype=np.int64)
-    starts = np.minimum(offsets[:P], pg.num_nodes)
-    d = np.asarray(d_global, dtype=np.float32)
-    w = np.zeros((P, S, pe), dtype=np.float32)
-    for p in range(P):
-        # padding dst slots use part_nodes - 1 (may exceed the real
-        # rows); clip for the lookup — the src dummy mask zeroes them
-        dstg = np.minimum(starts[p] + rt.dst[p].astype(np.int64),
-                          pg.num_nodes - 1)
-        for s in range(S):
-            srcl = rt.src[p, s].astype(np.int64)
-            real = srcl < pg.part_nodes
-            srcg = np.minimum(starts[s] + srcl, pg.num_nodes - 1)
-            w[p, s] = np.where(real, d[dstg[s]] * d[srcg], 0.0)
-    return w
+def ring_part_weights(src: np.ndarray, dst: np.ndarray, d: np.ndarray,
+                      p: int) -> np.ndarray:
+    """Partition ``p``'s baked fused-normalization weights for its ring
+    tables ``src`` / ``dst`` ``[S, pair_edges]`` (:func:`ring_aggregate`
+    ``weights``): fp32, ``w = d[row summed into] * d[source row]`` — the
+    per-edge entries of ``D^-1/2 A D^-1/2`` in ring layout, so the
+    fused aggregation runs the rotation with ZERO runtime
+    normalization.  ``d`` ``[P, part_nodes]``: the inv-sqrt in-degrees
+    of every partition's padded rows.  Padding slots (dummy source id
+    ``part_nodes``) weigh 0."""
+    pn = d.shape[1]
+    real = src < pn
+    # padding dst slots use part_nodes - 1 and the dummy source is
+    # part_nodes: clip for the lookup, the mask zeroes them
+    w = (d[p][np.minimum(dst, pn - 1)]
+         * d[np.arange(src.shape[0])[:, None], np.minimum(src, pn - 1)])
+    return np.where(real, w, 0.0).astype(np.float32)
 
 
 def ring_aggregate(x: jax.Array, ring_src: jax.Array,
@@ -188,7 +135,7 @@ def ring_aggregate(x: jax.Array, ring_src: jax.Array,
     construction).
 
     ``weights`` (optional): [S, pair_edges] per-edge weights
-    (:func:`ring_weight_tables` — the baked fused-norm scales),
+    (:func:`ring_part_weights` — the baked fused-norm scales),
     applied to the gathered rows in-register before the scatter-add.
 
     ``overlap`` (default True): double-buffered hop schedule — the

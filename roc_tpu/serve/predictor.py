@@ -283,10 +283,11 @@ class Predictor:
         stable across processes — part of the program key)."""
         import jax.numpy as jnp
 
+        from ..models.tables import EdgeList
         from ..models.builder import GraphContext
         return GraphContext(
-            edge_src=jnp.zeros(1, jnp.int32),
-            edge_dst=jnp.zeros(1, jnp.int32),
+            tables=EdgeList(src=jnp.zeros(1, jnp.int32),
+                            dst=jnp.zeros(1, jnp.int32)),
             in_degree=jnp.zeros(1, jnp.int32),
             num_rows=1, gathered_rows=1, aggr_impl="segment",
             symmetric=True)

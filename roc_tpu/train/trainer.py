@@ -19,9 +19,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.graph import Dataset
-from ..core.partition import padded_edge_list
 from ..core.relations import (AGGREGATE_FIRST, ORDER_PASSES, agg_passes,
                               resolve_agg_order)
+from ..models.tables import EdgeList, View, build_tables, upload
 from ..models.builder import GraphContext, Model
 from ..obs.events import emit, flush_spans, span
 from ..obs.metrics_registry import MetricsRegistry
@@ -876,20 +876,6 @@ def resolve_config(model: Model, dataset: Dataset, config: TrainConfig,
     return model, config, bd_census
 
 
-def upload(tree, what: str, put=jnp.asarray):
-    """Hand every array of the pytree ``tree`` to the device through
-    ``put``, under one ``setup.upload`` span that counts the bytes
-    handed over.  The span closes when ``put`` returns, which is when
-    the host buffer has been handed over, not when the device holds
-    it: it adds no sync."""
-    with span("setup.upload", what=what) as s:
-        def one(a):
-            out = put(a)
-            s["h2d_bytes"] += out.nbytes
-            return out
-        return jax.tree_util.tree_map(one, tree)
-
-
 def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
                        chunk: int = 512,
                        symmetric: Optional[bool] = None,
@@ -923,240 +909,58 @@ def make_graph_context(dataset: Dataset, aggr_impl: str = "segment",
     ``Model.loss_cut().rel_cuts()``): beside them the tables of the
     relation subsets the loss program's cut layers sum."""
     g = dataset.graph
+    view = View.one_device(g, chunk)
+    relations = None
     if rel_orders:
-        return _relation_context(
-            dataset, aggr_impl, _relation_tables(rel_orders, rel_cuts),
-            head_chunk)
-    if aggr_impl == "auto":
+        relations = (dataset.typed, _relation_tables(rel_orders,
+                                                      rel_cuts))
+    elif aggr_impl == "auto":
         aggr_impl, bd_census = resolve_auto_impl_probed(
             g, bdense_min_fill=bdense_min_fill,
             bdense_a_budget=bdense_a_budget,
             bdense_group=bdense_group, verbose=verbose)
-    d_np = None
-    if fuse:
-        from ..ops.norm import inv_sqrt_degree_np
-        d_np = inv_sqrt_degree_np(g.in_degree)
-    ell_idx: tuple = ()
-    ell_row_pos = None
-    sect_idx: tuple = ()
-    sect_sub_dst: tuple = ()
-    sect_meta: tuple = ()
-    flat8_idx = flat8_dst = flat8_w = None
-    flat8_win = 0
-    flat8_bands = ()
-    bd_a = bd_src = bd_dst = None
-    bd_vpad = 0
-    ell_w: tuple = ()
-    sect_w: tuple = ()
-    bd_scale: tuple = ()
-    if aggr_impl != "segment":
-        # the table layouts never read the flat edge arrays — don't upload
-        # two [E] int32 tensors (~920 MB at Reddit scale) they'd ignore
-        edge_src = np.zeros(1, dtype=np.int32)
-        edge_dst = np.zeros(1, dtype=np.int32)
-    else:
-        with span("setup.tables", table="edge_list", edges=g.num_edges):
-            edge_src, edge_dst = padded_edge_list(g, multiple=chunk)
-    ell_row_id: tuple = ()
-
-    def sectioned(row_ptr, col_idx):
-        """The sectioned tables of one CSR and their weights: the
-        numpy build under ``setup.tables``, then the hand-over."""
-        from ..core.ell import default_section_rows, sectioned_from_graph
-        with span("setup.tables", table="sectioned",
-                  edges=col_idx.shape[0]) as s:
-            sect = sectioned_from_graph(
-                row_ptr, col_idx, g.num_nodes,
-                section_rows=default_section_rows(sect_u16),
-                sub_w=sect_sub_w)
-            if sect_u16:
-                sect = sect.with_idx_dtype(np.uint16)
-            s["sub_rows"] = sum(a.size for a in sect.sub_dst)
-        with span("setup.upload", what="tables") as s:
-            idx, sub_dst, meta = sect.as_jax()
-            s["h2d_bytes"] = sum(a.nbytes for a in idx + sub_dst)
-        w = ()
-        if fuse:
-            with span("setup.tables", table="sect_w"):
-                w = sect.weight_tables(d_np, d_np)
-            w = upload(tuple(w), "tables")
-        return idx, sub_dst, meta, w
-
-    if aggr_impl == "ell":
-        from ..core.ell import ell_from_graph
-        with span("setup.tables", table="ell", edges=g.num_edges) as s:
-            table = ell_from_graph(g.row_ptr, g.col_idx, g.num_nodes)
-            s["slots"] = sum(a.size for a in table.idx)
-        ell_idx, ell_row_pos, ell_row_id = upload(
-            (tuple(a[0] for a in table.idx), table.row_pos[0],
-             tuple(a[0] for a in table.row_id)), "tables")
-        if fuse:
-            from ..core.ell import ell_weight_tables
-            with span("setup.tables", table="ell_w"):
-                ell_w = ell_weight_tables(table, d_np[None, :], d_np)
-            ell_w = upload(tuple(w[0] for w in ell_w), "tables")
-    elif aggr_impl == "sectioned":
-        sect_idx, sect_sub_dst, sect_meta, sect_w = sectioned(
-            g.row_ptr, g.col_idx)
-    elif aggr_impl == "bdense":
-        # block-dense MXU aggregation: dense [128,128] adjacency tiles
-        # as uint8 multiplicity tables, scattered residual through the
-        # sectioned gather (ops/blockdense.py — wins when the vertex
-        # order concentrates edges into tiles; the occupancy echo
-        # makes a mis-fit choice visible)
-        from ..ops.blockdense import BLOCK, plan_blocks_packed
-        with span("setup.tables", table="bdense",
-                  edges=g.num_edges) as s:
-            plan = plan_blocks_packed(g.row_ptr, g.col_idx, g.num_nodes,
-                                      min_fill=bdense_min_fill,
-                                      a_budget_bytes=bdense_a_budget,
-                                      group=bdense_group,
-                                      census=bd_census)
-            s["blocks"] = plan.n_blocks
-        packed = plan.a_blocks.shape[-1] == BLOCK // 2
-        occ = plan.occupancy()
-        if plan.n_blocks:
-            emit("plan", f"bdense plan: {occ['n_blocks']} blocks, "
-                 f"fill {occ['mean_fill']}, dense "
-                 f"{occ['dense_frac']:.0%} (residual "
-                 f"{1 - occ['dense_frac']:.0%} via sectioned"
-                 f"{', A u4-packed' if packed else ''})",
-                 console=verbose, packed=packed, **occ)
-            bd_a, bd_src, bd_dst = upload(
-                (plan.a_blocks, plan.src_blk, plan.dst_blk), "tables")
-            bd_vpad = plan.vpad
-        else:
-            # no tile qualifies: running the zero-block kernel every
-            # step would be pure overhead — this changes the effective
-            # execution path, so it echoes unconditionally
-            emit("plan", f"bdense: no [128,128] tile reaches min_fill="
-                 f"{bdense_min_fill} on this graph/order — running "
-                 f"the sectioned residual only", **occ)
-        if fuse:
-            # in-register tile scales (ops/blockdense.py scale_dst/
-            # scale_src) — the integer A-table stays intact
-            dd = np.zeros(plan.vpad, np.float32)
-            dd[:g.num_nodes] = d_np
-            ds = np.zeros(plan.src_vpad, np.float32)
-            ds[:g.num_nodes] = d_np
-            bd_scale = upload((dd, ds), "tables")
-        if plan.res_col.shape[0]:
-            # same tuning knobs as the 'sectioned' branch — bdense's
-            # residual must not silently drop user-selected config
-            sect_idx, sect_sub_dst, sect_meta, sect_w = sectioned(
-                plan.res_row_ptr, plan.res_col)
-    elif aggr_impl in ("attn_flat8", "flat_sum"):
-        # the uniform flat layout: ONE section spanning all sources
-        # (global ids, dummy == num_nodes == the appended zero row),
-        # sub-rows of a row consecutive/ascending — compile size
-        # independent of the degree distribution.  Two consumers of
-        # the same tables: gat_aggregate_flat8 (attention) and
-        # aggregate_flat_sum/_max (the sum/MAX consolidation).
-        # FLAT_SEG_ROWS bounds the per-chunk transient [seg, 8, F] at
-        # 64 MiB for F=256 fp32.
-        from ..core.ell import flat_sum_from_graph
-        with span("setup.tables", table="flat_sum",
-                  edges=g.num_edges) as s:
-            sect = flat_sum_from_graph(g.row_ptr, g.col_idx, g.num_nodes)
-            s["sub_rows"] = sect.sub_dst[0].size
-        flat8_idx, flat8_dst = upload((sect.idx[0], sect.sub_dst[0]),
-                                      "tables")
-        if aggr_impl == "flat_sum":
-            flat8_win = sect.win_rows[0]
-            flat8_bands = sect.bands[0]
-            if fuse:
-                # baked D^-1/2 A D^-1/2 entries of the single section
-                # — zero runtime normalization on the fused flat path
-                with span("setup.tables", table="flat_sum_w"):
-                    flat8_w = sect.weight_tables(d_np, d_np)[0]
-                flat8_w = upload(flat8_w, "tables")
-    edge_src, edge_dst, in_degree = upload(
-        (edge_src, edge_dst, g.in_degree), "tables")
+    notes: Dict[str, Any] = {}
+    tables = build_tables(
+        aggr_impl, view, fuse=fuse, relations=relations, notes=notes,
+        sect_sub_w=sect_sub_w, sect_u16=sect_u16,
+        bdense_min_fill=bdense_min_fill,
+        bdense_a_budget=bdense_a_budget, bdense_group=bdense_group,
+        census=bd_census)
+    if "bd_occupancy" in notes:
+        _echo_bdense(tables, notes["bd_occupancy"][0], bdense_min_fill,
+                     verbose)
     return GraphContext(
-        edge_src=edge_src,
-        edge_dst=edge_dst,
-        in_degree=in_degree,
+        tables=tables,
+        in_degree=upload(g.in_degree, "tables"),
         num_rows=g.num_nodes,
         gathered_rows=g.num_nodes,
         aggr_impl=aggr_impl,
-        symmetric=resolve_symmetric(dataset, symmetric),
-        ell_idx=ell_idx,
-        ell_row_pos=ell_row_pos,
-        ell_row_id=ell_row_id,
-        sect_idx=sect_idx,
-        sect_sub_dst=sect_sub_dst,
-        sect_meta=sect_meta,
-        flat8_idx=flat8_idx,
-        flat8_dst=flat8_dst,
-        flat8_w=flat8_w,
-        flat8_win=flat8_win,
-        flat8_bands=flat8_bands,
-        head_chunk=head_chunk,
-        bd_a=bd_a,
-        bd_src=bd_src,
-        bd_dst=bd_dst,
-        bd_vpad=bd_vpad,
-        bd_group=bdense_group if bd_a is not None else 1,
-        ell_w=ell_w,
-        sect_w=sect_w,
-        bd_scale=bd_scale,
-    )
+        # every backward pass of a typed graph has its transposed
+        # table: the union's symmetry is never read
+        symmetric=(True if relations
+                   else resolve_symmetric(dataset, symmetric)),
+        head_chunk=head_chunk)
 
 
-def _relation_context(dataset: Dataset, aggr_impl: str, tables,
-                      head_chunk: int) -> GraphContext:
-    """The GraphContext of a typed graph: per relation pass
-    (``core/relations.py``) the flat width-8 table, its output rows
-    and each slot's ``1 / deg_r(v)`` — the builder of the homogeneous
-    'flat_sum' tables (``core/ell.py flat_sum_from_graph``) over the
-    pass's own CSR — or, under 'segment', the forward passes' edge
-    lists.  The union's symmetry is never read: every backward pass
-    has its transposed table.  ``tables``: ``(order, rels)`` a table
-    set (:func:`_relation_tables`), the whole graph's (``rels`` None)
-    before any cut of it, whose passes then mask the whole one's
-    sorted edges (``TypedGraph.restrict``)."""
-    from ..core.ell import flat_sum_from_graph
-    g = dataset.graph
-    idx, dst, w, meta = [], [], [], []
-    for order, rels in tables:
-        typed, cut = dataset.typed, "whole"
-        if rels is not None:
-            cut = "cut"
-            with span("setup.tables", table="rel.cut.restrict"):
-                typed = typed.restrict(rels)
-        fwd, bwd = ORDER_PASSES[order]
-        for name in ((fwd,) if aggr_impl == "segment" else (fwd, bwd)):
-            with span("setup.tables", table=f"rel.{cut}.{name}") as s:
-                row_ptr, col, n_into, n_out = typed.pass_csr(name)
-                if aggr_impl == "segment":
-                    into = np.repeat(np.arange(n_into, dtype=np.int32),
-                                     np.diff(row_ptr))
-                    t_idx, t_dst, win, bands = col, into, 0, ()
-                    t_w = typed.slot_weights(
-                        name, col[:, None], into)[:, 0]
-                else:
-                    sect = flat_sum_from_graph(row_ptr, col, n_into,
-                                               src_rows=n_out)
-                    t_idx, t_dst = sect.idx[0], sect.sub_dst[0]
-                    win, bands = sect.win_rows[0], sect.bands[0]
-                    # slot-major at rest: [n_chunks, 8 * seg_rows]
-                    n = t_idx.shape[0]
-                    t_w = typed.slot_weights(
-                        name, t_idx, t_dst).transpose(
-                        0, 2, 1).reshape(n, -1)
-                    t_idx = t_idx.transpose(0, 2, 1).reshape(n, -1)
-                s.update(edges=col.shape[0], sub_rows=t_dst.size)
-            t_idx, t_dst, t_w = upload((t_idx, t_dst, t_w), "tables")
-            idx.append(t_idx)
-            dst.append(t_dst)
-            w.append(t_w)
-            meta.append((name, n_into, n_out, win, rels, bands))
-    return GraphContext(
-        edge_src=jnp.zeros(1, jnp.int32), edge_dst=jnp.zeros(1, jnp.int32),
-        in_degree=upload(g.in_degree, "tables"), num_rows=g.num_nodes,
-        gathered_rows=g.num_nodes, aggr_impl=aggr_impl, symmetric=True,
-        head_chunk=head_chunk, rel_idx=tuple(idx), rel_dst=tuple(dst),
-        rel_w=tuple(w), rel_meta=tuple(meta))
+def _echo_bdense(tables, occ, min_fill: int, verbose: bool) -> None:
+    """The block-dense plan's occupancy (the echo that makes a mis-fit
+    choice visible); a plan with no tile changes the path run and
+    echoes unconditionally."""
+    from ..ops.blockdense import BLOCK
+    if tables.a is not None:
+        packed = tables.a.shape[-1] == BLOCK // 2
+        emit("plan", f"bdense plan: {occ['n_blocks']} blocks, "
+             f"fill {occ['mean_fill']}, dense "
+             f"{occ['dense_frac']:.0%} (residual "
+             f"{1 - occ['dense_frac']:.0%} via sectioned"
+             f"{', A u4-packed' if packed else ''})",
+             console=verbose, packed=packed, **occ)
+    else:
+        # no tile qualifies: running the zero-block kernel every step
+        # would be pure overhead
+        emit("plan", f"bdense: no [128,128] tile reaches min_fill="
+             f"{min_fill} on this graph/order — running "
+             f"the sectioned residual only", **occ)
 
 
 def batch_norm_plan(ops, num_nodes: int) -> Dict[str, Any]:
@@ -1375,8 +1179,8 @@ class Trainer:
             from ..models.builder import GraphContext
             g = dataset.graph
             self.gctx = GraphContext(
-                edge_src=jnp.zeros(1, jnp.int32),
-                edge_dst=jnp.zeros(1, jnp.int32),
+                tables=EdgeList(src=jnp.zeros(1, jnp.int32),
+                                dst=jnp.zeros(1, jnp.int32)),
                 in_degree=upload(g.in_degree, "tables"),
                 num_rows=g.num_nodes, gathered_rows=g.num_nodes,
                 aggr_impl="segment",

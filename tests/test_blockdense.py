@@ -115,10 +115,10 @@ def test_trainer_bdense_group_matches_segment():
         tr.train()
         trainers[label] = tr
     tg = trainers["bdense_g4"]
-    assert tg.gctx.bd_group == 4
-    assert tg.gctx.bd_a.shape[0] % 4 == 0
+    assert tg.gctx.tables.group == 4
+    assert tg.gctx.tables.a.shape[0] % 4 == 0
     # padding actually happened (the fixture's tile widths are odd)
-    assert tg.gctx.bd_a.shape[0] > trainers["bdense"].gctx.bd_a.shape[0]
+    assert tg.gctx.tables.a.shape[0] > trainers["bdense"].gctx.tables.a.shape[0]
     for ref in ("bdense", "segment"):
         for k in trainers[ref].params:
             np.testing.assert_allclose(
@@ -337,7 +337,7 @@ def test_auto_impl_probes_structure(monkeypatch):
                                  intra_frac=0.9, shuffle=False, seed=1)
     gc = make_graph_context(mk(comm), "auto", bdense_min_fill=64)
     assert gc.aggr_impl == "bdense"
-    assert gc.bd_a is not None
+    assert gc.tables.a is not None
     unif = random_csr(20_000, 100_000, seed=2)
     gu = make_graph_context(mk(unif), "auto", bdense_min_fill=64)
     assert gu.aggr_impl == "sectioned"
@@ -426,8 +426,8 @@ def test_trainer_bdense_matches_segment():
                  TrainConfig(aggr_impl="bdense", bdense_min_fill=250,
                              **kw))
     # the plan actually split: dense tiles AND a sectioned residual
-    assert tb.gctx.bd_a is not None and tb.gctx.bd_a.shape[0] > 0
-    assert tb.gctx.sect_idx, "fixture must leave residual edges"
+    assert tb.gctx.tables.a is not None and tb.gctx.tables.a.shape[0] > 0
+    assert tb.gctx.tables.residual.idx, "fixture must leave residual edges"
     ts = Trainer(build_gcn([12, 8, 3], dropout_rate=0.0), ds,
                  TrainConfig(aggr_impl="segment", **kw))
     tb.train()
@@ -454,8 +454,8 @@ def test_trainer_bdense_no_dense_tiles_falls_back():
     tb = Trainer(build_gcn([12, 8, 3], dropout_rate=0.0), ds,
                  TrainConfig(aggr_impl="bdense",
                              bdense_min_fill=10**9, **kw))
-    assert tb.gctx.bd_a is None
-    assert tb.gctx.sect_idx
+    assert tb.gctx.tables.a is None
+    assert tb.gctx.tables.residual.idx
     ts = Trainer(build_gcn([12, 8, 3], dropout_rate=0.0), ds,
                  TrainConfig(aggr_impl="segment", **kw))
     tb.train()
@@ -501,13 +501,13 @@ def test_bdense_distributed_matches_segment():
                             TrainConfig(aggr_impl="bdense",
                                         bdense_min_fill=64, **kw))
     # the per-part plans actually split: dense tiles AND residuals
-    assert tb.data.bd_tabs, "fixture must yield dense tiles"
+    assert tb.data.tables.a is not None, "fixture must yield dense tiles"
     dense_total = sum(o["dense_edges"] for o in tb.data.bd_occupancy)
-    # a REAL residual remains (sect_idx alone is vacuous: the bdense
-    # branch builds the stacked tables even for an all-dense plan)
+    # a REAL residual remains (counted off the plans, not read off
+    # the residual tables' presence)
     assert 0 < dense_total < ds.graph.num_edges, dense_total
-    assert tb.data.sect_idx
-    assert tb.data.bd_src_vpad >= 4 * tb.pg.part_nodes
+    assert tb.data.tables.residual.idx
+    assert tb.data.tables.src_vpad >= 4 * tb.pg.part_nodes
     ts = DistributedTrainer(build_gcn([12, 8, 3], dropout_rate=0.0),
                             ds, 4, TrainConfig(aggr_impl="segment",
                                                **kw))
@@ -548,8 +548,8 @@ def test_bdense_distributed_matches_single_device(group):
                  TrainConfig(aggr_impl="bdense", bdense_min_fill=64,
                              **kw))
     if group > 1:
-        assert td.data.bd_group == group
-        assert td.data.bd_tabs[0].shape[1] % group == 0
+        assert td.data.tables.group == group
+        assert td.data.tables.a.shape[1] % group == 0
     td.train()
     t1.train()
     for k in t1.params:
@@ -597,7 +597,7 @@ def test_bdense_distributed_packs_with_zero_block_parts():
     assert any(o["n_blocks"] == 0 for o in occ), \
         "fixture must leave some partition without dense tiles"
     assert any(o["n_blocks"] > 0 for o in occ)
-    assert td.data.bd_tabs[0].shape[-1] == 64  # u4 despite empties
+    assert td.data.tables.a.shape[-1] == 64  # u4 despite empties
     ts = DistributedTrainer(build_gcn([8, 8, 3], dropout_rate=0.0),
                             ds, 4, TrainConfig(aggr_impl="segment",
                                                **kw))
@@ -636,7 +636,7 @@ def test_bdense_distributed_unpackable_stays_uint8_and_exact():
                       learning_rate=0.05)
     td = DistributedTrainer(build_gcn([8, 8, 3], dropout_rate=0.0),
                             ds, 4, cfg)
-    assert td.data.bd_tabs[0].shape[-1] == 128  # uint8, not packed
+    assert td.data.tables.a.shape[-1] == 128  # uint8, not packed
     ts = DistributedTrainer(build_gcn([8, 8, 3], dropout_rate=0.0),
                             ds, 4,
                             TrainConfig(aggr_impl="segment",
@@ -670,7 +670,7 @@ def test_bdense_distributed_group_mismatch_fails_fast():
     mesh = mh.make_parts_mesh(4)
     data = shard_dataset(ds, pg, mesh, aggr_impl="bdense",
                          bdense_min_fill=8)  # group=1 tables
-    assert data.bd_tabs and data.bd_group == 1
+    assert data.tables.a is not None and data.tables.group == 1
     with pytest.raises(ValueError, match="bdense_group"):
         DistributedTrainer(
             build_gcn([12, 8, 3], dropout_rate=0.0), ds, 4,
@@ -694,8 +694,8 @@ def test_bdense_distributed_no_dense_tiles_falls_back():
                             ds, 4,
                             TrainConfig(aggr_impl="bdense",
                                         bdense_min_fill=10**9, **kw))
-    assert not tb.data.bd_tabs
-    assert tb.data.sect_idx
+    assert tb.data.tables.a is None
+    assert tb.data.tables.residual.idx
     ts = DistributedTrainer(build_gcn([12, 8, 3], dropout_rate=0.0),
                             ds, 4, TrainConfig(aggr_impl="segment",
                                                **kw))
@@ -730,23 +730,22 @@ def test_bdense_multihost_local_build_matches_global_and_trains(group):
               bdense_group=group)
     loc = mh.shard_dataset_local(ds, pg, mesh, **kw)
     glo = shard_dataset(ds, pg, mesh, **kw)
-    assert len(loc.bd_tabs) == 3 == len(glo.bd_tabs), \
+    lt, gt = loc.tables, glo.tables
+    assert lt.a is not None and gt.a is not None, \
         "fixture must yield dense tiles in both builders"
-    assert loc.bd_group == group == glo.bd_group
+    assert lt.group == group == gt.group
     # the packable fixture stacks u4 tables in BOTH builders (the
     # multihost packing decision rides the max-multiplicity
     # collective; a width mismatch here means the agreement broke)
-    assert loc.bd_tabs[0].shape[-1] == 64 == glo.bd_tabs[0].shape[-1]
-    for a, b in zip(loc.bd_tabs, glo.bd_tabs):
+    assert lt.a.shape[-1] == 64 == gt.a.shape[-1]
+    for a, b in zip((lt.a, lt.src, lt.dst), (gt.a, gt.src, gt.dst)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert (loc.bd_vpad, loc.bd_src_vpad) == (glo.bd_vpad,
-                                              glo.bd_src_vpad)
-    for a, b in zip(loc.sect_idx, glo.sect_idx):
+    assert (lt.vpad, lt.src_vpad) == (gt.vpad, gt.src_vpad)
+    for a, b in zip(lt.residual.idx, gt.residual.idx):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(loc.sect_sub_dst, glo.sect_sub_dst):
+    for a, b in zip(lt.residual.sub_dst, gt.residual.sub_dst):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert loc.sect_meta == glo.sect_meta
-    assert loc.edge_src.shape[-1] == 1
+    assert lt.residual.meta == gt.residual.meta
     cfg = TrainConfig(epochs=2, verbose=False, aggr_impl="bdense",
                       bdense_min_fill=8, bdense_group=group,
                       dropout_rate=0.0, eval_every=1 << 30)
@@ -777,11 +776,11 @@ def test_trainer_bdense_a_budget_caps_plan_and_stays_exact():
         build_gcn([12, 8, 3], dropout_rate=0.0), ds,
         TrainConfig(aggr_impl="bdense", bdense_min_fill=250,
                     bdense_a_budget=128 * 128, **kw))
-    n_unc = int(uncapped.gctx.bd_a.shape[0])
+    n_unc = int(uncapped.gctx.tables.a.shape[0])
     assert n_unc > 2, "fixture must yield multiple dense tiles"
-    assert int(capped.gctx.bd_a.shape[0]) == 2
-    assert capped.gctx.bd_a.shape[-1] == 64  # u4-packed
-    assert capped.gctx.bd_a.size <= 128 * 128  # device bytes <= cap
+    assert int(capped.gctx.tables.a.shape[0]) == 2
+    assert capped.gctx.tables.a.shape[-1] == 64  # u4-packed
+    assert capped.gctx.tables.a.size <= 128 * 128  # device bytes <= cap
     ref = Trainer(build_gcn([12, 8, 3], dropout_rate=0.0), ds,
                   TrainConfig(aggr_impl="segment", **kw))
     capped.train()

@@ -414,7 +414,7 @@ def test_partitions_are_exact_against_one(ds, parts):
     from roc_tpu.train.optimizer import AdamConfig
     one.adam_cfg = many.adam_cfg = AdamConfig(epsilon=1e-3)
     assert many.pg.part_nodes * parts > V           # padding exists
-    assert [int(n) for n in np.asarray(many.data.real_rows[0])] == [
+    assert [int(n) for n in np.asarray(many.data.real_rows)] == [
         r - l + 1 for l, r in many.pg.bounds]
     np.testing.assert_allclose(
         np.asarray(one.predict()), np.asarray(many.predict()),

@@ -174,18 +174,18 @@ def test_ring_overlap_matches_sequential(dataset, num_parts,
     epilogue — the rotation never reads the accumulator, so the
     reorder is a schedule change, not a numerics one."""
     from jax.sharding import PartitionSpec as P
-    from roc_tpu.ops.norm import inv_sqrt_degree_np
+    from roc_tpu.models.tables import build_tables
     from roc_tpu.parallel import ring as R
-    from roc_tpu.parallel.distributed import _shard_map
+    from roc_tpu.parallel.distributed import _shard_map, stacked_view
     pg = partition_graph(dataset.graph, num_parts, node_multiple=8)
-    rt = R.build_ring_tables(pg)
+    # the fused build's tables: the pairs and their baked weights
+    rt = build_tables("ring", stacked_view(pg, jnp.asarray, remap=False),
+                      fuse=True)
     mesh = make_mesh(num_parts)
     rng = np.random.RandomState(7)
     xs = jnp.asarray(pad_nodes(
         rng.randn(dataset.graph.num_nodes, 8).astype(np.float32), pg))
-    src, dst = jnp.asarray(rt.src), jnp.asarray(rt.dst)
-    w = jnp.asarray(R.ring_weight_tables(
-        pg, rt, inv_sqrt_degree_np(dataset.graph.in_degree)))
+    src, dst, w = rt.src, rt.dst, rt.w
     res = {}
     for overlap in (False, True):
         def body(xb, sb, db, wb, o=overlap):
@@ -226,9 +226,10 @@ def test_ring_overlap_config_trains_identically(dataset):
 def test_ring_tables_cover_all_edges(dataset):
     """Every global edge appears in exactly one (partition, shard) table,
     reconstructed back to its (global_src, global_dst) pair."""
-    from roc_tpu.parallel.ring import build_ring_tables
+    from roc_tpu.models.tables import build_tables
+    from roc_tpu.parallel.distributed import stacked_view
     pg = partition_graph(dataset.graph, 4, node_multiple=8)
-    rt = build_ring_tables(pg)
+    rt = build_tables("ring", stacked_view(pg, np.asarray, remap=False))
     P = pg.num_parts
     starts = np.asarray([l for l, _ in pg.bounds], dtype=np.int64)
     got = []
@@ -256,13 +257,17 @@ def test_ring_padding_ratio_bounded():
     the exact value is a property of the fixture draw — 2.05 on the
     current generator stream — so the bound guards against runaway
     padding, not a point estimate)."""
-    from roc_tpu.parallel.ring import build_ring_tables
+    from roc_tpu.models.tables import build_tables
+    from roc_tpu.parallel.distributed import stacked_view
     ds = synthetic_dataset(512, 9, in_dim=8, num_classes=4, seed=3)
     pg = partition_graph(ds.graph, 8, node_multiple=8)
-    rt = build_ring_tables(pg)
-    assert rt.padding_ratio >= 1.0
-    assert rt.padding_ratio < 2.5, (
-        f"ring padding ratio {rt.padding_ratio:.2f} exceeds the bound")
+    notes = {}
+    build_tables("ring", stacked_view(pg, np.asarray, remap=False),
+                 notes=notes)
+    ratio = notes["ring_padding_ratio"]
+    assert ratio >= 1.0
+    assert ratio < 2.5, (
+        f"ring padding ratio {ratio:.2f} exceeds the bound")
 
 
 def test_sectioned_distributed_matches_single(dataset):
@@ -327,11 +332,12 @@ def test_sectioned_distributed_multi_section(dataset):
     got_sd = mh.shard_dataset_local(ds, pg, mesh,
                                     aggr_impl="sectioned",
                                     section_rows=32)
-    assert len(want_sd.sect_idx) > 2
-    assert got_sd.sect_meta == want_sd.sect_meta
-    for a, b in zip(got_sd.sect_idx, want_sd.sect_idx):
+    got_t, want_t = got_sd.tables, want_sd.tables
+    assert len(want_t.idx) > 2
+    assert got_t.meta == want_t.meta
+    for a, b in zip(got_t.idx, want_t.idx):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(got_sd.sect_sub_dst, want_sd.sect_sub_dst):
+    for a, b in zip(got_t.sub_dst, want_t.sub_dst):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -350,7 +356,7 @@ def test_sectioned_distributed_honors_sub_w_and_u16(dataset):
                                       dropout_rate=0.0), ds, 4,
                             TrainConfig(**kw))
     # the knobs actually shaped the uploaded tables
-    for a in t4.data.sect_idx:
+    for a in t4.data.tables.idx:
         assert a.shape[-1] == 16
         assert a.dtype == jnp.uint16
     t1.train()

@@ -93,7 +93,7 @@ def test_flat_sum_parity_distributed(ds, parts, halo):
 
 def test_flat_sum_fused_weight_parity(ds):
     """aggr_fuse='on' bakes the D^-1/2 A D^-1/2 entries into the flat
-    tables (flat8_w): fused flat_sum == fused sectioned == UNfused
+    tables (FlatTables.w): fused flat_sum == fused sectioned == UNfused
     flat_sum (exact linear algebra), single-device and P=2."""
     mk = lambda: build_gcn([24, 16, 5], dropout_rate=0.0)
     _, _, p_sect = _train(ds, mk, "sectioned", aggr_fuse="on")
@@ -101,7 +101,7 @@ def test_flat_sum_fused_weight_parity(ds):
     _, _, p_off = _train(ds, mk, "flat_sum", aggr_fuse="off")
     # the fused model really did fuse, and the tables really exist
     assert t_f.model.num_fused_aggregates() > 0
-    assert t_f.gctx.flat8_w is not None
+    assert t_f.gctx.tables.w is not None
     assert _rel_err(p_f, p_sect) < REL
     assert _rel_err(p_f, p_off) < REL
     _, _, p_d = _train(ds, mk, "flat_sum", parts=2, aggr_fuse="on")

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from roc_tpu.core.graph import synthetic_dataset
+from roc_tpu.models.tables import RingTables, SectionedTables
 from roc_tpu.models.builder import Model
 from roc_tpu.models.gcn import build_gcn
 from roc_tpu.models.gcn2 import build_gcn2
@@ -161,15 +162,15 @@ def test_fused_matches_unfused_single_device(dataset, impl, build):
 
 def test_fused_weight_tables_present(dataset):
     # the table-baked forms actually engage (not the scaling fallback)
-    g = make_graph_context(dataset, "ell", fuse=True)
-    assert g.ell_w and len(g.ell_w) == len(g.ell_idx)
-    g = make_graph_context(dataset, "sectioned", fuse=True)
-    assert g.sect_w and len(g.sect_w) == len(g.sect_idx)
-    g = make_graph_context(dataset, "bdense", bdense_min_fill=1,
-                           fuse=True)
-    assert len(g.bd_scale) == 2
-    g = make_graph_context(dataset, "flat_sum", fuse=True)
-    assert g.flat8_w is not None and g.flat8_w.shape == g.flat8_idx.shape
+    t = make_graph_context(dataset, "ell", fuse=True).tables
+    assert t.w and len(t.w) == len(t.idx)
+    t = make_graph_context(dataset, "sectioned", fuse=True).tables
+    assert t.w and len(t.w) == len(t.idx)
+    t = make_graph_context(dataset, "bdense", bdense_min_fill=1,
+                           fuse=True).tables
+    assert len(t.scale) == 2
+    t = make_graph_context(dataset, "flat_sum", fuse=True).tables
+    assert t.w is not None and t.w.shape == t.idx.shape
 
 
 @pytest.mark.parametrize("halo,impl", [
@@ -189,8 +190,8 @@ def test_fused_matches_unfused_distributed(dataset, halo, impl):
     assert t1.model.num_fused_aggregates() == 2
     if halo == "gather":
         # shard_dataset baked the layout's own weight tables
-        baked = t1.data.ell_w if impl == "ell" else t1.data.sect_w
-        assert baked and not (t0.data.ell_w or t0.data.sect_w)
+        assert t1.data.tables.weighted
+        assert not t0.data.tables.weighted
     assert _rel_err(t0.predict(), t1.predict()) < REL
     # gradients: two full training epochs must keep params aligned
     t0.train(2)
@@ -207,10 +208,9 @@ def test_fused_ring_weight_tables_bake(dataset, halo):
                       memory="manual", aggr_fuse="on",
                       verbose=False)
     t = DistributedTrainer(build_gcn([12, 16, 4]), dataset, 2, cfg)
-    if halo == "ring":
-        assert t.data.ring_w
-    else:
-        assert t.data.sect_w
+    assert t.data.tables.weighted
+    assert isinstance(t.data.tables,
+                      RingTables if halo == "ring" else SectionedTables)
 
 
 def test_trainer_fuse_knob_and_equivalence(dataset):

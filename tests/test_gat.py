@@ -296,9 +296,9 @@ def test_gat_ring_rejected_at_setup(dataset):
 def test_gat_rejects_sectioned_tables():
     """A GraphContext without ELL tables raises the actionable error
     rather than silently mis-aggregating."""
+    from roc_tpu.models.tables import SectionedTables
     from roc_tpu.models.builder import GraphContext
-    gctx = GraphContext(edge_src=jnp.zeros(1, jnp.int32),
-                        edge_dst=jnp.zeros(1, jnp.int32),
+    gctx = GraphContext(tables=SectionedTables(idx=(), sub_dst=()),
                         in_degree=jnp.zeros(4, jnp.int32),
                         num_rows=4, gathered_rows=4,
                         aggr_impl="sectioned")

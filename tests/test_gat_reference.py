@@ -32,6 +32,7 @@ import pytest
 
 from roc_tpu.core.ell import flat_sum_from_graph
 from roc_tpu.core.graph import Dataset, Graph, MASK_TRAIN
+from roc_tpu.models.tables import FlatTables
 from roc_tpu.models.gat import build_gat
 from roc_tpu.obs.scopes import (AGG, ATTN_PHASES, parse_op_name,
                                 parse_op_phase)
@@ -147,7 +148,8 @@ def _gctx(ds, layout, padding, symmetric=False):
         idx, dst = sect.idx[0], sect.sub_dst[0]
         assert idx.shape[0] > 2
         assert (dst == g.num_nodes).any() == (padding == "padded")
-        gctx.flat8_idx, gctx.flat8_dst = jnp.asarray(idx), jnp.asarray(dst)
+        gctx.tables = FlatTables(idx=jnp.asarray(idx),
+                                 dst=jnp.asarray(dst))
     return gctx
 
 
@@ -376,14 +378,12 @@ def test_attention_plan_entries(data, layout, parts, monkeypatch):
         (3, HEADS, HEAD_W), (9, HEADS, HEAD_W), (15, 1, CLASSES)]
     assert {e["layout"] for e in got} == {layout}
     rows = V if parts == 1 else tr.pg.part_nodes     # padded
+    tables = tr.gctx.tables if parts == 1 else tr.data.tables
     if layout == "ell":
-        tables = tr.gctx.ell_idx if parts == 1 else tr.data.ell_idx
-        slots = sum(int(np.prod(a.shape[-2:])) for a in tables)
+        slots = sum(int(np.prod(a.shape[-2:])) for a in tables.idx)
         want = (1, slots, None)
     else:
-        table = (tr.gctx.flat8_idx if parts == 1
-                 else tr.data.sect_idx[0])
-        want = (2, int(np.prod(table.shape[-3:])), rows + 1)
+        want = (2, int(np.prod(tables.idx.shape[-3:])), rows + 1)
     assert {(e["edge_passes"], e["padded_slots_per_pass"],
              e["carry_rows"]) for e in got} == {want}
     assert want[1] >= ds.graph.num_edges / parts

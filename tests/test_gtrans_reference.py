@@ -239,7 +239,7 @@ def test_pass_b_draws_the_forward_mask_on_every_stored_edge(ds):
     gctx = make_graph_context(ds, "ell")
     seed = _seeds(jax.random.PRNGKey(3))[0]
     fwd, bwd = {}, {}
-    for idx, rid in zip(gctx.ell_idx, gctx.ell_row_id):
+    for idx, rid in zip(gctx.tables.idx, gctx.tables.row_id):
         idx, rid = np.asarray(idx), np.asarray(rid)
         real = (idx != V) & (rid[:, None] < V)
         rows = np.broadcast_to(rid[:, None], idx.shape)

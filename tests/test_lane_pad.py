@@ -74,14 +74,13 @@ def _bare_scan(g, x, fused):
     """The unpadded scan, called directly on ``[x; 0]`` with the
     context's own tables."""
     full = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+    t = g.tables
     if g.aggr_impl == "flat_sum":
-        return aggregate_flat_sum(full, g.flat8_idx, g.flat8_dst,
-                                  g.num_rows,
-                                  flat_w=g.flat8_w if fused else None,
-                                  win_rows=g.flat8_win)
-    return aggregate_ell_sect(full, g.sect_idx, g.sect_sub_dst,
-                              g.sect_meta, g.num_rows,
-                              sect_w=g.sect_w if fused else None)
+        return aggregate_flat_sum(full, t.idx, t.dst, g.num_rows,
+                                  flat_w=t.w if fused else None,
+                                  win_rows=t.win_rows)
+    return aggregate_ell_sect(full, t.idx, t.sub_dst, t.meta,
+                              g.num_rows, sect_w=t.w if fused else None)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -100,8 +99,7 @@ def test_padded_scan_is_bit_identical_p1(gctxs, ds, impl, fused, F,
     runs."""
     g = gctxs[impl, fused]
     if fused:
-        assert (g.flat8_w if impl == "flat_sum" else g.sect_w) \
-            is not None
+        assert g.tables.weighted
     V = ds.graph.num_nodes
     kx, kc = jax.random.split(jax.random.PRNGKey(F))
     x = jax.random.normal(kx, (V, F), jnp.float32).astype(dtype)
@@ -274,13 +272,16 @@ def test_plan_line_carries_agg_chunk_rows(tmp_path, ds, impl, parts,
         configure(jsonl_path=p, console=False)
         if parts > 1:
             tr = DistributedTrainer(model, ds, parts, cfg)
-            tables = tr.data.sect_idx
+            t = tr.data.tables
         else:
             tr = Trainer(model, ds, cfg)
-            tables = (tr.gctx.sect_idx if impl != "flat_sum"
-                      else (tr.gctx.flat8_idx,))
+            t = tr.gctx.tables
     finally:
         configure(jsonl_path=None)
+    # the chunk tables of the sum scan: the sections, the flat
+    # layout's one, bdense's residual
+    tables = ((t.idx,) if impl == "flat_sum" else
+              t.residual.idx if impl == "bdense" else t.idx)
     res = [json.loads(line) for line in open(p)
            if json.loads(line)["cat"] == "manifest"][-1]["resolved"]
     if impl == "ell":

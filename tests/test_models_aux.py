@@ -269,12 +269,12 @@ def test_min_aggregator_matches_numpy(dataset):
 @pytest.mark.parametrize("impl", ["sectioned", "bdense"])
 def test_max_has_no_sectioned_or_bdense_form(dataset, impl):
     """A context built on ``sectioned`` / ``bdense`` tables refuses a
-    MAX reduction by name: its edge list is a one-element stub, so
-    falling through to the segment path would answer from one fake
-    edge (and at scale materialize ``[E, F]``)."""
+    MAX reduction by name: it carries no edge list, and the segment
+    path would at scale materialize ``[E, F]``."""
+    from roc_tpu.models.tables import EdgeList
     gctx = make_graph_context(dataset, aggr_impl=impl,
                               bdense_min_fill=1)
-    assert gctx.edge_src.shape == (1,)
+    assert not isinstance(gctx.tables, EdgeList)
     feats = jnp.asarray(dataset.features)
     for aggr in (AGGR_MAX, "min"):
         with pytest.raises(NotImplementedError, match=impl):

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from roc_tpu.core.graph import synthetic_dataset
 from roc_tpu.core.partition import partition_graph
+from roc_tpu.models.tables import FlatTables
 from roc_tpu.models.gcn import build_gcn
 from roc_tpu.parallel import multihost as mh
 
@@ -83,14 +84,14 @@ def test_local_ell_plan_matches_global_on_full_part():
     mesh = mh.make_parts_mesh(4)
     loc = mh.shard_dataset_local(ds, pg, mesh, aggr_impl="ell")
     glo = shard_dataset(ds, pg, mesh, aggr_impl="ell")
-    assert len(loc.ell_idx) == len(glo.ell_idx)
-    for a, b in zip(loc.ell_idx, glo.ell_idx):
+    assert len(loc.tables.idx) == len(glo.tables.idx)
+    for a, b in zip(loc.tables.idx, glo.tables.idx):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    np.testing.assert_array_equal(np.asarray(loc.ell_row_pos),
-                                  np.asarray(glo.ell_row_pos))
+    np.testing.assert_array_equal(np.asarray(loc.tables.row_pos),
+                                  np.asarray(glo.tables.row_pos))
     # the attention row map must agree too (EllTable.row_id)
-    assert len(loc.ell_row_id) == len(glo.ell_row_id)
-    for a, b in zip(loc.ell_row_id, glo.ell_row_id):
+    assert len(loc.tables.row_id) == len(glo.tables.row_id)
+    for a, b in zip(loc.tables.row_id, glo.tables.row_id):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -240,14 +241,14 @@ def test_local_sectioned_honors_sub_w_and_u16():
                                  sect_sub_w=16, sect_u16=True)
     glo = shard_dataset(ds, pg, mesh, aggr_impl="sectioned",
                         sect_sub_w=16, sect_u16=True)
-    assert len(loc.sect_idx) == len(glo.sect_idx)
-    for a, b in zip(loc.sect_idx, glo.sect_idx):
+    assert len(loc.tables.idx) == len(glo.tables.idx)
+    for a, b in zip(loc.tables.idx, glo.tables.idx):
         assert a.dtype == jnp.uint16
         assert a.shape[-1] == 16
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(loc.sect_sub_dst, glo.sect_sub_dst):
+    for a, b in zip(loc.tables.sub_dst, glo.tables.sub_dst):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert loc.sect_meta == glo.sect_meta
+    assert loc.tables.meta == glo.tables.meta
 
 
 def test_local_flat8_matches_global_and_trains():
@@ -264,13 +265,12 @@ def test_local_flat8_matches_global_and_trains():
     mesh = mh.make_parts_mesh(4)
     loc = mh.shard_dataset_local(ds, pg, mesh, aggr_impl="attn_flat8")
     glo = shard_dataset(ds, pg, mesh, aggr_impl="attn_flat8")
-    assert len(loc.sect_idx) == 1 == len(glo.sect_idx)
-    np.testing.assert_array_equal(np.asarray(loc.sect_idx[0]),
-                                  np.asarray(glo.sect_idx[0]))
-    np.testing.assert_array_equal(np.asarray(loc.sect_sub_dst[0]),
-                                  np.asarray(glo.sect_sub_dst[0]))
-    # the flat edge arrays are stubs, not [P, E_p] uploads
-    assert loc.edge_src.shape[-1] == 1
+    np.testing.assert_array_equal(np.asarray(loc.tables.idx),
+                                  np.asarray(glo.tables.idx))
+    np.testing.assert_array_equal(np.asarray(loc.tables.dst),
+                                  np.asarray(glo.tables.dst))
+    # the flat tables alone: no [P, E_p] edge list is uploaded
+    assert isinstance(loc.tables, FlatTables)
     cfg = TrainConfig(epochs=2, verbose=False, aggr_impl="attn_flat8",
                       dropout_rate=0.0, eval_every=1 << 30)
     tr = DistributedTrainer(build_gat([12, 8, 3], dropout_rate=0.0),
@@ -295,13 +295,14 @@ def test_local_flat_sum_matches_global_and_trains():
     mesh = mh.make_parts_mesh(4)
     loc = mh.shard_dataset_local(ds, pg, mesh, aggr_impl="flat_sum")
     glo = shard_dataset(ds, pg, mesh, aggr_impl="flat_sum")
-    assert len(loc.sect_idx) == 1 == len(glo.sect_idx)
-    np.testing.assert_array_equal(np.asarray(loc.sect_idx[0]),
-                                  np.asarray(glo.sect_idx[0]))
-    np.testing.assert_array_equal(np.asarray(loc.sect_sub_dst[0]),
-                                  np.asarray(glo.sect_sub_dst[0]))
-    # the flat edge arrays are stubs, not [P, E_p] uploads
-    assert loc.edge_src.shape[-1] == 1
+    np.testing.assert_array_equal(np.asarray(loc.tables.idx),
+                                  np.asarray(glo.tables.idx))
+    np.testing.assert_array_equal(np.asarray(loc.tables.dst),
+                                  np.asarray(glo.tables.dst))
+    assert (loc.tables.win_rows, loc.tables.bands) == (
+        glo.tables.win_rows, glo.tables.bands)
+    # the flat tables alone: no [P, E_p] edge list is uploaded
+    assert isinstance(loc.tables, FlatTables)
     cfg = TrainConfig(epochs=3, verbose=False, aggr_impl="flat_sum",
                       symmetric=True, dropout_rate=0.0,
                       eval_every=1 << 30)
@@ -338,9 +339,9 @@ def test_injected_data_without_flat8_tables_fails_fast():
 
 
 def test_injected_sectioned_data_with_bdense_impl_fails_fast():
-    """Sectioned-built data passes the sect_idx/sect_meta checks but
-    carries no block plan; resolved aggr_impl='bdense' must raise at
-    construction, not silently run the pure sectioned residual."""
+    """Sectioned-built data carries no block plan; resolved
+    aggr_impl='bdense' must raise at construction, not silently run
+    the pure sectioned residual."""
     from roc_tpu.parallel.distributed import DistributedTrainer
     from roc_tpu.train.trainer import TrainConfig
 

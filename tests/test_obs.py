@@ -122,10 +122,10 @@ def test_manifest_resolved_carries_agg_window(tmp_path, impl, parts):
         configure(jsonl_path=p, console=False)
         if parts > 1:
             tr = DistributedTrainer(build_gcn([8, 8, 3]), ds, parts, cfg)
-            rows, gctx = tr.pg.part_nodes, tr._gctx()
+            rows, t = tr.pg.part_nodes, tr.data.tables
         else:
             tr = Trainer(build_gcn([8, 8, 3]), ds, cfg)
-            rows, gctx = ds.graph.num_nodes, tr.gctx
+            rows, t = ds.graph.num_nodes, tr.gctx.tables
     finally:
         configure(jsonl_path=None)
     res = [json.loads(line) for line in open(p)
@@ -139,8 +139,8 @@ def test_manifest_resolved_carries_agg_window(tmp_path, impl, parts):
     carry = rows + 1
     assert res["agg_carry_rows"] == carry
     wins = res["agg_window_rows"]
-    table = ([m[2] for m in gctx.sect_meta] if impl == "sectioned"
-             else [gctx.flat8_win])
+    table = ([m[2] for m in t.meta] if impl == "sectioned"
+             else [t.win_rows])
     assert table and all(w > 0 and w % 128 == 0 for w in table)
     # what a chunk step really reads and writes, not the raw span
     assert wins == [scan_window_rows(w, carry) for w in table]

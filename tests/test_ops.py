@@ -629,7 +629,7 @@ def test_directed_grad_matches_segment(impl):
                                   bdense_min_fill=1)
         assert not gctx.symmetric
         if aggr_impl == "bdense":
-            assert gctx.bd_a is not None
+            assert gctx.tables.a is not None
         return np.asarray(jax.grad(
             lambda v: (gctx.aggregate_sum(v) * cot).sum())(x))
 

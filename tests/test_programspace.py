@@ -5,6 +5,7 @@ a live rig run (the acceptance criterion — no under- or
 over-enumeration), the program budget ratchets shrink-only, and the
 CLI's --json output is machine-readable."""
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -140,11 +141,12 @@ def test_ring_halo_parity_and_violation():
     from roc_tpu.core.costmodel import partition_halo_stats
     from roc_tpu.core.graph import synthetic_dataset
     from roc_tpu.core.partition import partition_graph
-    from roc_tpu.parallel.ring import build_ring_tables
+    from roc_tpu.models.tables import build_tables
+    from roc_tpu.parallel.distributed import stacked_view
     ds = synthetic_dataset(num_nodes=96, avg_degree=5, in_dim=8,
                            num_classes=4, seed=3)
     pg = partition_graph(ds.graph, 3, node_multiple=8)
-    rt = build_ring_tables(pg)
+    rt = build_tables("ring", stacked_view(pg, np.asarray, remap=False))
     assert not check_ring_halo("collective:fix", pg, rt)
     recv, send = ring_table_halo_counts(pg, rt)
     hi, ho = partition_halo_stats(pg)
@@ -153,8 +155,7 @@ def test_ring_halo_parity_and_violation():
     ext = np.where(src[0, 1] < pg.part_nodes)[0]
     assert len(ext) > 1, "fixture graph must have a real halo"
     src[0, 1, ext] = src[0, 1, ext[0]]
-    rt2 = type(rt)(src=src, dst=rt.dst,
-                   padding_ratio=rt.padding_ratio)
+    rt2 = dataclasses.replace(rt, src=src)
     keys = sorted(f.key for f in check_ring_halo("collective:fix",
                                                  pg, rt2))
     assert keys == ["halo-in|part=0", "halo-out|part=1"]
@@ -516,14 +517,9 @@ def test_flat_sum_rig_one_scan_per_width(rig_dataset):
         pytest.skip(f"needs {spec.parts} devices")
     tr = build_rig_trainer(spec, rig_dataset)
     assert tr.config.aggr_impl == "flat_sum"
-    d = tr.data
     lr = jnp.asarray(0.01, jnp.float32)
     jaxpr = jax.make_jaxpr(tr._train_step._jit)(
-        tr.params, tr.opt_state, d.feats, d.labels, d.mask,
-        d.edge_src, d.edge_dst, d.in_degree, d.ell_idx,
-        d.ell_row_pos, d.ell_row_id, d.ring_idx, d.sect_idx,
-        d.sect_sub_dst, d.bd_tabs,
-        (d.ell_w, d.sect_w, d.ring_w, d.bd_scale), tr.key, lr)
+        *tr._train_args(tr.key, lr))
     shapes = _scan_shapes(jaxpr)
     widths = {op.dim for op in tr.model._ops
               if op.kind == "scatter_gather"}

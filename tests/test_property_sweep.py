@@ -61,7 +61,7 @@ def test_aggregation_impls_agree_on_stress_graphs(seed, layout):
     if layout in BDENSE:
         gctx, got = forward("bdense", bdense_min_fill=1,
                             **BDENSE[layout])
-        assert gctx.bd_a is not None
+        assert gctx.tables.a is not None
     else:
         _, got = forward(layout)
     np.testing.assert_allclose(got, forward("segment")[1], rtol=2e-4,

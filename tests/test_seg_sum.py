@@ -334,13 +334,13 @@ def test_flat_sum_engaged_under_the_lane_pad(two_pass):
                  labels=np.zeros(n, np.int32), mask=np.ones(n, np.int32),
                  num_classes=2, name="dense")
     gctx = make_graph_context(ds, "flat_sum")
-    assert gctx.flat8_bands and gctx.flat8_idx.shape[0] >= 2
+    t = gctx.tables
+    assert t.bands and t.idx.shape[0] >= 2
     plan = gctx.agg_window()
     (seg,), ((before, after),) = plan["agg_seg_sum"], \
         plan["agg_carry_updates"]
     n_chunks, seg_rows = plan["agg_chunk_rows"][0]
-    assert seg == list(scan_seg_sum(seg_rows, gctx.flat8_win,
-                                    gctx.flat8_bands, 128))
+    assert seg == list(scan_seg_sum(seg_rows, t.win_rows, t.bands, 128))
     assert before == n_chunks * seg_rows
     assert after == n_chunks * (seg_rows // seg[0]) * seg[1] < before / 2
     x = jnp.asarray(rng.randint(-4, 5, (n, 41)).astype(np.float32) / 4)

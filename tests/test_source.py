@@ -5,6 +5,7 @@ the spy asserting a host touches only its partitions' byte ranges
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from roc_tpu.core import graph as G
@@ -132,15 +133,12 @@ def test_shard_dataset_local_matches_global(aggr_impl):
                                   np.asarray(want.labels))
     np.testing.assert_array_equal(np.asarray(got.mask),
                                   np.asarray(want.mask))
-    np.testing.assert_array_equal(np.asarray(got.edge_src),
-                                  np.asarray(want.edge_src))
-    np.testing.assert_array_equal(np.asarray(got.edge_dst),
-                                  np.asarray(want.edge_dst))
-    assert len(got.ell_idx) == len(want.ell_idx)
-    for a, b in zip(got.ell_idx, want.ell_idx):
+    # the layout's tables, whole: one tree, every array equal
+    assert (jax.tree_util.tree_structure(got.tables)
+            == jax.tree_util.tree_structure(want.tables))
+    for a, b in zip(jax.tree_util.tree_leaves(got.tables),
+                    jax.tree_util.tree_leaves(want.tables)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    np.testing.assert_array_equal(np.asarray(got.ell_row_pos),
-                                  np.asarray(want.ell_row_pos))
 
 
 def test_trainer_on_file_source_local_shards(disk_ds):
@@ -164,9 +162,10 @@ def test_trainer_on_file_source_local_shards(disk_ds):
 
 
 def test_shard_dataset_local_ring_matches_global():
-    """Partition-local ring prep (pair lists from local column reads +
-    O(P) width agreement) must produce byte-identical ring tables to
-    the global build_ring_tables path."""
+    """Partition-local ring prep (pair lists from each process's own
+    column reads + O(P) width agreement by collective) must produce
+    byte-identical ring tables to the single-controller build over
+    every part's stacked columns (``shard_dataset``)."""
     from roc_tpu.parallel import multihost as mh
     from roc_tpu.parallel.distributed import shard_dataset
 
@@ -175,10 +174,10 @@ def test_shard_dataset_local_ring_matches_global():
     pg = partition_graph(ds.graph, 4, edge_multiple=64)
     want = shard_dataset(ds, pg, mesh, halo="ring")
     got = mh.shard_dataset_local(ds, pg, mesh, halo="ring")
-    np.testing.assert_array_equal(np.asarray(got.ring_idx[0]),
-                                  np.asarray(want.ring_idx[0]))
-    np.testing.assert_array_equal(np.asarray(got.ring_idx[1]),
-                                  np.asarray(want.ring_idx[1]))
+    np.testing.assert_array_equal(np.asarray(got.tables.src),
+                                  np.asarray(want.tables.src))
+    np.testing.assert_array_equal(np.asarray(got.tables.dst),
+                                  np.asarray(want.tables.dst))
     np.testing.assert_allclose(got.ring_padding_ratio,
                                want.ring_padding_ratio)
     np.testing.assert_allclose(np.asarray(got.feats),
@@ -248,9 +247,9 @@ def test_shard_dataset_local_sectioned_matches_global():
     pg = partition_graph(ds.graph, 4, edge_multiple=64)
     want = shard_dataset(ds, pg, mesh, aggr_impl="sectioned")
     got = mh.shard_dataset_local(ds, pg, mesh, aggr_impl="sectioned")
-    assert got.sect_meta == want.sect_meta
-    assert len(got.sect_idx) == len(want.sect_idx)
-    for a, b in zip(got.sect_idx, want.sect_idx):
+    assert got.tables.meta == want.tables.meta
+    assert len(got.tables.idx) == len(want.tables.idx)
+    for a, b in zip(got.tables.idx, want.tables.idx):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(got.sect_sub_dst, want.sect_sub_dst):
+    for a, b in zip(got.tables.sub_dst, want.tables.sub_dst):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
